@@ -36,7 +36,9 @@ def initialize(args=None,
     """
     from .runtime.engine import DeepSpeedEngine
     from .runtime.pipe.module import PipelineModule
+    from .utils.device import enable_compile_cache
 
+    enable_compile_cache()
     config = config if config is not None else config_params
     if config is None and args is not None and hasattr(args, "deepspeed_config") \
             and args.deepspeed_config is not None:
@@ -67,7 +69,9 @@ def init_inference(model, config=None, **kwargs):
     bert/distil_bert injection containers."""
     from .inference.engine import InferenceEngine
     from .inference.config import DeepSpeedInferenceConfig
+    from .utils.device import enable_compile_cache
 
+    enable_compile_cache()
     if config is None:
         config = {}
     if isinstance(config, dict):

@@ -15,6 +15,12 @@ few steps, and writes ``{"status": "ok", "latency_s": ..., "throughput": ...,
 "flops": ...}`` to ``--out``. Missing/partial output, a non-zero exit, or a
 timeout mark the experiment failed/timeout. ``slot_envs`` gives each parallel
 slot its own environment overlay (e.g. disjoint device sets on a pod).
+
+One process per chip: every runner claims the chips its environment shows it,
+so the tuner process itself stays off jax (``Autotuner`` does, in subprocess
+mode), and more than one slot at a time is only accepted when each slot's
+overlay differs — i.e. the caller pinned the slots to disjoint devices — or
+the runners are CPU-only (``JAX_PLATFORMS=cpu``).
 """
 
 import json
@@ -25,6 +31,7 @@ import tempfile
 import time
 from typing import Any, Dict, List, Optional
 
+from ..utils.device import claims_chips
 from ..utils.logging import log_dist, logger
 
 
@@ -42,6 +49,15 @@ class ExperimentScheduler:
         self.slot_envs = slot_envs or [{}] * self.max_parallel
         if not (len(self.slot_envs) >= self.max_parallel):
             raise AssertionError("need one env overlay per parallel slot")
+        slots = self.slot_envs[:self.max_parallel]
+        if self.max_parallel > 1 \
+                and any(claims_chips({**os.environ, **e}) for e in slots) \
+                and len({tuple(sorted(e.items())) for e in slots}) < len(slots):
+            raise ValueError(
+                f"max_parallel={self.max_parallel} runners with identical "
+                "slot_envs would all claim the same chips, and a chip serves "
+                "one process at a time: give every slot its own device set "
+                "through slot_envs, or run one experiment at a time")
         self.python = python or sys.executable
 
     def _launch(self, exp_id: int, overrides: Dict, workdir: str, slot: int):

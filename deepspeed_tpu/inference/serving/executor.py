@@ -14,7 +14,7 @@ decoding. Compile-key discipline:
   compiles.
 
 KV buffers are donated unconditionally (chunk in-place-updates the pool rows;
-jax 0.4.37 honours ``donate_argnums`` on CPU too — no backend guards).
+``donate_argnums`` is honoured on CPU too — no backend guards).
 
 Watchdog: with ``chunk_deadline_s`` set, each chunk (dispatch + host fetch — the
 two places a hung compile or collective wedges) runs on a watchdog thread and a
@@ -58,6 +58,11 @@ class ChunkTimeoutError(RuntimeError):
     def __init__(self, deadline_s: float):
         super().__init__(f"decode chunk exceeded its {deadline_s:.3f}s deadline")
         self.deadline_s = float(deadline_s)
+
+
+class ReplicaKilledError(RuntimeError):
+    """Raised by the ``arm_restore_kill`` chaos hook: the stand-in for a
+    replica dying between prefix restore/bind and suffix prefill."""
 
 
 def prompt_buckets(max_prompt_len: int, smallest: int = 8) -> Tuple[int, ...]:
@@ -266,8 +271,8 @@ class ChunkedDecodeExecutor:
                 overlap=getattr(engine, "comm_overlap", None))
             select = self._slot_select
 
-            def prefill(params, caches, slot, ids, prefix_len, suffix_len,
-                        seed, base_key):
+            def suffix_prefill(params, caches, slot, ids, prefix_len,
+                               suffix_len, seed, base_key):
                 one = [{"k": jax.lax.dynamic_slice_in_dim(c["k"], slot, 1, 0),
                         "v": jax.lax.dynamic_slice_in_dim(c["v"], slot, 1, 0)}
                        for c in caches]
@@ -282,7 +287,7 @@ class ChunkedDecodeExecutor:
                     for c, n in zip(caches, new_one)]
                 return tok0, caches
 
-            fns[key] = jax.jit(prefill, donate_argnums=(1,))
+            fns[key] = jax.jit(suffix_prefill, donate_argnums=(1,))
         return fns[key]
 
     def _suffix_prefill_fn_paged(self, bucket: int):
@@ -306,8 +311,8 @@ class ChunkedDecodeExecutor:
             ps, mp = self.pool.page_size, self.pool.max_pages
             P_total = self.pool.total_pages
 
-            def prefill(params, caches, tbl, ids, prefix_len, suffix_len,
-                        seed, base_key):
+            def suffix_prefill(params, caches, tbl, ids, prefix_len,
+                               suffix_len, seed, base_key):
                 one = []
                 for c in caches:
                     _, hk, _, d = c["k"].shape
@@ -337,7 +342,7 @@ class ChunkedDecodeExecutor:
                     out.append(kv)
                 return tok0, out
 
-            fns[key] = jax.jit(prefill, donate_argnums=(1,))
+            fns[key] = jax.jit(suffix_prefill, donate_argnums=(1,))
         return fns[key]
 
     def _spec_verify_fn(self, k: int):
@@ -474,8 +479,9 @@ class ChunkedDecodeExecutor:
             if self._restore_kill is not None:
                 cb, self._restore_kill = self._restore_kill, None
                 cb()
-                raise RuntimeError("chaos: replica killed between prefix "
-                                   "restore/bind and suffix prefill")
+                raise ReplicaKilledError("chaos: replica killed between "
+                                         "prefix restore/bind and suffix "
+                                         "prefill")
             ts0 = time.monotonic()
             with annotate("serving.suffix_prefill"):
                 if self.paged:

@@ -5,7 +5,7 @@ per pool; requests borrow a slot (row) for their lifetime, so every slot
 reserves its worst-case ``cap`` KV up front. Every pool mutation — scatter-in
 of a prefill's batch-1 cache, prefix-slab restore on a cache hit, zero-fill on
 release — runs as a donated jitted update, so the pool's HBM footprint is
-constant: jax 0.4.37 honours ``donate_argnums`` on CPU too, so there are no
+constant: ``donate_argnums`` is honoured on CPU too, so there are no
 backend guards (guarding donation behind backend checks cost 1500x on pool
 scatters in an earlier revision of this codebase).
 

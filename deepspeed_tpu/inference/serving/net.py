@@ -77,7 +77,8 @@ from typing import Dict, List, Optional
 from ...observability.metrics import RegistryFeed
 from ...utils.logging import logger
 from .scheduler import QueueFullError
-from .subproc import PROTO_VERSION, HostProtocolError, SubprocessReplica
+from .subproc import (PROTO_VERSION, HostProtocolError, SubprocessReplica,
+                      refuse_chip_child)
 
 #: frame sentinel: 0xD5 + wire version. Bumping the wire format bumps the
 #: second byte, so an old peer's resync scan never mis-frames a new stream.
@@ -302,6 +303,7 @@ class SocketReplicaLink(SubprocessReplica):
                                           socket.SO_REUSEADDR, 1)
                 self._listener.bind(("127.0.0.1", 0))
                 self._listener.listen(4)
+            jax_child = cmd is None     # a cmd override is a jax-free stub
             if cmd is None:
                 cmd = [sys.executable, "-m",
                        "deepspeed_tpu.inference.serving.subproc",
@@ -316,7 +318,6 @@ class SocketReplicaLink(SubprocessReplica):
                 if prefix_cache:
                     cmd += ["--prefix-cache"]
             full_env = dict(os.environ)
-            full_env.setdefault("JAX_PLATFORMS", "cpu")
             try:
                 import jax
                 full_env.setdefault(
@@ -326,6 +327,8 @@ class SocketReplicaLink(SubprocessReplica):
                 pass
             if env:
                 full_env.update(env)
+            if jax_child:
+                refuse_chip_child(full_env)
             self.proc = subprocess.Popen(
                 cmd, cwd=repo_root, env=full_env, text=True,
                 stdin=subprocess.DEVNULL, stdout=subprocess.PIPE,

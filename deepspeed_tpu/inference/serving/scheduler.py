@@ -20,6 +20,10 @@ Semantics:
 - **transient faults** — prefill and chunk dispatch run under
   ``retry_with_backoff`` with ``fault_point`` sites ``serving.prefill`` /
   ``serving.decode_chunk``, the same injection substrate as the checkpoint ring.
+  A dispatch that still fails with one of :data:`TRANSIENT_FAULTS` fails its
+  requests and the loop keeps serving; any other exception — a compile
+  refusal, an HBM ``RESOURCE_EXHAUSTED``, a trace-time error — is
+  deterministic, so after the same clean-up it propagates out of ``step()``.
 
 Token parity: greedy decode through the scheduler is bit-identical to per-request
 ``InferenceEngine.generate`` (same prefill math, same per-step decode math —
@@ -46,9 +50,17 @@ from ...observability.trace import CAT_SERVING, get_tracer
 from ...utils.fault_injection import fault_point, retry_with_backoff
 from ...utils.logging import logger
 from ..speculative import SpeculativeConfig, make_proposer
-from .executor import ChunkedDecodeExecutor
+from .executor import (ChunkedDecodeExecutor, ChunkTimeoutError,
+                       ReplicaKilledError)
 from .prefix_cache import PrefixCache, PrefixCacheConfig
 from .telemetry import ServingTelemetry, adaptive_retry_after
+
+
+#: Dispatch failures the serving loop outlives: I/O errors (the class
+#: ``retry_with_backoff`` retries), a chunk that overran its watchdog deadline,
+#: and the chaos kill hook. Everything else would fail every later dispatch
+#: the same way, so it is re-raised to whoever drives ``step()``.
+TRANSIENT_FAULTS = (OSError, ChunkTimeoutError, ReplicaKilledError)
 
 
 class RequestState(Enum):
@@ -551,9 +563,10 @@ class ContinuousBatchingScheduler:
                 tracer.end_span(prefill_span,
                                 attrs={"outcome": "error",
                                        "error": type(e).__name__})
-                # retry budget exhausted: fail THIS request, keep serving — the
-                # slot must not leak and the loop must not die with the queue
-                # still holding live requests
+                # retry budget exhausted: fail THIS request and (for a
+                # transient fault) keep serving — the slot must not leak and
+                # the loop must not die with the queue still holding live
+                # requests
                 logger.error(f"[serving] prefill failed for request "
                              f"{handle.id}: {type(e).__name__}: {e}")
                 now = time.monotonic()
@@ -583,6 +596,8 @@ class ContinuousBatchingScheduler:
                     self._rebuild_pool()
                 else:
                     self._release(slot)
+                if not isinstance(e, TRANSIENT_FAULTS):
+                    raise
                 continue
             now = time.monotonic()
             tracer.end_span(prefill_span, t1=now,
@@ -640,7 +655,10 @@ class ContinuousBatchingScheduler:
             # retry budget exhausted mid-decode: the pool buffers may have been
             # donated into a dispatch that died, so they cannot be trusted —
             # fail every in-flight request, rebuild the pool, keep serving the
-            # queue (same never-kill-the-loop contract as admission)
+            # queue (same contract as admission: the loop outlives transient
+            # faults; a deterministic failure propagates once the in-flight
+            # requests are failed — rebuilding a pool that cannot be
+            # allocated would only raise again)
             logger.error(f"[serving] decode chunk failed: "
                          f"{type(e).__name__}: {e}; failing "
                          f"{sum(h is not None for h in self._slot_req)} "
@@ -654,6 +672,8 @@ class ContinuousBatchingScheduler:
             self._remaining[:] = 0
             self._steps[:] = 0
             self._eos[:] = -1
+            if not isinstance(e, TRANSIENT_FAULTS):
+                raise
             self._rebuild_pool()
             return False
         now = time.monotonic()

@@ -119,7 +119,6 @@ def _cache_gossip(sched) -> dict:
 
 
 def child_main(argv=None) -> int:
-    os.environ.setdefault("JAX_PLATFORMS", "cpu")
     ap = argparse.ArgumentParser(prog="serving.subproc")
     ap.add_argument("--family", default="gpt2", choices=("gpt2", "llama"))
     ap.add_argument("--vocab-size", type=int, default=96)
@@ -344,6 +343,21 @@ def child_main(argv=None) -> int:
     return 0
 
 
+def refuse_chip_child(env) -> None:
+    """The one-process-per-chip rule for replica children on THIS host (see
+    ``utils.device.claims_chips``): the child inherits the parent's platform,
+    and a parent that serves, or built a reference engine, already holds the
+    chips the child would claim."""
+    from ...utils.device import claims_chips
+    if claims_chips(env):
+        raise RuntimeError(
+            "a hosted replica child on this host would claim its TPU chips, "
+            "which libtpu gives to one process at a time. On a chip host run "
+            "in-process replicas (one process drives every local chip, one "
+            "replica per device) or attach a child started on its own host "
+            "by endpoint; for a CPU run set JAX_PLATFORMS=cpu.")
+
+
 class SubprocessReplica:
     """Parent-side handle on a subprocess-hosted replica.
 
@@ -357,6 +371,7 @@ class SubprocessReplica:
     def __init__(self, repo_root: str, env: Optional[Dict[str, str]] = None,
                  prefix_cache: bool = False, cmd: Optional[List[str]] = None,
                  **dims):
+        jax_child = cmd is None         # a cmd override is a jax-free stub
         if cmd is None:
             cmd = [sys.executable, "-m",
                    "deepspeed_tpu.inference.serving.subproc"]
@@ -365,7 +380,6 @@ class SubprocessReplica:
             if prefix_cache:
                 cmd += ["--prefix-cache"]
         full_env = dict(os.environ)
-        full_env.setdefault("JAX_PLATFORMS", "cpu")
         try:
             # the determinism contract is self-enforcing: the child must draw
             # the same init bits as the parent's reference engine, and
@@ -379,6 +393,8 @@ class SubprocessReplica:
             pass                    # parent never imported jax: child default
         if env:
             full_env.update(env)
+        if jax_child:
+            refuse_chip_child(full_env)
         self.proc = subprocess.Popen(
             cmd, cwd=repo_root, env=full_env, text=True,
             stdin=subprocess.PIPE, stdout=subprocess.PIPE,

@@ -5,9 +5,11 @@ node's rank and the world layout, spawn one Python process per local worker with
 coordinator env contract that ``comm.init_distributed`` consumes
 (``COORDINATOR_ADDRESS``/``NPROC``/``PROCESS_ID``/``LOCAL_RANK``), forward SIGINT/SIGTERM to
 the children, and propagate the first failure (killing the stragglers) — the reference's
-sig_names/поll loop, minus CUDA_VISIBLE_DEVICES bookkeeping which has no TPU analogue (chips
-are assigned by the TPU runtime per process via ``TPU_PROCESS_BOUNDS``-style env, or shared
-under a single process).
+sig_names/поll loop, minus CUDA_VISIBLE_DEVICES bookkeeping, which has no TPU analogue:
+libtpu gives a host's chips to ONE process, and that process drives all of them through
+the mesh. So on a chip host ``--nproc_per_node`` must be 1 and anything larger is
+refused; several workers per node are for ``JAX_PLATFORMS=cpu`` runs (the multi-process
+tests), where nothing claims a chip.
 """
 
 import argparse
@@ -18,6 +20,7 @@ import sys
 import time
 from typing import List
 
+from ..utils.device import claims_chips
 from ..utils.logging import logger
 
 
@@ -115,6 +118,14 @@ def _wait_group(processes: List[subprocess.Popen]) -> int:
 
 def main(args=None):
     args = parse_args(args)
+    if args.nproc_per_node > 1 and claims_chips(os.environ):
+        logger.error(
+            f"[launch] --nproc_per_node={args.nproc_per_node}: every worker "
+            "would claim this host's chips, and libtpu gives them to one "
+            "process at a time. Run ONE process per host (it drives all local "
+            "chips through the mesh); for a CPU multi-process run set "
+            "JAX_PLATFORMS=cpu.")
+        sys.exit(2)
     world_size = args.num_nodes * args.nproc_per_node
     cmd = build_cmd(args)
 

@@ -556,19 +556,20 @@ def _bias_attention(q, k, v, slopes):
     """Full-sequence causal attention, optionally with per-head alibi slopes.
 
     The alibi bias rides INSIDE the Pallas flash kernel (no (h, t, s) bias tensor in
-    HBM — the reference fuses the same bias into ``softmax_kernels.cu``); tiny or
-    non-128-aligned lengths take the XLA einsum path where block padding would
-    dominate the kernel."""
+    HBM — the reference fuses the same bias into ``softmax_kernels.cu``). Lengths
+    that are not ``flash_eligible`` — the 8..128-token serving prompt buckets,
+    anything not a multiple of 128 — take the XLA einsum path, with or without
+    alibi: ``_block_sizes`` would hand Mosaic sub-tile blocks there."""
     from ..ops.attention.flash import flash_attention
-    from ..ops.transformer.attention import flash_eligible
+    from ..ops.transformer.attention import flash_eligible, xla_attention
     if k.shape[2] != q.shape[2]:  # GQA prefill: broadcast kv heads to query heads
         g = q.shape[2] // k.shape[2]
         k = jnp.repeat(k, g, axis=2)
         v = jnp.repeat(v, g, axis=2)
-    if slopes is None:
-        return flash_attention(q, k, v, causal=True)
     if flash_eligible(q.shape[1]):
         return flash_attention(q, k, v, causal=True, alibi_slopes=slopes)
+    if slopes is None:
+        return xla_attention(q, k, v, causal=True)
     return _alibi_attention_xla(q, k, v, slopes)
 
 

@@ -29,13 +29,10 @@ import numpy as np
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from ...utils.device import pallas_interpret as _interpret
 from ..sparse_attention.sparsity_config import SparsityConfig, layout_to_dense_mask
 
 NEG_INF = -1e30
-
-
-def _interpret() -> bool:
-    return jax.default_backend() != "tpu"
 
 
 # ------------------------------------------------------------------ layout tables
@@ -128,6 +125,7 @@ def _bs_fwd(q3, k3, v3, fwd_idx, fwd_cnt, scale, causal, block, n_heads):
             jax.ShapeDtypeStruct((bh, t, d), q3.dtype),
             jax.ShapeDtypeStruct((bh, nq, 8, block), jnp.float32),
         ],
+        name="block_sparse_fwd",
         interpret=_interpret(),
     )(fwd_idx, fwd_cnt, q3, k3, v3)
     return o3, lse[:, :, 0, :].reshape(bh, t)
@@ -232,6 +230,7 @@ def _bs_bwd(q3, k3, v3, o3, lse, do3, tables, scale, causal, block, n_heads):
                           n_heads=n_heads),
         grid_spec=dq_spec,
         out_shape=jax.ShapeDtypeStruct((bh, t, d), q3.dtype),
+        name="block_sparse_bwd_dq",
         interpret=_interpret(),
     )(tables["fwd_idx"], tables["fwd_cnt"], q3, k3, v3, do3, lse_b, delta_b)
 
@@ -259,6 +258,7 @@ def _bs_bwd(q3, k3, v3, o3, lse, do3, tables, scale, causal, block, n_heads):
             jax.ShapeDtypeStruct((bh, t, d), k3.dtype),
             jax.ShapeDtypeStruct((bh, t, d), v3.dtype),
         ],
+        name="block_sparse_bwd_dkv",
         interpret=_interpret(),
     )(tables["bwd_idx"], tables["bwd_cnt"], q3, k3, v3, do3, lse_b, delta_b)
     return dq, dk, dv

@@ -20,11 +20,9 @@ import numpy as np
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from ...utils.device import pallas_interpret as _interpret
+
 NEG_INF = -1e30
-
-
-def _interpret() -> bool:
-    return jax.default_backend() != "tpu"
 
 
 def _decode_kernel(len_ref, q_ref, k_hbm, v_hbm, o_ref, *, block_k, scale):
@@ -139,6 +137,7 @@ def decode_attention(q: jnp.ndarray, k_cache: jnp.ndarray, v_cache: jnp.ndarray,
         functools.partial(_decode_kernel, block_k=bk, scale=scale),
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((b, hk, g, d), q.dtype),
+        name="decode_attention",
         interpret=_interpret(),
     )(lens, q4, k_cache, v_cache)
     return out.reshape(b, h, d)

@@ -34,18 +34,10 @@ import numpy as np
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 from jax.sharding import PartitionSpec as P
+from ...utils.device import pallas_interpret as _interpret
 from ...utils.jax_compat import shard_map
 
-# jax >= 0.5 renames TPUCompilerParams -> CompilerParams; support both so the
-# kernels load on either side of the rename
-_CompilerParams = getattr(pltpu, "CompilerParams", None) or \
-    getattr(pltpu, "TPUCompilerParams")
-
 NEG_INF = -1e30
-
-
-def _interpret() -> bool:
-    return jax.default_backend() != "tpu"
 
 
 def _block_sizes(t: int, block_q: int, block_k: int):
@@ -194,8 +186,9 @@ def _flash_fwd(q3, k3, v3, slopes3, scale, causal, block_q, block_k, t_valid):
             pltpu.VMEM((1, 8, bq), jnp.float32),      # l
             pltpu.VMEM((1, bq, d), jnp.float32),      # acc
         ],
-        compiler_params=_CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary", "arbitrary")),
+        name="flash_fwd",
         interpret=_interpret(),
     )(*args)
     return o3, lse[:, :, 0, :].reshape(bh, t)
@@ -341,8 +334,9 @@ def _flash_bwd(q3, k3, v3, o3, lse, do3, slopes3, scale, causal, block_q, block_
         out_specs=pl.BlockSpec((1, bq, d), lambda i, j, kb: (i, j, 0)),
         out_shape=jax.ShapeDtypeStruct((bh, t, d), q3.dtype),
         scratch_shapes=[pltpu.VMEM((1, bq, d), jnp.float32)],
-        compiler_params=_CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary", "arbitrary")),
+        name="flash_bwd_dq",
         interpret=_interpret(),
     )(*dq_args)
 
@@ -375,8 +369,9 @@ def _flash_bwd(q3, k3, v3, o3, lse, do3, slopes3, scale, causal, block_q, block_
         ],
         scratch_shapes=[pltpu.VMEM((1, bk, d), jnp.float32),
                         pltpu.VMEM((1, bk, d), jnp.float32)],
-        compiler_params=_CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary", "arbitrary")),
+        name="flash_bwd_dkv",
         interpret=_interpret(),
     )(*dkv_args)
     return dq, dk, dv

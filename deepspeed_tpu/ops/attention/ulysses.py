@@ -20,7 +20,7 @@ import jax.numpy as jnp
 import numpy as np
 from jax.sharding import PartitionSpec as P
 
-from ...parallel.mesh import AXIS_SEQ, get_global_mesh
+from ...parallel.mesh import AXIS_SEQ, BATCH_AXES, get_global_mesh
 from ...utils.jax_compat import shard_map
 
 
@@ -72,12 +72,20 @@ def ulysses_attention(q: jnp.ndarray, k: jnp.ndarray, v: jnp.ndarray,
         o = jnp.einsum("bhts,bshd->bthd", p, vh)
         return heads_to_seq(o).astype(q_l.dtype)
 
+    # manual over the batch axes too: the body is per-example, and leaving
+    # them to GSPMD around the all_to_all makes a partially-manual region
+    # (XLA's partitioner aborts on it: "Invalid binary instruction opcode copy")
+    batch_axes = tuple(ax for ax in BATCH_AXES if mesh.size(ax) > 1)
+    bsz = int(np.prod([mesh.size(ax) for ax in batch_axes])) if batch_axes else 1
+    if b % bsz:
+        batch_axes = ()
+    spec = P(batch_axes or None, axis_name, None, None)
     mapped = shard_map(
         ulysses_fn,
         mesh=mesh.mesh,
-        axis_names={axis_name},
-        in_specs=(P(None, axis_name, None, None),) * 3,
-        out_specs=P(None, axis_name, None, None),
+        axis_names=set(batch_axes) | {axis_name},
+        in_specs=(spec,) * 3,
+        out_specs=spec,
         check_vma=False,
     )
     return mapped(q, k, v)

@@ -1,12 +1,13 @@
 """Compiled-kernel parity checks — ONE source of shapes and tolerances.
 
-Shared by the real-TPU test lane (``tests/unit/ops/test_kernels_tpu.py``) and the
-bench's pre-run gate (``bench.py kernel_gate``), so the two cannot drift: a Mosaic
-regression that fails the test suite fails the bench identically. Each check
+Shared by the real-TPU test lane (``tests/unit/ops/test_kernels_tpu.py``), the
+bench's pre-run gate (``bench.py kernel_gate``) and ``chip_smoke.py``, so they
+cannot drift: a Mosaic regression that fails one fails all three identically. Each check
 compiles the Pallas kernel (no interpret mode) and compares against its XLA
 reference; thresholds are per-check, matched to the check's dtype.
 """
 
+from functools import partial
 from typing import Dict, Iterable, Optional, Tuple
 
 import numpy as np
@@ -106,6 +107,52 @@ def check_moe_decode_ffn() -> float:
     return _err(o1, moe_decode_ffn_xla(x, idx, w1, b1, w2, b2, act))
 
 
+def _check_paged(hk: int) -> float:
+    """Serving geometry: d_head 128, 16-token pages (``ServingConfig``'s
+    default), ragged lengths incl. one mid-page and one exactly on a page
+    edge; ``hk`` < 32 is the GQA case (several query heads per KV head)."""
+    import jax
+    import jax.numpy as jnp
+    from .paged_attention import paged_attention_fused, paged_attention_xla
+    rng = np.random.RandomState(4)
+    b, h, d, page, max_pages = 4, 32, 128, 16, 40
+    n_pages = b * max_pages + 1                        # + the null page 0
+    q = jnp.asarray(rng.standard_normal((b, h, d)), jnp.bfloat16)
+    kp = jnp.asarray(rng.standard_normal((n_pages, hk, page, d)), jnp.bfloat16)
+    vp = jnp.asarray(rng.standard_normal((n_pages, hk, page, d)), jnp.bfloat16)
+    table = jnp.asarray(rng.permutation(np.arange(1, n_pages)).reshape(
+        b, max_pages), jnp.int32)
+    lens = jnp.asarray([1, 37, 512, 640], jnp.int32)
+    o1 = jax.jit(paged_attention_fused)(q, kp, vp, table, lens)
+    return _err(o1, paged_attention_xla(q, kp, vp, table, lens,
+                                        cap=page * max_pages))
+
+
+def _check_qmm(bits: int, m: int) -> float:
+    """Fused dequant GEMM at a 7B projection's shape (k = n = 4096, group
+    128): ``m`` = 8 is the decode regime (one row block), 512 the m-blocked
+    prefill regime. Error is relative to the output's scale (|y| ~ sqrt(k))."""
+    import jax
+    import jax.numpy as jnp
+    from .quantizer.fused_matmul import (_block_config, quantized_matmul,
+                                         quantized_matmul_xla)
+    from .quantizer.quant import pack_int4, quantize_grouped
+    rng = np.random.RandomState(5 + bits)
+    k = n = 4096
+    x = jnp.asarray(rng.standard_normal((m, k)), jnp.bfloat16)
+    w = jnp.asarray(rng.standard_normal((k, n)), jnp.float32)
+    q, s = quantize_grouped(w, group_size=128, bits=bits)
+    if bits == 4:
+        q = pack_int4(q, s.shape[-2])
+    if _block_config(m, k, n, bits, 128, interpret=False) is None:
+        raise RuntimeError(f"quantized_matmul would route m={m} bits={bits} "
+                           "to its XLA fallback — nothing to check")
+    y = jax.jit(lambda *a: quantized_matmul(*a, bits=bits,
+                                            out_dtype=jnp.float32))(x, q, s)
+    ref = quantized_matmul_xla(x, q, s, bits=bits, out_dtype=jnp.float32)
+    return _err(y, ref) / float(np.sqrt(k))
+
+
 # name → (check fn, max-abs-err tolerance for the check's dtype/shape)
 KERNEL_CHECKS: Dict[str, Tuple] = {
     "flash_fwd": (check_flash_fwd, 0.02),       # fp32
@@ -114,6 +161,15 @@ KERNEL_CHECKS: Dict[str, Tuple] = {
     "decode": (check_decode, 0.03),             # bf16
     "block_sparse": (check_block_sparse, 0.03),  # bf16
     "moe_decode_ffn": (check_moe_decode_ffn, 0.03),  # bf16
+    "paged_mha": (partial(_check_paged, hk=32), 0.03),   # bf16
+    "paged_gqa": (partial(_check_paged, hk=8), 0.03),    # bf16
+    # bf16 activations x dequantized weights, f32 accumulate; relative to
+    # the output scale sqrt(k): the kernel rounds w to bf16 before the dot,
+    # the reference keeps it f32
+    "qmm_int8_decode": (partial(_check_qmm, bits=8, m=8), 0.02),
+    "qmm_int8_prefill": (partial(_check_qmm, bits=8, m=512), 0.02),
+    "qmm_int4_decode": (partial(_check_qmm, bits=4, m=8), 0.02),
+    "qmm_int4_prefill": (partial(_check_qmm, bits=4, m=512), 0.02),
 }
 
 

@@ -23,9 +23,7 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-
-def _interpret() -> bool:
-    return jax.default_backend() != "tpu"
+from ...utils.device import pallas_interpret as _interpret
 
 
 def _kernel(idx_ref, x_ref, w1_ref, b1_ref, w2_ref, b2_ref, o_ref, *, act):
@@ -127,6 +125,7 @@ def moe_decode_ffn(x, idx, w1, b1, w2, b2, act) -> jnp.ndarray:
         functools.partial(_kernel, act=act),
         out_shape=jax.ShapeDtypeStruct((n, 1, d), jnp.float32),
         grid_spec=grid_spec,
+        name="moe_decode_ffn",
         interpret=_interpret(),
     )(idx.astype(jnp.int32), x[:, None, :], w1, b1[:, None, :], w2,
       b2[:, None, :])

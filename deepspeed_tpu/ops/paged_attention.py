@@ -37,13 +37,10 @@ import numpy as np
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from ..utils.device import pallas_interpret as _interpret
 from .attention.decode import NEG_INF, decode_attention_xla
 
 FORCE_FUSED_ENV = "DS_TPU_PAGED_FORCE_FUSED"
-
-
-def _interpret() -> bool:
-    return jax.default_backend() != "tpu"
 
 
 def fused_paged_active() -> bool:
@@ -216,6 +213,7 @@ def paged_attention_fused(q, k_pages, v_pages, page_table, cache_len,
                           scale=scale),
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((b, hk, g, d), q.dtype),
+        name="paged_decode",
         interpret=_interpret(),
     )(lens, table, q4, k_pages, v_pages)
     return out.reshape(b, h, d)

@@ -39,16 +39,13 @@ import jax.numpy as jnp
 import numpy as np
 from jax.experimental import pallas as pl
 
+from ...utils.device import pallas_interpret as _interpret
 from .quant import (dequantize_node, is_quant_node, node_bits,
                     node_logical_shape, node_qs)
 
 # below this row count the matmul is a GEMV/skinny GEMM: keep one m block and
 # spend VMEM on wide n blocks (weight streaming dominates)
 SKINNY_M = 256
-
-
-def _interpret() -> bool:
-    return jax.default_backend() != "tpu"
 
 
 def force_fused() -> bool:
@@ -163,6 +160,7 @@ def quantized_matmul(x, q, scales, *, bits: int = 8, out_dtype=None,
         ],
         out_specs=pl.BlockSpec((bm, bn), lambda i, j, kb: (i, j)),
         out_shape=jax.ShapeDtypeStruct((m + pad, n), jnp.float32),
+        name=f"qmm_int{bits}",
         interpret=interp,
     )(x, q, scales)
     return out[:m].astype(out_dtype)

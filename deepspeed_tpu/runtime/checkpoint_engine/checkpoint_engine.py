@@ -397,24 +397,8 @@ class OrbaxCheckpointEngine(CheckpointEngine):
                 lambda l: jax.ShapeDtypeStruct(l.shape, l.dtype)
                 if hasattr(l, "shape") else l, template)
         abspath = os.path.abspath(path)
-        try:
-            restore = self._ocp.args.PyTreeRestore(item={key: abstract},
-                                                   partial_restore=True)
-        except TypeError:
-            # orbax < 0.9 has no partial_restore: restore the full tree with
-            # the non-requested entries landed on one local device
-            # (transiently costs their host RAM) and select the subtree
-            meta = self._ckptr.metadata(abspath)
-            meta_tree = dict(getattr(meta, "item_metadata", meta))
-            host = jax.sharding.SingleDeviceSharding(jax.local_devices()[0])
-            is_meta_leaf = lambda x: hasattr(x, "shape") and hasattr(x, "dtype")
-            full = {
-                k: (abstract if k == key else jax.tree_util.tree_map(
-                    lambda m: jax.ShapeDtypeStruct(m.shape, m.dtype,
-                                                   sharding=host),
-                    v, is_leaf=is_meta_leaf))
-                for k, v in meta_tree.items()}
-            restore = self._ocp.args.PyTreeRestore(item=full)
+        restore = self._ocp.args.PyTreeRestore(item={key: abstract},
+                                               partial_restore=True)
         with ocp.PyTreeCheckpointer() as ckptr:
             restored = ckptr.restore(abspath, args=restore)
         return restored[key]

@@ -44,6 +44,7 @@ from ..observability.trace import CAT_TRAIN, get_tracer
 from ..parallel.overlap import resolve_overlap_config, set_overlap_config
 from ..utils.comms_logging import (collective_spans, record_collective,
                                    spans_overlap_ratio, spans_total_bytes)
+from ..utils.device import PEAKS
 from ..utils.fault_injection import fault_point
 from ..utils.logging import log_dist, logger
 from ..utils.nvtx import annotate
@@ -68,18 +69,6 @@ class TrainState(NamedTuple):
     scaler: LossScaleState
     global_step: jnp.ndarray
     skipped_steps: jnp.ndarray
-
-
-#: bf16 peak TFLOPS per chip by device kind (for modeled Train/mfu when
-#: ``flops_profiler.peak_tflops`` is unset; unknown kinds — CPU hosts — skip
-#: the mfu event rather than publish a made-up number)
-_PEAK_TFLOPS_BY_KIND = {
-    "tpu v4": 275.0,
-    "tpu v5 lite": 197.0,
-    "tpu v5e": 197.0,
-    "tpu v5p": 459.0,
-    "tpu v6e": 918.0,
-}
 
 
 def _batch_tokens(batch) -> int:
@@ -1279,16 +1268,18 @@ class DeepSpeedEngine:
         """Modeled model-flops utilization: profiled step flops / step wall
         time / aggregate peak. Needs both a flops-profiler result (run the
         profiler via ``flops_profiler.profile_step``) and a per-chip peak —
-        ``flops_profiler.peak_tflops`` in config, or the device-kind table
-        for known TPUs. The profiled flops cover the whole GLOBAL-batch step,
-        so the peak is per-chip × device count."""
+        ``flops_profiler.peak_tflops`` in config, or the published-peaks table
+        (``utils.device.PEAKS``); a device kind it does not hold — a CPU host —
+        skips the mfu event rather than publish a made-up number. The profiled
+        flops cover the whole GLOBAL-batch step, so the peak is per-chip ×
+        device count."""
         prof = getattr(self, "flops_profiler", None)
         if prof is None or prof.result is None or step_time_s <= 0:
             return None
         peak_tflops = self._config.flops_profiler.peak_tflops
         if peak_tflops is None:
-            peak_tflops = _PEAK_TFLOPS_BY_KIND.get(
-                jax.devices()[0].device_kind.lower())
+            peak_tflops = PEAKS.get(jax.devices()[0].device_kind,
+                                    {}).get("bf16_tflops")
         if not peak_tflops:
             return None
         achieved = prof.result.total_flops / step_time_s / 1e12

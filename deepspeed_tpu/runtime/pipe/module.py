@@ -540,10 +540,6 @@ class PipelineModule:
             in_specs=(P(AXIS_PIPE), P(), P()),
             out_specs=P(AXIS_PIPE),
             check_vma=False,
-            # NOTE on old jax (no jax.shard_map): the shim runs fully manual —
-            # data/expert stay replicated through the region (values identical,
-            # redundant compute); expert-sharded MoE pipe bodies need true
-            # partial-auto and are unsupported there (fail loudly at trace)
         )
         stacked = mapped(params["body"], xs, rng)  # (S, M, mb, ...)
         return stacked[S - 1]

@@ -5,8 +5,8 @@ Behavioural equivalent of reference ``deepspeed/runtime/state_dict_factory.py``
 ``module_inject/load_checkpoint.py``: big checkpoints arrive as MANY files (HF
 ``pytorch_model-0000x-of-0000N.bin`` / ``model-*.safetensors`` with an index json, or a
 Megatron ``mp_rank_XX`` list); loading must stream shard-by-shard, never materialising
-the full model on host — the reference's AutoTP/sharded-load requirement and the
-round-1 VERDICT's "7B BLOOM needs sharded/streamed loading" item.
+the full model on host — the reference's AutoTP/sharded-load requirement (a 7B
+BLOOM checkpoint does not fit a host-side copy next to its device copy).
 
 Design: a :class:`ShardedStateDict` is a lazy mapping name → tensor backed by the shard
 index; tensors load on first access, and ``release_shard`` drops whole files once their

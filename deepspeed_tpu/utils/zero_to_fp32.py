@@ -58,30 +58,16 @@ def get_fp32_state_dict_from_zero_checkpoint(checkpoint_dir: str,
         return jax.ShapeDtypeStruct(m.shape, m.dtype, sharding=sharding)
 
     is_meta_leaf = lambda x: hasattr(x, "shape") and hasattr(x, "dtype")
-    # orbax >= 0.9 wraps the metadata tree in a result object (.item_metadata);
-    # older releases return the tree directly
-    meta_tree = getattr(meta, "item_metadata", meta)
-    params_meta = dict(meta_tree)["params"]
+    params_meta = dict(meta.item_metadata)["params"]
     abstract_params = jax.tree_util.tree_map(abstract, params_meta,
                                              is_leaf=is_meta_leaf)
     restore_args = jax.tree_util.tree_map(
         lambda _: ocp.ArrayRestoreArgs(sharding=sharding), params_meta,
         is_leaf=is_meta_leaf)
-    try:
-        restore = ocp.args.PyTreeRestore(
-            item={"params": abstract_params},
-            restore_args={"params": restore_args},
-            partial_restore=True)
-    except TypeError:
-        # orbax < 0.9 has no partial_restore: restore the FULL tree (optimizer
-        # state included — transiently costs its host RAM) and select params
-        full_abstract = jax.tree_util.tree_map(abstract, dict(meta_tree),
-                                               is_leaf=is_meta_leaf)
-        full_restore_args = jax.tree_util.tree_map(
-            lambda _: ocp.ArrayRestoreArgs(sharding=sharding), dict(meta_tree),
-            is_leaf=is_meta_leaf)
-        restore = ocp.args.PyTreeRestore(item=full_abstract,
-                                         restore_args=full_restore_args)
+    restore = ocp.args.PyTreeRestore(
+        item={"params": abstract_params},
+        restore_args={"params": restore_args},
+        partial_restore=True)
     with ocp.PyTreeCheckpointer() as tree_ckptr:
         restored = tree_ckptr.restore(os.path.abspath(state_path), args=restore)
     return _flatten_params(restored["params"])

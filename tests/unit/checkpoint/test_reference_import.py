@@ -159,8 +159,8 @@ class TestReferenceImport:
                                    rtol=1e-5, atol=1e-5)
 
     def test_import_then_serve_end_to_end(self, tmp_path):
-        """The composed reference workflow (train Megatron → serve injected,
-        VERDICT r4 missing #2): import a reference-format checkpoint, hand the
+        """The composed reference workflow (train Megatron → serve
+        injected): import a reference-format checkpoint, hand the
         converted tree straight to InferenceEngine, and pin the greedy rollout
         against the ground-truth module's full forward."""
         import deepspeed_tpu as ds

@@ -1,4 +1,4 @@
-"""Universal-checkpoint EXPORT round trip (VERDICT r3 item 7).
+"""Universal-checkpoint EXPORT round trip.
 
 Export a trained engine as the reference universal format, then (a) read the
 per-param ``zero/<name>/fp32.pt`` files with plain torch — the contract
@@ -131,7 +131,7 @@ class TestUniversalExport:
                                           err_msg=name)
 
     def test_pipeline_engine_export(self, tmp_path, eight_devices):
-        """1F1B-trained pipeline export (VERDICT r4 item 4): the stacked body is
+        """1F1B-trained pipeline export: the stacked body is
         un-stacked into reference per-layer files + per-layer dotted universal
         names, and the export re-imports through DeepSpeedCheckpoint exactly."""
         from deepspeed_tpu.models.gpt2 import GPT2Config

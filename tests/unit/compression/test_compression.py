@@ -348,7 +348,7 @@ class TestMoQ:
 
 class TestLambEndToEnd:
     def test_lamb_trains_end_to_end(self):
-        """VERDICT round-1 weak item 9: LAMB had only a trust-ratio unit test."""
+        """LAMB end to end, beyond the trust-ratio unit test."""
         cfg = base_config(batch_size=16, lr=5e-2)
         cfg["optimizer"] = {"type": "Lamb", "params": {"lr": 5e-2,
                                                        "weight_decay": 0.01}}

@@ -115,7 +115,7 @@ class TestHelpers:
 
 # ------------------------------------------------------- restart→resize→resume
 class TestElasticResumeIntegration:
-    """VERDICT r2 weak item 5: the restart→resize→resume path as ONE flow — a run
+    """The restart→resize→resume path as ONE flow — a run
     under the elastic agent is preempted (checkpoint-and-exit), the 'scheduler'
     restarts it on a DIFFERENT mesh, and training resumes from the durable state
     with bitwise-identical parameters."""

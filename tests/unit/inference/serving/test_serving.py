@@ -210,6 +210,36 @@ def test_exhausted_prefill_retries_fail_request_not_loop(engine):
     fi.reset_faults()
 
 
+def test_non_transient_dispatch_failure_reaches_the_caller(engine):
+    """Only transient faults (I/O errors, a chunk deadline, the chaos kill)
+    are absorbed. Anything else out of a dispatch — on the chip a Mosaic
+    refusal or an HBM RESOURCE_EXHAUSTED, here an injected RuntimeError —
+    would fail every later dispatch the same way: the in-flight requests are
+    failed AND the error propagates out of ``step()``, so a server whose
+    every dispatch fails cannot exit 0."""
+    fi.reset_faults()
+    p0 = _prompts(8, sizes=(4,))[0]
+    sched = ContinuousBatchingScheduler(engine, ServingConfig(
+        slots=2, chunk_size=2, max_seq_len=CAP, transient_retries=1,
+        retry_base_delay=0.001))
+    h_decode = sched.submit(p0, max_new_tokens=6)
+    with fi.inject("serving.decode_chunk", fi.FaultSpec(
+            kind="io_error", exc_type=RuntimeError, max_faults=5)):
+        with pytest.raises(RuntimeError, match="injected fault"):
+            sched.step()
+    assert h_decode.state == RequestState.CANCELLED
+    assert h_decode.finish_reason == "error"
+    assert fi.faults_fired("serving.decode_chunk") == 1      # never retried
+    h_prefill = sched.submit(p0, max_new_tokens=3)
+    with fi.inject("serving.prefill", fi.FaultSpec(
+            kind="io_error", exc_type=RuntimeError, max_faults=5)):
+        with pytest.raises(RuntimeError, match="injected fault"):
+            sched.step()
+    assert h_prefill.state == RequestState.CANCELLED
+    assert h_prefill.finish_reason == "error"
+    fi.reset_faults()
+
+
 def test_exhausted_decode_retries_fail_inflight_keep_serving(engine):
     """An unrecoverable decode chunk fails every in-flight request (the donated
     pool buffers cannot be trusted), but the pool is rebuilt and the scheduler

@@ -1,4 +1,4 @@
-"""Diffusers/CLIP serving surface (VERDICT r4 missing #1).
+"""Diffusers/CLIP serving surface.
 
 - CLIP text encoder: numerical parity against the real torch ``CLIPTextModel``.
 - UNet/VAE: the diffusers package is not installed, so the state dicts are

@@ -1,5 +1,5 @@
 """Encoder injection parity: HF BERT / DistilBERT → EncoderLM, outputs matching
-the torch modules (VERDICT r3 missing #5; reference
+the torch modules (reference
 ``module_inject/containers/bert.py`` + ``distil_bert.py``)."""
 
 import numpy as np

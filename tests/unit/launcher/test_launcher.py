@@ -1,7 +1,7 @@
 """Launcher tests.
 
 Mirrors reference ``tests/unit/launcher/test_ds_arguments.py`` + ``test_run.py`` (hostfile
-and filter parsing) and adds the integration lane VERDICT round-1 asked for: a 2-process CPU
+and filter parsing) and adds an integration lane: a 2-process CPU
 launch on localhost running a real DP train step through the CLI.
 """
 
@@ -73,8 +73,10 @@ class TestResourceParsing:
 # ------------------------------------------------------------------- integration
 class TestLocalLaunch:
     def _run_cli(self, cli_args, env_extra=None, timeout=240):
-        env = {k: v for k, v in os.environ.items()
-               if k not in ("XLA_FLAGS", "JAX_PLATFORMS")}
+        # one CPU device per worker; several workers per node are only
+        # accepted for CPU runs (a chip host gives its chips to one process)
+        env = {k: v for k, v in os.environ.items() if k != "XLA_FLAGS"}
+        env["JAX_PLATFORMS"] = "cpu"
         env["DS_TPU_REPO"] = REPO
         env["PYTHONPATH"] = REPO + os.pathsep + env.get("PYTHONPATH", "")
         env.update(env_extra or {})
@@ -83,7 +85,7 @@ class TestLocalLaunch:
             capture_output=True, text=True, timeout=timeout, env=env, cwd=REPO)
 
     def test_two_process_dp_train(self, tmp_path):
-        """The VERDICT item: CLI launches 2 CPU processes that jointly train one
+        """CLI launches 2 CPU processes that jointly train one
         DP step (cross-process collectives), both ranks agreeing on the loss."""
         child = os.path.join(REPO, "tests", "unit", "launcher", "dp_train_child.py")
         proc = self._run_cli(
@@ -98,7 +100,7 @@ class TestLocalLaunch:
         assert len(losses) == 2 and all(l == l for l in losses)  # finite
 
     def test_two_process_partitioned_offload(self, tmp_path):
-        """Multi-process ZeRO-Offload (VERDICT r2 item 1): per-process partitioned
+        """Multi-process ZeRO-Offload: per-process partitioned
         masters over a real 2-process mesh, with identical resulting parameters on
         both ranks and a partition-file checkpoint round-trip."""
         child = os.path.join(REPO, "tests", "unit", "launcher",
@@ -116,7 +118,7 @@ class TestLocalLaunch:
         assert r0["resumed_loss_finite"] and r1["resumed_loss_finite"]
 
     def test_two_process_param_offload(self, tmp_path):
-        """Multi-process ZeRO-3 parameter offload (VERDICT r3 item 4): per-process
+        """Multi-process ZeRO-3 parameter offload: per-process
         partitioned masters in the segment-streaming tier over a real 2-process
         mesh; both ranks end with bitwise-identical pushed params, and the
         per-rank partition files round-trip."""
@@ -134,7 +136,7 @@ class TestLocalLaunch:
         assert r0["decreased"] and r1["decreased"]
         assert r0["resumed_loss_finite"] and r1["resumed_loss_finite"]
 
-        # OFFLINE consolidation (VERDICT r4 item 4): merge the per-rank
+        # OFFLINE consolidation: merge the per-rank
         # partition files into one universal checkpoint with no engine/mesh,
         # and verify exact equality against the pushed full params
         import numpy as np
@@ -158,7 +160,7 @@ class TestLocalLaunch:
         assert tuple(got_m.shape) == expected[some].shape
 
     def test_ssh_lane_with_fake_ssh(self, tmp_path):
-        """The ssh launcher beyond localhost Gloo (VERDICT r4 weak #6): a fake
+        """The ssh launcher beyond localhost Gloo: a fake
         ``ssh`` on PATH records each session and executes the remote command
         LOCALLY, driving the full lane — hostfile parse → per-node command
         construction (quoting survives the remote shell re-tokenization) →

@@ -14,7 +14,6 @@ cross-process kill→retry tail capture over a real subprocess, the
 import importlib.util
 import json
 import os
-import shutil
 import signal
 import time
 import urllib.error
@@ -740,19 +739,34 @@ class TestLoadgenFlight:
             "bench_traj", os.path.join(REPO, "bench.py"))
         bench = importlib.util.module_from_spec(spec)
         spec.loader.exec_module(bench)
-        for name in ("BENCH_OBS_r10.json", "BENCH_PAGED_r13.json",
-                     "BENCH_r01.json"):
-            shutil.copy(os.path.join(REPO, name), tmp_path / name)
+        # self-made fixtures in the two shapes the scraper reads: a headline
+        # with an in-file ``*_gates`` dict, and a gate-less headline
+        fixtures = {
+            "BENCH_OBS_r10.json": {
+                "metric": "obs_tracing_tpot_overhead_frac", "value": 0.01,
+                "unit": "fraction", "smoke": False,
+                "obs_gates": {"overhead_le_5pct": True, "spans_complete": True}},
+            "BENCH_PAGED_r13.json": {
+                "metric": "paged_vs_slots_tokens_ratio", "value": 1.7,
+                "unit": "x", "paged_gates": {"parity": True}},
+            "BENCH_PLAIN_r01.json": {
+                "metric": "train_tokens_per_sec_per_chip", "value": 123.0,
+                "unit": "tokens/s/chip"},
+        }
+        for name, doc in fixtures.items():
+            (tmp_path / name).write_text(json.dumps(doc))
         out = bench.bench_trajectory(root=str(tmp_path))
         assert out["artifacts"] == 3
         rows = {r["file"]: r for r in out["rows"]}
         assert rows["BENCH_OBS_r10.json"]["gates_ok"] is True
+        assert rows["BENCH_OBS_r10.json"]["gates_total"] == 2
         assert rows["BENCH_OBS_r10.json"]["metric"] \
             == "obs_tracing_tpot_overhead_frac"
-        assert rows["BENCH_r01.json"]["round"] == 1
-        assert rows["BENCH_r01.json"]["value"] is not None
+        assert rows["BENCH_PLAIN_r01.json"]["round"] == 1
+        assert rows["BENCH_PLAIN_r01.json"]["value"] == 123.0
+        assert rows["BENCH_PLAIN_r01.json"]["gates_ok"] is None
         # round ordering: r01 first
-        assert out["rows"][0]["file"] == "BENCH_r01.json"
+        assert out["rows"][0]["file"] == "BENCH_PLAIN_r01.json"
         traj = json.load(open(tmp_path / "BENCH_TRAJECTORY.json"))
         assert traj["artifacts"] == 3
         assert traj["all_gates_ok"] is True

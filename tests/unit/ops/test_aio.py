@@ -66,7 +66,7 @@ def test_read_error_raises(tmp_path):
 
 # ------------------------------------------------------------------ O_DIRECT path
 class TestODirect:
-    """O_DIRECT aio (VERDICT r2 item 8): aligned-buffer helpers, correctness through
+    """O_DIRECT aio: aligned-buffer helpers, correctness through
     the direct path (with per-filesystem buffered fallback), and a sequential-
     throughput microbench documenting direct vs buffered."""
 

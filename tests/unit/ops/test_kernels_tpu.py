@@ -1,5 +1,5 @@
 """Real-TPU compiled-kernel correctness (skipped on CPU, where kernels run in
-interpreter mode and a Mosaic regression would go unseen — VERDICT r2 weak item 6).
+interpreter mode and a Mosaic regression would go unseen).
 
 The check bodies and tolerances live in ``deepspeed_tpu.ops.kernel_checks`` — the
 SAME source bench.py's pre-run kernel gate executes every round, so the test lane

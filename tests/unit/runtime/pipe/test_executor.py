@@ -1,5 +1,5 @@
 """Eager schedule-executor tests: heterogeneous stages, gradient correctness, and the
-1F1B activation-stash bound (VERDICT round-1 item 6).
+1F1B activation-stash bound.
 
 Mirrors the territory of reference ``tests/unit/runtime/pipe/test_pipe.py`` for models that
 are NOT one repeated block — the SPMD loop requires a homogeneous body; this path does not.

@@ -1,6 +1,6 @@
 """Pipe×expert: MoE blocks as 1F1B pipeline body layers.
 
-VERDICT r3 item 5: expert all-to-all inside the stage_fn (the ``expert`` axis stays
+Expert all-to-all inside the stage_fn (the ``expert`` axis stays
 under GSPMD while the shard_map is manual over ``pipe``), per-layer load-balancing
 aux losses aggregated across layers/stages/microbatches, and the full
 pipe×expert×data engine composition. Reference: ``deepspeed/utils/groups.py:109``,

@@ -202,7 +202,7 @@ def test_1f1b_matches_gpipe_loss_and_grads(eight_devices):
 
 
 def test_1f1b_memory_flat_in_microbatches(eight_devices):
-    """VERDICT round-1 item 6: peak activation (temp) memory must stay flat as the
+    """Peak activation (temp) memory must stay flat as the
     microbatch count doubles — the property 1F1B exists for. The GPipe autodiff path
     grows O(M); the 1F1B path's stash is O(stages)."""
     cfg = GPT2Config(**TINY)

@@ -1,8 +1,8 @@
 """3D parallelism composition + cross-topology checkpoint resize tests.
 
-VERDICT round-1 items 7 (weak) and 10: no test composed pipe × tensor × fsdp, and the
-reference's ``test_configurable_parallel_{mp,pp}`` territory (save on one parallel
-topology, resume on another) was untouched. Orbax makes resize nearly free — these
+Composes pipe × tensor × fsdp, and covers the reference's
+``test_configurable_parallel_{mp,pp}`` territory (save on one parallel
+topology, resume on another). Orbax makes resize nearly free — these
 tests prove it.
 """
 

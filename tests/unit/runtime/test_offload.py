@@ -217,7 +217,7 @@ class TestInterleavedPush:
         """The r3 interleaved-push optimization is real, not incidental: leaf i's
         H2D push is dispatched immediately after leaf i's SIMD update and BEFORE
         leaf i+1's update (reference cpu_adam.cpp copy/compute tiling) — pinned
-        by event order, which is timing-independent (VERDICT r3 weak #7)."""
+        by event order, which is timing-independent."""
         import deepspeed_tpu.ops.adam.cpu_adam as cpu_adam_mod
         from deepspeed_tpu.runtime.zero.offload import OffloadOptimizerTier
 

@@ -303,8 +303,8 @@ class TestNVMeParams:
 
 
 class TestOffloadCombos:
-    """QAT and flops-profiler compose with the streamed step (VERDICT r3 missing
-    #7 — these were fail-loud NotImplementedError combos)."""
+    """QAT and flops-profiler compose with the streamed step (these were
+    fail-loud NotImplementedError combos once)."""
 
     def test_qat_under_offload(self):
         """Compression QAT rides the push transform: pushed weights quantize once

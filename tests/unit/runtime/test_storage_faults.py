@@ -1,4 +1,4 @@
-"""Storage-tier fault injection (VERDICT r4 weak #6).
+"""Storage-tier fault injection.
 
 The reference's swap tier inherits libaio's error surface; this framework's
 O_DIRECT thread-pool backend must be equally loud: a truncated swap file, a
