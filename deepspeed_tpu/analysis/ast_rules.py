@@ -127,6 +127,22 @@ def iter_emission_tags_from_tree(tree: ast.Module
                     yield tag, node.lineno
 
 
+_SPAN_FUNCS = {"span", "phase", "begin", "start_span", "record_span",
+               "instant"}
+
+
+def iter_span_names_from_tree(tree: ast.Module) -> Iterator[Tuple[str, int]]:
+    """Yield ``(name, lineno)`` for the literal first argument of every
+    tracer call that names a span (``.span`` / ``.phase`` / ``.begin`` /
+    ``.start_span`` / ``.record_span`` / ``.instant``)."""
+    for node in ast.walk(tree):
+        if isinstance(node, ast.Call) and isinstance(node.func, ast.Attribute) \
+                and node.func.attr in _SPAN_FUNCS and node.args \
+                and isinstance(node.args[0], ast.Constant) \
+                and isinstance(node.args[0].value, str):
+            yield node.args[0].value, node.lineno
+
+
 def iter_emission_tags(path: str) -> Iterator[Tuple[str, int]]:
     """File-path face of :func:`iter_emission_tags_from_tree` (the API
     ``observability.schema`` re-exports)."""
@@ -141,26 +157,40 @@ class EmissionTagRule(AstRule):
     ``resolve`` is injected (``observability.schema.resolve``) so this module
     stays import-cycle-free; ``modules`` restricts the rule to the declared
     emitter files (tag-shaped strings elsewhere — docs, tests — are not
-    emission sites)."""
+    emission sites). With ``resolve_span`` the same rule holds every span
+    name at a tracer call site in ``span_modules`` to the declared span
+    table (``observability.schema.SPANS``)."""
 
     name = "emission_tags"
 
     def __init__(self, resolve: Callable[[str], Optional[str]],
-                 modules: Sequence[str]):
+                 modules: Sequence[str],
+                 resolve_span: Optional[Callable[[str], Optional[str]]] = None,
+                 span_modules: Sequence[str] = ()):
         self.resolve = resolve
         self.modules = tuple(modules)
+        self.resolve_span = resolve_span
+        self.span_modules = tuple(span_modules)
 
     def check(self, tree, source_lines, relpath):
-        if relpath not in self.modules:
-            return []
         findings = []
-        for tag, lineno in iter_emission_tags_from_tree(tree):
-            if self.resolve(tag) is None:
-                findings.append(Finding(
-                    self.name, SEVERITY_ERROR, f"{relpath}:{lineno}",
-                    f"metric tag {tag!r} is not declared in "
-                    "observability.schema.TAGS — declare it (kind + help) "
-                    "before emitting it", {"tag": tag}))
+        if relpath in self.modules:
+            for tag, lineno in iter_emission_tags_from_tree(tree):
+                if self.resolve(tag) is None:
+                    findings.append(Finding(
+                        self.name, SEVERITY_ERROR, f"{relpath}:{lineno}",
+                        f"metric tag {tag!r} is not declared in "
+                        "observability.schema.TAGS — declare it (kind + help) "
+                        "before emitting it", {"tag": tag}))
+        if self.resolve_span is not None and relpath in self.span_modules:
+            for name, lineno in iter_span_names_from_tree(tree):
+                if self.resolve_span(name) is None:
+                    findings.append(Finding(
+                        self.name, SEVERITY_ERROR, f"{relpath}:{lineno}",
+                        f"span name {name!r} is not declared in "
+                        "observability.schema.SPANS — declare it (layer, "
+                        "attributes, what reads it) before opening it",
+                        {"tag": name}))
         return findings
 
 

@@ -69,7 +69,9 @@ HOT_PATH_SPECS: Tuple[HotPathSpec, ...] = (
                  "ChunkedDecodeExecutor._suffix_prefill_fn",
                  "ChunkedDecodeExecutor._suffix_prefill_fn_paged",
                  "ChunkedDecodeExecutor.prefill_into_slot",
-                 "ChunkedDecodeExecutor.run_chunk")),
+                 "ChunkedDecodeExecutor.run_chunk",
+                 "ChunkedDecodeExecutor._dispatch",
+                 "ChunkedDecodeExecutor._timed")),
     HotPathSpec("deepspeed_tpu/runtime/engine.py",
                 ("DeepSpeedEngine._build_train_step",
                  "DeepSpeedEngine._build_train_step_quantized",

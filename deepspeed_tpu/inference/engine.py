@@ -23,6 +23,7 @@ import numpy as np
 from jax.sharding import NamedSharding, PartitionSpec as P
 
 from ..observability.metrics import record_events as obs_record_events
+from ..observability.trace import get_tracer
 from ..models.causal_lm import (CausalLM, CausalLMConfig, causal_lm_param_specs,
                                 init_cache)
 from ..parallel.mesh import AXIS_DATA, AXIS_TENSOR, MeshSpec, set_global_mesh
@@ -52,6 +53,10 @@ class InferenceEngine:
     def __init__(self, model, config: Optional[DeepSpeedInferenceConfig] = None,
                  params: Optional[Any] = None, mesh_spec: Optional[MeshSpec] = None,
                  seed: int = 0):
+        with get_tracer().phase("setup.inference_engine_init"):
+            self._init(model, config, params, mesh_spec, seed)
+
+    def _init(self, model, config, params, mesh_spec, seed):
         self._config = config or DeepSpeedInferenceConfig()
         tp = self._config.resolved_tp()
         dp = max(1, int(self._config.data_parallel))
@@ -99,7 +104,8 @@ class InferenceEngine:
         if isinstance(model, CausalLMConfig):
             cfg = model
             if params is None:
-                params = self._init_params_segmented(cfg, seed)
+                with get_tracer().phase("setup.init_params"):
+                    params = self._init_params_segmented(cfg, seed)
             return cfg, params
         if isinstance(model, tuple) and len(model) == 2:
             cfg, params = model
@@ -167,7 +173,8 @@ class InferenceEngine:
                     "gate_proj", "up_proj")
 
     def _shard_params(self):
-        self.params = self._place_params(self.params)
+        with get_tracer().phase("setup.place_params"):
+            self.params = self._place_params(self.params)
 
     def _place_params(self, raw):
         """Cast to serve dtype, optionally grouped-quantize matmul weights

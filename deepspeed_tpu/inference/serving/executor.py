@@ -39,7 +39,6 @@ from ...models.causal_lm import init_cache
 from ...observability import profiler as obs_profiler
 from ...observability.trace import get_tracer
 from ...utils.fault_injection import fault_point
-from ...utils.nvtx import annotate
 from ..decode_fns import (build_decode_chunk, build_paged_decode_chunk,
                           build_paged_spec_verify, build_prefill,
                           build_prefix_prefill, build_spec_verify,
@@ -85,7 +84,8 @@ class ChunkResult:
     active: np.ndarray       # (S,) bool
     remaining: np.ndarray    # (S,) decode budget left
     steps: np.ndarray        # (S,) per-request tokens emitted so far
-    elapsed: float           # wall seconds for dispatch + fetch
+    elapsed: float           # wall seconds for dispatch + fetch: from the end
+    #   of ``serving.place_inputs`` to the end of ``serving.fetch``
 
 
 @dataclass
@@ -140,6 +140,7 @@ class ChunkedDecodeExecutor:
         self.chunk_deadline_s = chunk_deadline_s
         self.cold_chunk_grace_s = float(cold_chunk_grace_s)
         self._warm_chunk = False        # first successful chunk marks warm
+        self._called = set()            # compiled fns this executor has run
         self._stall_next = 0.0
         self._restore_kill = None       # chaos hook: fires between prefix
         #   restore and suffix prefill (see arm_restore_kill)
@@ -173,13 +174,16 @@ class ChunkedDecodeExecutor:
         self._stall_next = float(seconds)
 
     def _build_pool(self):
-        if self.kv_pool_kind == "paged":
-            return PagedKVPool(self.engine.model_config, self.slots, self.cap,
-                               page_size=self.kv_page_size,
-                               dtype=self.engine.dtype,
-                               total_pages=self.kv_total_pages)
-        return SlotKVPool(self.engine.model_config, self.slots, self.cap,
-                          dtype=self.engine.dtype)
+        with get_tracer().phase("setup.kv_pool", pool=self.kv_pool_kind) as ph:
+            if self.kv_pool_kind == "paged":
+                pool = PagedKVPool(self.engine.model_config, self.slots,
+                                   self.cap, page_size=self.kv_page_size,
+                                   dtype=self.engine.dtype,
+                                   total_pages=self.kv_total_pages)
+                ph.set(pages=pool.total_pages)
+                return pool
+            return SlotKVPool(self.engine.model_config, self.slots, self.cap,
+                              dtype=self.engine.dtype)
 
     @property
     def paged(self) -> bool:
@@ -409,9 +413,23 @@ class ChunkedDecodeExecutor:
         return box["out"]
 
     # -------------------------------------------------------------------- steps
+    def _dispatch(self, fn, args, program: str, bucket: int = 0, parent=None):
+        """Call a compiled function under ``serving.dispatch``. The first call
+        this executor makes of ``fn`` is python tracing + lowering + compile
+        (or a cache load) as the host sees it: kept as a ``setup.program``
+        phase. ``parent`` places the ring span when the caller is the
+        watchdog's worker thread."""
+        tracer = get_tracer()
+        with tracer.span("serving.dispatch", parent=parent, program=program):
+            if fn in self._called:
+                return fn(*args)
+            self._called.add(fn)
+            with tracer.phase("setup.program", program=program, bucket=bucket):
+                return fn(*args)
+
     def prefill_into_slot(self, slot: int, prompt: np.ndarray, seed: int = 0,
                           prefix_len: int = 0, prefix_slab=None,
-                          trace_ctx=None) -> Tuple[int, float]:
+                          request_id: int = -1) -> Tuple[int, float]:
         """Prefill ``prompt`` (1-D int tokens) and scatter its KV into ``slot``.
 
         With ``prefix_len > 0`` (prefix-cache hit): restore ``prefix_slab``
@@ -423,8 +441,12 @@ class ChunkedDecodeExecutor:
         chaos ``when=restore`` hook) sits exactly between restore and suffix
         prefill — the boundary whose donation discipline the soak guards.
 
-        Returns ``(first_token, prefill_seconds)`` — the first token is
-        host-synced before the clock stops, so the scheduler's TTFT is honest.
+        Returns ``(first_token, first_token_at)``: the ``time.monotonic``
+        stamp at which the first token was on the host, which is the end of
+        the ``serving.prefill`` / ``serving.suffix_prefill`` span — the
+        scheduler's TTFT and the span share it. The spans nest (in the ring)
+        under whatever span the calling thread has open, ``serving.admit``
+        when the scheduler calls.
         """
         # lint: host-sync-ok (host prompt tokens, never a device value)
         prompt = np.asarray(prompt, dtype=np.int32).reshape(-1)
@@ -439,39 +461,23 @@ class ChunkedDecodeExecutor:
             bucket = self.bucket_for(suffix.size)
             ids = np.zeros((1, bucket), np.int32)
             ids[0, :suffix.size] = suffix
-            t0 = time.perf_counter()
-            tr0 = time.monotonic()
             if self.paged:
                 fn = self._suffix_prefill_fn_paged(bucket)
                 if prefix_slab is not None:
                     # host-tier PROMOTE hit: the match lives as a spilled
                     # dense slab, not as live pages — restore it into the
                     # slot's (all-fresh, unshared) pages, paying one
-                    # host→device copy instead of a re-prefill
-                    with annotate("serving.restore_prefix"):
+                    # host→device copy instead of a re-prefill. (A zero-copy
+                    # hit has nothing to restore here: its pages were bound
+                    # at admission, under ``serving.page_table``.)
+                    with tracer.span("serving.restore_prefix", slot=slot,
+                                     prefix_len=int(prefix_len), promoted=1):
                         self.pool.promote_prefix(slot, prefix_slab, prefix_len)
-                    tracer.record_span("restore_prefix", trace_ctx, tr0,
-                                       time.monotonic(),
-                                       attrs={"slot": slot,
-                                              "prefix_len": int(prefix_len),
-                                              "promoted": True})
-                else:
-                    # zero-copy hit: the prefix pages were BOUND into the
-                    # slot's table at admission (refcount bump + one COW
-                    # page) — there is no slab restore to pay; the span
-                    # records the bind seam
-                    tracer.record_span("bind_prefix", trace_ctx, tr0,
-                                       time.monotonic(),
-                                       attrs={"slot": slot,
-                                              "prefix_len": int(prefix_len)})
             else:
                 fn = self._suffix_prefill_fn(bucket)
-                with annotate("serving.restore_prefix"):
+                with tracer.span("serving.restore_prefix", slot=slot,
+                                 prefix_len=int(prefix_len), promoted=0):
                     self.pool.restore_prefix(slot, prefix_slab)
-                tracer.record_span("restore_prefix", trace_ctx, tr0,
-                                   time.monotonic(),
-                                   attrs={"slot": slot,
-                                          "prefix_len": int(prefix_len)})
             # the restore->prefill (paged: bind->prefill) seam: the chaos
             # when=restore hook and fault point fire exactly here, after the
             # pool/table was touched and before the suffix forward
@@ -482,51 +488,44 @@ class ChunkedDecodeExecutor:
                 raise ReplicaKilledError("chaos: replica killed between "
                                          "prefix restore/bind and suffix "
                                          "prefill")
-            ts0 = time.monotonic()
-            with annotate("serving.suffix_prefill"):
-                if self.paged:
-                    tok0, caches = fn(self.engine.params, self.pool.caches,
-                                      jnp.asarray(self.pool.page_table[slot]),
-                                      jnp.asarray(ids),
-                                      jnp.asarray([prefix_len], jnp.int32),
-                                      jnp.asarray([suffix.size], jnp.int32),
-                                      jnp.asarray([seed], jnp.int32),
-                                      self._base_key)
-                else:
-                    tok0, caches = fn(self.engine.params, self.pool.caches,
-                                      np.int32(slot), jnp.asarray(ids),
-                                      jnp.asarray([prefix_len], jnp.int32),
-                                      jnp.asarray([suffix.size], jnp.int32),
-                                      jnp.asarray([seed], jnp.int32),
-                                      self._base_key)
+            with tracer.span("serving.suffix_prefill", request_id=request_id,
+                             bucket=bucket, tokens=int(suffix.size),
+                             prefix_len=int(prefix_len)) as sp:
+                with tracer.span("serving.place_inputs",
+                                 program="suffix_prefill"):
+                    where = jnp.asarray(self.pool.page_table[slot]) \
+                        if self.paged else np.int32(slot)
+                    args = (self.engine.params, self.pool.caches, where,
+                            jnp.asarray(ids),
+                            jnp.asarray([prefix_len], jnp.int32),
+                            jnp.asarray([suffix.size], jnp.int32),
+                            jnp.asarray([seed], jnp.int32), self._base_key)
+                tok0, caches = self._dispatch(fn, args, "suffix_prefill",
+                                              bucket)
                 self.pool.caches = caches
-                # lint: host-sync-ok (honest TTFT: first token synced on purpose)
-                tok0 = int(np.asarray(tok0)[0, 0])
-            tracer.record_span("suffix_prefill", trace_ctx, ts0,
-                               time.monotonic(),
-                               attrs={"bucket": bucket,
-                                      "suffix_tokens": int(suffix.size)})
+                with tracer.span("serving.fetch", program="suffix_prefill"):
+                    # lint: host-sync-ok (honest TTFT: first token synced on purpose)
+                    tok0 = int(np.asarray(tok0)[0, 0])
             obs_profiler.tick("prefill")
-            return tok0, time.perf_counter() - t0
+            return tok0, sp.t1
         bucket = self.bucket_for(t)
         ids = np.zeros((1, bucket), np.int32)
         ids[0, :t] = prompt
         fn = self._prefill_fn(bucket)
-        t0 = time.perf_counter()
-        tb0 = time.monotonic()
-        with annotate("serving.prefill"):
-            tok0, one_caches = fn(self.engine.params, jnp.asarray(ids),
-                                  jnp.asarray([t], jnp.int32),
-                                  jnp.asarray([seed], jnp.int32),
-                                  self._base_key)
-            # lint: host-sync-ok (honest TTFT: first token synced on purpose)
-            tok0 = int(np.asarray(tok0)[0, 0])
-        tracer.record_span("bucket_prefill", trace_ctx, tb0, time.monotonic(),
-                           attrs={"bucket": bucket, "prompt_tokens": int(t)})
-        dt = time.perf_counter() - t0
-        self.pool.scatter_prefill(slot, one_caches)
+        with tracer.span("serving.prefill", request_id=request_id,
+                         bucket=bucket, tokens=int(t), prefix_len=0) as sp:
+            with tracer.span("serving.place_inputs", program="prefill"):
+                args = (self.engine.params, jnp.asarray(ids),
+                        jnp.asarray([t], jnp.int32),
+                        jnp.asarray([seed], jnp.int32), self._base_key)
+            tok0, one_caches = self._dispatch(fn, args, "prefill", bucket)
+            with tracer.span("serving.fetch", program="prefill"):
+                # lint: host-sync-ok (honest TTFT: first token synced on purpose)
+                tok0 = int(np.asarray(tok0)[0, 0])
+        with tracer.span("serving.scatter_prefill"):
+            self.pool.scatter_prefill(slot, one_caches)
         obs_profiler.tick("prefill")
-        return tok0, dt
+        return tok0, sp.t1
 
     def run_chunk(self, toks: np.ndarray, lens: np.ndarray, active: np.ndarray,
                   remaining: np.ndarray, eos_ids: np.ndarray, seeds: np.ndarray,
@@ -541,52 +540,64 @@ class ChunkedDecodeExecutor:
         """
         self.engine._activate()
         fn = self._chunk_fn()
+        tracer = get_tracer()
         # snapshot the cache binding on THIS thread: if the watchdog abandons a
         # wedged chunk and the caller rebuilds the pool, the late-finishing
         # thread must keep donating the OLD buffers, never the fresh pool's
         caches_in = self.pool.caches
-        state = (jnp.asarray(lens, jnp.int32), jnp.asarray(active, bool),
-                 jnp.asarray(remaining, jnp.int32),
-                 jnp.asarray(eos_ids, jnp.int32),
-                 jnp.asarray(seeds, jnp.int32), jnp.asarray(steps, jnp.int32),
-                 self._base_key)
-        if self.paged:
-            # the table is host state bound at admission; it never changes
-            # inside a chunk, so it rides as a (tiny) per-dispatch operand
-            args = (self.engine.params,
-                    jnp.asarray(toks, jnp.int32).reshape(-1, 1), caches_in,
-                    jnp.asarray(self.pool.page_table)) + state
-        else:
-            args = (self.engine.params,
-                    jnp.asarray(toks, jnp.int32).reshape(-1, 1),
-                    caches_in) + state
-        t0 = time.perf_counter()
-
-        def timed():
-            # the region a deadline must cover: injected stalls, compile +
-            # dispatch (hung compile), and host fetch (hung collective)
-            fault_point("serving.chunk_compute")
-            if self._stall_next > 0:
-                stall, self._stall_next = self._stall_next, 0.0
-                time.sleep(stall)
-            with annotate("serving.decode_chunk"):
-                buf, toks_d, caches, lens_d, active_d, remaining_d, steps_d = \
-                    fn(*args)
-                # lint: host-sync-ok (chunk-boundary harvest: the scheduler
-                # retires/admits between chunks; this fetch IS the boundary)
-                host = (np.asarray(buf), np.asarray(toks_d),
-                        np.asarray(lens_d), np.asarray(active_d),
-                        np.asarray(remaining_d), np.asarray(steps_d))
-            return host, caches
-
-        host, caches = self._dispatch_watched(timed)
+        with tracer.span("serving.place_inputs", program="decode_chunk") as placed:
+            state = (jnp.asarray(lens, jnp.int32), jnp.asarray(active, bool),
+                     jnp.asarray(remaining, jnp.int32),
+                     jnp.asarray(eos_ids, jnp.int32),
+                     jnp.asarray(seeds, jnp.int32),
+                     jnp.asarray(steps, jnp.int32), self._base_key)
+            if self.paged:
+                # the table is host state bound at admission; it never changes
+                # inside a chunk, so it rides as a (tiny) per-dispatch operand
+                args = (self.engine.params,
+                        jnp.asarray(toks, jnp.int32).reshape(-1, 1), caches_in,
+                        jnp.asarray(self.pool.page_table)) + state
+            else:
+                args = (self.engine.params,
+                        jnp.asarray(toks, jnp.int32).reshape(-1, 1),
+                        caches_in) + state
+        host, caches, t1 = self._dispatch_watched(
+            self._timed(fn, args, "decode_chunk", "serving.chunk_compute", 2))
         self._warm_chunk = True
         obs_profiler.tick("decode_chunk")
         self.pool.caches = caches
         buf, toks_d, lens_d, active_d, remaining_d, steps_d = host
         return ChunkResult(buf=buf, toks=toks_d, lens=lens_d, active=active_d,
                            remaining=remaining_d, steps=steps_d,
-                           elapsed=time.perf_counter() - t0)
+                           elapsed=t1 - placed.t1)
+
+    def _timed(self, fn, args, program: str, fault: str, caches_at: int):
+        """The region a chunk's deadline must cover, as a callable for
+        :meth:`_dispatch_watched`: injected stalls, compile + dispatch (hung
+        compile), and host fetch (hung collective). ``fn`` returns a tuple
+        with the pool's caches at ``caches_at``; the callable returns ``(the
+        other outputs as host arrays, caches, stamp of the fetch's end)``. It
+        may run on the watchdog's worker thread, so its spans are handed the
+        caller's open span."""
+        tracer = get_tracer()
+        parent = tracer.current()
+
+        def timed():
+            fault_point(fault)
+            if self._stall_next > 0:
+                stall, self._stall_next = self._stall_next, 0.0
+                time.sleep(stall)
+            out = self._dispatch(fn, args, program, self.chunk_size, parent)
+            with tracer.span("serving.fetch", parent=parent,
+                             program=program) as fetched:
+                # lint: host-sync-ok (chunk-boundary harvest: the scheduler
+                # retires/admits between chunks and a verify round's accept
+                # rule needs the window logits; this fetch IS the boundary)
+                host = tuple(np.asarray(x) for i, x in enumerate(out)
+                             if i != caches_at)
+            return host, out[caches_at], fetched.t1
+
+        return timed
 
     def run_spec_round(self, toks: np.ndarray, lens: np.ndarray,
                        active: np.ndarray, remaining: np.ndarray,
@@ -614,36 +625,26 @@ class ChunkedDecodeExecutor:
         proposals = np.asarray(proposals, np.int32).reshape(S, -1)
         k = int(proposals.shape[1])
         fn = self._spec_verify_fn(k)
+        tracer = get_tracer()
         caches_in = self.pool.caches
         ids = np.concatenate(
             [np.asarray(toks, np.int32).reshape(-1, 1), proposals], axis=1)
         spec_lens = np.asarray(spec_lens, np.int32)
         valid = spec_lens + 1
-        if self.paged:
-            args = (self.engine.params, jnp.asarray(ids), caches_in,
-                    jnp.asarray(self.pool.page_table),
-                    jnp.asarray(lens, jnp.int32), jnp.asarray(valid, jnp.int32),
-                    jnp.asarray(active, bool))
-        else:
-            args = (self.engine.params, jnp.asarray(ids), caches_in,
-                    jnp.asarray(lens, jnp.int32))
-        t0 = time.perf_counter()
-
-        def timed():
-            # the mid-verify chaos/injection seam: after the proposer built
-            # the window, before/through the verify dispatch + logits fetch
-            fault_point("serving.spec_verify")
-            if self._stall_next > 0:
-                stall, self._stall_next = self._stall_next, 0.0
-                time.sleep(stall)
-            with annotate("serving.spec_verify"):
-                logits, caches = fn(*args)
-                # lint: host-sync-ok (round-boundary harvest: accept/reject
-                # needs the window logits on the host; this fetch IS the
-                # boundary, the spec analogue of the chunk harvest)
-                return np.asarray(logits), caches
-
-        logits, caches = self._dispatch_watched(timed)
+        with tracer.span("serving.place_inputs", program="spec_verify") as placed:
+            if self.paged:
+                args = (self.engine.params, jnp.asarray(ids), caches_in,
+                        jnp.asarray(self.pool.page_table),
+                        jnp.asarray(lens, jnp.int32),
+                        jnp.asarray(valid, jnp.int32),
+                        jnp.asarray(active, bool))
+            else:
+                args = (self.engine.params, jnp.asarray(ids), caches_in,
+                        jnp.asarray(lens, jnp.int32))
+        # the mid-verify chaos/injection seam: after the proposer built the
+        # window, before/through the verify dispatch + logits fetch
+        (logits,), caches, t1 = self._dispatch_watched(
+            self._timed(fn, args, "spec_verify", "serving.spec_verify", 1))
         self._warm_chunk = True
         obs_profiler.tick("spec_verify")
         self.pool.caches = caches
@@ -684,5 +685,5 @@ class ChunkedDecodeExecutor:
         return SpecResult(buf=buf, toks=toks_out.reshape(-1, 1),
                           lens=lens_out, active=active_out,
                           remaining=remaining_out, steps=steps_out,
-                          elapsed=time.perf_counter() - t0,
+                          elapsed=t1 - placed.t1,
                           proposed=proposed, accepted=accepted)

@@ -41,6 +41,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from ...models.causal_lm import init_cache
+from ...observability.trace import get_tracer
 
 
 # Slot-pool movers at MODULE level (shape-keyed jit singletons), same reason
@@ -358,30 +359,34 @@ class PagedKVPool:
             raise ValueError(
                 f"matched={matched} needs {shared_full + cow} prefix pages, "
                 f"entry holds {len(prefix_pages)}")
-        slot = self._free_slots.pop(0)
-        row = self.page_table[slot]
-        n = 0
-        for j in range(shared_full):                   # zero-copy shared bind
-            p = int(prefix_pages[j])
-            self._ref[p] += 1
-            row[n] = p
-            n += 1
-        if cow:                                        # boundary page: COW
-            src = int(prefix_pages[shared_full])
-            dst = self._free_pages.pop(0)
-            self.caches = self._cow_fn(self.caches, np.int32(src),
-                                       np.int32(dst))
-            self.cow_copies_total += 1
-            self._ref[dst] = 1
-            row[n] = dst
-            n += 1
-        for _ in range(need - n):                      # private fresh pages
-            p = self._free_pages.pop(0)
-            self._ref[p] = 1
-            row[n] = p
-            n += 1
-        self._slot_npages[slot] = need
-        self._slot_tokens[slot] = tokens
+        with get_tracer().span("serving.page_table",
+                               op="acquire" if prefix_pages is None else "bind",
+                               pages_fresh=fresh, pages_shared=shared_full,
+                               cow=cow):
+            slot = self._free_slots.pop(0)
+            row = self.page_table[slot]
+            n = 0
+            for j in range(shared_full):               # zero-copy shared bind
+                p = int(prefix_pages[j])
+                self._ref[p] += 1
+                row[n] = p
+                n += 1
+            if cow:                                    # boundary page: COW
+                src = int(prefix_pages[shared_full])
+                dst = self._free_pages.pop(0)
+                self.caches = self._cow_fn(self.caches, np.int32(src),
+                                           np.int32(dst))
+                self.cow_copies_total += 1
+                self._ref[dst] = 1
+                row[n] = dst
+                n += 1
+            for _ in range(need - n):                  # private fresh pages
+                p = self._free_pages.pop(0)
+                self._ref[p] = 1
+                row[n] = p
+                n += 1
+            self._slot_npages[slot] = need
+            self._slot_tokens[slot] = tokens
         return slot
 
     def release(self, slot: int) -> None:
@@ -391,13 +396,16 @@ class PagedKVPool:
         module docstring's leak-safety argument."""
         if slot in self._free_slots:
             raise ValueError(f"slot {slot} is already free")
-        row = self.page_table[slot]
-        for j in range(int(self._slot_npages[slot])):
-            self._decref(int(row[j]))
-        row[:] = NULL_PAGE
-        self._slot_npages[slot] = 0
-        self._slot_tokens[slot] = 0
-        self._free_slots.append(slot)
+        with get_tracer().span("serving.page_table", op="release",
+                               pages_fresh=int(self._slot_npages[slot]),
+                               pages_shared=0, cow=0):
+            row = self.page_table[slot]
+            for j in range(int(self._slot_npages[slot])):
+                self._decref(int(row[j]))
+            row[:] = NULL_PAGE
+            self._slot_npages[slot] = 0
+            self._slot_tokens[slot] = 0
+            self._free_slots.append(slot)
 
     def _decref(self, page: int) -> None:
         if page == NULL_PAGE:

@@ -233,6 +233,11 @@ class ContinuousBatchingScheduler:
         self._eos = np.full(S, -1, np.int32)
         self._seeds = np.zeros(S, np.int32)
         self._steps = np.zeros(S, np.int32)
+        self._step_idx = 0
+        # stalled deliveries: prefills done so far, and per slot how many had
+        # been done at its stream's last delivery (or its own first token)
+        self._prefills_done = 0
+        self._prefills_seen = np.zeros(S, np.int64)
 
     # ---------------------------------------------------------------- frontend
     def submit(self, prompt, max_new_tokens: Optional[int] = None,
@@ -290,17 +295,24 @@ class ContinuousBatchingScheduler:
         """One scheduler iteration: sweep deadlines/cancellations, admit pending
         prompts into free slots, run one decode chunk, retire finished slots.
         Returns True when any request made progress."""
-        now = time.monotonic()
-        self._sweep_queue(now)
-        self._sweep_running(now)
-        admitted = self._admit()
-        decoded = self._decode_chunk()
-        pool = self.executor.pool
-        self.telemetry.on_step(
-            len(self.queue), pool.occupancy,
-            prefix_stats=(None if self.prefix_cache is None
-                          else self.prefix_cache.stats()),
-            paged_stats=(pool.stats() if pool.paged else None))
+        tracer = self._tracer
+        self._step_idx += 1
+        with tracer.span("serving.step", step=self._step_idx,
+                         queue_depth=len(self.queue),
+                         active_slots=int(np.count_nonzero(self._active))):
+            with tracer.span("serving.sweep"):
+                now = time.monotonic()
+                self._sweep_queue(now)
+                self._sweep_running(now)
+            admitted = self._admit()
+            decoded = self._decode_chunk()
+            with tracer.span("serving.telemetry"):
+                pool = self.executor.pool
+                self.telemetry.on_step(
+                    len(self.queue), pool.occupancy,
+                    prefix_stats=(None if self.prefix_cache is None
+                                  else self.prefix_cache.stats()),
+                    paged_stats=(pool.stats() if pool.paged else None))
         return admitted or decoded
 
     def run(self, max_steps: int = 100000) -> dict:
@@ -320,6 +332,10 @@ class ContinuousBatchingScheduler:
         slab copy (padded to the prompt bucket) as before."""
         if self.prefix_cache is None:
             return
+        with self._tracer.span("serving.prefix_insert"):
+            self._insert_prefix_pages(handle, slot)
+
+    def _insert_prefix_pages(self, handle: RequestHandle, slot: int) -> None:
         P = int(handle.prompt.size)
         if P < self.prefix_cache.config.min_insert_tokens:
             self.prefix_cache.insert_skipped += 1
@@ -423,10 +439,7 @@ class ContinuousBatchingScheduler:
             self._finalize(h, RequestState.EVICTED, reason, now)
             out.append(h)
             self._slot_req[slot] = None
-        self._active[:] = False
-        self._remaining[:] = 0
-        self._steps[:] = 0
-        self._eos[:] = -1
+        self._clear_slot_state()
         # rebuild rather than per-slot zero-fill: on the death path the old
         # buffers may be inside a failed/wedged dispatch and cannot be trusted
         self._rebuild_pool()
@@ -464,7 +477,6 @@ class ContinuousBatchingScheduler:
     # -------------------------------------------------------------- admission
     def _admit(self) -> bool:
         admitted = False
-        cfg = self.config
         tracer = self._tracer
         while self.queue:
             pool = self.executor.pool    # re-read: a failed hit-prefill below
@@ -516,203 +528,241 @@ class ContinuousBatchingScheduler:
                 if not pool.can_admit(need_tokens, matched=matched_hint):
                     break
             handle = self.queue.popleft()
-            admit_t = time.monotonic()
-            tracer.record_span("queue_wait", handle._span,
-                               handle.arrival, admit_t)
-            matched, entry = 0, None
-            if self.prefix_cache is not None:
-                t_lk = time.monotonic()
-                matched, entry = self.prefix_cache.lookup(handle.prompt)
-                tracer.record_span("prefix_lookup", handle._span, t_lk,
-                                   time.monotonic(),
-                                   attrs={"hit": entry is not None,
-                                          "matched_tokens": int(matched)})
-            if pool.paged and entry is not None and entry.pages is not None:
-                # zero-copy hit: bind the shared prefix pages into the fresh
-                # slot's table (refcount bump + one COW boundary page) — the
-                # paged replacement for the slab restore scatter
-                slot = pool.acquire(need_tokens, prefix_pages=entry.pages,
-                                    matched=matched)
-            else:
-                # miss, slot-pool hit, or host-rung PROMOTE hit (entry with a
-                # spilled numpy slab): all-fresh pages; the promote restores
-                # the slab into them inside prefill_into_slot
-                slot = pool.acquire(need_tokens)
-            if slot is None:   # can_admit is conservative, so only a racing
-                self.queue.appendleft(handle)          # caller could land here
+            waited = time.monotonic() - handle.arrival
+            with tracer.span("serving.admit", parent=handle._span,
+                             request_id=handle.id,
+                             queue_wait_ms=round(waited * 1e3, 3),
+                             prompt_tokens=int(handle.prompt.size)) as span:
+                tracer.record_span("queue_wait", handle._span, handle.arrival,
+                                   span.t0)
+                outcome = self._admit_one(handle, need_tokens, span)
+            if outcome is None:     # no slot after all: the head waits
                 break
-
-            def attempt(h=handle, s=slot, m=matched, e=entry):
-                fault_point("serving.prefill")
-                if e is not None:
-                    return self.executor.prefill_into_slot(
-                        s, h.prompt, h.seed, prefix_len=m,
-                        prefix_slab=e.slab, trace_ctx=h._span)
-                return self.executor.prefill_into_slot(s, h.prompt, h.seed,
-                                                       trace_ctx=h._span)
-
-            prefill_span = tracer.start_span(
-                "prefill", parent=handle._span,
-                attrs={"slot": slot, "prefix_len": int(matched)
-                       if entry is not None else 0})
-            try:
-                tok0, _ = retry_with_backoff(attempt,
-                                             retries=cfg.transient_retries,
-                                             base_delay=cfg.retry_base_delay)
-            except Exception as e:
-                tracer.end_span(prefill_span,
-                                attrs={"outcome": "error",
-                                       "error": type(e).__name__})
-                # retry budget exhausted: fail THIS request and (for a
-                # transient fault) keep serving — the slot must not leak and
-                # the loop must not die with the queue still holding live
-                # requests
-                logger.error(f"[serving] prefill failed for request "
-                             f"{handle.id}: {type(e).__name__}: {e}")
-                now = time.monotonic()
-                self._finalize(handle, RequestState.CANCELLED, "error", now)
-                if entry is not None:
-                    # cache-hit path: the suffix-prefill dispatch DONATES the
-                    # pool caches (unlike the miss path's batch-1 prefill), so
-                    # a failure here may have consumed them — zero-filling the
-                    # slot or restoring into the old binding would crash the
-                    # loop on deleted buffers. Same recovery as a failed
-                    # decode chunk: fail the in-flight requests, rebuild the
-                    # pool, keep serving (a router retries them elsewhere).
-                    logger.error("[serving] failed prefill was a prefix-cache "
-                                 "hit (donated pool dispatch); failing "
-                                 f"{sum(h is not None for h in self._slot_req)}"
-                                 " in-flight request(s) and rebuilding the "
-                                 "KV pool")
-                    for s2, h2 in enumerate(self._slot_req):
-                        if h2 is not None:
-                            self._finalize(h2, RequestState.CANCELLED,
-                                           "error", now)
-                            self._slot_req[s2] = None
-                    self._active[:] = False
-                    self._remaining[:] = 0
-                    self._steps[:] = 0
-                    self._eos[:] = -1
-                    self._rebuild_pool()
-                else:
-                    self._release(slot)
-                if not isinstance(e, TRANSIENT_FAULTS):
-                    raise
-                continue
-            now = time.monotonic()
-            tracer.end_span(prefill_span, t1=now,
-                            attrs={"outcome": "ok",
-                                   "prefix_hit": entry is not None})
-            handle.state = RequestState.RUNNING
-            handle.slot = slot
-            handle.tokens.append(int(tok0))
-            handle.first_token_at = now
-            handle.ttft = now - handle.arrival
-            handle.prefix_hit_tokens = matched if entry is not None else 0
-            self.telemetry.on_prefix(entry is not None,
-                                     handle.prefix_hit_tokens,
-                                     enabled=self.prefix_cache is not None)
-            if (self.prefix_cache is not None
-                    and self.prefix_cache.config.insert_on == "prefill"):
-                self._insert_prefix(handle, slot)
-            eos = -1 if handle.eos_token_id is None else int(handle.eos_token_id)
-            if tok0 == eos or handle.max_new_tokens == 1:
-                self._retire_prefix(handle, slot)
-                self._finalize(handle, RequestState.FINISHED,
-                               "eos" if tok0 == eos else "length", now)
-                self._release(slot)
-            else:
-                self._slot_req[slot] = handle
-                self._toks[slot] = tok0
-                self._lens[slot] = handle.prompt.size
-                self._active[slot] = True
-                self._remaining[slot] = handle.max_new_tokens - 1
-                self._eos[slot] = eos
-                self._seeds[slot] = handle.seed
-                self._steps[slot] = 1       # token 0 came from prefill
-            admitted = True
+            admitted = admitted or outcome
         return admitted
+
+    def _admit_one(self, handle: RequestHandle, need_tokens: int,
+                   span) -> Optional[bool]:
+        """The work of one admission, inside its ``serving.admit`` span:
+        prefix lookup, slot and pages, the prefill, the slot made live.
+        True = admitted (or finished at its first token), False = its prefill
+        failed and the request with it, None = no slot (requeued)."""
+        cfg = self.config
+        tracer = self._tracer
+        pool = self.executor.pool
+        matched, entry = 0, None
+        if self.prefix_cache is not None:
+            with tracer.span("serving.prefix_lookup") as lk:
+                matched, entry = self.prefix_cache.lookup(handle.prompt)
+                lk.set(hit=int(entry is not None), matched_tokens=int(matched))
+        if pool.paged and entry is not None and entry.pages is not None:
+            # zero-copy hit: bind the shared prefix pages into the fresh
+            # slot's table (refcount bump + one COW boundary page) — the
+            # paged replacement for the slab restore scatter
+            slot = pool.acquire(need_tokens, prefix_pages=entry.pages,
+                                matched=matched)
+        else:
+            # miss, slot-pool hit, or host-rung PROMOTE hit (entry with a
+            # spilled numpy slab): all-fresh pages; the promote restores
+            # the slab into them inside prefill_into_slot
+            slot = pool.acquire(need_tokens)
+        if slot is None:       # can_admit is conservative, so only a racing
+            self.queue.appendleft(handle)              # caller could land here
+            span.set(outcome="requeued")
+            return None
+        prefix_len = int(matched) if entry is not None else 0
+        span.set(slot=slot, prefix_len=prefix_len)
+
+        def attempt():
+            fault_point("serving.prefill")
+            if entry is not None:
+                return self.executor.prefill_into_slot(
+                    slot, handle.prompt, handle.seed, prefix_len=matched,
+                    prefix_slab=entry.slab, request_id=handle.id)
+            return self.executor.prefill_into_slot(
+                slot, handle.prompt, handle.seed, request_id=handle.id)
+
+        try:
+            tok0, first_token_at = retry_with_backoff(
+                attempt, retries=cfg.transient_retries,
+                base_delay=cfg.retry_base_delay)
+        except Exception as e:
+            span.set(outcome="error")
+            # retry budget exhausted: fail THIS request and (for a
+            # transient fault) keep serving — the slot must not leak and
+            # the loop must not die with the queue still holding live
+            # requests
+            logger.error(f"[serving] prefill failed for request "
+                         f"{handle.id}: {type(e).__name__}: {e}")
+            now = time.monotonic()
+            self._finalize(handle, RequestState.CANCELLED, "error", now)
+            if entry is not None:
+                # cache-hit path: the suffix-prefill dispatch DONATES the
+                # pool caches (unlike the miss path's batch-1 prefill), so
+                # a failure here may have consumed them — zero-filling the
+                # slot or restoring into the old binding would crash the
+                # loop on deleted buffers. Same recovery as a failed
+                # decode chunk: fail the in-flight requests, rebuild the
+                # pool, keep serving (a router retries them elsewhere).
+                logger.error("[serving] failed prefill was a prefix-cache "
+                             "hit (donated pool dispatch); failing "
+                             f"{sum(h is not None for h in self._slot_req)}"
+                             " in-flight request(s) and rebuilding the "
+                             "KV pool")
+                self._fail_in_flight(now)
+                self._rebuild_pool()
+            else:
+                self._release(slot)
+            if not isinstance(e, TRANSIENT_FAULTS):
+                raise
+            return False
+        span.set(outcome="ok")
+        handle.state = RequestState.RUNNING
+        handle.slot = slot
+        handle.tokens.append(int(tok0))
+        # the stamp at which the token was on the host: the end of the
+        # executor's prefill span
+        handle.first_token_at = first_token_at
+        handle.ttft = first_token_at - handle.arrival
+        handle.prefix_hit_tokens = prefix_len
+        self._prefills_done += 1
+        self._prefills_seen[slot] = self._prefills_done
+        self.telemetry.on_prefix(entry is not None,
+                                 handle.prefix_hit_tokens,
+                                 enabled=self.prefix_cache is not None)
+        if (self.prefix_cache is not None
+                and self.prefix_cache.config.insert_on == "prefill"):
+            self._insert_prefix(handle, slot)
+        eos = -1 if handle.eos_token_id is None else int(handle.eos_token_id)
+        if tok0 == eos or handle.max_new_tokens == 1:
+            self._retire_prefix(handle, slot)
+            self._finalize(handle, RequestState.FINISHED,
+                           "eos" if tok0 == eos else "length",
+                           time.monotonic())
+            self._release(slot)
+        else:
+            self._slot_req[slot] = handle
+            self._toks[slot] = tok0
+            self._lens[slot] = handle.prompt.size
+            self._active[slot] = True
+            self._remaining[slot] = handle.max_new_tokens - 1
+            self._eos[slot] = eos
+            self._seeds[slot] = handle.seed
+            self._steps[slot] = 1       # token 0 came from prefill
+        return True
+
+    def _fail_in_flight(self, now: float) -> None:
+        """A dispatch that had the pool's buffers donated to it died: every
+        in-flight request fails with it and the slot tables are cleared."""
+        for slot, h in enumerate(self._slot_req):
+            if h is not None:
+                self._finalize(h, RequestState.CANCELLED, "error", now)
+                self._slot_req[slot] = None
+        self._clear_slot_state()
+
+    def _clear_slot_state(self) -> None:
+        self._active[:] = False
+        self._remaining[:] = 0
+        self._steps[:] = 0
+        self._eos[:] = -1
 
     # ----------------------------------------------------------------- decode
     def _decode_chunk(self) -> bool:
         if not self._active.any():
             return False
         cfg = self.config
+        tracer = self._tracer
         steps_before = self._steps.copy()
+        streams = [(slot, h) for slot, h in enumerate(self._slot_req)
+                   if h is not None and self._active[slot]]
+        chunk_idx = self.telemetry._chunk_idx + 1
+        spec = self.proposer is not None
+        width = self._spec_cfg.k + 1 if spec else cfg.chunk_size
+        slot_steps = width * len(streams)
 
         def attempt():
             fault_point("serving.decode_chunk")
-            if self.proposer is not None:
+            if spec:
                 return self._spec_round()
             return self.executor.run_chunk(
                 self._toks, self._lens, self._active, self._remaining,
                 self._eos, self._seeds, self._steps)
 
-        try:
-            res = retry_with_backoff(attempt, retries=cfg.transient_retries,
-                                     base_delay=cfg.retry_base_delay)
-        except Exception as e:
-            # retry budget exhausted mid-decode: the pool buffers may have been
-            # donated into a dispatch that died, so they cannot be trusted —
-            # fail every in-flight request, rebuild the pool, keep serving the
-            # queue (same contract as admission: the loop outlives transient
-            # faults; a deterministic failure propagates once the in-flight
-            # requests are failed — rebuilding a pool that cannot be
-            # allocated would only raise again)
-            logger.error(f"[serving] decode chunk failed: "
-                         f"{type(e).__name__}: {e}; failing "
-                         f"{sum(h is not None for h in self._slot_req)} "
-                         "in-flight request(s) and rebuilding the KV pool")
-            now = time.monotonic()
-            for slot, h in enumerate(self._slot_req):
-                if h is not None:
-                    self._finalize(h, RequestState.CANCELLED, "error", now)
-                    self._slot_req[slot] = None
-            self._active[:] = False
-            self._remaining[:] = 0
-            self._steps[:] = 0
-            self._eos[:] = -1
-            if not isinstance(e, TRANSIENT_FAULTS):
-                raise
-            self._rebuild_pool()
-            return False
-        now = time.monotonic()
-        counts = res.steps - steps_before
-        total = 0
-        chunk_t0 = now - res.elapsed
-        chunk_idx = self.telemetry._chunk_idx + 1
-        for slot, h in enumerate(self._slot_req):
-            if h is None or counts[slot] <= 0:
-                continue
-            h.tokens.extend(res.buf[slot, :counts[slot]].tolist())
-            total += int(counts[slot])
-            # one span per participating request: the chunk is a batch-level
-            # dispatch, but "where did THIS request's time go" needs it on the
-            # request's own trace. Guarded: tracing-off must not build attrs
-            # dicts on the hottest loop.
-            if h._span is not None:
-                self._tracer.record_span(
-                    "decode_chunk", h._span, chunk_t0, now,
-                    attrs={"chunk": chunk_idx, "slot": slot,
-                           "tokens": int(counts[slot])})
-        was_active = self._active.copy()
-        self._toks = res.toks[:, 0].copy()
-        self._lens = res.lens.copy()
-        self._remaining = res.remaining.copy()
-        self._steps = res.steps.copy()
-        self._active = res.active.copy()
-        for slot in np.nonzero(was_active & ~res.active)[0]:
-            h = self._slot_req[int(slot)]
-            if h is None:
-                continue
-            reason = ("eos" if h.eos_token_id is not None
-                      and h.tokens and h.tokens[-1] == h.eos_token_id
-                      else "length")
-            self._retire_prefix(h, int(slot))
-            self._finalize(h, RequestState.FINISHED, reason, now)
-            self._release(int(slot))
-        self.telemetry.on_chunk(total, res.elapsed)
-        if self.proposer is not None:
+        # the span stays on this thread whether or not the chunk watchdog
+        # moves the dispatch to its worker; the executor's place_inputs /
+        # dispatch / fetch nest under it
+        at_start = dict(chunk=chunk_idx, active_slots=len(streams),
+                        request_ids=" ".join(str(h.id) for _, h in streams),
+                        slot_steps_run=slot_steps)
+        with (tracer.span("serving.spec_verify", **at_start) if spec
+              else tracer.span("serving.decode_chunk", **at_start)) as span:
+            try:
+                res = retry_with_backoff(attempt,
+                                         retries=cfg.transient_retries,
+                                         base_delay=cfg.retry_base_delay)
+            except Exception as e:
+                # retry budget exhausted mid-decode: the pool buffers may have
+                # been donated into a dispatch that died, so they cannot be
+                # trusted — fail every in-flight request, rebuild the pool,
+                # keep serving the queue (same contract as admission: the loop
+                # outlives transient faults; a deterministic failure propagates
+                # once the in-flight requests are failed — rebuilding a pool
+                # that cannot be allocated would only raise again)
+                logger.error(f"[serving] decode chunk failed: "
+                             f"{type(e).__name__}: {e}; failing "
+                             f"{sum(h is not None for h in self._slot_req)} "
+                             "in-flight request(s) and rebuilding the KV pool")
+                self._fail_in_flight(time.monotonic())
+                if not isinstance(e, TRANSIENT_FAULTS):
+                    raise
+                self._rebuild_pool()
+                return False
+            counts = res.steps - steps_before
+            delivered = [(slot, h) for slot, h in streams if counts[slot] > 0]
+            total = int(sum(counts[slot] for slot, _ in delivered))
+            # a delivery is stalled when a prefill of ANOTHER request ran
+            # since this stream's previous delivery (or its first token)
+            stalled = sum(1 for slot, _ in delivered
+                          if self._prefills_seen[slot] < self._prefills_done)
+            span.set(tokens_kept=total, deliveries=len(delivered),
+                     stalled_deliveries=stalled)
+        now = span.t1
+        with tracer.span("serving.harvest") as harvest:
+            chunk_t0 = now - res.elapsed
+            for slot, h in delivered:
+                h.tokens.extend(res.buf[slot, :counts[slot]].tolist())
+                self._prefills_seen[slot] = self._prefills_done
+                # one ring span per participating request: the chunk is a
+                # batch-level dispatch, but "where did THIS request's time
+                # go" needs it on the request's own trace. Guarded:
+                # tracing-off must not build attrs dicts on the hottest loop.
+                if h._span is not None:
+                    tracer.record_span(
+                        "decode_chunk", h._span, chunk_t0, now,
+                        attrs={"request_id": h.id, "chunk": chunk_idx,
+                               "slot": slot, "tokens": int(counts[slot])})
+            was_active = self._active.copy()
+            self._toks = res.toks[:, 0].copy()
+            self._lens = res.lens.copy()
+            self._remaining = res.remaining.copy()
+            self._steps = res.steps.copy()
+            self._active = res.active.copy()
+            finished = 0
+            for slot in np.nonzero(was_active & ~res.active)[0]:
+                h = self._slot_req[int(slot)]
+                if h is None:
+                    continue
+                reason = ("eos" if h.eos_token_id is not None
+                          and h.tokens and h.tokens[-1] == h.eos_token_id
+                          else "length")
+                self._retire_prefix(h, int(slot))
+                self._finalize(h, RequestState.FINISHED, reason, now)
+                self._release(int(slot))
+                finished += 1
+            harvest.set(finished=finished)
+        self.telemetry.on_chunk(total, res.elapsed, slot_steps=slot_steps,
+                                deliveries=len(delivered), stalled=stalled)
+        if spec:
             self.telemetry.on_spec(res.proposed, res.accepted, total,
                                    res.draft_s, res.elapsed)
         return True
@@ -726,7 +776,7 @@ class ContinuousBatchingScheduler:
         S = self.config.slots
         proposals = np.zeros((S, k), np.int32)
         spec_lens = np.zeros(S, np.int32)
-        t0 = time.perf_counter()
+        t0 = time.monotonic()
         for slot, h in enumerate(self._slot_req):
             if h is None or not self._active[slot]:
                 continue
@@ -745,7 +795,7 @@ class ContinuousBatchingScheduler:
             if L > 0:
                 proposals[slot, :L] = draft[:L]
                 spec_lens[slot] = L
-        draft_s = time.perf_counter() - t0
+        draft_s = time.monotonic() - t0
         res = self.executor.run_spec_round(
             self._toks, self._lens, self._active, self._remaining,
             self._eos, self._seeds, self._steps, proposals, spec_lens)

@@ -18,6 +18,11 @@ parentheses):
 - ``serving/prefix_spilled_bytes``, ``serving/prefix_spills_total``,
   ``serving/prefix_promotions_total`` — per scheduler step, tiered prefix
   cache (host-RAM rung) enabled only;
+- ``serving/decode_slot_steps_total``, ``serving/decode_tokens_kept_total``,
+  ``serving/deliveries_total``, ``serving/deliveries_stalled_total`` — per
+  scheduler step: decode steps run against tokens a stream kept, and the
+  deliveries a prefill of another request held up (the same counts ride the
+  ``serving.decode_chunk`` span);
 - ``serving/spec_*`` — per verify round, speculation enabled only; the
   emission site lives in ``inference.speculative.emit_spec_events`` (the
   subsystem that owns the semantics), this class only keeps the counters.
@@ -90,6 +95,10 @@ class ServingTelemetry:
         self.expired = 0
         self.evicted = 0
         self.decode_seconds = 0.0
+        # decode waste and stalled deliveries (tokens kept = tokens_total)
+        self.decode_slot_steps = 0
+        self.deliveries = 0
+        self.deliveries_stalled = 0
         # prefix-cache counters (only advanced when the cache is enabled)
         self.prefix_enabled = False
         self.prefix_hits = 0
@@ -118,7 +127,14 @@ class ServingTelemetry:
         ev = [("serving/queue_depth", float(queue_depth), self._tick),
               ("serving/slot_occupancy", float(occupancy), self._tick),
               ("serving/completed_total", float(self.completed), self._tick),
-              ("serving/rejected_total", float(self.rejected), self._tick)]
+              ("serving/rejected_total", float(self.rejected), self._tick),
+              ("serving/decode_slot_steps_total",
+               float(self.decode_slot_steps), self._tick),
+              ("serving/decode_tokens_kept_total", float(self.tokens_total),
+               self._tick),
+              ("serving/deliveries_total", float(self.deliveries), self._tick),
+              ("serving/deliveries_stalled_total",
+               float(self.deliveries_stalled), self._tick)]
         if paged_stats is not None:
             # paged-pool gauges/counters (PagedKVPool.stats()): page-granular
             # occupancy, allocation-granularity waste, zero-copy sharing
@@ -167,10 +183,17 @@ class ServingTelemetry:
         else:
             self.prefix_misses += 1
 
-    def on_chunk(self, tokens: int, elapsed: float) -> None:
+    def on_chunk(self, tokens: int, elapsed: float, slot_steps: int = 0,
+                 deliveries: int = 0, stalled: int = 0) -> None:
+        """One decode chunk: ``tokens`` handed to streams of the
+        ``slot_steps`` (steps x active slots) it ran, in ``deliveries``
+        deliveries of which ``stalled`` waited on another request's prefill."""
         self._chunk_idx += 1
         self.tokens_total += int(tokens)
         self.decode_seconds += float(elapsed)
+        self.decode_slot_steps += int(slot_steps)
+        self.deliveries += int(deliveries)
+        self.deliveries_stalled += int(stalled)
         if elapsed > 0:
             self._write([("serving/tokens_per_sec", tokens / elapsed,
                           self._chunk_idx)])
@@ -260,6 +283,9 @@ class ServingTelemetry:
             "expired": self.expired,
             "evicted": self.evicted,
             "tokens_total": self.tokens_total,
+            "decode_slot_steps": self.decode_slot_steps,
+            "deliveries": self.deliveries,
+            "deliveries_stalled": self.deliveries_stalled,
             "tokens_per_sec": (self.tokens_total / self.decode_seconds
                                if self.decode_seconds > 0 else 0.0),
             "ttft_ms_p50": self.ttft_ms.percentile(50),

@@ -2,8 +2,9 @@
 
 The PR 10 tracer records *what* happened to a request (``request`` →
 ``attempt{replica}`` → ``replica_request`` → ``queue_wait`` /
-``prefix_lookup`` / ``restore_prefix`` / ``prefill`` / ``decode_chunk×N`` /
-``retire``); this module answers *where the time went*: every completed
+``serving.admit`` {``serving.prefix_lookup``, ``serving.page_table``,
+``serving.restore_prefix``, ``serving.prefill`` | ``serving.suffix_prefill``} /
+``decode_chunk×N`` / ``retire``); this module answers *where the time went*: every completed
 request's end-to-end latency is decomposed into a fixed set of named phases
 whose sum equals the e2e latency **by construction** (the phases partition the
 root span's wall window — the tested identity is sum(phases) == e2e within
@@ -12,10 +13,13 @@ root span's wall window — the tested identity is sum(phases) == e2e within
 - ``queue``    — admission-queue wait: the ``queue_wait`` spans plus any
   uncovered time before the first replica-side work begins (router-level
   queueing happens before an ``attempt`` span exists);
-- ``admission`` — admission-time work: prefix-cache trie lookups;
-- ``kv_restore`` — prefix-slab restore / page-bind time inside a cache-hit
-  prefill;
-- ``prefill``  — prefill dispatch minus the restore share;
+- ``admission`` — admission-time work: prefix-cache trie lookups and whatever
+  else of ``serving.admit`` no narrower span names (queue pop, operand
+  placement outside the prefill, prefix insert);
+- ``kv_restore`` — page-table acquire / bind (copy-on-write included) and
+  prefix-slab restore at an admission;
+- ``prefill``  — the prefill span: operand placement, dispatch, the first
+  token's fetch;
 - ``decode``   — decode-chunk compute (the slot-batch dispatches this request
   participated in);
 - ``gap``      — inter-chunk scheduling gap: time inside the serving window
@@ -66,9 +70,12 @@ _FAILED_LANE_STATES = ("abandoned", "evicted")
 Interval = Tuple[float, float]
 
 #: tracer span name → phase (spans with other names only move ``first_work``)
-_NAME_TO_PHASE = {"queue_wait": "queue", "prefix_lookup": "admission",
-                  "restore_prefix": "kv_restore", "prefill": "prefill",
-                  "suffix_prefill": "prefill", "bucket_prefill": "prefill",
+_NAME_TO_PHASE = {"queue_wait": "queue", "serving.admit": "admission",
+                  "serving.prefix_lookup": "admission",
+                  "serving.page_table": "kv_restore",
+                  "serving.restore_prefix": "kv_restore",
+                  "serving.prefill": "prefill",
+                  "serving.suffix_prefill": "prefill",
                   "decode_chunk": "decode"}
 
 
@@ -201,8 +208,11 @@ def attribute(spans: Sequence[Dict]) -> Optional[Dict]:
     # priority-ordered disjoint coverage: a restore second is a restore
     # second even though the prefill span covers it too. Empty phases are
     # skipped — this runs once per completed request on the serving host.
-    priority = ("retry_lost", "kv_restore", "admission", "queue",
-                "decode", "prefill")
+    # ``admission`` claims last: ``serving.admit`` covers the whole admission
+    # (lookup, page table, prefill, prefix insert), so what no narrower span
+    # names inside it is admission work.
+    priority = ("retry_lost", "kv_restore", "queue", "decode", "prefill",
+                "admission")
     covered: List[Interval] = []
     phases_ms = {p: 0.0 for p in PHASES}
     for phase in priority:

@@ -61,6 +61,16 @@ TAGS: Dict[str, Tuple[str, str]] = {
     "serving/spec_accepted_total": (COUNTER, "draft tokens accepted by the "
                                              "verify pass"),
     "serving/spec_draft_ms": (GAUGE, "proposer wall time of the last round"),
+    # ------------------------------- decode waste and stalled deliveries (PR 25)
+    "serving/decode_slot_steps_total": (COUNTER, "decode steps run times the "
+                                                 "slots active at dispatch"),
+    "serving/decode_tokens_kept_total": (COUNTER, "tokens of those steps "
+                                                  "handed to a stream"),
+    "serving/deliveries_total": (COUNTER, "deliveries of a chunk's tokens to "
+                                          "a stream"),
+    "serving/deliveries_stalled_total": (COUNTER, "deliveries held up by a "
+                                                  "prefill of another request "
+                                                  "since the stream's last one"),
     # ------------------------------------------------------------------ router
     "router/queue_depth": (GAUGE, "router admission queue depth per tick"),
     "router/retried_total": (COUNTER, "checkpointless retries (re-enqueues)"),
@@ -163,6 +173,143 @@ TAGS: Dict[str, Tuple[str, str]] = {
                                                  "modeled stream reduction"),
 }
 
+#: sinks of a span: the profiler's xplane and the ring (a scoped
+#: ``tracer.span``), the ring alone (request roots and retroactive spans,
+#: which no TraceMe can hold), or both plus ``tracer.phases`` (set-up).
+BOTH = "xplane+ring"
+RING = "ring"
+PHASE = "xplane+ring+phases"
+
+#: span name -> (sinks, layer, attributes, what reads it). THE span schema:
+#: every name a ``span`` / ``phase`` / ``begin`` / ``start_span`` /
+#: ``record_span`` / ``instant`` call in :data:`SPAN_MODULES` uses. ``reads``
+#: names the benchmark metric (``benchmarks/chipbench/layer_metrics/``) or
+#: the operator feature the span exists for.
+SPANS: Dict[str, Tuple[str, str, Tuple[str, ...], str]] = {
+    # ---------------------------------------------------------- serve scheduler
+    "serving.step": (BOTH, "serve scheduler",
+                     ("step", "queue_depth", "active_slots"),
+                     "frames the phases of one step() in the ring; "
+                     "sched_fetch_idle_ms_per_step's earlier lines"),
+    "serving.sweep": (BOTH, "serve scheduler", (),
+                      "breakdown idle gaps (deadline and cancellation sweeps)"),
+    "serving.admit": (BOTH, "serve scheduler",
+                      ("request_id", "queue_wait_ms", "prompt_tokens",
+                       "prefix_len", "slot", "outcome"),
+                      "sched_admit_host_ms; attribution phase admission"),
+    "serving.prefix_lookup": (BOTH, "serve scheduler",
+                              ("hit", "matched_tokens"),
+                              "sched_admit_host_ms lines; attribution phase "
+                              "admission"),
+    "serving.page_table": (BOTH, "serve scheduler",
+                           ("op", "pages_fresh", "pages_shared", "cow"),
+                           "sched_admit_host_ms lines; attribution phase "
+                           "kv_restore"),
+    "serving.restore_prefix": (BOTH, "serve scheduler",
+                               ("slot", "prefix_len", "promoted"),
+                               "attribution phase kv_restore (slab restore, "
+                               "host-tier promote)"),
+    "serving.prefill": (BOTH, "compiled steps",
+                        ("request_id", "bucket", "tokens", "prefix_len"),
+                        "sched_admit_host_ms by bucket; attribution phase "
+                        "prefill"),
+    "serving.suffix_prefill": (BOTH, "compiled steps",
+                               ("request_id", "bucket", "tokens",
+                                "prefix_len"),
+                               "sched_admit_host_ms by bucket; attribution "
+                               "phase prefill"),
+    "serving.scatter_prefill": (BOTH, "serve scheduler", (),
+                                "breakdown idle gaps (prefill KV into pages)"),
+    "serving.prefix_insert": (BOTH, "serve scheduler", (),
+                              "breakdown idle gaps (share pages with the "
+                              "prefix cache)"),
+    "serving.decode_chunk": (BOTH, "compiled steps",
+                             ("chunk", "active_slots", "request_ids",
+                              "slot_steps_run", "tokens_kept", "deliveries",
+                              "stalled_deliveries"),
+                             "decode_wasted_step_pct, delivery_stalled_pct, "
+                             "sched_fetch_idle_ms_per_step"),
+    "serving.spec_verify": (BOTH, "compiled steps",
+                            ("chunk", "active_slots", "request_ids",
+                             "slot_steps_run", "tokens_kept", "deliveries",
+                             "stalled_deliveries"),
+                            "as serving.decode_chunk, for a speculative "
+                            "verify round"),
+    "serving.place_inputs": (BOTH, "serve scheduler", ("program",),
+                             "sched_fetch_idle_ms_per_step and "
+                             "sched_admit_host_ms lines"),
+    "serving.dispatch": (BOTH, "serve scheduler", ("program",),
+                         "sched_fetch_idle_ms_per_step lines"),
+    "serving.fetch": (BOTH, "serve scheduler", ("program",),
+                      "sched_fetch_idle_ms_per_step; sched_admit_host_ms "
+                      "lines"),
+    "serving.harvest": (BOTH, "serve scheduler", ("finished",),
+                        "sched_fetch_idle_ms_per_step lines"),
+    "serving.telemetry": (BOTH, "serve scheduler", (),
+                          "sched_fetch_idle_ms_per_step lines"),
+    # request roots and retroactive spans of a request's own trace
+    "replica_request": (RING, "serve scheduler",
+                        ("request_id", "prompt_tokens", "max_new_tokens",
+                         "state", "reason", "tokens"),
+                        "flight recorder and attribution root of a request"),
+    "queue_wait": (RING, "serve scheduler", (),
+                   "attribution phase queue (the xplane has queue_wait_ms on "
+                   "serving.admit)"),
+    "decode_chunk": (RING, "serve scheduler",
+                     ("request_id", "chunk", "slot", "tokens"),
+                     "attribution phase decode: a serving.decode_chunk as "
+                     "one of its requests saw it"),
+    "retire": (RING, "serve scheduler", ("state", "reason"),
+               "flight recorder (why a request left its slot)"),
+    # ------------------------------------------------------------- train engine
+    "train_step": (BOTH, "train engine",
+                   ("step", "bytes_on_wire", "overlap_ratio", "offload"),
+                   "train_host_ms_per_step"),
+    "train.host_batch": (BOTH, "train engine", (),
+                         "train_host_ms_per_step lines"),
+    "train.dispatch": (BOTH, "train engine", (),
+                       "train_host_ms_per_step lines"),
+    "train.bookkeeping": (BOTH, "train engine", (),
+                          "train_host_ms_per_step lines"),
+    "checkpoint_commit": (BOTH, "train engine", ("tag", "step"),
+                          "operator: the commit stall of a checkpoint save "
+                          "(docs/OBSERVABILITY.md)"),
+    # ------------------------------------------------------------ device set-up
+    "setup.engine_init": (PHASE, "device set-up", (), "setup_engine_init_s"),
+    "setup.mesh": (PHASE, "device set-up", (), "setup_engine_init_s lines"),
+    "setup.init_params": (PHASE, "device set-up", (),
+                          "setup_engine_init_s lines"),
+    "setup.init_optimizer": (PHASE, "device set-up", (),
+                             "setup_engine_init_s lines"),
+    "setup.place_state": (PHASE, "device set-up", (),
+                          "setup_engine_init_s lines"),
+    "setup.build_train_step": (PHASE, "device set-up", (),
+                               "setup_engine_init_s"),
+    "setup.inference_engine_init": (PHASE, "device set-up", (),
+                                    "setup_engine_init_s"),
+    "setup.place_params": (PHASE, "device set-up", (),
+                           "setup_engine_init_s lines"),
+    "setup.kv_pool": (PHASE, "device set-up", ("pool", "pages"),
+                      "setup_engine_init_s"),
+    "setup.program": (PHASE, "device set-up", ("program", "bucket"),
+                      "setup_engine_init_s lines, beside setup_compile_s"),
+}
+
+#: modules whose span call sites the lint walks (repo-relative paths)
+SPAN_MODULES = (
+    "deepspeed_tpu/inference/serving/scheduler.py",
+    "deepspeed_tpu/inference/serving/executor.py",
+    "deepspeed_tpu/inference/serving/kv_pool.py",
+    "deepspeed_tpu/inference/engine.py",
+    "deepspeed_tpu/runtime/engine.py",
+)
+
+
+def resolve_span(name: str) -> Optional[str]:
+    """The declared span a call site's literal name is, or None."""
+    return name if name in SPANS else None
+
+
 _TEMPLATE_SEG = re.compile(r"\{[A-Za-z_][A-Za-z0-9_]*\}")
 
 
@@ -240,16 +387,19 @@ def emission_tag_rule():
     — the form ``bin/ds-tpu-lint`` runs it in, next to the bare-assert and
     hot-path-sync rules."""
     from ..analysis.ast_rules import EmissionTagRule
-    return EmissionTagRule(resolve, EMITTER_MODULES)
+    return EmissionTagRule(resolve, EMITTER_MODULES,
+                           resolve_span=resolve_span,
+                           span_modules=SPAN_MODULES)
 
 
 def lint_emission_sites(repo_root: str) -> List[str]:
-    """Every undeclared tag across :data:`EMITTER_MODULES`, as
-    ``"path:line: tag"`` strings (empty list = clean). Runs under the shared
-    AST rule runner (one framework for every source-level rule)."""
+    """Every undeclared tag across :data:`EMITTER_MODULES` and every
+    undeclared span name across :data:`SPAN_MODULES`, as ``"path:line: tag"``
+    strings (empty list = clean). Runs under the shared AST rule runner (one
+    framework for every source-level rule)."""
     from ..analysis.ast_rules import run_ast_rules
-    result = run_ast_rules(repo_root, [emission_tag_rule()],
-                           paths=EMITTER_MODULES)
+    paths = tuple(dict.fromkeys(EMITTER_MODULES + SPAN_MODULES))
+    result = run_ast_rules(repo_root, [emission_tag_rule()], paths=paths)
     # a syntax error in an emitter module surfaces as a runner finding with
     # no 'tag' detail — report it as a problem, don't crash on it
     return [f"{f.site}: {f.details.get('tag', f.message)}"

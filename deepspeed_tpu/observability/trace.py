@@ -1,28 +1,58 @@
-"""Hierarchical wall-clock span tracer with request-/step-scoped context.
+"""One span API, two sinks: the profiler's clock and the request-scoped ring.
 
-The host-side half of the observability spine: answers "where did this one
-request's 1.9 s go?" by recording every stage of the serving column
-(admit → queue wait → prefix-cache lookup → restore → prefill → decode chunks
-→ retire, with router retry attempts as linked spans carrying the retry
-replica id) and the training step (``train_step`` / ``grad_sync`` /
-``checkpoint_commit``) as spans that share one **trace id per request/step**.
+Every instrumented region of the program is ONE call, ``tracer.span(name,
+**attrs)``, under a name declared once in ``observability/schema.py: SPANS``.
+The call always opens a ``jax.profiler.TraceAnnotation(name, **attrs)`` (a
+TraceMe: an atomic load when no profiler is armed, its attributes formatted
+only when one is), so under ``jax.profiler`` the span lands in the xplane as an
+event with its attributes as ``stats``, on the one clock the device's ``XLA
+Modules`` and ``XLA Ops`` share. When the tracer is ``enabled`` the same call
+also records the span in the ring below, under the same name with the same
+attributes; request-scoped spans carry ``request_id`` (train steps ``step``) in
+both sinks, which is how a ring export and an xplane are joined.
 
-Design constraints, in order:
+Three kinds of span:
 
-1. **Disabled is near-zero cost.** The tracer is a process-global that starts
-   disabled; every instrumentation site costs one method call that returns
-   immediately (``begin``/``start_span`` return ``None``, ``span()`` yields a
-   shared null context). No allocation, no clock read.
-2. **Bounded.** Finished spans land in a drop-oldest ring (``max_spans``);
+- **scoped** (:meth:`Tracer.span`): a ``with`` block on one thread. Both
+  sinks. Without ``parent`` it nests (in the ring) under the innermost scoped
+  span open on the thread, else roots a fresh trace id. Attributes known only
+  at the end go in through :meth:`Span.set` (``TraceAnnotation.set_metadata``).
+  The span's ``t0``/``t1`` are ``time.monotonic`` stamps the caller may read,
+  so a latency the program reports and the span that covers it share stamps.
+- **unscoped** (:meth:`Tracer.begin` / :meth:`start_span` / :meth:`end_span`,
+  :meth:`record_span`, :meth:`instant`): a request's root lives from
+  ``submit`` to retirement across many steps and interleaves with other
+  requests' roots, and a queue wait is known only in retrospect; a TraceMe is
+  bound to one thread's stack and can hold neither. Ring only, ``None`` /
+  no-op while the tracer is disabled.
+- **phases** (:meth:`Tracer.phase`): the few set-up regions of a process's
+  life (engine construction, the first call of each compiled program). Set-up
+  runs before any profiler is armed and with the tracer off, so these are
+  kept unconditionally in :attr:`Tracer.phases` with ``time.monotonic`` start
+  and end (and go to both sinks like any scoped span).
+
+The ring answers "where did this one request's 1.9 s go?": the serving column
+(``replica_request`` -> ``queue_wait`` / ``serving.admit`` {``serving.prefix_lookup``,
+``serving.page_table``, ``serving.prefill`` ...} / ``decode_chunk`` x N /
+``retire``, router attempts as linked spans) and the training step share one
+**trace id per request/step**. Its constraints, in order:
+
+1. **Disabled is near-zero cost.** The tracer starts disabled; a scoped site
+   then pays the inactive TraceMe and two clock reads, an unscoped site one
+   method call that returns ``None``.
+2. **Enabled does not change the schedule.** No span fetches a device value
+   or waits for the device; a span covers what the host did.
+3. **Bounded.** Finished spans land in a drop-oldest ring (``max_spans``);
    drops are counted, never silent.
-3. **Cross-process joinable.** A ``SpanContext`` is two strings
+4. **Cross-process joinable.** A ``SpanContext`` is two strings
    (``trace_id``, ``span_id``) that serialize over the ``serving/subproc.py``
    JSONL pipe; the child's spans carry the parent's trace id and
    :meth:`Tracer.ingest` merges them into the parent's buffer under the
-   child's pid lane. Timestamps are wall-clock micros (``time.time``-anchored,
-   advanced by the monotonic clock) so lanes from different processes line up.
+   child's pid lane. Ring timestamps are wall-clock micros (``time.time``-
+   anchored, advanced by ``time.monotonic``) so lanes from different
+   processes line up.
 
-Exports: Chrome-trace-event JSON (``{"traceEvents": [...]}``; load in
+Ring exports: Chrome-trace-event JSON (``{"traceEvents": [...]}``; load in
 Perfetto / ``chrome://tracing``) and a JSONL stream (one finished span per
 line) for tailing.
 """
@@ -35,11 +65,18 @@ import time
 from collections import deque
 from typing import Dict, List, Optional
 
+from jax.profiler import TraceAnnotation
+
 # span categories (Chrome "cat" field) — one per subsystem lane
 CAT_SERVING = "serving"
 CAT_ROUTER = "router"
 CAT_TRAIN = "train"
 CAT_AUTOSCALE = "autoscale"
+CAT_SETUP = "setup"
+
+#: set-up phases kept per process (tens in practice: one per engine stage and
+#: one per compiled serving program)
+MAX_PHASES = 1024
 
 
 class SpanContext:
@@ -83,37 +120,70 @@ class OpenSpan:
         return SpanContext(self.trace_id, self.span_id)
 
 
-class _NullCtx:
-    def __enter__(self):
-        return None
+class Span:
+    """A scoped span: the profiler's annotation and, while the tracer is
+    enabled, its ring twin (``open``, else ``None``). ``t0``/``t1`` are the
+    ``time.monotonic`` stamps of entry and exit."""
 
-    def __exit__(self, *exc):
-        return False
+    __slots__ = ("_tracer", "_ann", "open", "name", "attrs", "t0", "t1",
+                 "_keep")
 
-
-_NULL = _NullCtx()
-
-
-class _SpanCtx:
-    __slots__ = ("_tracer", "_open")
-
-    def __init__(self, tracer, open_span):
+    def __init__(self, tracer, name, open_span, attrs, keep=False):
         self._tracer = tracer
-        self._open = open_span
+        self._ann = TraceAnnotation(name, **attrs)
+        self.open = open_span
+        self.name = name
+        self.attrs = attrs
+        self.t0 = self.t1 = None
+        self._keep = keep
+
+    # a Span stands in as ``parent=`` of another span (see ``_parent_of``)
+    @property
+    def trace_id(self):
+        return self.open.trace_id if self.open is not None else None
+
+    @property
+    def span_id(self):
+        return self.open.span_id if self.open is not None else None
+
+    @property
+    def cat(self):
+        return self.open.cat if self.open is not None else CAT_SERVING
+
+    def set(self, **attrs) -> None:
+        """Attributes known only at the end (counts, outcomes)."""
+        self._ann.set_metadata(**attrs)
+        self.attrs.update(attrs)
 
     def __enter__(self):
-        return self._open
+        self._ann.__enter__()
+        self.t0 = time.monotonic()
+        if self.open is not None:
+            self.open.t0 = self.t0
+        if self.open is not None or self._keep:
+            self._tracer._stack().append(self)
+        return self
 
     def __exit__(self, exc_type, exc, tb):
+        self.t1 = time.monotonic()
         if exc_type is not None:
-            self._open.attrs["error"] = exc_type.__name__
-        self._tracer.end_span(self._open)
+            self.set(error=exc_type.__name__)
+        if self.open is not None or self._keep:
+            stack = self._tracer._stack()
+            if stack and stack[-1] is self:
+                stack.pop()
+            if self._keep:
+                self._tracer._keep_phase(self, stack)
+            if self.open is not None:
+                self.open.attrs = self.attrs
+                self._tracer.end_span(self.open, t1=self.t1)
+        self._ann.__exit__(exc_type, exc, tb)
         return False
 
 
 def _parent_of(parent) -> tuple:
-    """(trace_id, span_id) from an OpenSpan / SpanContext / None."""
-    if parent is None:
+    """(trace_id, span_id) from an OpenSpan / Span / SpanContext / None."""
+    if parent is None or parent.trace_id is None:
         return None, None
     return parent.trace_id, getattr(parent, "span_id", None)
 
@@ -138,6 +208,10 @@ class Tracer:
         # time, in-process durations stay monotonic
         self._mono0 = time.monotonic()
         self._wall0 = time.time()
+        # scoped spans open on each thread, innermost last (ring nesting)
+        self._local = threading.local()
+        # set-up phases: kept whether or not the tracer is enabled
+        self._phases: "deque[Dict]" = deque(maxlen=MAX_PHASES)
 
     # ------------------------------------------------------------------ admin
     def enable(self, pid_label: Optional[str] = None,
@@ -235,15 +309,57 @@ class Tracer:
                      max((t1 - open_span.t0) * 1e6, 0.0),
                      open_span.attrs, open_span.tid)
 
-    def span(self, name: str, parent=None, cat: str = CAT_SERVING,
-             attrs: Optional[Dict] = None):
-        """Context manager. With ``parent`` the span nests under it; without,
-        it roots a fresh (step-scoped) trace id."""
+    def _stack(self) -> list:
+        stack = getattr(self._local, "stack", None)
+        if stack is None:
+            stack = self._local.stack = []
+        return stack
+
+    def current(self) -> Optional[Span]:
+        """The innermost scoped span open on this thread that the ring or the
+        phase list keeps, or ``None``: what a span opened on ANOTHER thread
+        (the chunk watchdog's worker) passes as ``parent``."""
+        stack = getattr(self._local, "stack", None)
+        return stack[-1] if stack else None
+
+    def span(self, name: str, parent=None, cat: Optional[str] = None,
+             **attrs) -> Span:
+        """A scoped span (``with tracer.span(...) as sp``): always a profiler
+        annotation, and a ring span while the tracer is enabled. ``parent``
+        (a request's root, a :class:`Span`, a :class:`SpanContext`) places the
+        ring span on that trace; without one it nests under the thread's
+        innermost open span, else roots a fresh trace id."""
         if not self.enabled:
-            return _NULL
-        if parent is not None:
-            return _SpanCtx(self, self.start_span(name, parent, cat, attrs))
-        return _SpanCtx(self, self.begin(name, cat, None, attrs))
+            return Span(self, name, None, attrs)
+        return Span(self, name, self._open_under(name, parent, cat, attrs),
+                    attrs)
+
+    def _open_under(self, name, parent, cat, attrs) -> OpenSpan:
+        if parent is None or parent.trace_id is None:
+            parent = self.current()
+        if parent is not None and parent.trace_id is not None:
+            return self.start_span(name, parent, cat, attrs)
+        return self.begin(name, cat or CAT_SERVING, None, attrs)
+
+    def phase(self, name: str, **attrs) -> Span:
+        """A set-up phase: a scoped span that is ALSO kept in :attr:`phases`
+        whether or not the tracer is enabled (set-up runs before anyone could
+        enable it)."""
+        open_span = self._open_under(name, None, CAT_SETUP, attrs) \
+            if self.enabled else None
+        return Span(self, name, open_span, attrs, keep=True)
+
+    def _keep_phase(self, span: Span, stack: list) -> None:
+        parent = next((s.name for s in reversed(stack) if s._keep), None)
+        self._phases.append({"name": span.name, "t0": span.t0, "t1": span.t1,
+                             "parent": parent, "attrs": dict(span.attrs)})
+
+    @property
+    def phases(self) -> List[Dict]:
+        """Set-up phases finished so far, in order of completion: ``name``,
+        ``t0``/``t1`` (``time.monotonic``), ``parent`` (the enclosing phase's
+        name or ``None``), ``attrs``."""
+        return list(self._phases)
 
     def record_span(self, name: str, parent, t0: float, t1: float,
                     cat: Optional[str] = None, attrs: Optional[Dict] = None,

@@ -47,7 +47,6 @@ from ..utils.comms_logging import (collective_spans, record_collective,
 from ..utils.device import PEAKS
 from ..utils.fault_injection import fault_point
 from ..utils.logging import log_dist, logger
-from ..utils.nvtx import annotate
 from ..utils.timer import (BACKWARD_GLOBAL_TIMER, FORWARD_GLOBAL_TIMER, STEP_GLOBAL_TIMER,
                            SynchronizedWallClockTimer, ThroughputTimer, TRAIN_BATCH_TIMER)
 from .checkpoint_engine.checkpoint_engine import (
@@ -92,6 +91,12 @@ class DeepSpeedEngine:
             raise AssertionError("deepspeed_tpu.initialize requires a Model")
         if not (isinstance(model, Model)):
             raise AssertionError("model must be deepspeed_tpu.models.Model (see models.base.from_flax)")
+        with get_tracer().phase("setup.engine_init"):
+            self._init(args, model, optimizer, training_data, lr_scheduler,
+                       mpu, collate_fn, config, mesh_spec, seed)
+
+    def _init(self, args, model, optimizer, training_data, lr_scheduler, mpu,
+              collate_fn, config, mesh_spec, seed):
         dist.init_distributed()
         self.module = model
         self.collate_fn = collate_fn
@@ -103,9 +108,10 @@ class DeepSpeedEngine:
         self._config = (config if isinstance(config, DeepSpeedConfig)
                         else DeepSpeedConfig(config))
         self.zero_stage = self._config.zero_config.stage
-        self.mesh_spec = mesh_spec or MeshSpec.from_config(
-            self._config.mesh, zero_stage=self.zero_stage)
-        set_global_mesh(self.mesh_spec)
+        with get_tracer().phase("setup.mesh"):
+            self.mesh_spec = mesh_spec or MeshSpec.from_config(
+                self._config.mesh, zero_stage=self.zero_stage)
+            set_global_mesh(self.mesh_spec)
         self._config.resolve_batch_config(self.mesh_spec.dp_world_size)
         # comm-compute overlap: installed like the mesh so model traces this
         # engine initiates see its setting (chunked TP matmuls / MoE a2a
@@ -377,8 +383,9 @@ class DeepSpeedEngine:
         self._param_shardings = to_shardings(self._param_spec_tree, mesh)
         # zero.Init equivalent: init jitted with sharded outputs — parameters are born
         # partitioned, never materialised replicated (partition_parameters.py:539).
-        params = jax.jit(self.module.init_fn,
-                         out_shardings=self._param_shardings)(rng)
+        with get_tracer().phase("setup.init_params"):
+            params = jax.jit(self.module.init_fn,
+                             out_shardings=self._param_shardings)(rng)
 
         self._grad_spec_tree = grad_accum_specs(abstract_params, mesh, self.zero_stage,
                                                 param_base_specs=self.module.param_specs)
@@ -418,18 +425,20 @@ class DeepSpeedEngine:
                 abstract_opt, mesh, self.zero_stage,
                 abstract_params=abstract_params, param_spec_tree=self._param_spec_tree)
             self._opt_shardings = to_shardings(self._opt_spec_tree, mesh)
-            opt_state = jax.jit(self.optimizer.init,
-                                out_shardings=self._opt_shardings)(params)
+            with get_tracer().phase("setup.init_optimizer"):
+                opt_state = jax.jit(self.optimizer.init,
+                                    out_shardings=self._opt_shardings)(params)
 
         repl = mesh.replicated()
         self._scaler_shardings = jax.tree_util.tree_map(lambda _: repl, scaler_state0)
-        self.state = TrainState(
-            params=params,
-            opt_state=opt_state,
-            scaler=jax.device_put(scaler_state0, repl),
-            global_step=jax.device_put(jnp.int32(0), repl),
-            skipped_steps=jax.device_put(jnp.int32(0), repl),
-        )
+        with get_tracer().phase("setup.place_state"):
+            self.state = TrainState(
+                params=params,
+                opt_state=opt_state,
+                scaler=jax.device_put(scaler_state0, repl),
+                global_step=jax.device_put(jnp.int32(0), repl),
+                skipped_steps=jax.device_put(jnp.int32(0), repl),
+            )
         self._state_shardings = TrainState(
             params=self._param_shardings,
             opt_state=self._opt_shardings,
@@ -890,6 +899,19 @@ class DeepSpeedEngine:
 
         return jax.tree_util.tree_map(one, batch)
 
+    def _dispatch_step(self, jitted, gbatch, lr, theta):
+        """Call the compiled step in the engine's regime; returns its metrics
+        (device values: nothing is fetched)."""
+        if self.offload_enabled:
+            self.state, grads, metrics = jitted(self.state, gbatch, theta)
+            self._host_optimizer_step(grads, lr, metrics)
+        elif self._quantized_dp:
+            self.state, metrics, self._qar_residual = jitted(
+                self.state, gbatch, lr, theta, self._qar_residual)
+        else:
+            self.state, metrics = jitted(self.state, gbatch, lr, theta)
+        return metrics
+
     # ------------------------------------------------------------------- API
     def train_batch(self, batch=None, data_iter=None):
         """Process one full global batch (gas microbatches) and take an optimizer step.
@@ -920,67 +942,65 @@ class DeepSpeedEngine:
             collective_spans.reset()
             self._build_train_step()
         jitted = self._fns["train_step"]
-        local = self._reshape_for_gas(batch)
-        gbatch = self._globalize(local, leading_gas=True)
-
-        fp_cfg = self._config.flops_profiler
-        if fp_cfg.enabled and self._host_steps + 1 == fp_cfg.profile_step:
-            self._run_flops_profiler(gbatch)
-
-        self.tput_timer.start()
-        self.timers(TRAIN_BATCH_TIMER).start()
-        lr = np.float32(self.get_lr_value())
-        theta = np.float32(self.progressive_layer_drop.get_theta()
-                           if self.progressive_layer_drop is not None else 1.0)
         tracer = get_tracer()
-        self._step_t0 = time.perf_counter()
-        self._last_step_tokens = _batch_tokens(batch)
-        step_span = tracer.begin("train_step", cat=CAT_TRAIN, tid="train",
-                                 attrs={"step": self._host_steps + 1})
-        with annotate("train_step"):
-            if self.offload_enabled:
-                self.state, grads, metrics = jitted(self.state, gbatch, theta)
-                self._host_optimizer_step(grads, lr, metrics)
-            elif self._quantized_dp:
-                self.state, metrics, self._qar_residual = jitted(
-                    self.state, gbatch, lr, theta, self._qar_residual)
-            else:
-                self.state, metrics = jitted(self.state, gbatch, lr, theta)
-        if first_trace:
-            self._comm_spans = collective_spans.summary()
-        if step_span is not None:
-            # tracing-enabled mode pays one sync so the span covers the device
-            # work, not just the async dispatch (disabled mode never syncs)
-            jax.block_until_ready(metrics["loss"])  # lint: host-sync-ok (tracer-gated)
-            # grad sync is XLA-scheduled inside the step: host wall-time can't
-            # split it out, but the trace-time byte accounting can ride the
-            # step's trace as a MODELED child span
-            if spans_total_bytes(self._comm_spans):
-                tracer.instant(
-                    "grad_sync", step_span, cat=CAT_TRAIN,
-                    attrs={"modeled": True,
-                           "bytes_on_wire": spans_total_bytes(self._comm_spans),
-                           "overlap_ratio":
-                               spans_overlap_ratio(self._comm_spans)})
-            tracer.end_span(step_span)
-        obs_profiler.tick("train_step")
-        self.timers(TRAIN_BATCH_TIMER).stop(sync=False)
-        self.tput_timer.stop(global_step=True)
+        # a span covers what the HOST did in this call; the device time of
+        # step n is joined from a profiler trace by the ``step`` attribute.
+        # Nothing here waits for the device, tracer on or off.
+        with tracer.span("train_step", cat=CAT_TRAIN,
+                         step=self._host_steps + 1) as step_span:
+            with tracer.span("train.host_batch"):
+                local = self._reshape_for_gas(batch)
+                gbatch = self._globalize(local, leading_gas=True)
 
-        # Host-side step mirror: the device counter (state.global_step) is exact but reading
-        # it forces a device sync per step; cadence decisions (print/monitor) use this mirror
-        # so the hot path never stalls the async dispatch queue. (Under fp16 overflow-skip the
-        # two can drift by the number of skipped steps; exact value remains at .global_steps.)
-        self._host_steps += 1
-        self.micro_steps += self.gradient_accumulation_steps()
-        if self.lr_scheduler is not None:
-            self.lr_scheduler.step()
-        if self.curriculum_scheduler is not None:
-            self.curriculum_scheduler.update_difficulty(self._host_steps)
-        if self.progressive_layer_drop is not None:
-            self.progressive_layer_drop.update_state(self._host_steps)
-        self._last_metrics = metrics
-        self._write_monitor_events(metrics)
+            fp_cfg = self._config.flops_profiler
+            if fp_cfg.enabled and self._host_steps + 1 == fp_cfg.profile_step:
+                self._run_flops_profiler(gbatch)
+
+            self.tput_timer.start()
+            self.timers(TRAIN_BATCH_TIMER).start()
+            lr = np.float32(self.get_lr_value())
+            theta = np.float32(self.progressive_layer_drop.get_theta()
+                               if self.progressive_layer_drop is not None
+                               else 1.0)
+            self._step_t0 = time.perf_counter()
+            self._last_step_tokens = _batch_tokens(batch)
+            with tracer.span("train.dispatch"):
+                if first_trace:
+                    # the first call is python tracing + lowering + compile
+                    # (or a cache load), as the host sees it
+                    with tracer.phase("setup.build_train_step"):
+                        metrics = self._dispatch_step(jitted, gbatch, lr, theta)
+                    self._comm_spans = collective_spans.summary()
+                else:
+                    metrics = self._dispatch_step(jitted, gbatch, lr, theta)
+            if spans_total_bytes(self._comm_spans):
+                # grad sync is XLA-scheduled inside the step: host wall-time
+                # can't split it out, so the trace-time byte accounting rides
+                # the step's span as MODELED attributes
+                step_span.set(
+                    bytes_on_wire=spans_total_bytes(self._comm_spans),
+                    overlap_ratio=spans_overlap_ratio(self._comm_spans))
+            with tracer.span("train.bookkeeping"):
+                obs_profiler.tick("train_step")
+                self.timers(TRAIN_BATCH_TIMER).stop(sync=False)
+                self.tput_timer.stop(global_step=True)
+
+                # Host-side step mirror: the device counter (state.global_step)
+                # is exact but reading it forces a device sync per step; cadence
+                # decisions (print/monitor) use this mirror so the hot path never
+                # stalls the async dispatch queue. (Under fp16 overflow-skip the
+                # two can drift by the number of skipped steps; exact value
+                # remains at .global_steps.)
+                self._host_steps += 1
+                self.micro_steps += self.gradient_accumulation_steps()
+                if self.lr_scheduler is not None:
+                    self.lr_scheduler.step()
+                if self.curriculum_scheduler is not None:
+                    self.curriculum_scheduler.update_difficulty(self._host_steps)
+                if self.progressive_layer_drop is not None:
+                    self.progressive_layer_drop.update_state(self._host_steps)
+                self._last_metrics = metrics
+                self._write_monitor_events(metrics)
         if self._host_steps % self._config.steps_per_print == 0:
             # lint: host-sync-ok (steps_per_print-gated: syncs only on print steps)
             log_dist(f"step={self._host_steps} loss={float(metrics['loss']):.4f} "
@@ -1013,14 +1033,11 @@ class DeepSpeedEngine:
         self._last_step_tokens = _batch_tokens(batch)
         lr = np.float32(self.get_lr_value())
         rng = jax.random.fold_in(self._base_rng, self._host_steps)
-        tracer = get_tracer()
-        step_span = tracer.begin("train_step", cat=CAT_TRAIN, tid="train",
-                                 attrs={"step": self._host_steps + 1,
-                                        "offload": True})
-        with annotate("train_step"):
+        # the streamed step is host-synchronous: its span covers the work
+        with get_tracer().span("train_step", cat=CAT_TRAIN,
+                               step=self._host_steps + 1, offload=1):
             metrics = self._param_offload.train_step(micros, lr=float(lr),
                                                      rng=rng)
-        tracer.end_span(step_span)       # streamed step is host-synchronous
         obs_profiler.tick("train_step")
         self.timers(TRAIN_BATCH_TIMER).stop(sync=False)
         self.tput_timer.stop(global_step=True)
@@ -1434,19 +1451,15 @@ class DeepSpeedEngine:
         if dist.get_rank() != 0:
             self.checkpoint_engine.commit(tag)
         dist.barrier("ckpt_drain")
-        tracer = get_tracer()
-        commit_span = tracer.begin("checkpoint_commit", cat=CAT_TRAIN,
-                                   tid="train",
-                                   attrs={"tag": str(tag),
-                                          "step": self._host_steps})
-        if dist.get_rank() == 0:
-            final = self.checkpoint_engine.commit_tag(save_dir, tag)
-        else:
-            final = os.path.join(save_dir, str(tag))
-        dist.barrier("ckpt_commit")
-        if save_latest and dist.get_rank() == 0:
-            write_latest_pointer(save_dir, tag)
-        tracer.end_span(commit_span)
+        with get_tracer().span("checkpoint_commit", cat=CAT_TRAIN,
+                               tag=str(tag), step=self._host_steps):
+            if dist.get_rank() == 0:
+                final = self.checkpoint_engine.commit_tag(save_dir, tag)
+            else:
+                final = os.path.join(save_dir, str(tag))
+            dist.barrier("ckpt_commit")
+            if save_latest and dist.get_rank() == 0:
+                write_latest_pointer(save_dir, tag)
         return final
 
     def _resolve_load_tag(self, load_dir: str, tag: Optional[str]):

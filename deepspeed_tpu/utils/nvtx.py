@@ -5,17 +5,18 @@ and the accelerator ``range_push/range_pop`` surface: on TPU the profiler is XLA
 ranges become ``jax.profiler.TraceAnnotation`` named scopes, visible in TensorBoard's
 trace viewer / Perfetto exactly where NVTX ranges land in Nsight.
 
-Two flavours, wired at the PR-10 observability call sites:
+Host-side ranges around a dispatch (prefill, decode chunk, train step) are
+not made here: ``observability.trace.Tracer.span`` opens the
+``TraceAnnotation`` itself, with the span's attributes, so every instrumented
+region is one call under one name. What stays here:
 
-- :func:`annotate` — HOST-side ``TraceAnnotation`` around a dispatch (prefill,
-  decode chunk, train step): shows as a named range on the host lane of an
-  XLA-profiler capture, aligning the device timeline with the wall-clock spans
-  ``observability.trace`` records for the same region;
 - :func:`named_scope` — IN-GRAPH ``jax.named_scope`` around traced collectives
   (``parallel/overlap.py`` rings, quantized allreduce): the name lands in XLA
-  op metadata, so the device ops themselves carry the call-site label.
+  op metadata, so the device ops themselves carry the call-site label;
+- :func:`instrument_w_nvtx`, :func:`range_push` / :func:`range_pop` — the
+  reference's decorator and accelerator surface.
 
-Both are no-ops cheap enough for hot paths when no profiler is capturing
+All are no-ops cheap enough for hot paths when no profiler is capturing
 (``TraceMe`` checks an atomic; ``named_scope`` only exists at trace time).
 """
 
@@ -24,11 +25,6 @@ import threading
 from typing import Callable
 
 import jax
-
-
-def annotate(name: str):
-    """Host-side profiler range (context manager)."""
-    return jax.profiler.TraceAnnotation(name)
 
 
 def named_scope(name: str):

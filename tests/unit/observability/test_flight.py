@@ -84,7 +84,7 @@ def _request_trace(tid="t1", e2e_ms=100.0, state="finished", retried=0,
     """A minimal healthy request tree: root + queue_wait + prefill + chunk."""
     return [
         _span("queue_wait", tid, "s2", "s1", 0, 10),
-        _span("prefill", tid, "s3", "s1", 10, 20),
+        _span("serving.prefill", tid, "s3", "s1", 10, 20),
         _span("decode_chunk", tid, "s4", "s1", 30, e2e_ms - 30),
         _span("request", tid, "s1", None, 0, e2e_ms,
               attrs={"request_id": request_id, "state": state,
@@ -103,9 +103,9 @@ class TestAttribution:
                   attrs={"state": "finished"}),
             _span("attempt", tid, "att", "root", 10, 88),
             _span("queue_wait", tid, "q", "rr", 10, 8),
-            _span("prefix_lookup", tid, "lk", "rr", 18, 2),
-            _span("prefill", tid, "pf", "rr", 20, 20),
-            _span("restore_prefix", tid, "rs", "pf", 20, 6),
+            _span("serving.prefix_lookup", tid, "lk", "rr", 18, 2),
+            _span("serving.prefill", tid, "pf", "rr", 20, 20),
+            _span("serving.restore_prefix", tid, "rs", "pf", 20, 6),
             _span("decode_chunk", tid, "c1", "rr", 40, 20),
             _span("decode_chunk", tid, "c2", "rr", 70, 20),
         ]
@@ -140,7 +140,7 @@ class TestAttribution:
                   attrs={"retry": True, "retry_of": "a1"}),
             _span("replica_request", tid, "rr2", "a2", 45, 55,
                   attrs={"state": "finished"}),
-            _span("prefill", tid, "pf", "rr2", 45, 15),
+            _span("serving.prefill", tid, "pf", "rr2", 45, 15),
             _span("decode_chunk", tid, "c2", "rr2", 60, 40),
         ]
         row = attribution.attribute(spans)

@@ -244,8 +244,11 @@ class TestTracer:
         t = Tracer()
         assert t.begin("x") is None
         assert t.start_span("y", parent=None) is None
-        with t.span("z") as s:
-            assert s is None
+        with t.span("z", k=1) as s:
+            # the profiler's annotation is always open; the ring has nothing
+            assert s.open is None and s.trace_id is None
+            s.set(n=2)
+        assert s.t1 >= s.t0
         t.end_span(None)
         assert t.spans == []
 
@@ -308,15 +311,25 @@ class TestServingTracing:
         mine = [s for s in spans if s["trace_id"] == h.trace_id
                 or (h.trace_id is None)]
         names = [s["name"] for s in spans]
-        for expect in ("replica_request", "queue_wait", "prefill",
-                       "bucket_prefill", "decode_chunk", "retire"):
+        for expect in ("replica_request", "queue_wait", "serving.admit",
+                       "serving.prefill", "decode_chunk", "retire"):
             assert expect in names, (expect, names)
         by_name = {}
         for s in spans:
             by_name.setdefault(s["name"], []).append(s)
         root = by_name["replica_request"][0]
-        # single trace id across the whole request column
-        assert all(s["trace_id"] == root["trace_id"] for s in spans)
+        # single trace id across the whole request column; the batch-level
+        # spans of a step() root a trace per step
+        request_scoped = ("replica_request", "queue_wait", "serving.admit",
+                          "serving.page_table", "serving.prefill",
+                          "serving.scatter_prefill", "decode_chunk", "retire")
+        assert all(s["trace_id"] == root["trace_id"] for s in spans
+                   if s["name"] in request_scoped
+                   and s["attrs"].get("op") != "release")
+        steps = {s["trace_id"] for s in by_name["serving.step"]}
+        assert root["trace_id"] not in steps
+        assert all(s["trace_id"] in steps
+                   for s in by_name["serving.decode_chunk"])
         # decode chunks nest under the request root
         for c in by_name["decode_chunk"]:
             assert c["parent_id"] == root["span_id"]
@@ -415,12 +428,18 @@ class TestServingTracing:
             tracer.end_span(root)
             child_spans = rep.take_spans()
             assert child_spans, "child streamed no spans"
-            assert all(s["trace_id"] == root.trace_id for s in child_spans)
+            # the request's own spans carry the parent's trace id; the rest
+            # are the batch-level spans of the child's step() calls
+            mine = [s for s in child_spans if s["trace_id"] == root.trace_id]
+            assert all(s["name"].startswith(("serving.", "setup."))
+                       for s in child_spans
+                       if s["trace_id"] != root.trace_id)
             assert any(s["name"] == "replica_request"
-                       and s["parent_id"] == root.span_id
-                       for s in child_spans)
-            assert any(s["name"] == "decode_chunk" for s in child_spans)
-            tracer.ingest(child_spans, pid_label="subproc-replica")
+                       and s["parent_id"] == root.span_id for s in mine)
+            assert any(s["name"] == "decode_chunk" for s in mine)
+            assert any(s["name"] == "serving.admit"
+                       and s["attrs"]["request_id"] == 0 for s in mine)
+            tracer.ingest(mine, pid_label="subproc-replica")
             events = tracer.chrome_events()
             _chrome_check(events)
             # two process lanes in one Perfetto file, one trace id
