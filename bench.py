@@ -48,7 +48,7 @@ def kernel_gate(mode: str):
     the TPU test lane and ``chip_smoke.py`` run, so they cannot drift. Returns
     the per-kernel max-abs-err dict; raises on any failure."""
     from deepspeed_tpu.ops.kernel_checks import run_kernel_checks
-    names = {"train": ("flash_fwd", "flash_bwd", "block_sparse"),
+    names = {"train": ("flash_fwd", "flash_fwd_bf16", "flash_bwd", "block_sparse"),
              "inference": ("flash_fwd", "flash_alibi", "decode", "paged_mha",
                            "paged_gqa")}[mode]
     return run_kernel_checks(names)

@@ -18,16 +18,33 @@ def _err(a, b) -> float:
     return float(jnp.max(jnp.abs(a.astype(jnp.float32) - b.astype(jnp.float32))))
 
 
-def check_flash_fwd() -> float:
+def _flash_fwd_err(q, k, v) -> float:
     import jax
-    import jax.numpy as jnp
     from .attention.flash import flash_attention
     from .transformer.attention import xla_attention
-    rng = np.random.RandomState(0)
-    q, k, v = (jnp.asarray(rng.standard_normal((2, 1024, 4, 64)), jnp.float32)
-               for _ in range(3))
     o1 = jax.jit(lambda *a: flash_attention(*a, causal=True))(q, k, v)
     return _err(o1, xla_attention(q, k, v, causal=True))
+
+
+def check_flash_fwd() -> float:
+    import jax.numpy as jnp
+    rng = np.random.RandomState(0)
+    return _flash_fwd_err(*(jnp.asarray(rng.standard_normal((2, 1024, 4, 64)),
+                                        jnp.float32) for _ in range(3)))
+
+
+def _flash_train_qkv():
+    """q, k, v at the training benchmark's own attention shape (``gpt2-125m.seq1k``:
+    seq 1024, d_head 64, bf16): one 1024 block a head, which the kernels walk in
+    sub-tiles — the path a 512-token check never reaches."""
+    import jax.numpy as jnp
+    rng = np.random.RandomState(1)
+    return tuple(jnp.asarray(rng.standard_normal((2, 1024, 4, 64)), jnp.bfloat16)
+                 for _ in range(3))
+
+
+def check_flash_fwd_bf16() -> float:
+    return _flash_fwd_err(*_flash_train_qkv())
 
 
 def check_flash_bwd() -> float:
@@ -35,9 +52,7 @@ def check_flash_bwd() -> float:
     import jax.numpy as jnp
     from .attention.flash import flash_attention
     from .transformer.attention import xla_attention
-    rng = np.random.RandomState(1)
-    q, k, v = (jnp.asarray(rng.standard_normal((2, 512, 4, 64)), jnp.bfloat16)
-               for _ in range(3))
+    q, k, v = _flash_train_qkv()
     g1 = jax.jit(jax.grad(lambda *a: flash_attention(
         *a, causal=True).astype(jnp.float32).sum(), argnums=(0, 1, 2)))(q, k, v)
     g2 = jax.jit(jax.grad(lambda *a: xla_attention(
@@ -156,7 +171,8 @@ def _check_qmm(bits: int, m: int) -> float:
 # name → (check fn, max-abs-err tolerance for the check's dtype/shape)
 KERNEL_CHECKS: Dict[str, Tuple] = {
     "flash_fwd": (check_flash_fwd, 0.02),       # fp32
-    "flash_bwd": (check_flash_bwd, 0.05),       # bf16 grads
+    "flash_fwd_bf16": (check_flash_fwd_bf16, 0.05),  # bf16, training shape
+    "flash_bwd": (check_flash_bwd, 0.05),       # bf16 grads, training shape
     "flash_alibi": (check_flash_alibi, 0.05),   # bf16
     "decode": (check_decode, 0.03),             # bf16
     "block_sparse": (check_block_sparse, 0.03),  # bf16

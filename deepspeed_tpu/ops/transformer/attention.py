@@ -50,7 +50,12 @@ def xla_attention(q: jnp.ndarray, k: jnp.ndarray, v: jnp.ndarray,
 # carry in VMEM scratch) flash wins at EVERY measured length — v5e, GPT-2-shaped
 # b*t=8192 h=12 d=64 bf16, fwd: 2.6x at 1024 / 8.6x at 4096; fwd+bwd: 2.8x at 1024 /
 # 6.3x at 4096 (see tests/unit/ops/test_flash_crossover.py) — so the kernel floor only
-# excludes degenerate tiny shapes where block padding dominates.
+# excludes degenerate tiny shapes where block padding dominates. What causality skips
+# there: up to 1024 tokens a head is ONE kernel block, so nothing is skipped between
+# blocks; inside it the kernel leaves out the 512-row (forward) or 256-column (backward)
+# sub-tiles above the diagonal — none at 256-512 tokens forward, a quarter of the square
+# at 1024 forward, 37.5 % backward — and masks only the sub-tiles on the diagonal. Whole
+# blocks above the diagonal are skipped from 2048 tokens on (``ops/attention/flash.py``).
 FLASH_MIN_SEQ = 256
 
 

@@ -18,14 +18,28 @@ def _qkv(rng, b, t, h, d, dtype=np.float32):
 
 
 # ------------------------------------------------------------------------ flash
+# (t, block_q, block_k) whose diagonal blocks are walked in sub-tiles: two or more forward
+# strips (512) and backward strips (256) in ONE block a head, in a 2-block and in a 3-block
+# grid, and unequal blocks (the diagonal enters a block at an offset: masked whole)
+SUB_TILED = [(1024, 1024, 1024), (2048, 1024, 1024), (1536, 512, 512), (1024, 512, 1024)]
+
+
 @pytest.mark.parametrize("causal", [True, False])
-@pytest.mark.parametrize("t,block", [(128, 64), (96, 64), (64, 128)])
+@pytest.mark.parametrize("t,block", [(128, 64), (96, 64), (64, 128), (1024, 1024),
+                                     (2048, 1024)])
 def test_flash_matches_xla(causal, t, block):
     rng = np.random.default_rng(0)
-    q, k, v = _qkv(rng, 2, t, 4, 32)
+    q, k, v = _qkv(rng, 2 if t < 1024 else 1, t, 4 if t < 1024 else 2, 32)
     o1 = flash_attention(q, k, v, causal=causal, block_q=block, block_k=block)
     o2 = xla_attention(q, k, v, causal=causal)
     np.testing.assert_allclose(np.asarray(o1), np.asarray(o2), rtol=1e-5, atol=1e-5)
+
+
+def _grads(fn, q, k, v, w):
+    """d/d(q, k, v) of sum(fn * w): a cotangent that differs from row to row, so a
+    strip written to the wrong rows cannot pass."""
+    return jax.grad(lambda *a: (fn(*a).astype(jnp.float32) * w).sum(),
+                    argnums=(0, 1, 2))(q, k, v)
 
 
 @pytest.mark.parametrize("causal", [True, False])
@@ -39,6 +53,52 @@ def test_flash_grads_match_xla(causal):
                   argnums=(0, 1, 2))(q, k, v)
     for a, b in zip(g1, g2):
         np.testing.assert_allclose(np.asarray(a), np.asarray(b), rtol=1e-4, atol=1e-5)
+
+
+@pytest.mark.parametrize("alibi", [False, True])
+@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16])
+@pytest.mark.parametrize("t,block_q,block_k", SUB_TILED)
+def test_flash_sub_tiled_matches_xla(t, block_q, block_k, dtype, alibi):
+    """Forward and all three gradients where the diagonal block is walked in strips."""
+    from deepspeed_tpu.models.causal_lm import _alibi_attention_xla, alibi_slopes
+    rng = np.random.default_rng(11)
+    h = 2
+    q, k, v = (x.astype(dtype) for x in _qkv(rng, 1, t, h, 32))
+    w = jnp.asarray(rng.normal(size=(1, t, h, 32)).astype(np.float32))
+    slopes = jnp.asarray(alibi_slopes(h)) if alibi else None
+
+    def flash(*a):
+        return flash_attention(*a, causal=True, alibi_slopes=slopes,
+                               block_q=block_q, block_k=block_k)
+
+    def ref(*a):
+        a = tuple(x.astype(jnp.float32) for x in a)
+        if alibi:
+            return _alibi_attention_xla(*a, slopes)
+        return xla_attention(*a, causal=True)
+
+    tol = dict(rtol=1e-4, atol=2e-5) if dtype == jnp.float32 else dict(rtol=4e-2, atol=4e-2)
+    o1 = flash(q, k, v)
+    assert o1.dtype == dtype
+    np.testing.assert_allclose(np.asarray(o1, np.float32), np.asarray(ref(q, k, v)), **tol)
+    for a, b in zip(_grads(flash, q, k, v, w), _grads(ref, q, k, v, w)):
+        np.testing.assert_allclose(np.asarray(a, np.float32), np.asarray(b, np.float32),
+                                   **tol)
+
+
+def test_flash_causal_work_share():
+    """The share of the score square the kernels form, from the strips they walk."""
+    from deepspeed_tpu.ops.attention.flash import causal_work_share
+    # the benchmark's shape: one 1024 block a head, d_head 64
+    assert causal_work_share(1024, backward=True) <= 0.63      # 4 strips of 256
+    assert causal_work_share(1024) <= 0.75                     # 2 strips of 512
+    assert causal_work_share(1024, causal=False) == 1.0
+    assert causal_work_share(1024, causal=False, backward=True) == 1.0
+    # a block no strip divides, or unequal blocks, is formed whole
+    assert causal_work_share(128, 64, 64) == 0.75
+    assert causal_work_share(1024, 512, 1024) == 1.0
+    # long sequences: the grid-level skip does the rest, towards the triangle's half
+    assert 0.5 < causal_work_share(16384, backward=True) < causal_work_share(4096) < 0.6
 
 
 def test_flash_bf16():
