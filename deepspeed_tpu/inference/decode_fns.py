@@ -25,6 +25,43 @@ import jax.numpy as jnp
 from ..parallel.overlap import overlap_scope
 
 
+def apply_model(module, params, with_stats: bool, *args, **kw):
+    """``module.apply`` -> ``(its outputs, stats)``. With ``with_stats`` the
+    counts the model's expert layers sow (``stats`` collection: assignments on
+    held experts, distinct held experts read) come back summed over the layers
+    as a ``(2,)`` int32; without, the plain call and ``None``."""
+    if not with_stats:
+        return module.apply({"params": params}, *args, **kw), None
+    out, sown = module.apply({"params": params}, *args, mutable=["stats"], **kw)
+    leaves = jax.tree_util.tree_leaves(sown)
+    return out, (sum(leaves) if leaves else jnp.zeros((2,), jnp.int32))
+
+
+def _chunk_body(step_model, slot_select, base_key, seeds, eos_ids):
+    """The ``fori_loop`` body every decode chunk shares: one model step
+    (``step_model(toks, caches, lens) -> ((logits, caches), stats or None)``),
+    token selection, and the per-slot bookkeeping. The carry is ``(toks,
+    caches, lens, active, remaining, steps, buf)`` and, where the model step
+    hands out stats, their running sum as an eighth element."""
+
+    def body(i, s):
+        toks, caches, lens, active, remaining, steps, buf = s[:7]
+        (logits, caches), stats = step_model(toks, caches, lens)
+        nxt = slot_select(logits[:, -1], base_key, seeds, steps)
+        tok = jnp.where(active[:, None], nxt,
+                        jnp.maximum(eos_ids, 0)[:, None]).astype(jnp.int32)
+        buf = buf.at[:, i].set(tok[:, 0])
+        remaining = remaining - active.astype(jnp.int32)
+        finished = jnp.logical_or(tok[:, 0] == eos_ids, remaining <= 0)
+        lens = lens + active.astype(jnp.int32)
+        steps = steps + active.astype(jnp.int32)
+        active = jnp.logical_and(active, jnp.logical_not(finished))
+        out = (tok, caches, lens, active, remaining, steps, buf)
+        return out if stats is None else out + (s[7] + stats,)
+
+    return body
+
+
 def logits_transform(do_sample: bool, temperature: float, top_k: int,
                      top_p: float) -> Callable[[Any], Any]:
     """Temperature/top-k/top-p masking over ``(b, V)`` logits (sampling only)."""
@@ -83,10 +120,13 @@ def make_slot_select_fn(do_sample: bool, temperature: float, top_k: int,
     return select
 
 
-def build_prefill(module, dequant, overlap=None):
+def build_prefill(module, dequant, overlap=None, with_stats: bool = False):
     """Prefill: one forward over the (right-padded) prompt, logits read only at each
     sequence's last valid position (``logits_positions`` skips the rest of the head
-    matmul), KV written into the fixed cache buffers.
+    matmul), KV written into the fixed cache buffers. The rows' real lengths go in
+    as ``seq_lens`` too: a layer with a recurrent state takes it after the last
+    real token. ``with_stats``: also return the expert layers' counts
+    (:func:`apply_model`).
 
     ``overlap``: the owning engine's ``OverlapConfig`` — installed for the
     duration of the TRACE (``overlap_scope``) so the compiled body bakes in
@@ -100,10 +140,12 @@ def build_prefill(module, dequant, overlap=None):
 
     def prefill(params, ids, caches, lens0):
         with overlap_scope(overlap):
-            logits, new_caches = module.apply(
-                {"params": dequant(params)}, ids, caches=caches,
+            (logits, new_caches), stats = apply_model(
+                module, dequant(params), with_stats, ids, caches=caches,
                 cache_lens=jnp.zeros_like(lens0),
-                logits_positions=jnp.maximum(lens0 - 1, 0))
+                logits_positions=jnp.maximum(lens0 - 1, 0), seq_lens=lens0)
+        if with_stats:
+            return logits[:, 0], new_caches, stats
         return logits[:, 0], new_caches
 
     return prefill
@@ -275,7 +317,7 @@ def build_decode_loop(module, dequant, select, gen_cap: int, overlap=None):
 
 
 def build_decode_chunk(module, dequant, slot_select, chunk_size: int,
-                       overlap=None):
+                       overlap=None, with_stats: bool = False):
     """Fixed-shape chunked decode over a slot-batch: exactly ``chunk_size`` steps,
     every shape static, one compile per (slots, cap, chunk, sampling) key.
 
@@ -294,7 +336,10 @@ def build_decode_chunk(module, dequant, slot_select, chunk_size: int,
     A slot's real tokens in the returned ``buf (S, chunk_size)`` are the prefix of
     length ``steps_out[s] - steps_in[s]`` — active→inactive is one-way inside a
     chunk, so no gaps. The scheduler harvests on the host between chunks.
+    ``with_stats`` appends the expert layers' counts summed over the chunk's
+    steps (:func:`apply_model`) to the outputs.
     """
+    stats0 = (jnp.zeros((2,), jnp.int32),) if with_stats else ()
 
     def decode_chunk(params, toks, caches, lens, active, remaining, eos_ids,
                      seeds, steps, base_key):
@@ -304,41 +349,36 @@ def build_decode_chunk(module, dequant, slot_select, chunk_size: int,
         S = toks.shape[0]
         buf = jnp.zeros((S, chunk_size), jnp.int32)
 
-        def body(i, s):
-            toks, caches, lens, active, remaining, steps, buf = s
-            logits, caches = module.apply(
-                {"params": params}, toks, positions=lens[:, None],
-                caches=caches, cache_lens=lens)
-            nxt = slot_select(logits[:, -1], base_key, seeds, steps)
-            tok = jnp.where(active[:, None], nxt,
-                            jnp.maximum(eos_ids, 0)[:, None]).astype(jnp.int32)
-            buf = buf.at[:, i].set(tok[:, 0])
-            remaining = remaining - active.astype(jnp.int32)
-            finished = jnp.logical_or(tok[:, 0] == eos_ids, remaining <= 0)
-            lens = lens + active.astype(jnp.int32)
-            steps = steps + active.astype(jnp.int32)
-            active = jnp.logical_and(active, jnp.logical_not(finished))
-            return tok, caches, lens, active, remaining, steps, buf
+        def step_model(toks, caches, lens):
+            return apply_model(module, params, with_stats, toks,
+                               positions=lens[:, None], caches=caches,
+                               cache_lens=lens)
 
+        body = _chunk_body(step_model, slot_select, base_key, seeds, eos_ids)
         with overlap_scope(overlap):     # trace-time: fori body traces inside
-            toks, caches, lens, active, remaining, steps, buf = jax.lax.fori_loop(
+            out = jax.lax.fori_loop(
                 0, chunk_size, body,
-                (toks, caches, lens, active, remaining, steps, buf))
-        return buf, toks, caches, lens, active, remaining, steps
+                (toks, caches, lens, active, remaining, steps, buf) + stats0)
+        toks, caches, lens, active, remaining, steps, buf = out[:7]
+        return (buf, toks, caches, lens, active, remaining, steps) + out[7:]
 
     return decode_chunk
 
 
 def build_paged_decode_chunk(module, dequant, slot_select, chunk_size: int,
-                             kv_cap: int, overlap=None, fused: bool = False):
-    """Paged sibling of :func:`build_decode_chunk`: the caches are GLOBAL KV
-    pages (``{"k": (P, hk, page, d), ...}`` per layer) and each step writes at
+                             kv_cap: int, overlap=None, fused: bool = False,
+                             with_stats: bool = False):
+    """Paged sibling of :func:`build_decode_chunk`: the caches of the layers
+    that keep keys and values are GLOBAL KV pages (``{"k": (P, hk, page, d),
+    ...}``) and each step writes at
     the page-mapped row of the slot's static-shape ``page_table`` row — the
     table itself never changes inside a chunk (pages are bound at admission),
     so it rides as a loop constant. Every shape is static in (slots,
     total-pages, page, chunk): a slot's page COUNT is runtime data in the
     table, so page growth across requests never mints a compile key (pinned by
-    the analysis sweep's paged lane).
+    the analysis sweep's paged lane). A layer with a recurrent state carries
+    its per-slot ``{"conv", "ssm"}`` arrays through the loop as they are, and
+    a layer that keeps nothing an empty dict.
 
     ``fused=True`` (TPU / ``DS_TPU_PAGED_FORCE_FUSED=1``): each step attends
     straight against the pages through the Pallas gather-by-page-index kernel
@@ -354,6 +394,7 @@ def build_paged_decode_chunk(module, dequant, slot_select, chunk_size: int,
     occupancy win on CPU hosts; per-chunk it is 1/K of that. ``kv_cap``
     bounds the dense view at exactly the slot-row pool's ``cap``."""
     from ..ops.paged_attention import gather_kv_dense
+    stats0 = (jnp.zeros((2,), jnp.int32),) if with_stats else ()
 
     def decode_chunk(params, toks, caches, page_table, lens, active, remaining,
                      eos_ids, seeds, steps, base_key):
@@ -363,70 +404,53 @@ def build_paged_decode_chunk(module, dequant, slot_select, chunk_size: int,
         buf = jnp.zeros((S, chunk_size), jnp.int32)
 
         if fused:
-            def body(i, s):
-                toks, caches, lens, active, remaining, steps, buf = s
-                logits, caches = module.apply(
-                    {"params": params}, toks, positions=lens[:, None],
-                    caches=caches, cache_lens=lens, page_table=page_table,
-                    kv_cap=kv_cap)
-                nxt = slot_select(logits[:, -1], base_key, seeds, steps)
-                tok = jnp.where(active[:, None], nxt,
-                                jnp.maximum(eos_ids, 0)[:, None]
-                                ).astype(jnp.int32)
-                buf = buf.at[:, i].set(tok[:, 0])
-                remaining = remaining - active.astype(jnp.int32)
-                finished = jnp.logical_or(tok[:, 0] == eos_ids, remaining <= 0)
-                lens = lens + active.astype(jnp.int32)
-                steps = steps + active.astype(jnp.int32)
-                active = jnp.logical_and(active, jnp.logical_not(finished))
-                return tok, caches, lens, active, remaining, steps, buf
+            def step_model(toks, caches, lens):
+                return apply_model(module, params, with_stats, toks,
+                                   positions=lens[:, None], caches=caches,
+                                   cache_lens=lens, page_table=page_table,
+                                   kv_cap=kv_cap)
 
+            body = _chunk_body(step_model, slot_select, base_key, seeds, eos_ids)
             with overlap_scope(overlap):
-                toks, caches, lens, active, remaining, steps, buf = \
-                    jax.lax.fori_loop(0, chunk_size, body,
-                                      (toks, caches, lens, active, remaining,
-                                       steps, buf))
-            return buf, toks, caches, lens, active, remaining, steps
+                out = jax.lax.fori_loop(
+                    0, chunk_size, body,
+                    (toks, caches, lens, active, remaining, steps, buf) + stats0)
+            toks, caches, lens, active, remaining, steps, buf = out[:7]
+            return (buf, toks, caches, lens, active, remaining, steps) + out[7:]
 
         # XLA fallback: hoisted per-chunk gather, pure slot-row steps over the
         # dense carry, ONE end-of-chunk mirror of the appended rows back into
         # the pages — the pages leave/enter the loop nowhere, so the loop body
         # is byte-for-byte the slot pool's
-        ps = caches[0]["k"].shape[2]
+        paged = [c for c in caches if "k" in c]
+        ps = paged[0]["k"].shape[2]
         mp = page_table.shape[1]
-        P_total = caches[0]["k"].shape[0]
+        P_total = paged[0]["k"].shape[0]
         lens_in = lens
         dense = [dict(zip(("k", "v"),
                           gather_kv_dense(c["k"], c["v"], page_table, kv_cap)))
-                 for c in caches]
+                 if "k" in c else c for c in caches]
 
-        def body(i, s):
-            toks, dense, lens, active, remaining, steps, buf = s
-            logits, dense = module.apply(
-                {"params": params}, toks, positions=lens[:, None],
-                caches=dense, cache_lens=lens)
-            nxt = slot_select(logits[:, -1], base_key, seeds, steps)
-            tok = jnp.where(active[:, None], nxt,
-                            jnp.maximum(eos_ids, 0)[:, None]).astype(jnp.int32)
-            buf = buf.at[:, i].set(tok[:, 0])
-            remaining = remaining - active.astype(jnp.int32)
-            finished = jnp.logical_or(tok[:, 0] == eos_ids, remaining <= 0)
-            lens = lens + active.astype(jnp.int32)
-            steps = steps + active.astype(jnp.int32)
-            active = jnp.logical_and(active, jnp.logical_not(finished))
-            return tok, dense, lens, active, remaining, steps, buf
+        def step_model(toks, dense, lens):
+            return apply_model(module, params, with_stats, toks,
+                               positions=lens[:, None], caches=dense,
+                               cache_lens=lens)
 
+        body = _chunk_body(step_model, slot_select, base_key, seeds, eos_ids)
         with overlap_scope(overlap):     # trace-time: fori body traces inside
-            toks, dense, lens, active, remaining, steps, buf = \
-                jax.lax.fori_loop(0, chunk_size, body,
-                                  (toks, dense, lens, active, remaining,
-                                   steps, buf))
+            out = jax.lax.fori_loop(
+                0, chunk_size, body,
+                (toks, dense, lens, active, remaining, steps, buf) + stats0)
+        toks, dense, lens, active, remaining, steps, buf = out[:7]
         # mirror rows [lens_in, lens) (this chunk's appends) into the pages;
         # rows a slot never advanced past, or beyond cap, route to an
         # out-of-range page index and the scatter drops them
         done = lens - lens_in
         new_caches = []
         for c, dn in zip(caches, dense):
+            if "k" not in c:             # per-slot state: the loop's carry IS it
+                new_caches.append(dn)
+                continue
             k_p, v_p = c["k"], c["v"]
             for j in range(chunk_size):
                 rows = lens_in + j
@@ -443,6 +467,6 @@ def build_paged_decode_chunk(module, dequant, slot_select, chunk_size: int,
                 k_p = k_p.at[pidx, :, off, :].set(k_new.astype(k_p.dtype))
                 v_p = v_p.at[pidx, :, off, :].set(v_new.astype(v_p.dtype))
             new_caches.append({"k": k_p, "v": v_p})
-        return buf, toks, new_caches, lens, active, remaining, steps
+        return (buf, toks, new_caches, lens, active, remaining, steps) + out[7:]
 
     return decode_chunk

@@ -13,6 +13,7 @@ Reference: ``deepspeed/inference/engine.py`` (``InferenceEngine:35``,
   (selected per family by the policy registry in ``module_inject``).
 """
 
+import dataclasses
 import os
 import time
 from typing import Any, Dict, List, Optional
@@ -106,6 +107,15 @@ class InferenceEngine:
             if params is None:
                 with get_tracer().phase("setup.init_params"):
                     params = self._init_params_segmented(cfg, seed)
+                if cfg.level_random_experts:
+                    from ..moe.latent_moe import level_expert_load
+                    with get_tracer().phase("setup.balance_experts"):
+                        params, before, after = level_expert_load(
+                            dataclasses.replace(cfg, dtype=self._config.jax_dtype()),
+                            params, seed)
+                    log_dist("stand-in weights: expert load, largest over mean "
+                             f"{before:.2f} -> {after:.2f} (selection bias "
+                             "levelled on random tokens)", ranks=[0])
             return cfg, params
         if isinstance(model, tuple) and len(model) == 2:
             cfg, params = model
@@ -475,7 +485,7 @@ class InferenceEngine:
         if "touch" not in self._fns:
             self._fns["touch"] = jax.jit(
                 lambda i, k, c: i[0, 0] + k[0].astype(i.dtype)
-                + sum(leaf[0, 0, 0, 0] for leaf in jax.tree_util.tree_leaves(c)
+                + sum(leaf[(0,) * leaf.ndim] for leaf in jax.tree_util.tree_leaves(c)
                       ).astype(i.dtype))
         np.asarray(self._fns["touch"](ids_dev, prefill_key, caches))
         t0 = time.perf_counter()
@@ -485,13 +495,25 @@ class InferenceEngine:
         self.ttft = time.perf_counter() - t0
 
         eos = np.int32(-1 if eos_token_id is None else eos_token_id)
+        rows = b if do_sample else max(b, int(self.model_config.greedy_decode_rows or 0))
+        if rows > b:
+            # the configuration asks for greedy decodes at a fixed row count
+            # (``CausalLMConfig.greedy_decode_rows``, there is why): the rows
+            # added hold one pad token each (finished at once where there is
+            # an EOS) and are cut off below. Sampling draws one key a batch,
+            # so it keeps its own batch.
+            pad = rows - b
+            tok0 = jnp.pad(tok0, ((0, pad), (0, 0)), constant_values=max(int(eos), 0))
+            caches = jax.tree_util.tree_map(
+                lambda a: jnp.pad(a, ((0, pad),) + ((0, 0),) * (a.ndim - 1)), caches)
+            lens = jnp.pad(lens, (0, pad))
         # cache room is guaranteed: cap >= t + max_new_tokens, and the last appended KV
         # lands at position t + max_new_tokens - 2 < cap
         t1 = time.perf_counter()
         buf, n = decode_loop(self.params, tok0, caches, lens,
                              np.int32(max_new_tokens), eos, rng)
         n = int(n)
-        gen = np.asarray(buf)[:, :n]                    # host sync ends the decode clock
+        gen = np.asarray(buf)[:b, :n]                   # host sync ends the decode clock
         decode_time = time.perf_counter() - t1
         # TPOT counts only loop-produced tokens (the first token is TTFT's);
         # decode_tps is batch-aggregate throughput of the same window

@@ -26,6 +26,7 @@ they were donated into the wedged dispatch — so the caller must ``reset_pool``
 (the scheduler's decode-failure path already does).
 """
 
+import os
 import threading
 import time
 from dataclasses import dataclass
@@ -86,6 +87,9 @@ class ChunkResult:
     steps: np.ndarray        # (S,) per-request tokens emitted so far
     elapsed: float           # wall seconds for dispatch + fetch: from the end
     #   of ``serving.place_inputs`` to the end of ``serving.fetch``
+    moe: Optional[np.ndarray] = None   # (assignments on held experts, distinct
+    #   held experts read) over the chunk's steps and expert layers; None for
+    #   a model without expert layers
 
 
 @dataclass
@@ -134,6 +138,16 @@ class ChunkedDecodeExecutor:
         self.kv_pool_kind = kv_pool
         self.kv_page_size = int(kv_page_size)
         self.kv_total_pages = kv_total_pages
+        kinds = engine.model_config.layer_kinds
+        # what the model's layers keep decides what the programs carry: a
+        # state-space layer a per-slot state, an expert layer its two counts
+        # and no cache. The prefix and slab movers (and the suffix prefill)
+        # read keys and values from EVERY layer, so prefix hits and
+        # speculation need a model whose layers all keep them: see the
+        # scheduler
+        self.kv_every_layer = all(k in "A*" for k in kinds)
+        self.with_stats = "E" in kinds
+        self.last_prefill_moe = None    # the last prefill's counts (or None)
         self.pool = self._build_pool()
         self._slot_select = make_slot_select_fn(*self.sampling)
         self._base_key = jax.random.PRNGKey(base_seed)
@@ -180,7 +194,8 @@ class ChunkedDecodeExecutor:
                                    self.cap, page_size=self.kv_page_size,
                                    dtype=self.engine.dtype,
                                    total_pages=self.kv_total_pages)
-                ph.set(pages=pool.total_pages)
+                ph.set(pages=pool.total_pages, slots=self.slots,
+                       state_bytes=pool.state_nbytes)
                 return pool
             return SlotKVPool(self.engine.model_config, self.slots, self.cap,
                               dtype=self.engine.dtype)
@@ -199,7 +214,7 @@ class ChunkedDecodeExecutor:
     # ------------------------------------------------------------- compiled fns
     def _chunk_fn(self):
         if self.paged:
-            from ...ops.paged_attention import fused_paged_for
+            from ...ops.paged_attention import FORCE_FUSED_ENV, fused_paged_for
             from ...parallel.mesh import AXIS_TENSOR, get_global_mesh
             mesh = get_global_mesh()
             cfg = self.engine.model_config
@@ -208,8 +223,17 @@ class ChunkedDecodeExecutor:
             # it once per chunk), no shard_map TP path (the fallback's dense
             # steps route through _sharded_decode), and its dispatcher needs
             # a lane-aligned head dim on-chip (fused_paged_for mirrors it);
-            # every excluded regime decodes strictly faster on the fallback
+            # every excluded regime decodes strictly faster on the fallback.
+            # So does a pool that holds a row for every slot's whole cap: on
+            # the chip the kernel took 1.07-2.6x the fallback's time a step at
+            # every page of keys from 8 to 128 KiB (32 slots, pages of 16, cap
+            # 2048: PERF.md, PR 27), so it is taken where the dense view would
+            # need more rows than the pool has (an oversubscribed pool, what
+            # paging is for); the env override forces it
+            oversubscribed = self.slots * self.cap > \
+                (self.pool.total_pages - 1) * self.pool.page_size
             fused = fused_paged_for(cfg.head_dim) \
+                and (oversubscribed or os.environ.get(FORCE_FUSED_ENV, "0") == "1") \
                 and getattr(cfg, "pos_emb", None) != "alibi" \
                 and (mesh is None or mesh.size(AXIS_TENSOR) <= 1)
             # one compile per (slots, pages, page, cap, chunk, sampling) key:
@@ -229,12 +253,13 @@ class ChunkedDecodeExecutor:
                 chunk = build_paged_decode_chunk(
                     self.engine.module, self.engine._dequant,
                     self._slot_select, self.chunk_size, kv_cap=self.cap,
-                    overlap=overlap, fused=fused)
+                    overlap=overlap, fused=fused, with_stats=self.with_stats)
             else:
                 chunk = build_decode_chunk(self.engine.module,
                                            self.engine._dequant,
                                            self._slot_select, self.chunk_size,
-                                           overlap=overlap)
+                                           overlap=overlap,
+                                           with_stats=self.with_stats)
             fns[key] = jax.jit(chunk, donate_argnums=(2,))   # caches/pages
         return fns[key]
 
@@ -245,16 +270,18 @@ class ChunkedDecodeExecutor:
             engine = self.engine
             prefill_logits = build_prefill(engine.module, engine._dequant,
                                            overlap=getattr(engine,
-                                                           "comm_overlap", None))
+                                                           "comm_overlap", None),
+                                           with_stats=self.with_stats)
             select = self._slot_select
             cfg = engine.model_config
             cap, dtype = self.cap, engine.dtype
 
             def prefill(params, ids, len0, seed, base_key):
                 caches = init_cache(cfg, 1, cap, dtype=dtype)
-                logits, new_caches = prefill_logits(params, ids, caches, len0)
+                logits, new_caches, *stats = prefill_logits(params, ids, caches,
+                                                            len0)
                 tok0 = select(logits, base_key, seed, jnp.zeros_like(seed))
-                return tok0, new_caches
+                return (tok0, new_caches, *stats)
 
             fns[key] = jax.jit(prefill)
         return fns[key]
@@ -453,6 +480,11 @@ class ChunkedDecodeExecutor:
         t = prompt.shape[0]
         tracer = get_tracer()
         self.engine._activate()
+        if prefix_len and not self.kv_every_layer:
+            raise NotImplementedError(
+                "a prefix hit restores keys and values of every layer: a "
+                "recurrent state after the prefix was not kept, and a layer "
+                "without a cache has no rows to restore")
         if prefix_len:
             if not 0 < prefix_len < t:
                 raise ValueError(f"prefix_len must be in (0, prompt_len={t}), "
@@ -518,10 +550,16 @@ class ChunkedDecodeExecutor:
                 args = (self.engine.params, jnp.asarray(ids),
                         jnp.asarray([t], jnp.int32),
                         jnp.asarray([seed], jnp.int32), self._base_key)
-            tok0, one_caches = self._dispatch(fn, args, "prefill", bucket)
+            tok0, one_caches, *stats = self._dispatch(fn, args, "prefill",
+                                                      bucket)
             with tracer.span("serving.fetch", program="prefill"):
                 # lint: host-sync-ok (honest TTFT: first token synced on purpose)
                 tok0 = int(np.asarray(tok0)[0, 0])
+                # lint: host-sync-ok (the same program's output, ready with the token)
+                self.last_prefill_moe = np.asarray(stats[0]) if stats else None
+            if stats:
+                sp.set(moe_assignments=int(self.last_prefill_moe[0]),
+                       moe_experts_touched=int(self.last_prefill_moe[1]))
         with tracer.span("serving.scatter_prefill"):
             self.pool.scatter_prefill(slot, one_caches)
         obs_profiler.tick("prefill")
@@ -566,10 +604,11 @@ class ChunkedDecodeExecutor:
         self._warm_chunk = True
         obs_profiler.tick("decode_chunk")
         self.pool.caches = caches
-        buf, toks_d, lens_d, active_d, remaining_d, steps_d = host
+        buf, toks_d, lens_d, active_d, remaining_d, steps_d, *stats = host
         return ChunkResult(buf=buf, toks=toks_d, lens=lens_d, active=active_d,
                            remaining=remaining_d, steps=steps_d,
-                           elapsed=t1 - placed.t1)
+                           elapsed=t1 - placed.t1,
+                           moe=stats[0] if stats else None)
 
     def _timed(self, fn, args, program: str, fault: str, caches_at: int):
         """The region a chunk's deadline must cover, as a callable for

@@ -51,8 +51,8 @@ from ...observability.trace import get_tracer
 @functools.lru_cache(maxsize=None)
 def _slot_scatter_jit():
     def scatter(caches, one, slot):
-        return [{"k": c["k"].at[slot].set(o["k"][0]),
-                 "v": c["v"].at[slot].set(o["v"][0])}
+        # every array of a slot-row pool is slot-major, whatever its layer keeps
+        return [{key: c[key].at[slot].set(o[key][0]) for key in c}
                 for c, o in zip(caches, one)]
 
     return jax.jit(scatter, donate_argnums=(0,))
@@ -61,8 +61,7 @@ def _slot_scatter_jit():
 @functools.lru_cache(maxsize=None)
 def _slot_zero_jit():
     def zero_fill(caches, slot):
-        return [{"k": c["k"].at[slot].set(0.0),
-                 "v": c["v"].at[slot].set(0.0)} for c in caches]
+        return [{key: c[key].at[slot].set(0.0) for key in c} for c in caches]
 
     return jax.jit(zero_fill, donate_argnums=(0,))
 
@@ -159,8 +158,9 @@ class SlotKVPool:
         budgets BEFORE paying the device gather."""
         total = 0
         for c in self.caches:
-            _, hk, _, d = c["k"].shape
-            total += 2 * hk * int(rows) * d * c["k"].dtype.itemsize
+            if "k" in c:
+                _, hk, _, d = c["k"].shape
+                total += 2 * hk * int(rows) * d * c["k"].dtype.itemsize
         return total
 
     def restore_prefix(self, slot: int, slab: List[Dict[str, Any]]) -> None:
@@ -200,12 +200,17 @@ NULL_PAGE = 0      # reserved sentinel: pads every table row; rows it could
 # argument shapes, so one compiled mover serves every same-shaped pool.
 @functools.lru_cache(maxsize=None)
 def _paged_scatter_jit():
-    def scatter(caches, one, tbl):
+    def scatter(caches, one, tbl, slot):
         # write a prefill's dense batch-1 cache into the slot's pages; rows
-        # beyond cap pad with zeros into the (dead) null page
+        # beyond cap pad with zeros into the (dead) null page. A layer's
+        # per-slot state (no "k") is written whole into row ``slot``.
         mp = tbl.shape[0]
         out = []
         for c, o in zip(caches, one):
+            if "k" not in c:
+                out.append({key: c[key].at[slot].set(o[key][0].astype(c[key].dtype))
+                            for key in c})
+                continue
             _, hk, cap_r, d = o["k"].shape
             ps = c["k"].shape[2]
             pad = ((0, 0), (0, mp * ps - cap_r), (0, 0))
@@ -222,10 +227,20 @@ def _paged_scatter_jit():
 
 
 @functools.lru_cache(maxsize=None)
+def _state_zero_jit():
+    def zero_fill(caches, slot):
+        return [c if "k" in c else
+                {key: c[key].at[slot].set(0.0) for key in c} for c in caches]
+
+    return jax.jit(zero_fill, donate_argnums=(0,))
+
+
+@functools.lru_cache(maxsize=None)
 def _paged_cow_jit():
     def cow(caches, src, dst):
         return [{"k": c["k"].at[dst].set(c["k"][src]),
-                 "v": c["v"].at[dst].set(c["v"][src])} for c in caches]
+                 "v": c["v"].at[dst].set(c["v"][src])} if "k" in c else c
+                for c in caches]
 
     return jax.jit(cow, donate_argnums=(0,))
 
@@ -296,10 +311,15 @@ class PagedKVPool:
         self.n_layer = cfg.n_layer
         dtype = dtype or cfg.dtype
         shape = (P, cfg.kv_heads, ps, cfg.head_dim)
-        self.caches = [{"k": jnp.zeros(shape, dtype),
-                        "v": jnp.zeros(shape, dtype)}
-                       for _ in range(cfg.n_layer)]
-        self.page_nbytes = 2 * cfg.n_layer * cfg.kv_heads * ps * \
+        # two kinds of state in one manager: pages for the layers that keep
+        # keys and values, a per-slot array for the layers with a recurrent
+        # state (bound to the slot, not to pages: it does not grow with the
+        # sequence), nothing for the rest
+        self.caches = init_cache(cfg, self.slots, dtype=dtype, kv_shape=shape)
+        self.kv_layers = sum(1 for c in self.caches if "k" in c)
+        self.state_nbytes = sum(int(a.nbytes) for c in self.caches
+                                if "k" not in c for a in c.values())
+        self.page_nbytes = 2 * self.kv_layers * cfg.kv_heads * ps * \
             cfg.head_dim * jnp.dtype(dtype).itemsize
         # host allocator state
         self.page_table = np.full((self.slots, mp), NULL_PAGE, np.int32)
@@ -406,6 +426,10 @@ class PagedKVPool:
             self._slot_npages[slot] = 0
             self._slot_tokens[slot] = 0
             self._free_slots.append(slot)
+        if self.state_nbytes:
+            # a recurrent state has no length to mask it by: the slot's is
+            # cleared here (and written whole again at the next admission)
+            self.caches = _state_zero_jit()(self.caches, np.int32(slot))
 
     def _decref(self, page: int) -> None:
         if page == NULL_PAGE:
@@ -452,15 +476,17 @@ class PagedKVPool:
         """Write a prefill's dense batch-1 per-layer cache into the slot's
         pages (the miss-path sibling of the slot pool's row scatter)."""
         self.caches = self._scatter_fn(self.caches, one_caches,
-                                       jnp.asarray(self.page_table[slot]))
+                                       jnp.asarray(self.page_table[slot]),
+                                       np.int32(slot))
 
     # --------------------------------------------------------- slab I/O (wire)
     def slab_nbytes(self, rows: int) -> int:
         """Host-side size of a dense ``rows``-row slab (serialization API)."""
         total = 0
         for c in self.caches:
-            _, hk, _, d = c["k"].shape
-            total += 2 * hk * int(rows) * d * c["k"].dtype.itemsize
+            if "k" in c:
+                _, hk, _, d = c["k"].shape
+                total += 2 * hk * int(rows) * d * c["k"].dtype.itemsize
         return total
 
     def gather_prefix(self, slot: int, rows: int) -> List[Dict[str, Any]]:
@@ -589,4 +615,5 @@ class PagedKVPool:
             "cow_copies_total": float(self.cow_copies_total),
             "total_pages": float(self.total_pages - 1),
             "page_size": float(self.page_size),
+            "state_bytes": float(self.state_nbytes),
         }

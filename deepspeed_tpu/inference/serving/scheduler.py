@@ -198,6 +198,30 @@ class ContinuousBatchingScheduler:
             chunk_deadline_s=cfg.chunk_deadline_s, kv_pool=cfg.kv_pool,
             kv_page_size=cfg.kv_page_size, kv_total_pages=cfg.kv_total_pages)
         self.cap = cap
+        if not self.executor.kv_every_layer:
+            # both rest on "a sequence's state up to token n is the first n
+            # cache rows of every layer": a prefix hit restores rows, a
+            # rejected draft rewinds cache_len. A recurrent state is one array
+            # per slot that every token overwrites, so neither holds until the
+            # pool keeps state snapshots; and the movers index every layer's
+            # keys and values, which an expert layer does not keep.
+            if "M" in engine.model_config.layer_kinds:
+                why = ("the recurrent state of its state-space layers is "
+                       "overwritten by every token and was not kept (needs "
+                       "state snapshots)")
+            else:
+                why = ("its expert layers keep no keys and values, which the "
+                       "prefix and slab movers read from every layer")
+            if cfg.prefix_cache is not None and cfg.prefix_cache.enabled:
+                raise ValueError(
+                    "prefix_cache.enabled with this model: a prefix hit "
+                    f"restores every layer's keys and values, and {why}; "
+                    "set prefix_cache.enabled=False")
+            if cfg.speculate:
+                raise ValueError(
+                    "speculate with this model: a rejected draft rewinds "
+                    f"cache_len over every layer's keys and values, and {why}; "
+                    "set speculate=False")
         self.proposer = None
         self._spec_cfg: Optional[SpeculativeConfig] = None
         if cfg.speculate:
@@ -627,6 +651,7 @@ class ContinuousBatchingScheduler:
         handle.prefix_hit_tokens = prefix_len
         self._prefills_done += 1
         self._prefills_seen[slot] = self._prefills_done
+        self.telemetry.on_moe(self.executor.last_prefill_moe)
         self.telemetry.on_prefix(entry is not None,
                                  handle.prefix_hit_tokens,
                                  enabled=self.prefix_cache is not None)
@@ -726,6 +751,9 @@ class ContinuousBatchingScheduler:
                           if self._prefills_seen[slot] < self._prefills_done)
             span.set(tokens_kept=total, deliveries=len(delivered),
                      stalled_deliveries=stalled)
+            if res.moe is not None:
+                span.set(moe_assignments=int(res.moe[0]),
+                         moe_experts_touched=int(res.moe[1]))
         now = span.t1
         with tracer.span("serving.harvest") as harvest:
             chunk_t0 = now - res.elapsed
@@ -762,6 +790,7 @@ class ContinuousBatchingScheduler:
             harvest.set(finished=finished)
         self.telemetry.on_chunk(total, res.elapsed, slot_steps=slot_steps,
                                 deliveries=len(delivered), stalled=stalled)
+        self.telemetry.on_moe(res.moe)
         if spec:
             self.telemetry.on_spec(res.proposed, res.accepted, total,
                                    res.draft_s, res.elapsed)

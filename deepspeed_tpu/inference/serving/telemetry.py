@@ -19,7 +19,9 @@ parentheses):
   ``serving/prefix_promotions_total`` — per scheduler step, tiered prefix
   cache (host-RAM rung) enabled only;
 - ``serving/decode_slot_steps_total``, ``serving/decode_tokens_kept_total``,
-  ``serving/deliveries_total``, ``serving/deliveries_stalled_total`` — per
+  ``serving/deliveries_total``, ``serving/deliveries_stalled_total``,
+  ``serving/moe_assignments_total``, ``serving/moe_experts_touched_total``
+  (expert layers only), ``serving/ssm_state_bytes`` (state-space layers only) — per
   scheduler step: decode steps run against tokens a stream kept, and the
   deliveries a prefill of another request held up (the same counts ride the
   ``serving.decode_chunk`` span);
@@ -99,6 +101,9 @@ class ServingTelemetry:
         self.decode_slot_steps = 0
         self.deliveries = 0
         self.deliveries_stalled = 0
+        # expert layers (a model without them leaves these at 0 and unpublished)
+        self.moe_assignments = 0
+        self.moe_experts_touched = 0
         # prefix-cache counters (only advanced when the cache is enabled)
         self.prefix_enabled = False
         self.prefix_hits = 0
@@ -147,6 +152,14 @@ class ServingTelemetry:
                     float(paged_stats["prefix_shared_pages"]), self._tick),
                    ("serving/cow_copies_total",
                     float(paged_stats["cow_copies_total"]), self._tick)]
+            if paged_stats.get("state_bytes"):
+                ev += [("serving/ssm_state_bytes",
+                        float(paged_stats["state_bytes"]), self._tick)]
+        if self.moe_assignments:
+            ev += [("serving/moe_assignments_total",
+                    float(self.moe_assignments), self._tick),
+                   ("serving/moe_experts_touched_total",
+                    float(self.moe_experts_touched), self._tick)]
         if prefix_stats is not None:
             self._prefix_stats = prefix_stats
             # hit_rate here is ADMISSION-level (successful prefills), the same
@@ -182,6 +195,14 @@ class ServingTelemetry:
             self.prefix_hit_tokens += int(tokens)
         else:
             self.prefix_misses += 1
+
+    def on_moe(self, stats) -> None:
+        """``stats`` = (assignments on held experts, distinct held experts
+        read) of one compiled program, summed over its expert layers and
+        steps; None from a model without expert layers."""
+        if stats is not None:
+            self.moe_assignments += int(stats[0])
+            self.moe_experts_touched += int(stats[1])
 
     def on_chunk(self, tokens: int, elapsed: float, slot_steps: int = 0,
                  deliveries: int = 0, stalled: int = 0) -> None:

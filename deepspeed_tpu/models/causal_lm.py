@@ -15,7 +15,7 @@ Two execution paths:
 
 import dataclasses
 from functools import partial
-from typing import Any, Dict, Optional
+from typing import Any, Dict, Optional, Tuple
 
 import flax.linen as nn
 import jax
@@ -70,8 +70,42 @@ class CausalLMConfig:
     # "pallas" = gather-fused kernel (weights stream HBM→MXU once);
     # "xla" = w[idx] gather + einsum (lets XLA pin small expert stacks in VMEM)
     moe_decode_impl: str = "pallas"
+    # One MIXER a layer, chosen by a pattern string with a letter a layer
+    # ("M" Mamba-2, "*" attention, "E" latent mixture of experts); every
+    # layer is then ``x + mixer(norm(x))``. None = the classic layer
+    # (attention, then feed-forward). The sizes below are read only by the
+    # mixers the pattern names.
+    layer_pattern: Optional[str] = None
+    head_dim_override: Optional[int] = None  # attention head size where != n_embd / n_head
+    mamba_num_heads: int = 0
+    mamba_head_dim: int = 0
+    ssm_state_size: int = 0
+    ssm_n_groups: int = 1
+    conv_kernel: int = 4
+    ssm_chunk_size: int = 128
+    n_routed_experts: int = 0                # the router's width
+    experts_per_token: int = 0
+    moe_expert_width: int = 0
+    moe_shared_width: int = 0
+    moe_latent_size: int = 0
+    routed_scaling_factor: float = 1.0
+    norm_topk_prob: bool = True
+    # (first, count): the routed experts THIS program holds (expert
+    # parallelism's share); None = all of them
+    experts_held: Optional[Tuple[int, int]] = None
+    # RANDOM weights only (an engine that is given none): after the seeded
+    # init, level each expert layer's load on random tokens by its selection
+    # bias, as training leaves it (``moe/latent_moe.py: level_expert_load``)
+    level_random_experts: bool = False
+    # Rows a GREEDY ``InferenceEngine.generate`` decodes at, at least (the
+    # rows it adds hold nothing and are cut off). XLA rounds a row of a matmul
+    # differently by how many rows the matmul has, so a deployment that wants
+    # from ``generate`` the very tokens its serving chunk gives sets its slot
+    # count here. None = the batch's own rows
+    greedy_decode_rows: Optional[int] = None
 
     VALID_MOE_DECODE_IMPLS = ("pallas", "xla")
+    LAYER_KINDS = ("M", "*", "E")
 
     def __post_init__(self):
         # case-sensitive on purpose: 'XLA'/'Pallas'/'triton' must not silently
@@ -80,13 +114,47 @@ class CausalLMConfig:
             raise ValueError(
                 f"moe_decode_impl={self.moe_decode_impl!r} is not one of "
                 f"{self.VALID_MOE_DECODE_IMPLS}")
+        if self.layer_pattern is not None:
+            bad = sorted(set(self.layer_pattern) - set(self.LAYER_KINDS))
+            if bad or len(self.layer_pattern) != self.n_layer:
+                raise ValueError(
+                    f"layer_pattern={self.layer_pattern!r} must be {self.n_layer} "
+                    f"letters of {self.LAYER_KINDS}")
+            if self.experts_held is not None:
+                first, count = (int(v) for v in self.experts_held)
+                if not (0 <= first and count >= 1
+                        and first + count <= self.n_routed_experts):
+                    raise ValueError(
+                        f"experts_held={self.experts_held} is no share of "
+                        f"{self.n_routed_experts} experts")
+                self.experts_held = (first, count)
+
+    def layer_kind(self, i: int) -> str:
+        """``"A"`` for the classic layer (attention + feed-forward), else the
+        pattern's letter: what kind of state the layer keeps follows from it
+        (keys and values for "A" and "*", a recurrent state for "M", none
+        for "E")."""
+        return "A" if self.layer_pattern is None else self.layer_pattern[i]
+
+    @property
+    def layer_kinds(self) -> str:
+        return "".join(self.layer_kind(i) for i in range(self.n_layer))
+
+    @property
+    def held_experts(self) -> Tuple[int, int]:
+        return self.experts_held or (0, self.n_routed_experts)
+
+    @property
+    def conv_dim(self) -> int:
+        return (self.mamba_num_heads * self.mamba_head_dim
+                + 2 * self.ssm_n_groups * self.ssm_state_size)
 
     def is_moe_layer(self, i: int) -> bool:
         return self.num_experts > 0 and (i + 1) % self.moe_layer_interval == 0
 
     @property
     def head_dim(self) -> int:
-        return self.n_embd // self.n_head
+        return self.head_dim_override or self.n_embd // self.n_head
 
     @property
     def kv_heads(self) -> int:
@@ -98,6 +166,21 @@ class CausalLMConfig:
 
     def num_params(self) -> int:
         d, L, v = self.n_embd, self.n_layer, self.vocab_size
+        if self.layer_pattern is not None:
+            q = self.n_head * self.head_dim
+            inner = self.mamba_num_heads * self.mamba_head_dim
+            per = {
+                "*": d * q + 2 * d * self.kv_heads * self.head_dim + q * d,
+                "M": (d * (inner + self.conv_dim + self.mamba_num_heads)
+                      + (self.conv_kernel + 1) * self.conv_dim
+                      + 3 * self.mamba_num_heads + inner + inner * d),
+                "E": (d * self.n_routed_experts + self.n_routed_experts
+                      + 2 * d * self.moe_latent_size + 2 * d * self.moe_shared_width
+                      + self.held_experts[1] * 2 * self.moe_latent_size
+                      * self.moe_expert_width),
+            }
+            return (v * d + sum(per[k] + d for k in self.layer_pattern) + d
+                    + (0 if self.tie_word_embeddings else v * d))
         f = self.ffn_dim
         mlp = d * f * (3 if self.gated_mlp else 2)
         attn = d * d + 2 * d * self.kv_heads * self.head_dim + d * d
@@ -157,6 +240,39 @@ def llama_cfg(**kw) -> CausalLMConfig:
     kw.setdefault("ln_eps", 1e-6)
     kw.setdefault("name", "llama")
     return CausalLMConfig(**kw)
+
+
+def nemotron_h_cfg(*, hidden_size, hybrid_override_pattern, vocab_size,
+                   num_attention_heads, num_key_value_heads, head_dim,
+                   mamba_num_heads, mamba_head_dim, ssm_state_size, n_groups,
+                   conv_kernel, chunk_size, n_routed_experts, num_experts_per_tok,
+                   moe_intermediate_size, moe_shared_expert_intermediate_size,
+                   moe_latent_size, routed_scaling_factor, norm_topk_prob=True,
+                   layer_norm_epsilon=1e-5, experts_held=None, **kw) -> CausalLMConfig:
+    """Nemotron-H hybrids (``model_type: nemotron_h``): the keywords are the
+    published config's. One mixer a layer from ``hybrid_override_pattern``
+    ("M" Mamba-2, "*" attention with grouped keys and values, "E" latent
+    mixture of experts with a shared expert, squared ReLU), RMSNorm, no bias
+    but the convolution's, untied head. Set here and not published: no
+    position encoding in attention (the family uses none; ``rope_theta`` is
+    not read), the recurrent state in float32."""
+    kw.setdefault("name", "nemotron-h")
+    return CausalLMConfig(
+        n_embd=hidden_size, n_layer=len(hybrid_override_pattern),
+        layer_pattern=hybrid_override_pattern, vocab_size=vocab_size,
+        n_head=num_attention_heads, n_kv_head=num_key_value_heads,
+        head_dim_override=head_dim, pos_emb="none", layernorm="rmsnorm",
+        ln_eps=layer_norm_epsilon, qkv_bias=False, mlp_bias=False,
+        tie_word_embeddings=False, mamba_num_heads=mamba_num_heads,
+        mamba_head_dim=mamba_head_dim, ssm_state_size=ssm_state_size,
+        ssm_n_groups=n_groups, conv_kernel=conv_kernel, ssm_chunk_size=chunk_size,
+        n_routed_experts=n_routed_experts, experts_per_token=num_experts_per_tok,
+        moe_expert_width=moe_intermediate_size,
+        moe_shared_width=moe_shared_expert_intermediate_size,
+        moe_latent_size=moe_latent_size,
+        routed_scaling_factor=float(routed_scaling_factor),
+        norm_topk_prob=bool(norm_topk_prob),
+        experts_held=None if experts_held is None else tuple(experts_held), **kw)
 
 
 FAMILIES = {
@@ -470,8 +586,27 @@ class CausalLMLayer(nn.Module):
         cache; Pallas gather-by-page-index kernel on TPU).
         Returns (y, new_cache_kv or None)."""
         cfg = self.config
-        b, t, _ = x.shape
         h_in = _norm(cfg, "ln_attn")(x).astype(cfg.dtype)
+        attn_out, new_kv = self._attention(h_in, positions, cache, cache_len,
+                                           prefix_fill, page_table, kv_cap)
+
+        mlp = self._moe_mlp if self.is_moe else self._mlp
+        if cfg.parallel_residual:
+            h_mlp = _norm(cfg, "ln_mlp")(x).astype(cfg.dtype)
+            y = x + attn_out + mlp(h_mlp)
+        else:
+            x = x + attn_out
+            h_mlp = _norm(cfg, "ln_mlp")(x).astype(cfg.dtype)
+            y = x + mlp(h_mlp)
+        return y, new_kv
+
+    def _attention(self, h_in, positions, cache, cache_len, prefix_fill,
+                   page_table, kv_cap):
+        """Causal self-attention on the normed input, in whichever of the
+        four cache modes ``__call__`` describes; returns the projected
+        output and the layer's new keys and values (or None)."""
+        cfg = self.config
+        b, t, _ = h_in.shape
         q, k, v = self._attn_proj(h_in)
         if cfg.pos_emb == "rotary":
             q = apply_rotary(q, positions, cfg.rotary_base, cfg.rotary_pct)
@@ -535,21 +670,72 @@ class CausalLMLayer(nn.Module):
                 pad = ((0, 0), (0, 0), (0, T - t), (0, 0))
                 new_kv = {"k": jnp.pad(k_hm, pad).astype(cache["k"].dtype),
                           "v": jnp.pad(v_hm, pad).astype(cache["v"].dtype)}
-        o = o.reshape(b, t, cfg.n_embd)
+        o = o.reshape(b, t, cfg.n_head * cfg.head_dim)
         proj_init = nn.initializers.normal(cfg.init_std / (2 * cfg.n_layer) ** 0.5)
         attn_out = RowParallelDense(cfg.n_embd, use_bias=cfg.mlp_bias,
                                     dtype=cfg.dtype, kernel_init=proj_init,
                                     span="tp.o_proj", name="o_proj")(o)
+        return attn_out, new_kv
 
-        mlp = self._moe_mlp if self.is_moe else self._mlp
-        if cfg.parallel_residual:
-            h_mlp = _norm(cfg, "ln_mlp")(x).astype(cfg.dtype)
-            y = x + attn_out + mlp(h_mlp)
+
+class MixerLayer(CausalLMLayer):
+    """A layer of ONE mixer, ``x + mixer(norm(x))``; ``kind`` is the letter
+    of the configuration's pattern: "M" a Mamba-2 mixer (state ``{"conv",
+    "ssm"}``), "*" this module's attention (state ``{"k", "v"}``, every cache
+    mode of :class:`CausalLMLayer`), "E" a latent mixture of experts (no
+    state; its two counts are sown into the ``stats`` collection).
+    ``seq_lens`` (b,) are the real lengths of right-padded rows in a prefill:
+    a recurrence must not run over the padding that a causal mask forgives."""
+    kind: str = "*"
+
+    @nn.compact
+    def __call__(self, x, positions, cache: Optional[Dict] = None,
+                 cache_len: Optional[jnp.ndarray] = None,
+                 prefix_fill: bool = False, page_table=None,
+                 kv_cap: Optional[int] = None, seq_lens=None):
+        cfg = self.config
+        h = _norm(cfg, "norm")(x).astype(cfg.dtype)
+        out_std = cfg.init_std / (2 * cfg.n_layer) ** 0.5
+        if self.kind == "*":
+            out, new = self._attention(h, positions, cache, cache_len,
+                                       prefix_fill, page_table, kv_cap)
+        elif self.kind == "M":
+            if prefix_fill:
+                raise NotImplementedError(
+                    "a state-space layer cannot resume at a cache offset: its "
+                    "state after the prefix was not kept (no prefix hits, no "
+                    "speculative verify on a model with such layers)")
+            from .mamba2 import Mamba2Mixer
+            out, new = Mamba2Mixer(
+                d_model=cfg.n_embd, num_heads=cfg.mamba_num_heads,
+                head_dim=cfg.mamba_head_dim, state_size=cfg.ssm_state_size,
+                n_groups=cfg.ssm_n_groups, conv_kernel=cfg.conv_kernel,
+                chunk_size=cfg.ssm_chunk_size, eps=cfg.ln_eps, dtype=cfg.dtype,
+                init_std=cfg.init_std, out_std=out_std, name="mamba")(
+                    h, cache=cache, seq_lens=seq_lens)
         else:
-            x = x + attn_out
-            h_mlp = _norm(cfg, "ln_mlp")(x).astype(cfg.dtype)
-            y = x + mlp(h_mlp)
-        return y, new_kv
+            from ..moe.latent_moe import LatentMoE
+            valid = None
+            if seq_lens is not None and x.shape[1] > 1:
+                valid = jnp.arange(x.shape[1])[None, :] < seq_lens[:, None]
+            out, stats = LatentMoE(
+                d_model=cfg.n_embd, n_routed=cfg.n_routed_experts,
+                top_k=cfg.experts_per_token, expert_width=cfg.moe_expert_width,
+                shared_width=cfg.moe_shared_width, latent=cfg.moe_latent_size,
+                scale=cfg.routed_scaling_factor, norm_topk=cfg.norm_topk_prob,
+                experts_held=cfg.held_experts, dtype=cfg.dtype,
+                init_std=cfg.init_std, out_std=out_std, name="moe")(h, valid)
+            self.sow("stats", "moe_counts", stats)
+            new = None if cache is None else {}
+        return x + out.astype(x.dtype), new
+
+
+def make_layer(cfg: CausalLMConfig, i: int, **kw):
+    """The module of layer ``i``: the classic layer, or the mixer its letter names."""
+    kind = cfg.layer_kind(i)
+    if kind == "A":
+        return CausalLMLayer(cfg, is_moe=cfg.is_moe_layer(i), **kw)
+    return MixerLayer(cfg, kind=kind, **kw)
 
 
 def _bias_attention(q, k, v, slopes):
@@ -687,8 +873,11 @@ class CausalLM(nn.Module):
     @nn.compact
     def __call__(self, input_ids, positions=None, caches=None, cache_lens=None,
                  logits_positions=None, prefix_fill=False, page_table=None,
-                 kv_cap=None):
-        """``logits_positions`` (b,): compute the LM head ONLY at these sequence
+                 kv_cap=None, seq_lens=None):
+        """``seq_lens`` (b,): the real lengths of right-padded rows of a
+        prefill, for layers whose state a padded token would advance.
+
+        ``logits_positions`` (b,): compute the LM head ONLY at these sequence
         positions (serving prefill needs just each prompt's last valid token — for a
         250k vocab at t=512 this removes ~99.8% of the head matmul and the (b, t, V)
         fp32 logits buffer; reference parity: ds_inference reads final-token logits).
@@ -715,11 +904,12 @@ class CausalLM(nn.Module):
         new_caches = []
         for i in range(cfg.n_layer):
             layer_cache = None if caches is None else caches[i]
-            x, new_kv = CausalLMLayer(cfg, is_moe=cfg.is_moe_layer(i),
-                                      name=f"layers_{i}")(
+            # only a layer of one mixer is told the rows' real lengths
+            extra = {} if cfg.layer_kind(i) == "A" else {"seq_lens": seq_lens}
+            x, new_kv = make_layer(cfg, i, name=f"layers_{i}")(
                 x, positions, cache=layer_cache, cache_len=cache_lens,
                 prefix_fill=prefix_fill, page_table=page_table,
-                kv_cap=kv_cap)
+                kv_cap=kv_cap, **extra)
             new_caches.append(new_kv)
 
         x = _norm(cfg, "ln_f")(x)
@@ -807,21 +997,23 @@ def causal_lm_segments(cfg: CausalLMConfig, layers_per_group: int = 2):
     # same arg structure, so a 48-layer model compiles its interior group once, not 24×.
     _group_fns = {}
 
-    def _fns_for(flags):
+    def _fns_for(flags, first):
+        # ``flags``: per layer (kind, is_moe); a layer's module depends on
+        # nothing else, so ``first`` (a layer index of that signature) builds it
         if flags not in _group_fns:
             def group_init(rng, flags=flags):
                 x = jnp.zeros((1, 4, cfg.n_embd), cfg.dtype)
                 pos = jnp.zeros((1, 4), jnp.int32)
                 return tuple(
-                    CausalLMLayer(cfg, is_moe=moe).init(
+                    make_layer(cfg, first + j).init(
                         {"params": jax.random.fold_in(rng, j)}, x, pos)["params"]
-                    for j, moe in enumerate(flags))
+                    for j in range(len(flags)))
 
             def group_apply(p, x, batch, rng, flags=flags):
                 pos = _positions(batch["input_ids"])
-                for moe, layer_params in zip(flags, p):
-                    layer = CausalLMLayer(cfg, is_moe=moe)
-                    x, _ = layer.apply({"params": layer_params}, x, pos)
+                for j, layer_params in enumerate(p):
+                    x, _ = make_layer(cfg, first + j).apply(
+                        {"params": layer_params}, x, pos)
                 return x
 
             _group_fns[flags] = (group_init, group_apply)
@@ -830,8 +1022,9 @@ def causal_lm_segments(cfg: CausalLMConfig, layers_per_group: int = 2):
     for lo in range(0, cfg.n_layer, layers_per_group):
         hi = min(lo + layers_per_group, cfg.n_layer)
         keys = tuple(f"layers_{i}" for i in range(lo, hi))
-        flags = tuple(cfg.is_moe_layer(i) for i in range(lo, hi))
-        group_init, group_apply = _fns_for(flags)
+        flags = tuple((cfg.layer_kind(i), cfg.is_moe_layer(i))
+                      for i in range(lo, hi))
+        group_init, group_apply = _fns_for(flags, lo)
         segs.append(Segment(name=f"layers[{lo}:{hi}]", kind="mid", param_keys=keys,
                             init_keys=keys, init_fn=group_init,
                             apply_fn=group_apply))
@@ -910,14 +1103,35 @@ def causal_lm_model(cfg: CausalLMConfig, sample_seq_len: Optional[int] = None,
                  segments=causal_lm_segments(cfg, layers_per_group))
 
 
+def init_state_cache(cfg: CausalLMConfig, batch_size: int, dtype=None) -> Dict:
+    """A state-space layer's state for ``batch_size`` sequences: the last
+    ``conv_kernel - 1`` inputs of the convolution (serving type) and the
+    recurrent state (float32)."""
+    dtype = dtype or cfg.dtype
+    return {"conv": jnp.zeros((batch_size, cfg.conv_kernel - 1, cfg.conv_dim), dtype),
+            "ssm": jnp.zeros((batch_size, cfg.mamba_num_heads, cfg.mamba_head_dim,
+                              cfg.ssm_state_size), jnp.float32)}
+
+
 def init_cache(cfg: CausalLMConfig, batch_size: int, max_len: Optional[int] = None,
-               dtype=None):
-    """Fixed-capacity head-major KV caches, one per layer."""
+               dtype=None, kv_shape=None):
+    """One cache a layer, typed by the layer's kind: fixed-capacity head-major
+    keys and values for attention (``kv_shape`` where the caller lays them out
+    otherwise: the paged pool's pages), ``{"conv", "ssm"}`` of ``batch_size``
+    sequences for a state-space layer, an empty dict for a layer that keeps
+    nothing."""
     T = max_len or cfg.max_seq_len
     dtype = dtype or cfg.dtype
-    shape = (batch_size, cfg.kv_heads, T, cfg.head_dim)
-    return [{"k": jnp.zeros(shape, dtype), "v": jnp.zeros(shape, dtype)}
-            for _ in range(cfg.n_layer)]
+    shape = kv_shape or (batch_size, cfg.kv_heads, T, cfg.head_dim)
+    out = []
+    for kind in cfg.layer_kinds:
+        if kind == "M":
+            out.append(init_state_cache(cfg, batch_size, dtype))
+        elif kind == "E":
+            out.append({})
+        else:
+            out.append({"k": jnp.zeros(shape, dtype), "v": jnp.zeros(shape, dtype)})
+    return out
 
 
 def causal_lm_param_specs(params, tensor_axis: str = "tensor") -> Any:

@@ -71,6 +71,18 @@ TAGS: Dict[str, Tuple[str, str]] = {
     "serving/deliveries_stalled_total": (COUNTER, "deliveries held up by a "
                                                   "prefill of another request "
                                                   "since the stream's last one"),
+    # ------------------------------ expert and state-space layers (PR 27)
+    "serving/moe_assignments_total": (COUNTER, "(token, expert) assignments "
+                                               "that fell on experts this "
+                                               "program holds, over prefills "
+                                               "and decode chunks"),
+    "serving/moe_experts_touched_total": (COUNTER, "distinct held experts "
+                                                   "read, summed over expert "
+                                                   "layers and steps: the "
+                                                   "bytes the grouped expert "
+                                                   "kernel had to read"),
+    "serving/ssm_state_bytes": (GAUGE, "bytes of the per-slot recurrent "
+                                       "state of the state-space layers"),
     # ------------------------------------------------------------------ router
     "router/queue_depth": (GAUGE, "router admission queue depth per tick"),
     "router/retried_total": (COUNTER, "checkpointless retries (re-enqueues)"),
@@ -210,9 +222,10 @@ SPANS: Dict[str, Tuple[str, str, Tuple[str, ...], str]] = {
                                "attribution phase kv_restore (slab restore, "
                                "host-tier promote)"),
     "serving.prefill": (BOTH, "compiled steps",
-                        ("request_id", "bucket", "tokens", "prefix_len"),
+                        ("request_id", "bucket", "tokens", "prefix_len",
+                         "moe_assignments", "moe_experts_touched"),
                         "sched_admit_host_ms by bucket; attribution phase "
-                        "prefill"),
+                        "prefill; moe_experts_touched_per_step lines"),
     "serving.suffix_prefill": (BOTH, "compiled steps",
                                ("request_id", "bucket", "tokens",
                                 "prefix_len"),
@@ -226,9 +239,12 @@ SPANS: Dict[str, Tuple[str, str, Tuple[str, ...], str]] = {
     "serving.decode_chunk": (BOTH, "compiled steps",
                              ("chunk", "active_slots", "request_ids",
                               "slot_steps_run", "tokens_kept", "deliveries",
-                              "stalled_deliveries"),
+                              "stalled_deliveries", "moe_assignments",
+                              "moe_experts_touched"),
                              "decode_wasted_step_pct, delivery_stalled_pct, "
-                             "sched_fetch_idle_ms_per_step"),
+                             "sched_fetch_idle_ms_per_step, "
+                             "moe_experts_touched_per_step, "
+                             "moe_ffn_roofline_pct"),
     "serving.spec_verify": (BOTH, "compiled steps",
                             ("chunk", "active_slots", "request_ids",
                              "slot_steps_run", "tokens_kept", "deliveries",
@@ -289,7 +305,12 @@ SPANS: Dict[str, Tuple[str, str, Tuple[str, ...], str]] = {
                                     "setup_engine_init_s"),
     "setup.place_params": (PHASE, "device set-up", (),
                            "setup_engine_init_s lines"),
-    "setup.kv_pool": (PHASE, "device set-up", ("pool", "pages"),
+    "setup.balance_experts": (PHASE, "device set-up", (),
+                              "setup_engine_init_s lines (random weights of a "
+                              "configuration with level_random_experts: the "
+                              "selection bias levelled on random tokens)"),
+    "setup.kv_pool": (PHASE, "device set-up",
+                      ("pool", "pages", "slots", "state_bytes"),
                       "setup_engine_init_s"),
     "setup.program": (PHASE, "device set-up", ("program", "bucket"),
                       "setup_engine_init_s lines, beside setup_compile_s"),

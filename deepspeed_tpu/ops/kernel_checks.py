@@ -122,6 +122,32 @@ def check_moe_decode_ffn() -> float:
     return _err(o1, moe_decode_ffn_xla(x, idx, w1, b1, w2, b2, act))
 
 
+def check_moe_grouped_ffn(tokens: int = 32) -> float:
+    """The grouped expert kernel at the serving benchmark's shapes (128 held
+    experts of 512, 22 a token, latent 1024 -> 2688 -> 1024, bf16, squared
+    ReLU): 32 tokens is a decode step of 32 slots (tiles of 16 rows), 512 a
+    prompt bucket (tiles of 32). Against the ``jax.numpy`` form on the same
+    plan; rows of tiles past the last real one are compared too (zeros)."""
+    import jax
+    import jax.numpy as jnp
+    from ..moe.latent_moe import relu2
+    from .moe.grouped_ffn import (dispatch_plan, grouped_ffn, grouped_ffn_xla,
+                                  tile_rows)
+    rng = np.random.RandomState(6)
+    e, lat, f, k = 128, 1024, 2688, 22
+    idx = jnp.asarray(np.stack([rng.permutation(512)[:k] for _ in range(tokens)]),
+                      jnp.int32)
+    z = jnp.asarray(rng.standard_normal((tokens, lat)), jnp.bfloat16)
+    w1 = jax.random.normal(jax.random.PRNGKey(1), (e, lat, f), jnp.bfloat16) * lat ** -0.5
+    w2 = jax.random.normal(jax.random.PRNGKey(2), (e, f, lat), jnp.bfloat16) * f ** -0.5
+    tm = tile_rows(tokens * k)
+    plan = jax.jit(partial(dispatch_plan, first=0, count=e, tm=tm))(idx)
+    args = (z[plan["row_token"]], plan["tile_expert"], plan["tile_valid"], w1, w2)
+    got = jax.jit(partial(grouped_ffn, act=relu2, tm=tm))(*args)
+    want = jax.jit(partial(grouped_ffn_xla, act=relu2, tm=tm))(*args)
+    return _err(got, want)
+
+
 def _check_paged(hk: int) -> float:
     """Serving geometry: d_head 128, 16-token pages (``ServingConfig``'s
     default), ragged lengths incl. one mid-page and one exactly on a page
@@ -177,6 +203,11 @@ KERNEL_CHECKS: Dict[str, Tuple] = {
     "decode": (check_decode, 0.03),             # bf16
     "block_sparse": (check_block_sparse, 0.03),  # bf16
     "moe_decode_ffn": (check_moe_decode_ffn, 0.03),  # bf16
+    # bf16 operands, f32 accumulation and f32 out on both sides: the kernel
+    # and the reference make the same MXU products per row (the chip read 0.0,
+    # PR 27), so the tolerance is a bf16 step of an output near 4, not a model
+    "moe_grouped_ffn_decode": (check_moe_grouped_ffn, 0.03),
+    "moe_grouped_ffn_prefill": (partial(check_moe_grouped_ffn, tokens=512), 0.03),
     "paged_mha": (partial(_check_paged, hk=32), 0.03),   # bf16
     "paged_gqa": (partial(_check_paged, hk=8), 0.03),    # bf16
     # bf16 activations x dequantized weights, f32 accumulate; relative to

@@ -1,0 +1,147 @@
+"""Grouped expert feed-forward: every touched expert's weights read once.
+
+The expert layer lays the rows of its (token, expert) assignments out in
+expert order, in tiles of ``tm`` rows, each tile holding rows of ONE expert
+(:func:`dispatch_plan`; an expert's group is padded up to whole tiles). The
+kernel walks the tiles; the expert of a tile is scalar-prefetched and picks
+the weight block in the ``BlockSpec`` index maps, so consecutive tiles of one
+expert reuse the block already in VMEM and an expert nobody chose is never
+fetched. Per tile: ``act(x W1_e) W2_e``. Tiles beyond the last real one (the
+grid is sized for the worst case) point at the last real tile's expert — no
+fetch — and write zeros.
+
+``dispatch_plan`` also says where each assignment's row went, so the caller
+gathers its ``k`` rows back per token and weights them: no scatter-add.
+Prefill and decode use the same plan and the same kernel.
+"""
+
+import functools
+
+import jax
+import jax.numpy as jnp
+from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
+
+from ...utils.device import pallas_interpret as _interpret
+
+# 2 x (W1 + W2) blocks of an expert in flight plus the tile's activations
+VMEM_LIMIT_BYTES = 64 * 2 ** 20
+
+
+def tile_rows(assignments: int) -> int:
+    """Rows a tile: 16 (one bf16 sublane tile) while the step is bound by the
+    weights it reads, 32 once a prompt brings tens of rows an expert."""
+    return 16 if assignments <= 2048 else 32
+
+
+def dispatch_plan(idx, first: int, count: int, tm: int, valid=None):
+    """Lay the assignments that fall on experts ``[first, first + count)``
+    out in tiles of ``tm`` rows, one expert a tile.
+
+    ``idx`` (T, k) int32: the experts each token chose, over ALL experts;
+    ``valid`` (T,) bool: tokens that count (padding does not). Returns a dict:
+    ``row_token`` (R,) the token that feeds each row (0 where the row is
+    padding); ``pos`` (T, k) the row of each assignment, R where it is not
+    held here; ``held`` (T, k) bool; ``tile_expert`` / ``tile_valid`` (NT,)
+    int32; ``n_assigned`` and ``n_touched`` (scalars): assignments on held
+    experts and distinct held experts with at least one."""
+    T, k = idx.shape
+    A = T * k
+    NT = A // tm + min(count, A)
+    R = NT * tm
+    local = idx - first
+    held = (local >= 0) & (local < count)
+    if valid is not None:
+        held = held & valid[:, None]
+    # No sort and no scatter: both are slow on the chip beside a few dense
+    # comparisons of these sizes (A x A, A x count, R x A), which fuse.
+    key = jnp.where(held, local, count).reshape(A).astype(jnp.int32)
+    a = jnp.arange(A, dtype=jnp.int32)
+    experts = jnp.arange(count, dtype=jnp.int32)
+    on = key[:, None] == experts[None, :]                             # (A, count)
+    here = jnp.sum(on, axis=0, dtype=jnp.int32)
+    # rank of an assignment among the earlier ones of its expert
+    rank = jnp.sum((key[:, None] == key[None, :]) & (a[None, :] < a[:, None]),
+                   axis=1, dtype=jnp.int32)
+    padded = (here + tm - 1) // tm * tm
+    pad_end = jnp.sum(jnp.where(experts[None, :] <= experts[:, None],
+                                padded[None, :], 0), axis=1)          # cumsum
+    pad_start = pad_end - padded
+    dest = jnp.where(key < count,
+                     jnp.sum(jnp.where(on, pad_start[None, :], 0), axis=1) + rank,
+                     R).astype(jnp.int32)
+    rows = jnp.arange(R, dtype=jnp.int32)
+    row_token = jnp.sum(jnp.where(dest[None, :] == rows[:, None],
+                                  (a // k)[None, :], 0), axis=1, dtype=jnp.int32)
+    pos = dest.reshape(T, k)
+    n_tiles = pad_end[-1] // tm
+    tiles = jnp.arange(NT, dtype=jnp.int32)
+    tile_valid = (tiles < n_tiles).astype(jnp.int32)
+    # the expert whose padded range holds the tile's first row; tiles beyond
+    # the last real one repeat its expert
+    te = jnp.sum(pad_end[None, :] <= (jnp.minimum(tiles, n_tiles - 1) * tm)[:, None],
+                 axis=1, dtype=jnp.int32)
+    tile_expert = jnp.clip(te, 0, count - 1)
+    return {"row_token": row_token, "pos": pos, "held": held,
+            "tile_expert": tile_expert, "tile_valid": tile_valid,
+            "n_assigned": jnp.sum(here), "n_touched": jnp.sum(here > 0)}
+
+
+def grouped_ffn_xla(x_rows, tile_expert, tile_valid, w1, w2, act, tm: int):
+    """``jax.numpy`` form: gathers a copy of each tile's expert (so it reads
+    an expert once a TILE and writes the copy): what the kernel is checked
+    against, not what serves."""
+    NT = tile_expert.shape[0]
+    xt = x_rows.reshape(NT, tm, -1)
+    h = jnp.einsum("ntl,nlf->ntf", xt, w1[tile_expert],
+                   preferred_element_type=jnp.float32)
+    y = jnp.einsum("ntf,nfl->ntl", act(h).astype(w2.dtype), w2[tile_expert],
+                   preferred_element_type=jnp.float32)
+    y = jnp.where(tile_valid[:, None, None] == 1, y, 0.0)
+    return y.reshape(NT * tm, -1)
+
+
+def _kernel(te_ref, tv_ref, x_ref, w1_ref, w2_ref, o_ref, *, act):
+    i = pl.program_id(0)
+
+    @pl.when(tv_ref[i] == 1)
+    def _():
+        h = jnp.dot(x_ref[...], w1_ref[...], preferred_element_type=jnp.float32)
+        o_ref[...] = jnp.dot(act(h).astype(w2_ref.dtype), w2_ref[...],
+                             preferred_element_type=jnp.float32)
+
+    @pl.when(tv_ref[i] == 0)
+    def _():
+        o_ref[...] = jnp.zeros_like(o_ref)
+
+
+def grouped_ffn(x_rows, tile_expert, tile_valid, w1, w2, act, tm: int):
+    """``x_rows`` (NT * tm, l) rows in the plan's order; ``w1`` (e, l, f),
+    ``w2`` (e, f, l) the held experts. Returns (NT * tm, l) float32; rows of
+    padding hold whatever token 0 gives and are never gathered back. The
+    kernel's name in a trace is ``moe_grouped_ffn``."""
+    R, l = x_rows.shape
+    f = w1.shape[2]
+    NT = R // tm
+    if not _interpret() and (l % 128 or f % 128):
+        return grouped_ffn_xla(x_rows, tile_expert, tile_valid, w1, w2, act, tm)
+    grid_spec = pltpu.PrefetchScalarGridSpec(
+        num_scalar_prefetch=2,
+        grid=(NT,),
+        in_specs=[
+            pl.BlockSpec((tm, l), lambda i, te, tv: (i, 0)),
+            pl.BlockSpec((None, l, f), lambda i, te, tv: (te[i], 0, 0)),
+            pl.BlockSpec((None, f, l), lambda i, te, tv: (te[i], 0, 0)),
+        ],
+        out_specs=pl.BlockSpec((tm, l), lambda i, te, tv: (i, 0)),
+    )
+    return pl.pallas_call(
+        functools.partial(_kernel, act=act),
+        out_shape=jax.ShapeDtypeStruct((R, l), jnp.float32),
+        grid_spec=grid_spec,
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("arbitrary",),
+            vmem_limit_bytes=VMEM_LIMIT_BYTES),
+        name="moe_grouped_ffn",
+        interpret=_interpret(),
+    )(tile_expert, tile_valid, x_rows, w1, w2)

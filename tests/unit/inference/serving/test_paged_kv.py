@@ -251,6 +251,26 @@ def test_fused_chunk_path_runs_and_matches(engine, monkeypatch):
     np.testing.assert_array_equal(out[False], out[True])
 
 
+@pytest.mark.parametrize("pages,fused", [(None, False), (9, True)])
+def test_on_the_chip_the_kernel_is_taken_only_by_an_oversubscribed_pool(
+        engine, monkeypatch, pages, fused):
+    """Where the kernel is active without being forced (a TPU backend; here
+    ``fused_paged_active`` is patched), the chunk takes it only if the dense
+    view would need more rows than the pool holds: measured on the chip, the
+    dense view is the faster route at every page size (PERF.md, PR 27)."""
+    from deepspeed_tpu.ops import paged_attention as pa
+    monkeypatch.delenv(pa.FORCE_FUSED_ENV, raising=False)
+    monkeypatch.setattr(pa, "fused_paged_active", lambda: True)
+    over = {} if pages is None else dict(kv_total_pages=pages)
+    sched = _sched(engine, **over)
+    ex = sched.executor
+    assert (ex.slots * ex.cap > (ex.pool.total_pages - 1) * ex.pool.page_size) is fused
+    monkeypatch.setattr(engine, "_fns", {})
+    ex._chunk_fn()
+    (key,) = engine._fns
+    assert key[-1] is fused
+
+
 # --------------------------------------------------- end-to-end bit-exactness
 def test_hit_miss_parity_and_zero_copy(engine):
     """Greedy through the paged pool == generate, miss and (zero-copy) hit;
