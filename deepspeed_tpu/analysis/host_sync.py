@@ -13,9 +13,11 @@ Two complementary views:
   the comment block immediately above it, downgrades the call to an ``info``
   finding (it stays visible in the report) — the statement is the annotation
   unit, so a multi-line harvest tuple needs one marker, not one per line.
-  The documented cases: the executor's TTFT-honesty syncs and
-  chunk-boundary harvest, and the training engine's monitor-gated
-  ``Train/*`` event build.
+  The documented cases: the executor's TTFT-honesty syncs (one array a
+  prefill: the first token, with the expert counts behind it) and
+  chunk-boundary harvest (one fetch of one packed array a chunk; a
+  speculative round's copies are all started before the first is read), and
+  the training engine's monitor-gated ``Train/*`` event build.
 - **runtime half** (:func:`trace_sync_findings`): traces the function under
   ``jax.transfer_guard("disallow")`` — a concretization
   (``.item()``/``float()`` on a tracer) or an implicit device transfer

@@ -66,7 +66,7 @@ def serving_lane(report: Report) -> None:
     from ..inference.decode_fns import (build_decode_chunk, build_decode_loop,
                                         make_select_fn, make_slot_select_fn)
     from ..inference.engine import InferenceEngine
-    from ..inference.serving.executor import ChunkedDecodeExecutor
+    from ..inference.serving.executor import CTL_COLS, ChunkedDecodeExecutor
     from ..models.causal_lm import gpt2_cfg, init_cache
     from ..parallel.mesh import set_global_mesh
     from .donation import donation_findings
@@ -116,11 +116,8 @@ def serving_lane(report: Report) -> None:
     # donation: the real chunk fn + the pool's donated movers
     chunk_key = next(k for k in engine._fns if k[0] == "serve_chunk")
     S = ex.slots
-    chunk_args = (engine.params, jnp.zeros((S, 1), jnp.int32), ex.pool.caches,
-                  jnp.zeros((S,), jnp.int32), jnp.zeros((S,), bool),
-                  jnp.zeros((S,), jnp.int32), jnp.zeros((S,), jnp.int32),
-                  jnp.zeros((S,), jnp.int32), jnp.zeros((S,), jnp.int32),
-                  ex._base_key)
+    chunk_args = (engine.params, jnp.zeros((S, CTL_COLS), jnp.int32),
+                  ex.pool.caches, ex._base_key)
     report.add(donation_findings(engine._fns[chunk_key], chunk_args,
                                  target="serve_chunk"))
     one = init_cache(cfg, 1, _CAP, dtype=engine.dtype)
@@ -131,10 +128,8 @@ def serving_lane(report: Report) -> None:
                                  target="kv_pool.zero_fill"))
     # suffix prefill (prefix-cache hit path): donates the POOL through the jit
     sfn = ex._suffix_prefill_fn(8)
-    sargs = (engine.params, ex.pool.caches, np.int32(0),
-             jnp.zeros((1, 8), jnp.int32), jnp.asarray([4], jnp.int32),
-             jnp.asarray([4], jnp.int32), jnp.asarray([0], jnp.int32),
-             ex._base_key)
+    sargs = (engine.params, ex.pool.caches, jnp.zeros((1, 8), jnp.int32),
+             jnp.asarray([4, 4, 0, 0], jnp.int32), ex._base_key)
     report.add(donation_findings(sfn, sargs, target="serve_suffix_prefill"))
 
     # loop-invariance: dequant hoisted out of BOTH decode bodies (int8 engine)
@@ -189,7 +184,7 @@ def paged_lane(report: Report) -> None:
     import numpy as np
     from ..inference.config import DeepSpeedInferenceConfig
     from ..inference.engine import InferenceEngine
-    from ..inference.serving.executor import ChunkedDecodeExecutor
+    from ..inference.serving.executor import CTL_COLS, ChunkedDecodeExecutor
     from ..models.causal_lm import gpt2_cfg, init_cache
     from ..parallel.mesh import set_global_mesh
     from .donation import donation_findings
@@ -232,11 +227,8 @@ def paged_lane(report: Report) -> None:
 
     chunk_key = next(k for k in engine._fns if k[0] == "serve_chunk_paged")
     S, mp = ex.slots, ex.pool.max_pages
-    chunk_args = (engine.params, jnp.zeros((S, 1), jnp.int32), ex.pool.caches,
-                  jnp.zeros((S, mp), jnp.int32), jnp.zeros((S,), jnp.int32),
-                  jnp.zeros((S,), bool), jnp.zeros((S,), jnp.int32),
-                  jnp.zeros((S,), jnp.int32), jnp.zeros((S,), jnp.int32),
-                  jnp.zeros((S,), jnp.int32), ex._base_key)
+    chunk_args = (engine.params, jnp.zeros((S, CTL_COLS + mp), jnp.int32),
+                  ex.pool.caches, ex._base_key)
     report.add(donation_findings(engine._fns[chunk_key], chunk_args,
                                  target="serve_chunk_paged"))
     one = init_cache(cfg, 1, _CAP, dtype=engine.dtype)
@@ -245,10 +237,8 @@ def paged_lane(report: Report) -> None:
                                   jnp.zeros((mp,), jnp.int32)),
                                  target="paged_pool.scatter"))
     sfn = ex._suffix_prefill_fn_paged(8)
-    sargs = (engine.params, ex.pool.caches, jnp.zeros((mp,), jnp.int32),
-             jnp.zeros((1, 8), jnp.int32), jnp.asarray([4], jnp.int32),
-             jnp.asarray([4], jnp.int32), jnp.asarray([0], jnp.int32),
-             ex._base_key)
+    sargs = (engine.params, ex.pool.caches, jnp.zeros((1, 8), jnp.int32),
+             jnp.asarray([4, 4, 0] + [0] * mp, jnp.int32), ex._base_key)
     report.add(donation_findings(sfn, sargs,
                                  target="serve_suffix_prefill_paged"))
     set_global_mesh(None)

@@ -65,6 +65,27 @@ class ReplicaKilledError(RuntimeError):
     replica dying between prefix restore/bind and suffix prefill."""
 
 
+# One int32 operand in and one int32 result out a call: every small array is
+# a host-device crossing of its own with the chip idle, whatever its size. A
+# chunk's operand ``ctl`` is a row a slot, these columns and then the slot's
+# page-table row (paged pool); its result is a row a slot, the chunk's
+# emitted tokens and then ``OUT``'s columns, with the two expert counts at
+# the head of one more row where the model has expert layers. A prefill's
+# rank-1 ``ctl`` is ``len, seed``; a suffix prefill's is ``prefix_len,
+# suffix_len, seed`` and then the slot (slots pool) or the slot's page-table
+# row.
+CTL_TOK, CTL_LEN, CTL_ACTIVE, CTL_REMAINING, CTL_EOS, CTL_SEED, CTL_STEPS, \
+    CTL_COLS = range(8)
+OUT_TOK, OUT_LEN, OUT_ACTIVE, OUT_REMAINING, OUT_STEPS = range(5)
+PRE_COLS = 3
+
+
+def _suffix_ctl(ctl):
+    """A suffix prefill's operand apart: ``prefix_len, suffix_len, seed`` as
+    ``(1,)`` arrays and the tail that says where the slot's rows live."""
+    return ctl[0:1], ctl[1:2], ctl[2:3], ctl[PRE_COLS:]
+
+
 def prompt_buckets(max_prompt_len: int, smallest: int = 8) -> Tuple[int, ...]:
     """Power-of-two right-pad buckets covering ``[1, max_prompt_len]``."""
     buckets = []
@@ -78,7 +99,8 @@ def prompt_buckets(max_prompt_len: int, smallest: int = 8) -> Tuple[int, ...]:
 
 @dataclass
 class ChunkResult:
-    """Host view of one decode chunk (everything already fetched)."""
+    """Host view of one decode chunk: slices of the one packed array its
+    fetch brought back (a speculative round builds the fields on the host)."""
     buf: np.ndarray          # (S, K) emitted tokens; per-slot real prefix only
     toks: np.ndarray         # (S, 1) each slot's last token
     lens: np.ndarray         # (S,) KV append positions
@@ -86,7 +108,8 @@ class ChunkResult:
     remaining: np.ndarray    # (S,) decode budget left
     steps: np.ndarray        # (S,) per-request tokens emitted so far
     elapsed: float           # wall seconds for dispatch + fetch: from the end
-    #   of ``serving.place_inputs`` to the end of ``serving.fetch``
+    #   of ``serving.place_inputs`` to the end of ``serving.fetch`` (the
+    #   dispatch, the device's chunk and the one copy back)
     moe: Optional[np.ndarray] = None   # (assignments on held experts, distinct
     #   held experts read) over the chunk's steps and expert layers; None for
     #   a model without expert layers
@@ -260,8 +283,34 @@ class ChunkedDecodeExecutor:
                                            self._slot_select, self.chunk_size,
                                            overlap=overlap,
                                            with_stats=self.with_stats)
-            fns[key] = jax.jit(chunk, donate_argnums=(2,))   # caches/pages
+            fns[key] = jax.jit(self._packed_chunk(chunk),
+                               donate_argnums=(2,))          # caches/pages
         return fns[key]
+
+    def _packed_chunk(self, chunk):
+        """``chunk`` (a ``decode_fns`` chunk builder's function) behind the
+        packed operand and result the module's head describes; the name the
+        trace and the lowered module carry stays ``decode_chunk``."""
+        paged = self.paged
+
+        def decode_chunk(params, ctl, caches, base_key):
+            # the table is host state bound at admission; it never changes
+            # inside a chunk, so it rides in the operand's tail
+            where = (ctl[:, CTL_COLS:],) if paged else ()
+            buf, toks, caches, lens, active, remaining, steps, *stats = chunk(
+                params, ctl[:, CTL_TOK:CTL_TOK + 1], caches, *where,
+                ctl[:, CTL_LEN], ctl[:, CTL_ACTIVE] != 0,
+                ctl[:, CTL_REMAINING], ctl[:, CTL_EOS], ctl[:, CTL_SEED],
+                ctl[:, CTL_STEPS], base_key)
+            packed = jnp.concatenate(
+                [buf, toks, lens[:, None], active.astype(jnp.int32)[:, None],
+                 remaining[:, None], steps[:, None]], axis=1)
+            if stats:
+                counts = jnp.pad(stats[0], (0, packed.shape[1] - 2))
+                packed = jnp.concatenate([packed, counts[None]], axis=0)
+            return packed, caches
+
+        return decode_chunk
 
     def _prefill_fn(self, bucket: int):
         key = ("serve_prefill", bucket, self.cap, self.sampling)
@@ -276,12 +325,14 @@ class ChunkedDecodeExecutor:
             cfg = engine.model_config
             cap, dtype = self.cap, engine.dtype
 
-            def prefill(params, ids, len0, seed, base_key):
+            def prefill(params, ids, ctl, base_key):
                 caches = init_cache(cfg, 1, cap, dtype=dtype)
+                seed = ctl[1:2]
                 logits, new_caches, *stats = prefill_logits(params, ids, caches,
-                                                            len0)
+                                                            ctl[0:1])
                 tok0 = select(logits, base_key, seed, jnp.zeros_like(seed))
-                return (tok0, new_caches, *stats)
+                # the first token, and the expert layers' two counts behind it
+                return jnp.concatenate([tok0[0], *stats]), new_caches
 
             fns[key] = jax.jit(prefill)
         return fns[key]
@@ -302,8 +353,8 @@ class ChunkedDecodeExecutor:
                 overlap=getattr(engine, "comm_overlap", None))
             select = self._slot_select
 
-            def suffix_prefill(params, caches, slot, ids, prefix_len,
-                               suffix_len, seed, base_key):
+            def suffix_prefill(params, caches, ids, ctl, base_key):
+                prefix_len, suffix_len, seed, (slot,) = _suffix_ctl(ctl)
                 one = [{"k": jax.lax.dynamic_slice_in_dim(c["k"], slot, 1, 0),
                         "v": jax.lax.dynamic_slice_in_dim(c["v"], slot, 1, 0)}
                        for c in caches]
@@ -316,7 +367,7 @@ class ChunkedDecodeExecutor:
                      "v": jax.lax.dynamic_update_slice_in_dim(
                         c["v"], n["v"].astype(c["v"].dtype), slot, 0)}
                     for c, n in zip(caches, new_one)]
-                return tok0, caches
+                return tok0[0], caches
 
             fns[key] = jax.jit(suffix_prefill, donate_argnums=(1,))
         return fns[key]
@@ -342,8 +393,8 @@ class ChunkedDecodeExecutor:
             ps, mp = self.pool.page_size, self.pool.max_pages
             P_total = self.pool.total_pages
 
-            def suffix_prefill(params, caches, tbl, ids, prefix_len,
-                               suffix_len, seed, base_key):
+            def suffix_prefill(params, caches, ids, ctl, base_key):
+                prefix_len, suffix_len, seed, tbl = _suffix_ctl(ctl)
                 one = []
                 for c in caches:
                     _, hk, _, d = c["k"].shape
@@ -371,7 +422,7 @@ class ChunkedDecodeExecutor:
                         kv[key_] = c[key_].at[pidx, :, off, :].set(
                             vals.astype(c[key_].dtype))
                     out.append(kv)
-                return tok0, out
+                return tok0[0], out
 
             fns[key] = jax.jit(suffix_prefill, donate_argnums=(1,))
         return fns[key]
@@ -524,20 +575,20 @@ class ChunkedDecodeExecutor:
                              bucket=bucket, tokens=int(suffix.size),
                              prefix_len=int(prefix_len)) as sp:
                 with tracer.span("serving.place_inputs",
-                                 program="suffix_prefill"):
-                    where = jnp.asarray(self.pool.page_table[slot]) \
-                        if self.paged else np.int32(slot)
-                    args = (self.engine.params, self.pool.caches, where,
-                            jnp.asarray(ids),
-                            jnp.asarray([prefix_len], jnp.int32),
-                            jnp.asarray([suffix.size], jnp.int32),
-                            jnp.asarray([seed], jnp.int32), self._base_key)
-                tok0, caches = self._dispatch(fn, args, "suffix_prefill",
-                                              bucket)
+                                 program="suffix_prefill", arrays=2):
+                    where = self.pool.page_table[slot] if self.paged else slot
+                    ctl = np.empty(PRE_COLS + np.size(where), np.int32)
+                    ctl[:PRE_COLS] = prefix_len, suffix.size, seed
+                    ctl[PRE_COLS:] = where
+                    args = (self.engine.params, self.pool.caches,
+                            *jax.device_put((ids, ctl)), self._base_key)
+                out, caches = self._dispatch(fn, args, "suffix_prefill",
+                                             bucket)
                 self.pool.caches = caches
-                with tracer.span("serving.fetch", program="suffix_prefill"):
+                with tracer.span("serving.fetch", program="suffix_prefill",
+                                 arrays=1):
                     # lint: host-sync-ok (honest TTFT: first token synced on purpose)
-                    tok0 = int(np.asarray(tok0)[0, 0])
+                    tok0 = int(np.asarray(out)[0])
             obs_profiler.tick("prefill")
             return tok0, sp.t1
         bucket = self.bucket_for(t)
@@ -546,20 +597,23 @@ class ChunkedDecodeExecutor:
         fn = self._prefill_fn(bucket)
         with tracer.span("serving.prefill", request_id=request_id,
                          bucket=bucket, tokens=int(t), prefix_len=0) as sp:
-            with tracer.span("serving.place_inputs", program="prefill"):
-                args = (self.engine.params, jnp.asarray(ids),
-                        jnp.asarray([t], jnp.int32),
-                        jnp.asarray([seed], jnp.int32), self._base_key)
-            tok0, one_caches, *stats = self._dispatch(fn, args, "prefill",
-                                                      bucket)
-            with tracer.span("serving.fetch", program="prefill"):
-                # lint: host-sync-ok (honest TTFT: first token synced on purpose)
-                tok0 = int(np.asarray(tok0)[0, 0])
-                # lint: host-sync-ok (the same program's output, ready with the token)
-                self.last_prefill_moe = np.asarray(stats[0]) if stats else None
-            if stats:
-                sp.set(moe_assignments=int(self.last_prefill_moe[0]),
-                       moe_experts_touched=int(self.last_prefill_moe[1]))
+            with tracer.span("serving.place_inputs", program="prefill",
+                             arrays=2):
+                ctl = np.empty(2, np.int32)
+                ctl[:] = t, seed
+                args = (self.engine.params, *jax.device_put((ids, ctl)),
+                        self._base_key)
+            out, one_caches = self._dispatch(fn, args, "prefill", bucket)
+            with tracer.span("serving.fetch", program="prefill", arrays=1):
+                # lint: host-sync-ok (honest TTFT: first token synced on
+                # purpose; the expert counts ride in the same array)
+                out = np.asarray(out)
+            tok0 = int(out[0])
+            self.last_prefill_moe = None
+            if self.with_stats:
+                self.last_prefill_moe = out[1:]
+                sp.set(moe_assignments=int(out[1]),
+                       moe_experts_touched=int(out[2]))
         with tracer.span("serving.scatter_prefill"):
             self.pool.scatter_prefill(slot, one_caches)
         obs_profiler.tick("prefill")
@@ -583,41 +637,46 @@ class ChunkedDecodeExecutor:
         # wedged chunk and the caller rebuilds the pool, the late-finishing
         # thread must keep donating the OLD buffers, never the fresh pool's
         caches_in = self.pool.caches
-        with tracer.span("serving.place_inputs", program="decode_chunk") as placed:
-            state = (jnp.asarray(lens, jnp.int32), jnp.asarray(active, bool),
-                     jnp.asarray(remaining, jnp.int32),
-                     jnp.asarray(eos_ids, jnp.int32),
-                     jnp.asarray(seeds, jnp.int32),
-                     jnp.asarray(steps, jnp.int32), self._base_key)
+        S, K = self.slots, self.chunk_size
+        with tracer.span("serving.place_inputs", program="decode_chunk",
+                         arrays=1) as placed:
+            # a fresh array a call: the CPU client may alias a host buffer for
+            # the device array's life, and a chunk the watchdog abandoned
+            # still holds its operand
+            ctl = np.empty((S, CTL_COLS + (self.pool.max_pages if self.paged
+                                           else 0)), np.int32)
+            for col, host in ((CTL_TOK, np.reshape(toks, -1)), (CTL_LEN, lens),
+                              (CTL_ACTIVE, active), (CTL_REMAINING, remaining),
+                              (CTL_EOS, eos_ids), (CTL_SEED, seeds),
+                              (CTL_STEPS, steps)):
+                ctl[:, col] = host
             if self.paged:
-                # the table is host state bound at admission; it never changes
-                # inside a chunk, so it rides as a (tiny) per-dispatch operand
-                args = (self.engine.params,
-                        jnp.asarray(toks, jnp.int32).reshape(-1, 1), caches_in,
-                        jnp.asarray(self.pool.page_table)) + state
-            else:
-                args = (self.engine.params,
-                        jnp.asarray(toks, jnp.int32).reshape(-1, 1),
-                        caches_in) + state
-        host, caches, t1 = self._dispatch_watched(
-            self._timed(fn, args, "decode_chunk", "serving.chunk_compute", 2))
+                ctl[:, CTL_COLS:] = self.pool.page_table
+            args = (self.engine.params, jax.device_put(ctl), caches_in,
+                    self._base_key)
+        (packed,), caches, t1 = self._dispatch_watched(
+            self._timed(fn, args, "decode_chunk", "serving.chunk_compute"))
         self._warm_chunk = True
         obs_profiler.tick("decode_chunk")
         self.pool.caches = caches
-        buf, toks_d, lens_d, active_d, remaining_d, steps_d, *stats = host
-        return ChunkResult(buf=buf, toks=toks_d, lens=lens_d, active=active_d,
-                           remaining=remaining_d, steps=steps_d,
+        state = packed[:S, K:]
+        return ChunkResult(buf=packed[:S, :K],
+                           toks=state[:, OUT_TOK:OUT_TOK + 1],
+                           lens=state[:, OUT_LEN],
+                           active=state[:, OUT_ACTIVE] != 0,
+                           remaining=state[:, OUT_REMAINING],
+                           steps=state[:, OUT_STEPS],
                            elapsed=t1 - placed.t1,
-                           moe=stats[0] if stats else None)
+                           moe=packed[S, :2] if self.with_stats else None)
 
-    def _timed(self, fn, args, program: str, fault: str, caches_at: int):
+    def _timed(self, fn, args, program: str, fault: str):
         """The region a chunk's deadline must cover, as a callable for
         :meth:`_dispatch_watched`: injected stalls, compile + dispatch (hung
         compile), and host fetch (hung collective). ``fn`` returns a tuple
-        with the pool's caches at ``caches_at``; the callable returns ``(the
-        other outputs as host arrays, caches, stamp of the fetch's end)``. It
-        may run on the watchdog's worker thread, so its spans are handed the
-        caller's open span."""
+        that ends in the pool's caches; the callable returns ``(the other
+        outputs as host arrays, caches, stamp of the fetch's end)``: a chunk
+        has one other output, its packed result. It may run on the watchdog's
+        worker thread, so its spans are handed the caller's open span."""
         tracer = get_tracer()
         parent = tracer.current()
 
@@ -626,15 +685,20 @@ class ChunkedDecodeExecutor:
             if self._stall_next > 0:
                 stall, self._stall_next = self._stall_next, 0.0
                 time.sleep(stall)
-            out = self._dispatch(fn, args, program, self.chunk_size, parent)
-            with tracer.span("serving.fetch", parent=parent,
-                             program=program) as fetched:
+            *outs, caches = self._dispatch(fn, args, program,
+                                           self.chunk_size, parent)
+            with tracer.span("serving.fetch", parent=parent, program=program,
+                             arrays=len(outs)) as fetched:
+                for x in outs:
+                    # lint: host-sync-ok (starts the copy and waits for
+                    # nothing: every copy is under way before the first is
+                    # read, so several outputs cost one wait; a chunk has one)
+                    x.copy_to_host_async()
                 # lint: host-sync-ok (chunk-boundary harvest: the scheduler
                 # retires/admits between chunks and a verify round's accept
                 # rule needs the window logits; this fetch IS the boundary)
-                host = tuple(np.asarray(x) for i, x in enumerate(out)
-                             if i != caches_at)
-            return host, out[caches_at], fetched.t1
+                host = tuple(np.asarray(x) for x in outs)
+            return host, caches, fetched.t1
 
         return timed
 
@@ -680,10 +744,11 @@ class ChunkedDecodeExecutor:
             else:
                 args = (self.engine.params, jnp.asarray(ids), caches_in,
                         jnp.asarray(lens, jnp.int32))
+            placed.set(arrays=len(args) - 2)    # all but params and caches
         # the mid-verify chaos/injection seam: after the proposer built the
         # window, before/through the verify dispatch + logits fetch
         (logits,), caches, t1 = self._dispatch_watched(
-            self._timed(fn, args, "spec_verify", "serving.spec_verify", 1))
+            self._timed(fn, args, "spec_verify", "serving.spec_verify"))
         self._warm_chunk = True
         obs_profiler.tick("spec_verify")
         self.pool.caches = caches

@@ -251,12 +251,15 @@ SPANS: Dict[str, Tuple[str, str, Tuple[str, ...], str]] = {
                              "stalled_deliveries"),
                             "as serving.decode_chunk, for a speculative "
                             "verify round"),
-    "serving.place_inputs": (BOTH, "serve scheduler", ("program",),
+    # arrays: the device arrays that crossed the host-device boundary in the
+    # span (puts under place_inputs, copies back under fetch): 1 and 1 for a
+    # decode chunk, 2 and 1 for a prefill
+    "serving.place_inputs": (BOTH, "serve scheduler", ("program", "arrays"),
                              "sched_fetch_idle_ms_per_step and "
                              "sched_admit_host_ms lines"),
     "serving.dispatch": (BOTH, "serve scheduler", ("program",),
                          "sched_fetch_idle_ms_per_step lines"),
-    "serving.fetch": (BOTH, "serve scheduler", ("program",),
+    "serving.fetch": (BOTH, "serve scheduler", ("program", "arrays"),
                       "sched_fetch_idle_ms_per_step; sched_admit_host_ms "
                       "lines"),
     "serving.harvest": (BOTH, "serve scheduler", ("finished",),

@@ -1,0 +1,213 @@
+"""One packed operand in, one packed result out (PR 28): every program the
+scheduler calls takes its per-call control state as ONE host-built int32 array
+and hands back what the host reads as ONE int32 array.
+
+- the packed chunk gives token for token what the unpacked call gave (the
+  unpacked call is built here from ``decode_fns``' builders, which keep their
+  signatures, and placed and fetched array by array as ``run_chunk`` did);
+- the ``arrays`` attribute of ``serving.place_inputs`` / ``serving.fetch``
+  counts the crossings: 1 and 1 for a chunk, 2 and 1 for a prefill;
+- the lowered programs keep what the benchmark's readers find them by: their
+  names, and the padded prompt as ``@main``'s first int32 argument of rank 2.
+"""
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+import pytest
+
+import deepspeed_tpu as ds
+from deepspeed_tpu.inference.decode_fns import (build_decode_chunk,
+                                                build_paged_decode_chunk)
+from deepspeed_tpu.inference.engine import InferenceEngine
+from deepspeed_tpu.inference.serving import (ContinuousBatchingScheduler,
+                                             ServingConfig)
+from deepspeed_tpu.inference.serving import executor as ex_mod
+from deepspeed_tpu.inference.serving.executor import ChunkResult
+from deepspeed_tpu.inference.serving.prefix_cache import PrefixCacheConfig
+from deepspeed_tpu.models.causal_lm import gpt2_cfg
+from deepspeed_tpu.observability.trace import get_tracer
+from benchmarks.chipbench.probe import first_int_arg_shape
+from tests.unit import hybrid_tiny as ht
+
+pytestmark = pytest.mark.serving
+
+CAP, CHUNK, SLOTS = 64, 4, 2
+DENSE = dict(vocab_size=96, max_seq_len=CAP, n_embd=32, n_layer=2, n_head=4,
+             dtype=jnp.float32)
+
+
+@pytest.fixture(scope="module")
+def engines():
+    conf = ds.inference.DeepSpeedInferenceConfig(dtype="float32",
+                                                 max_out_tokens=CAP)
+    return {False: InferenceEngine(gpt2_cfg(**DENSE), conf),
+            True: InferenceEngine(ht.config(max_seq_len=CAP), conf, seed=3)}
+
+
+@pytest.fixture(autouse=True)
+def _fresh_tracer():
+    t = get_tracer()
+    t.disable()
+    t.reset()
+    yield t
+    t.disable()
+    t.reset()
+
+
+def _scheduler(engine, pool, sample, prefix=False):
+    sampling = dict(do_sample=True, temperature=0.9) if sample else {}
+    return ContinuousBatchingScheduler(engine, ServingConfig(
+        slots=SLOTS, chunk_size=CHUNK, max_seq_len=CAP, max_queue=8,
+        kv_pool=pool, kv_page_size=8,
+        prefix_cache=PrefixCacheConfig(enabled=prefix), **sampling))
+
+
+def _unpacked_run_chunk(ex):
+    """``run_chunk`` as it was before the packing: the builder's function
+    jitted as it stands, every operand placed and every output fetched as an
+    array of its own."""
+    build = build_paged_decode_chunk if ex.paged else build_decode_chunk
+    kw = dict(kv_cap=ex.cap) if ex.paged else {}
+    fn = jax.jit(build(ex.engine.module, ex.engine._dequant, ex._slot_select,
+                       ex.chunk_size, with_stats=ex.with_stats, **kw),
+                 donate_argnums=(2,))
+
+    def run_chunk(toks, lens, active, remaining, eos_ids, seeds, steps):
+        where = (jnp.asarray(ex.pool.page_table),) if ex.paged else ()
+        out = fn(ex.engine.params, jnp.asarray(toks, jnp.int32).reshape(-1, 1),
+                 ex.pool.caches, *where, jnp.asarray(lens, jnp.int32),
+                 jnp.asarray(active, bool), jnp.asarray(remaining, jnp.int32),
+                 jnp.asarray(eos_ids, jnp.int32), jnp.asarray(seeds, jnp.int32),
+                 jnp.asarray(steps, jnp.int32), ex._base_key)
+        ex.pool.caches = out[2]
+        buf, toks_d, lens_d, active_d, remaining_d, steps_d, *stats = (
+            np.asarray(x) for i, x in enumerate(out) if i != 2)
+        return ChunkResult(buf=buf, toks=toks_d, lens=lens_d, active=active_d,
+                           remaining=remaining_d, steps=steps_d, elapsed=0.0,
+                           moe=stats[0] if stats else None)
+
+    return run_chunk
+
+
+def _serve(sched, vocab):
+    rng = np.random.default_rng(5)
+    prompts = [rng.integers(1, vocab, size=n).astype(np.int32)
+               for n in (5, 13, 16, 9)]
+    # four requests through two slots, ending anywhere in a chunk; the second
+    # stops at an EOS it is sure to draw late or never (both are compared)
+    handles = [sched.submit(p, max_new_tokens=6 + 3 * i, seed=11 + i,
+                            eos_token_id=7 if i == 1 else None)
+               for i, p in enumerate(prompts)]
+    sched.run()
+    return handles
+
+
+@pytest.mark.parametrize("experts", [False, True], ids=["dense", "experts"])
+@pytest.mark.parametrize("sample", [False, True], ids=["greedy", "sampled"])
+@pytest.mark.parametrize("pool", ["paged", "slots"])
+def test_the_packed_chunk_gives_the_unpacked_calls_tokens(engines, pool, sample,
+                                                          experts):
+    engine = engines[experts]
+    vocab = engine.model_config.vocab_size
+    packed = _scheduler(engine, pool, sample)
+    chunks = []
+    run = packed.executor.run_chunk
+    packed.executor.run_chunk = lambda *a: chunks.append(run(*a)) or chunks[-1]
+    got = _serve(packed, vocab)
+    plain = _scheduler(engine, pool, sample)
+    plain.executor.run_chunk = _unpacked_run_chunk(plain.executor)
+    want = _serve(plain, vocab)
+    for g, w in zip(got, want):
+        assert g.state == w.state and g.finish_reason == w.finish_reason
+        assert list(g.tokens) == list(w.tokens)
+        assert len(g.tokens) >= 2
+    assert (packed.telemetry.moe_assignments, packed.telemetry.moe_experts_touched) \
+        == (plain.telemetry.moe_assignments, plain.telemetry.moe_experts_touched)
+    assert (packed.telemetry.moe_assignments > 0) is experts
+    # the harvest reads the fields it read before, with the types it read
+    res = chunks[0]
+    assert res.buf.shape == (SLOTS, CHUNK) and res.toks.shape == (SLOTS, 1)
+    assert res.active.dtype == np.bool_ and res.active.shape == (SLOTS,)
+    for field in (res.buf, res.toks, res.lens, res.remaining, res.steps):
+        assert field.dtype == np.int32
+    assert (res.moe is not None) is experts
+    if experts:
+        assert res.moe.shape == (2,) and res.moe.dtype == np.int32
+
+
+def _crossings(ring, program):
+    return {name: [s["attrs"]["arrays"] for s in ring
+                   if s["name"] == name and s["attrs"]["program"] == program]
+            for name in ("serving.place_inputs", "serving.fetch")}
+
+
+@pytest.mark.parametrize("experts", [False, True], ids=["dense", "experts"])
+def test_a_chunk_crosses_once_each_way_and_a_prefill_twice_in_once_out(
+        engines, experts):
+    tracer = get_tracer().enable()
+    _serve(_scheduler(engines[experts], "paged", False),
+           engines[experts].model_config.vocab_size)
+    ring = list(tracer.spans)
+    chunk, prefill = _crossings(ring, "decode_chunk"), _crossings(ring, "prefill")
+    assert len(chunk["serving.fetch"]) >= 4 and len(prefill["serving.fetch"]) == 4
+    assert set(chunk["serving.place_inputs"]) == {1}
+    assert set(chunk["serving.fetch"]) == {1}
+    assert set(prefill["serving.place_inputs"]) == {2}
+    assert set(prefill["serving.fetch"]) == {1}
+
+
+@pytest.mark.parametrize("pool", ["paged", "slots"])
+def test_a_prefix_hit_crosses_as_a_prefill_does_and_keeps_its_tokens(engines, pool):
+    """The suffix prefill's packed operand carries the page-table row (paged)
+    or the slot (slots pool, after the slab restore): two arrays in, one out,
+    and the tokens of a request served without the cache."""
+    tracer = get_tracer().enable()
+    shared = np.arange(1, 17, dtype=np.int32)          # two whole pages of 8
+    prompts = [np.concatenate([shared, np.asarray(tail, np.int32)])
+               for tail in ([40, 41, 42], [50, 51])]
+    tokens = {}
+    for prefix in (True, False):
+        sched = _scheduler(engines[False], pool, False, prefix=prefix)
+        handles = []
+        for p in prompts:
+            handles.append(sched.submit(p, max_new_tokens=5))
+            sched.run()
+        tokens[prefix] = [list(h.tokens) for h in handles]
+        if prefix:
+            assert handles[1].prefix_hit_tokens == 16
+    assert tokens[True] == tokens[False]
+    hit = _crossings(list(tracer.spans), "suffix_prefill")
+    assert hit == {"serving.place_inputs": [2], "serving.fetch": [1]}
+
+
+@pytest.mark.parametrize("pool", ["paged", "slots"])
+def test_the_lowered_programs_keep_their_names_and_the_prompt_leads(engines, pool):
+    """What the benchmark's readers find the programs by: ``jit_<name>`` in
+    the trace and the dump, and ``first_int_arg_shape`` = the prefill's
+    ``1 x bucket``: no other int32 operand of rank 2 or more before the
+    padded prompt."""
+    ex = _scheduler(engines[False], pool, False).executor
+    ids = jax.ShapeDtypeStruct((1, 16), jnp.int32)
+    vec = lambda n: jax.ShapeDtypeStruct((n,), jnp.int32)  # noqa: E731
+    table = ex.pool.max_pages if ex.paged else 0
+    params, key = ex.engine.params, ex._base_key
+    cases = {
+        "prefill": (ex._prefill_fn(16), (params, ids, vec(2), key)),
+        "suffix_prefill": (
+            ex._suffix_prefill_fn_paged(16) if ex.paged else ex._suffix_prefill_fn(16),
+            (params, ex.pool.caches, ids,
+             vec(ex_mod.PRE_COLS + (table or 1)), key)),
+        "decode_chunk": (
+            ex._chunk_fn(),
+            (params, jax.ShapeDtypeStruct((SLOTS, ex_mod.CTL_COLS + table),
+                                          jnp.int32), ex.pool.caches, key)),
+    }
+    for name, (fn, args) in cases.items():
+        text = fn.lower(*args).as_text()
+        assert f"module @jit_{name} " in text, name
+        shape = first_int_arg_shape(text)
+        if name == "decode_chunk":
+            assert shape == f"{SLOTS}x{ex_mod.CTL_COLS + table}"
+        else:
+            assert shape == "1x16", (name, shape)
