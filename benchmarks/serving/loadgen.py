@@ -548,7 +548,7 @@ def host_config(args):
                                       if args.prefix_cache
                                       and getattr(args, "prefix_tier_mb", 0.0)
                                       else None),
-                      kv_pool=args.kv_pool, kv_page_size=args.kv_page_size,
+                      kv_page_size=args.kv_page_size,
                       chunk_deadline_s=args.chunk_deadline)
 
 
@@ -1059,15 +1059,9 @@ def main(argv=None) -> int:
                          "(e.g. BENCH_PREFIX_r09.json)")
     ap.add_argument("--slots", type=int, default=4)
     ap.add_argument("--chunk-size", type=int, default=8)
-    ap.add_argument("--kv-pool", default="paged", choices=("paged", "slots"),
-                    help="KV memory shape: 'paged' (default) = page-table "
-                         "pool with page-count admission + zero-copy prefix "
-                         "sharing; 'slots' = legacy cap-row-per-slot pool")
     ap.add_argument("--kv-page-size", type=int, default=None,
-                    help="KV page size in tokens (paged pool; default 16). "
-                         "Must be a positive multiple of --chunk-size. With "
-                         "--bench-paged, overrides both lanes' pinned page "
-                         "size (the page-size-tradeoff sweep knob)")
+                    help="KV page size in tokens (default 16). "
+                         "Must be a positive multiple of --chunk-size")
     ap.add_argument("--max-queue", type=int, default=8)
     ap.add_argument("--min-prompt", type=int, default=4)
     ap.add_argument("--max-prompt", type=int, default=24)
@@ -1081,11 +1075,6 @@ def main(argv=None) -> int:
                     help="mixed-length generation budgets, same grammar as "
                          "--prompt-dist; default uniform "
                          "[--min-new, --max-new]")
-    ap.add_argument("--bench-paged", action="store_true",
-                    help="acceptance A/B: paged vs slot-row KV pool at EQUAL "
-                         "HBM budget on a mixed-length trace (sustained "
-                         "tok/s) + zero-copy vs scatter-restore prefix-hit "
-                         "TTFT; emits BENCH_PAGED JSON with gates")
     ap.add_argument("--bench-spec", action="store_true",
                     help="speculative-decoding acceptance A/B: spec-on vs "
                          "spec-off greedy lanes on a repetitive-suffix trace "
@@ -1197,14 +1186,12 @@ def main(argv=None) -> int:
                             if args.output_dist else None)
     except ValueError as e:
         ap.error(str(e))
-    if args.kv_page_size is not None and args.kv_pool == "paged" and (
+    if args.kv_page_size is not None and (
             args.kv_page_size < 1
-            or (not args.bench_paged
-                and args.kv_page_size % args.chunk_size != 0)):
-        # the bench pins its own per-lane chunk sizes and re-validates there
+            or args.kv_page_size % args.chunk_size != 0):
         ap.error(f"--kv-page-size {args.kv_page_size} must be a positive "
                  f"multiple of --chunk-size {args.chunk_size}")
-    if args.kv_page_size is None and not args.bench_paged:
+    if args.kv_page_size is None:
         args.kv_page_size = 16         # documented default
     # arrival-mode grammar: poisson | bursty | schedule[+bursty]:<windows>
     args.schedule_windows = None
@@ -1260,7 +1247,7 @@ def main(argv=None) -> int:
             ap.error(f"--max-seq-len {args.max_seq_len} too small for "
                      f"prefix({args.prefix_len}) + tail({args.max_prompt}) + "
                      f"new({args.max_new}); need >= {need}")
-    if (args.prompt_dist or args.output_dist) and not args.bench_paged:
+    if args.prompt_dist or args.output_dist:
         # nothing requires the second mode to be the longer one: a spec like
         # bimodal:64-96,4-8,0.3 is legal, so bound on the max of BOTH modes
         hi_p = (max(args.prompt_dist[1], args.prompt_dist[3])
@@ -1284,9 +1271,8 @@ def main(argv=None) -> int:
         # adopted router member); an explicit larger --replicas tops up with
         # locally-spawned socket children
         args.replicas = max(args.replicas, len(args.replica_endpoint))
-    if (args.host_replicas or args.replica_endpoint) \
-            and (args.bench_paged or args.obs_ab):
-        ap.error("--bench-paged/--obs-ab measure the single-scheduler hot "
+    if (args.host_replicas or args.replica_endpoint) and args.obs_ab:
+        ap.error("--obs-ab measures the single-scheduler hot "
                  "path; drop --host-replicas/--replica-endpoint")
     if args.autoscale and args.max_replicas < args.min_replicas:
         ap.error("--max-replicas must be >= --min-replicas")
@@ -1306,31 +1292,30 @@ def main(argv=None) -> int:
         monitor = MonitorMaster(MonitorConfig(jsonl_monitor={
             "enabled": True, "output_path": args.jsonl_metrics,
             "job_name": "loadgen"}))
-    if (args.bench_paged or args.bench_autoscale or args.bench_hosts
+    if (args.bench_autoscale or args.bench_hosts
             or args.bench_net or args.bench_spec or args.bench_kv_economy) \
             and (args.flight_out or args.trace_out):
         # these lanes dispatch before the tracer/flight wiring: refusing
         # beats silently writing no bundle the caller asked for
-        ap.error("--bench-paged/--bench-autoscale/--bench-hosts/--bench-net/"
+        ap.error("--bench-autoscale/--bench-hosts/--bench-net/"
                  "--bench-spec/--bench-kv-economy manage their own runs; "
                  "--trace-out/--flight-out are single-run options")
     if args.bench_net:
         # the bench pins its own geometry + fleets (stdio AND socket)
-        if args.bench_paged or args.bench_autoscale or args.obs_ab \
-                or args.bench_hosts:
+        if args.bench_autoscale or args.obs_ab or args.bench_hosts:
             ap.error("--bench-net is its own acceptance run; drop the "
                      "other bench flags")
         return _run_net_bench(args, monitor)
     if args.bench_hosts:
         # the bench pins its own geometry + arrival shape (self-calibrated)
-        if args.bench_paged or args.bench_autoscale or args.obs_ab:
+        if args.bench_autoscale or args.obs_ab:
             ap.error("--bench-hosts is its own acceptance run; drop the "
                      "other bench flags")
         return _run_hosts_bench(args, monitor)
     if args.bench_spec:
         # dispatched before serving_cfg: the bench pins its own geometry,
         # prompt trace (repetitive-suffix), and per-lane serving configs
-        if args.bench_paged or args.bench_autoscale or args.obs_ab:
+        if args.bench_autoscale or args.obs_ab:
             ap.error("--bench-spec is its own acceptance run; drop the "
                      "other bench flags")
         if args.replicas > 1 or args.chaos or args.autoscale:
@@ -1340,7 +1325,7 @@ def main(argv=None) -> int:
     if args.bench_kv_economy:
         # dispatched before serving_cfg: the bench pins its own geometry,
         # many-tenant trace, per-lane cache budgets and router configs
-        if args.bench_paged or args.bench_autoscale or args.obs_ab \
+        if args.bench_autoscale or args.obs_ab \
                 or args.bench_net or args.bench_hosts or args.bench_spec:
             ap.error("--bench-kv-economy is its own acceptance run; drop "
                      "the other bench flags")
@@ -1350,13 +1335,6 @@ def main(argv=None) -> int:
                      "chaos one); drop --replicas/--chaos/--autoscale/"
                      "--host-replicas/--replica-endpoint")
         return _run_kvecon_bench(args, monitor)
-    if args.bench_paged:
-        # dispatched before serving_cfg: the bench pins its own per-lane
-        # geometries (and --kv-page-size may be None = per-lane default here)
-        if args.replicas > 1 or args.chaos or args.autoscale:
-            ap.error("--bench-paged measures the single-scheduler pool A/B; "
-                     "drop --replicas/--chaos/--autoscale")
-        return _run_paged_bench(args, monitor)
     prefix_cfg = None
     if args.prefix_cache:
         from deepspeed_tpu.inference.serving import PrefixCacheConfig
@@ -1369,8 +1347,7 @@ def main(argv=None) -> int:
     serving_cfg = ServingConfig(
         slots=args.slots, chunk_size=args.chunk_size, max_queue=args.max_queue,
         max_seq_len=args.max_seq_len, chunk_deadline_s=args.chunk_deadline,
-        prefix_cache=prefix_cfg, kv_pool=args.kv_pool,
-        kv_page_size=args.kv_page_size)
+        prefix_cache=prefix_cfg, kv_page_size=args.kv_page_size)
     if args.obs_ab:
         if args.replicas > 1 or args.chaos:
             ap.error("--obs-ab measures the single-scheduler hot path; "
@@ -1546,7 +1523,7 @@ def _run_net_bench(args, monitor) -> int:
     args.min_new, args.max_new = (8, 14) if smoke else (16, 24)
     args.max_queue = 64
     args.restart_backoff = 0.3
-    args.kv_pool, args.kv_page_size = "paged", None
+    args.kv_page_size = None
     args.chunk_deadline = None
     args.smoke = True     # _build_router: hosted-loose health thresholds
 
@@ -2229,7 +2206,7 @@ def _run_spec_bench(args, monitor) -> int:
     def cfg_for(speculate):
         return ServingConfig(slots=geom["slots"], chunk_size=geom["chunk"],
                              max_queue=256, max_seq_len=geom["cap"],
-                             kv_pool="paged", kv_page_size=geom["page"],
+                             kv_page_size=geom["page"],
                              speculate=speculate, spec_k=geom["k"])
 
     def lane(speculate, record):
@@ -2401,7 +2378,7 @@ def _run_kvecon_bench(args, monitor) -> int:
     def scfg(device_bytes):
         return ServingConfig(slots=geom["slots"], chunk_size=geom["chunk"],
                              max_queue=256, max_seq_len=geom["cap"],
-                             kv_pool="paged", kv_page_size=geom["page"],
+                             kv_page_size=geom["page"],
                              prefix_cache=pcfg(device_bytes))
 
     roomy = int(geom["device_mb"] * 2**20)       # holds every pool prefix
@@ -2541,261 +2518,6 @@ def _run_kvecon_bench(args, monitor) -> int:
            "detail": {"single": rec["single"], "affinity": rec["affinity"],
                       "aware": rec["aware"], "promote": rec["promote"],
                       "chaos": chaos_snap}}
-    if args.out:
-        with open(args.out, "w") as f:
-            json.dump(out, f, indent=1)
-    print(json.dumps(out))
-    return 0 if ok else 1
-
-
-def _run_paged_bench(args, monitor) -> int:
-    """Paged-KV acceptance A/B (``BENCH_PAGED`` JSON).
-
-    Two interleaved lanes, both greedy with EVERY request parity-checked
-    against per-request ``generate``:
-
-    - **throughput at equal HBM budget** — a mixed short/long trace
-      (``--prompt-dist``/``--output-dist`` bimodal mix) replayed saturated
-      over (a) the slot-row pool at ``--slots`` slots × cap rows, and (b) the
-      paged pool holding the SAME KV bytes (``kv_total_pages`` pinned to the
-      slot lane's pages) but a 3× larger compiled slot-batch — pages let the
-      short requests stop reserving the worst case, so more of the mix
-      decodes concurrently. Gate: sustained tok/s (wall-clock, prefills
-      included) >= 1.5x;
-    - **prefix-hit TTFT** — a shared-prefix trace over both pools with the
-      prefix cache on, unsaturated (TTFT must measure the hit path, not
-      queue wait). The paged hit binds page indices (zero-copy + one COW
-      page); the slot hit pays PR 9's slab restore scatter. Gate: paged hit
-      TTFT p50 <= the scatter-based hit's.
-
-    Lanes are order-interleaved (slots, paged, paged, slots, ...) and the
-    gates compare medians across reps, so machine drift cancels. The two
-    lane families run on DIFFERENT engine geometries on purpose — each is
-    pinned to the regime where its mechanism is CPU-measurable:
-
-    - tput lanes: tiny model, small chunks — per-chunk cost is then flat in
-      the slot-batch size (dispatch-bound, the CPU stand-in for a
-      decode-bandwidth-bound chip), so sustained tok/s tracks CONCURRENCY,
-      which is exactly what page-granular admission multiplies. On a large
-      CPU model the XLA dense-gather fallback's per-chunk traffic scales
-      with slots x cap and eats the win — on a chip the Pallas kernel
-      gathers only live pages, so that dilution is a fallback artifact
-      (ROADMAP carried item);
-    - hit lanes: mid model, long page-aligned prefix — the slab the slot
-      pool must restore-scatter on every hit is then real bytes, which is
-      the cost the zero-copy bind deletes.
-    """
-    import copy
-    import math
-    from deepspeed_tpu.inference.serving import (ContinuousBatchingScheduler,
-                                                 PrefixCacheConfig,
-                                                 ServingConfig)
-    slot_mult = 5                       # paged lane's slot-batch multiplier
-    if args.smoke:
-        tput_geom = dict(vocab_size=96, max_seq_len=64, n_embd=32, n_layer=2,
-                         n_head=4, cap=64, slots=2, chunk=4, page=8,
-                         requests=max(args.requests, 40))
-        # smoke: ONE tiny engine for both lane families (runtime budget);
-        # the hit prefix is page-ALIGNED (16 % 8 == 0, the shared-system-
-        # prompt shape) so the lane measures bind-vs-restore without COW
-        hit_geom = dict(tput_geom, prefix=16, requests=16, rate=30.0)
-        reps = 2
-    else:
-        # cap = the deployment's supported max: the slot-row lane reserves
-        # it per slot even though the mixed trace's longest request is ~45
-        # tokens — exactly the worst-case-reservation waste pages remove
-        tput_geom = dict(vocab_size=96, max_seq_len=96, n_embd=32, n_layer=2,
-                         n_head=4, cap=96, slots=2, chunk=2, page=8,
-                         requests=max(args.requests, 96))
-        hit_geom = dict(vocab_size=512, max_seq_len=256, n_embd=128,
-                        n_layer=4, n_head=4, cap=192, slots=4, chunk=8,
-                        page=16, prefix=96, requests=24, rate=20.0)
-        reps = 3
-    if args.prompt_dist is None:
-        args.prompt_dist = parse_dist("bimodal:3-6,18-26,0.3")
-    if args.output_dist is None:
-        # decode-weighted: the occupancy win shows on chunk-bound time;
-        # 2-token outputs would be prefill-overhead-bound on both pools
-        args.output_dist = parse_dist("bimodal:6-10,14-18,0.3")
-    # user dists drive ONLY the tput lane (the hit lane pins its own short
-    # tails); an over-cap long mode must refuse upfront, not crash mid-lane
-    hi_p = max(args.prompt_dist[1], args.prompt_dist[3])
-    hi_n = max(args.output_dist[1], args.output_dist[3])
-    if hi_p + hi_n > tput_geom["cap"]:
-        raise SystemExit(
-            f"--prompt-dist/--output-dist long modes ({hi_p}+{hi_n} tokens) "
-            f"exceed the tput lane's pinned cap {tput_geom['cap']}")
-    if args.kv_page_size is not None:
-        # explicit page-size sweep (the ROADMAP tradeoff knob): override the
-        # pinned geometries rather than silently ignoring the flag
-        for geom in (tput_geom, hit_geom):
-            if args.kv_page_size % geom["chunk"] != 0:
-                raise SystemExit(
-                    f"--kv-page-size {args.kv_page_size} is not a multiple "
-                    f"of the bench's chunk size {geom['chunk']}")
-            geom["page"] = args.kv_page_size
-
-    def mk_engine(geom):
-        a = copy.copy(args)
-        for k in ("vocab_size", "max_seq_len", "n_embd", "n_layer", "n_head"):
-            setattr(a, k, geom[k])
-        a.max_seq_len = max(a.max_seq_len, geom["cap"])
-        return a, build_engine(a)
-
-    def cfg_for(geom, kind, prefix=False, slots=None):
-        prefix_cfg = PrefixCacheConfig(
-            min_hit_tokens=8, min_insert_tokens=8,
-            insert_on="prefill") if prefix else None
-        pages_per_slot = math.ceil(geom["cap"] / geom["page"])
-        equal_pages = geom["slots"] * pages_per_slot + 1    # +1 = null page
-        return ServingConfig(
-            slots=(slots if slots is not None
-                   else (geom["slots"] * slot_mult if kind == "paged"
-                         else geom["slots"])),
-            chunk_size=geom["chunk"], max_queue=256,
-            max_seq_len=geom["cap"], prefix_cache=prefix_cfg, kv_pool=kind,
-            kv_page_size=geom["page"],
-            kv_total_pages=(equal_pages if kind == "paged" else None))
-
-    def kv_bytes(front):
-        pool = front.executor.pool
-        if pool.paged:
-            return pool.total_pages * pool.page_nbytes
-        return pool.slots * pool.slab_nbytes(pool.cap)
-
-    tput_args, tput_engine = mk_engine(tput_geom)
-    if args.smoke:
-        hit_args, hit_engine = tput_args, tput_engine
-    else:
-        hit_args, hit_engine = mk_engine(hit_geom)
-
-    def tput_lane(kind, record):
-        a = copy.copy(tput_args)
-        a.rate, a.verify_parity = 1000.0, True      # saturate: sustained rate
-        a.requests = tput_geom["requests"]
-        a.prefix_pool, a.prefix_cache = 0, False
-        a.max_queue = 256
-        front = ContinuousBatchingScheduler(tput_engine,
-                                            cfg_for(tput_geom, kind))
-        snap = run_load(front, a)
-        snap["kv_bytes"] = kv_bytes(front)
-        snap["slots"] = front.config.slots
-        snap["sustained_tok_s"] = (snap["tokens_total"] / snap["wall_s"]
-                                   if snap["wall_s"] > 0 else 0.0)
-        if record is not None:
-            record.append(snap)
-        return snap
-
-    def hit_lane(kind, record):
-        a = copy.copy(hit_args)
-        a.prefix_pool, a.prefix_cache, a.prefix_min_hit = 2, True, 8
-        a.prefix_len = hit_geom["prefix"]
-        # UNSATURATED and at the SAME slot count on both pools: hit TTFT must
-        # compare the hit PATH (zero-copy bind vs slab-restore scatter, then
-        # the same suffix prefill) — queue-wait under saturation or different
-        # batch geometry would swamp the restore cost being measured
-        a.rate = hit_geom["rate"]
-        a.requests = hit_geom["requests"]
-        a.max_queue = 256
-        a.verify_parity = True
-        # short tails only: one suffix bucket on both pools
-        a.prompt_dist = parse_dist("bimodal:3-6,3-6,0.0")
-        a.output_dist = parse_dist("bimodal:2-4,2-4,0.0")
-        front = ContinuousBatchingScheduler(
-            hit_engine, cfg_for(hit_geom, kind, prefix=True,
-                                slots=hit_geom["slots"]))
-        snap = run_load(front, a)
-        if record is not None:
-            record.append(snap)
-        return snap
-
-    print("[bench-paged] warming both pools' compiles...", file=sys.stderr)
-    tput_lane("slots", None)
-    tput_lane("paged", None)
-    hit_lane("slots", None)
-    hit_lane("paged", None)
-    tput = {"slots": [], "paged": []}
-    hits = {"slots": [], "paged": []}
-    for rep in range(reps):
-        order = (("slots", "paged") if rep % 2 == 0 else ("paged", "slots"))
-        for kind in order:
-            print(f"[bench-paged] tput lane {kind} rep {rep}...",
-                  file=sys.stderr)
-            tput_lane(kind, tput[kind])
-        for kind in order:
-            print(f"[bench-paged] prefix-hit lane {kind} rep {rep}...",
-                  file=sys.stderr)
-            hit_lane(kind, hits[kind])
-
-    def med(snaps, key):
-        return _med_notnull(s.get(key) for s in snaps)
-
-    tok_slots = med(tput["slots"], "sustained_tok_s")
-    tok_paged = med(tput["paged"], "sustained_tok_s")
-    ratio = (tok_paged / tok_slots if tok_slots else None)
-    hit_slots = _med_notnull(s["prefix_trace"]["ttft_hit_ms_p50"]
-                             for s in hits["slots"])
-    hit_paged = _med_notnull(s["prefix_trace"]["ttft_hit_ms_p50"]
-                             for s in hits["paged"])
-    parity_all = all(
-        s.get("parity_ok", False) and s.get("full_parity_bad", 1) == 0
-        for rec in (tput["slots"], tput["paged"], hits["slots"],
-                    hits["paged"])
-        for s in rec)
-    lost_all = all(
-        s.get("lost", 1) == 0 and s.get("all_finished", False)
-        for rec in (tput["slots"], tput["paged"], hits["slots"],
-                    hits["paged"])
-        for s in rec)
-    bytes_slots = tput["slots"][0]["kv_bytes"]
-    bytes_paged = tput["paged"][0]["kv_bytes"]
-    # smoke thresholds: at toy scale (n_embd 32, 2 layers) both effects
-    # compress into sub-ms dispatch overheads — the tiny-model forward is so
-    # cheap that per-dispatch fixed costs mask the occupancy and restore-copy
-    # deltas the full-size artifact (BENCH_PAGED_r13.json) gates strictly.
-    # The smoke still requires the ratio to favor paged and every request to
-    # be bit-exact with lost == 0.
-    ratio_gate = 1.15 if args.smoke else 1.5
-    hit_tol = 1.5 if args.smoke else 1.0
-    gates = {
-        "sustained_tok_s_slots": tok_slots,
-        "sustained_tok_s_paged": tok_paged,
-        "throughput_ratio": ratio,
-        "throughput_ratio_gate": ratio_gate,
-        "throughput_ok": bool(ratio is not None and ratio >= ratio_gate),
-        # equal HBM: the paged lane holds the slot lane's KV bytes + one null
-        # page + cap-to-page rounding (never more than one page per slot)
-        "kv_bytes_slots": bytes_slots,
-        "kv_bytes_paged": bytes_paged,
-        "equal_hbm_budget": bool(bytes_paged <= bytes_slots
-                                 + (tput_geom["slots"] + 1) * bytes_paged
-                                 // max(1, tput_geom["slots"] * math.ceil(
-                                     tput_geom["cap"] / tput_geom["page"])
-                                     + 1)),
-        "hit_ttft_ms_p50_slots": hit_slots,
-        "hit_ttft_ms_p50_paged": hit_paged,
-        "hit_ttft_tolerance": hit_tol,
-        "hit_ttft_paged_le_scatter": bool(
-            hit_paged is not None and hit_slots is not None
-            and hit_paged <= hit_slots * hit_tol),
-        "parity_ok_every_request": parity_all,
-        "lost_zero_all_lanes": lost_all,
-    }
-    ok = all(bool(gates[k]) for k in
-             ("throughput_ok", "equal_hbm_budget",
-              "hit_ttft_paged_le_scatter", "parity_ok_every_request",
-              "lost_zero_all_lanes"))
-    out = {"metric": "paged_vs_slots_tok_s_ratio", "value": ratio,
-           "unit": "x", "smoke": bool(args.smoke),
-           "prompt_dist": "bimodal:%d-%d,%d-%d,%.2f" % args.prompt_dist,
-           "output_dist": "bimodal:%d-%d,%d-%d,%.2f" % args.output_dist,
-           "kv_page_size": tput_geom["page"],
-           "geometry": {"tput": tput_geom, "hit": hit_geom},
-           "slots": {"slots": tput_geom["slots"],
-                     "paged": tput_geom["slots"] * slot_mult},
-           "paged_gates": gates, "gates_ok": ok,
-           "detail": {"tput_slots": tput["slots"], "tput_paged": tput["paged"],
-                      "hit_slots": hits["slots"], "hit_paged": hits["paged"]}}
     if args.out:
         with open(args.out, "w") as f:
             json.dump(out, f, indent=1)

@@ -316,7 +316,7 @@ def phase_serve(tag, shape, prompts_cfg, on_tpu, probe):
         placement(tag, engine.params, tp, split=True)
     sched = ContinuousBatchingScheduler(engine, ServingConfig(
         slots=slots, chunk_size=shape["chunk"], max_seq_len=cap,
-        kv_pool="paged", kv_total_pages=shape.get("total_pages"),
+        kv_total_pages=shape.get("total_pages"),
         prefix_cache=PrefixCacheConfig(insert_on="prefill")))
 
     pc = prompts_cfg

@@ -63,12 +63,10 @@ class HotPathSpec:
 HOT_PATH_SPECS: Tuple[HotPathSpec, ...] = (
     HotPathSpec("deepspeed_tpu/inference/decode_fns.py",
                 ("build_prefill", "build_prefix_prefill",
-                 "build_decode_loop", "build_decode_chunk",
-                 "build_paged_decode_chunk")),
+                 "build_decode_loop", "build_paged_decode_chunk")),
     HotPathSpec("deepspeed_tpu/inference/serving/executor.py",
                 ("ChunkedDecodeExecutor._chunk_fn",
                  "ChunkedDecodeExecutor._prefill_fn",
-                 "ChunkedDecodeExecutor._suffix_prefill_fn",
                  "ChunkedDecodeExecutor._suffix_prefill_fn_paged",
                  "ChunkedDecodeExecutor.prefill_into_slot",
                  "ChunkedDecodeExecutor.run_chunk",

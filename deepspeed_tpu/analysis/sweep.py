@@ -4,10 +4,11 @@ Runs every contract pass against the repo's *real* programs — not toys:
 
 - **serving lane** — a tiny ``InferenceEngine`` (fp32 + int8-quantized) under
   a real :class:`ChunkedDecodeExecutor`: donation audit on the chunk /
-  suffix-prefill / KV-pool movers, retrace lint across a repeated workload
-  (the documented one-compile-per-key property), the dequant-hoist
-  loop-invariance pin on BOTH decode bodies (while-loop generate and
-  scan-lowered chunk), and the trace-time host-sync guard;
+  suffix-prefill / KV-pool movers, retrace lint across a repeated
+  mixed-length workload (the documented one-compile-per-key property: page
+  growth rides the page table), the dequant-hoist loop-invariance pin on
+  BOTH decode bodies (while-loop generate and scan-lowered chunk), and the
+  trace-time host-sync guard;
 - **spec lane** — the speculative-decoding verify step under a speculating
   scheduler: one-compile-per-(slots, pages, page, cap, k, sampling) key
   across a grown-k workload (draft length is runtime data), donation audit
@@ -59,13 +60,22 @@ def _infra_result(name: str, target: str, exc: Exception) -> PassResult:
 
 # ------------------------------------------------------------- serving lane
 def serving_lane(report: Report) -> None:
+    """Serving contracts on the executor the cells run: the
+    one-compile-per-(slots, pages, page, cap, chunk, sampling)-key property
+    across a MIXED-LENGTH workload — page-count growth must ride the page
+    table (runtime data), never mint a new compile key; donation on the
+    chunk, the suffix prefill and the pool's movers; the dequant-hoist
+    loop-invariance pin on BOTH decode bodies (while-loop generate and
+    scan-lowered chunk, int8 engine); and the trace-time host-sync guard."""
     import jax
     import jax.numpy as jnp
     import numpy as np
     from ..inference.config import DeepSpeedInferenceConfig
-    from ..inference.decode_fns import (build_decode_chunk, build_decode_loop,
+    from ..inference.decode_fns import (build_decode_loop,
+                                        build_paged_decode_chunk,
                                         make_select_fn, make_slot_select_fn)
     from ..inference.engine import InferenceEngine
+    from ..inference.serving import kv_pool as kvp
     from ..inference.serving.executor import CTL_COLS, ChunkedDecodeExecutor
     from ..models.causal_lm import gpt2_cfg, init_cache
     from ..parallel.mesh import set_global_mesh
@@ -82,120 +92,9 @@ def serving_lane(report: Report) -> None:
         dtype="float32", max_out_tokens=_CAP,
         weight_quant={"enabled": True, "bits": 8}))
 
-    # the legacy slot-row pool's movers, explicitly — the paged default's
-    # contracts live in paged_lane
     ex = ChunkedDecodeExecutor(engine, slots=2, cap=_CAP, chunk_size=3,
-                               kv_pool="slots")
+                               kv_page_size=8)
     lint = CompileCacheLint(engine._fns, target="serving-engine")
-    rng = np.random.default_rng(0)
-
-    def workload():
-        prompt = rng.integers(0, _TINY["vocab_size"], size=8).astype(np.int32)
-        slot = ex.pool.acquire()
-        tok0, _ = ex.prefill_into_slot(slot, prompt, seed=0)
-        S = ex.slots
-        state = dict(
-            toks=np.full((S,), tok0, np.int32),
-            lens=np.full((S,), 8, np.int32),
-            active=np.array([True, False]),
-            remaining=np.full((S,), 5, np.int32),
-            eos=np.full((S,), -1, np.int32),
-            seeds=np.zeros((S,), np.int32), steps=np.zeros((S,), np.int32))
-        r = ex.run_chunk(state["toks"], state["lens"], state["active"],
-                         state["remaining"], state["eos"], state["seeds"],
-                         state["steps"])
-        ex.run_chunk(r.toks[:, 0], r.lens, r.active, r.remaining,
-                     state["eos"], state["seeds"], r.steps)
-        ex.pool.release(slot)
-
-    workload()                   # warmup: every key compiles exactly once
-    lint.snapshot()
-    workload()                   # identical shapes: zero new compiles allowed
-    report.add(lint.findings())
-
-    # donation: the real chunk fn + the pool's donated movers
-    chunk_key = next(k for k in engine._fns if k[0] == "serve_chunk")
-    S = ex.slots
-    chunk_args = (engine.params, jnp.zeros((S, CTL_COLS), jnp.int32),
-                  ex.pool.caches, ex._base_key)
-    report.add(donation_findings(engine._fns[chunk_key], chunk_args,
-                                 target="serve_chunk"))
-    one = init_cache(cfg, 1, _CAP, dtype=engine.dtype)
-    report.add(donation_findings(ex.pool._scatter_fn,
-                                 (ex.pool.caches, one, 0),
-                                 target="kv_pool.scatter"))
-    report.add(donation_findings(ex.pool._zero_fn, (ex.pool.caches, 0),
-                                 target="kv_pool.zero_fill"))
-    # suffix prefill (prefix-cache hit path): donates the POOL through the jit
-    sfn = ex._suffix_prefill_fn(8)
-    sargs = (engine.params, ex.pool.caches, jnp.zeros((1, 8), jnp.int32),
-             jnp.asarray([4, 4, 0, 0], jnp.int32), ex._base_key)
-    report.add(donation_findings(sfn, sargs, target="serve_suffix_prefill"))
-
-    # loop-invariance: dequant hoisted out of BOTH decode bodies (int8 engine)
-    int8_invar = lambda a: getattr(a, "dtype", None) == jnp.int8  # noqa: E731
-
-    def loop_pin(fn, args, site):
-        findings, n_loops = loop_body_findings(
-            fn, args, invar_predicate=int8_invar, what="dequant-hoist",
-            site=site)
-        res = PassResult("loop_invariance", site, findings, n_loops)
-        if n_loops == 0:
-            res.findings.append(Finding(
-                "loop_invariance", SEVERITY_ERROR, site,
-                "no loop found — the dequant-hoist pin target vanished"))
-        report.add(res)
-
-    select = make_select_fn(False, 1.0, 0, 1.0)
-    caches = init_cache(cfg, 2, _CAP, dtype=engine_q.dtype)
-    loop = build_decode_loop(engine_q.module, engine_q._dequant, select, _CAP,
-                             overlap=engine_q.comm_overlap)
-    largs = (engine_q.params, jnp.zeros((2, 1), jnp.int32), caches,
-             jnp.full((2,), 8, jnp.int32), np.int32(8), np.int32(-1),
-             jax.random.PRNGKey(0))
-    loop_pin(loop, largs, "decode_loop")
-
-    slot_select = make_slot_select_fn(False, 1.0, 0, 1.0)
-    chunk = build_decode_chunk(engine_q.module, engine_q._dequant,
-                               slot_select, 3,
-                               overlap=engine_q.comm_overlap)
-    qcaches = init_cache(cfg, 2, _CAP, dtype=engine_q.dtype)
-    cargs = (engine_q.params, jnp.zeros((2, 1), jnp.int32), qcaches,
-             jnp.full((2,), 8, jnp.int32), jnp.ones((2,), bool),
-             jnp.full((2,), 5, jnp.int32), jnp.full((2,), -1, jnp.int32),
-             jnp.zeros((2,), jnp.int32), jnp.zeros((2,), jnp.int32),
-             jax.random.PRNGKey(0))
-    loop_pin(chunk, cargs, "decode_chunk")
-
-    # host-sync runtime guard: the traced chunk body performs zero transfers
-    report.add(trace_sync_findings(chunk, cargs, target="decode_chunk"))
-    set_global_mesh(None)
-
-
-# ---------------------------------------------------------------- paged lane
-def paged_lane(report: Report) -> None:
-    """Paged-KV serving contracts: donation on the page-table chunk /
-    suffix-prefill / scatter movers, and the one-compile-per-(slots, pages,
-    page, chunk, sampling)-key property across a MIXED-LENGTH workload —
-    page-count growth must ride the page table (runtime data), never mint a
-    new compile key."""
-    import jax
-    import jax.numpy as jnp
-    import numpy as np
-    from ..inference.config import DeepSpeedInferenceConfig
-    from ..inference.engine import InferenceEngine
-    from ..inference.serving.executor import CTL_COLS, ChunkedDecodeExecutor
-    from ..models.causal_lm import gpt2_cfg, init_cache
-    from ..parallel.mesh import set_global_mesh
-    from .donation import donation_findings
-    from .retrace import CompileCacheLint
-
-    cfg = gpt2_cfg(**_TINY, dtype=jnp.float32)
-    engine = InferenceEngine(cfg, DeepSpeedInferenceConfig(
-        dtype="float32", max_out_tokens=_CAP))
-    ex = ChunkedDecodeExecutor(engine, slots=2, cap=_CAP, chunk_size=3,
-                               kv_pool="paged", kv_page_size=8)
-    lint = CompileCacheLint(engine._fns, target="paged-serving-engine")
     rng = np.random.default_rng(0)
 
     def one_request(plen, new):
@@ -225,22 +124,64 @@ def paged_lane(report: Report) -> None:
     workload()                # mixed lengths again: zero new compiles allowed
     report.add(lint.findings())
 
-    chunk_key = next(k for k in engine._fns if k[0] == "serve_chunk_paged")
+    # donation: the real chunk fn, the suffix prefill (prefix-cache hit path)
+    # and the pool's donated movers
     S, mp = ex.slots, ex.pool.max_pages
     chunk_args = (engine.params, jnp.zeros((S, CTL_COLS + mp), jnp.int32),
                   ex.pool.caches, ex._base_key)
-    report.add(donation_findings(engine._fns[chunk_key], chunk_args,
-                                 target="serve_chunk_paged"))
-    one = init_cache(cfg, 1, _CAP, dtype=engine.dtype)
-    report.add(donation_findings(ex.pool._scatter_fn,
-                                 (ex.pool.caches, one,
-                                  jnp.zeros((mp,), jnp.int32)),
-                                 target="paged_pool.scatter"))
+    report.add(donation_findings(ex._chunk_fn(), chunk_args,
+                                 target="serve_chunk"))
     sfn = ex._suffix_prefill_fn_paged(8)
     sargs = (engine.params, ex.pool.caches, jnp.zeros((1, 8), jnp.int32),
              jnp.asarray([4, 4, 0] + [0] * mp, jnp.int32), ex._base_key)
-    report.add(donation_findings(sfn, sargs,
-                                 target="serve_suffix_prefill_paged"))
+    report.add(donation_findings(sfn, sargs, target="serve_suffix_prefill"))
+    one = init_cache(cfg, 1, _CAP, dtype=engine.dtype)
+    report.add(donation_findings(ex.pool._scatter_fn,
+                                 (ex.pool.caches, one,
+                                  jnp.zeros((mp,), jnp.int32), 0),
+                                 target="kv_pool.scatter"))
+    report.add(donation_findings(ex.pool._cow_fn, (ex.pool.caches, 1, 2),
+                                 target="kv_pool.cow"))
+    report.add(donation_findings(kvp._state_zero_jit(), (ex.pool.caches, 0),
+                                 target="kv_pool.state_zero_fill"))
+
+    # loop-invariance: dequant hoisted out of BOTH decode bodies (int8 engine)
+    int8_invar = lambda a: getattr(a, "dtype", None) == jnp.int8  # noqa: E731
+
+    def loop_pin(fn, args, site):
+        findings, n_loops = loop_body_findings(
+            fn, args, invar_predicate=int8_invar, what="dequant-hoist",
+            site=site)
+        res = PassResult("loop_invariance", site, findings, n_loops)
+        if n_loops == 0:
+            res.findings.append(Finding(
+                "loop_invariance", SEVERITY_ERROR, site,
+                "no loop found — the dequant-hoist pin target vanished"))
+        report.add(res)
+
+    select = make_select_fn(False, 1.0, 0, 1.0)
+    caches = init_cache(cfg, 2, _CAP, dtype=engine_q.dtype)
+    loop = build_decode_loop(engine_q.module, engine_q._dequant, select, _CAP,
+                             overlap=engine_q.comm_overlap)
+    largs = (engine_q.params, jnp.zeros((2, 1), jnp.int32), caches,
+             jnp.full((2,), 8, jnp.int32), np.int32(8), np.int32(-1),
+             jax.random.PRNGKey(0))
+    loop_pin(loop, largs, "decode_loop")
+
+    slot_select = make_slot_select_fn(False, 1.0, 0, 1.0)
+    chunk = build_paged_decode_chunk(engine_q.module, engine_q._dequant,
+                                     slot_select, 3, kv_cap=_CAP,
+                                     overlap=engine_q.comm_overlap)
+    cargs = (engine_q.params, jnp.zeros((2, 1), jnp.int32), ex.pool.caches,
+             jnp.asarray(ex.pool.page_table),
+             jnp.full((2,), 8, jnp.int32), jnp.ones((2,), bool),
+             jnp.full((2,), 5, jnp.int32), jnp.full((2,), -1, jnp.int32),
+             jnp.zeros((2,), jnp.int32), jnp.zeros((2,), jnp.int32),
+             jax.random.PRNGKey(0))
+    loop_pin(chunk, cargs, "decode_chunk")
+
+    # host-sync runtime guard: the traced chunk body performs zero transfers
+    report.add(trace_sync_findings(chunk, cargs, target="decode_chunk"))
     set_global_mesh(None)
 
 
@@ -270,8 +211,8 @@ def spec_lane(report: Report) -> None:
     engine = InferenceEngine(cfg, DeepSpeedInferenceConfig(
         dtype="float32", max_out_tokens=_CAP))
     sched = ContinuousBatchingScheduler(engine, ServingConfig(
-        slots=2, chunk_size=3, max_seq_len=_CAP, kv_pool="paged",
-        kv_page_size=8, speculate=True, spec_k=4))
+        slots=2, chunk_size=3, max_seq_len=_CAP, kv_page_size=8,
+        speculate=True, spec_k=4))
     lint = CompileCacheLint(engine._fns, target="spec-serving-engine")
     rng = np.random.default_rng(0)
 
@@ -309,15 +250,12 @@ def spec_lane(report: Report) -> None:
     engine_q = InferenceEngine((cfg, raw), DeepSpeedInferenceConfig(
         dtype="float32", max_out_tokens=_CAP,
         weight_quant={"enabled": True, "bits": 8}))
-    from ..inference.serving.executor import ChunkedDecodeExecutor
-    exq = ChunkedDecodeExecutor(engine_q, slots=2, cap=_CAP, chunk_size=3,
-                                kv_pool="paged", kv_page_size=8)
     verify = build_paged_spec_verify(engine_q.module, engine_q._dequant,
                                      kv_cap=_CAP,
                                      overlap=engine_q.comm_overlap)
     int8_invar = lambda a: getattr(a, "dtype", None) == jnp.int8  # noqa: E731
     qargs = (engine_q.params, jnp.zeros((S, k + 1), jnp.int32),
-             exq.pool.caches, jnp.zeros((S, exq.pool.max_pages), jnp.int32),
+             ex.pool.caches, jnp.zeros((S, mp), jnp.int32),
              jnp.zeros((S,), jnp.int32), jnp.ones((S,), jnp.int32),
              jnp.zeros((S,), bool))
     findings, n_loops = loop_body_findings(
@@ -360,8 +298,7 @@ def kvecon_lane(report: Report) -> None:
     # evicts the first, which spills to the (generous) host rung; re-serving
     # the first prefix then promotes it back — the canonical tier traffic
     sched = ContinuousBatchingScheduler(engine, ServingConfig(
-        slots=2, chunk_size=2, max_seq_len=_CAP, kv_pool="paged",
-        kv_page_size=4,
+        slots=2, chunk_size=2, max_seq_len=_CAP, kv_page_size=4,
         prefix_cache=PrefixCacheConfig(
             max_bytes=12 * 1024, host_tier_bytes=1 << 20,
             min_hit_tokens=4, min_insert_tokens=4, insert_on="prefill")))
@@ -746,7 +683,7 @@ def run_sweep(repo_root: str, *, ast_only: bool = False,
     report = Report()
     ast_lane(report, repo_root, paths=paths)
     if not ast_only:
-        for lane in (serving_lane, paged_lane, spec_lane, kvecon_lane,
+        for lane in (serving_lane, spec_lane, kvecon_lane,
                      train_lane, overlap_lane, qring_lane):
             try:
                 lane(report)

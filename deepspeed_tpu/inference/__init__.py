@@ -3,6 +3,6 @@ from .engine import InferenceEngine
 from .diffusion_engine import DiffusionInferenceEngine, init_diffusion_inference
 from .serving import (ChunkedDecodeExecutor, ContinuousBatchingScheduler,
                       QueueFullError, RequestHandle, RequestState, ServingConfig,
-                      ServingTelemetry, SlotKVPool)
+                      ServingTelemetry)
 from .speculative import (DraftModelProposer, NgramProposer, SpeculativeConfig,
                           make_proposer)

@@ -4,7 +4,7 @@ and fixed-shape chunked decode.
 This is the factored-out core of ``InferenceEngine._loop_fns``: the single-call
 ``generate`` path keeps its one-``lax.while_loop``-per-call shape (the XLA analogue
 of CUDA-graph replay), while the serving executor composes the same prefill with
-:func:`build_decode_chunk` — K fixed steps over a fixed slot-batch, returning to the
+:func:`build_paged_decode_chunk` — K fixed steps over a fixed slot-batch, returning to the
 host between chunks so the continuous-batching scheduler can admit/retire requests
 mid-stream. Both paths share the token-selection closures here, so sampling
 semantics cannot drift between them.
@@ -176,60 +176,42 @@ def build_prefix_prefill(module, dequant, overlap=None):
     return prefix_prefill
 
 
-def build_spec_verify(module, dequant, overlap=None):
-    """Speculative one-pass verify over a slot-batch (dense slot-row caches).
+def build_paged_spec_verify(module, dequant, kv_cap: int, overlap=None):
+    """Speculative one-pass verify over a slot-batch.
 
     ``ids (S, t)`` is each slot's verify window ``[cur_tok, draft_0 ..
-    draft_{t-2}]``; the forward runs in ``prefix_fill`` mode at cache offset
-    ``lens`` — the window's K/V scatter into rows ``lens + j`` and every
-    window position attends over committed rows + the in-window prefix
-    (``key_pos <= query_pos``), exactly the PR 9 suffix-prefill math. Unlike
-    :func:`build_prefix_prefill` the LM head runs at EVERY window position
-    (``logits_positions=None``): the accept rule needs the target's
-    distribution after each draft prefix.
+    draft_{t-2}]``. Each slot's pages are gathered to the dense view once and
+    the forward runs in ``prefix_fill`` mode at cache offset ``lens`` — the
+    window's K/V land in rows ``lens + j`` and every window position attends
+    over committed rows + the in-window prefix (``key_pos <= query_pos``),
+    exactly the suffix-prefill math. Unlike :func:`build_prefix_prefill` the
+    LM head runs at EVERY window position (``logits_positions=None``): the
+    accept rule needs the target's distribution after each draft prefix.
+
+    Then ONLY the valid window rows ``[lens, lens + valid)`` of live slots
+    are mirrored back through the page table (the chunk's end-of-chunk
+    writeback idiom). ``valid (S,)`` is ``spec_len + 1`` — the cur-token row
+    plus the real (un-padded) draft rows; pad rows, inactive slots, and rows
+    at/past ``kv_cap`` route to the out-of-range page index and the scatter
+    drops them, so released or shared pages are never written.
 
     Rollback is the caller's job and is free: rows written past the accepted
     prefix stay stale-but-masked (attention masks ``>= cache_len``) and are
     overwritten by later appends — committing is a ``cache_len`` advance,
     rejecting is not advancing. Returns ``(logits (S, t, V), new_caches)``.
-    """
-
-    def spec_verify(params, ids, caches, lens):
-        b, t = ids.shape
-        positions = lens[:, None] + jnp.arange(t)[None]
-        with overlap_scope(overlap):
-            logits, new_caches = module.apply(
-                {"params": dequant(params)}, ids, positions=positions,
-                caches=caches, cache_lens=lens,
-                logits_positions=None, prefix_fill=True)
-        return logits, new_caches
-
-    return spec_verify
-
-
-def build_paged_spec_verify(module, dequant, kv_cap: int, overlap=None):
-    """Paged sibling of :func:`build_spec_verify`: gather each slot's pages to
-    the dense view once, run the same ``prefix_fill`` verify forward, then
-    mirror ONLY the valid window rows ``[lens, lens + valid)`` of live slots
-    back through the page table (the paged chunk's end-of-chunk writeback
-    idiom). ``valid (S,)`` is ``spec_len + 1`` — the cur-token row plus the
-    real (un-padded) draft rows; pad rows, inactive slots, and rows at/past
-    ``kv_cap`` route to the out-of-range page index and the scatter drops
-    them, so released or shared pages are never written.
 
     The mirror is a ``fori_loop`` over the window rows — the loop the
     analysis sweep's dequant pin targets: ``dequant`` collapses the quantized
     params ONCE above it, so int8 payloads must never appear as loop-body
-    inputs (the same loop-invariance contract as both decode-chunk bodies).
+    inputs (the same loop-invariance contract as the decode-chunk body).
     """
-    from ..ops.paged_attention import gather_kv_dense
+    from ..ops.paged_attention import gather_kv_dense, page_address
 
     def spec_verify(params, ids, caches, page_table, lens, valid, active):
         # hoisted: dequant once per verify dispatch, never inside the mirror
         params = dequant(params)
         b, t = ids.shape
         ps = caches[0]["k"].shape[2]
-        mp = page_table.shape[1]
         P_total = caches[0]["k"].shape[0]
         dense = [dict(zip(("k", "v"),
                           gather_kv_dense(c["k"], c["v"], page_table, kv_cap)))
@@ -243,12 +225,8 @@ def build_paged_spec_verify(module, dequant, kv_cap: int, overlap=None):
 
         def mirror(j, pages):
             rows = lens + j
-            page_pos = jnp.clip(rows // ps, 0, mp - 1)
-            pidx = jnp.where(active & (j < valid) & (rows < kv_cap),
-                             jnp.take_along_axis(
-                                 page_table, page_pos[:, None], axis=1)[:, 0],
-                             P_total)
-            off = rows % ps
+            pidx, off = page_address(page_table, rows, kv_cap, ps, P_total,
+                                     live=lambda: active & (j < valid))
             idx = jnp.minimum(rows, kv_cap - 1)[:, None, None, None]
             out = []
             for c, dn in zip(pages, dense):
@@ -316,17 +294,18 @@ def build_decode_loop(module, dequant, select, gen_cap: int, overlap=None):
     return decode_loop
 
 
-def build_decode_chunk(module, dequant, slot_select, chunk_size: int,
-                       overlap=None, with_stats: bool = False):
-    """Fixed-shape chunked decode over a slot-batch: exactly ``chunk_size`` steps,
-    every shape static, one compile per (slots, cap, chunk, sampling) key.
+def build_paged_decode_chunk(module, dequant, slot_select, chunk_size: int,
+                             kv_cap: int, overlap=None, fused: bool = False,
+                             with_stats: bool = False):
+    """Fixed-shape chunked decode over a slot-batch: exactly ``chunk_size``
+    steps, every shape static, one compile per (slots, total-pages, page, cap,
+    chunk, sampling) key.
 
     Per-slot state (all ``(S,)`` unless noted):
 
     - ``toks (S, 1)``: each slot's last emitted token (the next step's input);
     - ``lens``: the slot's KV append position — advances only while the slot is
-      active, so a retired slot's cache rows below ``lens`` stay intact until the
-      pool zero-fills it;
+      active, so a retired slot's cache rows below ``lens`` stay intact;
     - ``active``: slot holds a live, unfinished request. Inactive slots still flow
       through the batch (fixed shapes) but emit ``max(eos, 0)`` and freeze;
     - ``remaining``: decode-token budget (prefill's first token already spent);
@@ -338,47 +317,16 @@ def build_decode_chunk(module, dequant, slot_select, chunk_size: int,
     chunk, so no gaps. The scheduler harvests on the host between chunks.
     ``with_stats`` appends the expert layers' counts summed over the chunk's
     steps (:func:`apply_model`) to the outputs.
-    """
-    stats0 = (jnp.zeros((2,), jnp.int32),) if with_stats else ()
 
-    def decode_chunk(params, toks, caches, lens, active, remaining, eos_ids,
-                     seeds, steps, base_key):
-        # hoisted out of the fori_loop body — same loop-invariance contract as
-        # build_decode_loop (dequant once per chunk dispatch, not per step)
-        params = dequant(params)
-        S = toks.shape[0]
-        buf = jnp.zeros((S, chunk_size), jnp.int32)
-
-        def step_model(toks, caches, lens):
-            return apply_model(module, params, with_stats, toks,
-                               positions=lens[:, None], caches=caches,
-                               cache_lens=lens)
-
-        body = _chunk_body(step_model, slot_select, base_key, seeds, eos_ids)
-        with overlap_scope(overlap):     # trace-time: fori body traces inside
-            out = jax.lax.fori_loop(
-                0, chunk_size, body,
-                (toks, caches, lens, active, remaining, steps, buf) + stats0)
-        toks, caches, lens, active, remaining, steps, buf = out[:7]
-        return (buf, toks, caches, lens, active, remaining, steps) + out[7:]
-
-    return decode_chunk
-
-
-def build_paged_decode_chunk(module, dequant, slot_select, chunk_size: int,
-                             kv_cap: int, overlap=None, fused: bool = False,
-                             with_stats: bool = False):
-    """Paged sibling of :func:`build_decode_chunk`: the caches of the layers
-    that keep keys and values are GLOBAL KV pages (``{"k": (P, hk, page, d),
-    ...}``) and each step writes at
-    the page-mapped row of the slot's static-shape ``page_table`` row — the
-    table itself never changes inside a chunk (pages are bound at admission),
-    so it rides as a loop constant. Every shape is static in (slots,
-    total-pages, page, chunk): a slot's page COUNT is runtime data in the
-    table, so page growth across requests never mints a compile key (pinned by
-    the analysis sweep's paged lane). A layer with a recurrent state carries
-    its per-slot ``{"conv", "ssm"}`` arrays through the loop as they are, and
-    a layer that keeps nothing an empty dict.
+    The caches of the layers that keep keys and values are GLOBAL KV pages
+    (``{"k": (P, hk, page, d), ...}``) and each step writes at the page-mapped
+    row of the slot's static-shape ``page_table`` row — the table itself never
+    changes inside a chunk (pages are bound at admission), so it rides as a
+    loop constant. A slot's page COUNT is runtime data in the table, so page
+    growth across requests never mints a compile key (pinned by the analysis
+    sweep's serving lane). A layer with a recurrent state carries its per-slot
+    ``{"conv", "ssm"}`` arrays through the loop as they are, and a layer that
+    keeps nothing an empty dict.
 
     ``fused=True`` (TPU / ``DS_TPU_PAGED_FORCE_FUSED=1``): each step attends
     straight against the pages through the Pallas gather-by-page-index kernel
@@ -387,18 +335,19 @@ def build_paged_decode_chunk(module, dequant, slot_select, chunk_size: int,
     ``fused=False`` (the XLA fallback): the dense per-slot view is gathered
     ONCE per chunk — hoisted out of the ``fori_loop``, same loop-invariance
     idea as the dequant hoist — and carried through the steps; each step runs
-    the EXACT slot-row decode math on the carry (greedy bit-identity with the
-    slot pool is then structural, not analytical) and mirrors its appended
-    K/V row into the pages so they stay the source of truth across chunks. A
-    per-step gather cost S·cap bytes every step and measurably ate the paged
-    occupancy win on CPU hosts; per-chunk it is 1/K of that. ``kv_cap``
-    bounds the dense view at exactly the slot-row pool's ``cap``."""
-    from ..ops.paged_attention import gather_kv_dense
+    the contiguous-cache decode math of ``engine.generate`` on the carry
+    (greedy bit-identity with it is then structural, not analytical) and its
+    appended K/V rows are mirrored into the pages at the end of the chunk so
+    they stay the source of truth across chunks. A per-step gather cost S·cap
+    bytes every step; per-chunk it is 1/K of that. ``kv_cap`` bounds the dense
+    view at exactly ``cap`` rows."""
+    from ..ops.paged_attention import gather_kv_dense, page_address
     stats0 = (jnp.zeros((2,), jnp.int32),) if with_stats else ()
 
     def decode_chunk(params, toks, caches, page_table, lens, active, remaining,
                      eos_ids, seeds, steps, base_key):
-        # same dequant loop-invariance contract as build_decode_chunk
+        # hoisted out of the fori_loop body — same loop-invariance contract as
+        # build_decode_loop (dequant once per chunk dispatch, not per step)
         params = dequant(params)
         S = toks.shape[0]
         buf = jnp.zeros((S, chunk_size), jnp.int32)
@@ -418,13 +367,11 @@ def build_paged_decode_chunk(module, dequant, slot_select, chunk_size: int,
             toks, caches, lens, active, remaining, steps, buf = out[:7]
             return (buf, toks, caches, lens, active, remaining, steps) + out[7:]
 
-        # XLA fallback: hoisted per-chunk gather, pure slot-row steps over the
-        # dense carry, ONE end-of-chunk mirror of the appended rows back into
-        # the pages — the pages leave/enter the loop nowhere, so the loop body
-        # is byte-for-byte the slot pool's
+        # XLA fallback: hoisted per-chunk gather, contiguous-cache steps over
+        # the dense carry, ONE end-of-chunk mirror of the appended rows back
+        # into the pages — the pages leave/enter the loop nowhere
         paged = [c for c in caches if "k" in c]
         ps = paged[0]["k"].shape[2]
-        mp = page_table.shape[1]
         P_total = paged[0]["k"].shape[0]
         lens_in = lens
         dense = [dict(zip(("k", "v"),
@@ -443,8 +390,7 @@ def build_paged_decode_chunk(module, dequant, slot_select, chunk_size: int,
                 (toks, dense, lens, active, remaining, steps, buf) + stats0)
         toks, dense, lens, active, remaining, steps, buf = out[:7]
         # mirror rows [lens_in, lens) (this chunk's appends) into the pages;
-        # rows a slot never advanced past, or beyond cap, route to an
-        # out-of-range page index and the scatter drops them
+        # rows a slot never advanced past, or beyond cap, are dropped
         done = lens - lens_in
         new_caches = []
         for c, dn in zip(caches, dense):
@@ -454,13 +400,8 @@ def build_paged_decode_chunk(module, dequant, slot_select, chunk_size: int,
             k_p, v_p = c["k"], c["v"]
             for j in range(chunk_size):
                 rows = lens_in + j
-                page_pos = jnp.clip(rows // ps, 0, mp - 1)
-                pidx = jnp.where((j < done) & (rows < kv_cap),
-                                 jnp.take_along_axis(
-                                     page_table, page_pos[:, None],
-                                     axis=1)[:, 0],
-                                 P_total)
-                off = rows % ps
+                pidx, off = page_address(page_table, rows, kv_cap, ps, P_total,
+                                         live=lambda: j < done)
                 idx = jnp.minimum(rows, kv_cap - 1)[:, None, None, None]
                 k_new = jnp.take_along_axis(dn["k"], idx, axis=2)[:, :, 0, :]
                 v_new = jnp.take_along_axis(dn["v"], idx, axis=2)[:, :, 0, :]

@@ -352,7 +352,7 @@ class InferenceEngine:
         EOS termination is an on-device all-reduce in the loop condition.
 
         The step bodies live in ``decode_fns`` (``build_prefill``/``build_decode_loop``),
-        shared with the serving executor's chunked variant (``build_decode_chunk``) so the
+        shared with the serving executor's chunked variant (``build_paged_decode_chunk``) so the
         two decode paths cannot drift."""
         key = ("loop", do_sample, float(temperature), int(top_k), float(top_p), gen_cap)
         if key in self._fns:

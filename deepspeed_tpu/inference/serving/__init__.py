@@ -1,21 +1,21 @@
-"""TPU-native serving subsystem: continuous batching over a slot-recycled KV pool,
+"""TPU-native serving subsystem: continuous batching over a paged KV pool,
 behind a health-supervised multi-replica router.
 
 Layers (bottom-up):
 
-- :mod:`kv_pool` — :class:`PagedKVPool` (default): one global pool of
-  fixed-size KV pages behind static-shape per-slot page tables — page-count
-  admission, refcounted zero-copy prefix sharing (copy-on-write boundary
-  page), donated movers; :class:`SlotKVPool`: the legacy slot-indexed
-  fixed-capacity buffers (scatter-in prefill, zero-fill on release);
+- :mod:`kv_pool` — :class:`PagedKVPool`: one global pool of fixed-size KV
+  pages behind static-shape per-slot page tables — page-count admission,
+  refcounted zero-copy prefix sharing (copy-on-write boundary page), donated
+  movers;
 - :mod:`executor` — :class:`ChunkedDecodeExecutor`: compiled fixed-shape decode
-  chunks of K steps over the slot-batch (one compile per (slots, cap, chunk,
-  sampling) key), per-slot prefill bucketed by prompt length, optional per-chunk
+  chunks of K steps over the slot-batch (one compile per (slots, pages, page,
+  cap, chunk, sampling) key), per-slot prefill bucketed by prompt length, optional per-chunk
   watchdog deadline (:class:`ChunkTimeoutError`);
 - :mod:`prefix_cache` — :class:`PrefixCache`: radix/trie index over token-ID
-  prefixes whose entries hold gathered KV slabs (LRU under an HBM byte budget,
-  exact match by token); a hit restores the slab into the slot and prefills
-  only the suffix, so shared system prompts skip prefill;
+  prefixes whose entries hold shared pool pages (LRU under an HBM byte budget,
+  exact match by token; evicted entries spill to a host tier as numpy slabs);
+  a hit binds the pages into the slot's table and prefills only the suffix,
+  so shared system prompts skip prefill;
 - :mod:`scheduler` — :class:`ContinuousBatchingScheduler`: bounded request queue
   with admission control, backpressure (reject-with-retry-after), deadlines,
   cancellation, slot recycling between chunks, per-replica prefix-cache
@@ -55,7 +55,7 @@ from .host import (HostConfig, HostedReplica, ReplicaSupervisor,
                    SocketHostedReplica, SupervisorConfig)
 from .net import FrameDecoder, NetConfig, SocketReplicaLink, encode_frame
 from .executor import ChunkedDecodeExecutor, ChunkTimeoutError
-from .kv_pool import PagedKVPool, SlotKVPool
+from .kv_pool import PagedKVPool
 from .prefix_cache import PrefixCache, PrefixCacheConfig
 from .router import (AdmissionDeferredError, AdmissionShedError,
                      DegradationRung, EngineReplica, ReplicaDeadError,
@@ -66,7 +66,7 @@ from .scheduler import (ContinuousBatchingScheduler, QueueFullError,
 from .telemetry import ServingTelemetry
 
 __all__ = [
-    "ChunkedDecodeExecutor", "ChunkTimeoutError", "SlotKVPool", "PagedKVPool",
+    "ChunkedDecodeExecutor", "ChunkTimeoutError", "PagedKVPool",
     "PrefixCache", "PrefixCacheConfig",
     "ContinuousBatchingScheduler", "QueueFullError", "RequestHandle",
     "RequestState", "ServingConfig", "ServingTelemetry",

@@ -13,7 +13,7 @@ decoding. Compile-key discipline:
   right-padded to power-of-two buckets so arbitrary lengths hit a handful of
   compiles.
 
-KV buffers are donated unconditionally (chunk in-place-updates the pool rows;
+KV buffers are donated unconditionally (chunk in-place-updates the pool pages;
 ``donate_argnums`` is honoured on CPU too — no backend guards).
 
 Watchdog: with ``chunk_deadline_s`` set, each chunk (dispatch + host fetch — the
@@ -40,12 +40,14 @@ from ...models.causal_lm import init_cache
 from ...observability import profiler as obs_profiler
 from ...observability.trace import get_tracer
 from ...utils.fault_injection import fault_point
-from ..decode_fns import (build_decode_chunk, build_paged_decode_chunk,
-                          build_paged_spec_verify, build_prefill,
-                          build_prefix_prefill, build_spec_verify,
+from ...ops.paged_attention import (FORCE_FUSED_ENV, fused_paged_for,
+                                    page_address, pages_to_dense)
+from ...parallel.mesh import AXIS_TENSOR, get_global_mesh
+from ..decode_fns import (build_paged_decode_chunk, build_paged_spec_verify,
+                          build_prefill, build_prefix_prefill,
                           make_slot_select_fn)
 from ..speculative import accept_tokens
-from .kv_pool import PagedKVPool, SlotKVPool
+from .kv_pool import PagedKVPool
 
 
 class ChunkTimeoutError(RuntimeError):
@@ -68,22 +70,40 @@ class ReplicaKilledError(RuntimeError):
 # One int32 operand in and one int32 result out a call: every small array is
 # a host-device crossing of its own with the chip idle, whatever its size. A
 # chunk's operand ``ctl`` is a row a slot, these columns and then the slot's
-# page-table row (paged pool); its result is a row a slot, the chunk's
-# emitted tokens and then ``OUT``'s columns, with the two expert counts at
-# the head of one more row where the model has expert layers. A prefill's
-# rank-1 ``ctl`` is ``len, seed``; a suffix prefill's is ``prefix_len,
-# suffix_len, seed`` and then the slot (slots pool) or the slot's page-table
-# row.
+# page-table row; its result is a row a slot, the chunk's emitted tokens and
+# then ``OUT``'s columns, with the two expert counts at the head of one more
+# row where the model has expert layers. A prefill's rank-1 ``ctl`` is ``len,
+# seed``; a suffix prefill's is ``prefix_len, suffix_len, seed`` and then the
+# slot's page-table row.
 CTL_TOK, CTL_LEN, CTL_ACTIVE, CTL_REMAINING, CTL_EOS, CTL_SEED, CTL_STEPS, \
     CTL_COLS = range(8)
 OUT_TOK, OUT_LEN, OUT_ACTIVE, OUT_REMAINING, OUT_STEPS = range(5)
 PRE_COLS = 3
 
 
-def _suffix_ctl(ctl):
-    """A suffix prefill's operand apart: ``prefix_len, suffix_len, seed`` as
-    ``(1,)`` arrays and the tail that says where the slot's rows live."""
-    return ctl[0:1], ctl[1:2], ctl[2:3], ctl[PRE_COLS:]
+def _packed_chunk(chunk):
+    """``chunk`` (``decode_fns.build_paged_decode_chunk``'s function) behind
+    the packed operand and result the module's head describes; the name the
+    trace and the lowered module carry stays ``decode_chunk``."""
+
+    def decode_chunk(params, ctl, caches, base_key):
+        # the table is host state bound at admission; it never changes
+        # inside a chunk, so it rides in the operand's tail
+        page_table = ctl[:, CTL_COLS:]
+        buf, toks, caches, lens, active, remaining, steps, *stats = chunk(
+            params, ctl[:, CTL_TOK:CTL_TOK + 1], caches, page_table,
+            ctl[:, CTL_LEN], ctl[:, CTL_ACTIVE] != 0,
+            ctl[:, CTL_REMAINING], ctl[:, CTL_EOS], ctl[:, CTL_SEED],
+            ctl[:, CTL_STEPS], base_key)
+        packed = jnp.concatenate(
+            [buf, toks, lens[:, None], active.astype(jnp.int32)[:, None],
+             remaining[:, None], steps[:, None]], axis=1)
+        if stats:
+            counts = jnp.pad(stats[0], (0, packed.shape[1] - 2))
+            packed = jnp.concatenate([packed, counts[None]], axis=0)
+        return packed, caches
+
+    return decode_chunk
 
 
 def prompt_buckets(max_prompt_len: int, smallest: int = 8) -> Tuple[int, ...]:
@@ -136,17 +156,13 @@ class ChunkedDecodeExecutor:
                  top_k: int = 0, top_p: float = 1.0, max_prompt_len: Optional[int]
                  = None, base_seed: int = 0,
                  chunk_deadline_s: Optional[float] = None,
-                 cold_chunk_grace_s: float = 120.0,
-                 kv_pool: str = "paged", kv_page_size: int = 16,
+                 cold_chunk_grace_s: float = 120.0, kv_page_size: int = 16,
                  kv_total_pages: Optional[int] = None):
         if chunk_size < 1:
             raise ValueError(f"chunk_size must be >= 1, got {chunk_size}")
         if chunk_deadline_s is not None and chunk_deadline_s <= 0:
             raise ValueError("chunk_deadline_s must be positive when set, got "
                              f"{chunk_deadline_s}")
-        if kv_pool not in ("paged", "slots"):
-            raise ValueError(f"kv_pool must be 'paged' or 'slots', "
-                             f"got {kv_pool!r}")
         self.engine = engine
         self.slots = int(slots)
         self.cap = int(cap)
@@ -158,7 +174,6 @@ class ChunkedDecodeExecutor:
         self.sampling = (bool(do_sample), float(temperature), int(top_k),
                          float(top_p))
         self.buckets = prompt_buckets(self.max_prompt_len)
-        self.kv_pool_kind = kv_pool
         self.kv_page_size = int(kv_page_size)
         self.kv_total_pages = kv_total_pages
         kinds = engine.model_config.layer_kinds
@@ -211,106 +226,61 @@ class ChunkedDecodeExecutor:
         self._stall_next = float(seconds)
 
     def _build_pool(self):
-        with get_tracer().phase("setup.kv_pool", pool=self.kv_pool_kind) as ph:
-            if self.kv_pool_kind == "paged":
-                pool = PagedKVPool(self.engine.model_config, self.slots,
-                                   self.cap, page_size=self.kv_page_size,
-                                   dtype=self.engine.dtype,
-                                   total_pages=self.kv_total_pages)
-                ph.set(pages=pool.total_pages, slots=self.slots,
-                       state_bytes=pool.state_nbytes)
-                return pool
-            return SlotKVPool(self.engine.model_config, self.slots, self.cap,
-                              dtype=self.engine.dtype)
-
-    @property
-    def paged(self) -> bool:
-        return self.kv_pool_kind == "paged"
+        with get_tracer().phase("setup.kv_pool", pool="paged") as ph:
+            pool = PagedKVPool(self.engine.model_config, self.slots,
+                               self.cap, page_size=self.kv_page_size,
+                               dtype=self.engine.dtype,
+                               total_pages=self.kv_total_pages)
+            ph.set(pages=pool.total_pages, slots=self.slots,
+                   state_bytes=pool.state_nbytes)
+            return pool
 
     def reset_pool(self) -> None:
         """Discard the pool (e.g. after a failed dispatch that may have consumed
-        donated buffers) and rebuild it fresh, every slot free. On the paged
-        pool this also voids every page the prefix cache holds references to —
-        the scheduler clears its cache alongside (``_rebuild_pool``)."""
+        donated buffers) and rebuild it fresh, every slot free. This also
+        voids every page the prefix cache holds references to — the scheduler
+        clears its cache alongside (``_rebuild_pool``)."""
         self.pool = self._build_pool()
 
     # ------------------------------------------------------------- compiled fns
     def _chunk_fn(self):
-        if self.paged:
-            from ...ops.paged_attention import FORCE_FUSED_ENV, fused_paged_for
-            from ...parallel.mesh import AXIS_TENSOR, get_global_mesh
-            mesh = get_global_mesh()
-            cfg = self.engine.model_config
-            # the fused kernel has no alibi bias (the layer would re-gather
-            # the dense view EVERY step inside the loop — the fallback hoists
-            # it once per chunk), no shard_map TP path (the fallback's dense
-            # steps route through _sharded_decode), and its dispatcher needs
-            # a lane-aligned head dim on-chip (fused_paged_for mirrors it);
-            # every excluded regime decodes strictly faster on the fallback.
-            # So does a pool that holds a row for every slot's whole cap: on
-            # the chip the kernel took 1.07-2.6x the fallback's time a step at
-            # every page of keys from 8 to 128 KiB (32 slots, pages of 16, cap
-            # 2048: PERF.md, PR 27), so it is taken where the dense view would
-            # need more rows than the pool has (an oversubscribed pool, what
-            # paging is for); the env override forces it
-            oversubscribed = self.slots * self.cap > \
-                (self.pool.total_pages - 1) * self.pool.page_size
-            fused = fused_paged_for(cfg.head_dim) \
-                and (oversubscribed or os.environ.get(FORCE_FUSED_ENV, "0") == "1") \
-                and getattr(cfg, "pos_emb", None) != "alibi" \
-                and (mesh is None or mesh.size(AXIS_TENSOR) <= 1)
-            # one compile per (slots, pages, page, cap, chunk, sampling) key:
-            # per-request page COUNTS are runtime table data, so mixed-length
-            # traffic and page growth never mint a new key (sweep-pinned).
-            # The fused flag is part of the key — tests toggle the env var.
-            key = ("serve_chunk_paged", self.slots, self.pool.total_pages,
-                   self.pool.page_size, self.cap, self.chunk_size,
-                   self.sampling, fused)
-        else:
-            key = ("serve_chunk", self.slots, self.cap, self.chunk_size,
-                   self.sampling)
+        mesh = get_global_mesh()
+        cfg = self.engine.model_config
+        # the fused kernel has no alibi bias (the layer would re-gather
+        # the dense view EVERY step inside the loop — the fallback hoists
+        # it once per chunk), no shard_map TP path (the fallback's dense
+        # steps route through _sharded_decode), and its dispatcher needs
+        # a lane-aligned head dim on-chip (fused_paged_for mirrors it);
+        # every excluded regime decodes strictly faster on the fallback.
+        # So does a pool that holds a row for every slot's whole cap: on
+        # the chip the kernel took 1.07-2.6x the fallback's time a step at
+        # every page of keys from 8 to 128 KiB (32 slots, pages of 16, cap
+        # 2048: PERF.md, PR 27), so it is taken where the dense view would
+        # need more rows than the pool has (an oversubscribed pool, what
+        # paging is for); the env override forces it
+        oversubscribed = self.slots * self.cap > \
+            (self.pool.total_pages - 1) * self.pool.page_size
+        fused = fused_paged_for(cfg.head_dim) \
+            and (oversubscribed or os.environ.get(FORCE_FUSED_ENV, "0") == "1") \
+            and getattr(cfg, "pos_emb", None) != "alibi" \
+            and (mesh is None or mesh.size(AXIS_TENSOR) <= 1)
+        # one compile per (slots, pages, page, cap, chunk, sampling) key:
+        # per-request page COUNTS are runtime table data, so mixed-length
+        # traffic and page growth never mint a new key (sweep-pinned).
+        # The fused flag is part of the key — tests toggle the env var.
+        key = ("serve_chunk_paged", self.slots, self.pool.total_pages,
+               self.pool.page_size, self.cap, self.chunk_size,
+               self.sampling, fused)
         fns = self.engine._fns
         if key not in fns:
-            overlap = getattr(self.engine, "comm_overlap", None)
-            if self.paged:
-                chunk = build_paged_decode_chunk(
-                    self.engine.module, self.engine._dequant,
-                    self._slot_select, self.chunk_size, kv_cap=self.cap,
-                    overlap=overlap, fused=fused, with_stats=self.with_stats)
-            else:
-                chunk = build_decode_chunk(self.engine.module,
-                                           self.engine._dequant,
-                                           self._slot_select, self.chunk_size,
-                                           overlap=overlap,
-                                           with_stats=self.with_stats)
-            fns[key] = jax.jit(self._packed_chunk(chunk),
-                               donate_argnums=(2,))          # caches/pages
+            chunk = build_paged_decode_chunk(
+                self.engine.module, self.engine._dequant,
+                self._slot_select, self.chunk_size, kv_cap=self.cap,
+                overlap=getattr(self.engine, "comm_overlap", None),
+                fused=fused, with_stats=self.with_stats)
+            fns[key] = jax.jit(_packed_chunk(chunk),
+                               donate_argnums=(2,))          # pages
         return fns[key]
-
-    def _packed_chunk(self, chunk):
-        """``chunk`` (a ``decode_fns`` chunk builder's function) behind the
-        packed operand and result the module's head describes; the name the
-        trace and the lowered module carry stays ``decode_chunk``."""
-        paged = self.paged
-
-        def decode_chunk(params, ctl, caches, base_key):
-            # the table is host state bound at admission; it never changes
-            # inside a chunk, so it rides in the operand's tail
-            where = (ctl[:, CTL_COLS:],) if paged else ()
-            buf, toks, caches, lens, active, remaining, steps, *stats = chunk(
-                params, ctl[:, CTL_TOK:CTL_TOK + 1], caches, *where,
-                ctl[:, CTL_LEN], ctl[:, CTL_ACTIVE] != 0,
-                ctl[:, CTL_REMAINING], ctl[:, CTL_EOS], ctl[:, CTL_SEED],
-                ctl[:, CTL_STEPS], base_key)
-            packed = jnp.concatenate(
-                [buf, toks, lens[:, None], active.astype(jnp.int32)[:, None],
-                 remaining[:, None], steps[:, None]], axis=1)
-            if stats:
-                counts = jnp.pad(stats[0], (0, packed.shape[1] - 2))
-                packed = jnp.concatenate([packed, counts[None]], axis=0)
-            return packed, caches
-
-        return decode_chunk
 
     def _prefill_fn(self, bucket: int):
         key = ("serve_prefill", bucket, self.cap, self.sampling)
@@ -337,45 +307,10 @@ class ChunkedDecodeExecutor:
             fns[key] = jax.jit(prefill)
         return fns[key]
 
-    def _suffix_prefill_fn(self, bucket: int):
-        """Cache-hit prefill: gather the slot's batch-1 cache view (holding the
-        restored prefix slab), run the suffix forward at the prefix offset,
-        scatter the row back. The POOL caches flow through and are donated —
-        same compile-key discipline as the chunk fn, one compile per
-        (slots, cap, suffix-bucket, sampling) key."""
-        key = ("serve_suffix_prefill", self.slots, self.cap, bucket,
-               self.sampling)
-        fns = self.engine._fns
-        if key not in fns:
-            engine = self.engine
-            prefix_prefill = build_prefix_prefill(
-                engine.module, engine._dequant,
-                overlap=getattr(engine, "comm_overlap", None))
-            select = self._slot_select
-
-            def suffix_prefill(params, caches, ids, ctl, base_key):
-                prefix_len, suffix_len, seed, (slot,) = _suffix_ctl(ctl)
-                one = [{"k": jax.lax.dynamic_slice_in_dim(c["k"], slot, 1, 0),
-                        "v": jax.lax.dynamic_slice_in_dim(c["v"], slot, 1, 0)}
-                       for c in caches]
-                logits, new_one = prefix_prefill(params, ids, one, prefix_len,
-                                                 suffix_len)
-                tok0 = select(logits, base_key, seed, jnp.zeros_like(seed))
-                caches = [
-                    {"k": jax.lax.dynamic_update_slice_in_dim(
-                        c["k"], n["k"].astype(c["k"].dtype), slot, 0),
-                     "v": jax.lax.dynamic_update_slice_in_dim(
-                        c["v"], n["v"].astype(c["v"].dtype), slot, 0)}
-                    for c, n in zip(caches, new_one)]
-                return tok0[0], caches
-
-            fns[key] = jax.jit(suffix_prefill, donate_argnums=(1,))
-        return fns[key]
-
     def _suffix_prefill_fn_paged(self, bucket: int):
-        """Paged cache-hit prefill: the slot's pages (shared prefix pages
-        bound zero-copy at admission + its COW/fresh pages) are gathered into
-        the dense batch-1 view INSIDE the dispatch, the suffix forward runs at
+        """Cache-hit prefill: the slot's pages (shared prefix pages bound
+        zero-copy at admission + its COW/fresh pages) are gathered into the
+        dense batch-1 view INSIDE the dispatch, the suffix forward runs at
         the prefix offset, and ONLY the suffix rows scatter back to their
         page-mapped positions — shared pages are read, never written. The
         POOL pages flow through and are donated; one compile per
@@ -390,29 +325,26 @@ class ChunkedDecodeExecutor:
                 overlap=getattr(engine, "comm_overlap", None))
             select = self._slot_select
             cap = self.cap
-            ps, mp = self.pool.page_size, self.pool.max_pages
-            P_total = self.pool.total_pages
+            ps, P_total = self.pool.page_size, self.pool.total_pages
 
             def suffix_prefill(params, caches, ids, ctl, base_key):
-                prefix_len, suffix_len, seed, tbl = _suffix_ctl(ctl)
+                prefix_len, suffix_len, seed = ctl[0:1], ctl[1:2], ctl[2:3]
+                tbl = ctl[PRE_COLS:]        # the slot's page-table row
                 one = []
                 for c in caches:
-                    _, hk, _, d = c["k"].shape
-                    k = c["k"][tbl].transpose(1, 0, 2, 3).reshape(hk, -1, d)
-                    v = c["v"][tbl].transpose(1, 0, 2, 3).reshape(hk, -1, d)
+                    k = pages_to_dense(c["k"], tbl)
+                    v = pages_to_dense(c["v"], tbl)
                     one.append({"k": k[None, :, :cap, :],
                                 "v": v[None, :, :cap, :]})
                 logits, new_one = prefix_prefill(params, ids, one, prefix_len,
                                                  suffix_len)
                 tok0 = select(logits, base_key, seed, jnp.zeros_like(seed))
                 # scatter ONLY the suffix rows [prefix, prefix + bucket) back;
-                # rows beyond cap route to an out-of-range page index and the
-                # scatter drops them (the dense path's OOB-pad-drop contract)
+                # rows beyond cap are dropped (the dense path's OOB-pad-drop
+                # contract)
                 t = ids.shape[1]
                 rows = prefix_len[0] + jnp.arange(t)
-                page_pos = jnp.clip(rows // ps, 0, mp - 1)
-                pidx = jnp.where(rows < cap, tbl[page_pos], P_total)
-                off = rows % ps
+                pidx, off = page_address(tbl, rows, cap, ps, P_total)
                 out = []
                 for c, n in zip(caches, new_one):
                     kv = {}
@@ -428,30 +360,20 @@ class ChunkedDecodeExecutor:
         return fns[key]
 
     def _spec_verify_fn(self, k: int):
-        """Speculative one-pass verify: ONE compile per (slots, cap, k,
-        sampling) key (paged adds the pool geometry, mirroring the chunk
-        key). ``k`` is the static window width minus the cur-token row —
-        per-slot draft LENGTHS are runtime data (``valid``), so shrunken
-        proposals at the cap edge or a dry proposer never mint a new key.
-        The pool caches/pages are donated like every other decode dispatch."""
-        if self.paged:
-            key = ("serve_spec_verify_paged", self.slots,
-                   self.pool.total_pages, self.pool.page_size, self.cap, k,
-                   self.sampling)
-        else:
-            key = ("serve_spec_verify", self.slots, self.cap, k,
-                   self.sampling)
+        """Speculative one-pass verify: ONE compile per (slots, pages, page,
+        cap, k, sampling) key, mirroring the chunk key. ``k`` is the static
+        window width minus the cur-token row — per-slot draft LENGTHS are
+        runtime data (``valid``), so shrunken proposals at the cap edge or a
+        dry proposer never mint a new key. The pool pages are donated like
+        every other decode dispatch."""
+        key = ("serve_spec_verify_paged", self.slots, self.pool.total_pages,
+               self.pool.page_size, self.cap, k, self.sampling)
         fns = self.engine._fns
         if key not in fns:
-            overlap = getattr(self.engine, "comm_overlap", None)
-            if self.paged:
-                fn = build_paged_spec_verify(self.engine.module,
-                                             self.engine._dequant,
-                                             kv_cap=self.cap, overlap=overlap)
-            else:
-                fn = build_spec_verify(self.engine.module,
-                                       self.engine._dequant, overlap=overlap)
-            fns[key] = jax.jit(fn, donate_argnums=(2,))   # caches/pages
+            fn = build_paged_spec_verify(
+                self.engine.module, self.engine._dequant, kv_cap=self.cap,
+                overlap=getattr(self.engine, "comm_overlap", None))
+            fns[key] = jax.jit(fn, donate_argnums=(2,))   # pages
         return fns[key]
 
     def bucket_for(self, prompt_len: int) -> int:
@@ -510,14 +432,16 @@ class ChunkedDecodeExecutor:
                           request_id: int = -1) -> Tuple[int, float]:
         """Prefill ``prompt`` (1-D int tokens) and scatter its KV into ``slot``.
 
-        With ``prefix_len > 0`` (prefix-cache hit): restore ``prefix_slab``
-        into the slot via the pool's donated scatter, then prefill ONLY the
-        suffix ``prompt[prefix_len:]`` at cache offset ``prefix_len`` — the
-        prompt bucket is chosen by **suffix** length, so a 128-token cached
+        With ``prefix_len > 0`` (prefix-cache hit) the slot's first
+        ``prefix_len`` rows are already there — shared pages bound at
+        admission, or ``prefix_slab`` (a host-tier entry's numpy slab)
+        restored here by the pool's donated scatter — and ONLY the suffix
+        ``prompt[prefix_len:]`` is prefilled at cache offset ``prefix_len`` —
+        the prompt bucket is chosen by **suffix** length, so a 128-token cached
         system prompt with an 8-token user turn pays an 8-bucket forward, not a
         256-bucket one. The ``serving.prefix_restore`` fault point (and the
-        chaos ``when=restore`` hook) sits exactly between restore and suffix
-        prefill — the boundary whose donation discipline the soak guards.
+        chaos ``when=restore`` hook) sits exactly between bind/restore and
+        suffix prefill — the boundary whose donation discipline the soak guards.
 
         Returns ``(first_token, first_token_at)``: the ``time.monotonic``
         stamp at which the first token was on the host, which is the end of
@@ -544,24 +468,18 @@ class ChunkedDecodeExecutor:
             bucket = self.bucket_for(suffix.size)
             ids = np.zeros((1, bucket), np.int32)
             ids[0, :suffix.size] = suffix
-            if self.paged:
-                fn = self._suffix_prefill_fn_paged(bucket)
-                if prefix_slab is not None:
-                    # host-tier PROMOTE hit: the match lives as a spilled
-                    # dense slab, not as live pages — restore it into the
-                    # slot's (all-fresh, unshared) pages, paying one
-                    # host→device copy instead of a re-prefill. (A zero-copy
-                    # hit has nothing to restore here: its pages were bound
-                    # at admission, under ``serving.page_table``.)
-                    with tracer.span("serving.restore_prefix", slot=slot,
-                                     prefix_len=int(prefix_len), promoted=1):
-                        self.pool.promote_prefix(slot, prefix_slab, prefix_len)
-            else:
-                fn = self._suffix_prefill_fn(bucket)
+            fn = self._suffix_prefill_fn_paged(bucket)
+            if prefix_slab is not None:
+                # host-tier PROMOTE hit: the match lives as a spilled
+                # dense slab, not as live pages — restore it into the
+                # slot's (all-fresh, unshared) pages, paying one
+                # host→device copy instead of a re-prefill. (A zero-copy
+                # hit has nothing to restore here: its pages were bound
+                # at admission, under ``serving.page_table``.)
                 with tracer.span("serving.restore_prefix", slot=slot,
-                                 prefix_len=int(prefix_len), promoted=0):
-                    self.pool.restore_prefix(slot, prefix_slab)
-            # the restore->prefill (paged: bind->prefill) seam: the chaos
+                                 prefix_len=int(prefix_len), promoted=1):
+                    self.pool.promote_prefix(slot, prefix_slab, prefix_len)
+            # the bind->prefill (promote: restore->prefill) seam: the chaos
             # when=restore hook and fault point fire exactly here, after the
             # pool/table was touched and before the suffix forward
             fault_point("serving.prefix_restore")
@@ -576,10 +494,9 @@ class ChunkedDecodeExecutor:
                              prefix_len=int(prefix_len)) as sp:
                 with tracer.span("serving.place_inputs",
                                  program="suffix_prefill", arrays=2):
-                    where = self.pool.page_table[slot] if self.paged else slot
-                    ctl = np.empty(PRE_COLS + np.size(where), np.int32)
+                    ctl = np.empty(PRE_COLS + self.pool.max_pages, np.int32)
                     ctl[:PRE_COLS] = prefix_len, suffix.size, seed
-                    ctl[PRE_COLS:] = where
+                    ctl[PRE_COLS:] = self.pool.page_table[slot]
                     args = (self.engine.params, self.pool.caches,
                             *jax.device_put((ids, ctl)), self._base_key)
                 out, caches = self._dispatch(fn, args, "suffix_prefill",
@@ -622,7 +539,7 @@ class ChunkedDecodeExecutor:
     def run_chunk(self, toks: np.ndarray, lens: np.ndarray, active: np.ndarray,
                   remaining: np.ndarray, eos_ids: np.ndarray, seeds: np.ndarray,
                   steps: np.ndarray) -> ChunkResult:
-        """One K-step compiled chunk over the slot-batch; pool caches are donated
+        """One K-step compiled chunk over the slot-batch; pool pages are donated
         in and rebound from the output. All other state is host numpy.
 
         With ``chunk_deadline_s`` set, dispatch + host fetch run on a watchdog
@@ -643,15 +560,13 @@ class ChunkedDecodeExecutor:
             # a fresh array a call: the CPU client may alias a host buffer for
             # the device array's life, and a chunk the watchdog abandoned
             # still holds its operand
-            ctl = np.empty((S, CTL_COLS + (self.pool.max_pages if self.paged
-                                           else 0)), np.int32)
+            ctl = np.empty((S, CTL_COLS + self.pool.max_pages), np.int32)
             for col, host in ((CTL_TOK, np.reshape(toks, -1)), (CTL_LEN, lens),
                               (CTL_ACTIVE, active), (CTL_REMAINING, remaining),
                               (CTL_EOS, eos_ids), (CTL_SEED, seeds),
                               (CTL_STEPS, steps)):
                 ctl[:, col] = host
-            if self.paged:
-                ctl[:, CTL_COLS:] = self.pool.page_table
+            ctl[:, CTL_COLS:] = self.pool.page_table
             args = (self.engine.params, jax.device_put(ctl), caches_in,
                     self._base_key)
         (packed,), caches, t1 = self._dispatch_watched(
@@ -735,15 +650,11 @@ class ChunkedDecodeExecutor:
         spec_lens = np.asarray(spec_lens, np.int32)
         valid = spec_lens + 1
         with tracer.span("serving.place_inputs", program="spec_verify") as placed:
-            if self.paged:
-                args = (self.engine.params, jnp.asarray(ids), caches_in,
-                        jnp.asarray(self.pool.page_table),
-                        jnp.asarray(lens, jnp.int32),
-                        jnp.asarray(valid, jnp.int32),
-                        jnp.asarray(active, bool))
-            else:
-                args = (self.engine.params, jnp.asarray(ids), caches_in,
-                        jnp.asarray(lens, jnp.int32))
+            args = (self.engine.params, jnp.asarray(ids), caches_in,
+                    jnp.asarray(self.pool.page_table),
+                    jnp.asarray(lens, jnp.int32),
+                    jnp.asarray(valid, jnp.int32),
+                    jnp.asarray(active, bool))
             placed.set(arrays=len(args) - 2)    # all but params and caches
         # the mid-verify chaos/injection seam: after the proposer built the
         # window, before/through the verify dispatch + logits fetch

@@ -108,7 +108,6 @@ class HostConfig:
     prefix_tier_mb: Optional[float] = None   # host-RAM rung under the HBM
     #   budget (PR 19): evicted device entries spill here and promote back
     prefix_min_hit: Optional[int] = None
-    kv_pool: Optional[str] = None      # paged | slots (child default: paged)
     kv_page_size: Optional[int] = None
     chunk_deadline_s: Optional[float] = None
     # ----------------------------------------------- socket transport (PR 16)
@@ -125,7 +124,6 @@ class HostConfig:
         for key, val in (("prefix_cache_mb", self.prefix_cache_mb),
                          ("prefix_tier_mb", self.prefix_tier_mb),
                          ("prefix_min_hit", self.prefix_min_hit),
-                         ("kv_pool", self.kv_pool),
                          ("kv_page_size", self.kv_page_size),
                          ("chunk_deadline", self.chunk_deadline_s)):
             if val is not None:
@@ -199,8 +197,6 @@ class HostedHandle:
 class _HostPoolView:
     """The KV-pool slice of the replica surface (occupancy/slot accounting)
     from the child's heartbeat stream."""
-
-    paged = False
 
     def __init__(self, host):
         self._host = host

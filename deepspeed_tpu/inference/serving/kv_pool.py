@@ -1,35 +1,32 @@
-"""KV cache pools: slot-rows (legacy) and the paged page-table pool.
+"""The serve path's KV cache pool: fixed-size pages behind per-slot page tables.
 
-:class:`SlotKVPool` — one fixed allocation of ``init_cache(cfg, slots, cap)``
-per pool; requests borrow a slot (row) for their lifetime, so every slot
-reserves its worst-case ``cap`` KV up front. Every pool mutation — scatter-in
-of a prefill's batch-1 cache, prefix-slab restore on a cache hit, zero-fill on
-release — runs as a donated jitted update, so the pool's HBM footprint is
-constant: ``donate_argnums`` is honoured on CPU too, so there are no
-backend guards (guarding donation behind backend checks cost 1500x on pool
-scatters in an earlier revision of this codebase).
+:class:`PagedKVPool` — one global pool of fixed-size KV **pages** per layer
+(``{"k": (P, hk, page, d), ...}``; the layout itself lives in
+``ops/paged_attention.py``) behind a static-shape per-slot page table. A slot
+allocates only the pages its ``prompt + max_new`` needs (page-granular
+admission: occupancy tracks requested tokens, not the pow2-bucketed worst
+case), pages are refcounted so the prefix cache can **share** a prompt's pages
+zero-copy (a hit binds page indices into the new slot's table — no slab
+gather, no restore scatter; the first partially-covered page is
+copy-on-write), and a page is the shipment unit disaggregated prefill will
+serialize. Every pool mutation — scatter-in of a prefill's batch-1 cache,
+copy-on-write, slab restore, a released slot's state zero-fill — runs as a
+donated jitted update, so the pool's HBM footprint is constant:
+``donate_argnums`` is honoured on CPU too, so there are no backend guards
+(guarding donation behind backend checks cost 1500x on pool scatters in an
+earlier revision of this codebase). Released pages are NOT zero-filled: every
+row below a slot's ``cache_len`` is freshly written (prefill/suffix/decode) or
+a verbatim shared prefix row, and attention masks everything at or beyond
+``cache_len`` — leak safety is structural, and release is O(pages) host
+bookkeeping.
 
-:class:`PagedKVPool` — the default since PR 13: one global pool of fixed-size
-KV **pages** per layer (``{"k": (P, hk, page, d), ...}``) behind a static-shape
-per-slot page table. A slot allocates only the pages its ``prompt + max_new``
-needs (page-granular admission: occupancy tracks requested tokens, not the
-pow2-bucketed worst case), pages are refcounted so the prefix cache can
-**share** a prompt's pages zero-copy (a hit binds page indices into the new
-slot's table — no slab gather, no restore scatter; the first partially-covered
-page is copy-on-write), and a page is the shipment unit disaggregated prefill
-will serialize. Released pages are NOT zero-filled: every row below a slot's
-``cache_len`` is freshly written (prefill/suffix/decode) or a verbatim shared
-prefix row, and attention masks everything at or beyond ``cache_len`` — the
-leak-safety argument the slot pool bought with a zero scatter is structural
-here, and release becomes O(pages) host bookkeeping.
-
-``gather_prefix``/``restore_prefix`` survive on BOTH pools as the dense-slab
-serialization API (page-granular underneath on the paged pool) — the wire
-format disaggregated prefill ships between replicas.
+``gather_prefix``/``restore_prefix`` are the dense-slab serialization API
+(page-granular underneath) — the wire format disaggregated prefill ships
+between replicas, and what the prefix cache's host tier holds.
 
 Per-slot sequence lengths are scheduler state (host numpy, passed into each
-decode chunk); the pool owns the device buffers, the free lists and (paged)
-the page table + refcounts.
+decode chunk); the pool owns the device buffers, the free lists and the page
+table + refcounts.
 """
 
 import functools
@@ -42,157 +39,14 @@ import numpy as np
 
 from ...models.causal_lm import init_cache
 from ...observability.trace import get_tracer
-
-
-# Slot-pool movers at MODULE level (shape-keyed jit singletons), same reason
-# as the paged movers below: a pool is rebuilt on every reset_pool (failure
-# recovery) and per serving lane, and per-instance jitted closures re-paid
-# their XLA compile each time.
-@functools.lru_cache(maxsize=None)
-def _slot_scatter_jit():
-    def scatter(caches, one, slot):
-        # every array of a slot-row pool is slot-major, whatever its layer keeps
-        return [{key: c[key].at[slot].set(o[key][0]) for key in c}
-                for c, o in zip(caches, one)]
-
-    return jax.jit(scatter, donate_argnums=(0,))
-
-
-@functools.lru_cache(maxsize=None)
-def _slot_zero_jit():
-    def zero_fill(caches, slot):
-        return [{key: c[key].at[slot].set(0.0) for key in c} for c in caches]
-
-    return jax.jit(zero_fill, donate_argnums=(0,))
-
-
-@functools.lru_cache(maxsize=None)
-def _slot_gather_jit(R: int):
-    def gather(caches, slot):
-        out = []
-        for c in caches:
-            _, hk, _, d = c["k"].shape
-            out.append({
-                "k": jax.lax.dynamic_slice(
-                    c["k"], (slot, 0, 0, 0), (1, hk, R, d))[0],
-                "v": jax.lax.dynamic_slice(
-                    c["v"], (slot, 0, 0, 0), (1, hk, R, d))[0]})
-        return out
-
-    return jax.jit(gather)
-
-
-@functools.lru_cache(maxsize=None)
-def _slot_restore_jit():
-    def restore(caches, slab, slot):
-        out = []
-        for c, s in zip(caches, slab):
-            out.append({
-                "k": jax.lax.dynamic_update_slice(
-                    c["k"], s["k"][None].astype(c["k"].dtype),
-                    (slot, 0, 0, 0)),
-                "v": jax.lax.dynamic_update_slice(
-                    c["v"], s["v"][None].astype(c["v"].dtype),
-                    (slot, 0, 0, 0))})
-        return out
-
-    return jax.jit(restore, donate_argnums=(0,))
-
-
-class SlotKVPool:
-    """Fixed ``slots × cap`` KV buffers with acquire/release slot recycling."""
-
-    def __init__(self, model_config, slots: int, cap: int, dtype=None):
-        if slots < 1 or cap < 2:
-            raise ValueError(f"need slots >= 1 and cap >= 2, got {slots}, {cap}")
-        self.slots = int(slots)
-        self.cap = int(cap)
-        self.caches = init_cache(model_config, self.slots, self.cap, dtype=dtype)
-        self._free: List[int] = list(range(self.slots))
-        # pool buffers donated unconditionally: the old ones are always dead after
-        # the update (the prefill's batch-1 cache is NOT donatable — its (1, ...)
-        # buffers cannot alias any (slots, ...) output)
-        self._scatter_fn = _slot_scatter_jit()
-        self._zero_fn = _slot_zero_jit()
-
-    # ------------------------------------------------------------ slot lifecycle
-    def can_admit(self, tokens: Optional[int] = None, matched: int = 0) -> bool:
-        """Shared admission protocol with :class:`PagedKVPool` — here a slot
-        IS the reservation, so only slot availability matters."""
-        return bool(self._free)
-
-    def acquire(self, tokens: Optional[int] = None, prefix_pages=None,
-                matched: int = 0) -> Optional[int]:
-        """Borrow a free slot index, or ``None`` when the pool is full.
-        ``tokens``/``prefix_pages``/``matched`` are accepted for protocol
-        parity with :class:`PagedKVPool` and ignored (a slot reserves ``cap``
-        regardless)."""
-        return self._free.pop(0) if self._free else None
-
-    def release(self, slot: int) -> None:
-        """Zero-fill ``slot`` and return it to the free list — a recycled slot must
-        never leak the previous request's KV into a new prefill/decode."""
-        if slot in self._free:
-            raise ValueError(f"slot {slot} is already free")
-        self.caches = self._zero_fn(self.caches, np.int32(slot))
-        self._free.append(slot)
-
-    def scatter_prefill(self, slot: int, one_caches: List[Dict[str, Any]]) -> None:
-        """Write a prefill's batch-1 per-layer cache into row ``slot``."""
-        self.caches = self._scatter_fn(self.caches, one_caches, np.int32(slot))
-
-    # --------------------------------------------------------- prefix-cache I/O
-    def gather_prefix(self, slot: int, rows: int) -> List[Dict[str, Any]]:
-        """Copy rows ``[0, rows)`` of ``slot`` out as an independent KV slab
-        (per-layer ``{"k": (hk, rows, d), "v": ...}``) — the prefix-cache
-        insert path, and the slab disaggregated prefill will ship to decode
-        replicas. NOT donated: the pool keeps serving; the slab's lifetime is
-        the trie's, so pool rebuilds after faults never invalidate it."""
-        R = int(rows)
-        if not 0 < R <= self.cap:
-            raise ValueError(f"rows must be in [1, cap={self.cap}], got {R}")
-        return _slot_gather_jit(R)(self.caches, np.int32(slot))
-
-    def slab_nbytes(self, rows: int) -> int:
-        """Host-side size of a ``rows``-row slab — lets callers apply byte
-        budgets BEFORE paying the device gather."""
-        total = 0
-        for c in self.caches:
-            if "k" in c:
-                _, hk, _, d = c["k"].shape
-                total += 2 * hk * int(rows) * d * c["k"].dtype.itemsize
-        return total
-
-    def restore_prefix(self, slot: int, slab: List[Dict[str, Any]]) -> None:
-        """Write a gathered KV slab into rows ``[0, slab_rows)`` of ``slot`` —
-        the donated scatter on the cache-hit path (``scatter_prefill``'s
-        prefix-restore sibling). The pool buffers are donated (the old ones are
-        dead after the update); the slab is NOT (it stays resident in the
-        trie for the next hit)."""
-        R = int(slab[0]["k"].shape[1])
-        if R > self.cap:
-            raise ValueError(f"slab rows {R} exceed pool cap {self.cap}")
-        self.caches = _slot_restore_jit()(self.caches, slab, np.int32(slot))
-
-    # ------------------------------------------------------------------ metrics
-    @property
-    def free_slots(self) -> int:
-        return len(self._free)
-
-    @property
-    def occupancy(self) -> float:
-        return 1.0 - len(self._free) / self.slots
-
-    @property
-    def paged(self) -> bool:
-        return False
+from ...ops.paged_attention import pages_to_dense, write_dense_pages
 
 
 NULL_PAGE = 0      # reserved sentinel: pads every table row; rows it could
 #   contribute are always masked by cache_len, writes to it are dead stores
 
 
-# Paged movers live at MODULE level (lru_cache + jit-by-shape), not on the
+# The movers live at MODULE level (lru_cache + jit-by-shape), not on the
 # pool instance: a pool is rebuilt on every reset_pool (failure recovery) and
 # per serving lane, and per-instance jitted closures re-paid their XLA compile
 # each time — measured at ~0.15 s per pool, which dominated short serving
@@ -204,23 +58,13 @@ def _paged_scatter_jit():
         # write a prefill's dense batch-1 cache into the slot's pages; rows
         # beyond cap pad with zeros into the (dead) null page. A layer's
         # per-slot state (no "k") is written whole into row ``slot``.
-        mp = tbl.shape[0]
         out = []
         for c, o in zip(caches, one):
             if "k" not in c:
                 out.append({key: c[key].at[slot].set(o[key][0].astype(c[key].dtype))
                             for key in c})
                 continue
-            _, hk, cap_r, d = o["k"].shape
-            ps = c["k"].shape[2]
-            pad = ((0, 0), (0, mp * ps - cap_r), (0, 0))
-            k = jnp.pad(o["k"][0], pad).reshape(hk, mp, ps, d)
-            v = jnp.pad(o["v"][0], pad).reshape(hk, mp, ps, d)
-            out.append({
-                "k": c["k"].at[tbl].set(
-                    k.transpose(1, 0, 2, 3).astype(c["k"].dtype)),
-                "v": c["v"].at[tbl].set(
-                    v.transpose(1, 0, 2, 3).astype(c["v"].dtype))})
+            out.append(write_dense_pages(c, o, tbl))
         return out
 
     return jax.jit(scatter, donate_argnums=(0,))
@@ -250,9 +94,8 @@ def _paged_gather_jit(R: int):
     def gather(caches, tbl):
         out = []
         for c in caches:
-            _, hk, ps, d = c["k"].shape
-            k = c["k"][tbl].transpose(1, 0, 2, 3).reshape(hk, -1, d)
-            v = c["v"][tbl].transpose(1, 0, 2, 3).reshape(hk, -1, d)
+            k = pages_to_dense(c["k"], tbl)
+            v = pages_to_dense(c["v"], tbl)
             out.append({"k": k[:, :R, :], "v": v[:, :R, :]})
         return out
 
@@ -262,20 +105,7 @@ def _paged_gather_jit(R: int):
 @functools.lru_cache(maxsize=None)
 def _paged_restore_jit(R: int):
     def restore(caches, slab, tbl):
-        n = tbl.shape[0]
-        out = []
-        for c, s in zip(caches, slab):
-            hk, _, d = s["k"].shape
-            ps = c["k"].shape[2]
-            pad = ((0, 0), (0, n * ps - R), (0, 0))
-            k = jnp.pad(s["k"], pad).reshape(hk, n, ps, d)
-            v = jnp.pad(s["v"], pad).reshape(hk, n, ps, d)
-            out.append({
-                "k": c["k"].at[tbl].set(
-                    k.transpose(1, 0, 2, 3).astype(c["k"].dtype)),
-                "v": c["v"].at[tbl].set(
-                    v.transpose(1, 0, 2, 3).astype(c["v"].dtype))})
-        return out
+        return [write_dense_pages(c, s, tbl) for c, s in zip(caches, slab)]
 
     return jax.jit(restore, donate_argnums=(0,))
 
@@ -285,8 +115,8 @@ class PagedKVPool:
     docstring). ``cap`` is the per-slot row capacity the compiled fns see —
     pages round it UP internally (``max_pages = ceil(cap / page)``) but every
     dense view the model computes over is sliced back to exactly ``cap`` rows,
-    so attention math (reduction shapes included) is bit-identical to the
-    slot-row pool's."""
+    so attention math (reduction shapes included) is bit-identical to a
+    contiguous ``cap``-row cache's."""
 
     def __init__(self, model_config, slots: int, cap: int, page_size: int = 16,
                  dtype=None, total_pages: Optional[int] = None):
@@ -299,8 +129,8 @@ class PagedKVPool:
         self.page_size = ps = int(page_size)
         self.max_pages = mp = math.ceil(self.cap / ps)   # table width per slot
         if total_pages is None:
-            # default budget matches the slot-row pool's HBM exactly (plus the
-            # one null page): same bytes, page-granular occupancy
+            # default budget: every slot's whole cap at once (plus the one
+            # null page)
             total_pages = self.slots * mp + 1
         self.total_pages = P = int(total_pages)
         if P < mp + 1:
@@ -329,10 +159,11 @@ class PagedKVPool:
         self._slot_npages = np.zeros(self.slots, np.int32)
         self._slot_tokens = np.zeros(self.slots, np.int64)  # reserved tokens
         self.cow_copies_total = 0
-        # pool pages donated unconditionally (same contract as SlotKVPool:
-        # the old buffers are always dead after the update); the jitted
-        # movers are module-level shape-keyed singletons — rebuilding a pool
-        # after a failure (or per serving lane) must not re-pay XLA compiles
+        # pool pages donated unconditionally (the old buffers are always
+        # dead after the update; the prefill's batch-1 cache is NOT donatable:
+        # its buffers cannot alias any page); the jitted movers are
+        # module-level shape-keyed singletons — rebuilding a pool after a
+        # failure (or per serving lane) must not re-pay XLA compiles
         self._scatter_fn = _paged_scatter_jit()
         self._cow_fn = _paged_cow_jit()
 
@@ -443,8 +274,8 @@ class PagedKVPool:
     # ----------------------------------------------------- prefix page sharing
     def share_prefix(self, slot: int, tokens: int) -> np.ndarray:
         """Refcount-bump the slot's pages covering rows ``[0, tokens)`` and
-        return their indices — the prefix cache's zero-copy insert (the paged
-        replacement for the slab gather). The boundary page is shared too: a
+        return their indices — the prefix cache's zero-copy insert. The
+        boundary page is shared too: a
         later hit only trusts its rows below the matched length and
         copy-on-writes before writing."""
         n = self.pages_for(tokens)
@@ -474,21 +305,12 @@ class PagedKVPool:
     def scatter_prefill(self, slot: int, one_caches: List[Dict[str, Any]]) \
             -> None:
         """Write a prefill's dense batch-1 per-layer cache into the slot's
-        pages (the miss-path sibling of the slot pool's row scatter)."""
+        pages (and the slot's per-slot state, where a layer keeps one)."""
         self.caches = self._scatter_fn(self.caches, one_caches,
                                        jnp.asarray(self.page_table[slot]),
                                        np.int32(slot))
 
     # --------------------------------------------------------- slab I/O (wire)
-    def slab_nbytes(self, rows: int) -> int:
-        """Host-side size of a dense ``rows``-row slab (serialization API)."""
-        total = 0
-        for c in self.caches:
-            if "k" in c:
-                _, hk, _, d = c["k"].shape
-                total += 2 * hk * int(rows) * d * c["k"].dtype.itemsize
-        return total
-
     def gather_prefix(self, slot: int, rows: int) -> List[Dict[str, Any]]:
         """Copy rows ``[0, rows)`` of ``slot`` out as an independent dense KV
         slab — the page-granular serialization API disaggregated prefill
@@ -569,10 +391,6 @@ class PagedKVPool:
 
     # ------------------------------------------------------------------ metrics
     @property
-    def paged(self) -> bool:
-        return True
-
-    @property
     def free_slots(self) -> int:
         return len(self._free_slots)
 
@@ -586,8 +404,8 @@ class PagedKVPool:
 
     @property
     def occupancy(self) -> float:
-        """SLOT occupancy — same quantity (and autoscaler signal semantics)
-        as the slot-row pool; page-level utilisation is in :meth:`stats`."""
+        """SLOT occupancy (the autoscaler's signal); page-level utilisation
+        is in :meth:`stats`."""
         return 1.0 - len(self._free_slots) / self.slots
 
     @property

@@ -1,18 +1,20 @@
 """Radix prompt-prefix KV cache: shared system prompts skip prefill.
 
-A path-compressed trie over token-ID prefixes whose entries hold **KV slabs** —
-per-layer ``{"k": (hk, R, d), "v": (hk, R, d)}`` device arrays gathered from a
-:class:`~.kv_pool.SlotKVPool` slot after that prompt's prefill (rows padded to
-the prompt's power-of-two bucket ``R``; the real covered length is the entry's
-trie depth). On admission the scheduler walks the trie, splits the prompt into
-``cached_prefix + suffix``, restores the slab into the slot and prefills only
-the suffix — a hit costs one suffix-bucket forward instead of a full-prompt
+A path-compressed trie over token-ID prefixes whose entries hold a prompt's
+**KV rows**: refcounted page indices into the :class:`~.kv_pool.PagedKVPool`
+(shared, zero-copy, after that prompt's prefill; the real covered length is
+the entry's trie depth), or a dense slab — per-layer ``{"k": (hk, R, d), "v":
+(hk, R, d)}`` — on the host tier. On admission the scheduler walks the trie,
+splits the prompt into ``cached_prefix + suffix``, binds the pages into the
+slot's table (or restores the slab into its pages) and prefills only the
+suffix — a hit costs one suffix-bucket forward instead of a full-prompt
 prefill (the serving-side analogue of SGLang's RadixAttention, specialized to
 this codebase's fixed-shape compiled-chunk world).
 
 Two residency rungs share the one trie. The **device rung** (``_lru``) holds
-hot entries under ``max_bytes`` of HBM — gathered slabs on the slot pool,
-refcounted page indices on the paged pool. When ``host_tier_bytes > 0``, an
+hot entries under ``max_bytes`` of HBM as refcounted page indices (a slab
+handed to :meth:`PrefixCache.insert` lives there too: the scheduler makes
+none). When ``host_tier_bytes > 0``, an
 LRU eviction from the device rung **spills**: the entry's KV is gathered into
 a dense host-numpy slab (the ``gather_prefix`` wire format) and the entry
 moves to the **host rung** (``_host``) under its own byte budget. A lookup
@@ -116,12 +118,13 @@ def match_from_digests(prompt, digests) -> int:
 class _Entry:
     """A cached prefix anchored at a trie node (depth == covered tokens).
 
-    Three storage forms: ``slab`` as device arrays — an independent gathered
-    per-layer KV copy (slot-row pool, device rung); ``pages`` — REFCOUNTED
-    physical page indices into the paged pool (zero-copy: a hit binds them
-    into the new slot's table, an eviction is a refcount drop via the owner's
+    Two storage forms the scheduler makes: ``pages`` — REFCOUNTED physical
+    page indices into the pool (zero-copy: a hit binds them into the new
+    slot's table, an eviction is a refcount drop via the owner's
     ``page_release`` hook); ``slab`` as host numpy — a spilled dense copy on
-    the host rung, restored device-side on a promote hit."""
+    the host rung, restored device-side on a promote hit. (A ``slab`` given
+    to :meth:`PrefixCache.insert` directly sits on the device rung as it
+    is.)"""
     __slots__ = ("slab", "tokens", "bytes", "node", "pages")
 
     def __init__(self, slab: Optional[List[Dict]], tokens: int, node: "_Node",
@@ -160,14 +163,14 @@ class PrefixCache:
 
     def __init__(self, config: Optional[PrefixCacheConfig] = None):
         self.config = config or PrefixCacheConfig()
-        # paged mode: the pool's release_shared, set by the owning scheduler —
+        # the pool's release_shared, set by the owning scheduler —
         # LRU eviction of a page entry decrefs through it, and so does
         # clear(): against a still-live pool (idle-replica revive) the pages
         # must return to the free list or they leak forever; against a pool
         # about to be discarded (_rebuild_pool) the decref is harmless.
         self.page_release = None
-        # paged-mode spill hook: gather_pages(pages, rows) -> dense slab, set
-        # by the owning scheduler. Without it a paged eviction cannot spill
+        # spill hook: gather_pages(pages, rows) -> dense slab, set by the
+        # owning scheduler. Without it a page entry's eviction cannot spill
         # (there is no dense copy to keep) and falls back to a plain drop.
         self.page_gather = None
         self.root = _Node(np.zeros(0, np.int32), None, 0)
@@ -322,7 +325,7 @@ class PrefixCache:
         return True
 
     def insert_pages(self, prompt, pages, nbytes: int) -> bool:
-        """Paged-pool insert: index refcounted page indices under the prompt
+        """The scheduler's insert: index refcounted page indices under the prompt
         path. Returns True when the cache TOOK OWNERSHIP of the caller's page
         references; False (too short / over budget / already device-resident)
         means the caller must release them. A host-rung entry at the path is
@@ -394,8 +397,8 @@ class PrefixCache:
 
     def evict_lru(self, predicate=None) -> bool:
         """Evict the least-recently-used device entry matching ``predicate``
-        (admission-pressure eviction: on the paged pool, cached prefixes pin
-        real pool pages, so when admission runs out of free pages the
+        (admission-pressure eviction: cached prefixes pin real pool
+        pages, so when admission runs out of free pages the
         scheduler trades cold cached prefixes for admission capacity). The
         predicate lets the caller skip entries whose eviction would free
         nothing — an entry all of whose pages are still bound by live slots
@@ -478,8 +481,7 @@ class PrefixCache:
         the slabs/pages live in was poisoned by a donation-consumed failure,
         so gathering from it is not trustworthy). Host-rung entries are
         independent numpy buffers and survive to serve promote hits against
-        the rebuilt pool — the tiered analogue of the slot pool's
-        "independent slabs survive rebuilds" property."""
+        the rebuilt pool."""
         for entry in list(self._lru.values()):
             self._remove(entry, spill=False)
 

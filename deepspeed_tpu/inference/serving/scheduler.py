@@ -101,14 +101,14 @@ class ServingConfig:
     base_seed: int = 0
     chunk_deadline_s: Optional[float] = None   # per-chunk watchdog (None = off)
     prefix_cache: Optional[PrefixCacheConfig] = None   # None = cache off
-    # KV memory shape: "paged" (default) = global fixed-size pages behind
-    # per-slot page tables, page-count admission, zero-copy refcounted prefix
-    # sharing; "slots" = the legacy slot-row pool (one cap-row reservation
-    # per slot). Greedy output is bit-identical either way.
+    # the one KV pool: global fixed-size pages behind per-slot page tables,
+    # page-count admission, zero-copy refcounted prefix sharing. The field
+    # has one value and nothing reads it; it stays until the benchmark's
+    # serve_closed.py stops passing it (ROADMAP D14)
     kv_pool: str = "paged"
     kv_page_size: int = 16
-    kv_total_pages: Optional[int] = None   # HBM budget in pages (None = match
-    #   the slot-row pool's bytes: slots * ceil(cap/page) + the null page)
+    kv_total_pages: Optional[int] = None   # HBM budget in pages (None = every
+    #   slot's whole cap: slots * ceil(cap/page) + the null page)
     # speculative decoding: every decode chunk becomes ONE draft-propose /
     # one-pass-verify round (greedy output stays bit-identical; sampled keeps
     # the per-slot key-stream distribution exactly — see inference.speculative)
@@ -118,6 +118,12 @@ class ServingConfig:
     spec_ngram_max: int = 4
     spec_ngram_min: int = 1
     spec_draft_engine: object = None    # tiny engine for "draft_model"
+
+    def __post_init__(self):
+        if self.kv_pool != "paged":
+            raise ValueError(
+                f"kv_pool={self.kv_pool!r}: the slot-row pool was removed; "
+                "'paged' is the one KV pool")
 
 
 def validate_admission(prompt, max_new_tokens: Optional[int],
@@ -195,7 +201,7 @@ class ContinuousBatchingScheduler:
             do_sample=cfg.do_sample, temperature=cfg.temperature,
             top_k=cfg.top_k, top_p=cfg.top_p,
             max_prompt_len=cfg.max_prompt_len, base_seed=cfg.base_seed,
-            chunk_deadline_s=cfg.chunk_deadline_s, kv_pool=cfg.kv_pool,
+            chunk_deadline_s=cfg.chunk_deadline_s,
             kv_page_size=cfg.kv_page_size, kv_total_pages=cfg.kv_total_pages)
         self.cap = cap
         if not self.executor.kv_every_layer:
@@ -235,17 +241,16 @@ class ContinuousBatchingScheduler:
         self.prefix_cache: Optional[PrefixCache] = None
         if cfg.prefix_cache is not None and cfg.prefix_cache.enabled:
             self.prefix_cache = PrefixCache(cfg.prefix_cache)
-            if self.executor.paged:
-                # LRU eviction of a page entry decrefs against the CURRENT
-                # pool (any pool swap clears the cache first, so an entry's
-                # pages always belong to the pool this resolves to)
-                self.prefix_cache.page_release = \
-                    lambda pages: self.executor.pool.release_shared(pages)
-                # spill path: gather an evicted entry's pages as a dense host
-                # slab (the gather_prefix wire format) before the refs drop
-                self.prefix_cache.page_gather = \
-                    lambda pages, rows: self.executor.pool.gather_pages(
-                        pages, rows)
+            # LRU eviction of a page entry decrefs against the CURRENT
+            # pool (any pool swap clears the cache first, so an entry's
+            # pages always belong to the pool this resolves to)
+            self.prefix_cache.page_release = \
+                lambda pages: self.executor.pool.release_shared(pages)
+            # spill path: gather an evicted entry's pages as a dense host
+            # slab (the gather_prefix wire format) before the refs drop
+            self.prefix_cache.page_gather = \
+                lambda pages, rows: self.executor.pool.gather_pages(
+                    pages, rows)
         self.queue: Deque[RequestHandle] = deque()
         self._ids = itertools.count()
         S = cfg.slots
@@ -336,7 +341,7 @@ class ContinuousBatchingScheduler:
                     len(self.queue), pool.occupancy,
                     prefix_stats=(None if self.prefix_cache is None
                                   else self.prefix_cache.stats()),
-                    paged_stats=(pool.stats() if pool.paged else None))
+                    paged_stats=pool.stats())
         return admitted or decoded
 
     def run(self, max_steps: int = 100000) -> dict:
@@ -351,24 +356,19 @@ class ContinuousBatchingScheduler:
     # ------------------------------------------------------------ prefix cache
     def _insert_prefix(self, handle: RequestHandle, slot: int) -> None:
         """Index the slot's prompt KV in the trie under the full prompt token
-        path. Paged pool: SHARE the slot's prompt-covering pages (refcount
-        bump — zero-copy, no device gather at all). Slot-row pool: gather a
-        slab copy (padded to the prompt bucket) as before."""
+        path: SHARE the slot's prompt-covering pages (refcount bump —
+        zero-copy, no device gather at all)."""
         if self.prefix_cache is None:
             return
         with self._tracer.span("serving.prefix_insert"):
-            self._insert_prefix_pages(handle, slot)
-
-    def _insert_prefix_pages(self, handle: RequestHandle, slot: int) -> None:
-        P = int(handle.prompt.size)
-        if P < self.prefix_cache.config.min_insert_tokens:
-            self.prefix_cache.insert_skipped += 1
-            return                   # skip the device gather, not just the insert
-        if self.prefix_cache.contains(handle.prompt):
-            return                   # resident (LRU refreshed): same tokens ⇒
-            #   bit-identical slab, don't pay the gather to drop it
-        pool = self.executor.pool
-        if pool.paged:
+            P = int(handle.prompt.size)
+            if P < self.prefix_cache.config.min_insert_tokens:
+                self.prefix_cache.insert_skipped += 1
+                return
+            if self.prefix_cache.contains(handle.prompt):
+                return               # resident (LRU refreshed): same tokens ⇒
+                #   the same rows, nothing to share again
+            pool = self.executor.pool
             nbytes = pool.pages_for(P) * pool.page_nbytes
             if nbytes > self.prefix_cache.config.max_bytes:
                 self.prefix_cache.insert_skipped += 1
@@ -377,13 +377,6 @@ class ContinuousBatchingScheduler:
             if not self.prefix_cache.insert_pages(handle.prompt, pages,
                                                   nbytes):
                 pool.release_shared(pages)   # resident/refused: drop our refs
-            return
-        rows = self.executor.bucket_for(P)
-        if pool.slab_nbytes(rows) > self.prefix_cache.config.max_bytes:
-            self.prefix_cache.insert_skipped += 1
-            return                   # could never fit: skip the gather too
-        slab = pool.gather_prefix(slot, rows)
-        self.prefix_cache.insert(handle.prompt, slab)
 
     def _retire_prefix(self, handle: RequestHandle, slot: int) -> None:
         """Completion-path insert hook: runs for every request leaving a slot
@@ -429,14 +422,13 @@ class ContinuousBatchingScheduler:
 
     def _rebuild_pool(self) -> None:
         """Discard + rebuild the KV pool after a failure that may have
-        consumed donated buffers. On the paged pool the prefix cache's shared
-        pages live INSIDE the discarded buffers, so its device rung is
-        dropped with it (without spilling — gathering from a poisoned pool is
-        not trustworthy) — the honest cost of zero-copy sharing. Host-rung
-        entries are independent numpy slabs and survive to serve promote hits
-        against the rebuilt pool, exactly like slot-mode's independent
-        gathered slabs always have."""
-        if self.executor.paged and self.prefix_cache is not None:
+        consumed donated buffers. The prefix cache's shared pages live INSIDE
+        the discarded buffers, so its device rung is dropped with it (without
+        spilling — gathering from a poisoned pool is not trustworthy) — the
+        honest cost of zero-copy sharing. Host-rung entries are independent
+        numpy slabs and survive to serve promote hits against the rebuilt
+        pool."""
+        if self.prefix_cache is not None:
             self.prefix_cache.drop_device()
         self.executor.reset_pool()
 
@@ -505,11 +497,11 @@ class ContinuousBatchingScheduler:
         while self.queue:
             pool = self.executor.pool    # re-read: a failed hit-prefill below
             head = self.queue[0]         # rebuilds the pool mid-loop
-            # page-count admission: the paged pool admits when the request's
-            # OWN reservation (prompt + budget, page-granular) fits — not when
-            # a whole cap-row slot frees up. Conservative (all-fresh) check:
-            # a prefix hit can only need fewer pages. The slot pool reduces
-            # to its free-slot check. FIFO: a head that doesn't fit waits.
+            # page-count admission: the pool admits when the request's OWN
+            # reservation (prompt + budget, page-granular) fits — not when a
+            # whole cap of rows frees up. Conservative (all-fresh) check: a
+            # prefix hit can only need fewer pages. FIFO: a head that
+            # doesn't fit waits.
             need_tokens = int(head.prompt.size) + int(head.max_new_tokens)
             if self.proposer is not None:
                 # speculation headroom: a verify window writes up to spec_k
@@ -520,7 +512,7 @@ class ContinuousBatchingScheduler:
                 # window near the cap edge.
                 need_tokens = min(need_tokens + self._spec_cfg.k, self.cap)
             if not pool.can_admit(need_tokens):
-                # admission-pressure eviction (paged): cached prefixes pin
+                # admission-pressure eviction: cached prefixes pin
                 # real pool pages, so a full free list trades the coldest
                 # cached prefixes for admission capacity before giving up —
                 # a waiting request always outranks a cold cached prefix.
@@ -535,8 +527,7 @@ class ContinuousBatchingScheduler:
                 # prefixes frees pages, never slots, so a queue blocked on a
                 # full slot set must not drain the cache for zero gain.
                 matched_hint = 0
-                if pool.paged and self.prefix_cache is not None \
-                        and pool.free_slots > 0:
+                if self.prefix_cache is not None and pool.free_slots > 0:
                     matched_hint, keep = self.prefix_cache.peek(head.prompt)
                     if keep is not None and keep.pages is None:
                         # host-rung match: the promote path acquires all-fresh
@@ -579,16 +570,15 @@ class ContinuousBatchingScheduler:
             with tracer.span("serving.prefix_lookup") as lk:
                 matched, entry = self.prefix_cache.lookup(handle.prompt)
                 lk.set(hit=int(entry is not None), matched_tokens=int(matched))
-        if pool.paged and entry is not None and entry.pages is not None:
+        if entry is not None and entry.pages is not None:
             # zero-copy hit: bind the shared prefix pages into the fresh
-            # slot's table (refcount bump + one COW boundary page) — the
-            # paged replacement for the slab restore scatter
+            # slot's table (refcount bump + one COW boundary page)
             slot = pool.acquire(need_tokens, prefix_pages=entry.pages,
                                 matched=matched)
         else:
-            # miss, slot-pool hit, or host-rung PROMOTE hit (entry with a
-            # spilled numpy slab): all-fresh pages; the promote restores
-            # the slab into them inside prefill_into_slot
+            # miss, or host-rung PROMOTE hit (entry with a spilled numpy
+            # slab): all-fresh pages; the promote restores the slab into
+            # them inside prefill_into_slot
             slot = pool.acquire(need_tokens)
         if slot is None:       # can_admit is conservative, so only a racing
             self.queue.appendleft(handle)              # caller could land here

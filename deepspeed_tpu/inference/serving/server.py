@@ -150,8 +150,10 @@ def make_status_provider(front, autoscaler=None, recorder=None,
                     "deferred": tel.deferred, "expired": tel.expired,
                     "handed_off": tel.handed_off},
             })
-            pools = [r.scheduler.executor.pool for r in front.replicas]
-            paged = [p.stats() for p in pools if p.paged]
+            # a hosted replica's pages are its child's: only the pools in
+            # this process have page counts to add up
+            paged = [r.scheduler.executor.pool.stats() for r in front.replicas
+                     if not getattr(r, "is_hosted", False)]
             if paged:
                 doc["pages"] = {
                     "pages_in_use": sum(p["pages_in_use"] for p in paged),
@@ -204,8 +206,7 @@ def make_status_provider(front, autoscaler=None, recorder=None,
                              "evicted": tel.evicted,
                              "tokens_total": tel.tokens_total},
             })
-            if pool.paged:
-                doc["pages"] = pool.stats()
+            doc["pages"] = pool.stats()
             if front.prefix_cache is not None:
                 doc["prefix_hit_rate"] = front.prefix_hit_rate
             if getattr(tel, "spec_enabled", False):
@@ -445,13 +446,8 @@ def main(argv=None) -> int:
                     help="training checkpoint dir to serve")
     ap.add_argument("--slots", type=int, default=4)
     ap.add_argument("--chunk-size", type=int, default=8)
-    ap.add_argument("--kv-pool", default="paged", choices=("paged", "slots"),
-                    help="KV memory shape: 'paged' (default) = fixed-size "
-                         "pages behind per-slot page tables (page-count "
-                         "admission, zero-copy refcounted prefix sharing); "
-                         "'slots' = legacy cap-row-per-slot pool")
     ap.add_argument("--kv-page-size", type=int, default=16,
-                    help="KV page size in tokens (paged pool; default 16). "
+                    help="KV page size in tokens (default 16). "
                          "Must be a positive multiple of --chunk-size so "
                          "page boundaries stay chunk-aligned")
     ap.add_argument("--speculate", action="store_true",
@@ -627,9 +623,7 @@ def main(argv=None) -> int:
             host_tier_bytes=int(args.prefix_tier_mb * 1024 * 1024),
             min_hit_tokens=args.prefix_min_hit,
             min_insert_tokens=args.prefix_min_hit)
-    if args.kv_pool == "paged" and (
-            args.kv_page_size < 1
-            or args.kv_page_size % args.chunk_size != 0):
+    if args.kv_page_size < 1 or args.kv_page_size % args.chunk_size != 0:
         raise SystemExit(
             f"--kv-page-size {args.kv_page_size} must be a positive multiple "
             f"of --chunk-size {args.chunk_size} (page boundaries stay "
@@ -639,7 +633,6 @@ def main(argv=None) -> int:
                                 max_seq_len=args.max_seq_len,
                                 chunk_deadline_s=args.chunk_deadline,
                                 prefix_cache=prefix_cfg,
-                                kv_pool=args.kv_pool,
                                 kv_page_size=args.kv_page_size,
                                 speculate=args.speculate, spec_k=args.spec_k,
                                 spec_ngram_max=args.spec_ngram_max)
@@ -694,7 +687,7 @@ def main(argv=None) -> int:
                                 else None),
                 prefix_min_hit=(args.prefix_min_hit
                                 if args.prefix_cache else None),
-                kv_pool=args.kv_pool, kv_page_size=args.kv_page_size,
+                kv_page_size=args.kv_page_size,
                 chunk_deadline_s=args.chunk_deadline)
             if args.replica_endpoint:
                 # adopt running children: the endpoint list IS the fleet

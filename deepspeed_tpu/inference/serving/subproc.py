@@ -135,7 +135,6 @@ def child_main(argv=None) -> int:
     ap.add_argument("--prefix-cache-mb", type=float, default=None)
     ap.add_argument("--prefix-tier-mb", type=float, default=None)
     ap.add_argument("--prefix-min-hit", type=int, default=4)
-    ap.add_argument("--kv-pool", default="paged", choices=("paged", "slots"))
     ap.add_argument("--kv-page-size", type=int, default=None)
     ap.add_argument("--chunk-deadline", type=float, default=None)
     # socket transport (net.py): serve protocol v1 over framed TCP instead of
@@ -192,7 +191,7 @@ def child_main(argv=None) -> int:
     sched = ContinuousBatchingScheduler(engine, ServingConfig(
         slots=args.slots, chunk_size=args.chunk_size,
         max_seq_len=args.max_seq_len, prefix_cache=prefix,
-        kv_pool=args.kv_pool, chunk_deadline_s=args.chunk_deadline,
+        chunk_deadline_s=args.chunk_deadline,
         **page_kw))
 
     out = sys.stdout

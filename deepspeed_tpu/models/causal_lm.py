@@ -582,7 +582,7 @@ class CausalLMLayer(nn.Module):
         ``{"k": (P, hk, page, d), ...}`` and the ``(b, max_pages)`` table maps
         each row's positions to physical pages — the step appends K/V at the
         page-mapped row and attends through the paged-attention op (XLA dense
-        gather sliced to ``kv_cap`` rows = bit-identical to the slot-row
+        gather sliced to ``kv_cap`` rows = bit-identical to a contiguous
         cache; Pallas gather-by-page-index kernel on TPU).
         Returns (y, new_cache_kv or None)."""
         cfg = self.config

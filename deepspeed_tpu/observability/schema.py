@@ -219,8 +219,8 @@ SPANS: Dict[str, Tuple[str, str, Tuple[str, ...], str]] = {
                            "kv_restore"),
     "serving.restore_prefix": (BOTH, "serve scheduler",
                                ("slot", "prefix_len", "promoted"),
-                               "attribution phase kv_restore (slab restore, "
-                               "host-tier promote)"),
+                               "attribution phase kv_restore (the host "
+                               "tier's slab restored into fresh pages)"),
     "serving.prefill": (BOTH, "compiled steps",
                         ("request_id", "bucket", "tokens", "prefix_len",
                          "moe_assignments", "moe_experts_touched"),

@@ -9,16 +9,22 @@ could contribute is masked by ``cache_len``). All shapes are static: the page
 count ``P``, the per-slot table width and the page size are compile-time
 constants, so a slot serving an 8-token prompt and one serving a 500-token
 prompt hit the SAME compiled chunk — page-count growth never mints a compile
-key (pinned by the analysis sweep's paged lane).
+key (pinned by the analysis sweep's serving lane).
+
+This module is the one place that knows the page layout ``(P, h_kv, page,
+d)``: pages -> dense rows (:func:`gather_kv_dense`, :func:`pages_to_dense`),
+dense rows -> whole pages (:func:`write_dense_pages`) and row -> (page,
+offset) (:func:`page_address`, :func:`paged_cache_update`). The pool's movers
+and the serve programs call these.
 
 Two implementations, PR-5 style:
 
 - :func:`paged_attention_xla` — ground truth: gather the slot's pages into the
   dense head-major ``(b, h_kv, cap, d)`` view and run the EXACT same masked
   softmax as ``decode_attention_xla``. Because the gathered view is
-  element-identical to what the slot-row pool holds (and sliced to exactly
-  ``cap`` rows), greedy decode through this path is **bit-identical** to the
-  slot-row pool — the property every serving parity lane leans on.
+  element-identical to the contiguous cache ``engine.generate`` decodes over
+  (and sliced to exactly ``cap`` rows), greedy decode through this path is
+  **bit-identical** to it — the property every serving parity test leans on.
 - :func:`paged_attention` — the fused Pallas kernel: grid over slots, K/V
   pages DMA'd HBM→VMEM double-buffered **by page index** (the gather happens
   inside the grid; the dense view is never materialised in HBM), online
@@ -67,8 +73,9 @@ def gather_kv_dense(k_pages, v_pages, page_table, cap: int):
     ``k_pages``/``v_pages``: ``(P, hk, page, d)``; ``page_table``:
     ``(b, max_pages)`` int32. Returns ``(b, hk, cap, d)`` ×2 — rows sliced to
     EXACTLY ``cap`` so downstream attention math (reduction shapes included)
-    is identical to the slot-row pool's, keeping greedy bit-exact even when
-    ``cap`` is not a page multiple (pages round it up internally)."""
+    is identical to a contiguous ``cap``-row cache's, keeping greedy
+    bit-exact even when ``cap`` is not a page multiple (pages round it up
+    internally)."""
     kp = k_pages[page_table]                       # (b, mp, hk, page, d)
     vp = v_pages[page_table]
     b, mp, hk, ps, d = kp.shape
@@ -77,10 +84,59 @@ def gather_kv_dense(k_pages, v_pages, page_table, cap: int):
     return k[:, :, :cap, :], v[:, :, :cap, :]
 
 
+def pages_to_dense(pages, tbl):
+    """One table row's form of :func:`gather_kv_dense`, an array at a time:
+    ``pages (P, hk, page, d)`` through ``tbl (n,)`` -> the ``(hk, n * page,
+    d)`` rows those pages hold, in table order. The caller slices to the rows
+    it wants."""
+    hk, d = pages.shape[1], pages.shape[3]
+    return pages[tbl].transpose(1, 0, 2, 3).reshape(hk, -1, d)
+
+
+def write_dense_pages(pages, dense, tbl):
+    """The inverse, a layer at a time: overwrite pages ``tbl (n,)`` of
+    ``pages {"k", "v"}: (P, hk, page, d)`` with the dense rows ``dense {"k",
+    "v"}: (hk, R, d)`` (or the batch of one, ``(1, hk, R, d)``, a prefill
+    returns), zero-padded to ``n`` whole pages."""
+    n, ps = tbl.shape[0], pages["k"].shape[2]
+    blocks = {}
+    for key in ("k", "v"):
+        x = dense[key][0] if dense[key].ndim == 4 else dense[key]
+        hk, R, d = x.shape
+        blocks[key] = jnp.pad(x, ((0, 0), (0, n * ps - R), (0, 0))) \
+            .reshape(hk, n, ps, d)
+    return {key: pages[key].at[tbl].set(
+        blocks[key].transpose(1, 0, 2, 3).astype(pages[key].dtype))
+        for key in ("k", "v")}
+
+
+def page_address(page_table, rows, kv_cap: int, page_size: int,
+                 total_pages: int, live=None):
+    """Dense rows -> ``(page index, offset in the page)`` for a scatter into
+    the pages. ``page_table (S, max_pages)`` with ``rows (S,)`` (a row a
+    slot), or one slot's ``(max_pages,)`` with ``rows (t,)``. A row at or
+    beyond ``kv_cap``, or one ``live`` rules out, gets the out-of-range page
+    ``total_pages``: the scatter drops it, so released or shared pages are
+    never written. ``live`` is a function returning the mask, called once the
+    page position is traced: the chunk and the verify round keep the
+    operation order, and so the compile-cache keys, they had with this
+    arithmetic written out in them."""
+    page_pos = jnp.clip(rows // page_size, 0, page_table.shape[-1] - 1)
+    keep = rows < kv_cap if live is None else live() & (rows < kv_cap)
+    if page_table.ndim == 1:
+        pidx = jnp.where(keep, page_table[page_pos], total_pages)
+    else:
+        pidx = jnp.where(keep,
+                         jnp.take_along_axis(page_table, page_pos[:, None],
+                                             axis=1)[:, 0],
+                         total_pages)
+    return pidx, rows % page_size
+
+
 def paged_attention_xla(q, k_pages, v_pages, page_table, cache_len, cap: int,
                         softmax_scale=None):
-    """Ground-truth paged decode attention: dense gather + the slot-row
-    pool's exact masked-softmax math (``decode_attention_xla``)."""
+    """Ground-truth paged decode attention: dense gather + the contiguous
+    cache's exact masked-softmax math (``decode_attention_xla``)."""
     k, v = gather_kv_dense(k_pages, v_pages, page_table, cap)
     return decode_attention_xla(q, k, v, cache_len, softmax_scale)
 
@@ -223,9 +279,9 @@ def paged_attention(q, k_pages, v_pages, page_table, cache_len, cap: int,
                     softmax_scale=None):
     """Dispatch: fused kernel on TPU (or under ``DS_TPU_PAGED_FORCE_FUSED=1``
     interpret mode), XLA dense-gather ground truth otherwise. The XLA path is
-    the default on CPU hosts — it is bit-identical to the slot-row pool, which
-    is what the serving parity lanes gate on; the kernel carries its own
-    numerical parity test."""
+    the default on CPU hosts — it is bit-identical to a contiguous cache,
+    which is what the serving parity tests gate on; the kernel carries its
+    own numerical parity test."""
     d = q.shape[-1]
     if fused_paged_for(d):
         return paged_attention_fused(q, k_pages, v_pages, page_table,

@@ -281,28 +281,30 @@ def test_loop_invariance_catches_in_body_dequant_on_chunk_fn():
     traces the int8 payload INTO the body — the generalized pass must catch
     it there too, not only in the generate while_loop (the PR 5 pin's gap)."""
     from deepspeed_tpu.inference.config import DeepSpeedInferenceConfig
-    from deepspeed_tpu.inference.decode_fns import (build_decode_chunk,
+    from deepspeed_tpu.inference.decode_fns import (build_paged_decode_chunk,
                                                     make_slot_select_fn)
     from deepspeed_tpu.inference.engine import InferenceEngine
-    from deepspeed_tpu.models.causal_lm import gpt2_cfg, init_cache
+    from deepspeed_tpu.inference.serving.kv_pool import PagedKVPool
+    from deepspeed_tpu.models.causal_lm import gpt2_cfg
     cfg = gpt2_cfg(vocab_size=96, max_seq_len=64, n_embd=32, n_layer=2,
                    n_head=4, dtype=jnp.float32)
     eng = InferenceEngine(cfg, DeepSpeedInferenceConfig(
         dtype="float32", max_out_tokens=32,
         weight_quant={"enabled": True, "bits": 8}))
     select = make_slot_select_fn(False, 1.0, 0, 1.0)
-    caches = init_cache(cfg, 2, 32, dtype=eng.dtype)
-    args = (eng.params, jnp.zeros((2, 1), jnp.int32), caches,
+    pool = PagedKVPool(cfg, 2, 32, page_size=8, dtype=eng.dtype)
+    args = (eng.params, jnp.zeros((2, 1), jnp.int32), pool.caches,
+            jnp.asarray(pool.page_table),
             jnp.full((2,), 8, jnp.int32), jnp.ones((2,), bool),
             jnp.full((2,), 5, jnp.int32), jnp.full((2,), -1, jnp.int32),
             jnp.zeros((2,), jnp.int32), jnp.zeros((2,), jnp.int32),
             jax.random.PRNGKey(0))
-    good = build_decode_chunk(eng.module, eng._dequant, select, 3,
-                              overlap=eng.comm_overlap)
+    good = build_paged_decode_chunk(eng.module, eng._dequant, select, 3,
+                                    kv_cap=32, overlap=eng.comm_overlap)
     assert assert_loop_invariant(good, args, invar_predicate=_INT8,
                                  what="dequant-hoist") >= 1
-    bad = build_decode_chunk(eng.module, lambda p: p, select, 3,
-                             overlap=eng.comm_overlap)
+    bad = build_paged_decode_chunk(eng.module, lambda p: p, select, 3,
+                                   kv_cap=32, overlap=eng.comm_overlap)
     with pytest.raises(LoopInvarianceError, match="dequant-hoist"):
         assert_loop_invariant(bad, args, invar_predicate=_INT8,
                               what="dequant-hoist")
@@ -439,9 +441,14 @@ def test_sweep_lane_runs_clean_on_real_programs(lane_name, eight_devices):
     if lane_name == "serving_lane":
         assert {"retrace", "donation", "loop_invariance",
                 "host_sync_trace"} <= names
-        donation_checked = sum(r.checked for r in report.results
-                               if r.name == "donation")
-        assert donation_checked >= 8       # chunk + pool movers + suffix
+        donation = {r.target: r.checked for r in report.results
+                    if r.name == "donation"}
+        # the chunk and the suffix prefill the cells run, and the pool's
+        # donated movers
+        assert set(donation) == {"serve_chunk", "serve_suffix_prefill",
+                                 "kv_pool.scatter", "kv_pool.cow",
+                                 "kv_pool.state_zero_fill"}
+        assert sum(donation.values()) >= 8 and min(donation.values()) >= 1
     elif lane_name == "train_lane":
         assert {"retrace", "donation"} <= names
         don = next(r for r in report.results if r.name == "donation")

@@ -17,8 +17,7 @@ import numpy as np
 import pytest
 
 import deepspeed_tpu as ds
-from deepspeed_tpu.inference.decode_fns import (build_decode_chunk,
-                                                build_paged_decode_chunk)
+from deepspeed_tpu.inference.decode_fns import build_paged_decode_chunk
 from deepspeed_tpu.inference.engine import InferenceEngine
 from deepspeed_tpu.inference.serving import (ContinuousBatchingScheduler,
                                              ServingConfig)
@@ -55,28 +54,26 @@ def _fresh_tracer():
     t.reset()
 
 
-def _scheduler(engine, pool, sample, prefix=False):
+def _scheduler(engine, sample, prefix=False):
     sampling = dict(do_sample=True, temperature=0.9) if sample else {}
     return ContinuousBatchingScheduler(engine, ServingConfig(
         slots=SLOTS, chunk_size=CHUNK, max_seq_len=CAP, max_queue=8,
-        kv_pool=pool, kv_page_size=8,
-        prefix_cache=PrefixCacheConfig(enabled=prefix), **sampling))
+        kv_page_size=8, prefix_cache=PrefixCacheConfig(enabled=prefix),
+        **sampling))
 
 
 def _unpacked_run_chunk(ex):
     """``run_chunk`` as it was before the packing: the builder's function
     jitted as it stands, every operand placed and every output fetched as an
     array of its own."""
-    build = build_paged_decode_chunk if ex.paged else build_decode_chunk
-    kw = dict(kv_cap=ex.cap) if ex.paged else {}
-    fn = jax.jit(build(ex.engine.module, ex.engine._dequant, ex._slot_select,
-                       ex.chunk_size, with_stats=ex.with_stats, **kw),
-                 donate_argnums=(2,))
+    fn = jax.jit(build_paged_decode_chunk(
+        ex.engine.module, ex.engine._dequant, ex._slot_select, ex.chunk_size,
+        kv_cap=ex.cap, with_stats=ex.with_stats), donate_argnums=(2,))
 
     def run_chunk(toks, lens, active, remaining, eos_ids, seeds, steps):
-        where = (jnp.asarray(ex.pool.page_table),) if ex.paged else ()
         out = fn(ex.engine.params, jnp.asarray(toks, jnp.int32).reshape(-1, 1),
-                 ex.pool.caches, *where, jnp.asarray(lens, jnp.int32),
+                 ex.pool.caches, jnp.asarray(ex.pool.page_table),
+                 jnp.asarray(lens, jnp.int32),
                  jnp.asarray(active, bool), jnp.asarray(remaining, jnp.int32),
                  jnp.asarray(eos_ids, jnp.int32), jnp.asarray(seeds, jnp.int32),
                  jnp.asarray(steps, jnp.int32), ex._base_key)
@@ -105,17 +102,16 @@ def _serve(sched, vocab):
 
 @pytest.mark.parametrize("experts", [False, True], ids=["dense", "experts"])
 @pytest.mark.parametrize("sample", [False, True], ids=["greedy", "sampled"])
-@pytest.mark.parametrize("pool", ["paged", "slots"])
-def test_the_packed_chunk_gives_the_unpacked_calls_tokens(engines, pool, sample,
+def test_the_packed_chunk_gives_the_unpacked_calls_tokens(engines, sample,
                                                           experts):
     engine = engines[experts]
     vocab = engine.model_config.vocab_size
-    packed = _scheduler(engine, pool, sample)
+    packed = _scheduler(engine, sample)
     chunks = []
     run = packed.executor.run_chunk
     packed.executor.run_chunk = lambda *a: chunks.append(run(*a)) or chunks[-1]
     got = _serve(packed, vocab)
-    plain = _scheduler(engine, pool, sample)
+    plain = _scheduler(engine, sample)
     plain.executor.run_chunk = _unpacked_run_chunk(plain.executor)
     want = _serve(plain, vocab)
     for g, w in zip(got, want):
@@ -146,7 +142,7 @@ def _crossings(ring, program):
 def test_a_chunk_crosses_once_each_way_and_a_prefill_twice_in_once_out(
         engines, experts):
     tracer = get_tracer().enable()
-    _serve(_scheduler(engines[experts], "paged", False),
+    _serve(_scheduler(engines[experts], False),
            engines[experts].model_config.vocab_size)
     ring = list(tracer.spans)
     chunk, prefill = _crossings(ring, "decode_chunk"), _crossings(ring, "prefill")
@@ -157,52 +153,58 @@ def test_a_chunk_crosses_once_each_way_and_a_prefill_twice_in_once_out(
     assert set(prefill["serving.fetch"]) == {1}
 
 
-@pytest.mark.parametrize("pool", ["paged", "slots"])
-def test_a_prefix_hit_crosses_as_a_prefill_does_and_keeps_its_tokens(engines, pool):
-    """The suffix prefill's packed operand carries the page-table row (paged)
-    or the slot (slots pool, after the slab restore): two arrays in, one out,
-    and the tokens of a request served without the cache."""
+@pytest.mark.parametrize("shared_tokens,cow", [(16, 0), (13, 1)],
+                         ids=["whole-pages", "copy-on-write"])
+def test_a_prefix_hit_crosses_as_a_prefill_does_and_keeps_its_tokens(
+        engines, shared_tokens, cow):
+    """The suffix prefill's packed operand carries the slot's page-table row,
+    whether the match ends on a page boundary (two pages of 8 bound as they
+    are) or inside a page (the boundary page copied first): two arrays in,
+    one out, and the tokens of a request served without the cache."""
     tracer = get_tracer().enable()
-    shared = np.arange(1, 17, dtype=np.int32)          # two whole pages of 8
+    shared = np.arange(1, shared_tokens + 1, dtype=np.int32)
     prompts = [np.concatenate([shared, np.asarray(tail, np.int32)])
                for tail in ([40, 41, 42], [50, 51])]
     tokens = {}
     for prefix in (True, False):
-        sched = _scheduler(engines[False], pool, False, prefix=prefix)
+        sched = _scheduler(engines[False], False, prefix=prefix)
         handles = []
         for p in prompts:
             handles.append(sched.submit(p, max_new_tokens=5))
             sched.run()
         tokens[prefix] = [list(h.tokens) for h in handles]
         if prefix:
-            assert handles[1].prefix_hit_tokens == 16
+            assert handles[1].prefix_hit_tokens == shared_tokens
+            assert sched.executor.pool.cow_copies_total == cow
     assert tokens[True] == tokens[False]
     hit = _crossings(list(tracer.spans), "suffix_prefill")
     assert hit == {"serving.place_inputs": [2], "serving.fetch": [1]}
 
 
-@pytest.mark.parametrize("pool", ["paged", "slots"])
-def test_the_lowered_programs_keep_their_names_and_the_prompt_leads(engines, pool):
+@pytest.mark.parametrize("experts", [False, True], ids=["dense", "experts"])
+def test_the_lowered_programs_keep_their_names_and_the_prompt_leads(engines,
+                                                                    experts):
     """What the benchmark's readers find the programs by: ``jit_<name>`` in
     the trace and the dump, and ``first_int_arg_shape`` = the prefill's
     ``1 x bucket``: no other int32 operand of rank 2 or more before the
-    padded prompt."""
-    ex = _scheduler(engines[False], pool, False).executor
+    padded prompt. (A prefix hit needs keys and values in every layer, so
+    the hybrid pattern has no suffix prefill.)"""
+    ex = _scheduler(engines[experts], False).executor
     ids = jax.ShapeDtypeStruct((1, 16), jnp.int32)
     vec = lambda n: jax.ShapeDtypeStruct((n,), jnp.int32)  # noqa: E731
-    table = ex.pool.max_pages if ex.paged else 0
+    table = ex.pool.max_pages
     params, key = ex.engine.params, ex._base_key
     cases = {
         "prefill": (ex._prefill_fn(16), (params, ids, vec(2), key)),
-        "suffix_prefill": (
-            ex._suffix_prefill_fn_paged(16) if ex.paged else ex._suffix_prefill_fn(16),
-            (params, ex.pool.caches, ids,
-             vec(ex_mod.PRE_COLS + (table or 1)), key)),
         "decode_chunk": (
             ex._chunk_fn(),
             (params, jax.ShapeDtypeStruct((SLOTS, ex_mod.CTL_COLS + table),
                                           jnp.int32), ex.pool.caches, key)),
     }
+    if ex.kv_every_layer:
+        cases["suffix_prefill"] = (
+            ex._suffix_prefill_fn_paged(16),
+            (params, ex.pool.caches, ids, vec(ex_mod.PRE_COLS + table), key))
     for name, (fn, args) in cases.items():
         text = fn.lower(*args).as_text()
         assert f"module @jit_{name} " in text, name

@@ -1,18 +1,17 @@
 """Paged KV memory tests: page allocator (alloc/free/exhaustion/refusal),
-refcount lifecycle + copy-on-write boundary page, paged-attention
-kernel-vs-XLA parity, hit/miss/retry/drain/migration bit-exactness on the
-paged pool, the page-bind chaos seam (``when=restore`` extended to the bind
-path), the slab serialization API roundtrip, the front-door ``--kv-page-size``
-validation, and the ``--bench-paged`` smoke.
+refcount lifecycle + copy-on-write boundary page, the page layout's three
+mappings against a numpy reference, paged-attention kernel-vs-XLA parity,
+hit/miss/retry/drain/migration bit-exactness, the page-bind chaos seam
+(``when=restore`` extended to the bind path), the slab serialization API
+roundtrip, and the front-door ``--kv-page-size`` validation.
 
-Every parity assertion is exact token equality: the paged pool's XLA decode
-path reassembles the exact dense view the slot-row pool held (sliced to
-``cap`` rows), so greedy decode is bit-identical pool-for-pool — hit or miss,
-killed or not, migrated or not.
+Every parity assertion is exact token equality: the pool's XLA decode path
+reassembles the exact contiguous cache ``engine.generate`` decodes over
+(sliced to ``cap`` rows), so greedy decode is bit-identical to it — hit or
+miss, killed or not, migrated or not.
 """
 
 import importlib.util
-import json
 import os
 import sys
 
@@ -29,9 +28,11 @@ from deepspeed_tpu.inference.serving import (ChaosEvent, ChaosSchedule,
                                              Router, RouterConfig,
                                              ServingConfig)
 from deepspeed_tpu.models.causal_lm import gpt2_cfg
-from deepspeed_tpu.ops.paged_attention import (gather_kv_dense,
+from deepspeed_tpu.ops.paged_attention import (gather_kv_dense, page_address,
                                                paged_attention_fused,
-                                               paged_attention_xla)
+                                               paged_attention_xla,
+                                               pages_to_dense,
+                                               write_dense_pages)
 from deepspeed_tpu.ops.attention.decode import decode_attention_xla
 
 pytestmark = pytest.mark.paged_kv
@@ -69,7 +70,7 @@ def _cache_cfg(**over):
 
 def _sched(engine, cache=False, page_size=8, **over):
     kw = dict(slots=2, chunk_size=3, max_seq_len=CAP, retry_base_delay=0.001,
-              kv_pool="paged", kv_page_size=page_size,
+              kv_page_size=page_size,
               prefix_cache=(_cache_cfg() if cache is True
                             else (cache or None)))
     kw.update(over)
@@ -188,6 +189,100 @@ def test_clear_releases_cached_pages(engine):
     sched.prefix_cache.clear()             # idle revive: live pool, no rebuild
     assert pool.pages_in_use == 0
     assert pool.can_admit(CAP)
+
+
+# ------------------------------------------------------------- page layout
+def _np_address(table, rows, live, cap, ps, total):
+    """Row by row: ``(table[row // ps], row % ps)``, or the out-of-range
+    page where the row is dropped."""
+    pidx = np.full(rows.shape, total, np.int64)
+    for i, r in enumerate(rows):
+        row_table = table if table.ndim == 1 else table[i]
+        if live[i] and r < cap:
+            pidx[i] = row_table[r // ps]
+    return pidx, rows % ps
+
+
+@pytest.mark.parametrize("site", ["chunk", "verify", "suffix"])
+def test_page_address_matches_numpy_and_its_scatter_drops_dead_rows(site):
+    """The three call sites' forms: the chunk's mirror (row ``lens + j`` of
+    every slot, live while ``j < done``), the verify round's (``active &
+    (j < valid)``) and the suffix prefill's (one slot's table, a vector of
+    rows). Rows at or past ``cap``, inactive slots and rows past ``done`` get
+    the out-of-range page, and a scatter through the addresses leaves every
+    page they do not name as it was."""
+    ps, mp, total, cap, hk, d = 4, 3, 9, 10, 2, 3      # cap ends inside page 2
+    rng = np.random.default_rng(1)
+    table = np.array([[5, 2, 7], [1, 8, 3], [4, 6, 0]], np.int32)
+    pages = rng.normal(size=(total, hk, ps, d)).astype(np.float32)
+    if site == "suffix":
+        tbl = table[1]
+        rows = 6 + np.arange(8)                        # 6..13: four past cap
+        live = np.ones(8, bool)
+        pidx, off = page_address(jnp.asarray(tbl), jnp.asarray(rows), cap, ps,
+                                 total)
+        want = _np_address(tbl, rows, live, cap, ps, total)
+    else:
+        lens = np.array([3, 8, 11], np.int32)          # slot 2 is past cap
+        j = 1
+        if site == "chunk":
+            done = np.array([2, 2, 2], np.int32)
+            done[0] = 1                                # slot 0 stopped at j=1
+            live = j < done
+            mask = lambda: j < jnp.asarray(done)       # noqa: E731
+        else:
+            active = np.array([True, False, True])
+            valid = np.array([3, 3, 3], np.int32)
+            live = active & (j < valid)
+            mask = lambda: jnp.asarray(active) & (j < jnp.asarray(valid))  # noqa: E731
+        rows = lens + j
+        pidx, off = page_address(jnp.asarray(table), jnp.asarray(rows), cap,
+                                 ps, total, live=mask)
+        want = _np_address(table, rows, live, cap, ps, total)
+    np.testing.assert_array_equal(np.asarray(pidx), want[0])
+    np.testing.assert_array_equal(np.asarray(off), want[1])
+    assert (want[0] == total).any() and (want[0] != total).any()
+    new = rng.normal(size=(len(rows), hk, d)).astype(np.float32)
+    got = jnp.asarray(pages).at[pidx, :, off, :].set(jnp.asarray(new))
+    ref = pages.copy()
+    for i, (pg, o) in enumerate(zip(*want)):
+        if pg != total:
+            ref[pg, :, o, :] = new[i]
+    np.testing.assert_array_equal(np.asarray(got), ref)
+
+
+@pytest.mark.parametrize("rows,batched", [(16, False), (13, True), (5, False)])
+def test_dense_rows_to_pages_and_back(rows, batched):
+    """``write_dense_pages`` then ``pages_to_dense``: the rows come back
+    where a numpy loop puts them, the rest of the last page is zero, and no
+    other page is touched (whole pages, a ragged last page, a single page;
+    with and without the batch of one a prefill returns)."""
+    ps, total, hk, d = 8, 7, 2, 3
+    rng = np.random.default_rng(rows)
+    n = -(-rows // ps)
+    tbl = np.array([5, 2, 6], np.int32)[:n]
+    before = {key: rng.normal(size=(total, hk, ps, d)).astype(np.float32)
+              for key in ("k", "v")}
+    dense = {key: rng.normal(size=(hk, rows, d)).astype(np.float32)
+             for key in ("k", "v")}
+    given = {key: jnp.asarray(x[None] if batched else x)
+             for key, x in dense.items()}
+    after = write_dense_pages({key: jnp.asarray(x) for key, x in before.items()},
+                              given, jnp.asarray(tbl))
+    for key in ("k", "v"):
+        ref = before[key].copy()
+        ref[tbl] = 0.0
+        for r in range(rows):
+            ref[tbl[r // ps], :, r % ps, :] = dense[key][:, r, :]
+        np.testing.assert_array_equal(np.asarray(after[key]), ref)
+        back = np.asarray(pages_to_dense(after[key], jnp.asarray(tbl)))
+        assert back.shape == (hk, n * ps, d)
+        np.testing.assert_array_equal(back[:, :rows], dense[key])
+        assert not back[:, rows:].any()
+        # the batched gather of the decode chunk reads the same rows
+        k2, _ = gather_kv_dense(after[key], after[key],
+                                jnp.asarray(tbl)[None], rows)
+        np.testing.assert_array_equal(np.asarray(k2[0]), dense[key])
 
 
 # ------------------------------------------------------- kernel-vs-XLA parity
@@ -315,21 +410,50 @@ def test_cow_hit_parity_unaligned_prefix(engine):
     np.testing.assert_array_equal(h1.result(), _ref(engine, p1, 6))
 
 
-def test_sampled_decode_parity_paged_vs_slots(engine):
-    """Seeded sampling: identical streams through the paged and slot-row
-    pools (per-slot key streams are pool-independent by construction)."""
+def test_sampled_decode_parity_across_page_geometries(engine):
+    """Seeded sampling: identical streams whatever the page size and the
+    slot (per-slot key streams know nothing of where the rows live): pages
+    of 8 alone in slot 0, pages of 4 behind another request in slot 1."""
     rng = np.random.default_rng(13)
     p = rng.integers(0, 96, size=9).astype(np.int32)
+    other = rng.integers(0, 96, size=5).astype(np.int32)
     outs = []
-    for kind in ("slots", "paged"):
+    for page, crowded in ((8, False), (4, True)):
         sched = ContinuousBatchingScheduler(engine, ServingConfig(
-            slots=2, chunk_size=3, max_seq_len=CAP, kv_pool=kind,
-            kv_page_size=8, do_sample=True, temperature=0.9, base_seed=5))
+            slots=2, chunk_size=3, max_seq_len=CAP, kv_page_size=page,
+            do_sample=True, temperature=0.9, base_seed=5))
+        if crowded:
+            sched.submit(other, max_new_tokens=12, seed=3)
         h = sched.submit(p, max_new_tokens=8, seed=17)
         sched.run()
         assert h.state.value == "finished"
         outs.append(h.result())
     np.testing.assert_array_equal(outs[0], outs[1])
+
+
+@pytest.mark.parametrize("page", [4, 8, 16])
+def test_mixed_lengths_across_page_boundaries_match_generate(engine, page):
+    """Prompts that end before, on and after a page boundary, decoded over
+    one or more further boundaries through two recycled slots: every stream
+    is ``engine.generate``'s, token for token."""
+    rng = np.random.default_rng(page)
+    sizes = (3, page, page + 1, 2 * page - 1, 30)
+    prompts = [rng.integers(0, 96, size=n).astype(np.int32) for n in sizes]
+    sched = _sched(engine, page_size=page, max_queue=8)
+    handles = [sched.submit(p, max_new_tokens=min(page + 2 + i, CAP - p.size))
+               for i, p in enumerate(prompts)]
+    sched.run()
+    for p, h in zip(prompts, handles):
+        assert h.state.value == "finished"
+        np.testing.assert_array_equal(h.result(),
+                                      _ref(engine, p, len(h.tokens)))
+    assert sched.executor.pool.pages_in_use == 0
+
+
+def test_the_removed_slot_pool_is_refused_at_construction():
+    assert ServingConfig(kv_pool="paged").kv_pool == "paged"
+    with pytest.raises(ValueError, match="slot-row pool was removed"):
+        ServingConfig(kv_pool="slots")
 
 
 def test_mixed_length_page_admission(engine):
@@ -407,7 +531,7 @@ def test_admission_pressure_protects_head_hit(engine):
 def _router(engines, **over):
     serving = over.pop("serving", None) or ServingConfig(
         slots=2, chunk_size=3, max_seq_len=CAP, retry_base_delay=0.001,
-        kv_pool="paged", kv_page_size=8, prefix_cache=_cache_cfg())
+        kv_page_size=8, prefix_cache=_cache_cfg())
     rcfg = RouterConfig(serving=serving, suspect_after_s=0.04,
                         dead_after_s=0.12, recover_after_s=30.0,
                         breaker_threshold=2, max_attempts=4,
@@ -573,39 +697,3 @@ def test_kv_page_size_validation():
         lg.main(["--smoke", "--kv-page-size", "10", "--chunk-size", "8"])
     with pytest.raises(SystemExit):
         lg.main(["--smoke", "--prompt-dist", "bimodal:garbage"])
-
-
-# ------------------------------------------------------------- bench smoke
-@pytest.mark.slow
-def test_bench_paged_smoke(tmp_path, capsys):
-    """--bench-paged --smoke: schema + parity/lost gates must hold in-process
-    (the throughput ratio is reported but only the committed BENCH artifact
-    gates >= 1.5x — a loaded CI host is not a benchmarking rig).
-
-    Slow lane (tier-1 window reclaim, the PR 15 bench-smoke pattern): the
-    in-window paged_kv unit lanes cover allocator/parity/eviction; the
-    committed BENCH_PAGED artifact gates the A/B."""
-    spec = importlib.util.spec_from_file_location(
-        "loadgen_pagedbench", os.path.join(REPO, "benchmarks", "serving",
-                                           "loadgen.py"))
-    lg = importlib.util.module_from_spec(spec)
-    spec.loader.exec_module(lg)
-    out_file = str(tmp_path / "BENCH_PAGED_smoke.json")
-    lg.main(["--smoke", "--bench-paged", "--out", out_file])
-    capsys.readouterr()
-    with open(out_file) as f:
-        out = json.load(f)
-    assert out["metric"] == "paged_vs_slots_tok_s_ratio"
-    g = out["paged_gates"]
-    for key in ("throughput_ratio", "throughput_ratio_gate", "throughput_ok",
-                "sustained_tok_s_slots", "sustained_tok_s_paged",
-                "kv_bytes_slots", "kv_bytes_paged",
-                "hit_ttft_ms_p50_slots", "hit_ttft_ms_p50_paged"):
-        assert g[key] is not None
-    assert g["parity_ok_every_request"] is True
-    assert g["lost_zero_all_lanes"] is True
-    assert g["equal_hbm_budget"] is True
-    # CI hosts are not benchmarking rigs: the full thresholds are gated by
-    # the committed BENCH_PAGED artifact; here the ratio only has to exist
-    # and favor neither lane absurdly
-    assert g["throughput_ratio"] > 0

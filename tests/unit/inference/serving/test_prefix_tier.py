@@ -161,7 +161,7 @@ def test_paged_entry_without_gather_hook_cannot_spill():
 # --------------------------------------------------- promote greedy parity e2e
 def _tiered_sched(engine, device_bytes, host_bytes=1 << 20, **over):
     kw = dict(slots=2, chunk_size=2, max_seq_len=CAP, retry_base_delay=0.001,
-              kv_pool="paged", kv_page_size=4,
+              kv_page_size=4,
               prefix_cache=PrefixCacheConfig(
                   max_bytes=device_bytes, host_tier_bytes=host_bytes,
                   min_hit_tokens=4, min_insert_tokens=4,
@@ -356,7 +356,7 @@ def test_chaos_kill_mid_promote_retry_parity(engines):
     restore must never leak a half-promoted slot into the stream."""
     serving = ServingConfig(
         slots=2, chunk_size=2, max_seq_len=CAP, retry_base_delay=0.001,
-        kv_pool="paged", kv_page_size=4,
+        kv_page_size=4,
         prefix_cache=PrefixCacheConfig(
             max_bytes=12 * 1024, host_tier_bytes=1 << 20,
             min_hit_tokens=4, min_insert_tokens=4, insert_on="prefill"))

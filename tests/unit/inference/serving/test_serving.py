@@ -19,9 +19,11 @@ import deepspeed_tpu as ds
 from deepspeed_tpu.inference.engine import InferenceEngine
 from deepspeed_tpu.inference.serving import (ContinuousBatchingScheduler,
                                              QueueFullError, RequestState,
-                                             ServingConfig, SlotKVPool)
+                                             ServingConfig)
+from deepspeed_tpu.inference.serving.kv_pool import NULL_PAGE, PagedKVPool
 from deepspeed_tpu.models.causal_lm import gpt2_cfg
 from deepspeed_tpu.utils import fault_injection as fi
+from tests.unit import hybrid_tiny as ht
 
 pytestmark = pytest.mark.serving
 
@@ -111,24 +113,52 @@ def test_eos_finish_matches_generate(engine):
 
 # ------------------------------------------------------------------ kv pool
 def test_kv_pool_recycling_zero_fills(engine):
-    pool = SlotKVPool(engine.model_config, slots=2, cap=CAP,
-                      dtype=engine.dtype)
+    """Released pages go back to the free list as they are (rows at or past
+    ``cache_len`` are masked, and the next prefill's scatter overwrites whole
+    pages); a released slot's recurrent state, which no length masks, comes
+    back zeroed."""
+    pool = PagedKVPool(engine.model_config, slots=2, cap=CAP, page_size=8,
+                       dtype=engine.dtype)
     a, b = pool.acquire(), pool.acquire()
     assert (a, b) == (0, 1) and pool.acquire() is None
-    assert pool.occupancy == 1.0
-    # dirty slot 1, release, and the row must come back zeroed
-    dirty = [{"k": jnp.ones_like(c["k"][:1]), "v": jnp.ones_like(c["v"][:1])}
+    assert pool.occupancy == 1.0 and pool.free_pages == 0
+    pages = pool.table_row(1).copy()
+    assert np.all(pages != NULL_PAGE) and len(set(pages)) == pool.max_pages
+    # dirty slot 1: its pages, and nobody else's, hold the rows
+    dirty = [{"k": jnp.ones((1,) + c["k"].shape[1:2] + (CAP,) + c["k"].shape[3:]),
+              "v": jnp.ones((1,) + c["v"].shape[1:2] + (CAP,) + c["v"].shape[3:])}
              for c in pool.caches]
     pool.scatter_prefill(1, dirty)
-    assert float(np.abs(np.asarray(pool.caches[0]["k"][1])).max()) == 1.0
+    k0 = np.asarray(pool.caches[0]["k"])
+    assert np.all(k0[pages] == 1.0) and np.all(k0[pool.table_row(0)] == 0.0)
     pool.release(1)
-    assert pool.free_slots == 1
-    assert float(np.abs(np.asarray(pool.caches[0]["k"][1])).max()) == 0.0
-    # released slot is recyclable; double release is an error
-    assert pool.acquire() == 1
+    assert pool.free_slots == 1 and pool.free_pages == pool.max_pages
+    assert np.all(pool.table_row(1) == NULL_PAGE)
+    # released slot is recyclable, its pages with it; a shorter prefill's
+    # scatter pads to whole pages, so no row of the last tenant survives
+    assert pool.acquire(tokens=10) == 1
+    reused = pool.table_row(1)[:2]
+    assert set(reused) <= set(pages)
+    short = [{"k": jnp.full_like(d["k"][:, :, :10], 2.0),
+              "v": jnp.full_like(d["v"][:, :, :10], 2.0)} for d in dirty]
+    pool.scatter_prefill(1, short)
+    rows = np.asarray(pool.caches[0]["k"])[reused].transpose(1, 0, 2, 3) \
+        .reshape(k0.shape[1], -1, k0.shape[3])
+    assert np.all(rows[:, :10] == 2.0) and np.all(rows[:, 10:] == 0.0)
     pool.release(0)
     with pytest.raises(ValueError):
         pool.release(0)
+    # the state pool: a hybrid model's per-slot state is cleared on release
+    hybrid = PagedKVPool(ht.config(), slots=2, cap=CAP, page_size=8)
+    assert hybrid.state_nbytes > 0
+    slot = hybrid.acquire()
+    hybrid.caches = [c if "k" in c else {key: jnp.ones_like(x) for key, x in c.items()}
+                     for c in hybrid.caches]
+    hybrid.release(slot)
+    for c in hybrid.caches:
+        for key in set(c) - {"k", "v"}:
+            x = np.asarray(c[key].astype(jnp.float32))
+            assert np.all(x[slot] == 0.0) and np.all(x[1 - slot] == 1.0)
 
 
 # ------------------------------------------------- deadlines / cancellation

@@ -63,7 +63,7 @@ def engines(engine):
 
 def _sched(engine, speculate=True, cache=False, **over):
     kw = dict(slots=2, chunk_size=3, max_seq_len=CAP, retry_base_delay=0.001,
-              kv_pool="paged", kv_page_size=8, speculate=speculate, spec_k=4,
+              kv_page_size=8, speculate=speculate, spec_k=4,
               prefix_cache=(PrefixCacheConfig(min_hit_tokens=4,
                                               min_insert_tokens=4,
                                               insert_on="prefill")
@@ -184,25 +184,23 @@ def test_rejection_sampling_preserves_target_distribution():
 
 
 # --------------------------------------------------- scheduler-level parity
-def test_greedy_parity_spec_vs_plain_both_pools(engine):
+def test_greedy_parity_spec_vs_plain_vs_generate(engine):
     """Greedy speculative output is bit-identical to non-speculative decode,
-    paged and slot-row pools alike, for repetitive (high-acceptance) and
-    random (dry-proposer) prompts co-batched together."""
+    and both to ``engine.generate``, token for token, for repetitive
+    (high-acceptance) and random (dry-proposer) prompts co-batched together."""
     rng = np.random.default_rng(3)
     prompts = [_rep_prompt(rng), _rep_prompt(rng, unit=3, reps=4, tail=2),
                rng.integers(0, 96, size=7).astype(np.int32)]
     maxn = (14, 10, 8)
-    for pool in ("paged", "slots"):
-        outs = {}
-        for speculate in (False, True):
-            sched = _sched(engine, speculate=speculate, kv_pool=pool)
-            hs = [sched.submit(p, max_new_tokens=m)
-                  for p, m in zip(prompts, maxn)]
-            sched.run()
-            outs[speculate] = [h.result() for h in hs]
-            assert all(h.state == RequestState.FINISHED for h in hs)
-        for a, b in zip(outs[False], outs[True]):
-            np.testing.assert_array_equal(a, b)
+    want = [_ref(engine, p, m) for p, m in zip(prompts, maxn)]
+    for speculate in (False, True):
+        sched = _sched(engine, speculate=speculate)
+        hs = [sched.submit(p, max_new_tokens=m)
+              for p, m in zip(prompts, maxn)]
+        sched.run()
+        assert all(h.state == RequestState.FINISHED for h in hs)
+        for h, w in zip(hs, want):
+            np.testing.assert_array_equal(h.result(), w)
     # speculation actually sped something up: fewer verify rounds than tokens
     snap = sched.telemetry.snapshot()
     assert snap["spec_accepted"] > 0
@@ -354,7 +352,7 @@ def test_spec_prefix_cache_hit_parity(engine):
 def _router(engines, **over):
     serving = over.pop("serving", None) or ServingConfig(
         slots=2, chunk_size=3, max_seq_len=CAP, retry_base_delay=0.001,
-        kv_pool="paged", kv_page_size=8, speculate=True, spec_k=4,
+        kv_page_size=8, speculate=True, spec_k=4,
         prefix_cache=PrefixCacheConfig(min_hit_tokens=4, min_insert_tokens=4,
                                        insert_on="prefill"))
     rcfg = RouterConfig(serving=serving, suspect_after_s=0.04,
@@ -440,7 +438,7 @@ def test_mid_verify_chaos_kill_bit_exact_retry(engines):
     fi.reset_faults()
     serving = ServingConfig(
         slots=2, chunk_size=3, max_seq_len=CAP, transient_retries=1,
-        retry_base_delay=0.001, kv_pool="paged", kv_page_size=8,
+        retry_base_delay=0.001, kv_page_size=8,
         speculate=True, spec_k=4)
     router = _router(engines, serving=serving)
     rng = np.random.default_rng(31)

@@ -102,14 +102,15 @@ def _decode(module):
         variables, toks, positions=lens[:, None], caches=caches, cache_lens=lens))
 
 
-def _served_logits(cfg, module, params, ids, prompt_len, pool_kind):
+def _served_logits(cfg, module, params, ids, prompt_len):
     """Prefill ``prompt_len`` tokens (right-padded to a bucket) into slot 1 of
     a pool of 3, then decode the rest one token at a time through the pool."""
-    from deepspeed_tpu.inference.serving.kv_pool import PagedKVPool, SlotKVPool
+    from deepspeed_tpu.inference.serving.kv_pool import PagedKVPool
     from deepspeed_tpu.models.causal_lm import init_cache
+    from deepspeed_tpu.ops.paged_attention import (gather_kv_dense,
+                                                   paged_cache_update)
     cap, slots, slot = 64, 3, 1
-    pool = (PagedKVPool(cfg, slots, cap, page_size=8) if pool_kind == "paged"
-            else SlotKVPool(cfg, slots, cap))
+    pool = PagedKVPool(cfg, slots, cap, page_size=8)
     for _ in range(slot + 1):
         got = pool.acquire(tokens=cap)
     assert got == slot
@@ -130,57 +131,48 @@ def _served_logits(cfg, module, params, ids, prompt_len, pool_kind):
         # never written again: the step may still be running when the loop goes
         # on (on the CPU a jax array made from a numpy array can alias it)
         toks_d, lens_d = jnp.asarray(toks), jnp.asarray(lens)
-        kw = dict(page_table=jnp.asarray(pool.page_table), kv_cap=cap) \
-            if pool_kind == "paged" else {}
-        if pool_kind == "paged":
-            # the XLA route of the paged chunk: attention over the dense view
-            from deepspeed_tpu.ops.paged_attention import gather_kv_dense
-            caches = [dict(zip(("k", "v"), gather_kv_dense(
-                c["k"], c["v"], kw["page_table"], cap))) if "k" in c else c
-                for c in pool.caches]
-            logits, new = decode({"params": params}, toks_d, caches, lens_d)
-            # mirror the appended row back, as the chunk does
-            from deepspeed_tpu.ops.paged_attention import paged_cache_update
-            out = []
-            for c, n in zip(pool.caches, new):
-                if "k" not in c:
-                    out.append(n)
-                    continue
-                idx = lens_d[:, None, None, None]
-                k_new = jnp.take_along_axis(n["k"], idx, axis=2)
-                v_new = jnp.take_along_axis(n["v"], idx, axis=2)
-                kp, vp = paged_cache_update(c["k"], c["v"], k_new, v_new,
-                                            kw["page_table"], lens_d)
-                out.append({"k": kp, "v": vp})
-            pool.caches = out
-        else:
-            logits, pool.caches = decode({"params": params}, toks_d, pool.caches,
-                                         lens_d)
+        table = jnp.asarray(pool.page_table)
+        # the XLA route of the chunk: attention over the dense view
+        caches = [dict(zip(("k", "v"), gather_kv_dense(
+            c["k"], c["v"], table, cap))) if "k" in c else c
+            for c in pool.caches]
+        logits, new = decode({"params": params}, toks_d, caches, lens_d)
+        # mirror the appended row back, as the chunk does
+        out = []
+        for c, n in zip(pool.caches, new):
+            if "k" not in c:
+                out.append(n)
+                continue
+            idx = lens_d[:, None, None, None]
+            k_new = jnp.take_along_axis(n["k"], idx, axis=2)
+            v_new = jnp.take_along_axis(n["v"], idx, axis=2)
+            kp, vp = paged_cache_update(c["k"], c["v"], k_new, v_new,
+                                        table, lens_d)
+            out.append({"k": kp, "v": vp})
+        pool.caches = out
         rows.append(logits[slot, 0])
         lens = lens + (np.arange(slots) == slot).astype(np.int32)   # a NEW array
     return jnp.stack(rows), pool
 
 
-@pytest.mark.parametrize("pool_kind", ["paged", "slots"])
-def test_prefill_then_decode_through_the_pool_is_the_references_forward(tiny, pool_kind):
+def test_prefill_then_decode_through_the_pool_is_the_references_forward(tiny):
     cfg, module, params = tiny
     ids = ht.ids(30, seed=3)[0]
-    got, pool = _served_logits(cfg, module, params, ids, 13, pool_kind)
+    got, pool = _served_logits(cfg, module, params, ids, 13)
     want = REF.forward(params, ht.MODEL, ids)[12:]
     assert got.shape == want.shape
     assert float(jnp.abs(got - want).max()) < TOL
-    if pool_kind == "paged":
-        kinds = ["ssm" in c for c in pool.caches]
-        assert kinds == [True, False, True, False, False]
-        assert pool.caches[1] == {} and set(pool.caches[3]) == {"k", "v"}
-        assert pool.kv_layers == 1 and pool.state_nbytes == sum(
-            int(a.nbytes) for c in pool.caches if "ssm" in c for a in c.values())
+    kinds = ["ssm" in c for c in pool.caches]
+    assert kinds == [True, False, True, False, False]
+    assert pool.caches[1] == {} and set(pool.caches[3]) == {"k", "v"}
+    assert pool.kv_layers == 1 and pool.state_nbytes == sum(
+        int(a.nbytes) for c in pool.caches if "ssm" in c for a in c.values())
 
 
 def test_a_released_slot_is_cleared_and_a_recycled_one_leaks_nothing(tiny):
     cfg, module, params = tiny
     ids = ht.ids(20, seed=5)[0]
-    _, pool = _served_logits(cfg, module, params, ids, 9, "paged")
+    _, pool = _served_logits(cfg, module, params, ids, 9)
     assert float(jnp.abs(pool.caches[0]["ssm"][1]).max()) > 0
     pool.release(1)
     for c in pool.caches:
@@ -201,18 +193,17 @@ def test_the_scheduler_serves_it_and_a_recycled_slot_gives_the_same_tokens():
     assert eng.model_config.num_params() == sum(
         a.size for a in jax.tree_util.tree_leaves(eng.params))
     prompts = [ht.ids(n, seed=n)[0] for n in (5, 13, 16, 9, 21)]
-    for pool in ("paged", "slots"):
-        sched = ContinuousBatchingScheduler(eng, ServingConfig(
-            slots=2, chunk_size=4, max_seq_len=64, max_queue=8, kv_pool=pool,
-            kv_page_size=8, prefix_cache=PrefixCacheConfig(enabled=False)))
-        # five requests through two slots: every slot is recycled
-        handles = [sched.submit(p, max_new_tokens=6 + i) for i, p in enumerate(prompts)]
-        sched.run()
-        for p, h in zip(prompts, handles):
-            alone = eng.generate(p[None], max_new_tokens=len(h.tokens))[0, p.size:]
-            assert list(h.tokens) == [int(t) for t in alone], (pool, p.size)
-        assert sched.telemetry.moe_assignments > 0
-        assert 0 < sched.telemetry.moe_experts_touched <= sched.telemetry.moe_assignments
+    sched = ContinuousBatchingScheduler(eng, ServingConfig(
+        slots=2, chunk_size=4, max_seq_len=64, max_queue=8, kv_page_size=8,
+        prefix_cache=PrefixCacheConfig(enabled=False)))
+    # five requests through two slots: every slot is recycled
+    handles = [sched.submit(p, max_new_tokens=6 + i) for i, p in enumerate(prompts)]
+    sched.run()
+    for p, h in zip(prompts, handles):
+        alone = eng.generate(p[None], max_new_tokens=len(h.tokens))[0, p.size:]
+        assert list(h.tokens) == [int(t) for t in alone], p.size
+    assert sched.telemetry.moe_assignments > 0
+    assert 0 < sched.telemetry.moe_experts_touched <= sched.telemetry.moe_assignments
     first = int(np.argmax(eng.forward(prompts[2][None])[0, -1]))
     assert first == handles[2].tokens[0]
     # the first token of a request is the argmax of the reference's logits
@@ -283,18 +274,17 @@ def test_a_pattern_without_state_space_layers_is_served_like_any_other():
                           DeepSpeedInferenceConfig(dtype="float32", max_out_tokens=64),
                           seed=2)
     prompts = [ht.ids(n, seed=n)[0] for n in (7, 16, 11)]
-    for pool in ("paged", "slots"):
-        sched = ContinuousBatchingScheduler(eng, ServingConfig(
-            slots=2, chunk_size=4, max_seq_len=64, max_queue=8, kv_pool=pool,
-            kv_page_size=8, prefix_cache=PrefixCacheConfig(enabled=False)))
-        assert not sched.executor.kv_every_layer
-        assert [sorted(c) for c in sched.executor.pool.caches] == \
-            [["k", "v"], [], ["k", "v"], []]
-        handles = [sched.submit(p, max_new_tokens=5 + i) for i, p in enumerate(prompts)]
-        sched.run()
-        for p, h in zip(prompts, handles):
-            alone = eng.generate(p[None], max_new_tokens=len(h.tokens))[0, p.size:]
-            assert list(h.tokens) == [int(t) for t in alone], (pool, p.size)
+    sched = ContinuousBatchingScheduler(eng, ServingConfig(
+        slots=2, chunk_size=4, max_seq_len=64, max_queue=8, kv_page_size=8,
+        prefix_cache=PrefixCacheConfig(enabled=False)))
+    assert not sched.executor.kv_every_layer
+    assert [sorted(c) for c in sched.executor.pool.caches] == \
+        [["k", "v"], [], ["k", "v"], []]
+    handles = [sched.submit(p, max_new_tokens=5 + i) for i, p in enumerate(prompts)]
+    sched.run()
+    for p, h in zip(prompts, handles):
+        alone = eng.generate(p[None], max_new_tokens=len(h.tokens))[0, p.size:]
+        assert list(h.tokens) == [int(t) for t in alone], p.size
 
 
 def test_a_state_space_layer_refuses_a_prefill_at_an_offset(tiny):

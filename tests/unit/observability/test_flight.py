@@ -725,10 +725,10 @@ class TestLoadgenFlight:
         assert tags.get("serving/ttft_ms", 0) == tags["latency/e2e_ms"]
 
     def test_flight_out_rejected_by_dedicated_bench_lanes(self, tmp_path):
-        """--bench-paged/--bench-autoscale dispatch before the flight wiring:
+        """--bench-spec/--bench-autoscale dispatch before the flight wiring:
         the combination must error, not silently write no bundle."""
         loadgen = self._loadgen()
-        for lane in ("--bench-paged", "--bench-autoscale"):
+        for lane in ("--bench-spec", "--bench-autoscale"):
             with pytest.raises(SystemExit) as ei:
                 loadgen.main(["--smoke", lane,
                               "--flight-out", str(tmp_path / "f.json")])
