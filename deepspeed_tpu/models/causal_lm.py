@@ -805,10 +805,21 @@ def _prefix_attention_xla(q, k_cache, v_cache, offset, slopes=None):
 
 
 def _cache_update(cache, new, cache_len):
-    """cache: (b, hk, T, d); new: (b, hk, 1, d); write at per-sequence position."""
-    def one(c, n, p):
-        return jax.lax.dynamic_update_slice(c, n.astype(c.dtype), (0, p, 0))
-    return jax.vmap(one)(cache, new, cache_len)
+    """cache: (b, hk, T, d); new: (b, hk, 1, d); write at per-sequence position.
+
+    One ``dynamic_update_slice`` a sequence with the sequence's index static:
+    each is one in-place write on a loop's carry, a position at or past ``T``
+    clamped to the last row. The batched forms cost more on the chip: a
+    vmapped update is a scatter that the TPU compiler expands into a serial
+    loop over the sequences (seven small ops an iteration, twice a layer a
+    step), and a native scatter gives the cache a layout that a Mosaic
+    ``decode_attention`` cannot read, so the whole cache is copied back every
+    step (PERF.md, PR 30)."""
+    new = new.astype(cache.dtype)
+    for i in range(cache.shape[0]):
+        cache = jax.lax.dynamic_update_slice(
+            cache, new[i:i + 1], (i, 0, cache_len[i], 0))
+    return cache
 
 
 def _sharded_decode(q, k_cache, v_cache, lens, alibi=None):

@@ -45,3 +45,63 @@ def test_the_grouped_expert_kernel_compiles_at_the_published_widths(
     plan = jax.jit(functools.partial(g.dispatch_plan, first=0, count=held, tm=tm)).lower(
         sds((tokens, k), jnp.int32)).compile().as_text()
     assert " sort(" not in plan and " scatter(" not in plan
+
+
+def _bloom_layers():
+    from deepspeed_tpu.models.causal_lm import bloom_cfg
+    return bloom_cfg(n_layer=2, n_embd=4096, n_head=32, vocab_size=250880,
+                     dtype=jnp.bfloat16)
+
+
+def _hybrid_attention_layer():
+    from deepspeed_tpu.models.causal_lm import nemotron_h_cfg
+    return nemotron_h_cfg(
+        hidden_size=4096, hybrid_override_pattern="*", vocab_size=131072,
+        num_attention_heads=32, num_key_value_heads=2, head_dim=128,
+        mamba_num_heads=128, mamba_head_dim=64, ssm_state_size=128, n_groups=8,
+        conv_kernel=4, chunk_size=128, n_routed_experts=512,
+        num_experts_per_tok=22, moe_intermediate_size=2688,
+        moe_shared_expert_intermediate_size=5376, moe_latent_size=1024,
+        routed_scaling_factor=5, dtype=jnp.bfloat16)
+
+
+@pytest.mark.parametrize("make_cfg,slots,cap,pages,kernels", [
+    (_bloom_layers, 2, 576, 73, 0),               # bloom-7b1's cells, 2 of 30 layers
+    (_hybrid_attention_layer, 32, 2048, 4097, 1),  # the hybrid's one attention layer
+], ids=["bloom-7b1", "nemotron-h-attention"])
+def test_the_decode_chunk_holds_no_loop_but_its_own(
+        one_chip, make_cfg, slots, cap, pages, kernels, monkeypatch):
+    """The dense-view decode chunk at the cells' shapes: a step appends its
+    K/V rows without a loop over the slots (a scatter the TPU compiler
+    expands into a serial ``while`` of trip count = slots, twice a layer a
+    step), so the chunk's own ``while`` is the only one; BLOOM's chunk holds
+    no Mosaic kernel, the hybrid's attention layer its ``decode_attention``."""
+    from deepspeed_tpu.inference.decode_fns import (build_paged_decode_chunk,
+                                                    make_slot_select_fn)
+    from deepspeed_tpu.models.causal_lm import CausalLM, init_cache
+    from deepspeed_tpu.ops.attention import decode
+    monkeypatch.setattr(decode, "_interpret", lambda: False)
+    cfg, page, chunk = make_cfg(), 16, 8
+    module = CausalLM(cfg)
+
+    def sds(shape, dtype=jnp.int32):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    params = jax.eval_shape(lambda: module.init(
+        jax.random.PRNGKey(0), jnp.zeros((1, 8), jnp.int32))["params"])
+    params = jax.tree_util.tree_map(lambda x: sds(x.shape, cfg.dtype), params)
+    caches = jax.tree_util.tree_map(
+        lambda x: sds(x.shape, x.dtype), jax.eval_shape(lambda: init_cache(
+            cfg, slots, cap, kv_shape=(pages, cfg.kv_heads, page, cfg.head_dim))))
+    fn = build_paged_decode_chunk(module, lambda p: p,
+                                  make_slot_select_fn(False, 1.0, 0, 1.0),
+                                  chunk, kv_cap=cap)
+    text = jax.jit(fn, donate_argnums=(2,)).lower(
+        params, sds((slots, 1)), caches, sds((slots, cap // page)), sds((slots,)),
+        sds((slots,), jnp.bool_), sds((slots,)), sds((slots,)), sds((slots,)),
+        sds((slots,)), sds((2,), jnp.uint32)).compile().as_text()
+    loops = [line for line in text.splitlines() if " while(" in line]
+    assert len(loops) == 1, [line.split(" = ")[0].strip() for line in loops]
+    assert 'op_name="jit(decode_chunk)/while"' in loops[0]
+    assert text.count("tpu_custom_call") == kernels
+    assert not kernels or "decode_attention" in text
