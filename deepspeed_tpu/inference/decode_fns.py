@@ -21,6 +21,7 @@ from typing import Any, Callable
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 
 from ..parallel.overlap import overlap_scope
 
@@ -411,3 +412,217 @@ def build_paged_decode_chunk(module, dequant, slot_select, chunk_size: int,
         return (buf, toks, new_caches, lens, active, remaining, steps) + out[7:]
 
     return decode_chunk
+
+
+# ------------------------------------------------- generation by diffusion over blocks
+def block_unmask(cfg, masked, logits, x0):
+    """Which masked positions of a block a denoise forward unmasks (``cfg`` a
+    ``CausalLMConfig`` with ``gen_block_length``): ``masked`` (S, B) bool,
+    ``logits`` (S, B, V) at the block's positions, ``x0`` (S, B) the token
+    chosen at each. ``block / steps`` positions a forward: ``sequential`` the
+    leftmost masked; ``low_confidence_static`` the masked positions whose
+    chosen token has the largest probability; ``low_confidence_dynamic``
+    every masked position whose probability passes the threshold where those
+    are at least as many, else the static choice. Ties go to the left."""
+    B = cfg.gen_block_length
+    n = B // cfg.gen_denoising_steps
+    if cfg.gen_remasking == "sequential":
+        return masked & (jnp.cumsum(masked, axis=1) <= n)
+    lg = logits.astype(jnp.float32)
+    chosen = jnp.take_along_axis(lg, x0[..., None], axis=-1)[..., 0]
+    conf = jnp.exp(chosen - jax.nn.logsumexp(lg, axis=-1))
+    conf = jnp.where(masked, conf, -jnp.inf)
+    place = jnp.arange(B)
+    ahead = (conf[:, None, :] > conf[:, :, None]) | (
+        (conf[:, None, :] == conf[:, :, None]) & (place[None, None, :] < place[None, :, None]))
+    static = masked & (jnp.sum(ahead, axis=2) < n)
+    if cfg.gen_remasking == "low_confidence_static":
+        return static
+    high = masked & (conf > cfg.gen_confidence_threshold)
+    return jnp.where(jnp.sum(high, axis=1, keepdims=True) >= n, high, static)
+
+
+def _block_body(cfg, step_model, slot_select, base_key, seeds, eos_ids, steps_in,
+                width: int):
+    """One FORWARD of generation by blocks over a slot-batch, the body the
+    serving chunk and ``InferenceEngine.generate`` share. ``step_model(ids,
+    caches, lens) -> ((logits (S, B, V), caches), stats or None)`` runs every
+    slot's block (still-masked positions fed as the mask token) against rows
+    ``[0, lens)`` of its cache plus the block's own; the block's keys and
+    values land at ``[lens, lens + B)`` and ``lens`` does not move.
+
+    A slot whose block still has a masked position DENOISES: a token is
+    chosen at each masked position from the logits AT it and some are kept
+    (:func:`block_unmask`). A slot whose block has none COMMITS: the rows
+    this forward wrote are the finished block's, ``lens += B``, the block's
+    generated tokens go to ``buf`` (those the prompt opened it with, ``skip``
+    of them, and those past the tokens asked or an EOS do not), and the next
+    block starts all masked.
+
+    The carry is ``(blk (S, B), masked (S, B), skip, caches, lens, active,
+    remaining, steps, buf (S, width), counts (2,): blocks committed and
+    positions unmasked)`` and, with stats, their running sum."""
+    B = cfg.gen_block_length
+    place = jnp.arange(B, dtype=jnp.int32)
+    cols = jnp.arange(width, dtype=jnp.int32)
+
+    def body(i, s):
+        blk, masked, skip, caches, lens, active, remaining, steps, buf, counts = s[:10]
+        S = blk.shape[0]
+        ids = jnp.where(masked, cfg.mask_token_id, blk).astype(jnp.int32)
+        (logits, caches), stats = step_model(ids, caches, lens)
+        open_ = jnp.any(masked, axis=1)
+        commit = active & ~open_
+        pos = lens[:, None] + place[None]
+        x0 = slot_select(logits.reshape(S * B, -1), base_key, jnp.repeat(seeds, B),
+                         pos.reshape(-1)).reshape(S, B)
+        unmask = block_unmask(cfg, masked, logits, x0) & (active & open_)[:, None]
+        # a committing slot has nothing masked, so its block is untouched here
+        blk = jnp.where(unmask, x0, blk)
+        masked = masked & ~unmask
+        rel = place[None] - skip[:, None]          # place among the generated tokens
+        m = jnp.minimum(B - skip, remaining)
+        is_eos = (rel >= 0) & (rel < m[:, None]) & (blk == eos_ids[:, None])
+        any_eos = jnp.any(is_eos, axis=1)
+        m = jnp.where(any_eos, jnp.argmax(is_eos, axis=1) - skip + 1, m)
+        m = jnp.where(commit, m, 0).astype(jnp.int32)
+        at = cols[None] - (steps - steps_in)[:, None]
+        tok = jnp.take_along_axis(blk, jnp.clip(at + skip[:, None], 0, B - 1), axis=1)
+        buf = jnp.where((at >= 0) & (at < m[:, None]), tok, buf)
+        steps = steps + m
+        remaining = remaining - m
+        active = active & ~(commit & ((remaining <= 0) | any_eos))
+        lens = lens + jnp.where(commit, B, 0).astype(lens.dtype)
+        masked = masked | commit[:, None]
+        skip = jnp.where(commit, 0, skip)
+        counts = counts + jnp.stack([jnp.sum(commit), jnp.sum(unmask)]).astype(jnp.int32)
+        out = (blk, masked, skip, caches, lens, active, remaining, steps, buf, counts)
+        return out if stats is None else out + (s[10] + stats,)
+
+    return body
+
+
+def block_chunk_width(cfg, forwards: int) -> int:
+    """Tokens a slot can emit in ``forwards`` forwards at most: a commit
+    every second forward."""
+    return -(-forwards // 2) * cfg.gen_block_length
+
+
+def build_block_decode_chunk(module, dequant, slot_select, forwards: int,
+                             kv_cap: int, overlap=None, with_stats: bool = False):
+    """The decode chunk of a model that generates by diffusion over blocks:
+    exactly ``forwards`` forwards over a slot-batch (:func:`_block_body`),
+    every shape static. The pages are gathered into the dense per-slot view
+    once, the forwards run on it (a block's rows are written before they
+    count: ``lens`` moves only on a commit), and the blocks COMMITTED in the
+    chunk, rows ``[lens_in, lens_out)`` of a slot, are copied back into its
+    pages at the end, a block at a time: a block never straddles a page (the
+    page size is a multiple of the block), so each is one in-place slab
+    write, and a block that was not committed goes to the null page. The
+    block in flight stays out of the pages: its rows are rewritten by the
+    next forward. Per-slot state between chunks: the block's tokens ``blk``,
+    which are still ``masked``, and how many of them the prompt gave
+    (``skip``)."""
+    from ..ops.paged_attention import gather_kv_dense
+    cfg = module.config
+    B = cfg.gen_block_length
+    width = block_chunk_width(cfg, forwards)
+    stats0 = (jnp.zeros((2,), jnp.int32),) if with_stats else ()
+
+    def decode_chunk(params, blk, masked, skip, caches, page_table, lens, active,
+                     remaining, eos_ids, seeds, steps, base_key):
+        params = dequant(params)
+        S = blk.shape[0]
+        buf = jnp.zeros((S, width), jnp.int32)
+        ps = next(c["k"].shape[2] for c in caches if "k" in c)
+        lens_in = lens
+        dense = [dict(zip(("k", "v"),
+                          gather_kv_dense(c["k"], c["v"], page_table, kv_cap)))
+                 if "k" in c else c for c in caches]
+
+        def step_model(ids, dense, lens):
+            return apply_model(module, params, with_stats, ids,
+                               positions=lens[:, None] + jnp.arange(B)[None],
+                               caches=dense, cache_lens=lens, block_step=True)
+
+        body = _block_body(cfg, step_model, slot_select, base_key, seeds, eos_ids,
+                           steps, width)
+        with overlap_scope(overlap):
+            out = jax.lax.fori_loop(
+                0, forwards, body,
+                (blk, masked, skip, dense, lens, active, remaining, steps, buf,
+                 jnp.zeros((2,), jnp.int32)) + stats0)
+        blk, masked, skip, dense, lens, active, remaining, steps, buf, counts = out[:10]
+        new_caches = []
+        for c, dn in zip(caches, dense):
+            if "k" not in c:
+                new_caches.append(dn)
+                continue
+            pages = dict(c)
+            for j in range(width // B):
+                row0 = lens_in + j * B
+                live = row0 < lens
+                row0 = jnp.minimum(row0, kv_cap - B)
+                page = jnp.where(live, jnp.take_along_axis(
+                    page_table, (row0 // ps)[:, None], axis=1)[:, 0], 0)
+                off = row0 % ps
+                rows = (row0[:, None] + jnp.arange(B)[None])[:, None, :, None]
+                for key in ("k", "v"):
+                    slabs = jnp.take_along_axis(dn[key], rows, axis=2)   # (S, hk, B, d)
+                    for i in range(S):
+                        pages[key] = jax.lax.dynamic_update_slice(
+                            pages[key], slabs[i:i + 1].astype(pages[key].dtype),
+                            (page[i], 0, off[i], 0))
+            new_caches.append(pages)
+        return (buf, blk, masked, skip, new_caches, lens, active, remaining, steps,
+                counts) + out[10:]
+
+    return decode_chunk
+
+
+def build_block_decode_loop(module, dequant, slot_select, gen_cap: int, overlap=None):
+    """``InferenceEngine.generate``'s loop for a model that generates by
+    diffusion over blocks: :func:`_block_body` on the contiguous caches in ONE
+    ``lax.while_loop`` until no row is active. ``remaining`` (rows,) are the
+    tokens asked of each row (0: a row that holds nothing); returns ``buf``
+    (rows, gen_cap), a row's tokens its prefix, the rest ``max(eos, 0)``."""
+    cfg = module.config
+    B = cfg.gen_block_length
+
+    def decode_loop_inner(params, blk, masked, skip, caches, lens, remaining, eos_ids,
+                          seeds, base_key):
+        params = dequant(params)
+        rows = blk.shape[0]
+        buf = jnp.broadcast_to(jnp.maximum(eos_ids, 0)[:, None],
+                               (rows, gen_cap)).astype(jnp.int32)
+        zeros = jnp.zeros((rows,), jnp.int32)
+
+        def step_model(ids, caches, lens):
+            return apply_model(module, params, False, ids,
+                               positions=lens[:, None] + jnp.arange(B)[None],
+                               caches=caches, cache_lens=lens, block_step=True)
+
+        body = _block_body(cfg, step_model, slot_select, base_key, seeds, eos_ids,
+                           zeros, gen_cap)
+        state = (jnp.int32(0), (blk, masked, skip, caches, lens, remaining > 0,
+                                remaining, zeros, buf, jnp.zeros((2,), jnp.int32)))
+        n, out = jax.lax.while_loop(lambda s: jnp.any(s[1][5]),
+                                    lambda s: (s[0] + 1, body(s[0], s[1])), state)
+        return out[8], out[7], n
+
+    def decode_loop(*args):
+        with overlap_scope(overlap):
+            return decode_loop_inner(*args)
+
+    return decode_loop
+
+
+def open_block(cfg, prompt_tail):
+    """Host state of a sequence's first block: ``prompt_tail`` are the
+    ``P % block`` prompt tokens after its last whole block. Returns ``(blk
+    (B,), masked (B,) bool, skip)``: the block opens with them, unmasked."""
+    B = cfg.gen_block_length
+    r = len(prompt_tail)
+    blk = np.zeros(B, np.int32)
+    blk[:r] = prompt_tail
+    return blk, np.arange(B) >= r, r

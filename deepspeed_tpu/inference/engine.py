@@ -31,7 +31,8 @@ from ..parallel.mesh import AXIS_DATA, AXIS_TENSOR, MeshSpec, set_global_mesh
 from ..parallel.overlap import resolve_overlap_config, set_overlap_config
 from ..utils.logging import log_dist, logger
 from .config import DeepSpeedInferenceConfig
-from .decode_fns import build_decode_loop, build_prefill, make_select_fn
+from .decode_fns import (build_block_decode_loop, build_decode_loop, build_prefill,
+                         make_select_fn, make_slot_select_fn, open_block)
 
 
 def spec_fits(mesh_spec, shape, spec) -> bool:
@@ -341,9 +342,45 @@ class InferenceEngine:
 
     # ------------------------------------------------------------------ compiled steps
     def _build_fns(self):
+        if self.model_config.gen_block_length:
+            self._fns["forward"] = jax.jit(self._block_forward)
+            return
         self._fns["forward"] = jax.jit(
             lambda params, ids: self.module.apply(
                 {"params": self._dequant(params)}, ids))
+
+    def _block_forward(self, params, ids):
+        """``forward`` of a model that generates by diffusion over blocks of
+        ``B``, under the same contract: row ``p`` is what token ``p + 1`` is
+        chosen from given ``ids[:p + 1]``. For this model that is the logits
+        AT position ``p + 1`` with ``p + 1`` to the end of its block masked
+        and everything before it clean (the order ``sequential`` unmasks in).
+        One forward gives every row: the family's training layout, a clean
+        copy of the sequence beside ``B`` masked copies under one mask. Copy
+        ``r`` has the places ``>= r`` of every block masked; a query of a
+        masked copy sees the blocks before its own in the CLEAN copy and its
+        own block in its own copy; the clean copy is block-causal. Row ``p``
+        is read from copy ``(p + 1) % B`` at position ``p + 1``."""
+        cfg = self.model_config
+        B = cfg.gen_block_length
+        b, t = ids.shape
+        tp = (t // B + 1) * B                      # room for position t
+        pos = np.arange(tp)
+        clean = jnp.pad(ids, ((0, 0), (0, tp - t)), constant_values=cfg.mask_token_id)
+        copies = [clean] + [jnp.where((pos % B >= r)[None], cfg.mask_token_id, clean)
+                            for r in range(B)]
+        copy = np.repeat(np.arange(B + 1), tp)     # which copy a place of the row is in
+        block = np.tile(pos // B, B + 1)
+        mask = ((copy[None, :] == 0) & (block[None, :] < block[:, None])) | \
+            ((copy[None, :] == copy[:, None]) & (block[None, :] == block[:, None]))
+        want = np.arange(1, t + 1)
+        rows = (1 + want % B) * tp + want          # copy (p + 1) % B, position p + 1
+        return self.module.apply(
+            {"params": self._dequant(params)}, jnp.concatenate(copies, axis=1),
+            positions=jnp.broadcast_to(jnp.asarray(np.tile(pos, B + 1))[None],
+                                       (b, (B + 1) * tp)),
+            attn_mask=jnp.asarray(mask),
+            logits_positions=jnp.broadcast_to(jnp.asarray(rows)[None], (b, t)))
 
     def _loop_fns(self, do_sample, temperature, top_k, top_p, gen_cap):
         """Device-resident generation: prefill (first token, synced for TTFT) + ONE compiled
@@ -375,6 +412,12 @@ class InferenceEngine:
         # No donation on either fn: prefill rebuilds cache buffers (pad-write) and the loop
         # reuses its carry buffers internally — donating caches cannot alias any output
         # (they are not returned) and only produces "donated buffer not usable" warnings.
+        if self.model_config.gen_block_length:
+            # the loop the serving chunk's forwards are: the same body
+            decode_loop = build_block_decode_loop(
+                self.module, self._dequant,
+                make_slot_select_fn(do_sample, temperature, top_k, top_p), gen_cap,
+                overlap=self.comm_overlap)
         fns = (jax.jit(prefill), jax.jit(decode_loop))
         self._fns[key] = fns
         return fns
@@ -466,7 +509,10 @@ class InferenceEngine:
         else:
             lens_np = np.full((b,), t, dtype=np.int32)
 
+        block = int(self.model_config.gen_block_length)
         cap = max(self._config.max_out_tokens, t + max_new_tokens)
+        if block:
+            cap = -(-cap // block) * block     # a last block is written whole
         # buffer sized by the prompt-independent cap so the decode loop compiles ONCE per
         # (cap, sampling config, batch) — varying prompt lengths only recompile prefill
         gen_cap = cap
@@ -496,6 +542,9 @@ class InferenceEngine:
 
         eos = np.int32(-1 if eos_token_id is None else eos_token_id)
         rows = b if do_sample else max(b, int(self.model_config.greedy_decode_rows or 0))
+        if block:
+            return self._generate_blocks(ids, lens_np, caches, decode_loop,
+                                         max_new_tokens, eos, seed, rows)
         if rows > b:
             # the configuration asks for greedy decodes at a fixed row count
             # (``CausalLMConfig.greedy_decode_rows``, there is why): the rows
@@ -532,6 +581,43 @@ class InferenceEngine:
         obs_record_events(events)        # registry: independent of monitor
         if self._monitor is not None and getattr(self._monitor, "enabled", False):
             self._monitor.write_events(events)
+        return np.concatenate([ids, gen], axis=1)
+
+    def _generate_blocks(self, ids, lens_np, caches, decode_loop, max_new_tokens,
+                         eos, seed, rows):
+        """``generate`` of a model that generates by diffusion over blocks,
+        after the prefill: the prompt's whole blocks are committed, the
+        tokens left open the first block, and ONE compiled loop runs the
+        forwards the serving chunk runs (``decode_fns._block_body``) until
+        every row has its tokens. The token the prefill selected is no token
+        of this model and is dropped."""
+        cfg = self.model_config
+        b, B = ids.shape[0], cfg.gen_block_length
+        whole = lens_np // B * B
+        blk = np.zeros((rows, B), np.int32)
+        masked = np.ones((rows, B), bool)
+        skip = np.zeros(rows, np.int32)
+        lens = np.zeros(rows, np.int32)
+        remaining = np.zeros(rows, np.int32)
+        for i in range(b):
+            blk[i], masked[i], skip[i] = open_block(cfg, ids[i, whole[i]:lens_np[i]])
+        lens[:b], remaining[:b] = whole, max_new_tokens
+        if rows > b:     # rows that hold nothing (``greedy_decode_rows``)
+            caches = jax.tree_util.tree_map(
+                lambda a: jnp.pad(a, ((0, rows - b),) + ((0, 0),) * (a.ndim - 1)),
+                caches)
+        t1 = time.perf_counter()
+        buf, steps, n = decode_loop(
+            self.params, blk, masked, skip, caches, lens, remaining,
+            np.full(rows, eos, np.int32), seed + np.arange(rows, dtype=np.int32),
+            jax.random.PRNGKey(seed))
+        gen = np.asarray(buf)[:b, :max_new_tokens]
+        if eos >= 0:     # as the token loop: stop at the longest row's end
+            gen = gen[:, :max(1, int(np.asarray(steps)[:b].max()))]
+        decode_time = time.perf_counter() - t1
+        self.tpot = decode_time / max(1, gen.shape[1])
+        self.decode_tps = b * gen.shape[1] / decode_time if decode_time > 0 else None
+        self._gen_count += 1
         return np.concatenate([ids, gen], axis=1)
 
     # ------------------------------------------------------------------ checkpoints

@@ -43,7 +43,8 @@ from ...utils.fault_injection import fault_point
 from ...ops.paged_attention import (FORCE_FUSED_ENV, fused_paged_for,
                                     page_address, pages_to_dense)
 from ...parallel.mesh import AXIS_TENSOR, get_global_mesh
-from ..decode_fns import (build_paged_decode_chunk, build_paged_spec_verify,
+from ..decode_fns import (block_chunk_width, build_block_decode_chunk,
+                          build_paged_decode_chunk, build_paged_spec_verify,
                           build_prefill, build_prefix_prefill,
                           make_slot_select_fn)
 from ..speculative import accept_tokens
@@ -81,6 +82,13 @@ OUT_TOK, OUT_LEN, OUT_ACTIVE, OUT_REMAINING, OUT_STEPS = range(5)
 PRE_COLS = 3
 
 
+def ctl_head(block: int) -> int:
+    """Columns of a chunk's operand before the page-table row: ``CTL_COLS``
+    and, for a model that generates by blocks of ``block``, the block's
+    tokens, the bitmask of its masked places and ``skip``."""
+    return CTL_COLS + (block + 2 if block else 0)
+
+
 def _packed_chunk(chunk):
     """``chunk`` (``decode_fns.build_paged_decode_chunk``'s function) behind
     the packed operand and result the module's head describes; the name the
@@ -102,6 +110,40 @@ def _packed_chunk(chunk):
             counts = jnp.pad(stats[0], (0, packed.shape[1] - 2))
             packed = jnp.concatenate([packed, counts[None]], axis=0)
         return packed, caches
+
+    return decode_chunk
+
+
+def _packed_block_chunk(chunk, block: int):
+    """``chunk`` (``decode_fns.build_block_decode_chunk``'s function) behind
+    the packed operand and result: a model that generates by diffusion over
+    blocks carries a slot's block in flight between chunks, so its operand
+    has, between ``CTL_COLS`` and the page-table row, the block's ``block``
+    tokens, a bit a position that is still masked, and how many of the tokens
+    the prompt gave; its result has the same three behind ``OUT``'s columns.
+    The last row holds ``(expert assignments, experts touched, blocks
+    committed, positions unmasked)``, the first two 0 without expert layers.
+    The name in the trace stays ``decode_chunk``."""
+    bits = 1 << np.arange(block, dtype=np.int32)
+    tail = ctl_head(block)
+
+    def decode_chunk(params, ctl, caches, base_key):
+        blk = ctl[:, CTL_COLS:CTL_COLS + block]
+        masked = (ctl[:, CTL_COLS + block, None] & bits[None]) != 0
+        buf, blk, masked, skip, caches, lens, active, remaining, steps, counts, \
+            *stats = chunk(
+                params, blk, masked, ctl[:, CTL_COLS + block + 1], caches,
+                ctl[:, tail:], ctl[:, CTL_LEN], ctl[:, CTL_ACTIVE] != 0,
+                ctl[:, CTL_REMAINING], ctl[:, CTL_EOS], ctl[:, CTL_SEED],
+                ctl[:, CTL_STEPS], base_key)
+        packed = jnp.concatenate(
+            [buf, jnp.zeros_like(lens)[:, None], lens[:, None],
+             active.astype(jnp.int32)[:, None], remaining[:, None], steps[:, None],
+             blk, jnp.sum(jnp.where(masked, bits[None], 0), axis=1,
+                          dtype=jnp.int32)[:, None], skip[:, None]], axis=1)
+        moe = stats[0] if stats else jnp.zeros((2,), jnp.int32)
+        last = jnp.pad(jnp.concatenate([moe, counts]), (0, packed.shape[1] - 4))
+        return jnp.concatenate([packed, last[None]], axis=0), caches
 
     return decode_chunk
 
@@ -133,6 +175,12 @@ class ChunkResult:
     moe: Optional[np.ndarray] = None   # (assignments on held experts, distinct
     #   held experts read) over the chunk's steps and expert layers; None for
     #   a model without expert layers
+    block: Optional[Tuple[np.ndarray, np.ndarray, np.ndarray]] = None   # a
+    #   model that generates by blocks: each slot's block in flight after the
+    #   chunk, ``(tokens (S, B), still masked (S, B) bool, given by the
+    #   prompt (S,))``
+    block_counts: Optional[np.ndarray] = None   # and (blocks committed,
+    #   positions unmasked) over the chunk's forwards
 
 
 @dataclass
@@ -185,6 +233,16 @@ class ChunkedDecodeExecutor:
         # scheduler
         self.kv_every_layer = all(k in "A*" for k in kinds)
         self.with_stats = "E" in kinds
+        # a model that generates by diffusion over blocks: the chunk counts
+        # FORWARDS, a slot carries its block in flight between chunks, a
+        # prefill yields no token
+        self.block = int(engine.model_config.gen_block_length)
+        if self.block and (self.cap % self.block
+                           or self.kv_page_size % self.block):
+            raise ValueError(
+                f"a block of {self.block} positions must divide the cap "
+                f"({self.cap}) and the page size ({self.kv_page_size}): a "
+                "block is committed whole and never straddles a page")
         self.last_prefill_moe = None    # the last prefill's counts (or None)
         self.pool = self._build_pool()
         self._slot_select = make_slot_select_fn(*self.sampling)
@@ -272,6 +330,16 @@ class ChunkedDecodeExecutor:
                self.pool.page_size, self.cap, self.chunk_size,
                self.sampling, fused)
         fns = self.engine._fns
+        if key not in fns and self.block:
+            # always on the dense view: every query of a block sees the same
+            # rows, which is the decode kernel's operand
+            chunk = build_block_decode_chunk(
+                self.engine.module, self.engine._dequant, self._slot_select,
+                self.chunk_size, kv_cap=self.cap,
+                overlap=getattr(self.engine, "comm_overlap", None),
+                with_stats=self.with_stats)
+            fns[key] = jax.jit(_packed_block_chunk(chunk, self.block),
+                               donate_argnums=(2,))          # pages
         if key not in fns:
             chunk = build_paged_decode_chunk(
                 self.engine.module, self.engine._dequant,
@@ -448,7 +516,9 @@ class ChunkedDecodeExecutor:
         the ``serving.prefill`` / ``serving.suffix_prefill`` span — the
         scheduler's TTFT and the span share it. The spans nest (in the ring)
         under whatever span the calling thread has open, ``serving.admit``
-        when the scheduler calls.
+        when the scheduler calls. A model that generates by diffusion over
+        blocks yields no token here (``first_token`` is None): the prompt's
+        whole blocks are committed, and the tokens left open its first block.
         """
         # lint: host-sync-ok (host prompt tokens, never a device value)
         prompt = np.asarray(prompt, dtype=np.int32).reshape(-1)
@@ -531,6 +601,11 @@ class ChunkedDecodeExecutor:
                 self.last_prefill_moe = out[1:]
                 sp.set(moe_assignments=int(out[1]),
                        moe_experts_touched=int(out[2]))
+            if self.block:
+                # the whole blocks of the prompt are committed and no token
+                # is yielded: what the head gave is not a token of this model
+                tok0 = None
+                sp.set(blocks_committed=t // self.block)
         with tracer.span("serving.scatter_prefill"):
             self.pool.scatter_prefill(slot, one_caches)
         obs_profiler.tick("prefill")
@@ -538,9 +613,12 @@ class ChunkedDecodeExecutor:
 
     def run_chunk(self, toks: np.ndarray, lens: np.ndarray, active: np.ndarray,
                   remaining: np.ndarray, eos_ids: np.ndarray, seeds: np.ndarray,
-                  steps: np.ndarray) -> ChunkResult:
+                  steps: np.ndarray, block=None) -> ChunkResult:
         """One K-step compiled chunk over the slot-batch; pool pages are donated
         in and rebound from the output. All other state is host numpy.
+        ``block``: a model that generates by blocks is also given each slot's
+        block in flight (``ChunkResult.block``'s triple), K counts forwards
+        and ``toks`` is not read.
 
         With ``chunk_deadline_s`` set, dispatch + host fetch run on a watchdog
         thread; an overrun raises :class:`ChunkTimeoutError` and the pool is left
@@ -554,19 +632,27 @@ class ChunkedDecodeExecutor:
         # wedged chunk and the caller rebuilds the pool, the late-finishing
         # thread must keep donating the OLD buffers, never the fresh pool's
         caches_in = self.pool.caches
-        S, K = self.slots, self.chunk_size
+        S, K, B = self.slots, self.chunk_size, self.block
+        head = ctl_head(B)
+        if B:
+            K = block_chunk_width(self.engine.model_config, K)
         with tracer.span("serving.place_inputs", program="decode_chunk",
                          arrays=1) as placed:
             # a fresh array a call: the CPU client may alias a host buffer for
             # the device array's life, and a chunk the watchdog abandoned
             # still holds its operand
-            ctl = np.empty((S, CTL_COLS + self.pool.max_pages), np.int32)
+            ctl = np.empty((S, head + self.pool.max_pages), np.int32)
+            if B:
+                blk, masked, skip = block
+                ctl[:, CTL_COLS:CTL_COLS + B] = blk
+                ctl[:, CTL_COLS + B] = masked @ (1 << np.arange(B))
+                ctl[:, CTL_COLS + B + 1] = skip
             for col, host in ((CTL_TOK, np.reshape(toks, -1)), (CTL_LEN, lens),
                               (CTL_ACTIVE, active), (CTL_REMAINING, remaining),
                               (CTL_EOS, eos_ids), (CTL_SEED, seeds),
                               (CTL_STEPS, steps)):
                 ctl[:, col] = host
-            ctl[:, CTL_COLS:] = self.pool.page_table
+            ctl[:, head:] = self.pool.page_table
             args = (self.engine.params, jax.device_put(ctl), caches_in,
                     self._base_key)
         (packed,), caches, t1 = self._dispatch_watched(
@@ -575,6 +661,12 @@ class ChunkedDecodeExecutor:
         obs_profiler.tick("decode_chunk")
         self.pool.caches = caches
         state = packed[:S, K:]
+        in_flight = counts = None
+        if B:
+            tail = state[:, OUT_STEPS + 1:]
+            in_flight = (tail[:, :B], (tail[:, B, None] >> np.arange(B)) & 1 != 0,
+                         tail[:, B + 1])
+            counts = packed[S, 2:4]
         return ChunkResult(buf=packed[:S, :K],
                            toks=state[:, OUT_TOK:OUT_TOK + 1],
                            lens=state[:, OUT_LEN],
@@ -582,7 +674,8 @@ class ChunkedDecodeExecutor:
                            remaining=state[:, OUT_REMAINING],
                            steps=state[:, OUT_STEPS],
                            elapsed=t1 - placed.t1,
-                           moe=packed[S, :2] if self.with_stats else None)
+                           moe=packed[S, :2] if self.with_stats else None,
+                           block=in_flight, block_counts=counts)
 
     def _timed(self, fn, args, program: str, fault: str):
         """The region a chunk's deadline must cover, as a callable for

@@ -98,6 +98,19 @@ def encode_frame(payload: bytes) -> bytes:
             + (zlib.crc32(payload) & 0xFFFFFFFF).to_bytes(4, "big") + payload)
 
 
+def _readable(socks, timeout_s: float) -> list:
+    """Those of ``socks`` that can be read within ``timeout_s``. ``poll`` and
+    not ``select``: a process that holds more than 1024 descriptors hands out
+    socket numbers that ``select`` refuses outright (``filedescriptor out of
+    range``), which severed a healthy link for ever. A closed socket raises
+    ``ValueError`` here as it did there."""
+    by_fd = {s.fileno(): s for s in socks}
+    poller = select.poll()
+    for fd in by_fd:
+        poller.register(fd, select.POLLIN)
+    return [by_fd[fd] for fd, _ in poller.poll(timeout_s * 1e3)]
+
+
 class FrameDecoder:
     """Streaming frame decoder with the v1 quarantine contract: garbage
     between frames, a corrupted CRC, or an insane length is counted +
@@ -475,7 +488,7 @@ class SocketReplicaLink(SubprocessReplica):
                     return             # peer's decoder resyncs on its CRC
             # ----------------------------------------------------- read side
             try:
-                r, _, _ = select.select([sock, self._wake_r], [], [], 0.05)
+                r = _readable((sock, self._wake_r), 0.05)
             except (OSError, ValueError):
                 self._on_sever(sock, "select")
                 return

@@ -49,6 +49,7 @@ import numpy as np
 from ...observability.trace import CAT_SERVING, get_tracer
 from ...utils.fault_injection import fault_point, retry_with_backoff
 from ...utils.logging import logger
+from ..decode_fns import open_block
 from ..speculative import SpeculativeConfig, make_proposer
 from .executor import (ChunkedDecodeExecutor, ChunkTimeoutError,
                        ReplicaKilledError)
@@ -196,6 +197,23 @@ class ContinuousBatchingScheduler:
                  monitor=None):
         self.config = cfg = config or ServingConfig()
         cap = int(cfg.max_seq_len or engine._config.max_out_tokens)
+        # it is the model that generates by diffusion over blocks, not a
+        # serving switch: block length, steps, order and mask token are the
+        # model configuration's
+        self.block = block = int(engine.model_config.gen_block_length)
+        if block and cfg.speculate:
+            raise ValueError(
+                "speculate with this model: it generates by diffusion over "
+                f"blocks of {block}, so a forward yields no next token for a "
+                "draft to be checked against and a block's rows count only "
+                "once it is committed; set speculate=False")
+        if block and cfg.prefix_cache is not None and cfg.prefix_cache.enabled \
+                and cfg.kv_page_size % block:
+            raise ValueError(
+                f"prefix_cache.enabled with kv_page_size={cfg.kv_page_size} "
+                f"and a model that generates in blocks of {block}: a cached "
+                "prefix is shared by whole pages and a block is committed "
+                "whole, so the block length must divide the page size")
         self.executor = ChunkedDecodeExecutor(
             engine, slots=cfg.slots, cap=cap, chunk_size=cfg.chunk_size,
             do_sample=cfg.do_sample, temperature=cfg.temperature,
@@ -262,6 +280,12 @@ class ContinuousBatchingScheduler:
         self._eos = np.full(S, -1, np.int32)
         self._seeds = np.zeros(S, np.int32)
         self._steps = np.zeros(S, np.int32)
+        # generation by blocks: each slot's block in flight (its tokens, which
+        # are still masked, how many the prompt gave); carried as state and
+        # never read back from the ids, since a prompt may hold the mask token
+        self._blk = np.zeros((S, max(block, 1)), np.int32)
+        self._masked = np.ones((S, max(block, 1)), bool)
+        self._skip = np.zeros(S, np.int32)
         self._step_idx = 0
         # stalled deliveries: prefills done so far, and per slot how many had
         # been done at its stream's last delivery (or its own first token)
@@ -633,11 +657,12 @@ class ContinuousBatchingScheduler:
         span.set(outcome="ok")
         handle.state = RequestState.RUNNING
         handle.slot = slot
-        handle.tokens.append(int(tok0))
-        # the stamp at which the token was on the host: the end of the
-        # executor's prefill span
-        handle.first_token_at = first_token_at
-        handle.ttft = first_token_at - handle.arrival
+        if tok0 is not None:
+            handle.tokens.append(int(tok0))
+            # the stamp at which the token was on the host: the end of the
+            # executor's prefill span
+            handle.first_token_at = first_token_at
+            handle.ttft = first_token_at - handle.arrival
         handle.prefix_hit_tokens = prefix_len
         self._prefills_done += 1
         self._prefills_seen[slot] = self._prefills_done
@@ -649,21 +674,32 @@ class ContinuousBatchingScheduler:
                 and self.prefix_cache.config.insert_on == "prefill"):
             self._insert_prefix(handle, slot)
         eos = -1 if handle.eos_token_id is None else int(handle.eos_token_id)
-        if tok0 == eos or handle.max_new_tokens == 1:
+        if tok0 is None:
+            # generation by blocks: the prefill committed the prompt's whole
+            # blocks and yielded no token; the tokens left open the first
+            # block, unmasked, and the slot starts it at the next chunk. The
+            # first token is stamped when a chunk has brought it to the host
+            whole = int(handle.prompt.size) // self.block * self.block
+            self._blk[slot], self._masked[slot], self._skip[slot] = open_block(
+                self.executor.engine.model_config, handle.prompt[whole:])
+            tok0, committed, emitted = 0, whole, 0
+        elif tok0 == eos or handle.max_new_tokens == 1:
             self._retire_prefix(handle, slot)
             self._finalize(handle, RequestState.FINISHED,
                            "eos" if tok0 == eos else "length",
                            time.monotonic())
             self._release(slot)
+            return True
         else:
-            self._slot_req[slot] = handle
-            self._toks[slot] = tok0
-            self._lens[slot] = handle.prompt.size
-            self._active[slot] = True
-            self._remaining[slot] = handle.max_new_tokens - 1
-            self._eos[slot] = eos
-            self._seeds[slot] = handle.seed
-            self._steps[slot] = 1       # token 0 came from prefill
+            committed, emitted = handle.prompt.size, 1   # token 0 came from prefill
+        self._slot_req[slot] = handle
+        self._toks[slot] = tok0
+        self._lens[slot] = committed
+        self._active[slot] = True
+        self._remaining[slot] = handle.max_new_tokens - emitted
+        self._eos[slot] = eos
+        self._seeds[slot] = handle.seed
+        self._steps[slot] = emitted
         return True
 
     def _fail_in_flight(self, now: float) -> None:
@@ -699,9 +735,11 @@ class ContinuousBatchingScheduler:
             fault_point("serving.decode_chunk")
             if spec:
                 return self._spec_round()
+            in_flight = {"block": (self._blk, self._masked, self._skip)} \
+                if self.block else {}
             return self.executor.run_chunk(
                 self._toks, self._lens, self._active, self._remaining,
-                self._eos, self._seeds, self._steps)
+                self._eos, self._seeds, self._steps, **in_flight)
 
         # the span stays on this thread whether or not the chunk watchdog
         # moves the dispatch to its worker; the executor's place_inputs /
@@ -744,10 +782,19 @@ class ContinuousBatchingScheduler:
             if res.moe is not None:
                 span.set(moe_assignments=int(res.moe[0]),
                          moe_experts_touched=int(res.moe[1]))
+            if res.block_counts is not None:
+                span.set(forwards=width, block_length=self.block,
+                         blocks_committed=int(res.block_counts[0]),
+                         positions_unmasked=int(res.block_counts[1]))
         now = span.t1
         with tracer.span("serving.harvest") as harvest:
             chunk_t0 = now - res.elapsed
             for slot, h in delivered:
+                if h.first_token_at is None:
+                    # generation by blocks: the request's first generated
+                    # token reached the host with this chunk, no earlier
+                    h.first_token_at = now
+                    h.ttft = now - h.arrival
                 h.tokens.extend(res.buf[slot, :counts[slot]].tolist())
                 self._prefills_seen[slot] = self._prefills_done
                 # one ring span per participating request: the chunk is a
@@ -765,6 +812,9 @@ class ContinuousBatchingScheduler:
             self._remaining = res.remaining.copy()
             self._steps = res.steps.copy()
             self._active = res.active.copy()
+            if res.block is not None:
+                self._blk, self._masked, self._skip = (
+                    np.array(a) for a in res.block)
             finished = 0
             for slot in np.nonzero(was_active & ~res.active)[0]:
                 h = self._slot_req[int(slot)]
@@ -781,6 +831,8 @@ class ContinuousBatchingScheduler:
         self.telemetry.on_chunk(total, res.elapsed, slot_steps=slot_steps,
                                 deliveries=len(delivered), stalled=stalled)
         self.telemetry.on_moe(res.moe)
+        if res.block_counts is not None:
+            self.telemetry.on_blocks(width, res.block_counts)
         if spec:
             self.telemetry.on_spec(res.proposed, res.accepted, total,
                                    res.draft_s, res.elapsed)

@@ -21,7 +21,10 @@ parentheses):
 - ``serving/decode_slot_steps_total``, ``serving/decode_tokens_kept_total``,
   ``serving/deliveries_total``, ``serving/deliveries_stalled_total``,
   ``serving/moe_assignments_total``, ``serving/moe_experts_touched_total``
-  (expert layers only), ``serving/ssm_state_bytes`` (state-space layers only) — per
+  (expert layers only), ``serving/ssm_state_bytes`` (state-space layers only),
+  ``serving/block_forwards_total``, ``serving/blocks_committed_total``,
+  ``serving/positions_unmasked_total`` (a model that generates by diffusion over
+  blocks only) — per
   scheduler step: decode steps run against tokens a stream kept, and the
   deliveries a prefill of another request held up (the same counts ride the
   ``serving.decode_chunk`` span);
@@ -104,6 +107,9 @@ class ServingTelemetry:
         # expert layers (a model without them leaves these at 0 and unpublished)
         self.moe_assignments = 0
         self.moe_experts_touched = 0
+        self.block_forwards = 0
+        self.blocks_committed = 0
+        self.positions_unmasked = 0
         # prefix-cache counters (only advanced when the cache is enabled)
         self.prefix_enabled = False
         self.prefix_hits = 0
@@ -160,6 +166,13 @@ class ServingTelemetry:
                     float(self.moe_assignments), self._tick),
                    ("serving/moe_experts_touched_total",
                     float(self.moe_experts_touched), self._tick)]
+        if self.block_forwards:
+            ev += [("serving/block_forwards_total", float(self.block_forwards),
+                    self._tick),
+                   ("serving/blocks_committed_total",
+                    float(self.blocks_committed), self._tick),
+                   ("serving/positions_unmasked_total",
+                    float(self.positions_unmasked), self._tick)]
         if prefix_stats is not None:
             self._prefix_stats = prefix_stats
             # hit_rate here is ADMISSION-level (successful prefills), the same
@@ -203,6 +216,14 @@ class ServingTelemetry:
         if stats is not None:
             self.moe_assignments += int(stats[0])
             self.moe_experts_touched += int(stats[1])
+
+    def on_blocks(self, forwards: int, counts) -> None:
+        """One decode chunk of a model that generates by diffusion over
+        blocks: the forwards it ran and ``counts`` = (blocks committed,
+        positions unmasked) over its slots."""
+        self.block_forwards += int(forwards)
+        self.blocks_committed += int(counts[0])
+        self.positions_unmasked += int(counts[1])
 
     def on_chunk(self, tokens: int, elapsed: float, slot_steps: int = 0,
                  deliveries: int = 0, stalled: int = 0) -> None:

@@ -77,6 +77,12 @@ class CausalLMConfig:
     # mixers the pattern names.
     layer_pattern: Optional[str] = None
     head_dim_override: Optional[int] = None  # attention head size where != n_embd / n_head
+    qk_norm: bool = False                    # RMSNorm of q and k per head, before the rotation
+    # what an "E" layer is: "latent" (sigmoid router with a selection bias,
+    # experts in a latent space, a shared expert: ``moe/latent_moe.py``) or
+    # "gated" (softmax router, SwiGLU experts of the full width:
+    # ``moe/gated_moe.py``)
+    moe_kind: str = "latent"
     mamba_num_heads: int = 0
     mamba_head_dim: int = 0
     ssm_state_size: int = 0
@@ -103,11 +109,44 @@ class CausalLMConfig:
     # from ``generate`` the very tokens its serving chunk gives sets its slot
     # count here. None = the batch's own rows
     greedy_decode_rows: Optional[int] = None
+    # GENERATION BY DIFFUSION OVER BLOCKS (0 = one token a step, left to
+    # right). The model generates ``gen_block_length`` positions at a time:
+    # a block starts as ``mask_token_id``, is run ``gen_denoising_steps``
+    # times with full attention inside the block and attention to the blocks
+    # before it, positions are unmasked between the runs in the order
+    # ``gen_remasking`` says, and a last run on the finished block writes its
+    # keys and values. The attention mask is then block-causal everywhere: key
+    # ``j`` is seen by query ``i`` iff ``j // block <= i // block``. It is the
+    # model that generates this way, so the serving scheduler and
+    # ``InferenceEngine.generate`` read these fields and have no switch
+    gen_block_length: int = 0
+    gen_denoising_steps: int = 0
+    gen_remasking: str = "sequential"
+    gen_confidence_threshold: float = 0.9
+    mask_token_id: int = 0
 
     VALID_MOE_DECODE_IMPLS = ("pallas", "xla")
     LAYER_KINDS = ("M", "*", "E")
+    MOE_KINDS = ("latent", "gated")
+    REMASKING = ("sequential", "low_confidence_static", "low_confidence_dynamic")
 
     def __post_init__(self):
+        if self.moe_kind not in self.MOE_KINDS:
+            raise ValueError(f"moe_kind={self.moe_kind!r} is not one of "
+                             f"{self.MOE_KINDS}")
+        if self.gen_block_length:
+            B, n = self.gen_block_length, self.gen_denoising_steps
+            if B < 2 or n < 1 or B % n:
+                raise ValueError(
+                    f"gen_block_length={B} with gen_denoising_steps={n}: a block "
+                    "of at least 2 positions, unmasked in equal parts over the "
+                    "steps (the steps must divide the block)")
+            if self.gen_remasking not in self.REMASKING:
+                raise ValueError(f"gen_remasking={self.gen_remasking!r} is not "
+                                 f"one of {self.REMASKING}")
+            if not 0 <= self.mask_token_id < self.vocab_size:
+                raise ValueError(f"mask_token_id={self.mask_token_id} lies "
+                                 f"outside the vocabulary ({self.vocab_size})")
         # case-sensitive on purpose: 'XLA'/'Pallas'/'triton' must not silently
         # select the pallas path through a failed == "xla" comparison
         if self.moe_decode_impl not in self.VALID_MOE_DECODE_IMPLS:
@@ -179,6 +218,11 @@ class CausalLMConfig:
                       + self.held_experts[1] * 2 * self.moe_latent_size
                       * self.moe_expert_width),
             }
+            if self.moe_kind == "gated":
+                per["E"] = (d * self.n_routed_experts
+                            + self.held_experts[1] * 3 * d * self.moe_expert_width)
+            if self.qk_norm:
+                per["*"] += 2 * self.head_dim
             return (v * d + sum(per[k] + d for k in self.layer_pattern) + d
                     + (0 if self.tie_word_embeddings else v * d))
         f = self.ffn_dim
@@ -273,6 +317,43 @@ def nemotron_h_cfg(*, hidden_size, hybrid_override_pattern, vocab_size,
         routed_scaling_factor=float(routed_scaling_factor),
         norm_topk_prob=bool(norm_topk_prob),
         experts_held=None if experts_held is None else tuple(experts_held), **kw)
+
+
+def sdar_moe_cfg(*, hidden_size, num_hidden_layers, vocab_size,
+                 num_attention_heads, num_key_value_heads, head_dim, num_experts,
+                 num_experts_per_tok, moe_intermediate_size, norm_topk_prob=True,
+                 rms_norm_eps=1e-6, rope_theta=1e6, gen_block_length=4,
+                 gen_denoising_steps=4, gen_remasking="sequential",
+                 gen_confidence_threshold=0.9, mask_token_id=151669,
+                 experts_held=None, **kw) -> CausalLMConfig:
+    """SDAR mixtures of experts (``model_type: sdar_moe``): the keywords are the
+    published config's, and ``gen_*`` / ``mask_token_id`` what the family's
+    ``generate.py`` takes (the config gives none). A published layer is
+    attention and then a mixture of experts, each ``x + f(rmsnorm(x))``: here
+    the pair of mixer layers "*E", so ``n_layer`` is twice
+    ``num_hidden_layers``. Attention: grouped keys and values, RMSNorm of q
+    and k per head before a rotation over the whole head, no bias; experts:
+    softmax router, top ``num_experts_per_tok`` renormalised, SwiGLU of width
+    ``moe_intermediate_size``, no shared expert; RMSNorm, untied head. The
+    mask is block-causal and generation is by diffusion over blocks
+    (``CausalLMConfig.gen_block_length``)."""
+    kw.setdefault("name", "sdar-moe")
+    return CausalLMConfig(
+        n_embd=hidden_size, n_layer=2 * int(num_hidden_layers),
+        layer_pattern="*E" * int(num_hidden_layers), vocab_size=vocab_size,
+        n_head=num_attention_heads, n_kv_head=num_key_value_heads,
+        head_dim_override=head_dim, qk_norm=True, pos_emb="rotary",
+        rotary_base=float(rope_theta), layernorm="rmsnorm", ln_eps=rms_norm_eps,
+        qkv_bias=False, mlp_bias=False, tie_word_embeddings=False,
+        moe_kind="gated", n_routed_experts=num_experts,
+        experts_per_token=num_experts_per_tok,
+        moe_expert_width=moe_intermediate_size,
+        norm_topk_prob=bool(norm_topk_prob),
+        experts_held=None if experts_held is None else tuple(experts_held),
+        gen_block_length=int(gen_block_length),
+        gen_denoising_steps=int(gen_denoising_steps), gen_remasking=gen_remasking,
+        gen_confidence_threshold=float(gen_confidence_threshold),
+        mask_token_id=int(mask_token_id), **kw)
 
 
 FAMILIES = {
@@ -570,7 +651,8 @@ class CausalLMLayer(nn.Module):
     def __call__(self, x, positions, cache: Optional[Dict] = None,
                  cache_len: Optional[jnp.ndarray] = None,
                  prefix_fill: bool = False, page_table=None,
-                 kv_cap: Optional[int] = None):
+                 kv_cap: Optional[int] = None, block_step: bool = False,
+                 attn_mask=None):
         """x: (b, t, d). With ``cache`` given (decode): t==1, attention against the cache.
         With ``prefix_fill`` (static): suffix prefill at a nonzero cache offset —
         ``cache`` already holds a restored prompt-prefix KV slab in rows
@@ -588,7 +670,8 @@ class CausalLMLayer(nn.Module):
         cfg = self.config
         h_in = _norm(cfg, "ln_attn")(x).astype(cfg.dtype)
         attn_out, new_kv = self._attention(h_in, positions, cache, cache_len,
-                                           prefix_fill, page_table, kv_cap)
+                                           prefix_fill, page_table, kv_cap,
+                                           block_step, attn_mask)
 
         mlp = self._moe_mlp if self.is_moe else self._mlp
         if cfg.parallel_residual:
@@ -601,13 +684,27 @@ class CausalLMLayer(nn.Module):
         return y, new_kv
 
     def _attention(self, h_in, positions, cache, cache_len, prefix_fill,
-                   page_table, kv_cap):
+                   page_table, kv_cap, block_step=False, attn_mask=None):
         """Causal self-attention on the normed input, in whichever of the
         four cache modes ``__call__`` describes; returns the projected
-        output and the layer's new keys and values (or None)."""
+        output and the layer's new keys and values (or None).
+
+        A model that generates by diffusion over blocks
+        (``cfg.gen_block_length``) has a fifth mode, ``block_step``: ``t`` is
+        the block, its keys and values are written at rows ``[cache_len,
+        cache_len + t)`` of the dense cache and every query of the block sees
+        rows ``[0, cache_len + t)``: the committed blocks and its own. Its
+        whole-sequence modes take the block-causal mask, or ``attn_mask`` (t,
+        t) bool where the caller lays several copies of a sequence side by
+        side (``InferenceEngine.forward``)."""
         cfg = self.config
         b, t, _ = h_in.shape
         q, k, v = self._attn_proj(h_in)
+        if cfg.qk_norm:
+            q = nn.RMSNorm(epsilon=cfg.ln_eps, dtype=jnp.float32,
+                           name="q_norm")(q).astype(cfg.dtype)
+            k = nn.RMSNorm(epsilon=cfg.ln_eps, dtype=jnp.float32,
+                           name="k_norm")(k).astype(cfg.dtype)
         if cfg.pos_emb == "rotary":
             q = apply_rotary(q, positions, cfg.rotary_base, cfg.rotary_pct)
             k = apply_rotary(k, positions, cfg.rotary_base, cfg.rotary_pct)
@@ -616,7 +713,15 @@ class CausalLMLayer(nn.Module):
                   if cfg.pos_emb == "alibi" else None)
 
         new_kv = None
-        if cache is not None and t == 1 and page_table is not None:
+        if block_step:
+            if cache is None or page_table is not None or slopes is not None:
+                raise NotImplementedError(
+                    "a block step runs on the dense cache view, without alibi")
+            k_cache = _cache_update(cache["k"], k.transpose(0, 2, 1, 3), cache_len)
+            v_cache = _cache_update(cache["v"], v.transpose(0, 2, 1, 3), cache_len)
+            new_kv = {"k": k_cache, "v": v_cache}
+            o = _block_decode(q, k_cache, v_cache, cache_len + t)
+        elif cache is not None and t == 1 and page_table is not None:
             # paged decode: append at the page-mapped row, attend by page index
             from ..ops.paged_attention import (gather_kv_dense,
                                                paged_attention,
@@ -645,6 +750,10 @@ class CausalLMLayer(nn.Module):
             new_kv = {"k": k_cache, "v": v_cache}
             o = _sharded_decode(q[:, 0], k_cache, v_cache, cache_len + 1,
                                 alibi=slopes)[:, None]
+        elif cache is not None and prefix_fill and cfg.gen_block_length:
+            raise NotImplementedError(
+                "a prefill at a cache offset is causal: a model that generates "
+                "by blocks has no prefix hits and no speculative verify")
         elif cache is not None and prefix_fill:
             # suffix prefill at offset cache_len: scatter suffix K/V into rows
             # [cache_len, cache_len + t) (OOB pad rows drop), attend each suffix
@@ -661,7 +770,10 @@ class CausalLMLayer(nn.Module):
             new_kv = {"k": k_cache, "v": v_cache}
             o = _prefix_attention_xla(q, k_cache, v_cache, cache_len, slopes)
         else:
-            o = _bias_attention(q, k, v, slopes)
+            if cache is not None and attn_mask is not None:
+                raise NotImplementedError("attn_mask is for a forward without a cache")
+            o = _bias_attention(q, k, v, slopes, cfg.gen_block_length or 1,
+                                attn_mask)
             if cache is not None:
                 # prefill: write the prompt's K/V (post-rotary) into the fixed cache
                 T = cache["k"].shape[2]
@@ -692,13 +804,15 @@ class MixerLayer(CausalLMLayer):
     def __call__(self, x, positions, cache: Optional[Dict] = None,
                  cache_len: Optional[jnp.ndarray] = None,
                  prefix_fill: bool = False, page_table=None,
-                 kv_cap: Optional[int] = None, seq_lens=None):
+                 kv_cap: Optional[int] = None, seq_lens=None,
+                 block_step: bool = False, attn_mask=None):
         cfg = self.config
         h = _norm(cfg, "norm")(x).astype(cfg.dtype)
         out_std = cfg.init_std / (2 * cfg.n_layer) ** 0.5
         if self.kind == "*":
             out, new = self._attention(h, positions, cache, cache_len,
-                                       prefix_fill, page_table, kv_cap)
+                                       prefix_fill, page_table, kv_cap,
+                                       block_step, attn_mask)
         elif self.kind == "M":
             if prefix_fill:
                 raise NotImplementedError(
@@ -714,17 +828,27 @@ class MixerLayer(CausalLMLayer):
                 init_std=cfg.init_std, out_std=out_std, name="mamba")(
                     h, cache=cache, seq_lens=seq_lens)
         else:
-            from ..moe.latent_moe import LatentMoE
             valid = None
-            if seq_lens is not None and x.shape[1] > 1:
+            if seq_lens is not None and x.shape[1] > 1 and not block_step:
                 valid = jnp.arange(x.shape[1])[None, :] < seq_lens[:, None]
-            out, stats = LatentMoE(
-                d_model=cfg.n_embd, n_routed=cfg.n_routed_experts,
-                top_k=cfg.experts_per_token, expert_width=cfg.moe_expert_width,
-                shared_width=cfg.moe_shared_width, latent=cfg.moe_latent_size,
-                scale=cfg.routed_scaling_factor, norm_topk=cfg.norm_topk_prob,
-                experts_held=cfg.held_experts, dtype=cfg.dtype,
-                init_std=cfg.init_std, out_std=out_std, name="moe")(h, valid)
+            if cfg.moe_kind == "gated":
+                from ..moe.gated_moe import GatedMoE
+                moe = GatedMoE(
+                    d_model=cfg.n_embd, n_routed=cfg.n_routed_experts,
+                    top_k=cfg.experts_per_token, expert_width=cfg.moe_expert_width,
+                    norm_topk=cfg.norm_topk_prob, experts_held=cfg.held_experts,
+                    dtype=cfg.dtype, init_std=cfg.init_std, out_std=out_std,
+                    name="moe")
+            else:
+                from ..moe.latent_moe import LatentMoE
+                moe = LatentMoE(
+                    d_model=cfg.n_embd, n_routed=cfg.n_routed_experts,
+                    top_k=cfg.experts_per_token, expert_width=cfg.moe_expert_width,
+                    shared_width=cfg.moe_shared_width, latent=cfg.moe_latent_size,
+                    scale=cfg.routed_scaling_factor, norm_topk=cfg.norm_topk_prob,
+                    experts_held=cfg.held_experts, dtype=cfg.dtype,
+                    init_std=cfg.init_std, out_std=out_std, name="moe")
+            out, stats = moe(h, valid)
             self.sow("stats", "moe_counts", stats)
             new = None if cache is None else {}
         return x + out.astype(x.dtype), new
@@ -738,8 +862,16 @@ def make_layer(cfg: CausalLMConfig, i: int, **kw):
     return MixerLayer(cfg, kind=kind, **kw)
 
 
-def _bias_attention(q, k, v, slopes):
+def block_causal_mask(t: int, block: int):
+    """(t, t) bool: key ``j`` is seen by query ``i`` iff ``j // block <= i // block``."""
+    pos = np.arange(t) // block
+    return pos[None, :] <= pos[:, None]
+
+
+def _bias_attention(q, k, v, slopes, mask_block: int = 1, attn_mask=None):
     """Full-sequence causal attention, optionally with per-head alibi slopes.
+    ``mask_block`` > 1 makes the mask block-causal (:func:`block_causal_mask`);
+    ``attn_mask`` (t, t) bool replaces the mask altogether (XLA path).
 
     The alibi bias rides INSIDE the Pallas flash kernel (no (h, t, s) bias tensor in
     HBM — the reference fuses the same bias into ``softmax_kernels.cu``). Lengths
@@ -752,8 +884,17 @@ def _bias_attention(q, k, v, slopes):
         g = q.shape[2] // k.shape[2]
         k = jnp.repeat(k, g, axis=2)
         v = jnp.repeat(v, g, axis=2)
-    if flash_eligible(q.shape[1]):
-        return flash_attention(q, k, v, causal=True, alibi_slopes=slopes)
+    t = q.shape[1]
+    if attn_mask is not None or (mask_block > 1 and not (
+            flash_eligible(t) and t % mask_block == 0)):
+        if slopes is not None:
+            raise NotImplementedError("alibi with a mask that is not causal")
+        if attn_mask is None:
+            attn_mask = jnp.asarray(block_causal_mask(t, mask_block))
+        return xla_attention(q, k, v, causal=False, mask=attn_mask[None, None])
+    if flash_eligible(t):
+        return flash_attention(q, k, v, causal=True, alibi_slopes=slopes,
+                               mask_block=mask_block)
     if slopes is None:
         return xla_attention(q, k, v, causal=True)
     return _alibi_attention_xla(q, k, v, slopes)
@@ -804,8 +945,23 @@ def _prefix_attention_xla(q, k_cache, v_cache, offset, slopes=None):
     return o.reshape(b, t, h, d).astype(q.dtype)
 
 
+def _block_decode(q, k_cache, v_cache, lens):
+    """Attention of a whole block of queries against the cache: ``q`` (b, t, h,
+    d), every query of a sequence sees the same rows ``[0, lens)`` (the
+    committed blocks and its own), so the ``t`` queries of a key head's ``g``
+    query heads are ``t x g`` rows of ONE group in ``decode_attention``'s
+    ``(b, h_kv, g, d)`` operand: the decode kernel, with no mask of its own."""
+    b, t, h, d = q.shape
+    hk = k_cache.shape[1]
+    g = h // hk
+    rows = q.reshape(b, t, hk, g, d).transpose(0, 2, 1, 3, 4).reshape(b, hk * t * g, d)
+    o = _sharded_decode(rows, k_cache, v_cache, lens)
+    return o.reshape(b, hk, t, g, d).transpose(0, 2, 1, 3, 4).reshape(b, t, h, d)
+
+
 def _cache_update(cache, new, cache_len):
-    """cache: (b, hk, T, d); new: (b, hk, 1, d); write at per-sequence position.
+    """cache: (b, hk, T, d); new: (b, hk, t, d), ``t`` = 1 for a decode step and
+    the block for a block step; write at per-sequence position.
 
     One ``dynamic_update_slice`` a sequence with the sequence's index static:
     each is one in-place write on a loop's carry, a position at or past ``T``
@@ -884,11 +1040,16 @@ class CausalLM(nn.Module):
     @nn.compact
     def __call__(self, input_ids, positions=None, caches=None, cache_lens=None,
                  logits_positions=None, prefix_fill=False, page_table=None,
-                 kv_cap=None, seq_lens=None):
+                 kv_cap=None, seq_lens=None, block_step=False, attn_mask=None):
         """``seq_lens`` (b,): the real lengths of right-padded rows of a
         prefill, for layers whose state a padded token would advance.
 
-        ``logits_positions`` (b,): compute the LM head ONLY at these sequence
+        ``block_step`` (static; a model with ``gen_block_length``): the ``t``
+        inputs are one block a sequence, run against the dense caches at
+        offset ``cache_lens`` (``CausalLMLayer._attention``). ``attn_mask``
+        (t, t) bool: the whole mask of a forward without caches.
+
+        ``logits_positions`` (b,) or (b, n): compute the LM head ONLY at these sequence
         positions (serving prefill needs just each prompt's last valid token — for a
         250k vocab at t=512 this removes ~99.8% of the head matmul and the (b, t, V)
         fp32 logits buffer; reference parity: ds_inference reads final-token logits).
@@ -917,6 +1078,8 @@ class CausalLM(nn.Module):
             layer_cache = None if caches is None else caches[i]
             # only a layer of one mixer is told the rows' real lengths
             extra = {} if cfg.layer_kind(i) == "A" else {"seq_lens": seq_lens}
+            if block_step or attn_mask is not None:
+                extra.update(block_step=block_step, attn_mask=attn_mask)
             x, new_kv = make_layer(cfg, i, name=f"layers_{i}")(
                 x, positions, cache=layer_cache, cache_len=cache_lens,
                 prefix_fill=prefix_fill, page_table=page_table,
@@ -924,7 +1087,9 @@ class CausalLM(nn.Module):
             new_caches.append(new_kv)
 
         x = _norm(cfg, "ln_f")(x)
-        if logits_positions is not None:
+        if logits_positions is not None and logits_positions.ndim == 2:
+            x = jnp.take_along_axis(x, logits_positions[..., None], axis=1)
+        elif logits_positions is not None:
             x = x[jnp.arange(b), logits_positions][:, None]    # (b, 1, d)
         if cfg.tie_word_embeddings:
             logits = x.astype(jnp.float32) @ wte.T

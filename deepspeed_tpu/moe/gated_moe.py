@@ -1,0 +1,70 @@
+"""Mixture of gated experts behind a softmax router, one chip's share of it.
+
+The router scores every expert of the layer (``n_routed``) in float32, ``p =
+softmax(h W_r)``, chooses the ``top_k`` largest and weights the chosen by
+their probabilities, renormalised to sum to one where ``norm_topk`` says so:
+no selection bias, no scaling, no shared expert (the Qwen3-MoE layer, which
+``sdar_moe`` keeps). An expert is a SwiGLU block of the full width, ``W_down
+(silu(h W_gate) * (h W_up))``, three matrices.
+
+As :class:`~.latent_moe.LatentMoE` the layer is TOLD which experts it holds,
+``experts_held = (first, count)``: it routes over all ``n_routed`` and sums
+only the assignments that fall on its own experts. With ``count ==
+n_routed`` it is the whole layer; the partial sums of the chips of an
+expert-parallel layer add up to it. Prefill and block step go through one
+plan and one grouped kernel (``ops/moe/grouped_ffn.py``, the gated form).
+"""
+
+from typing import Any, Optional, Tuple
+
+import flax.linen as nn
+import jax
+import jax.numpy as jnp
+
+from ..ops.moe.grouped_ffn import grouped_experts
+
+
+def route_softmax(h, w_router, top_k: int, norm: bool):
+    """``h`` (T, d). Returns the chosen experts ``idx`` (T, k) int32 and their
+    weights (T, k) float32: softmax over all experts in float32, the ``top_k``
+    largest, renormalised over the chosen where ``norm``."""
+    p = jax.nn.softmax(jnp.dot(h.astype(jnp.float32), w_router.astype(jnp.float32),
+                               precision=jax.lax.Precision.HIGHEST), axis=-1)
+    w, idx = jax.lax.top_k(p, top_k)
+    if norm:
+        w = w / jnp.sum(w, axis=-1, keepdims=True)
+    return idx.astype(jnp.int32), w
+
+
+class GatedMoE(nn.Module):
+    d_model: int
+    n_routed: int
+    top_k: int
+    expert_width: int
+    norm_topk: bool
+    experts_held: Tuple[int, int]
+    dtype: Any
+    init_std: float
+    out_std: float
+
+    @nn.compact
+    def __call__(self, h, valid: Optional[jnp.ndarray] = None):
+        """``h`` (b, t, d) normed input; ``valid`` (b, t) bool marks real
+        tokens (padding is routed nowhere). Returns the layer's output and
+        ``(assignments on held experts, distinct held experts touched)``."""
+        first, count = self.experts_held
+        d, f, dt = self.d_model, self.expert_width, self.dtype
+        init = nn.initializers.normal(self.init_std)
+        w_r = self.param("router", init, (d, self.n_routed), jnp.float32)
+        w_gate = self.param("experts_gate", init, (count, d, f), jnp.float32)
+        w_up = self.param("experts_up", init, (count, d, f), jnp.float32)
+        w_down = self.param("experts_down", nn.initializers.normal(self.out_std),
+                            (count, f, d), jnp.float32)
+        b_, t, _ = h.shape
+        x = h.reshape(b_ * t, d).astype(dt)
+        idx, w = route_softmax(x, w_r, self.top_k, self.norm_topk)
+        out, stats = grouped_experts(
+            x, idx, w, first, count, w_up.astype(dt), w_down.astype(dt),
+            jax.nn.silu, None if valid is None else valid.reshape(-1),
+            w_gate.astype(dt))
+        return out.astype(dt).reshape(b_, t, d), stats

@@ -23,7 +23,7 @@ import flax.linen as nn
 import jax
 import jax.numpy as jnp
 
-from ..ops.moe.grouped_ffn import dispatch_plan, grouped_ffn, tile_rows
+from ..ops.moe.grouped_ffn import grouped_experts
 
 
 def relu2(x):
@@ -84,18 +84,12 @@ class LatentMoE(nn.Module):
         x = h.reshape(b_ * t, d).astype(dt)
         idx, w = route(x, w_r, b_r, self.top_k, self.scale, self.norm_topk)
         z = x @ w_down.astype(dt)                                     # (T, l)
-        tm = tile_rows(idx.size)
-        plan = dispatch_plan(idx, first, count, tm,
-                             None if valid is None else valid.reshape(-1))
-        rows = grouped_ffn(z[plan["row_token"]], plan["tile_expert"],
-                           plan["tile_valid"], w1.astype(dt), w2.astype(dt),
-                           self.act, tm)                              # (R, l) f32
-        mine = jnp.take(rows, plan["pos"], axis=0, mode="fill", fill_value=0.0)
-        r = jnp.sum(jnp.where(plan["held"], w, 0.0)[..., None] * mine, axis=1)
+        r, stats = grouped_experts(
+            z, idx, w, first, count, w1.astype(dt), w2.astype(dt), self.act,
+            None if valid is None else valid.reshape(-1))
         shared = self.act(jnp.dot(x, s1.astype(dt),
                                   preferred_element_type=jnp.float32))
         out = r.astype(dt) @ w_up.astype(dt) + shared.astype(dt) @ s2.astype(dt)
-        stats = jnp.stack([plan["n_assigned"], plan["n_touched"]]).astype(jnp.int32)
         return out.reshape(b_, t, d), stats
 
 

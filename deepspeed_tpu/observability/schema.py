@@ -83,6 +83,15 @@ TAGS: Dict[str, Tuple[str, str]] = {
                                                    "kernel had to read"),
     "serving/ssm_state_bytes": (GAUGE, "bytes of the per-slot recurrent "
                                        "state of the state-space layers"),
+    # ------------------------- generation by diffusion over blocks (PR 31)
+    "serving/block_forwards_total": (COUNTER, "forwards run by decode chunks "
+                                              "of a model that generates by "
+                                              "blocks (denoise and commit)"),
+    "serving/blocks_committed_total": (COUNTER, "blocks whose keys and "
+                                                "values were committed to a "
+                                                "slot's cache"),
+    "serving/positions_unmasked_total": (COUNTER, "block positions unmasked "
+                                                  "by denoise forwards"),
     # ------------------------------------------------------------------ router
     "router/queue_depth": (GAUGE, "router admission queue depth per tick"),
     "router/retried_total": (COUNTER, "checkpointless retries (re-enqueues)"),
@@ -223,9 +232,11 @@ SPANS: Dict[str, Tuple[str, str, Tuple[str, ...], str]] = {
                                "tier's slab restored into fresh pages)"),
     "serving.prefill": (BOTH, "compiled steps",
                         ("request_id", "bucket", "tokens", "prefix_len",
-                         "moe_assignments", "moe_experts_touched"),
+                         "moe_assignments", "moe_experts_touched",
+                         "blocks_committed"),
                         "sched_admit_host_ms by bucket; attribution phase "
-                        "prefill; moe_experts_touched_per_step lines"),
+                        "prefill; moe_experts_touched_per_step lines; "
+                        "block_tokens_per_forward lines"),
     "serving.suffix_prefill": (BOTH, "compiled steps",
                                ("request_id", "bucket", "tokens",
                                 "prefix_len"),
@@ -240,11 +251,15 @@ SPANS: Dict[str, Tuple[str, str, Tuple[str, ...], str]] = {
                              ("chunk", "active_slots", "request_ids",
                               "slot_steps_run", "tokens_kept", "deliveries",
                               "stalled_deliveries", "moe_assignments",
-                              "moe_experts_touched"),
+                              "moe_experts_touched", "forwards",
+                              "blocks_committed", "positions_unmasked",
+                              "block_length"),
                              "decode_wasted_step_pct, delivery_stalled_pct, "
                              "sched_fetch_idle_ms_per_step, "
                              "moe_experts_touched_per_step, "
-                             "moe_ffn_roofline_pct"),
+                             "moe_ffn_roofline_pct, block_tokens_per_forward, "
+                             "block_forward_hbm_roofline_pct, "
+                             "moe_gated_ffn_roofline_pct"),
     "serving.spec_verify": (BOTH, "compiled steps",
                             ("chunk", "active_slots", "request_ids",
                              "slot_steps_run", "tokens_kept", "deliveries",

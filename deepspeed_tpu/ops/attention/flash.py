@@ -158,12 +158,16 @@ def _dot(a, b, contract):
                                preferred_element_type=jnp.float32)
 
 
-def _scores(rows, cols, scale, slope, off, masked, key_axis):
+def _scores(rows, cols, scale, slope, off, masked, key_axis, mask_block=1):
     """Scaled scores ``rows @ cols^T`` of one tile; keys run along ``key_axis`` of
     the result and ``off`` = (first q row) - (first key column) in sequence
     positions. The alibi term ``slope * (key - query)`` rides every tile; iota,
     compare and select are spent only where ``masked`` says the diagonal crosses
-    the tile."""
+    the tile. ``mask_block`` > 1 is the block-causal mask: a key is seen when its
+    block of that many positions is not later than the query's, so a query sees
+    up to the end of its own block; tiles start on block boundaries (every tile
+    size is a multiple of the block), so the query's place in its block is its
+    row's in the tile."""
     s = _dot(rows, cols, (1, 1)) * scale
     if slope is None and not masked:
         return s
@@ -173,7 +177,10 @@ def _scores(rows, cols, scale, slope, off, masked, key_axis):
     if slope is not None:
         # 0 on the diagonal, negative below (alibi distance penalty)
         s = s + slope * dist.astype(jnp.float32)
-    if masked:
+    if masked and mask_block > 1:
+        reach = mask_block - 1 - jax.lax.rem(query, mask_block)
+        s = jnp.where(dist <= reach, s, NEG_INF)
+    elif masked:
         s = jnp.where(dist <= 0, s, NEG_INF)
     return s
 
@@ -219,7 +226,7 @@ def _walk(causal, q_idx, k_idx, nq, bq, bk, by_cols, sb, strip, commit):
 
 
 # ----------------------------------------------------------------------- forward kernel
-def _fwd_kernel(*refs, scale, causal, use_alibi, nq, nk, bq, bk):
+def _fwd_kernel(*refs, scale, causal, use_alibi, nq, nk, bq, bk, mask_block=1):
     q_ref, k_ref, v_ref = refs[:3]
     slopes_ref = refs[3] if use_alibi else None
     o_ref, lse_ref, *scratch = refs[4 if use_alibi else 3:]
@@ -242,7 +249,8 @@ def _fwd_kernel(*refs, scale, causal, use_alibi, nq, nk, bq, bk):
         slope = slopes_ref[0, 0, 0] if use_alibi else None
         q = q_ref[0, r0:r0 + nr, :]
         ss = [_scores(q, k_ref[0, c0:c0 + nc, :], scale, slope,
-                      base_off + r0 - c0, masked, 1) for c0, nc, masked in parts]
+                      base_off + r0 - c0, masked, 1, mask_block)
+              for c0, nc, masked in parts]
         # one kv block holds every key of its rows: no running max to start from
         m = None if nk == 1 else m_scr[r0:r0 + nr, :]
         for s in ss:
@@ -286,7 +294,7 @@ def _fwd_kernel(*refs, scale, causal, use_alibi, nq, nk, bq, bk):
         write(0, bq, m_scr[...], l_scr[...], acc_scr[...])
 
 
-def _flash_fwd(q3, k3, v3, slopes3, scale, causal, block_q, block_k):
+def _flash_fwd(q3, k3, v3, slopes3, scale, causal, block_q, block_k, mask_block=1):
     """q3/k3/v3: (bh, t, d); slopes3: per-(b·h) alibi slopes broadcast to
     (bh, 8, 128) for lane alignment, or None. Returns (o3, lse (bh, t))."""
     bh, t, d = q3.shape
@@ -297,7 +305,8 @@ def _flash_fwd(q3, k3, v3, slopes3, scale, causal, block_q, block_k):
 
     k_index = _k_index_map(causal, bq, bk)
     kernel = functools.partial(_fwd_kernel, scale=scale, causal=causal,
-                               use_alibi=use_alibi, nq=nq, nk=nk, bq=bq, bk=bk)
+                               use_alibi=use_alibi, nq=nq, nk=nk, bq=bq, bk=bk,
+                               mask_block=mask_block)
     in_specs = [
         pl.BlockSpec((1, bq, d), lambda i, j, kb: (i, j, 0)),
         pl.BlockSpec((1, bk, d), k_index),
@@ -362,7 +371,7 @@ def _summed(out_refs, scratch, scales, step, n_steps, walk):
         store(0, out_refs[0].shape[1], *(scr[...] for scr in scratch))
 
 
-def _bwd_dq_kernel(*refs, scale, causal, use_alibi, nq, nk, bq, bk):
+def _bwd_dq_kernel(*refs, scale, causal, use_alibi, nq, nk, bq, bk, mask_block=1):
     q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref = refs[:6]
     slopes_ref = refs[6] if use_alibi else None
     dq_ref, *scratch = refs[7 if use_alibi else 6:]
@@ -381,7 +390,8 @@ def _bwd_dq_kernel(*refs, scale, causal, use_alibi, nq, nk, bq, bk):
         for c0, nc, masked in parts:
             k = k_ref[0, c0:c0 + nc, :]
             v = v_ref[0, c0:c0 + nc, :]
-            s = _scores(q, k, scale, slope, base_off + r0 - c0, masked, 1)
+            s = _scores(q, k, scale, slope, base_off + r0 - c0, masked, 1,
+                        mask_block)
             p = jnp.exp(s - lse)                               # true probs
             dp = _dot(do, v, (1, 1))
             # ds without its factor ``scale``: applied to the (bq, d) result
@@ -394,7 +404,7 @@ def _bwd_dq_kernel(*refs, scale, causal, use_alibi, nq, nk, bq, bk):
         causal, j, kb, nq, bq, bk, False, BWD_STRIP, strip, commit))
 
 
-def _bwd_dkv_kernel(*refs, scale, causal, use_alibi, nq, bq, bk):
+def _bwd_dkv_kernel(*refs, scale, causal, use_alibi, nq, bq, bk, mask_block=1):
     q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref = refs[:6]
     slopes_ref = refs[6] if use_alibi else None
     dk_ref, dv_ref, *scratch = refs[7 if use_alibi else 6:]
@@ -415,7 +425,8 @@ def _bwd_dkv_kernel(*refs, scale, causal, use_alibi, nq, bq, bk):
             do = do_ref[0, r0:r0 + nr, :]
             lse = lse_ref[0, 0, 0:1, r0:r0 + nr]               # (1, nr)
             delta = delta_ref[0, 0, 0:1, r0:r0 + nr]
-            st = _scores(k, q, scale, slope, base_off + r0 - c0, masked, 0)
+            st = _scores(k, q, scale, slope, base_off + r0 - c0, masked, 0,
+                         mask_block)
             pt = jnp.exp(st - lse)                             # (nc, nr)
             dpt = _dot(v, do, (1, 1))
             dst = (pt * (dpt - delta)).astype(q.dtype)         # see _bwd_dq_kernel
@@ -429,7 +440,8 @@ def _bwd_dkv_kernel(*refs, scale, causal, use_alibi, nq, bq, bk):
         causal, qb, kb, nq, bq, bk, True, BWD_STRIP, strip, commit))
 
 
-def _flash_bwd(q3, k3, v3, o3, lse, do3, slopes3, scale, causal, block_q, block_k):
+def _flash_bwd(q3, k3, v3, o3, lse, do3, slopes3, scale, causal, block_q, block_k,
+               mask_block=1):
     bh, t, d = q3.shape
     bq, bk = _block_sizes(t, block_q, block_k)
     nq, nk = t // bq, t // bk
@@ -453,7 +465,8 @@ def _flash_bwd(q3, k3, v3, o3, lse, do3, slopes3, scale, causal, block_q, block_
         dq_args.append(slopes3)
     dq = pl.pallas_call(
         functools.partial(_bwd_dq_kernel, scale=scale, causal=causal,
-                          use_alibi=use_alibi, nq=nq, nk=nk, bq=bq, bk=bk),
+                          use_alibi=use_alibi, nq=nq, nk=nk, bq=bq, bk=bk,
+                          mask_block=mask_block),
         grid=(bh, nq, nk),
         in_specs=dq_in_specs,
         out_specs=pl.BlockSpec((1, bq, d), lambda i, j, kb: (i, j, 0)),
@@ -481,7 +494,8 @@ def _flash_bwd(q3, k3, v3, o3, lse, do3, slopes3, scale, causal, block_q, block_
         dkv_args.append(slopes3)
     dk, dv = pl.pallas_call(
         functools.partial(_bwd_dkv_kernel, scale=scale, causal=causal,
-                          use_alibi=use_alibi, nq=nq, bq=bq, bk=bk),
+                          use_alibi=use_alibi, nq=nq, bq=bq, bk=bk,
+                          mask_block=mask_block),
         grid=(bh, nk, nq),
         in_specs=dkv_in_specs,
         out_specs=[
@@ -502,24 +516,26 @@ def _flash_bwd(q3, k3, v3, o3, lse, do3, slopes3, scale, causal, block_q, block_
 
 
 # --------------------------------------------------------------------------- public op
-@functools.partial(jax.custom_vjp, nondiff_argnums=(4, 5, 6, 7, 8))
-def _flash_core(q3, k3, v3, slopes3, scale, causal, use_alibi, block_q, block_k):
+@functools.partial(jax.custom_vjp, nondiff_argnums=(4, 5, 6, 7, 8, 9))
+def _flash_core(q3, k3, v3, slopes3, scale, causal, use_alibi, block_q, block_k,
+                mask_block=1):
     o3, _ = _flash_fwd(q3, k3, v3, slopes3 if use_alibi else None, scale, causal,
-                       block_q, block_k)
+                       block_q, block_k, mask_block)
     return o3
 
 
-def _flash_core_fwd(q3, k3, v3, slopes3, scale, causal, use_alibi, block_q, block_k):
+def _flash_core_fwd(q3, k3, v3, slopes3, scale, causal, use_alibi, block_q, block_k,
+                    mask_block=1):
     o3, lse = _flash_fwd(q3, k3, v3, slopes3 if use_alibi else None, scale, causal,
-                         block_q, block_k)
+                         block_q, block_k, mask_block)
     return o3, (q3, k3, v3, o3, lse, slopes3)
 
 
-def _flash_core_bwd(scale, causal, use_alibi, block_q, block_k, res, do3):
+def _flash_core_bwd(scale, causal, use_alibi, block_q, block_k, mask_block, res, do3):
     q3, k3, v3, o3, lse, slopes3 = res
     dq, dk, dv = _flash_bwd(q3, k3, v3, o3, lse, do3,
                             slopes3 if use_alibi else None, scale, causal,
-                            block_q, block_k)
+                            block_q, block_k, mask_block)
     # alibi slopes are a fixed schedule, not trained — zero cotangent
     return dq, dk, dv, jnp.zeros_like(slopes3)
 
@@ -539,11 +555,17 @@ def _slopes3(alibi_slopes, b, h):
 def flash_attention_local(q4, k4, v4, causal: bool = True,
                           softmax_scale: Optional[float] = None,
                           alibi_slopes: Optional[jnp.ndarray] = None,
-                          block_q: int = 1024, block_k: int = 1024):
+                          block_q: int = 1024, block_k: int = 1024,
+                          mask_block: int = 1):
     """Per-shard kernel invocation with NO mesh dispatch — for callers already inside a
     ``shard_map`` manual region (e.g. the TP pipeline stage_fn), where the public
     :func:`flash_attention`'s own shard_map wrapper would illegally nest."""
     lb, lt, lh, ld = q4.shape
+    if mask_block > 1 and (not causal or lt % mask_block
+                           or min(_block_sizes(lt, block_q, block_k)) % mask_block):
+        raise ValueError(
+            f"mask_block={mask_block} is the block-causal mask: it needs causal=True "
+            f"and a sequence ({lt}) and tiles that whole blocks divide")
     scale = softmax_scale if softmax_scale is not None else 1.0 / float(np.sqrt(ld))
     use_alibi = alibi_slopes is not None
     slopes3 = (_slopes3(alibi_slopes, lb, lh) if use_alibi
@@ -553,7 +575,7 @@ def flash_attention_local(q4, k4, v4, causal: bool = True,
         return x.transpose(0, 2, 1, 3).reshape(lb * lh, lt, ld)
 
     o3 = _flash_core(to3(q4), to3(k4), to3(v4), slopes3, scale, causal, use_alibi,
-                     block_q, block_k)
+                     block_q, block_k, mask_block)
     return o3.reshape(lb, lh, lt, ld).transpose(0, 2, 1, 3)
 
 
@@ -562,7 +584,8 @@ def flash_attention(q: jnp.ndarray, k: jnp.ndarray, v: jnp.ndarray,
                     softmax_scale: Optional[float] = None,
                     dropout_rate: float = 0.0, dropout_rng=None,
                     alibi_slopes: Optional[jnp.ndarray] = None,
-                    block_q: int = 1024, block_k: int = 1024) -> jnp.ndarray:
+                    block_q: int = 1024, block_k: int = 1024,
+                    mask_block: int = 1) -> jnp.ndarray:
     """Drop-in replacement for ``xla_attention``: q/k/v ``(b, t, h, d)`` → ``(b, t, h, d)``.
 
     ``alibi_slopes`` (h,) adds the per-head alibi distance bias ``slope*(col-row)``
@@ -570,14 +593,18 @@ def flash_attention(q: jnp.ndarray, k: jnp.ndarray, v: jnp.ndarray,
     kernel, ``softmax_kernels.cu``) — no (h, t, s) bias tensor is ever materialised.
 
     Falls back to the XLA path for features the kernel does not cover (arbitrary masks,
-    attention dropout, cross-attention with different kv length). There is no
+    attention dropout, cross-attention with different kv length). ``mask_block`` > 1
+    (with ``causal``) is the block-causal mask of generation by diffusion over blocks:
+    key ``j`` is seen by query ``i`` iff ``j // mask_block <= i // mask_block``; only the
+    tiles on the diagonal change, so what causality skips stays skipped. There is no
     sequence-length guard: K/V blocks stream through the grid pipeline, so VMEM use is
     O(block) regardless of t.
     """
     from ..transformer.attention import xla_attention
     if mask is not None or dropout_rate > 0.0 or q.shape[1] != k.shape[1]:
-        if alibi_slopes is not None:
+        if alibi_slopes is not None or mask_block > 1:
             raise NotImplementedError(
+                "mask_block is kernel-only" if mask_block > 1 else
                 "alibi_slopes is kernel-only: combine it with mask/dropout/"
                 "cross-attention via the model-level XLA bias path instead")
         return xla_attention(q, k, v, causal=causal, mask=mask,
@@ -590,7 +617,8 @@ def flash_attention(q: jnp.ndarray, k: jnp.ndarray, v: jnp.ndarray,
     def local(q4, k4, v4, slopes=None):
         return flash_attention_local(q4, k4, v4, causal=causal, softmax_scale=scale,
                                      alibi_slopes=slopes,
-                                     block_q=block_q, block_k=block_k)
+                                     block_q=block_q, block_k=block_k,
+                                     mask_block=mask_block)
 
     # A pallas_call is opaque to the SPMD partitioner: under a sharded mesh it would force a
     # full rematerialisation. Run the kernel per-shard with shard_map over the batch (and TP

@@ -6,9 +6,10 @@ expert order, in tiles of ``tm`` rows, each tile holding rows of ONE expert
 kernel walks the tiles; the expert of a tile is scalar-prefetched and picks
 the weight block in the ``BlockSpec`` index maps, so consecutive tiles of one
 expert reuse the block already in VMEM and an expert nobody chose is never
-fetched. Per tile: ``act(x W1_e) W2_e``. Tiles beyond the last real one (the
-grid is sized for the worst case) point at the last real tile's expert — no
-fetch — and write zeros.
+fetched. Per tile: ``act(x W1_e) W2_e``, or with a gate matrix the gated form
+``(act(x Wg_e) * (x W1_e)) W2_e`` (the same kernel with one weight block more).
+Tiles beyond the last real one (the grid is sized for the worst case) point at
+the last real tile's expert — no fetch — and write zeros.
 
 ``dispatch_plan`` also says where each assignment's row went, so the caller
 gathers its ``k`` rows back per token and weights them: no scatter-add.
@@ -24,7 +25,8 @@ from jax.experimental.pallas import tpu as pltpu
 
 from ...utils.device import pallas_interpret as _interpret
 
-# 2 x (W1 + W2) blocks of an expert in flight plus the tile's activations
+# 2 x (W1 + W2, and the gate's where there is one) blocks of an expert in
+# flight plus the tile's activations
 VMEM_LIMIT_BYTES = 64 * 2 ** 20
 
 
@@ -87,7 +89,8 @@ def dispatch_plan(idx, first: int, count: int, tm: int, valid=None):
             "n_assigned": jnp.sum(here), "n_touched": jnp.sum(here > 0)}
 
 
-def grouped_ffn_xla(x_rows, tile_expert, tile_valid, w1, w2, act, tm: int):
+def grouped_ffn_xla(x_rows, tile_expert, tile_valid, w1, w2, act, tm: int,
+                    w_gate=None):
     """``jax.numpy`` form: gathers a copy of each tile's expert (so it reads
     an expert once a TILE and writes the copy): what the kernel is checked
     against, not what serves."""
@@ -95,19 +98,31 @@ def grouped_ffn_xla(x_rows, tile_expert, tile_valid, w1, w2, act, tm: int):
     xt = x_rows.reshape(NT, tm, -1)
     h = jnp.einsum("ntl,nlf->ntf", xt, w1[tile_expert],
                    preferred_element_type=jnp.float32)
-    y = jnp.einsum("ntf,nfl->ntl", act(h).astype(w2.dtype), w2[tile_expert],
+    if w_gate is None:
+        h = act(h)
+    else:
+        h = act(jnp.einsum("ntl,nlf->ntf", xt, w_gate[tile_expert],
+                           preferred_element_type=jnp.float32)) * h
+    y = jnp.einsum("ntf,nfl->ntl", h.astype(w2.dtype), w2[tile_expert],
                    preferred_element_type=jnp.float32)
     y = jnp.where(tile_valid[:, None, None] == 1, y, 0.0)
     return y.reshape(NT * tm, -1)
 
 
-def _kernel(te_ref, tv_ref, x_ref, w1_ref, w2_ref, o_ref, *, act):
+def _kernel(te_ref, tv_ref, x_ref, *refs, act):
+    """``refs``: the expert's weight blocks ``[gate,] w1, w2`` and the output."""
+    *gate, w1_ref, w2_ref, o_ref = refs
     i = pl.program_id(0)
 
     @pl.when(tv_ref[i] == 1)
     def _():
         h = jnp.dot(x_ref[...], w1_ref[...], preferred_element_type=jnp.float32)
-        o_ref[...] = jnp.dot(act(h).astype(w2_ref.dtype), w2_ref[...],
+        if gate:
+            h = act(jnp.dot(x_ref[...], gate[0][...],
+                            preferred_element_type=jnp.float32)) * h
+        else:
+            h = act(h)
+        o_ref[...] = jnp.dot(h.astype(w2_ref.dtype), w2_ref[...],
                              preferred_element_type=jnp.float32)
 
     @pl.when(tv_ref[i] == 0)
@@ -115,24 +130,28 @@ def _kernel(te_ref, tv_ref, x_ref, w1_ref, w2_ref, o_ref, *, act):
         o_ref[...] = jnp.zeros_like(o_ref)
 
 
-def grouped_ffn(x_rows, tile_expert, tile_valid, w1, w2, act, tm: int):
+def grouped_ffn(x_rows, tile_expert, tile_valid, w1, w2, act, tm: int,
+                w_gate=None):
     """``x_rows`` (NT * tm, l) rows in the plan's order; ``w1`` (e, l, f),
-    ``w2`` (e, f, l) the held experts. Returns (NT * tm, l) float32; rows of
-    padding hold whatever token 0 gives and are never gathered back. The
-    kernel's name in a trace is ``moe_grouped_ffn``."""
+    ``w2`` (e, f, l) the held experts, ``w_gate`` (e, l, f) their gates where
+    the expert is gated (``(act(x Wg) * (x W1)) W2``; None: ``act(x W1) W2``).
+    Returns (NT * tm, l) float32; rows of padding hold whatever token 0 gives
+    and are never gathered back. The kernel's name in a trace is
+    ``moe_grouped_ffn`` in both forms."""
     R, l = x_rows.shape
     f = w1.shape[2]
     NT = R // tm
     if not _interpret() and (l % 128 or f % 128):
-        return grouped_ffn_xla(x_rows, tile_expert, tile_valid, w1, w2, act, tm)
+        return grouped_ffn_xla(x_rows, tile_expert, tile_valid, w1, w2, act, tm,
+                               w_gate)
+    up = pl.BlockSpec((None, l, f), lambda i, te, tv: (te[i], 0, 0))
+    gate = [] if w_gate is None else [w_gate]
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=2,
         grid=(NT,),
-        in_specs=[
-            pl.BlockSpec((tm, l), lambda i, te, tv: (i, 0)),
-            pl.BlockSpec((None, l, f), lambda i, te, tv: (te[i], 0, 0)),
-            pl.BlockSpec((None, f, l), lambda i, te, tv: (te[i], 0, 0)),
-        ],
+        in_specs=[pl.BlockSpec((tm, l), lambda i, te, tv: (i, 0))]
+        + [up] * (len(gate) + 1)
+        + [pl.BlockSpec((None, f, l), lambda i, te, tv: (te[i], 0, 0))],
         out_specs=pl.BlockSpec((tm, l), lambda i, te, tv: (i, 0)),
     )
     return pl.pallas_call(
@@ -144,4 +163,22 @@ def grouped_ffn(x_rows, tile_expert, tile_valid, w1, w2, act, tm: int):
             vmem_limit_bytes=VMEM_LIMIT_BYTES),
         name="moe_grouped_ffn",
         interpret=_interpret(),
-    )(tile_expert, tile_valid, x_rows, w1, w2)
+    )(tile_expert, tile_valid, x_rows, *gate, w1, w2)
+
+
+def grouped_experts(x, idx, w, first: int, count: int, w1, w2, act, valid=None,
+                    w_gate=None):
+    """What an expert layer that holds experts ``[first, first + count)``
+    adds for tokens ``x`` (T, l): plan, ONE kernel call, and each token's
+    ``k`` rows gathered back and weighted (``idx``, ``w`` (T, k): the experts
+    each token chose over ALL experts, and their weights). Assignments that
+    fall on experts held elsewhere add nothing. Returns ``(T, l)`` float32 and
+    ``(assignments on held experts, distinct held experts touched)``."""
+    tm = tile_rows(idx.size)
+    plan = dispatch_plan(idx, first, count, tm, valid)
+    rows = grouped_ffn(x[plan["row_token"]], plan["tile_expert"],
+                       plan["tile_valid"], w1, w2, act, tm, w_gate)   # (R, l) f32
+    mine = jnp.take(rows, plan["pos"], axis=0, mode="fill", fill_value=0.0)
+    out = jnp.sum(jnp.where(plan["held"], w, 0.0)[..., None] * mine, axis=1)
+    stats = jnp.stack([plan["n_assigned"], plan["n_touched"]]).astype(jnp.int32)
+    return out, stats

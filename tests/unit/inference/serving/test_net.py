@@ -600,3 +600,27 @@ def test_bench_net_smoke(capsys):
     assert g["soak_ok"] and g["respawn_with_redial"]
     assert g["sever_resumed_session"] and g["sever_served_after"]
     assert g["delay_no_false_kill"]
+
+
+def test_a_link_reads_a_socket_numbered_past_selects_limit():
+    """A process that holds more than 1024 descriptors (the NVMe tests leave
+    that many open on a worker until they are collected) hands out socket
+    numbers ``select`` refuses; the link polls, so it is served all the same."""
+    import select
+    import socket
+    from deepspeed_tpu.inference.serving.net import _readable
+    held = [open(os.devnull) for _ in range(1100)]
+    a, b = socket.socketpair()
+    try:
+        assert a.fileno() >= 1024
+        with pytest.raises(ValueError):
+            select.select([a], [], [], 0)
+        assert _readable((a,), 0.0) == []
+        b.send(b"x")
+        assert _readable((a, b), 1.0) == [a]
+        b.close()
+        assert _readable((a,), 1.0) == [a] and a.recv(8) == b"x" and a.recv(8) == b""
+    finally:
+        a.close()
+        for f in held:
+            f.close()

@@ -105,3 +105,71 @@ def test_the_decode_chunk_holds_no_loop_but_its_own(
     assert 'op_name="jit(decode_chunk)/while"' in loops[0]
     assert text.count("tpu_custom_call") == kernels
     assert not kernels or "decode_attention" in text
+
+
+@pytest.mark.parametrize("tokens", [128, 512])
+def test_the_gated_expert_kernel_compiles_at_sdars_published_widths(
+        one_chip, tokens, monkeypatch):
+    """``moe_grouped_ffn`` in its gated form (three weight blocks a tile) at
+    SDAR-30B-A3B's widths: a block forward of 32 slots x 4 rows and a
+    512-token prefill, top 8 of 128 experts of 2048 x 768."""
+    from deepspeed_tpu.ops.moe import grouped_ffn as g
+    monkeypatch.setattr(g, "_interpret", lambda: False)
+    held, d, width, k = 128, 2048, 768, 8
+    tm = g.tile_rows(tokens * k)
+    tiles = tokens * k // tm + held
+
+    def sds(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    up = sds((held, d, width), jnp.bfloat16)
+    text = jax.jit(functools.partial(g.grouped_ffn, act=jax.nn.silu, tm=tm)).lower(
+        sds((tiles * tm, d), jnp.bfloat16), sds((tiles,), jnp.int32),
+        sds((tiles,), jnp.int32), up, sds((held, width, d), jnp.bfloat16),
+        w_gate=up).compile().as_text()
+    assert "tpu_custom_call" in text and "moe_grouped_ffn" in text
+
+
+def test_sdars_block_chunk_holds_its_two_kernels_and_no_loop_but_its_own(
+        one_chip, monkeypatch):
+    """The decode chunk of generation by diffusion over blocks at the cell's
+    shapes (32 slots x cap 2048, 10 forwards; 2 of the 8 layers): every
+    forward's block of 4 rows a slot goes through ``decode_attention`` (the 4
+    queries of a key head's 8 query heads as 32 rows of one group) and the
+    gated ``moe_grouped_ffn``; the block's rows are appended and the committed
+    blocks copied back to their pages without a loop over the slots."""
+    from deepspeed_tpu.inference.decode_fns import (build_block_decode_chunk,
+                                                    make_slot_select_fn)
+    from deepspeed_tpu.models.causal_lm import CausalLM, init_cache, sdar_moe_cfg
+    from deepspeed_tpu.ops.attention import decode
+    from deepspeed_tpu.ops.moe import grouped_ffn as g
+    monkeypatch.setattr(decode, "_interpret", lambda: False)
+    monkeypatch.setattr(g, "_interpret", lambda: False)
+    cfg = sdar_moe_cfg(hidden_size=2048, num_hidden_layers=2, vocab_size=151936,
+                       num_attention_heads=32, num_key_value_heads=4, head_dim=128,
+                       num_experts=128, num_experts_per_tok=8,
+                       moe_intermediate_size=768, dtype=jnp.bfloat16)
+    slots, cap, pages, page, forwards, B = 32, 2048, 4097, 16, 10, 4
+    module = CausalLM(cfg)
+
+    def sds(shape, dtype=jnp.int32):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    params = jax.eval_shape(lambda: module.init(
+        jax.random.PRNGKey(0), jnp.zeros((1, 8), jnp.int32))["params"])
+    params = jax.tree_util.tree_map(lambda x: sds(x.shape, cfg.dtype), params)
+    caches = jax.tree_util.tree_map(
+        lambda x: sds(x.shape, x.dtype), jax.eval_shape(lambda: init_cache(
+            cfg, slots, cap, kv_shape=(pages, cfg.kv_heads, page, cfg.head_dim))))
+    fn = build_block_decode_chunk(module, lambda p: p,
+                                  make_slot_select_fn(False, 1.0, 0, 1.0),
+                                  forwards, kv_cap=cap, with_stats=True)
+    text = jax.jit(fn, donate_argnums=(4,)).lower(
+        params, sds((slots, B)), sds((slots, B), jnp.bool_), sds((slots,)), caches,
+        sds((slots, cap // page)), sds((slots,)), sds((slots,), jnp.bool_),
+        sds((slots,)), sds((slots,)), sds((slots,)), sds((slots,)),
+        sds((2,), jnp.uint32)).compile().as_text()
+    loops = [line for line in text.splitlines() if " while(" in line]
+    assert len(loops) == 1, [line.split(" = ")[0].strip() for line in loops]
+    assert text.count("tpu_custom_call") == 4       # two kernels a layer
+    assert "decode_attention" in text and "moe_grouped_ffn" in text
