@@ -17,6 +17,7 @@ independent of which KV slot it lands in and of who shares the slot-batch. Servi
 needs the latter: continuous batching re-binds requests to slots arbitrarily.
 """
 
+import math
 from typing import Any, Callable
 
 import jax
@@ -445,23 +446,33 @@ def block_unmask(cfg, masked, logits, x0):
 def _block_body(cfg, step_model, slot_select, base_key, seeds, eos_ids, steps_in,
                 width: int):
     """One FORWARD of generation by blocks over a slot-batch, the body the
-    serving chunk and ``InferenceEngine.generate`` share. ``step_model(ids,
-    caches, lens) -> ((logits (S, B, V), caches), stats or None)`` runs every
-    slot's block (still-masked positions fed as the mask token) against rows
-    ``[0, lens)`` of its cache plus the block's own; the block's keys and
-    values land at ``[lens, lens + B)`` and ``lens`` does not move.
+    serving chunk and ``InferenceEngine.generate`` share. A forward carries
+    TWO blocks a slot: the block in flight (still-masked positions fed as the
+    mask token) at rows ``[lens, lens + B)`` of the slot's cache, and behind
+    it a block of mask tokens at ``[lens + B, lens + 2B)``. ``step_model(ids
+    (S, 2B), caches, lens, second (S,) bool) -> ((logits (S, B, V), caches),
+    stats or None)`` (:func:`_block_step_model`) writes the keys and values of
+    both, lets the first see rows ``[0, lens + B)`` and the second ``[0, lens
+    + 2B)``, and hands out the logits of the second block where ``second``,
+    else of the first; ``lens`` does not move in it.
 
     A slot whose block still has a masked position DENOISES: a token is
     chosen at each masked position from the logits AT it and some are kept
-    (:func:`block_unmask`). A slot whose block has none COMMITS: the rows
-    this forward wrote are the finished block's, ``lens += B``, the block's
-    generated tokens go to ``buf`` (those the prompt opened it with, ``skip``
-    of them, and those past the tokens asked or an EOS do not), and the next
-    block starts all masked.
+    (:func:`block_unmask`); its second block is padding, which no query of
+    the slot's first block sees and which the next forward rewrites. A slot
+    whose block has none COMMITS and OPENS THE NEXT BLOCK in the same forward:
+    the first block's rows are the finished block's, ``lens += B``, the
+    block's generated tokens go to ``buf`` (those the prompt opened it with,
+    ``skip`` of them, and those past the tokens asked or an EOS do not), and
+    the second block is the next one, all masked, seeing exactly the committed
+    blocks and itself: its first positions are unmasked from the logits at its
+    rows. A slot that ends at its commit (length or EOS) throws the opened
+    block away.
 
     The carry is ``(blk (S, B), masked (S, B), skip, caches, lens, active,
-    remaining, steps, buf (S, width), counts (2,): blocks committed and
-    positions unmasked)`` and, with stats, their running sum."""
+    remaining, steps, buf (S, width), counts (3,): blocks committed, positions
+    unmasked, and commits that opened their next block)`` and, with stats,
+    their running sum."""
     B = cfg.gen_block_length
     place = jnp.arange(B, dtype=jnp.int32)
     cols = jnp.arange(width, dtype=jnp.int32)
@@ -469,17 +480,12 @@ def _block_body(cfg, step_model, slot_select, base_key, seeds, eos_ids, steps_in
     def body(i, s):
         blk, masked, skip, caches, lens, active, remaining, steps, buf, counts = s[:10]
         S = blk.shape[0]
-        ids = jnp.where(masked, cfg.mask_token_id, blk).astype(jnp.int32)
-        (logits, caches), stats = step_model(ids, caches, lens)
-        open_ = jnp.any(masked, axis=1)
-        commit = active & ~open_
-        pos = lens[:, None] + place[None]
-        x0 = slot_select(logits.reshape(S * B, -1), base_key, jnp.repeat(seeds, B),
-                         pos.reshape(-1)).reshape(S, B)
-        unmask = block_unmask(cfg, masked, logits, x0) & (active & open_)[:, None]
-        # a committing slot has nothing masked, so its block is untouched here
-        blk = jnp.where(unmask, x0, blk)
-        masked = masked & ~unmask
+        commit = active & ~jnp.any(masked, axis=1)
+        ids = jnp.concatenate(
+            [jnp.where(masked, cfg.mask_token_id, blk),
+             jnp.full((S, B), cfg.mask_token_id, blk.dtype)], axis=1).astype(jnp.int32)
+        (logits, caches), stats = step_model(ids, caches, lens, commit)
+        # the commit: the finished block's tokens to ``buf``
         rel = place[None] - skip[:, None]          # place among the generated tokens
         m = jnp.minimum(B - skip, remaining)
         is_eos = (rel >= 0) & (rel < m[:, None]) & (blk == eos_ids[:, None])
@@ -495,17 +501,61 @@ def _block_body(cfg, step_model, slot_select, base_key, seeds, eos_ids, steps_in
         lens = lens + jnp.where(commit, B, 0).astype(lens.dtype)
         masked = masked | commit[:, None]
         skip = jnp.where(commit, 0, skip)
-        counts = counts + jnp.stack([jnp.sum(commit), jnp.sum(unmask)]).astype(jnp.int32)
+        # the denoise: of the block in flight, or of the block just opened
+        pos = lens[:, None] + place[None]
+        x0 = slot_select(logits.reshape(S * B, -1), base_key, jnp.repeat(seeds, B),
+                         pos.reshape(-1)).reshape(S, B)
+        unmask = block_unmask(cfg, masked, logits, x0) & active[:, None]
+        blk = jnp.where(unmask, x0, blk)
+        masked = masked & ~unmask
+        counts = counts + jnp.stack([jnp.sum(commit), jnp.sum(unmask),
+                                     jnp.sum(commit & active)]).astype(jnp.int32)
         out = (blk, masked, skip, caches, lens, active, remaining, steps, buf, counts)
         return out if stats is None else out + (s[10] + stats,)
 
     return body
 
 
+def _block_step_model(module, params, with_stats: bool):
+    """:func:`_block_body`'s ``step_model``: one forward of two blocks a
+    sequence on the dense caches at offset ``lens``. Only the rows that
+    count reach the expert layers (the first block, and the second where it
+    is a block: ``seq_lens``), and only the block whose logits are read goes
+    through the head (``logits_positions``)."""
+    B = module.config.gen_block_length
+    place = jnp.arange(B, dtype=jnp.int32)
+
+    def step_model(ids, caches, lens, second):
+        first_row = jnp.where(second, B, 0).astype(jnp.int32)
+        return apply_model(module, params, with_stats, ids,
+                           positions=lens[:, None] + jnp.arange(2 * B)[None],
+                           caches=caches, cache_lens=lens, block_step=True,
+                           seq_lens=B + first_row,
+                           logits_positions=first_row[:, None] + place[None])
+
+    return step_model
+
+
 def block_chunk_width(cfg, forwards: int) -> int:
-    """Tokens a slot can emit in ``forwards`` forwards at most: a commit
-    every second forward."""
-    return -(-forwards // 2) * cfg.gen_block_length
+    """Tokens a slot can emit in ``forwards`` forwards at most. A chunk's
+    first forward may commit; a block opened by a commit is unmasked ``block
+    / steps`` positions a forward under the ``sequential`` and
+    ``low_confidence_static`` orders, so the next commit comes ``steps``
+    forwards later; ``low_confidence_dynamic`` may unmask it whole in the
+    forward that opens it: a commit every forward."""
+    per = 1 if cfg.gen_remasking == "low_confidence_dynamic" else cfg.gen_denoising_steps
+    return (1 + (forwards - 1) // per) * cfg.gen_block_length
+
+
+def block_view_rows(cfg, cap: int) -> int:
+    """Rows of the dense cache a block step runs on, for sequences of at most
+    ``cap`` tokens: a forward writes two blocks at ``lens`` whatever the slot
+    does, and ``_cache_update`` clamps a write that would pass the end back
+    over committed rows, so the view holds two blocks of spare rows past
+    ``cap``, rounded up so that the decode kernel keeps the key block it has
+    at ``cap`` rows."""
+    unit = math.gcd(cap, 128)
+    return cap + -(-2 * cfg.gen_block_length // unit) * unit
 
 
 def build_block_decode_chunk(module, dequant, slot_select, forwards: int,
@@ -513,7 +563,8 @@ def build_block_decode_chunk(module, dequant, slot_select, forwards: int,
     """The decode chunk of a model that generates by diffusion over blocks:
     exactly ``forwards`` forwards over a slot-batch (:func:`_block_body`),
     every shape static. The pages are gathered into the dense per-slot view
-    once, the forwards run on it (a block's rows are written before they
+    once (:func:`block_view_rows` rows: the spare ones come from the null
+    page), the forwards run on it (a block's rows are written before they
     count: ``lens`` moves only on a commit), and the blocks COMMITTED in the
     chunk, rows ``[lens_in, lens_out)`` of a slot, are copied back into its
     pages at the end, a block at a time: a block never straddles a page (the
@@ -522,11 +573,13 @@ def build_block_decode_chunk(module, dequant, slot_select, forwards: int,
     block in flight stays out of the pages: its rows are rewritten by the
     next forward. Per-slot state between chunks: the block's tokens ``blk``,
     which are still ``masked``, and how many of them the prompt gave
-    (``skip``)."""
+    (``skip``); a block with nothing masked is committed, and the next one
+    opened, by the next chunk's first forward."""
     from ..ops.paged_attention import gather_kv_dense
     cfg = module.config
     B = cfg.gen_block_length
     width = block_chunk_width(cfg, forwards)
+    rows_view = block_view_rows(cfg, kv_cap)
     stats0 = (jnp.zeros((2,), jnp.int32),) if with_stats else ()
 
     def decode_chunk(params, blk, masked, skip, caches, page_table, lens, active,
@@ -536,22 +589,18 @@ def build_block_decode_chunk(module, dequant, slot_select, forwards: int,
         buf = jnp.zeros((S, width), jnp.int32)
         ps = next(c["k"].shape[2] for c in caches if "k" in c)
         lens_in = lens
+        # the null page (0) behind every slot's own: the view's spare rows
+        table = jnp.pad(page_table, ((0, 0), (0, -(-(rows_view - kv_cap) // ps))))
         dense = [dict(zip(("k", "v"),
-                          gather_kv_dense(c["k"], c["v"], page_table, kv_cap)))
+                          gather_kv_dense(c["k"], c["v"], table, rows_view)))
                  if "k" in c else c for c in caches]
-
-        def step_model(ids, dense, lens):
-            return apply_model(module, params, with_stats, ids,
-                               positions=lens[:, None] + jnp.arange(B)[None],
-                               caches=dense, cache_lens=lens, block_step=True)
-
-        body = _block_body(cfg, step_model, slot_select, base_key, seeds, eos_ids,
-                           steps, width)
+        body = _block_body(cfg, _block_step_model(module, params, with_stats),
+                           slot_select, base_key, seeds, eos_ids, steps, width)
         with overlap_scope(overlap):
             out = jax.lax.fori_loop(
                 0, forwards, body,
                 (blk, masked, skip, dense, lens, active, remaining, steps, buf,
-                 jnp.zeros((2,), jnp.int32)) + stats0)
+                 jnp.zeros((3,), jnp.int32)) + stats0)
         blk, masked, skip, dense, lens, active, remaining, steps, buf, counts = out[:10]
         new_caches = []
         for c, dn in zip(caches, dense):
@@ -582,12 +631,13 @@ def build_block_decode_chunk(module, dequant, slot_select, forwards: int,
 
 def build_block_decode_loop(module, dequant, slot_select, gen_cap: int, overlap=None):
     """``InferenceEngine.generate``'s loop for a model that generates by
-    diffusion over blocks: :func:`_block_body` on the contiguous caches in ONE
-    ``lax.while_loop`` until no row is active. ``remaining`` (rows,) are the
-    tokens asked of each row (0: a row that holds nothing); returns ``buf``
-    (rows, gen_cap), a row's tokens its prefix, the rest ``max(eos, 0)``."""
+    diffusion over blocks: :func:`_block_body` on the contiguous caches
+    (:func:`block_view_rows` rows for their cap) in ONE ``lax.while_loop``
+    until no row is active. ``remaining`` (rows,) are the tokens asked of
+    each row (0: a row that holds nothing); returns ``buf`` (rows, gen_cap),
+    a row's tokens its prefix, the rest ``max(eos, 0)``, each row's count of
+    tokens and the forwards the loop ran."""
     cfg = module.config
-    B = cfg.gen_block_length
 
     def decode_loop_inner(params, blk, masked, skip, caches, lens, remaining, eos_ids,
                           seeds, base_key):
@@ -596,16 +646,10 @@ def build_block_decode_loop(module, dequant, slot_select, gen_cap: int, overlap=
         buf = jnp.broadcast_to(jnp.maximum(eos_ids, 0)[:, None],
                                (rows, gen_cap)).astype(jnp.int32)
         zeros = jnp.zeros((rows,), jnp.int32)
-
-        def step_model(ids, caches, lens):
-            return apply_model(module, params, False, ids,
-                               positions=lens[:, None] + jnp.arange(B)[None],
-                               caches=caches, cache_lens=lens, block_step=True)
-
-        body = _block_body(cfg, step_model, slot_select, base_key, seeds, eos_ids,
-                           zeros, gen_cap)
+        body = _block_body(cfg, _block_step_model(module, params, False), slot_select,
+                           base_key, seeds, eos_ids, zeros, gen_cap)
         state = (jnp.int32(0), (blk, masked, skip, caches, lens, remaining > 0,
-                                remaining, zeros, buf, jnp.zeros((2,), jnp.int32)))
+                                remaining, zeros, buf, jnp.zeros((3,), jnp.int32)))
         n, out = jax.lax.while_loop(lambda s: jnp.any(s[1][5]),
                                     lambda s: (s[0] + 1, body(s[0], s[1])), state)
         return out[8], out[7], n

@@ -31,8 +31,8 @@ from ..parallel.mesh import AXIS_DATA, AXIS_TENSOR, MeshSpec, set_global_mesh
 from ..parallel.overlap import resolve_overlap_config, set_overlap_config
 from ..utils.logging import log_dist, logger
 from .config import DeepSpeedInferenceConfig
-from .decode_fns import (build_block_decode_loop, build_decode_loop, build_prefill,
-                         make_select_fn, make_slot_select_fn, open_block)
+from .decode_fns import (block_view_rows, build_block_decode_loop, build_decode_loop,
+                         build_prefill, make_select_fn, make_slot_select_fn, open_block)
 
 
 def spec_fits(mesh_spec, shape, spec) -> bool:
@@ -519,7 +519,9 @@ class InferenceEngine:
         prefill, decode_loop = self._loop_fns(do_sample, temperature, top_k, top_p,
                                               gen_cap)
 
-        caches = init_cache(self.model_config, b, cap, dtype=self.dtype)
+        caches = init_cache(
+            self.model_config, b,
+            block_view_rows(self.model_config, cap) if block else cap, dtype=self.dtype)
         lens0 = jnp.asarray(lens_np)
         rng = jax.random.PRNGKey(seed)
         ids_dev = jnp.asarray(ids)
@@ -589,8 +591,8 @@ class InferenceEngine:
         after the prefill: the prompt's whole blocks are committed, the
         tokens left open the first block, and ONE compiled loop runs the
         forwards the serving chunk runs (``decode_fns._block_body``) until
-        every row has its tokens. The token the prefill selected is no token
-        of this model and is dropped."""
+        every row has its tokens (``self.block_forwards``: how many). The
+        token the prefill selected is no token of this model and is dropped."""
         cfg = self.model_config
         b, B = ids.shape[0], cfg.gen_block_length
         whole = lens_np // B * B
@@ -612,6 +614,7 @@ class InferenceEngine:
             np.full(rows, eos, np.int32), seed + np.arange(rows, dtype=np.int32),
             jax.random.PRNGKey(seed))
         gen = np.asarray(buf)[:b, :max_new_tokens]
+        self.block_forwards = int(n)
         if eos >= 0:     # as the token loop: stop at the longest row's end
             gen = gen[:, :max(1, int(np.asarray(steps)[:b].max()))]
         decode_time = time.perf_counter() - t1

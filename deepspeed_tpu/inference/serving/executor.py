@@ -122,7 +122,8 @@ def _packed_block_chunk(chunk, block: int):
     tokens, a bit a position that is still masked, and how many of the tokens
     the prompt gave; its result has the same three behind ``OUT``'s columns.
     The last row holds ``(expert assignments, experts touched, blocks
-    committed, positions unmasked)``, the first two 0 without expert layers.
+    committed, positions unmasked, commits that opened their next block)``,
+    the first two 0 without expert layers.
     The name in the trace stays ``decode_chunk``."""
     bits = 1 << np.arange(block, dtype=np.int32)
     tail = ctl_head(block)
@@ -142,7 +143,7 @@ def _packed_block_chunk(chunk, block: int):
              blk, jnp.sum(jnp.where(masked, bits[None], 0), axis=1,
                           dtype=jnp.int32)[:, None], skip[:, None]], axis=1)
         moe = stats[0] if stats else jnp.zeros((2,), jnp.int32)
-        last = jnp.pad(jnp.concatenate([moe, counts]), (0, packed.shape[1] - 4))
+        last = jnp.pad(jnp.concatenate([moe, counts]), (0, packed.shape[1] - 5))
         return jnp.concatenate([packed, last[None]], axis=0), caches
 
     return decode_chunk
@@ -180,7 +181,8 @@ class ChunkResult:
     #   chunk, ``(tokens (S, B), still masked (S, B) bool, given by the
     #   prompt (S,))``
     block_counts: Optional[np.ndarray] = None   # and (blocks committed,
-    #   positions unmasked) over the chunk's forwards
+    #   positions unmasked, commits that opened their next block in the same
+    #   forward) over the chunk's forwards
 
 
 @dataclass
@@ -666,7 +668,7 @@ class ChunkedDecodeExecutor:
             tail = state[:, OUT_STEPS + 1:]
             in_flight = (tail[:, :B], (tail[:, B, None] >> np.arange(B)) & 1 != 0,
                          tail[:, B + 1])
-            counts = packed[S, 2:4]
+            counts = packed[S, 2:5]
         return ChunkResult(buf=packed[:S, :K],
                            toks=state[:, OUT_TOK:OUT_TOK + 1],
                            lens=state[:, OUT_LEN],

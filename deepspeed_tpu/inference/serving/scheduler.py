@@ -785,7 +785,8 @@ class ContinuousBatchingScheduler:
             if res.block_counts is not None:
                 span.set(forwards=width, block_length=self.block,
                          blocks_committed=int(res.block_counts[0]),
-                         positions_unmasked=int(res.block_counts[1]))
+                         positions_unmasked=int(res.block_counts[1]),
+                         blocks_merged=int(res.block_counts[2]))
         now = span.t1
         with tracer.span("serving.harvest") as harvest:
             chunk_t0 = now - res.elapsed

@@ -23,8 +23,8 @@ parentheses):
   ``serving/moe_assignments_total``, ``serving/moe_experts_touched_total``
   (expert layers only), ``serving/ssm_state_bytes`` (state-space layers only),
   ``serving/block_forwards_total``, ``serving/blocks_committed_total``,
-  ``serving/positions_unmasked_total`` (a model that generates by diffusion over
-  blocks only) — per
+  ``serving/positions_unmasked_total``, ``serving/blocks_merged_total`` (a model
+  that generates by diffusion over blocks only) — per
   scheduler step: decode steps run against tokens a stream kept, and the
   deliveries a prefill of another request held up (the same counts ride the
   ``serving.decode_chunk`` span);
@@ -110,6 +110,7 @@ class ServingTelemetry:
         self.block_forwards = 0
         self.blocks_committed = 0
         self.positions_unmasked = 0
+        self.blocks_merged = 0
         # prefix-cache counters (only advanced when the cache is enabled)
         self.prefix_enabled = False
         self.prefix_hits = 0
@@ -172,7 +173,9 @@ class ServingTelemetry:
                    ("serving/blocks_committed_total",
                     float(self.blocks_committed), self._tick),
                    ("serving/positions_unmasked_total",
-                    float(self.positions_unmasked), self._tick)]
+                    float(self.positions_unmasked), self._tick),
+                   ("serving/blocks_merged_total",
+                    float(self.blocks_merged), self._tick)]
         if prefix_stats is not None:
             self._prefix_stats = prefix_stats
             # hit_rate here is ADMISSION-level (successful prefills), the same
@@ -220,10 +223,12 @@ class ServingTelemetry:
     def on_blocks(self, forwards: int, counts) -> None:
         """One decode chunk of a model that generates by diffusion over
         blocks: the forwards it ran and ``counts`` = (blocks committed,
-        positions unmasked) over its slots."""
+        positions unmasked, commits that opened their next block in the same
+        forward) over its slots."""
         self.block_forwards += int(forwards)
         self.blocks_committed += int(counts[0])
         self.positions_unmasked += int(counts[1])
+        self.blocks_merged += int(counts[2])
 
     def on_chunk(self, tokens: int, elapsed: float, slot_steps: int = 0,
                  deliveries: int = 0, stalled: int = 0) -> None:

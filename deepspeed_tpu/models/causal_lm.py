@@ -691,9 +691,11 @@ class CausalLMLayer(nn.Module):
 
         A model that generates by diffusion over blocks
         (``cfg.gen_block_length``) has a fifth mode, ``block_step``: ``t`` is
-        the block, its keys and values are written at rows ``[cache_len,
-        cache_len + t)`` of the dense cache and every query of the block sees
-        rows ``[0, cache_len + t)``: the committed blocks and its own. Its
+        one block or several in a row, their keys and values are written at
+        rows ``[cache_len, cache_len + t)`` of the dense cache (which must
+        hold them: ``_cache_update`` clamps) and every query of the ``j``-th
+        block sees rows ``[0, cache_len + (j + 1) * block)``: the committed
+        blocks, the blocks before its own and its own. Its
         whole-sequence modes take the block-causal mask, or ``attn_mask`` (t,
         t) bool where the caller lays several copies of a sequence side by
         side (``InferenceEngine.forward``)."""
@@ -720,7 +722,7 @@ class CausalLMLayer(nn.Module):
             k_cache = _cache_update(cache["k"], k.transpose(0, 2, 1, 3), cache_len)
             v_cache = _cache_update(cache["v"], v.transpose(0, 2, 1, 3), cache_len)
             new_kv = {"k": k_cache, "v": v_cache}
-            o = _block_decode(q, k_cache, v_cache, cache_len + t)
+            o = _block_decode(q, k_cache, v_cache, cache_len, cfg.gen_block_length)
         elif cache is not None and t == 1 and page_table is not None:
             # paged decode: append at the page-mapped row, attend by page index
             from ..ops.paged_attention import (gather_kv_dense,
@@ -796,8 +798,9 @@ class MixerLayer(CausalLMLayer):
     "ssm"}``), "*" this module's attention (state ``{"k", "v"}``, every cache
     mode of :class:`CausalLMLayer`), "E" a latent mixture of experts (no
     state; its two counts are sown into the ``stats`` collection).
-    ``seq_lens`` (b,) are the real lengths of right-padded rows in a prefill:
-    a recurrence must not run over the padding that a causal mask forgives."""
+    ``seq_lens`` (b,) are the real lengths of right-padded rows in a prefill
+    or a block step: a recurrence must not run over the padding that a causal
+    mask forgives, and an expert layer routes it nowhere."""
     kind: str = "*"
 
     @nn.compact
@@ -829,7 +832,7 @@ class MixerLayer(CausalLMLayer):
                     h, cache=cache, seq_lens=seq_lens)
         else:
             valid = None
-            if seq_lens is not None and x.shape[1] > 1 and not block_step:
+            if seq_lens is not None and x.shape[1] > 1:
                 valid = jnp.arange(x.shape[1])[None, :] < seq_lens[:, None]
             if cfg.moe_kind == "gated":
                 from ..moe.gated_moe import GatedMoE
@@ -945,17 +948,21 @@ def _prefix_attention_xla(q, k_cache, v_cache, offset, slopes=None):
     return o.reshape(b, t, h, d).astype(q.dtype)
 
 
-def _block_decode(q, k_cache, v_cache, lens):
-    """Attention of a whole block of queries against the cache: ``q`` (b, t, h,
-    d), every query of a sequence sees the same rows ``[0, lens)`` (the
-    committed blocks and its own), so the ``t`` queries of a key head's ``g``
-    query heads are ``t x g`` rows of ONE group in ``decode_attention``'s
-    ``(b, h_kv, g, d)`` operand: the decode kernel, with no mask of its own."""
+def _block_decode(q, k_cache, v_cache, lens, block: int):
+    """Attention of whole blocks of queries against the cache: ``q`` (b, t, h,
+    d), ``t`` a multiple of ``block``; every query of a sequence's ``j``-th
+    block sees the same rows ``[0, lens + (j + 1) * block)`` (the committed
+    blocks, the blocks before its own and its own), so the ``t`` queries of a
+    key head's ``g`` query heads are ``t x g`` rows of ONE group in
+    ``decode_attention``'s ``(b, h_kv, g, d)`` operand, a block's rows in a
+    row: the decode kernel, with no mask of its own, given a length a block
+    (one block: the one-length kernel)."""
     b, t, h, d = q.shape
     hk = k_cache.shape[1]
     g = h // hk
     rows = q.reshape(b, t, hk, g, d).transpose(0, 2, 1, 3, 4).reshape(b, hk * t * g, d)
-    o = _sharded_decode(rows, k_cache, v_cache, lens)
+    ends = lens[:, None] + block * jnp.arange(1, t // block + 1)[None]   # (b, blocks)
+    o = _sharded_decode(rows, k_cache, v_cache, ends)
     return o.reshape(b, hk, t, g, d).transpose(0, 2, 1, 3, 4).reshape(b, t, h, d)
 
 
@@ -1042,11 +1049,13 @@ class CausalLM(nn.Module):
                  logits_positions=None, prefix_fill=False, page_table=None,
                  kv_cap=None, seq_lens=None, block_step=False, attn_mask=None):
         """``seq_lens`` (b,): the real lengths of right-padded rows of a
-        prefill, for layers whose state a padded token would advance.
+        prefill, for layers whose state a padded token would advance, and of
+        a block step, whose padding the expert layers leave out.
 
         ``block_step`` (static; a model with ``gen_block_length``): the ``t``
-        inputs are one block a sequence, run against the dense caches at
-        offset ``cache_lens`` (``CausalLMLayer._attention``). ``attn_mask``
+        inputs are one block a sequence or several in a row, run against the
+        dense caches at offset ``cache_lens`` (``CausalLMLayer._attention``),
+        the first ``seq_lens`` of them real. ``attn_mask``
         (t, t) bool: the whole mask of a forward without caches.
 
         ``logits_positions`` (b,) or (b, n): compute the LM head ONLY at these sequence

@@ -86,12 +86,17 @@ TAGS: Dict[str, Tuple[str, str]] = {
     # ------------------------- generation by diffusion over blocks (PR 31)
     "serving/block_forwards_total": (COUNTER, "forwards run by decode chunks "
                                               "of a model that generates by "
-                                              "blocks (denoise and commit)"),
+                                              "blocks (each denoises or "
+                                              "commits a slot's block)"),
     "serving/blocks_committed_total": (COUNTER, "blocks whose keys and "
                                                 "values were committed to a "
                                                 "slot's cache"),
     "serving/positions_unmasked_total": (COUNTER, "block positions unmasked "
                                                   "by denoise forwards"),
+    "serving/blocks_merged_total": (COUNTER, "commits that opened the slot's "
+                                             "next block in the same forward "
+                                             "(PR 32): of blocks_committed, "
+                                             "all but a request's last"),
     # ------------------------------------------------------------------ router
     "router/queue_depth": (GAUGE, "router admission queue depth per tick"),
     "router/retried_total": (COUNTER, "checkpointless retries (re-enqueues)"),
@@ -253,7 +258,7 @@ SPANS: Dict[str, Tuple[str, str, Tuple[str, ...], str]] = {
                               "stalled_deliveries", "moe_assignments",
                               "moe_experts_touched", "forwards",
                               "blocks_committed", "positions_unmasked",
-                              "block_length"),
+                              "block_length", "blocks_merged"),
                              "decode_wasted_step_pct, delivery_stalled_pct, "
                              "sched_fetch_idle_ms_per_step, "
                              "moe_experts_touched_per_step, "

@@ -25,14 +25,22 @@ from ...utils.device import pallas_interpret as _interpret
 NEG_INF = -1e30
 
 
-def _decode_kernel(len_ref, q_ref, k_hbm, v_hbm, o_ref, *, block_k, scale):
+def _decode_kernel(len_ref, q_ref, k_hbm, v_hbm, o_ref, *, block_k, scale, n_lens=1):
     """q_ref: (1, hk, g, d) VMEM; k/v_hbm: (b, hk, T, d) in HBM (DMA'd blockwise);
-    len_ref: scalar-prefetch (b,). Double-buffered DMA overlaps cache reads with compute —
-    the cache never fits VMEM (the reason the reference streams its KV cache too)."""
+    len_ref: scalar-prefetch (b,), or (b, n_lens) where a group's ``g`` rows are
+    ``n_lens`` equal runs, each with a length of its own. Double-buffered DMA
+    overlaps cache reads with compute — the cache never fits VMEM (the reason the
+    reference streams its KV cache too)."""
     i = pl.program_id(0)
-    L = len_ref[i]
+    L = row_len = len_ref[i] if n_lens == 1 else len_ref[i, 0]
     q = q_ref[0].astype(jnp.float32)            # (hk, g, d)
     hk, g, d = q.shape
+    if n_lens > 1:
+        # the blocks are read up to the longest run's length, once for all runs
+        run = jax.lax.broadcasted_iota(jnp.int32, (hk, g, block_k), 1) // (g // n_lens)
+        for j in range(1, n_lens):
+            L = jnp.maximum(L, len_ref[i, j])
+            row_len = jnp.where(run >= j, len_ref[i, j], row_len)
     nk = pl.cdiv(L, block_k)                    # dynamic: only touch valid cache blocks
 
     def scoped(k_buf, v_buf, ksem, vsem):
@@ -70,7 +78,7 @@ def _decode_kernel(len_ref, q_ref, k_hbm, v_hbm, o_ref, *, block_k, scale):
                 preferred_element_type=jnp.float32) * scale
             cols = kb * block_k + jax.lax.broadcasted_iota(
                 jnp.int32, (hk, g, block_k), 2)
-            s = jnp.where(cols < L, s, NEG_INF)
+            s = jnp.where(cols < row_len, s, NEG_INF)
             m_new = jnp.maximum(m, jnp.max(s, axis=-1))
             alpha = jnp.exp(m - m_new)
             p = jnp.exp(s - m_new[..., None])
@@ -106,6 +114,12 @@ def decode_attention(q: jnp.ndarray, k_cache: jnp.ndarray, v_cache: jnp.ndarray,
     q: ``(b, h, d)`` (current position); k_cache/v_cache: ``(b, h_kv, T, d)`` head-major
     fixed-capacity; cache_len: ``(b,)`` valid lengths (the current position is already
     written to the cache). Returns ``(b, h, d)``.
+
+    ``cache_len`` ``(b, n)``: ``n`` lengths a sequence. The ``h / h_kv`` rows of
+    every key head are then ``n`` equal runs in a row, run ``j`` seeing rows ``[0,
+    cache_len[:, j])`` (a block step of ``n`` blocks a sequence lays its queries
+    out so): one call reads the cache once for all runs. With ``(b,)`` the
+    kernel is the one-length kernel it was.
     """
     b, h, d = q.shape
     hk, T = k_cache.shape[1], k_cache.shape[2]
@@ -122,6 +136,11 @@ def decode_attention(q: jnp.ndarray, k_cache: jnp.ndarray, v_cache: jnp.ndarray,
         bk //= 2
     q4 = q.reshape(b, hk, g, d)
     lens = cache_len.astype(jnp.int32)
+    n_lens = 1 if lens.ndim == 1 else lens.shape[1]
+    if g % n_lens:
+        raise AssertionError(f"{g} rows a key head are not {n_lens} equal runs")
+    if n_lens == 1:
+        lens = lens.reshape(b)
 
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=1,
@@ -134,7 +153,7 @@ def decode_attention(q: jnp.ndarray, k_cache: jnp.ndarray, v_cache: jnp.ndarray,
         out_specs=pl.BlockSpec((1, hk, g, d), lambda i, lens_ref: (i, 0, 0, 0)),
     )
     out = pl.pallas_call(
-        functools.partial(_decode_kernel, block_k=bk, scale=scale),
+        functools.partial(_decode_kernel, block_k=bk, scale=scale, n_lens=n_lens),
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((b, hk, g, d), q.dtype),
         name="decode_attention",
@@ -146,7 +165,8 @@ def decode_attention(q: jnp.ndarray, k_cache: jnp.ndarray, v_cache: jnp.ndarray,
 def decode_attention_xla(q, k_cache, v_cache, cache_len, softmax_scale=None):
     """jnp reference implementation (ground truth for kernel tests; fallback path).
 
-    Same head-major cache layout ``(b, h_kv, T, d)`` as the kernel."""
+    Same head-major cache layout ``(b, h_kv, T, d)`` as the kernel, and the same
+    two forms of ``cache_len``."""
     b, h, d = q.shape
     hk, T = k_cache.shape[1], k_cache.shape[2]
     g = h // hk
@@ -155,7 +175,10 @@ def decode_attention_xla(q, k_cache, v_cache, cache_len, softmax_scale=None):
     k = k_cache.astype(jnp.float32)
     v = v_cache.astype(jnp.float32)
     s = jnp.einsum("bkgd,bktd->bkgt", q4, k) * scale
-    mask = jnp.arange(T)[None, None, None, :] < cache_len[:, None, None, None]
+    if cache_len.ndim == 1:
+        cache_len = cache_len[:, None]
+    row_len = jnp.repeat(cache_len, g // cache_len.shape[1], axis=1)   # (b, g)
+    mask = jnp.arange(T)[None, None, None, :] < row_len[:, None, :, None]
     s = jnp.where(mask, s, NEG_INF)
     p = jax.nn.softmax(s, axis=-1)
     o = jnp.einsum("bkgt,bktd->bkgd", p, v)

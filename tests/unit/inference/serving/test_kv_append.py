@@ -77,3 +77,90 @@ def test_the_append_on_a_loop_carry_matches_step_by_step():
     got, want = run(_cache_update), run(_vmapped_update)
     np.testing.assert_array_equal(np.asarray(got[0]), np.asarray(want[0]))
     np.testing.assert_array_equal(np.asarray(got[1]), np.asarray(want[1]))
+
+
+# ------------------------------------------- a block step of two blocks a sequence (PR 32)
+def _kernel_calls(fn, *args) -> int:
+    return str(jax.make_jaxpr(fn)(*args)).count("name=decode_attention")
+
+
+def test_two_lengths_a_sequence_give_what_two_calls_of_one_length_give():
+    """A block step that carries two blocks a sequence
+    (``models/causal_lm.py: _block_decode``) lets the first block's queries
+    see ``[0, lens + B)`` and the second's ``[0, lens + 2B)`` in ONE call of
+    ``decode_attention``, given two lengths a sequence: what two calls with
+    one length a sequence give, and what the masked ``jax.numpy`` form gives.
+    One block stays the one-length call it was, as a decode step of one token
+    a slot (the hybrid's route) does: there the kernel is traced to the
+    program it was, with no run of rows in it."""
+    from deepspeed_tpu.models.causal_lm import _block_decode
+    from deepspeed_tpu.ops.attention.decode import (decode_attention,
+                                                    decode_attention_xla)
+    b, B, h, hk, d, T = 3, 4, 4, 2, 128, 256
+    g = h // hk
+    rng = np.random.default_rng(5)
+    q = jnp.asarray(rng.standard_normal((b, 2 * B, h, d)), jnp.float32)
+    k = jnp.asarray(rng.standard_normal((b, hk, T, d)), jnp.float32)
+    v = jnp.asarray(rng.standard_normal((b, hk, T, d)), jnp.float32)
+    # the second block ends in another key block than the first, in the same
+    # one, and at the end of the cache
+    lens = jnp.asarray([124, 40, T - 2 * B], jnp.int32)
+
+    def rows(x):                # (b, t, h, d) -> the kernel's (b, hk * t * g, d)
+        return x.reshape(b, -1, hk, g, d).transpose(0, 2, 1, 3, 4).reshape(b, -1, d)
+
+    def back(o, t=B):
+        return o.reshape(b, hk, t, g, d).transpose(0, 2, 1, 3, 4).reshape(b, t, h, d)
+
+    got = _block_decode(q, k, v, lens, B)
+    for j in range(2):
+        half = q[:, j * B:(j + 1) * B]
+        one = back(decode_attention(rows(half), k, v, lens + (j + 1) * B))
+        np.testing.assert_allclose(np.asarray(got[:, j * B:(j + 1) * B]),
+                                   np.asarray(one), atol=2e-6)
+        plain = back(decode_attention_xla(rows(half), k, v, lens + (j + 1) * B))
+        np.testing.assert_allclose(np.asarray(one), np.asarray(plain), atol=2e-5)
+        # the one-block step: the same call, alone
+        np.testing.assert_array_equal(
+            np.asarray(_block_decode(half, k, v, lens + j * B, B)), np.asarray(one))
+    two = jnp.stack([lens + B, lens + 2 * B], axis=1)
+    np.testing.assert_allclose(
+        np.asarray(back(decode_attention_xla(rows(q), k, v, two), 2 * B)),
+        np.asarray(got), atol=2e-5)
+    assert _kernel_calls(lambda q: _block_decode(q, k, v, lens, B), q) == 1
+    assert _kernel_calls(lambda q: _block_decode(q, k, v, lens, B), q[:, :B]) == 1
+    # the one-length kernel builds no index of runs: its one iota is the
+    # key columns' (and its whole trace is the parent's, CHANGES.md, PR 32)
+    token = str(jax.make_jaxpr(lambda q: decode_attention(q, k, v, lens + 1))(q[:, 0]))
+    runs = str(jax.make_jaxpr(lambda q: decode_attention(q, k, v, two))(rows(q)))
+    assert (token.count("iota"), runs.count("iota")) == (1, 2)
+
+
+@pytest.mark.parametrize("cap,block,rows", [(2048, 4, 2176), (576, 4, 640),
+                                            (96, 4, 128), (40, 4, 48), (64, 16, 128)])
+def test_the_view_of_a_block_model_holds_two_blocks_past_the_cap(cap, block, rows):
+    """``decode_fns.block_view_rows``: a forward appends two blocks at a
+    slot's length whatever the slot does, and the append clamps: with the
+    spare rows a slot at the cap (finished, idling through a chunk) leaves
+    every row below the cap alone, and the decode kernel keeps the key block
+    it had at ``cap`` rows."""
+    from types import SimpleNamespace
+    from deepspeed_tpu.inference.decode_fns import block_view_rows
+    T = block_view_rows(SimpleNamespace(gen_block_length=block), cap)
+    assert T == rows and T >= cap + 2 * block
+
+    def key_block(T, bk=128):
+        bk = min(bk, T)
+        while T % bk:
+            bk //= 2
+        return bk
+    assert key_block(T) >= key_block(cap)
+    rng = np.random.default_rng(cap)
+    cache = jnp.asarray(rng.standard_normal((2, HK, T, D)), jnp.float32)
+    new = jnp.asarray(rng.standard_normal((2, HK, 2 * block, D)), jnp.float32)
+    lens = jnp.asarray([cap, cap - block], jnp.int32)
+    got = np.asarray(_cache_update(cache, new, lens))
+    np.testing.assert_array_equal(got[0, :, :cap], np.asarray(cache)[0, :, :cap])
+    np.testing.assert_array_equal(got[1, :, :cap - block],
+                                  np.asarray(cache)[1, :, :cap - block])
+    np.testing.assert_array_equal(got[1, :, cap - block:cap + block], np.asarray(new)[1])

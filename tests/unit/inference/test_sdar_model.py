@@ -294,8 +294,10 @@ def test_the_configuration_refuses_a_generation_it_cannot_run(bad):
 
 def test_the_chunk_span_and_the_counters_say_what_the_blocks_did(engine):
     """A chunk's span carries ``forwards``, ``block_length``,
-    ``blocks_committed`` and ``positions_unmasked`` beside the expert counts,
-    a prefill's ``blocks_committed``; the registry the three totals."""
+    ``blocks_committed``, ``positions_unmasked`` and ``blocks_merged`` (the
+    commits that opened their next block in the same forward) beside the
+    expert counts, a prefill's ``blocks_committed``; the registry the four
+    totals."""
     from deepspeed_tpu.inference.serving.scheduler import (
         ContinuousBatchingScheduler, ServingConfig)
     from deepspeed_tpu.observability.metrics import get_registry
@@ -321,12 +323,145 @@ def test_the_chunk_span_and_the_counters_say_what_the_blocks_did(engine):
     # blocks [8, 12): 2 open + 2 denoised; [12, 16) and [16, 20): 4 each, cut to 8 tokens
     assert sum(c["blocks_committed"] for c in chunks) == 3
     assert sum(c["positions_unmasked"] for c in chunks) == 10
+    # the first two commits open the next block; the third ends the request
+    # and the block behind it is thrown away, uncounted
+    assert sum(c["blocks_merged"] for c in chunks) == 2
     assert sum(c["tokens_kept"] for c in chunks) == 8
     assert all("moe_experts_touched" in c for c in chunks)
     t = sched.telemetry
-    assert (t.block_forwards, t.blocks_committed, t.positions_unmasked) == \
-        (10 * len(chunks), 3, 10)
+    assert (t.block_forwards, t.blocks_committed, t.positions_unmasked,
+            t.blocks_merged) == (10 * len(chunks), 3, 10, 2)
     snap = get_registry().snapshot()
     for name in ("serving/block_forwards_total", "serving/blocks_committed_total",
-                 "serving/positions_unmasked_total"):
+                 "serving/positions_unmasked_total", "serving/blocks_merged_total"):
         assert name in snap
+
+
+# ------------------------------------- a commit in the forward that opens the next block
+def _serve(engine, requests, **serving):
+    """``requests``: ``(prompt, n, eos or None)``. Returns the scheduler, the
+    handles and, by request id, the rows its pages held when it finished (a
+    layer that keeps keys and values: ``(k, v)``, each ``(kv heads, rows,
+    d)``), read before another request can be given the pages (a request that
+    ran for more than one step)."""
+    from deepspeed_tpu.inference.serving.scheduler import (
+        ContinuousBatchingScheduler, ServingConfig)
+    from deepspeed_tpu.ops.paged_attention import pages_to_dense
+    sched = ContinuousBatchingScheduler(engine, ServingConfig(
+        **{**dict(slots=2, chunk_size=10, max_seq_len=96, kv_page_size=16), **serving}))
+    handles = [sched.submit(p, max_new_tokens=n, eos_token_id=eos)
+               for p, n, eos in requests]
+    tables, rows = {}, {}
+    for _ in range(200):
+        for slot, h in enumerate(sched._slot_req):
+            if h is not None:
+                tables[h.id] = jnp.asarray(sched.executor.pool.page_table[slot].copy())
+        more = sched.step()
+        for h in handles:
+            if h.state.name == "FINISHED" and h.id in tables and h.id not in rows:
+                rows[h.id] = [tuple(np.asarray(pages_to_dense(c[key], tables[h.id]))
+                                    for key in ("k", "v"))
+                              for c in sched.executor.pool.caches if "k" in c]
+        if not more:
+            break
+    assert all(h.state.name == "FINISHED" for h in handles)
+    return sched, handles, rows
+
+
+def test_a_block_of_four_costs_four_forwards_in_steady_state(engine):
+    """The commit of a block rides the forward that opens the next one: 40
+    tokens are 10 blocks of 4 denoising forwards and one last commit, where a
+    forward of its own a commit made them 50; a serving chunk always runs
+    its 10 forwards, so there the count is tokens kept a forward a slot."""
+    _use(engine, "sequential")
+    prompt = _ids(8, seed=60)
+    out = engine.generate(prompt[None], max_new_tokens=40)
+    assert out.shape == (1, 48)
+    assert 40 <= engine.block_forwards <= 42
+    from deepspeed_tpu.observability.trace import get_tracer
+    tracer = get_tracer()
+    tracer.reset()
+    tracer.enable()
+    try:
+        _, (h,), _ = _serve(engine, [(prompt, 80, None)], slots=1)
+        spans = list(tracer.spans)
+    finally:
+        tracer.disable()
+        tracer.reset()
+    assert list(h.tokens[:40]) == list(out[0, 8:])
+    chunks = [s["attrs"] for s in spans if s["name"] == "serving.decode_chunk"]
+    steady = chunks[:-1]                 # the last chunk holds the last commit alone
+    kept = sum(c["tokens_kept"] for c in steady)
+    run = sum(c["forwards"] * c["active_slots"] for c in steady)
+    assert len(steady) == 8 and kept / run >= 0.95, (kept, run)
+    assert sum(c["blocks_merged"] for c in chunks) == \
+        sum(c["blocks_committed"] for c in chunks) - 1 == 19
+
+
+def _reference_kv(params, model, ids):
+    """The keys and values the reference's layers form over ``ids``, its own
+    parts in its own order: a layer ``(k, v)``, each ``(t, kv heads, d)``."""
+    m = ref._Frozen(model)
+    nk, hd = int(m["num_key_value_heads"]), int(m["head_dim"])
+    eps, base = ref._eps(m), float(m["rope_theta"])
+    mask = jnp.asarray(ref.block_mask(len(ids), int(m["gen_block_length"])))
+    x = jnp.asarray(params["wte"][jnp.asarray(ids)], jnp.float32)
+    out = []
+    for i in range(int(m["num_hidden_layers"])):
+        lp = ref._f32(params[f"layers_{2 * i}"])
+        with jax.default_matmul_precision(ref.HI):
+            hn = ref.rmsnorm(x, lp["norm"]["scale"], eps)
+            k = (hn @ lp["k_proj"]["kernel"]).reshape(len(ids), nk, hd)
+            v = (hn @ lp["v_proj"]["kernel"]).reshape(len(ids), nk, hd)
+            out.append((ref.rotate(ref.rmsnorm(k, lp["k_norm"]["scale"], eps), base), v))
+        x = ref.attention_layer(x, lp, mask, m)
+        x = ref.moe_layer(x, params[f"layers_{2 * i + 1}"], m)
+    return out
+
+
+def test_a_last_block_that_ends_at_the_cap_leaves_the_references_rows_in_the_pages(
+        engine):
+    """A forward writes two blocks at a slot's length and the append clamps a
+    write that would pass the end of the view: the request whose last block
+    ends exactly at ``max_seq_len`` (its last commit writes past the cap, and
+    it idles at the cap while its neighbour runs on) must leave every row of
+    its pages as the keys and values of its tokens."""
+    model = _use(engine, "sequential")
+    cap = 32
+    requests = [(_ids(9, seed=70), cap - 9, None), (_ids(6, seed=71), 22, None),
+                (_ids(12, seed=72), cap - 12, None)]
+    _, handles, rows = _serve(engine, requests, max_seq_len=cap)
+    for (prompt, n, _), h in zip(requests, handles):
+        want, records = ref.generate(engine.params, model, prompt, n)
+        _agree(h.tokens, want, records, len(prompt), ("at the cap", len(prompt), n))
+        seq = np.concatenate([prompt, np.asarray(h.tokens, np.int32)])
+        whole = len(seq) // 4 * 4         # the committed blocks
+        for got, want_kv in zip(rows[h.id], _reference_kv(engine.params, model, seq)):
+            for g, w in zip(got, want_kv):
+                np.testing.assert_allclose(
+                    g[:, :whole], np.asarray(w).transpose(1, 0, 2)[:, :whole],
+                    atol=2e-4, err_msg=f"prompt {len(prompt)}")
+
+
+@pytest.mark.parametrize("tail", [1, 2, 3])
+def test_an_eos_inside_a_merged_commit_discards_the_opened_block(engine, tail):
+    """A prompt's tail of 1, 2, 3 tokens opens the first block (``skip``); a
+    request that meets its EOS in a block ends at that block's commit, and the
+    block the same forward opened behind it is thrown away: the reference's
+    tokens up to the EOS, and no position of the opened block counted."""
+    model = _use(engine, "sequential")
+    prompt = _ids(8 + tail, seed=80 + tail)
+    want, records = ref.generate(engine.params, model, prompt, 12)
+    want = list(map(int, want))
+    at = next(i for i in range(4 - tail + 1, 12) if want[i] not in want[:i])
+    eos = want[at]                       # inside the second or a later block
+    sched, (h,), _ = _serve(engine, [(prompt, 12, eos)])
+    _agree(h.tokens, want[:at + 1], records, len(prompt), ("eos", tail))
+    assert len(h.tokens) == at + 1 and h.finish_reason == "eos"
+    blocks = -(-(tail + at + 1) // 4)    # committed by the decode, the EOS's included
+    t = sched.telemetry
+    assert (t.blocks_committed, t.blocks_merged) == (blocks, blocks - 1)
+    assert t.positions_unmasked == 4 * blocks - tail
+    out = engine.generate(prompt[None], max_new_tokens=12, eos_token_id=eos)[0]
+    assert list(out[len(prompt):len(prompt) + at + 1]) == list(h.tokens)
+    assert all(int(x) == eos for x in out[len(prompt) + at:])

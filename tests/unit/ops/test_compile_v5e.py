@@ -134,10 +134,11 @@ def test_sdars_block_chunk_holds_its_two_kernels_and_no_loop_but_its_own(
         one_chip, monkeypatch):
     """The decode chunk of generation by diffusion over blocks at the cell's
     shapes (32 slots x cap 2048, 10 forwards; 2 of the 8 layers): every
-    forward's block of 4 rows a slot goes through ``decode_attention`` (the 4
-    queries of a key head's 8 query heads as 32 rows of one group) and the
-    gated ``moe_grouped_ffn``; the block's rows are appended and the committed
-    blocks copied back to their pages without a loop over the slots."""
+    forward's two blocks of 4 rows a slot go through ONE ``decode_attention``
+    a layer (the 8 queries of a key head's 8 query heads as two runs of 32
+    rows of one group, a length a run) and the gated ``moe_grouped_ffn``; the
+    blocks' rows are appended and the committed blocks copied back to their
+    pages without a loop over the slots."""
     from deepspeed_tpu.inference.decode_fns import (build_block_decode_chunk,
                                                     make_slot_select_fn)
     from deepspeed_tpu.models.causal_lm import CausalLM, init_cache, sdar_moe_cfg
