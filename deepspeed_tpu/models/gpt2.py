@@ -22,6 +22,7 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
 
+from ..ops.attention.flash import FLASH_LSE_NAME, FLASH_OUT_NAME
 from ..ops.transformer.attention import get_attention_impl
 from .base import Model
 
@@ -36,7 +37,10 @@ class GPT2Config:
     dropout: float = 0.0
     dtype: Any = jnp.bfloat16          # compute dtype
     remat: bool = False
-    remat_policy: str = "full"         # full | dots (save matmul outputs, recompute the rest)
+    # full: keep a layer's input, recompute the rest. dots: keep the matmul outputs too,
+    # and the flash kernel's output and log-sum-exp, which no matmul gives back, so no
+    # kernel runs twice; the two cost 2*b*t*n_embd + 4*b*n_head*t bytes a layer
+    remat_policy: str = "full"
     scan_layers: bool = True
     attention_impl: str = "auto"       # flash kernel on TPU, xla attention elsewhere
     init_std: float = 0.02
@@ -353,8 +357,12 @@ class GPT2(nn.Module):
 
         block = Block
         if cfg.remat:
-            policy = (jax.checkpoint_policies.dots_with_no_batch_dims_saveable
-                      if cfg.remat_policy == "dots" else None)
+            policy = None
+            if cfg.remat_policy == "dots":
+                policies = jax.checkpoint_policies
+                policy = policies.save_from_both_policies(
+                    policies.dots_with_no_batch_dims_saveable,
+                    policies.save_only_these_names(FLASH_OUT_NAME, FLASH_LSE_NAME))
             block = nn.remat(Block, prevent_cse=False, static_argnums=(2,), policy=policy)
         if cfg.scan_layers:
             x, _ = nn.scan(

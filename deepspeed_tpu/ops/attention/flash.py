@@ -37,6 +37,7 @@ from typing import Optional
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax.ad_checkpoint import checkpoint_name
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 from jax.sharding import PartitionSpec as P
@@ -44,6 +45,10 @@ from ...utils.device import pallas_interpret as _interpret
 from ...utils.jax_compat import shard_map
 
 NEG_INF = -1e30
+# ``checkpoint_name`` tags of the forward rule's two residuals that come out of no
+# matmul: the kernel's output (b*h, t, d) and its log-sum-exp (b*h, t), float32
+FLASH_OUT_NAME = "flash_out"
+FLASH_LSE_NAME = "flash_lse"
 
 
 def _block_sizes(t: int, block_q: int, block_k: int):
@@ -528,6 +533,10 @@ def _flash_core_fwd(q3, k3, v3, slopes3, scale, causal, use_alibi, block_q, bloc
                     mask_block=1):
     o3, lse = _flash_fwd(q3, k3, v3, slopes3 if use_alibi else None, scale, causal,
                          block_q, block_k, mask_block)
+    # the two residuals no matmul gives back: a remat policy that names them
+    # (models/gpt2.py, "dots") keeps them and the backward runs no second forward
+    o3 = checkpoint_name(o3, FLASH_OUT_NAME)
+    lse = checkpoint_name(lse, FLASH_LSE_NAME)
     return o3, (q3, k3, v3, o3, lse, slopes3)
 
 
