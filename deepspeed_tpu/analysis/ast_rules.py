@@ -143,6 +143,23 @@ def iter_span_names_from_tree(tree: ast.Module) -> Iterator[Tuple[str, int]]:
             yield node.args[0].value, node.lineno
 
 
+def iter_scope_names_from_tree(tree: ast.Module) -> Iterator[Tuple[str, int]]:
+    """Yield ``(name, lineno)`` for the literal first argument of every
+    ``scope(...)`` call (``observability.scope``, as a ``with`` or as a
+    decorator); a first argument that is no literal yields ``None`` for the
+    name: the lint cannot hold it to the declared list."""
+    for node in ast.walk(tree):
+        if not isinstance(node, ast.Call) or not node.args:
+            continue
+        fn = node.func
+        if (fn.id if isinstance(fn, ast.Name) else
+                fn.attr if isinstance(fn, ast.Attribute) else None) != "scope":
+            continue
+        arg = node.args[0]
+        literal = isinstance(arg, ast.Constant) and isinstance(arg.value, str)
+        yield (arg.value if literal else None), node.lineno
+
+
 def iter_emission_tags(path: str) -> Iterator[Tuple[str, int]]:
     """File-path face of :func:`iter_emission_tags_from_tree` (the API
     ``observability.schema`` re-exports)."""
@@ -159,18 +176,24 @@ class EmissionTagRule(AstRule):
     emitter files (tag-shaped strings elsewhere — docs, tests — are not
     emission sites). With ``resolve_span`` the same rule holds every span
     name at a tracer call site in ``span_modules`` to the declared span
-    table (``observability.schema.SPANS``)."""
+    table (``observability.schema.SPANS``), and with ``resolve_scope`` every
+    ``scope(...)`` call in ``scope_modules`` to the declared device scopes
+    (``observability.schema.SCOPES``)."""
 
     name = "emission_tags"
 
     def __init__(self, resolve: Callable[[str], Optional[str]],
                  modules: Sequence[str],
                  resolve_span: Optional[Callable[[str], Optional[str]]] = None,
-                 span_modules: Sequence[str] = ()):
+                 span_modules: Sequence[str] = (),
+                 resolve_scope: Optional[Callable[[str], Optional[str]]] = None,
+                 scope_modules: Sequence[str] = ()):
         self.resolve = resolve
         self.modules = tuple(modules)
         self.resolve_span = resolve_span
         self.span_modules = tuple(span_modules)
+        self.resolve_scope = resolve_scope
+        self.scope_modules = tuple(scope_modules)
 
     def check(self, tree, source_lines, relpath):
         findings = []
@@ -191,6 +214,15 @@ class EmissionTagRule(AstRule):
                         "observability.schema.SPANS — declare it (layer, "
                         "attributes, what reads it) before opening it",
                         {"tag": name}))
+        if self.resolve_scope is not None and relpath in self.scope_modules:
+            for name, lineno in iter_scope_names_from_tree(tree):
+                if name is None or self.resolve_scope(name) is None:
+                    findings.append(Finding(
+                        self.name, SEVERITY_ERROR, f"{relpath}:{lineno}",
+                        f"device scope {name!r} is not a literal declared in "
+                        "observability.schema.SCOPES — declare it (layer, "
+                        "what it holds, what reads it) before opening it",
+                        {"tag": str(name)}))
         return findings
 
 

@@ -17,7 +17,7 @@ from typing import Tuple
 import jax
 import jax.numpy as jnp
 
-from ..utils.nvtx import named_scope
+from ..observability import scope
 
 
 def _pack_bits(bits: jnp.ndarray) -> jnp.ndarray:
@@ -139,7 +139,7 @@ def int8_blockwise_decompress(q: jnp.ndarray, scales: jnp.ndarray, n: int,
 def quantized_allreduce(x: jnp.ndarray, error: jnp.ndarray, axis_name: str,
                         block: int = 256, bits: int = 8
                         ) -> Tuple[jnp.ndarray, jnp.ndarray]:
-    with named_scope("comm.quantized_allreduce"):
+    with scope("comm.quantized_allreduce"):
         return _quantized_allreduce(x, error, axis_name, block, bits)
 
 

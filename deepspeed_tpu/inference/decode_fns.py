@@ -24,6 +24,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from ..observability import scope
 from ..parallel.overlap import overlap_scope
 
 
@@ -36,7 +37,8 @@ def apply_model(module, params, with_stats: bool, *args, **kw):
         return module.apply({"params": params}, *args, **kw), None
     out, sown = module.apply({"params": params}, *args, mutable=["stats"], **kw)
     leaves = jax.tree_util.tree_leaves(sown)
-    return out, (sum(leaves) if leaves else jnp.zeros((2,), jnp.int32))
+    with scope("moe.plan"):
+        return out, (sum(leaves) if leaves else jnp.zeros((2,), jnp.int32))
 
 
 def _chunk_body(step_model, slot_select, base_key, seeds, eos_ids):
@@ -50,16 +52,20 @@ def _chunk_body(step_model, slot_select, base_key, seeds, eos_ids):
         toks, caches, lens, active, remaining, steps, buf = s[:7]
         (logits, caches), stats = step_model(toks, caches, lens)
         nxt = slot_select(logits[:, -1], base_key, seeds, steps)
-        tok = jnp.where(active[:, None], nxt,
-                        jnp.maximum(eos_ids, 0)[:, None]).astype(jnp.int32)
-        buf = buf.at[:, i].set(tok[:, 0])
-        remaining = remaining - active.astype(jnp.int32)
-        finished = jnp.logical_or(tok[:, 0] == eos_ids, remaining <= 0)
-        lens = lens + active.astype(jnp.int32)
-        steps = steps + active.astype(jnp.int32)
-        active = jnp.logical_and(active, jnp.logical_not(finished))
+        with scope("chunk.state"):
+            tok = jnp.where(active[:, None], nxt,
+                            jnp.maximum(eos_ids, 0)[:, None]).astype(jnp.int32)
+            buf = buf.at[:, i].set(tok[:, 0])
+            remaining = remaining - active.astype(jnp.int32)
+            finished = jnp.logical_or(tok[:, 0] == eos_ids, remaining <= 0)
+            lens = lens + active.astype(jnp.int32)
+            steps = steps + active.astype(jnp.int32)
+            active = jnp.logical_and(active, jnp.logical_not(finished))
         out = (tok, caches, lens, active, remaining, steps, buf)
-        return out if stats is None else out + (s[7] + stats,)
+        if stats is None:
+            return out
+        with scope("chunk.state"):
+            return out + (s[7] + stats,)
 
     return body
 
@@ -89,6 +95,7 @@ def make_select_fn(do_sample: bool, temperature: float, top_k: int, top_p: float
     """``(b, V)`` logits + one shared key → ``(b, 1)`` tokens (generate path)."""
     transform = logits_transform(do_sample, temperature, top_k, top_p)
 
+    @scope("sample")
     def select(logits, rng):
         if not do_sample:
             return jnp.argmax(logits, axis=-1)[:, None].astype(jnp.int32)
@@ -108,6 +115,7 @@ def make_slot_select_fn(do_sample: bool, temperature: float, top_k: int,
     """
     transform = logits_transform(do_sample, temperature, top_k, top_p)
 
+    @scope("sample")
     def select(logits, base_key, seeds, steps):
         if not do_sample:
             return jnp.argmax(logits, axis=-1)[:, None].astype(jnp.int32)
@@ -142,10 +150,13 @@ def build_prefill(module, dequant, overlap=None, with_stats: bool = False):
 
     def prefill(params, ids, caches, lens0):
         with overlap_scope(overlap):
+            params = dequant(params)
+            with scope("chunk.state"):
+                where = dict(cache_lens=jnp.zeros_like(lens0),
+                             logits_positions=jnp.maximum(lens0 - 1, 0))
             (logits, new_caches), stats = apply_model(
-                module, dequant(params), with_stats, ids, caches=caches,
-                cache_lens=jnp.zeros_like(lens0),
-                logits_positions=jnp.maximum(lens0 - 1, 0), seq_lens=lens0)
+                module, params, with_stats, ids, caches=caches, seq_lens=lens0,
+                **where)
         if with_stats:
             return logits[:, 0], new_caches, stats
         return logits[:, 0], new_caches
@@ -215,9 +226,10 @@ def build_paged_spec_verify(module, dequant, kv_cap: int, overlap=None):
         b, t = ids.shape
         ps = caches[0]["k"].shape[2]
         P_total = caches[0]["k"].shape[0]
-        dense = [dict(zip(("k", "v"),
-                          gather_kv_dense(c["k"], c["v"], page_table, kv_cap)))
-                 for c in caches]
+        with scope("kv.gather"):
+            dense = [dict(zip(("k", "v"),
+                              gather_kv_dense(c["k"], c["v"], page_table, kv_cap)))
+                     for c in caches]
         positions = lens[:, None] + jnp.arange(t)[None]
         with overlap_scope(overlap):
             logits, dense = module.apply(
@@ -225,6 +237,7 @@ def build_paged_spec_verify(module, dequant, kv_cap: int, overlap=None):
                 caches=dense, cache_lens=lens,
                 logits_positions=None, prefix_fill=True)
 
+        @scope("kv.copy_back")
         def mirror(j, pages):
             rows = lens + j
             pidx, off = page_address(page_table, rows, kv_cap, ps, P_total,
@@ -275,11 +288,12 @@ def build_decode_loop(module, dequant, select, gen_cap: int, overlap=None):
                 {"params": params}, tok, positions=positions,
                 caches=caches, cache_lens=lens)
             tok = select(logits[:, -1], jax.random.fold_in(rng, i))
-            # finished sequences keep emitting eos (HF pad-with-eos behaviour)
-            tok = jnp.where(finished[:, None], jnp.maximum(eos, 0), tok)
-            finished = jnp.logical_or(finished, tok[:, 0] == eos)
-            buf = buf.at[:, i].set(tok[:, 0])
-            return i + 1, tok, caches, lens + 1, finished, buf
+            with scope("chunk.state"):
+                # finished sequences keep emitting eos (HF pad-with-eos behaviour)
+                tok = jnp.where(finished[:, None], jnp.maximum(eos, 0), tok)
+                finished = jnp.logical_or(finished, tok[:, 0] == eos)
+                buf = buf.at[:, i].set(tok[:, 0])
+                return i + 1, tok, caches, lens + 1, finished, buf
 
         # lens is each sequence's append position: the prompt's true length (generated
         # tokens overwrite right-pad slots in the cache; decode masks by cache_len)
@@ -376,9 +390,10 @@ def build_paged_decode_chunk(module, dequant, slot_select, chunk_size: int,
         ps = paged[0]["k"].shape[2]
         P_total = paged[0]["k"].shape[0]
         lens_in = lens
-        dense = [dict(zip(("k", "v"),
-                          gather_kv_dense(c["k"], c["v"], page_table, kv_cap)))
-                 if "k" in c else c for c in caches]
+        with scope("kv.gather"):
+            dense = [dict(zip(("k", "v"),
+                              gather_kv_dense(c["k"], c["v"], page_table, kv_cap)))
+                     if "k" in c else c for c in caches]
 
         def step_model(toks, dense, lens):
             return apply_model(module, params, with_stats, toks,
@@ -393,22 +408,24 @@ def build_paged_decode_chunk(module, dequant, slot_select, chunk_size: int,
         toks, dense, lens, active, remaining, steps, buf = out[:7]
         # mirror rows [lens_in, lens) (this chunk's appends) into the pages;
         # rows a slot never advanced past, or beyond cap, are dropped
-        done = lens - lens_in
+        with scope("kv.copy_back"):
+            done = lens - lens_in
         new_caches = []
         for c, dn in zip(caches, dense):
             if "k" not in c:             # per-slot state: the loop's carry IS it
                 new_caches.append(dn)
                 continue
             k_p, v_p = c["k"], c["v"]
-            for j in range(chunk_size):
-                rows = lens_in + j
-                pidx, off = page_address(page_table, rows, kv_cap, ps, P_total,
-                                         live=lambda: j < done)
-                idx = jnp.minimum(rows, kv_cap - 1)[:, None, None, None]
-                k_new = jnp.take_along_axis(dn["k"], idx, axis=2)[:, :, 0, :]
-                v_new = jnp.take_along_axis(dn["v"], idx, axis=2)[:, :, 0, :]
-                k_p = k_p.at[pidx, :, off, :].set(k_new.astype(k_p.dtype))
-                v_p = v_p.at[pidx, :, off, :].set(v_new.astype(v_p.dtype))
+            with scope("kv.copy_back"):
+                for j in range(chunk_size):
+                    rows = lens_in + j
+                    pidx, off = page_address(page_table, rows, kv_cap, ps, P_total,
+                                             live=lambda: j < done)
+                    idx = jnp.minimum(rows, kv_cap - 1)[:, None, None, None]
+                    k_new = jnp.take_along_axis(dn["k"], idx, axis=2)[:, :, 0, :]
+                    v_new = jnp.take_along_axis(dn["v"], idx, axis=2)[:, :, 0, :]
+                    k_p = k_p.at[pidx, :, off, :].set(k_new.astype(k_p.dtype))
+                    v_p = v_p.at[pidx, :, off, :].set(v_new.astype(v_p.dtype))
             new_caches.append({"k": k_p, "v": v_p})
         return (buf, toks, new_caches, lens, active, remaining, steps) + out[7:]
 
@@ -480,38 +497,47 @@ def _block_body(cfg, step_model, slot_select, base_key, seeds, eos_ids, steps_in
     def body(i, s):
         blk, masked, skip, caches, lens, active, remaining, steps, buf, counts = s[:10]
         S = blk.shape[0]
-        commit = active & ~jnp.any(masked, axis=1)
-        ids = jnp.concatenate(
-            [jnp.where(masked, cfg.mask_token_id, blk),
-             jnp.full((S, B), cfg.mask_token_id, blk.dtype)], axis=1).astype(jnp.int32)
+        with scope("chunk.state"):
+            commit = active & ~jnp.any(masked, axis=1)
+            ids = jnp.concatenate(
+                [jnp.where(masked, cfg.mask_token_id, blk),
+                 jnp.full((S, B), cfg.mask_token_id, blk.dtype)],
+                axis=1).astype(jnp.int32)
         (logits, caches), stats = step_model(ids, caches, lens, commit)
-        # the commit: the finished block's tokens to ``buf``
-        rel = place[None] - skip[:, None]          # place among the generated tokens
-        m = jnp.minimum(B - skip, remaining)
-        is_eos = (rel >= 0) & (rel < m[:, None]) & (blk == eos_ids[:, None])
-        any_eos = jnp.any(is_eos, axis=1)
-        m = jnp.where(any_eos, jnp.argmax(is_eos, axis=1) - skip + 1, m)
-        m = jnp.where(commit, m, 0).astype(jnp.int32)
-        at = cols[None] - (steps - steps_in)[:, None]
-        tok = jnp.take_along_axis(blk, jnp.clip(at + skip[:, None], 0, B - 1), axis=1)
-        buf = jnp.where((at >= 0) & (at < m[:, None]), tok, buf)
-        steps = steps + m
-        remaining = remaining - m
-        active = active & ~(commit & ((remaining <= 0) | any_eos))
-        lens = lens + jnp.where(commit, B, 0).astype(lens.dtype)
-        masked = masked | commit[:, None]
-        skip = jnp.where(commit, 0, skip)
+        with scope("chunk.state"):
+            # the commit: the finished block's tokens to ``buf``
+            rel = place[None] - skip[:, None]      # place among the generated tokens
+            m = jnp.minimum(B - skip, remaining)
+            is_eos = (rel >= 0) & (rel < m[:, None]) & (blk == eos_ids[:, None])
+            any_eos = jnp.any(is_eos, axis=1)
+            m = jnp.where(any_eos, jnp.argmax(is_eos, axis=1) - skip + 1, m)
+            m = jnp.where(commit, m, 0).astype(jnp.int32)
+            at = cols[None] - (steps - steps_in)[:, None]
+            tok = jnp.take_along_axis(blk, jnp.clip(at + skip[:, None], 0, B - 1),
+                                      axis=1)
+            buf = jnp.where((at >= 0) & (at < m[:, None]), tok, buf)
+            steps = steps + m
+            remaining = remaining - m
+            active = active & ~(commit & ((remaining <= 0) | any_eos))
+            lens = lens + jnp.where(commit, B, 0).astype(lens.dtype)
+            masked = masked | commit[:, None]
+            skip = jnp.where(commit, 0, skip)
         # the denoise: of the block in flight, or of the block just opened
-        pos = lens[:, None] + place[None]
-        x0 = slot_select(logits.reshape(S * B, -1), base_key, jnp.repeat(seeds, B),
-                         pos.reshape(-1)).reshape(S, B)
-        unmask = block_unmask(cfg, masked, logits, x0) & active[:, None]
-        blk = jnp.where(unmask, x0, blk)
-        masked = masked & ~unmask
-        counts = counts + jnp.stack([jnp.sum(commit), jnp.sum(unmask),
-                                     jnp.sum(commit & active)]).astype(jnp.int32)
+        with scope("sample"):
+            pos = lens[:, None] + place[None]
+            x0 = slot_select(logits.reshape(S * B, -1), base_key,
+                             jnp.repeat(seeds, B), pos.reshape(-1)).reshape(S, B)
+            unmask = block_unmask(cfg, masked, logits, x0) & active[:, None]
+        with scope("chunk.state"):
+            blk = jnp.where(unmask, x0, blk)
+            masked = masked & ~unmask
+            counts = counts + jnp.stack([jnp.sum(commit), jnp.sum(unmask),
+                                         jnp.sum(commit & active)]).astype(jnp.int32)
         out = (blk, masked, skip, caches, lens, active, remaining, steps, buf, counts)
-        return out if stats is None else out + (s[10] + stats,)
+        if stats is None:
+            return out
+        with scope("chunk.state"):
+            return out + (s[10] + stats,)
 
     return body
 
@@ -526,12 +552,13 @@ def _block_step_model(module, params, with_stats: bool):
     place = jnp.arange(B, dtype=jnp.int32)
 
     def step_model(ids, caches, lens, second):
-        first_row = jnp.where(second, B, 0).astype(jnp.int32)
-        return apply_model(module, params, with_stats, ids,
-                           positions=lens[:, None] + jnp.arange(2 * B)[None],
-                           caches=caches, cache_lens=lens, block_step=True,
-                           seq_lens=B + first_row,
-                           logits_positions=first_row[:, None] + place[None])
+        with scope("chunk.state"):
+            first_row = jnp.where(second, B, 0).astype(jnp.int32)
+            where = dict(positions=lens[:, None] + jnp.arange(2 * B)[None],
+                         seq_lens=B + first_row,
+                         logits_positions=first_row[:, None] + place[None])
+        return apply_model(module, params, with_stats, ids, caches=caches,
+                           cache_lens=lens, block_step=True, **where)
 
     return step_model
 
@@ -589,11 +616,12 @@ def build_block_decode_chunk(module, dequant, slot_select, forwards: int,
         buf = jnp.zeros((S, width), jnp.int32)
         ps = next(c["k"].shape[2] for c in caches if "k" in c)
         lens_in = lens
-        # the null page (0) behind every slot's own: the view's spare rows
-        table = jnp.pad(page_table, ((0, 0), (0, -(-(rows_view - kv_cap) // ps))))
-        dense = [dict(zip(("k", "v"),
-                          gather_kv_dense(c["k"], c["v"], table, rows_view)))
-                 if "k" in c else c for c in caches]
+        with scope("kv.gather"):
+            # the null page (0) behind every slot's own: the view's spare rows
+            table = jnp.pad(page_table, ((0, 0), (0, -(-(rows_view - kv_cap) // ps))))
+            dense = [dict(zip(("k", "v"),
+                              gather_kv_dense(c["k"], c["v"], table, rows_view)))
+                     if "k" in c else c for c in caches]
         body = _block_body(cfg, _block_step_model(module, params, with_stats),
                            slot_select, base_key, seeds, eos_ids, steps, width)
         with overlap_scope(overlap):
@@ -608,20 +636,21 @@ def build_block_decode_chunk(module, dequant, slot_select, forwards: int,
                 new_caches.append(dn)
                 continue
             pages = dict(c)
-            for j in range(width // B):
-                row0 = lens_in + j * B
-                live = row0 < lens
-                row0 = jnp.minimum(row0, kv_cap - B)
-                page = jnp.where(live, jnp.take_along_axis(
-                    page_table, (row0 // ps)[:, None], axis=1)[:, 0], 0)
-                off = row0 % ps
-                rows = (row0[:, None] + jnp.arange(B)[None])[:, None, :, None]
-                for key in ("k", "v"):
-                    slabs = jnp.take_along_axis(dn[key], rows, axis=2)   # (S, hk, B, d)
-                    for i in range(S):
-                        pages[key] = jax.lax.dynamic_update_slice(
-                            pages[key], slabs[i:i + 1].astype(pages[key].dtype),
-                            (page[i], 0, off[i], 0))
+            with scope("kv.copy_back"):
+                for j in range(width // B):
+                    row0 = lens_in + j * B
+                    live = row0 < lens
+                    row0 = jnp.minimum(row0, kv_cap - B)
+                    page = jnp.where(live, jnp.take_along_axis(
+                        page_table, (row0 // ps)[:, None], axis=1)[:, 0], 0)
+                    off = row0 % ps
+                    rows = (row0[:, None] + jnp.arange(B)[None])[:, None, :, None]
+                    for key in ("k", "v"):
+                        slabs = jnp.take_along_axis(dn[key], rows, axis=2)  # (S, hk, B, d)
+                        for i in range(S):
+                            pages[key] = jax.lax.dynamic_update_slice(
+                                pages[key], slabs[i:i + 1].astype(pages[key].dtype),
+                                (page[i], 0, off[i], 0))
             new_caches.append(pages)
         return (buf, blk, masked, skip, new_caches, lens, active, remaining, steps,
                 counts) + out[10:]

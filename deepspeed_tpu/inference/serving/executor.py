@@ -38,7 +38,7 @@ import numpy as np
 
 from ...models.causal_lm import init_cache
 from ...observability import profiler as obs_profiler
-from ...observability.trace import get_tracer
+from ...observability.trace import get_tracer, scope
 from ...utils.fault_injection import fault_point
 from ...ops.paged_attention import (FORCE_FUSED_ENV, fused_paged_for,
                                     page_address, pages_to_dense)
@@ -97,18 +97,21 @@ def _packed_chunk(chunk):
     def decode_chunk(params, ctl, caches, base_key):
         # the table is host state bound at admission; it never changes
         # inside a chunk, so it rides in the operand's tail
-        page_table = ctl[:, CTL_COLS:]
+        with scope("chunk.pack"):
+            page_table = ctl[:, CTL_COLS:]
+            args = (ctl[:, CTL_TOK:CTL_TOK + 1], caches, page_table,
+                    ctl[:, CTL_LEN], ctl[:, CTL_ACTIVE] != 0,
+                    ctl[:, CTL_REMAINING], ctl[:, CTL_EOS], ctl[:, CTL_SEED],
+                    ctl[:, CTL_STEPS])
         buf, toks, caches, lens, active, remaining, steps, *stats = chunk(
-            params, ctl[:, CTL_TOK:CTL_TOK + 1], caches, page_table,
-            ctl[:, CTL_LEN], ctl[:, CTL_ACTIVE] != 0,
-            ctl[:, CTL_REMAINING], ctl[:, CTL_EOS], ctl[:, CTL_SEED],
-            ctl[:, CTL_STEPS], base_key)
-        packed = jnp.concatenate(
-            [buf, toks, lens[:, None], active.astype(jnp.int32)[:, None],
-             remaining[:, None], steps[:, None]], axis=1)
-        if stats:
-            counts = jnp.pad(stats[0], (0, packed.shape[1] - 2))
-            packed = jnp.concatenate([packed, counts[None]], axis=0)
+            params, *args, base_key)
+        with scope("chunk.pack"):
+            packed = jnp.concatenate(
+                [buf, toks, lens[:, None], active.astype(jnp.int32)[:, None],
+                 remaining[:, None], steps[:, None]], axis=1)
+            if stats:
+                counts = jnp.pad(stats[0], (0, packed.shape[1] - 2))
+                packed = jnp.concatenate([packed, counts[None]], axis=0)
         return packed, caches
 
     return decode_chunk
@@ -129,22 +132,25 @@ def _packed_block_chunk(chunk, block: int):
     tail = ctl_head(block)
 
     def decode_chunk(params, ctl, caches, base_key):
-        blk = ctl[:, CTL_COLS:CTL_COLS + block]
-        masked = (ctl[:, CTL_COLS + block, None] & bits[None]) != 0
+        with scope("chunk.pack"):
+            blk = ctl[:, CTL_COLS:CTL_COLS + block]
+            masked = (ctl[:, CTL_COLS + block, None] & bits[None]) != 0
+            args = (blk, masked, ctl[:, CTL_COLS + block + 1], caches,
+                    ctl[:, tail:], ctl[:, CTL_LEN], ctl[:, CTL_ACTIVE] != 0,
+                    ctl[:, CTL_REMAINING], ctl[:, CTL_EOS], ctl[:, CTL_SEED],
+                    ctl[:, CTL_STEPS])
         buf, blk, masked, skip, caches, lens, active, remaining, steps, counts, \
-            *stats = chunk(
-                params, blk, masked, ctl[:, CTL_COLS + block + 1], caches,
-                ctl[:, tail:], ctl[:, CTL_LEN], ctl[:, CTL_ACTIVE] != 0,
-                ctl[:, CTL_REMAINING], ctl[:, CTL_EOS], ctl[:, CTL_SEED],
-                ctl[:, CTL_STEPS], base_key)
-        packed = jnp.concatenate(
-            [buf, jnp.zeros_like(lens)[:, None], lens[:, None],
-             active.astype(jnp.int32)[:, None], remaining[:, None], steps[:, None],
-             blk, jnp.sum(jnp.where(masked, bits[None], 0), axis=1,
-                          dtype=jnp.int32)[:, None], skip[:, None]], axis=1)
-        moe = stats[0] if stats else jnp.zeros((2,), jnp.int32)
-        last = jnp.pad(jnp.concatenate([moe, counts]), (0, packed.shape[1] - 5))
-        return jnp.concatenate([packed, last[None]], axis=0), caches
+            *stats = chunk(params, *args, base_key)
+        with scope("chunk.pack"):
+            packed = jnp.concatenate(
+                [buf, jnp.zeros_like(lens)[:, None], lens[:, None],
+                 active.astype(jnp.int32)[:, None], remaining[:, None],
+                 steps[:, None], blk,
+                 jnp.sum(jnp.where(masked, bits[None], 0), axis=1,
+                         dtype=jnp.int32)[:, None], skip[:, None]], axis=1)
+            moe = stats[0] if stats else jnp.zeros((2,), jnp.int32)
+            last = jnp.pad(jnp.concatenate([moe, counts]), (0, packed.shape[1] - 5))
+            return jnp.concatenate([packed, last[None]], axis=0), caches
 
     return decode_chunk
 
@@ -366,13 +372,16 @@ class ChunkedDecodeExecutor:
             cap, dtype = self.cap, engine.dtype
 
             def prefill(params, ids, ctl, base_key):
-                caches = init_cache(cfg, 1, cap, dtype=dtype)
-                seed = ctl[1:2]
+                with scope("chunk.pack"):
+                    caches = init_cache(cfg, 1, cap, dtype=dtype)
+                    seed = ctl[1:2]
+                    lens0 = ctl[0:1]
                 logits, new_caches, *stats = prefill_logits(params, ids, caches,
-                                                            ctl[0:1])
+                                                            lens0)
                 tok0 = select(logits, base_key, seed, jnp.zeros_like(seed))
                 # the first token, and the expert layers' two counts behind it
-                return jnp.concatenate([tok0[0], *stats]), new_caches
+                with scope("chunk.pack"):
+                    return jnp.concatenate([tok0[0], *stats]), new_caches
 
             fns[key] = jax.jit(prefill)
         return fns[key]
@@ -398,14 +407,16 @@ class ChunkedDecodeExecutor:
             ps, P_total = self.pool.page_size, self.pool.total_pages
 
             def suffix_prefill(params, caches, ids, ctl, base_key):
-                prefix_len, suffix_len, seed = ctl[0:1], ctl[1:2], ctl[2:3]
-                tbl = ctl[PRE_COLS:]        # the slot's page-table row
+                with scope("chunk.pack"):
+                    prefix_len, suffix_len, seed = ctl[0:1], ctl[1:2], ctl[2:3]
+                    tbl = ctl[PRE_COLS:]        # the slot's page-table row
                 one = []
-                for c in caches:
-                    k = pages_to_dense(c["k"], tbl)
-                    v = pages_to_dense(c["v"], tbl)
-                    one.append({"k": k[None, :, :cap, :],
-                                "v": v[None, :, :cap, :]})
+                with scope("kv.gather"):
+                    for c in caches:
+                        k = pages_to_dense(c["k"], tbl)
+                        v = pages_to_dense(c["v"], tbl)
+                        one.append({"k": k[None, :, :cap, :],
+                                    "v": v[None, :, :cap, :]})
                 logits, new_one = prefix_prefill(params, ids, one, prefix_len,
                                                  suffix_len)
                 tok0 = select(logits, base_key, seed, jnp.zeros_like(seed))
@@ -413,17 +424,18 @@ class ChunkedDecodeExecutor:
                 # rows beyond cap are dropped (the dense path's OOB-pad-drop
                 # contract)
                 t = ids.shape[1]
-                rows = prefix_len[0] + jnp.arange(t)
-                pidx, off = page_address(tbl, rows, cap, ps, P_total)
                 out = []
-                for c, n in zip(caches, new_one):
-                    kv = {}
-                    for key_ in ("k", "v"):
-                        vals = jnp.take(n[key_][0], rows, axis=1,
-                                        mode="clip").transpose(1, 0, 2)
-                        kv[key_] = c[key_].at[pidx, :, off, :].set(
-                            vals.astype(c[key_].dtype))
-                    out.append(kv)
+                with scope("kv.copy_back"):
+                    rows = prefix_len[0] + jnp.arange(t)
+                    pidx, off = page_address(tbl, rows, cap, ps, P_total)
+                    for c, n in zip(caches, new_one):
+                        kv = {}
+                        for key_ in ("k", "v"):
+                            vals = jnp.take(n[key_][0], rows, axis=1,
+                                            mode="clip").transpose(1, 0, 2)
+                            kv[key_] = c[key_].at[pidx, :, off, :].set(
+                                vals.astype(c[key_].dtype))
+                        out.append(kv)
                 return tok0[0], out
 
             fns[key] = jax.jit(suffix_prefill, donate_argnums=(1,))

@@ -23,6 +23,7 @@ import jax.numpy as jnp
 import numpy as np
 from jax.sharding import PartitionSpec as P
 
+from ..observability import scope
 from ..ops.attention.decode import decode_attention, decode_attention_xla
 from ..ops.transformer.attention import xla_attention
 from ..parallel.overlap import (RowParallelDense, chunked_expert_exchange,
@@ -466,18 +467,20 @@ class CausalLMLayer(nn.Module):
     def _attn_proj(self, x):
         cfg = self.config
         hd, hk = cfg.head_dim, cfg.kv_heads
-        q = QuantDense(cfg.n_head * hd, use_bias=cfg.qkv_bias, dtype=cfg.dtype,
-                       kernel_init=nn.initializers.normal(cfg.init_std),
-                       site="wq.q_proj", name="q_proj")(x)
-        k = QuantDense(hk * hd, use_bias=cfg.qkv_bias, dtype=cfg.dtype,
-                       kernel_init=nn.initializers.normal(cfg.init_std),
-                       site="wq.k_proj", name="k_proj")(x)
-        v = QuantDense(hk * hd, use_bias=cfg.qkv_bias, dtype=cfg.dtype,
-                       kernel_init=nn.initializers.normal(cfg.init_std),
-                       site="wq.v_proj", name="v_proj")(x)
+        with scope("attn.qkv"):
+            q = QuantDense(cfg.n_head * hd, use_bias=cfg.qkv_bias, dtype=cfg.dtype,
+                           kernel_init=nn.initializers.normal(cfg.init_std),
+                           site="wq.q_proj", name="q_proj")(x)
+            k = QuantDense(hk * hd, use_bias=cfg.qkv_bias, dtype=cfg.dtype,
+                           kernel_init=nn.initializers.normal(cfg.init_std),
+                           site="wq.k_proj", name="k_proj")(x)
+            v = QuantDense(hk * hd, use_bias=cfg.qkv_bias, dtype=cfg.dtype,
+                           kernel_init=nn.initializers.normal(cfg.init_std),
+                           site="wq.v_proj", name="v_proj")(x)
         b, t = x.shape[:2]
-        return (q.reshape(b, t, cfg.n_head, hd), k.reshape(b, t, hk, hd),
-                v.reshape(b, t, hk, hd))
+        with scope("attn.heads"):
+            return (q.reshape(b, t, cfg.n_head, hd), k.reshape(b, t, hk, hd),
+                    v.reshape(b, t, hk, hd))
 
     def _mlp(self, h):
         cfg = self.config
@@ -485,23 +488,28 @@ class CausalLMLayer(nn.Module):
         init = nn.initializers.normal(cfg.init_std)
         proj_init = nn.initializers.normal(cfg.init_std / (2 * cfg.n_layer) ** 0.5)
         if cfg.gated_mlp:
-            gate = QuantDense(cfg.ffn_dim, use_bias=cfg.mlp_bias, dtype=cfg.dtype,
-                              kernel_init=init, site="wq.gate_proj",
-                              name="gate_proj")(h)
-            up = QuantDense(cfg.ffn_dim, use_bias=cfg.mlp_bias, dtype=cfg.dtype,
-                            kernel_init=init, site="wq.up_proj",
-                            name="up_proj")(h)
-            h = act(gate) * up
+            with scope("mlp.up"):
+                gate = QuantDense(cfg.ffn_dim, use_bias=cfg.mlp_bias,
+                                  dtype=cfg.dtype, kernel_init=init,
+                                  site="wq.gate_proj", name="gate_proj")(h)
+                up = QuantDense(cfg.ffn_dim, use_bias=cfg.mlp_bias, dtype=cfg.dtype,
+                                kernel_init=init, site="wq.up_proj",
+                                name="up_proj")(h)
+            with scope("mlp.act"):
+                h = act(gate) * up
         else:
-            h = QuantDense(cfg.ffn_dim, use_bias=cfg.mlp_bias, dtype=cfg.dtype,
-                           kernel_init=init, site="wq.fc_in", name="fc_in")(h)
-            h = act(h)
+            with scope("mlp.up"):
+                h = QuantDense(cfg.ffn_dim, use_bias=cfg.mlp_bias, dtype=cfg.dtype,
+                               kernel_init=init, site="wq.fc_in", name="fc_in")(h)
+            with scope("mlp.act"):
+                h = act(h)
         # row-parallel TP site: lowers to the chunked matmul-reduce-scatter
         # ring when comm_overlap is active (plain matmul + GSPMD allreduce
         # otherwise); parameter tree identical to nn.Dense
-        return RowParallelDense(cfg.n_embd, use_bias=cfg.mlp_bias,
-                                dtype=cfg.dtype, kernel_init=proj_init,
-                                span="tp.fc_out", name="fc_out")(h)
+        with scope("mlp.down"):
+            return RowParallelDense(cfg.n_embd, use_bias=cfg.mlp_bias,
+                                    dtype=cfg.dtype, kernel_init=proj_init,
+                                    span="tp.fc_out", name="fc_out")(h)
 
     # prefill tokens are routed in chunks of this size: the one-hot dispatch/combine
     # tensors are (C, e, C) per chunk — linear total memory/flops in token count instead
@@ -550,14 +558,18 @@ class CausalLMLayer(nn.Module):
             from ..moe.sharded_moe import topk_select
             from ..ops.moe import moe_decode_ffn_quant
             k = cfg.moe_top_k
-            logits = x.astype(jnp.float32) @ wg.astype(jnp.float32)
-            idx, gw = topk_select(logits, k)
-            xk = x.astype(cdtype)
-            if k > 1:
-                xk = jnp.repeat(xk, k, axis=0)
-            y = moe_decode_ffn_quant(xk, idx.reshape(-1), w1, b1, w2, b2, act)
-            out = jnp.einsum("bk,bkm->bm", gw, y.reshape(b, k, d))
-            return out.reshape(b, t, d).astype(h.dtype)
+            with scope("moe.router"):
+                logits = x.astype(jnp.float32) @ wg.astype(jnp.float32)
+                idx, gw = topk_select(logits, k)
+            with scope("moe.rows"):
+                xk = x.astype(cdtype)
+                if k > 1:
+                    xk = jnp.repeat(xk, k, axis=0)
+            with scope("moe.experts"):
+                y = moe_decode_ffn_quant(xk, idx.reshape(-1), w1, b1, w2, b2, act)
+            with scope("moe.rows"):
+                out = jnp.einsum("bk,bkm->bm", gw, y.reshape(b, k, d))
+                return out.reshape(b, t, d).astype(h.dtype)
         if quant_experts:
             # dispatch path (prefill / expert-sharded / fastpath off): every
             # expert's FFN runs, so collapse the nodes here — XLA fuses the
@@ -589,11 +601,13 @@ class CausalLMLayer(nn.Module):
             from ..moe.sharded_moe import topk_select
             from ..ops.moe import moe_decode_ffn, moe_decode_ffn_xla
             k = cfg.moe_top_k
-            logits = x.astype(jnp.float32) @ wg.astype(jnp.float32)       # (b, e)
-            idx, gw = topk_select(logits, k)                              # (b, k) ×2
-            xk = x.astype(cdtype)
-            if k > 1:
-                xk = jnp.repeat(xk, k, axis=0)                            # (b*k, d)
+            with scope("moe.router"):
+                logits = x.astype(jnp.float32) @ wg.astype(jnp.float32)   # (b, e)
+                idx, gw = topk_select(logits, k)                          # (b, k) ×2
+            with scope("moe.rows"):
+                xk = x.astype(cdtype)
+                if k > 1:
+                    xk = jnp.repeat(xk, k, axis=0)                        # (b*k, d)
             # dispatch-time re-validation: configs mutated after construction
             # (engine plumbing) must not silently fall through to pallas
             if cfg.moe_decode_impl not in CausalLMConfig.VALID_MOE_DECODE_IMPLS:
@@ -602,11 +616,13 @@ class CausalLMLayer(nn.Module):
                     f"{CausalLMConfig.VALID_MOE_DECODE_IMPLS}")
             ffn = (moe_decode_ffn_xla if cfg.moe_decode_impl == "xla"
                    else moe_decode_ffn)
-            y = ffn(xk, idx.reshape(-1),
-                    w1.astype(cdtype), b1.astype(cdtype),
-                    w2.astype(cdtype), b2.astype(cdtype), act)
-            out = jnp.einsum("bk,bkm->bm", gw, y.reshape(b, k, d))
-            return out.reshape(b, t, d).astype(h.dtype)
+            with scope("moe.experts"):
+                y = ffn(xk, idx.reshape(-1),
+                        w1.astype(cdtype), b1.astype(cdtype),
+                        w2.astype(cdtype), b2.astype(cdtype), act)
+            with scope("moe.rows"):
+                out = jnp.einsum("bk,bkm->bm", gw, y.reshape(b, k, d))
+                return out.reshape(b, t, d).astype(h.dtype)
 
         def expert_fn(expert_in):                       # (e, c, m) → (e, c, m)
             hh = jnp.einsum("ecm,emf->ecf", expert_in, w1.astype(cdtype)) + \
@@ -624,11 +640,13 @@ class CausalLMLayer(nn.Module):
         pad = (-s) % chunk
         xc = jnp.pad(x, ((0, pad), (0, 0))).reshape(-1, chunk, d)     # (n, C, m)
         n = xc.shape[0]
-        combine, dispatch = jax.vmap(gating)(xc)                      # (n, C, e, cap)
+        with scope("moe.router"):
+            combine, dispatch = jax.vmap(gating)(xc)                  # (n, C, e, cap)
         cap = combine.shape[-1]          # == chunk for top-1, 2*chunk for top-2 (no-drop)
-        expert_in = jnp.einsum("nsec,nsm->encm", dispatch.astype(jnp.float32),
-                               xc.astype(jnp.float32)).astype(cdtype)
-        expert_in = expert_in.reshape(e, n * cap, d)
+        with scope("moe.rows"):
+            expert_in = jnp.einsum("nsec,nsm->encm", dispatch.astype(jnp.float32),
+                                   xc.astype(jnp.float32)).astype(cdtype)
+            expert_in = expert_in.reshape(e, n * cap, d)
         if expert_sharded:
             # capacity-chunked exchange when comm_overlap is active: each
             # chunk's token-major → expert-major a2a overlaps the previous
@@ -640,12 +658,14 @@ class CausalLMLayer(nn.Module):
                 mesh.sharding(P(AXIS_EXPERT, None, None)), n_chunks,
                 site="moe.decode_a2a")
         else:
-            expert_out = expert_fn(expert_in)                         # (e, n*cap, m)
-        expert_out = expert_out.reshape(e, n, cap, d)
-        out = jnp.einsum("nsec,encm->nsm", combine.astype(jnp.float32),
-                         expert_out.astype(jnp.float32))
-        out = out.reshape(-1, d)[:s]
-        return out.reshape(b, t, d).astype(h.dtype)
+            with scope("moe.experts"):
+                expert_out = expert_fn(expert_in)                     # (e, n*cap, m)
+        with scope("moe.rows"):
+            expert_out = expert_out.reshape(e, n, cap, d)
+            out = jnp.einsum("nsec,encm->nsm", combine.astype(jnp.float32),
+                             expert_out.astype(jnp.float32))
+            out = out.reshape(-1, d)[:s]
+            return out.reshape(b, t, d).astype(h.dtype)
 
     @nn.compact
     def __call__(self, x, positions, cache: Optional[Dict] = None,
@@ -668,19 +688,29 @@ class CausalLMLayer(nn.Module):
         cache; Pallas gather-by-page-index kernel on TPU).
         Returns (y, new_cache_kv or None)."""
         cfg = self.config
-        h_in = _norm(cfg, "ln_attn")(x).astype(cfg.dtype)
+        with scope("norm"):
+            h_in = _norm(cfg, "ln_attn")(x).astype(cfg.dtype)
         attn_out, new_kv = self._attention(h_in, positions, cache, cache_len,
                                            prefix_fill, page_table, kv_cap,
                                            block_step, attn_mask)
 
         mlp = self._moe_mlp if self.is_moe else self._mlp
         if cfg.parallel_residual:
-            h_mlp = _norm(cfg, "ln_mlp")(x).astype(cfg.dtype)
-            y = x + attn_out + mlp(h_mlp)
+            with scope("norm"):
+                h_mlp = _norm(cfg, "ln_mlp")(x).astype(cfg.dtype)
+            with scope("residual"):
+                y = x + attn_out
+            mlp_out = mlp(h_mlp)
+            with scope("residual"):
+                y = y + mlp_out
         else:
-            x = x + attn_out
-            h_mlp = _norm(cfg, "ln_mlp")(x).astype(cfg.dtype)
-            y = x + mlp(h_mlp)
+            with scope("residual"):
+                x = x + attn_out
+            with scope("norm"):
+                h_mlp = _norm(cfg, "ln_mlp")(x).astype(cfg.dtype)
+            mlp_out = mlp(h_mlp)
+            with scope("residual"):
+                y = x + mlp_out
         return y, new_kv
 
     def _attention(self, h_in, positions, cache, cache_len, prefix_fill,
@@ -702,14 +732,15 @@ class CausalLMLayer(nn.Module):
         cfg = self.config
         b, t, _ = h_in.shape
         q, k, v = self._attn_proj(h_in)
-        if cfg.qk_norm:
-            q = nn.RMSNorm(epsilon=cfg.ln_eps, dtype=jnp.float32,
-                           name="q_norm")(q).astype(cfg.dtype)
-            k = nn.RMSNorm(epsilon=cfg.ln_eps, dtype=jnp.float32,
-                           name="k_norm")(k).astype(cfg.dtype)
-        if cfg.pos_emb == "rotary":
-            q = apply_rotary(q, positions, cfg.rotary_base, cfg.rotary_pct)
-            k = apply_rotary(k, positions, cfg.rotary_base, cfg.rotary_pct)
+        with scope("attn.heads"):
+            if cfg.qk_norm:
+                q = nn.RMSNorm(epsilon=cfg.ln_eps, dtype=jnp.float32,
+                               name="q_norm")(q).astype(cfg.dtype)
+                k = nn.RMSNorm(epsilon=cfg.ln_eps, dtype=jnp.float32,
+                               name="k_norm")(k).astype(cfg.dtype)
+            if cfg.pos_emb == "rotary":
+                q = apply_rotary(q, positions, cfg.rotary_base, cfg.rotary_pct)
+                k = apply_rotary(k, positions, cfg.rotary_base, cfg.rotary_pct)
 
         slopes = (jnp.asarray(alibi_slopes(cfg.n_head))
                   if cfg.pos_emb == "alibi" else None)
@@ -719,8 +750,14 @@ class CausalLMLayer(nn.Module):
             if cache is None or page_table is not None or slopes is not None:
                 raise NotImplementedError(
                     "a block step runs on the dense cache view, without alibi")
-            k_cache = _cache_update(cache["k"], k.transpose(0, 2, 1, 3), cache_len)
-            v_cache = _cache_update(cache["v"], v.transpose(0, 2, 1, 3), cache_len)
+            with scope("attn.heads"):
+                k_hm = k.transpose(0, 2, 1, 3)
+            with scope("kv.append"):
+                k_cache = _cache_update(cache["k"], k_hm, cache_len)
+            with scope("attn.heads"):
+                v_hm = v.transpose(0, 2, 1, 3)
+            with scope("kv.append"):
+                v_cache = _cache_update(cache["v"], v_hm, cache_len)
             new_kv = {"k": k_cache, "v": v_cache}
             o = _block_decode(q, k_cache, v_cache, cache_len, cfg.gen_block_length)
         elif cache is not None and t == 1 and page_table is not None:
@@ -730,28 +767,36 @@ class CausalLMLayer(nn.Module):
                                                paged_cache_update)
             cap = int(kv_cap if kv_cap is not None
                       else page_table.shape[1] * cache["k"].shape[2])
-            k_hm = k.transpose(0, 2, 1, 3)   # (b, hk, 1, d)
-            v_hm = v.transpose(0, 2, 1, 3)
-            k_pages, v_pages = paged_cache_update(
-                cache["k"], cache["v"], k_hm, v_hm, page_table, cache_len)
+            with scope("attn.heads"):
+                k_hm = k.transpose(0, 2, 1, 3)   # (b, hk, 1, d)
+                v_hm = v.transpose(0, 2, 1, 3)
+            with scope("kv.append"):
+                k_pages, v_pages = paged_cache_update(
+                    cache["k"], cache["v"], k_hm, v_hm, page_table, cache_len)
             new_kv = {"k": k_pages, "v": v_pages}
             lens1 = cache_len + 1
             if slopes is not None:
-                kd, vd = gather_kv_dense(k_pages, v_pages, page_table, cap)
-                o = decode_attention_xla_alibi(q[:, 0], kd, vd, lens1,
-                                               slopes)[:, None]
+                with scope("kv.gather"):
+                    kd, vd = gather_kv_dense(k_pages, v_pages, page_table, cap)
+                with scope("attn.core"):
+                    o = decode_attention_xla_alibi(q[:, 0], kd, vd, lens1,
+                                                   slopes)[:, None]
             else:
-                o = paged_attention(q[:, 0], k_pages, v_pages, page_table,
-                                    lens1, cap)[:, None]
+                with scope("attn.core"):
+                    o = paged_attention(q[:, 0], k_pages, v_pages, page_table,
+                                        lens1, cap)[:, None]
         elif cache is not None and t == 1:
             # decode: append to cache (head-major), fused decode kernel
-            k_hm = k.transpose(0, 2, 1, 3)   # (b, hk, 1, d)
-            v_hm = v.transpose(0, 2, 1, 3)
-            k_cache = _cache_update(cache["k"], k_hm, cache_len)
-            v_cache = _cache_update(cache["v"], v_hm, cache_len)
+            with scope("attn.heads"):
+                k_hm = k.transpose(0, 2, 1, 3)   # (b, hk, 1, d)
+                v_hm = v.transpose(0, 2, 1, 3)
+            with scope("kv.append"):
+                k_cache = _cache_update(cache["k"], k_hm, cache_len)
+                v_cache = _cache_update(cache["v"], v_hm, cache_len)
             new_kv = {"k": k_cache, "v": v_cache}
-            o = _sharded_decode(q[:, 0], k_cache, v_cache, cache_len + 1,
-                                alibi=slopes)[:, None]
+            with scope("attn.core"):
+                o = _sharded_decode(q[:, 0], k_cache, v_cache, cache_len + 1,
+                                    alibi=slopes)[:, None]
         elif cache is not None and prefix_fill and cfg.gen_block_length:
             raise NotImplementedError(
                 "a prefill at a cache offset is causal: a model that generates "
@@ -760,35 +805,43 @@ class CausalLMLayer(nn.Module):
             # suffix prefill at offset cache_len: scatter suffix K/V into rows
             # [cache_len, cache_len + t) (OOB pad rows drop), attend each suffix
             # query over every cache row at position <= its own
-            k_hm = k.transpose(0, 2, 1, 3)   # (b, hk, t, d)
-            v_hm = v.transpose(0, 2, 1, 3)
-            idx = cache_len[:, None] + jnp.arange(t)[None]        # (b, t)
+            with scope("attn.heads"):
+                k_hm = k.transpose(0, 2, 1, 3)   # (b, hk, t, d)
+                v_hm = v.transpose(0, 2, 1, 3)
 
             def put(c, n, i):
                 return c.at[:, i, :].set(n.astype(c.dtype))
 
-            k_cache = jax.vmap(put)(cache["k"], k_hm, idx)
-            v_cache = jax.vmap(put)(cache["v"], v_hm, idx)
+            with scope("kv.append"):
+                idx = cache_len[:, None] + jnp.arange(t)[None]        # (b, t)
+                k_cache = jax.vmap(put)(cache["k"], k_hm, idx)
+                v_cache = jax.vmap(put)(cache["v"], v_hm, idx)
             new_kv = {"k": k_cache, "v": v_cache}
-            o = _prefix_attention_xla(q, k_cache, v_cache, cache_len, slopes)
+            with scope("attn.core"):
+                o = _prefix_attention_xla(q, k_cache, v_cache, cache_len, slopes)
         else:
             if cache is not None and attn_mask is not None:
                 raise NotImplementedError("attn_mask is for a forward without a cache")
-            o = _bias_attention(q, k, v, slopes, cfg.gen_block_length or 1,
-                                attn_mask)
+            with scope("attn.core"):
+                o = _bias_attention(q, k, v, slopes, cfg.gen_block_length or 1,
+                                    attn_mask)
             if cache is not None:
                 # prefill: write the prompt's K/V (post-rotary) into the fixed cache
                 T = cache["k"].shape[2]
-                k_hm = k.transpose(0, 2, 1, 3)
-                v_hm = v.transpose(0, 2, 1, 3)
+                with scope("attn.heads"):
+                    k_hm = k.transpose(0, 2, 1, 3)
+                    v_hm = v.transpose(0, 2, 1, 3)
                 pad = ((0, 0), (0, 0), (0, T - t), (0, 0))
-                new_kv = {"k": jnp.pad(k_hm, pad).astype(cache["k"].dtype),
-                          "v": jnp.pad(v_hm, pad).astype(cache["v"].dtype)}
-        o = o.reshape(b, t, cfg.n_head * cfg.head_dim)
+                with scope("kv.append"):
+                    new_kv = {"k": jnp.pad(k_hm, pad).astype(cache["k"].dtype),
+                              "v": jnp.pad(v_hm, pad).astype(cache["v"].dtype)}
+        with scope("attn.heads"):
+            o = o.reshape(b, t, cfg.n_head * cfg.head_dim)
         proj_init = nn.initializers.normal(cfg.init_std / (2 * cfg.n_layer) ** 0.5)
-        attn_out = RowParallelDense(cfg.n_embd, use_bias=cfg.mlp_bias,
-                                    dtype=cfg.dtype, kernel_init=proj_init,
-                                    span="tp.o_proj", name="o_proj")(o)
+        with scope("attn.out"):
+            attn_out = RowParallelDense(cfg.n_embd, use_bias=cfg.mlp_bias,
+                                        dtype=cfg.dtype, kernel_init=proj_init,
+                                        span="tp.o_proj", name="o_proj")(o)
         return attn_out, new_kv
 
 
@@ -810,7 +863,8 @@ class MixerLayer(CausalLMLayer):
                  kv_cap: Optional[int] = None, seq_lens=None,
                  block_step: bool = False, attn_mask=None):
         cfg = self.config
-        h = _norm(cfg, "norm")(x).astype(cfg.dtype)
+        with scope("norm"):
+            h = _norm(cfg, "norm")(x).astype(cfg.dtype)
         out_std = cfg.init_std / (2 * cfg.n_layer) ** 0.5
         if self.kind == "*":
             out, new = self._attention(h, positions, cache, cache_len,
@@ -833,7 +887,8 @@ class MixerLayer(CausalLMLayer):
         else:
             valid = None
             if seq_lens is not None and x.shape[1] > 1:
-                valid = jnp.arange(x.shape[1])[None, :] < seq_lens[:, None]
+                with scope("moe.plan"):
+                    valid = jnp.arange(x.shape[1])[None, :] < seq_lens[:, None]
             if cfg.moe_kind == "gated":
                 from ..moe.gated_moe import GatedMoE
                 moe = GatedMoE(
@@ -854,7 +909,8 @@ class MixerLayer(CausalLMLayer):
             out, stats = moe(h, valid)
             self.sow("stats", "moe_counts", stats)
             new = None if cache is None else {}
-        return x + out.astype(x.dtype), new
+        with scope("residual"):
+            return x + out.astype(x.dtype), new
 
 
 def make_layer(cfg: CausalLMConfig, i: int, **kw):
@@ -960,10 +1016,14 @@ def _block_decode(q, k_cache, v_cache, lens, block: int):
     b, t, h, d = q.shape
     hk = k_cache.shape[1]
     g = h // hk
-    rows = q.reshape(b, t, hk, g, d).transpose(0, 2, 1, 3, 4).reshape(b, hk * t * g, d)
-    ends = lens[:, None] + block * jnp.arange(1, t // block + 1)[None]   # (b, blocks)
-    o = _sharded_decode(rows, k_cache, v_cache, ends)
-    return o.reshape(b, hk, t, g, d).transpose(0, 2, 1, 3, 4).reshape(b, t, h, d)
+    with scope("attn.heads"):
+        rows = q.reshape(b, t, hk, g, d).transpose(0, 2, 1, 3, 4).reshape(
+            b, hk * t * g, d)
+    with scope("attn.core"):
+        ends = lens[:, None] + block * jnp.arange(1, t // block + 1)[None]   # (b, blocks)
+        o = _sharded_decode(rows, k_cache, v_cache, ends)
+    with scope("attn.heads"):
+        return o.reshape(b, hk, t, g, d).transpose(0, 2, 1, 3, 4).reshape(b, t, h, d)
 
 
 def _cache_update(cache, new, cache_len):
@@ -1074,13 +1134,14 @@ class CausalLM(nn.Module):
             positions = jnp.broadcast_to(jnp.arange(t)[None], (b, t))
         wte = self.param("wte", nn.initializers.normal(cfg.init_std),
                          (cfg.vocab_size, cfg.n_embd), jnp.float32)
-        x = wte[input_ids].astype(cfg.dtype)
-        if cfg.pos_emb == "learned":
-            wpe = self.param("wpe", nn.initializers.normal(cfg.init_std),
-                             (cfg.max_seq_len, cfg.n_embd), jnp.float32)
-            x = x + jnp.take(wpe, positions, axis=0).astype(cfg.dtype)
-        if cfg.embed_layernorm:
-            x = _norm(cfg, "ln_embed")(x).astype(cfg.dtype)
+        with scope("embed"):
+            x = wte[input_ids].astype(cfg.dtype)
+            if cfg.pos_emb == "learned":
+                wpe = self.param("wpe", nn.initializers.normal(cfg.init_std),
+                                 (cfg.max_seq_len, cfg.n_embd), jnp.float32)
+                x = x + jnp.take(wpe, positions, axis=0).astype(cfg.dtype)
+            if cfg.embed_layernorm:
+                x = _norm(cfg, "ln_embed")(x).astype(cfg.dtype)
 
         new_caches = []
         for i in range(cfg.n_layer):
@@ -1095,18 +1156,19 @@ class CausalLM(nn.Module):
                 kv_cap=kv_cap, **extra)
             new_caches.append(new_kv)
 
-        x = _norm(cfg, "ln_f")(x)
-        if logits_positions is not None and logits_positions.ndim == 2:
-            x = jnp.take_along_axis(x, logits_positions[..., None], axis=1)
-        elif logits_positions is not None:
-            x = x[jnp.arange(b), logits_positions][:, None]    # (b, 1, d)
-        if cfg.tie_word_embeddings:
-            logits = x.astype(jnp.float32) @ wte.T
-        else:
-            logits = nn.Dense(cfg.vocab_size, use_bias=cfg.lm_head_bias,
-                              dtype=jnp.float32,
-                              kernel_init=nn.initializers.normal(cfg.init_std),
-                              name="lm_head")(x.astype(jnp.float32))
+        with scope("head"):
+            x = _norm(cfg, "ln_f")(x)
+            if logits_positions is not None and logits_positions.ndim == 2:
+                x = jnp.take_along_axis(x, logits_positions[..., None], axis=1)
+            elif logits_positions is not None:
+                x = x[jnp.arange(b), logits_positions][:, None]    # (b, 1, d)
+            if cfg.tie_word_embeddings:
+                logits = x.astype(jnp.float32) @ wte.T
+            else:
+                logits = nn.Dense(cfg.vocab_size, use_bias=cfg.lm_head_bias,
+                                  dtype=jnp.float32,
+                                  kernel_init=nn.initializers.normal(cfg.init_std),
+                                  name="lm_head")(x.astype(jnp.float32))
         if caches is None:
             return logits
         return logits, new_caches
@@ -1165,12 +1227,13 @@ def causal_lm_segments(cfg: CausalLMConfig, layers_per_group: int = 2):
     def embed_apply(p, batch, rng):
         p = dict(zip(embed_keys, p))
         ids = batch["input_ids"]
-        x = p["wte"][ids].astype(cfg.dtype)
-        if cfg.pos_emb == "learned":
-            x = x + jnp.take(p["wpe"], _positions(ids), axis=0).astype(cfg.dtype)
-        if cfg.embed_layernorm:
-            x = _norm_mod(cfg).apply({"params": p["ln_embed"]}, x).astype(cfg.dtype)
-        return x
+        with scope("embed"):
+            x = p["wte"][ids].astype(cfg.dtype)
+            if cfg.pos_emb == "learned":
+                x = x + jnp.take(p["wpe"], _positions(ids), axis=0).astype(cfg.dtype)
+            if cfg.embed_layernorm:
+                x = _norm_mod(cfg).apply({"params": p["ln_embed"]}, x).astype(cfg.dtype)
+            return x
 
     segs.append(Segment(name="embed", kind="first",
                         param_keys=tuple(embed_keys), init_keys=tuple(embed_keys),
@@ -1234,19 +1297,22 @@ def causal_lm_segments(cfg: CausalLMConfig, layers_per_group: int = 2):
 
     def final_apply(p, x, batch, rng):
         p = dict(zip(final_param_keys, p))
-        x = _norm_mod(cfg).apply({"params": p["ln_f"]}, x)
-        if cfg.tie_word_embeddings:
-            logits = x.astype(jnp.float32) @ p["wte"].T
-        else:
-            logits = x.astype(jnp.float32) @ p["lm_head"]["kernel"]
-            if cfg.lm_head_bias:
-                logits = logits + p["lm_head"]["bias"]
+        with scope("head"):
+            x = _norm_mod(cfg).apply({"params": p["ln_f"]}, x)
+            if cfg.tie_word_embeddings:
+                logits = x.astype(jnp.float32) @ p["wte"].T
+            else:
+                logits = x.astype(jnp.float32) @ p["lm_head"]["kernel"]
+                if cfg.lm_head_bias:
+                    logits = logits + p["lm_head"]["bias"]
         ids = batch["input_ids"]
-        labels = batch.get("labels")
-        if labels is None:
-            labels = jnp.concatenate(
-                [ids[:, 1:], jnp.full((ids.shape[0], 1), -100, dtype=ids.dtype)], axis=1)
-        return cross_entropy_loss(logits, labels)
+        with scope("loss"):
+            labels = batch.get("labels")
+            if labels is None:
+                labels = jnp.concatenate(
+                    [ids[:, 1:],
+                     jnp.full((ids.shape[0], 1), -100, dtype=ids.dtype)], axis=1)
+            return cross_entropy_loss(logits, labels)
 
     segs.append(Segment(name="final", kind="last",
                         param_keys=tuple(final_param_keys),
@@ -1272,11 +1338,13 @@ def causal_lm_model(cfg: CausalLMConfig, sample_seq_len: Optional[int] = None,
     def loss_fn(params, batch, rng):
         ids = batch["input_ids"]
         logits = module.apply({"params": params}, ids)
-        labels = batch.get("labels")
-        if labels is None:
-            labels = jnp.concatenate(
-                [ids[:, 1:], jnp.full((ids.shape[0], 1), -100, dtype=ids.dtype)], axis=1)
-        return cross_entropy_loss(logits, labels)
+        with scope("loss"):
+            labels = batch.get("labels")
+            if labels is None:
+                labels = jnp.concatenate(
+                    [ids[:, 1:],
+                     jnp.full((ids.shape[0], 1), -100, dtype=ids.dtype)], axis=1)
+            return cross_entropy_loss(logits, labels)
 
     def apply_fn(params, batch, rng=None):
         ids = batch["input_ids"] if isinstance(batch, dict) else batch

@@ -22,6 +22,7 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
 
+from ..observability import scope
 from ..ops.attention.flash import FLASH_LSE_NAME, FLASH_OUT_NAME
 from ..ops.transformer.attention import get_attention_impl
 from .base import Model
@@ -95,41 +96,55 @@ class Block(nn.Module):
     def __call__(self, x, deterministic: bool = True):
         cfg = self.config
         attn = get_attention_impl(cfg.attention_impl)
-        h = nn.LayerNorm(dtype=jnp.float32, name="ln_1")(x).astype(cfg.dtype)
-        if cfg.split_qkv:
-            q = nn.Dense(cfg.n_embd, dtype=cfg.dtype, name="q_attn",
-                         kernel_init=nn.initializers.normal(cfg.init_std))(h)
-            k = nn.Dense(cfg.n_embd, dtype=cfg.dtype, name="k_attn",
-                         kernel_init=nn.initializers.normal(cfg.init_std))(h)
-            v = nn.Dense(cfg.n_embd, dtype=cfg.dtype, name="v_attn",
-                         kernel_init=nn.initializers.normal(cfg.init_std))(h)
-        else:
-            qkv = nn.Dense(3 * cfg.n_embd, dtype=cfg.dtype, name="c_attn",
-                           kernel_init=nn.initializers.normal(cfg.init_std))(h)
-            q, k, v = jnp.split(qkv, 3, axis=-1)
+        with scope("norm"):
+            h = nn.LayerNorm(dtype=jnp.float32, name="ln_1")(x).astype(cfg.dtype)
+        with scope("attn.qkv"):
+            if cfg.split_qkv:
+                q = nn.Dense(cfg.n_embd, dtype=cfg.dtype, name="q_attn",
+                             kernel_init=nn.initializers.normal(cfg.init_std))(h)
+                k = nn.Dense(cfg.n_embd, dtype=cfg.dtype, name="k_attn",
+                             kernel_init=nn.initializers.normal(cfg.init_std))(h)
+                v = nn.Dense(cfg.n_embd, dtype=cfg.dtype, name="v_attn",
+                             kernel_init=nn.initializers.normal(cfg.init_std))(h)
+            else:
+                qkv = nn.Dense(3 * cfg.n_embd, dtype=cfg.dtype, name="c_attn",
+                               kernel_init=nn.initializers.normal(cfg.init_std))(h)
+                q, k, v = jnp.split(qkv, 3, axis=-1)
         b, t, _ = q.shape
-        q = q.reshape(b, t, cfg.n_head, cfg.head_dim)
-        k = k.reshape(b, t, cfg.n_head, cfg.head_dim)
-        v = v.reshape(b, t, cfg.n_head, cfg.head_dim)
+        with scope("attn.heads"):
+            q = q.reshape(b, t, cfg.n_head, cfg.head_dim)
+            k = k.reshape(b, t, cfg.n_head, cfg.head_dim)
+            v = v.reshape(b, t, cfg.n_head, cfg.head_dim)
         drop_rng = (None if deterministic or cfg.dropout == 0.0
                     else self.make_rng("dropout"))
-        o = attn(q, k, v, causal=True, dropout_rate=0.0 if deterministic else cfg.dropout,
-                 dropout_rng=drop_rng)
-        o = o.reshape(b, t, cfg.n_embd)
+        with scope("attn.core"):
+            o = attn(q, k, v, causal=True,
+                     dropout_rate=0.0 if deterministic else cfg.dropout,
+                     dropout_rng=drop_rng)
+        with scope("attn.heads"):
+            o = o.reshape(b, t, cfg.n_embd)
         # scaled init on residual-writing projections (GPT-2 scheme)
         proj_init = nn.initializers.normal(cfg.init_std / (2 * cfg.n_layer) ** 0.5)
-        o = nn.Dense(cfg.n_embd, dtype=cfg.dtype, name="c_proj", kernel_init=proj_init)(o)
-        o = nn.Dropout(cfg.dropout, deterministic=deterministic)(o)
-        x = x + o
+        with scope("attn.out"):
+            o = nn.Dense(cfg.n_embd, dtype=cfg.dtype, name="c_proj",
+                         kernel_init=proj_init)(o)
+        with scope("residual"):
+            o = nn.Dropout(cfg.dropout, deterministic=deterministic)(o)
+            x = x + o
 
-        h = nn.LayerNorm(dtype=jnp.float32, name="ln_2")(x).astype(cfg.dtype)
-        h = nn.Dense(4 * cfg.n_embd, dtype=cfg.dtype, name="c_fc",
-                     kernel_init=nn.initializers.normal(cfg.init_std))(h)
-        h = nn.gelu(h, approximate=True)
-        h = nn.Dense(cfg.n_embd, dtype=cfg.dtype, name="mlp_c_proj",
-                     kernel_init=proj_init)(h)
-        h = nn.Dropout(cfg.dropout, deterministic=deterministic)(h)
-        return x + h
+        with scope("norm"):
+            h = nn.LayerNorm(dtype=jnp.float32, name="ln_2")(x).astype(cfg.dtype)
+        with scope("mlp.up"):
+            h = nn.Dense(4 * cfg.n_embd, dtype=cfg.dtype, name="c_fc",
+                         kernel_init=nn.initializers.normal(cfg.init_std))(h)
+        with scope("mlp.act"):
+            h = nn.gelu(h, approximate=True)
+        with scope("mlp.down"):
+            h = nn.Dense(cfg.n_embd, dtype=cfg.dtype, name="mlp_c_proj",
+                         kernel_init=proj_init)(h)
+        with scope("residual"):
+            h = nn.Dropout(cfg.dropout, deterministic=deterministic)(h)
+            return x + h
 
 
 # ------------------------------------------------------- manual tensor parallelism
@@ -350,10 +365,11 @@ class GPT2(nn.Module):
                          (cfg.vocab_size, cfg.n_embd), jnp.float32)
         wpe = self.param("wpe", nn.initializers.normal(cfg.init_std),
                          (cfg.n_positions, cfg.n_embd), jnp.float32)
-        x = _pin_replicated(wte)[input_ids].astype(cfg.dtype) + \
-            wpe[:t][None].astype(cfg.dtype)
-        x = nn.Dropout(cfg.dropout, deterministic=deterministic)(x)
-        x = _pin_batch_sharding(x)
+        with scope("embed"):
+            x = _pin_replicated(wte)[input_ids].astype(cfg.dtype) + \
+                wpe[:t][None].astype(cfg.dtype)
+            x = nn.Dropout(cfg.dropout, deterministic=deterministic)(x)
+            x = _pin_batch_sharding(x)
 
         block = Block
         if cfg.remat:
@@ -377,16 +393,17 @@ class GPT2(nn.Module):
             for i in range(cfg.n_layer):
                 x = _pin_batch_sharding(block(cfg, name=f"h_{i}")(x, deterministic))
 
-        x = nn.LayerNorm(dtype=jnp.float32, name="ln_f")(x)
-        if return_hidden:
-            return x, wte
-        # Tied LM head. bf16 operands + fp32 MXU accumulation: full-rate matmul (an fp32
-        # matmul runs at ~1/4 MXU rate and this is ~25% of model FLOPs), fp32-accurate logits.
-        logits = jax.lax.dot_general(
-            x.astype(cfg.dtype), wte.astype(cfg.dtype),
-            dimension_numbers=(((2,), (1,)), ((), ())),
-            preferred_element_type=jnp.float32)
-        return logits
+        with scope("head"):
+            x = nn.LayerNorm(dtype=jnp.float32, name="ln_f")(x)
+            if return_hidden:
+                return x, wte
+            # Tied LM head. bf16 operands + fp32 MXU accumulation: full-rate matmul (an
+            # fp32 matmul runs at ~1/4 MXU rate and this is ~25% of model FLOPs),
+            # fp32-accurate logits.
+            return jax.lax.dot_general(
+                x.astype(cfg.dtype), wte.astype(cfg.dtype),
+                dimension_numbers=(((2,), (1,)), ((), ())),
+                preferred_element_type=jnp.float32)
 
 
 def cross_entropy_loss(logits, labels, ignore_index: int = -100):
@@ -447,12 +464,14 @@ def gpt2_model(config: GPT2Config, sample_seq_len: Optional[int] = None,
                                        deterministic=False,
                                        rngs={"dropout": rng},
                                        return_hidden=True)
-            return chunked_vocab_cross_entropy(hidden, wte, _shift_labels(batch),
-                                               chunk=config.vocab_chunk,
-                                               compute_dtype=config.dtype)
+            with scope("loss"):
+                return chunked_vocab_cross_entropy(hidden, wte, _shift_labels(batch),
+                                                   chunk=config.vocab_chunk,
+                                                   compute_dtype=config.dtype)
         logits = module.apply({"params": params}, batch["input_ids"],
                               deterministic=False, rngs={"dropout": rng})
-        return cross_entropy_loss(logits, _shift_labels(batch))
+        with scope("loss"):
+            return cross_entropy_loss(logits, _shift_labels(batch))
 
     def apply_fn(params, batch, rng=None):
         ids = batch["input_ids"] if isinstance(batch, dict) else batch

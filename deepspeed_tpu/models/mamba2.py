@@ -27,6 +27,7 @@ import flax.linen as nn
 import jax
 import jax.numpy as jnp
 
+from ..observability import scope
 from ..ops.ssm import ssm_step
 
 HI = jax.lax.Precision.HIGHEST
@@ -158,43 +159,52 @@ class Mamba2Mixer(nn.Module):
         w_out = self.param("out_proj", nn.initializers.normal(self.out_std),
                            (d_in, self.d_model), jnp.float32)
 
-        proj = x.astype(self.dtype) @ w_in.astype(self.dtype)
-        z = proj[..., :d_in].astype(jnp.float32)
-        xbc = proj[..., d_in:d_in + conv_dim].astype(jnp.float32)
-        dt = jax.nn.softplus(proj[..., d_in + conv_dim:].astype(jnp.float32)
-                             + dt_bias.astype(jnp.float32))          # (b, t, h)
-        A = -jnp.exp(A_log.astype(jnp.float32))
-        Df = D.astype(jnp.float32)
-        cw, cb = conv_w.astype(jnp.float32), conv_b.astype(jnp.float32)
+        with scope("ssm.in"):
+            proj = x.astype(self.dtype) @ w_in.astype(self.dtype)
+            z = proj[..., :d_in].astype(jnp.float32)
+            xbc = proj[..., d_in:d_in + conv_dim].astype(jnp.float32)
+            dt = jax.nn.softplus(proj[..., d_in + conv_dim:].astype(jnp.float32)
+                                 + dt_bias.astype(jnp.float32))      # (b, t, h)
+            A = -jnp.exp(A_log.astype(jnp.float32))
+            Df = D.astype(jnp.float32)
+            cw, cb = conv_w.astype(jnp.float32), conv_b.astype(jnp.float32)
 
         new_cache = None
         if cache is not None and t == 1:
-            conv = causal_conv(xbc, cw, cb, cache["conv"])[:, 0]     # (b, c)
-            xs = conv[:, :d_in].reshape(b_, h, p)
-            Bm = conv[:, d_in:d_in + g * n].reshape(b_, g, n)
-            Cm = conv[:, d_in + g * n:].reshape(b_, g, n)
-            y, ssm = ssm_step(cache["ssm"], xs, dt[:, 0], A, Bm, Cm, Df)
-            y = y.reshape(b_, 1, d_in)
-            new_cache = {
-                "conv": jnp.concatenate(
-                    [cache["conv"][:, 1:], xbc.astype(cache["conv"].dtype)], axis=1),
-                "ssm": ssm}
-        else:
-            if seq_lens is not None:
-                real = jnp.arange(t)[None, :] < seq_lens[:, None]    # (b, t)
-                dt = jnp.where(real[..., None], dt, 0.0)
-            conv = causal_conv(xbc, cw, cb)
-            xs = conv[..., :d_in].reshape(b_, t, h, p)
-            Bm = conv[..., d_in:d_in + g * n].reshape(b_, t, g, n)
-            Cm = conv[..., d_in + g * n:].reshape(b_, t, g, n)
-            y, ssm = ssd_chunked(xs, dt, A, Bm, Cm, Df, self.chunk_size)
-            y = y.reshape(b_, t, d_in)
-            if cache is not None:
-                lens = (jnp.full((b_,), t, jnp.int32) if seq_lens is None
-                        else seq_lens)
+            with scope("ssm.conv"):
+                conv = causal_conv(xbc, cw, cb, cache["conv"])[:, 0]     # (b, c)
+                xs = conv[:, :d_in].reshape(b_, h, p)
+                Bm = conv[:, d_in:d_in + g * n].reshape(b_, g, n)
+                Cm = conv[:, d_in + g * n:].reshape(b_, g, n)
+            with scope("ssm.update"):
+                y, ssm = ssm_step(cache["ssm"], xs, dt[:, 0], A, Bm, Cm, Df)
+                y = y.reshape(b_, 1, d_in)
+            with scope("ssm.conv"):
                 new_cache = {
-                    "conv": last_inputs(xbc, lens, K).astype(cache["conv"].dtype),
+                    "conv": jnp.concatenate(
+                        [cache["conv"][:, 1:], xbc.astype(cache["conv"].dtype)],
+                        axis=1),
                     "ssm": ssm}
-        y = gated_group_norm(y, z, norm_w.astype(jnp.float32), g, self.eps)
-        out = y.astype(self.dtype) @ w_out.astype(self.dtype)
+        else:
+            with scope("ssm.conv"):
+                if seq_lens is not None:
+                    real = jnp.arange(t)[None, :] < seq_lens[:, None]    # (b, t)
+                    dt = jnp.where(real[..., None], dt, 0.0)
+                conv = causal_conv(xbc, cw, cb)
+                xs = conv[..., :d_in].reshape(b_, t, h, p)
+                Bm = conv[..., d_in:d_in + g * n].reshape(b_, t, g, n)
+                Cm = conv[..., d_in + g * n:].reshape(b_, t, g, n)
+            with scope("ssm.update"):
+                y, ssm = ssd_chunked(xs, dt, A, Bm, Cm, Df, self.chunk_size)
+                y = y.reshape(b_, t, d_in)
+            if cache is not None:
+                with scope("ssm.conv"):
+                    lens = (jnp.full((b_,), t, jnp.int32) if seq_lens is None
+                            else seq_lens)
+                    new_cache = {
+                        "conv": last_inputs(xbc, lens, K).astype(cache["conv"].dtype),
+                        "ssm": ssm}
+        with scope("ssm.out"):
+            y = gated_group_norm(y, z, norm_w.astype(jnp.float32), g, self.eps)
+            out = y.astype(self.dtype) @ w_out.astype(self.dtype)
         return out, new_cache

@@ -21,6 +21,7 @@ import flax.linen as nn
 import jax
 import jax.numpy as jnp
 
+from ..observability import scope
 from ..ops.moe.grouped_ffn import grouped_experts
 
 
@@ -61,10 +62,12 @@ class GatedMoE(nn.Module):
         w_down = self.param("experts_down", nn.initializers.normal(self.out_std),
                             (count, f, d), jnp.float32)
         b_, t, _ = h.shape
-        x = h.reshape(b_ * t, d).astype(dt)
-        idx, w = route_softmax(x, w_r, self.top_k, self.norm_topk)
-        out, stats = grouped_experts(
-            x, idx, w, first, count, w_up.astype(dt), w_down.astype(dt),
-            jax.nn.silu, None if valid is None else valid.reshape(-1),
-            w_gate.astype(dt))
-        return out.astype(dt).reshape(b_, t, d), stats
+        with scope("moe.router"):
+            x = h.reshape(b_ * t, d).astype(dt)
+            idx, w = route_softmax(x, w_r, self.top_k, self.norm_topk)
+        with scope("moe.experts"):
+            args = (w_up.astype(dt), w_down.astype(dt), jax.nn.silu,
+                    None if valid is None else valid.reshape(-1), w_gate.astype(dt))
+        out, stats = grouped_experts(x, idx, w, first, count, *args)
+        with scope("moe.rows"):
+            return out.astype(dt).reshape(b_, t, d), stats

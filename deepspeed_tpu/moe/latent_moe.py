@@ -23,6 +23,7 @@ import flax.linen as nn
 import jax
 import jax.numpy as jnp
 
+from ..observability import scope
 from ..ops.moe.grouped_ffn import grouped_experts
 
 
@@ -81,16 +82,20 @@ class LatentMoE(nn.Module):
         s2 = self.param("shared_w2", out_init, (self.shared_width, d), jnp.float32)
 
         b_, t, _ = h.shape
-        x = h.reshape(b_ * t, d).astype(dt)
-        idx, w = route(x, w_r, b_r, self.top_k, self.scale, self.norm_topk)
-        z = x @ w_down.astype(dt)                                     # (T, l)
-        r, stats = grouped_experts(
-            z, idx, w, first, count, w1.astype(dt), w2.astype(dt), self.act,
-            None if valid is None else valid.reshape(-1))
-        shared = self.act(jnp.dot(x, s1.astype(dt),
-                                  preferred_element_type=jnp.float32))
-        out = r.astype(dt) @ w_up.astype(dt) + shared.astype(dt) @ s2.astype(dt)
-        return out.reshape(b_, t, d), stats
+        with scope("moe.router"):
+            x = h.reshape(b_ * t, d).astype(dt)
+            idx, w = route(x, w_r, b_r, self.top_k, self.scale, self.norm_topk)
+        with scope("moe.shared"):
+            z = x @ w_down.astype(dt)                                 # (T, l)
+        with scope("moe.experts"):
+            args = (w1.astype(dt), w2.astype(dt), self.act,
+                    None if valid is None else valid.reshape(-1))
+        r, stats = grouped_experts(z, idx, w, first, count, *args)
+        with scope("moe.shared"):
+            shared = self.act(jnp.dot(x, s1.astype(dt),
+                                      preferred_element_type=jnp.float32))
+            out = r.astype(dt) @ w_up.astype(dt) + shared.astype(dt) @ s2.astype(dt)
+            return out.reshape(b_, t, d), stats
 
 
 def level_selection_bias(scores, bias, top_k: int, steps: int = 120,

@@ -2,7 +2,8 @@
 
 - :mod:`.trace` — request-/step-scoped hierarchical span tracer; Chrome-trace
   (Perfetto) + JSONL export; cross-process trace-id join over the subprocess
-  serving pipe;
+  serving pipe; ``scope(name)``, the declared name of a region inside a
+  compiled program (op metadata, no switch);
 - :mod:`.metrics` — bounded process-wide registry (counters / gauges /
   fixed-log-bucket histograms) with ONE declared tag schema, MonitorMaster as
   an export backend and Prometheus text exposition plus the HTTP status plane
@@ -31,14 +32,14 @@ from .metrics import (Counter, Gauge, Histogram, MetricsRegistry,
 from .profiler import ProfilerCapture, configure_capture, get_capture
 from .profiler import tick as profiler_tick
 from .trace import (CAT_ROUTER, CAT_SERVING, CAT_TRAIN, OpenSpan, SpanContext,
-                    Tracer, chrome_events_from, get_tracer)
+                    Tracer, chrome_events_from, get_tracer, scope)
 
 __all__ = [
     "schema", "Counter", "Gauge", "Histogram", "MetricsRegistry",
     "get_registry", "record_events", "start_metrics_server",
     "ProfilerCapture", "configure_capture", "get_capture", "profiler_tick",
     "CAT_ROUTER", "CAT_SERVING", "CAT_TRAIN", "OpenSpan", "SpanContext",
-    "Tracer", "get_tracer", "chrome_events_from",
+    "Tracer", "get_tracer", "chrome_events_from", "scope",
     "attribute", "phase_breakdown",
     "FlightConfig", "FlightRecorder", "get_recorder", "install_recorder",
     "flight_journal",

@@ -12,9 +12,10 @@ This module makes that capture operational instead of a notebook trick:
   ``SIGUSR2`` (:meth:`install_sigusr2`) — send the signal to a live
   ``deepspeed-serve``/trainer and the *next* N ticks are captured to the
   logdir, then the profiler stops. No restart, no steady-state overhead;
-- **aligned**: the ``TraceAnnotation`` scopes wired at prefill / decode-chunk
-  / collective call sites (``utils/nvtx.py``) land inside the capture, so the
-  device timeline lines up with the host spans by name.
+- **aligned**: the program's host spans (``tracer.span``) and the declared
+  scopes of its compiled programs (``observability.scope``: each device op's
+  ``tf_op``) land inside the capture, so the device timeline lines up with
+  the host spans and every op says which region of the model it is.
 
 The module-level :func:`tick` costs one global load + ``is None`` check when
 no capture is configured — hot-path safe.

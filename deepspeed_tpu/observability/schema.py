@@ -349,6 +349,173 @@ SPAN_MODULES = (
 )
 
 
+#: what ``observability.scope(name)`` puts in front of ``name`` on jax's
+#: name stack: a reader finds the declared scopes of an op by this prefix
+#: alone, whatever the program's version
+SCOPE_PREFIX = "ds."
+
+#: scope name -> (layer, what it holds, what reads it). THE schema of the
+#: DEVICE's regions: every name an ``observability.scope`` call in
+#: :data:`SCOPE_MODULES` opens INSIDE a compiled program. A scope is a
+#: ``jax.named_scope``: it exists while jax traces, lands in each op's
+#: ``op_name`` metadata (the ``tf_op`` stat of the op's event in a profiler
+#: trace) and changes nothing in the executed program, so there is no switch.
+#: The names say what the ops DO, the same in every model; the phase
+#: (forward, ``transpose(`` backward, ``rematted_computation`` recomputed) is
+#: jax's own and is not declared again. ``act.stack``, a scan's stack of saved
+#: activations, is no scope: jax makes those slices outside any function of
+#: the program, and the reader (``benchmarks/chipbench/device_scopes.py``)
+#: names ``while/body/dynamic_slice`` and ``dynamic_update_slice`` so.
+_STEP = "compiled steps"
+_TRAIN = "train engine"
+_COMM = "zero and collectives"
+_TABLE = "the traced run's table of device time by scope"
+SCOPES: Dict[str, Tuple[str, str, str]] = {
+    # ------------------------------------------------ a model's forward
+    "embed": (_STEP, "token and position embedding lookups, embedding norm",
+              "train_outside_layers_dev_ms"),
+    "norm": (_STEP, "a layer's input norms", _TABLE),
+    "residual": (_STEP, "residual adds and dropout between a layer's parts",
+                 _TABLE),
+    "attn.qkv": (_STEP, "q/k/v projections, bias, split",
+                 "train_matmul_roofline_pct"),
+    "attn.heads": (_STEP, "reshapes and transposes between (b, t, h, d) and "
+                          "the attention's layout; rotary, q/k norm",
+                   "decode_attn_dev_ms_per_step"),
+    "attn.core": (_STEP, "flash_*, decode_attention, the XLA products and "
+                         "softmax (and the layout changes the kernels' own "
+                         "wrappers make)",
+                  "decode_attn_dev_ms_per_step"),
+    "attn.out": (_STEP, "the attention's output projection",
+                 "train_matmul_roofline_pct"),
+    "kv.append": (_STEP, "new keys and values written into the cache",
+                  "decode_attn_dev_ms_per_step"),
+    "mlp.up": (_STEP, "the MLP's first projection, with the gate",
+               "train_matmul_roofline_pct"),
+    "mlp.act": (_STEP, "the MLP's activation (and gate product)", _TABLE),
+    "mlp.down": (_STEP, "the MLP's second projection",
+                 "train_matmul_roofline_pct"),
+    "moe.router": (_STEP, "router scores and the top-k", _TABLE),
+    "moe.plan": (_STEP, "the dispatch plan's sorts and comparisons", _TABLE),
+    "moe.rows": (_STEP, "rows gathered into plan order, gathered back, the "
+                        "weighted sum", _TABLE),
+    "moe.experts": (_STEP, "the expert FFNs (moe_grouped_ffn; the classic "
+                           "layer's dispatch einsums)",
+                    _TABLE + ", beside moe_decode_dev_ms_per_step"),
+    "moe.shared": (_STEP, "the latent projections around the experts and "
+                          "the shared expert", _TABLE),
+    "ssm.in": (_STEP, "a Mamba-2 mixer's input projection and its split",
+               _TABLE),
+    "ssm.conv": (_STEP, "the causal convolution and its window", _TABLE),
+    "ssm.update": (_STEP, "the one-token state update and the chunked scan",
+                   _TABLE + ", beside ssm_decode_dev_ms_per_step"),
+    "ssm.out": (_STEP, "the gated norm and the output projection", _TABLE),
+    "head": (_STEP, "final norm, the rows the logits are read at, the "
+                    "vocabulary matmul",
+             "decode_head_dev_ms_per_step, train_outside_layers_dev_ms, "
+             "train_matmul_roofline_pct"),
+    "sample": (_STEP, "logits_transform, argmax or categorical, block_unmask",
+               "decode_head_dev_ms_per_step"),
+    # ------------------------------------------------- a decode chunk
+    "chunk.state": (_STEP, "a step's per-slot bookkeeping (lens, active, "
+                           "remaining, the token buffer, a block's commit)",
+                    _TABLE),
+    "kv.gather": (_STEP, "pages gathered into the dense view, once a chunk "
+                         "or a suffix prefill", "decode_per_chunk_dev_ms"),
+    "kv.copy_back": (_STEP, "the chunk's new rows mirrored or copied back "
+                            "into the pages", "decode_per_chunk_dev_ms"),
+    "chunk.pack": (_STEP, "the packed operand unpacked, the result packed",
+                   "decode_per_chunk_dev_ms"),
+    # ----------------------------------------------------- a train step
+    "param.cast": (_TRAIN, "the compute-dtype copy of the master parameters",
+                   _TABLE),
+    "loss": (_TRAIN, "the cross entropy and the loss scale",
+             "train_outside_layers_dev_ms"),
+    "grad.accum": (_TRAIN, "micro-batch gradients added to the accumulator",
+                   _TABLE),
+    "grad.norm_clip": (_TRAIN, "unscale, global norm, overflow check, clip",
+                       "train_outside_layers_dev_ms"),
+    "optimizer.update": (_TRAIN, "the optimizer's update and the overflow "
+                                 "guard", "train_outside_layers_dev_ms"),
+    # ------------------------------------------------------ collectives
+    "comm.allgather_matmul_monolithic": (_COMM, "all-gather then matmul", _TABLE),
+    "comm.matmul_reduce_scatter_monolithic": (_COMM, "matmul then "
+                                              "reduce-scatter", _TABLE),
+    "comm.chunked_allgather_matmul": (_COMM, "the all-gather ring "
+                                      "overlapped with its matmul", _TABLE),
+    "comm.chunked_matmul_reduce_scatter": (_COMM, "the matmul overlapped "
+                                           "with its reduce-scatter ring",
+                                           _TABLE),
+    "comm.chunked_expert_exchange": (_COMM, "the capacity-chunked expert "
+                                     "all-to-all", _TABLE),
+    "comm.fused_quant_matmul_reduce_scatter": (_COMM, "the intN-wire "
+                                               "reduce-scatter ring", _TABLE),
+    "comm.fused_quant_allgather_matmul": (_COMM, "the intN-wire all-gather "
+                                          "ring", _TABLE),
+    "comm.quantized_allreduce": (_COMM, "the error-compensated intN "
+                                 "gradient mean", _TABLE),
+}
+
+#: modules whose ``scope(...)`` call sites the lint walks (repo-relative)
+SCOPE_MODULES = (
+    "deepspeed_tpu/models/gpt2.py",
+    "deepspeed_tpu/models/causal_lm.py",
+    "deepspeed_tpu/models/mamba2.py",
+    "deepspeed_tpu/moe/gated_moe.py",
+    "deepspeed_tpu/moe/latent_moe.py",
+    "deepspeed_tpu/ops/moe/grouped_ffn.py",
+    "deepspeed_tpu/inference/decode_fns.py",
+    "deepspeed_tpu/inference/serving/executor.py",
+    "deepspeed_tpu/runtime/engine.py",
+    "deepspeed_tpu/parallel/overlap.py",
+    "deepspeed_tpu/parallel/qring.py",
+    "deepspeed_tpu/comm/compressed.py",
+)
+
+
+def scope_sites_digest() -> str:
+    """A digest of what the compiled programs' ops are named: the prefix, and
+    for every file of :data:`SCOPE_MODULES` the scopes its call sites open,
+    in source order (no line numbers: an edit elsewhere in a file moves
+    nothing here). ``utils.device.enable_compile_cache`` adds it to jax's
+    persistent-cache key, which leaves op names out: without it a program
+    that differs from a cached one only in its scopes is LOADED with the
+    cached one's op metadata, and a profiler trace shows names the source
+    does not have (a kernel-free serving program read 0 % scoped on a machine
+    whose cache held its parent's build; PERF.md, PR 36)."""
+    import hashlib
+    import os
+    root = os.path.dirname(os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
+    h = hashlib.sha256(SCOPE_PREFIX.encode())
+    for rel, names in scope_sites(root).items():
+        h.update(("\n" + rel + ":" + ",".join(str(n) for n in names)).encode())
+    return h.hexdigest()[:16]
+
+
+def scope_sites(repo_root: str) -> Dict[str, List[Optional[str]]]:
+    """``{file of SCOPE_MODULES: the scope names its call sites open, in
+    source order}`` (``None`` for a name that is no literal; a file that
+    cannot be read or parsed is left out: the lint's runner reports it)."""
+    import ast
+    import os
+    from ..analysis.ast_rules import iter_scope_names_from_tree
+    out = {}
+    for rel in SCOPE_MODULES:
+        try:
+            with open(os.path.join(repo_root, rel)) as f:
+                tree = ast.parse(f.read(), filename=rel)
+        except (OSError, SyntaxError):
+            continue
+        sites = sorted(iter_scope_names_from_tree(tree), key=lambda site: site[1])
+        out[rel] = [name for name, _ in sites]
+    return out
+
+
+def resolve_scope(name: str) -> Optional[str]:
+    """The declared scope a call site's literal name is, or None."""
+    return name if name in SCOPES else None
+
+
 def resolve_span(name: str) -> Optional[str]:
     """The declared span a call site's literal name is, or None."""
     return name if name in SPANS else None
@@ -433,18 +600,26 @@ def emission_tag_rule():
     from ..analysis.ast_rules import EmissionTagRule
     return EmissionTagRule(resolve, EMITTER_MODULES,
                            resolve_span=resolve_span,
-                           span_modules=SPAN_MODULES)
+                           span_modules=SPAN_MODULES,
+                           resolve_scope=resolve_scope,
+                           scope_modules=SCOPE_MODULES)
 
 
 def lint_emission_sites(repo_root: str) -> List[str]:
-    """Every undeclared tag across :data:`EMITTER_MODULES` and every
-    undeclared span name across :data:`SPAN_MODULES`, as ``"path:line: tag"``
-    strings (empty list = clean). Runs under the shared AST rule runner (one
-    framework for every source-level rule)."""
+    """Every undeclared tag across :data:`EMITTER_MODULES`, every undeclared
+    span name across :data:`SPAN_MODULES` and every undeclared device scope
+    across :data:`SCOPE_MODULES`, as ``"path:line: tag"`` strings, and every
+    declared scope that no call site opens (empty list = clean). Runs under
+    the shared AST rule runner (one framework for every source-level rule)."""
     from ..analysis.ast_rules import run_ast_rules
-    paths = tuple(dict.fromkeys(EMITTER_MODULES + SPAN_MODULES))
+    paths = tuple(dict.fromkeys(EMITTER_MODULES + SPAN_MODULES + SCOPE_MODULES))
     result = run_ast_rules(repo_root, [emission_tag_rule()], paths=paths)
     # a syntax error in an emitter module surfaces as a runner finding with
     # no 'tag' detail — report it as a problem, don't crash on it
-    return [f"{f.site}: {f.details.get('tag', f.message)}"
-            for f in result.findings]
+    problems = [f"{f.site}: {f.details.get('tag', f.message)}"
+                for f in result.findings]
+    opened = {name for names in scope_sites(repo_root).values() for name in names}
+    problems += [f"observability/schema.py: device scope {name!r} is declared "
+                 "in SCOPES and opened nowhere in SCOPE_MODULES"
+                 for name in SCOPES if name not in opened]
+    return problems

@@ -1,4 +1,5 @@
-"""One span API, two sinks: the profiler's clock and the request-scoped ring.
+"""One span API, two sinks: the profiler's clock and the request-scoped ring;
+and its second half, :func:`scope`, for regions INSIDE a compiled program.
 
 Every instrumented region of the program is ONE call, ``tracer.span(name,
 **attrs)``, under a name declared once in ``observability/schema.py: SPANS``.
@@ -52,6 +53,12 @@ The ring answers "where did this one request's 1.9 s go?": the serving column
    anchored, advanced by ``time.monotonic``) so lanes from different
    processes line up.
 
+A span covers what the HOST did. What the DEVICE did inside a compiled
+program is named by :func:`scope` (names declared in ``schema.py: SCOPES``): a
+``jax.named_scope`` that exists only while jax traces the function and lands
+in each op's ``op_name`` metadata, which a profiler trace keeps per device op.
+It costs nothing in the executed program, so it has no switch.
+
 Ring exports: Chrome-trace-event JSON (``{"traceEvents": [...]}``; load in
 Perfetto / ``chrome://tracing``) and a JSONL stream (one finished span per
 line) for tailing.
@@ -65,7 +72,10 @@ import time
 from collections import deque
 from typing import Dict, List, Optional
 
+import jax
 from jax.profiler import TraceAnnotation
+
+from . import schema
 
 # span categories (Chrome "cat" field) — one per subsystem lane
 CAT_SERVING = "serving"
@@ -476,6 +486,18 @@ def chrome_events_from(spans: List[Dict]) -> List[Dict]:
 
 
 _tracer = Tracer()
+
+
+def scope(name: str):
+    """Name the device ops traced under it ``ds.<name>``: a ``with`` block
+    (or a decorator) inside a function jax compiles. ``name`` is declared in
+    ``schema.SCOPES``; an undeclared one raises while jax traces."""
+    if name not in schema.SCOPES:
+        raise KeyError(
+            f"device scope {name!r} is not declared in observability.schema."
+            "SCOPES — declare it (layer, what it holds, what reads it) "
+            "before opening it")
+    return jax.named_scope(schema.SCOPE_PREFIX + name)
 
 
 def get_tracer() -> Tracer:

@@ -174,11 +174,18 @@ def grouped_experts(x, idx, w, first: int, count: int, w1, w2, act, valid=None,
     each token chose over ALL experts, and their weights). Assignments that
     fall on experts held elsewhere add nothing. Returns ``(T, l)`` float32 and
     ``(assignments on held experts, distinct held experts touched)``."""
+    from ...observability import scope   # here: the kernel's lines above stay put
     tm = tile_rows(idx.size)
-    plan = dispatch_plan(idx, first, count, tm, valid)
-    rows = grouped_ffn(x[plan["row_token"]], plan["tile_expert"],
-                       plan["tile_valid"], w1, w2, act, tm, w_gate)   # (R, l) f32
-    mine = jnp.take(rows, plan["pos"], axis=0, mode="fill", fill_value=0.0)
-    out = jnp.sum(jnp.where(plan["held"], w, 0.0)[..., None] * mine, axis=1)
-    stats = jnp.stack([plan["n_assigned"], plan["n_touched"]]).astype(jnp.int32)
+    with scope("moe.plan"):
+        plan = dispatch_plan(idx, first, count, tm, valid)
+    with scope("moe.rows"):
+        x_rows = x[plan["row_token"]]
+    with scope("moe.experts"):
+        rows = grouped_ffn(x_rows, plan["tile_expert"], plan["tile_valid"], w1, w2,
+                           act, tm, w_gate)                           # (R, l) f32
+    with scope("moe.rows"):
+        mine = jnp.take(rows, plan["pos"], axis=0, mode="fill", fill_value=0.0)
+        out = jnp.sum(jnp.where(plan["held"], w, 0.0)[..., None] * mine, axis=1)
+    with scope("moe.plan"):
+        stats = jnp.stack([plan["n_assigned"], plan["n_touched"]]).astype(jnp.int32)
     return out, stats

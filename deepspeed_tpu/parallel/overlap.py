@@ -40,7 +40,6 @@ Every decomposed/monolithic call site records a trace-time bytes-on-wire span
 
 import contextlib
 import dataclasses
-import functools
 from typing import Any, Callable, Optional
 
 import jax
@@ -48,23 +47,10 @@ import jax.numpy as jnp
 import numpy as np
 from jax.sharding import PartitionSpec as P
 
+from ..observability import scope
 from ..utils.comms_logging import record_collective
 from ..utils.jax_compat import shard_map
-from ..utils.nvtx import named_scope
 from .mesh import AXIS_PIPE, AXIS_SEQ, AXIS_TENSOR, BATCH_AXES, get_global_mesh
-
-
-def _scoped(name: str):
-    """Trace the decorated collective under a ``jax.named_scope``: the name
-    lands in XLA op metadata, so an on-demand profiler capture shows the ring
-    steps / fallbacks as labeled device ops aligned with the host spans."""
-    def deco(fn):
-        @functools.wraps(fn)
-        def wrapped(*args, **kwargs):
-            with named_scope(name):
-                return fn(*args, **kwargs)
-        return wrapped
-    return deco
 
 
 # --------------------------------------------------------------------- config
@@ -178,7 +164,7 @@ def _record_ring(site, op, per_shard_bytes, axis_name, overlapped):
                           overlapped=overlapped)
 
 
-@_scoped("comm.allgather_matmul_monolithic")
+@scope("comm.allgather_matmul_monolithic")
 def allgather_matmul_monolithic(x, w, axis_name, *, site=None):
     """Exact-numerics fallback: ``all_gather(x, tiled) @ w``."""
     _record_ring(site, "all_gather", x.size * x.dtype.itemsize, axis_name,
@@ -187,7 +173,7 @@ def allgather_matmul_monolithic(x, w, axis_name, *, site=None):
     return g @ w
 
 
-@_scoped("comm.matmul_reduce_scatter_monolithic")
+@scope("comm.matmul_reduce_scatter_monolithic")
 def matmul_reduce_scatter_monolithic(x, w, axis_name, *, site=None):
     """Exact-numerics fallback: ``psum_scatter(x @ w, scatter dim 0, tiled)``."""
     W = jax.lax.psum(1, axis_name)
@@ -198,7 +184,7 @@ def matmul_reduce_scatter_monolithic(x, w, axis_name, *, site=None):
                                 tiled=True)
 
 
-@_scoped("comm.chunked_allgather_matmul")
+@scope("comm.chunked_allgather_matmul")
 def chunked_allgather_matmul(x, w, axis_name, *, bidirectional: bool = True,
                              site=None):
     """``all_gather(x, axis=0, tiled) @ w`` as a ppermute ring.
@@ -243,7 +229,7 @@ def chunked_allgather_matmul(x, w, axis_name, *, bidirectional: bool = True,
     return out
 
 
-@_scoped("comm.chunked_matmul_reduce_scatter")
+@scope("comm.chunked_matmul_reduce_scatter")
 def chunked_matmul_reduce_scatter(x, w, axis_name, *,
                                   bidirectional: bool = True, site=None):
     """``psum_scatter(x @ w, scatter dim 0, tiled)`` as a compute/accumulate ring.
@@ -454,7 +440,7 @@ def moe_overlap_chunks(cfg: OverlapConfig, expert_parallel: int, cap: int) -> in
     return 1
 
 
-@_scoped("comm.chunked_expert_exchange")
+@scope("comm.chunked_expert_exchange")
 def chunked_expert_exchange(expert_in, expert_fn, sharding, n_chunks: int,
                             *, site: str = "moe.a2a"):
     """Run the expert exchange + FFN in ``n_chunks`` capacity slices.

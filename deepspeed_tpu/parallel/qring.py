@@ -51,10 +51,11 @@ from jax.sharding import PartitionSpec as P
 
 from ..comm.compressed import (intn_blockwise_compress,
                                intn_blockwise_decompress, intn_wire_nbytes)
+from ..observability import scope
 from ..utils.comms_logging import record_collective
 from ..utils.jax_compat import shard_map
 from .mesh import AXIS_TENSOR, get_global_mesh
-from .overlap import OverlapConfig, _ring_perm, _scoped
+from .overlap import OverlapConfig, _ring_perm
 
 
 def _wire_hop(chunk, residual, axis_name, perm, wire_bits: Optional[int],
@@ -112,7 +113,7 @@ def _chunk_gemm(x, q, scales, bits: int, groups: int, m_blk: int,
     return gemm
 
 
-@_scoped("comm.fused_quant_matmul_reduce_scatter")
+@scope("comm.fused_quant_matmul_reduce_scatter")
 def fused_quant_matmul_reduce_scatter(x, q, scales, axis_name, *,
                                       bits: int = 8,
                                       wire_bits: Optional[int] = 8,
@@ -202,7 +203,7 @@ def fused_quant_matmul_reduce_scatter(x, q, scales, axis_name, *,
         jnp.concatenate([r_a, r_b])
 
 
-@_scoped("comm.fused_quant_allgather_matmul")
+@scope("comm.fused_quant_allgather_matmul")
 def fused_quant_allgather_matmul(x, q, scales, axis_name, *, bits: int = 8,
                                  wire_bits: Optional[int] = 8,
                                  quant_block: int = 256,

@@ -40,7 +40,7 @@ from ..parallel.mesh import (AXIS_DATA, MeshSpec, get_global_mesh,
                              set_global_mesh)
 from ..observability import profiler as obs_profiler
 from ..observability.metrics import record_events as obs_record_events
-from ..observability.trace import CAT_TRAIN, get_tracer
+from ..observability.trace import CAT_TRAIN, get_tracer, scope
 from ..parallel.overlap import resolve_overlap_config, set_overlap_config
 from ..utils.comms_logging import (collective_spans, record_collective,
                                    spans_overlap_ratio, spans_total_bytes)
@@ -521,16 +521,18 @@ class DeepSpeedEngine:
         ``pld_theta`` (traced) reaches opt-in models (see ``_pld_in_loss``)."""
 
         def f(p):
-            p = tree_cast(p, self.compute_dtype)
-            if self._compression is not None and step is not None:
-                p = self._compression.qat(p, step)
+            with scope("param.cast"):
+                p = tree_cast(p, self.compute_dtype)
+                if self._compression is not None and step is not None:
+                    p = self._compression.qat(p, step)
             kwargs = {}
             if self._pld_in_loss and pld_theta is not None:
                 kwargs["pld_theta"] = pld_theta
             loss = self.module.loss_fn(p, batch, rng, **kwargs)
             if isinstance(loss, tuple):
                 loss = loss[0]
-            return loss * scale.astype(loss.dtype), loss
+            with scope("loss"):
+                return loss * scale.astype(loss.dtype), loss
 
         (scaled, loss), grads = jax.value_and_grad(f, has_aux=True)(params)
         return loss, grads
@@ -539,32 +541,35 @@ class DeepSpeedEngine:
         """Shared device-side tail of both update paths: unscale by loss-scale × n_micro,
         prescale, global-norm overflow check, clip. Returns (grads, norm, overflow)."""
         scale = state.scaler.cur_scale
-        grads = jax.tree_util.tree_map(
-            lambda g: g / (scale * np.float32(n_micro)), grads_acc)
-        if self._config.prescale_gradients:
+        with scope("grad.norm_clip"):
             grads = jax.tree_util.tree_map(
-                lambda g: g / np.float32(self._config.gradient_predivide_factor), grads)
-        norm = global_norm(grads)
-        if self._config.fp16.enabled:
-            overflow = jnp.logical_not(jnp.isfinite(norm))
-        else:
-            overflow = jnp.array(False)
-        clip = self._config.gradient_clipping
-        if clip and clip > 0:
-            safe_norm = jnp.where(jnp.isfinite(norm), norm, 1.0)
-            grads = clip_by_global_norm(grads, clip, norm=safe_norm)
+                lambda g: g / (scale * np.float32(n_micro)), grads_acc)
+            if self._config.prescale_gradients:
+                grads = jax.tree_util.tree_map(
+                    lambda g: g / np.float32(self._config.gradient_predivide_factor),
+                    grads)
+            norm = global_norm(grads)
+            if self._config.fp16.enabled:
+                overflow = jnp.logical_not(jnp.isfinite(norm))
+            else:
+                overflow = jnp.array(False)
+            clip = self._config.gradient_clipping
+            if clip and clip > 0:
+                safe_norm = jnp.where(jnp.isfinite(norm), norm, 1.0)
+                grads = clip_by_global_norm(grads, clip, norm=safe_norm)
         return grads, norm, overflow
 
     def _apply_update(self, state: TrainState, grads_acc, lr, n_micro):
         """Unscale, clip, overflow-guard, optimizer update, scaler update."""
         scale = state.scaler.cur_scale
         grads, norm, overflow = self._unscale_clip_and_check(state, grads_acc, n_micro)
-        new_params, new_opt = self.optimizer.update(grads, state.opt_state, state.params,
-                                                    jnp.float32(lr))
-        keep_old = lambda old, new: jnp.where(overflow, old, new)
-        new_params = jax.tree_util.tree_map(keep_old, state.params, new_params)
-        new_opt = jax.tree_util.tree_map(keep_old, state.opt_state, new_opt)
-        new_scaler = self.loss_scaler.update(state.scaler, overflow)
+        with scope("optimizer.update"):
+            new_params, new_opt = self.optimizer.update(
+                grads, state.opt_state, state.params, jnp.float32(lr))
+            keep_old = lambda old, new: jnp.where(overflow, old, new)
+            new_params = jax.tree_util.tree_map(keep_old, state.params, new_params)
+            new_opt = jax.tree_util.tree_map(keep_old, state.opt_state, new_opt)
+            new_scaler = self.loss_scaler.update(state.scaler, overflow)
         new_state = TrainState(
             params=new_params,
             opt_state=new_opt,
@@ -610,12 +615,14 @@ class DeepSpeedEngine:
                 loss, grads = self._loss_and_scaled_grads(
                     state.params, state.scaler.cur_scale, mb, rng,
                     step=state.global_step, pld_theta=pld_theta)
-                acc = jax.tree_util.tree_map(jnp.add, acc, grads)
-                acc = jax.lax.with_sharding_constraint(acc, grad_shardings)
+                with scope("grad.accum"):
+                    acc = jax.tree_util.tree_map(jnp.add, acc, grads)
+                    acc = jax.lax.with_sharding_constraint(acc, grad_shardings)
                 return acc, loss
 
-            acc0 = jax.lax.with_sharding_constraint(
-                tree_zeros_like(state.params, jnp.float32), grad_shardings)
+            with scope("grad.accum"):
+                acc0 = jax.lax.with_sharding_constraint(
+                    tree_zeros_like(state.params, jnp.float32), grad_shardings)
             return jax.lax.scan(micro, acc0, (batch, jnp.arange(gas)))
 
         if self.offload_enabled:
@@ -800,22 +807,25 @@ class DeepSpeedEngine:
             # values before the int8 cast — so the overflow flag must gate the
             # update at every precision, not just under fp16 loss scaling, or
             # a bf16/fp32 overflow step would be silently applied as zeros.
-            norm = global_norm(g_sync)
-            overflow = jnp.logical_or(overflow_q > 0,
-                                      jnp.logical_not(jnp.isfinite(norm)))
-            clip = self._config.gradient_clipping
-            if clip and clip > 0:
-                safe_norm = jnp.where(jnp.isfinite(norm), norm, 1.0)
-                g_sync = clip_by_global_norm(g_sync, clip, norm=safe_norm)
-            new_params, new_opt = self.optimizer.update(
-                g_sync, state.opt_state, state.params, jnp.float32(lr))
-            keep_old = lambda old, new: jnp.where(overflow, old, new)
-            new_params = jax.tree_util.tree_map(keep_old, state.params, new_params)
-            new_opt = jax.tree_util.tree_map(keep_old, state.opt_state, new_opt)
-            # EF contract assumes the transmitted grad was CONSUMED; a skipped
-            # step discards it, so committing the new residual would inject a
-            # phantom correction into step k+1 — keep the pre-step residual
-            new_residual = jax.tree_util.tree_map(keep_old, residual, new_residual)
+            with scope("grad.norm_clip"):
+                norm = global_norm(g_sync)
+                overflow = jnp.logical_or(overflow_q > 0,
+                                          jnp.logical_not(jnp.isfinite(norm)))
+                clip = self._config.gradient_clipping
+                if clip and clip > 0:
+                    safe_norm = jnp.where(jnp.isfinite(norm), norm, 1.0)
+                    g_sync = clip_by_global_norm(g_sync, clip, norm=safe_norm)
+            with scope("optimizer.update"):
+                new_params, new_opt = self.optimizer.update(
+                    g_sync, state.opt_state, state.params, jnp.float32(lr))
+                keep_old = lambda old, new: jnp.where(overflow, old, new)
+                new_params = jax.tree_util.tree_map(keep_old, state.params, new_params)
+                new_opt = jax.tree_util.tree_map(keep_old, state.opt_state, new_opt)
+                # EF contract assumes the transmitted grad was CONSUMED; a skipped
+                # step discards it, so committing the new residual would inject a
+                # phantom correction into step k+1 — keep the pre-step residual
+                new_residual = jax.tree_util.tree_map(keep_old, residual,
+                                                      new_residual)
             new_state = TrainState(
                 params=new_params, opt_state=new_opt,
                 scaler=self.loss_scaler.update(state.scaler, overflow),

@@ -55,12 +55,30 @@ def enable_compile_cache() -> str:
     process of this checkout (never a temp name, pid or time), so a second
     process — a child, or the next run — finds what the first compiled.
     Called by ``ds.initialize``, ``ds.init_inference`` and the entry scripts
-    before their first compile; idempotent."""
+    before their first compile; idempotent. Also makes the names of the
+    programs' device scopes part of the cache's key."""
+    _key_the_cache_on_the_scopes()
     path = os.environ.get(CACHE_ENV)
     if path:
         return path
     jax.config.update("jax_compilation_cache_dir", DEFAULT_CACHE_DIR)
     return DEFAULT_CACHE_DIR
+
+
+@functools.cache
+def _key_the_cache_on_the_scopes() -> None:
+    """jax keys a cached executable on its program WITHOUT op names and
+    locations, and an executable carries the op metadata it was compiled
+    with: a program that differs from a cached one only in its declared
+    scopes (``observability.scope``) would be loaded with the other's names,
+    and a profiler trace would show regions the source does not have. jax's
+    own hook for additions to the key takes the digest of the scopes' call
+    sites, so a change to them compiles once more and nothing else does."""
+    from jax._src import cache_key
+
+    from ..observability.schema import scope_sites_digest
+    digest = scope_sites_digest()
+    cache_key.custom_hook = lambda: "deepspeed_tpu scopes " + digest
 
 
 def claims_chips(env) -> bool:

@@ -5,19 +5,14 @@ and the accelerator ``range_push/range_pop`` surface: on TPU the profiler is XLA
 ranges become ``jax.profiler.TraceAnnotation`` named scopes, visible in TensorBoard's
 trace viewer / Perfetto exactly where NVTX ranges land in Nsight.
 
-Host-side ranges around a dispatch (prefill, decode chunk, train step) are
-not made here: ``observability.trace.Tracer.span`` opens the
-``TraceAnnotation`` itself, with the span's attributes, so every instrumented
-region is one call under one name. What stays here:
-
-- :func:`named_scope` — IN-GRAPH ``jax.named_scope`` around traced collectives
-  (``parallel/overlap.py`` rings, quantized allreduce): the name lands in XLA
-  op metadata, so the device ops themselves carry the call-site label;
-- :func:`instrument_w_nvtx`, :func:`range_push` / :func:`range_pop` — the
-  reference's decorator and accelerator surface.
-
-All are no-ops cheap enough for hot paths when no profiler is capturing
-(``TraceMe`` checks an atomic; ``named_scope`` only exists at trace time).
+Neither the program's host spans nor its device regions are made here:
+``observability.trace.Tracer.span`` opens the ``TraceAnnotation`` of a host
+region itself, with the span's attributes, and ``observability.scope`` names
+the ops inside a compiled program, each under a name declared once in
+``observability/schema.py``. What stays here is the reference's decorator and
+accelerator surface, :func:`instrument_w_nvtx` and :func:`range_push` /
+:func:`range_pop`: no-ops cheap enough for hot paths when no profiler is
+capturing (``TraceMe`` checks an atomic).
 """
 
 import functools
@@ -25,11 +20,6 @@ import threading
 from typing import Callable
 
 import jax
-
-
-def named_scope(name: str):
-    """Trace-time op-metadata scope for in-graph regions (collectives)."""
-    return jax.named_scope(name)
 
 
 def instrument_w_nvtx(func: Callable) -> Callable:
