@@ -23,8 +23,9 @@ import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
 
 from ..observability import scope
-from ..ops.attention.flash import FLASH_LSE_NAME, FLASH_OUT_NAME
-from ..ops.transformer.attention import get_attention_impl
+from ..ops.attention.flash import (FLASH_LSE_NAME, FLASH_OUT_NAME,
+                                   flash_attention_qkv)
+from ..ops.transformer.attention import flash_reads_fused_qkv, get_attention_impl
 from .base import Model
 
 
@@ -98,6 +99,10 @@ class Block(nn.Module):
         attn = get_attention_impl(cfg.attention_impl)
         with scope("norm"):
             h = nn.LayerNorm(dtype=jnp.float32, name="ln_1")(x).astype(cfg.dtype)
+        drop = 0.0 if deterministic else cfg.dropout
+        b, t, _ = h.shape
+        fused = not cfg.split_qkv and flash_reads_fused_qkv(
+            cfg.attention_impl, t, cfg.n_head, cfg.head_dim, drop)
         with scope("attn.qkv"):
             if cfg.split_qkv:
                 q = nn.Dense(cfg.n_embd, dtype=cfg.dtype, name="q_attn",
@@ -109,20 +114,22 @@ class Block(nn.Module):
             else:
                 qkv = nn.Dense(3 * cfg.n_embd, dtype=cfg.dtype, name="c_attn",
                                kernel_init=nn.initializers.normal(cfg.init_std))(h)
-                q, k, v = jnp.split(qkv, 3, axis=-1)
-        b, t, _ = q.shape
-        with scope("attn.heads"):
-            q = q.reshape(b, t, cfg.n_head, cfg.head_dim)
-            k = k.reshape(b, t, cfg.n_head, cfg.head_dim)
-            v = v.reshape(b, t, cfg.n_head, cfg.head_dim)
-        drop_rng = (None if deterministic or cfg.dropout == 0.0
-                    else self.make_rng("dropout"))
-        with scope("attn.core"):
-            o = attn(q, k, v, causal=True,
-                     dropout_rate=0.0 if deterministic else cfg.dropout,
-                     dropout_rng=drop_rng)
-        with scope("attn.heads"):
-            o = o.reshape(b, t, cfg.n_embd)
+                if not fused:
+                    q, k, v = jnp.split(qkv, 3, axis=-1)
+        if fused:
+            # the kernels read q, k, v where c_attn wrote them: no split, no copy
+            with scope("attn.core"):
+                o = flash_attention_qkv(qkv, cfg.n_head, causal=True)
+        else:
+            with scope("attn.heads"):
+                q = q.reshape(b, t, cfg.n_head, cfg.head_dim)
+                k = k.reshape(b, t, cfg.n_head, cfg.head_dim)
+                v = v.reshape(b, t, cfg.n_head, cfg.head_dim)
+            drop_rng = None if drop == 0.0 else self.make_rng("dropout")
+            with scope("attn.core"):
+                o = attn(q, k, v, causal=True, dropout_rate=drop, dropout_rng=drop_rng)
+            with scope("attn.heads"):
+                o = o.reshape(b, t, cfg.n_embd)
         # scaled init on residual-writing projections (GPT-2 scheme)
         proj_init = nn.initializers.normal(cfg.init_std / (2 * cfg.n_layer) ** 0.5)
         with scope("attn.out"):
@@ -230,12 +237,8 @@ def block_tp_apply(cfg: GPT2Config, tp: int, axis: str,
             from ..ops.attention.ring import allgather_attention_local
             return allgather_attention_local(q, k, v, causal=True,
                                              axis_name=sp_axis)
-        from ..ops.transformer.attention import FLASH_MIN_SEQ, xla_attention
-        t = q.shape[1]
-        use_flash = (impl == "flash" or
-                     (impl == "auto" and jax.default_backend() == "tpu"
-                      and t >= FLASH_MIN_SEQ and t % 128 == 0))
-        if use_flash:
+        from ..ops.transformer.attention import resolves_to_flash, xla_attention
+        if resolves_to_flash(impl, q.shape[1]):
             from ..ops.attention.flash import flash_attention_local
             return flash_attention_local(q, k, v, causal=True)
         return xla_attention(q, k, v, causal=True)

@@ -28,6 +28,25 @@ over q blocks × kv blocks, dk/dv kernel over kv blocks × q blocks, the latter 
 scores keys-by-queries so no tile is transposed) — no stored attention matrix, matching
 the activation-memory profile that makes long sequences feasible.
 
+Operand layout. A ``(b, t, h, d)`` array is, bit for bit, ``(b, t, h*d)``: the kernels take
+q, k, v (and write o, dq, dk, dv) in that flat layout, where the projection before them wrote
+it and the one after them reads it, and pick a head by a block index on the LAST axis
+(grid ``(b, lane groups, q blocks, kv blocks)``), not by a transpose before the call.
+Which head shapes take which branch (:func:`heads_a_block`):
+
+- ``d % 128 == 0`` (BLOOM, the hybrid, SDAR): a lane group is one head, the body as below;
+- ``d < 128``, ``128 % d == 0`` and ``h % (128 // d) == 0`` (GPT-2: d 64, 12 heads): a lane
+  group is 128 lanes = ``128 // d`` heads. The body takes them in turn: the operand of a
+  contraction over lanes is masked to the head's ``d`` lanes (zeros add nothing; the MXU
+  passes are those of the half-filled contraction), and of a product whose RESULT has the
+  128 lanes the head's are kept. No tile is sliced inside a lane tile, none is transposed;
+- any other head size, or a head count the lane tile does not divide: the transposing
+  ``(b*h, t, d)`` call, which is the same specs with ``b -> b*h`` and one lane group.
+
+:func:`flash_attention_qkv` reads a fused projection ``(b, t, 3*h*d)`` = q | k | v as ONE
+operand: the same index maps with a lane offset (k at group ``G + g``, v at ``2G + g``), so
+no split copies the three out. ``lse`` and ``delta`` stay rows-along-lanes, a row set a head.
+
 On CPU (tests) kernels run in interpreter mode automatically.
 """
 
@@ -46,7 +65,8 @@ from ...utils.jax_compat import shard_map
 
 NEG_INF = -1e30
 # ``checkpoint_name`` tags of the forward rule's two residuals that come out of no
-# matmul: the kernel's output (b*h, t, d) and its log-sum-exp (b*h, t), float32
+# matmul: the kernel's output, in the layout of its operands ((b, t, h*d) flat,
+# (b*h, t, d) else), and its log-sum-exp (rows, heads in the lanes, t), float32
 FLASH_OUT_NAME = "flash_out"
 FLASH_LSE_NAME = "flash_lse"
 
@@ -77,27 +97,62 @@ def _below_diagonal(q_idx, k_idx, bq, bk):
     return (k_idx + 1) * bk - 1 <= q_idx * bq
 
 
-def _k_index_map(causal, bq, bk):
+def heads_a_block(h: int, d: int) -> int:
+    """Heads one kernel block holds where ``h`` heads of ``d`` lie flat in the lanes
+    ``(b, t, h*d)``: 1 where a head is whole lane tiles, ``128 // d`` where heads fill
+    one tile, 0 where lane blocks cannot address a head (the ``(b*h, t, d)`` call)."""
+    if d % 128 == 0:
+        return 1
+    if 128 % d == 0 and h % (128 // d) == 0:
+        return 128 // d
+    return 0
+
+
+def _lane_groups(lanes: int, d: int):
+    """(block width, lane groups) of an operand whose heads of ``d`` span ``lanes``:
+    one head a block where a head is whole tiles or the only one, else 128 lanes."""
+    width = d if d % 128 == 0 or lanes == d else 128
+    return width, lanes // width
+
+
+# Grids are (rows, lane group, outer block, inner block): outer = q block and inner =
+# kv block in fwd and dq, the other way round in dkv. ``off`` is the operand's first
+# lane group: 0, or where k and v start in a fused q | k | v projection.
+def _outer_map(off=0):
+    return lambda i, g, a, c: (i, a, off + g)
+
+
+def _stat_map(i, g, a, c):
+    """lse / delta (rows, heads, q blocks, 8, bq) in fwd and dq."""
+    return (i, g, a, 0, 0)
+
+
+def _slopes_map(fold):
+    """alibi slopes (h, 8, 128) by the heads of lane group ``g``; ``fold`` = h where
+    the heads are folded into the rows (``i = bi*h + hi``, one group), else 1."""
+    return lambda i, g, a, c: (i % fold + g, 0, 0)
+
+
+def _k_index_map(causal, bq, bk, off=0):
     """kv-block index map: under causality, blocks above the diagonal clamp to the
     last needed block — same index as the previous grid step, so the pipeline skips
     the copy while ``pl.when`` skips the compute. Shared by fwd and bwd-dq so the
     two cannot drift."""
-    def k_index(i, j, kb):
+    def k_index(i, g, j, kb):
         if causal:
-            return (i, jnp.minimum(kb, _causal_k_hi(j, bq, bk)), 0)
-        return (i, kb, 0)
+            kb = jnp.minimum(kb, _causal_k_hi(j, bq, bk))
+        return (i, kb, off + g)
     return k_index
 
 
-def _q_index_map(causal, bq, bk, extra_dims=0):
-    """q/lse-block index map for the dkv kernel: q blocks strictly above the causal
-    diagonal clamp forward to the first contributing block (no copy, no compute)."""
-    tail = (0,) * (1 + extra_dims)
-
-    def q_index(i, kb, qb):
+def _q_index_map(causal, bq, bk, off=None):
+    """q-block index map for the dkv kernel, or (``off`` None) its lse/delta one: q
+    blocks strictly above the causal diagonal clamp forward to the first contributing
+    block (no copy, no compute)."""
+    def q_index(i, g, kb, qb):
         if causal:
-            return (i, jnp.maximum(qb, _causal_q_lo(kb, bq, bk))) + tail
-        return (i, qb) + tail
+            qb = jnp.maximum(qb, _causal_q_lo(kb, bq, bk))
+        return (i, g, qb, 0, 0) if off is None else (i, qb, off + g)
     return q_index
 
 
@@ -161,6 +216,27 @@ def _dot(a, b, contract):
     rate — an f32 upcast would halve matmul throughput)."""
     return jax.lax.dot_general(a, b, ((contract[:1], contract[1:]), ((), ())),
                                preferred_element_type=jnp.float32)
+
+
+def _head_lanes(x, hh, d):
+    """``x`` (rows, lanes) with every lane outside head ``hh``'s ``d`` zeroed: as an
+    operand of a contraction over the lanes it gives that head's product alone.
+    ``x`` itself where the block is one head."""
+    if x.shape[-1] == d:
+        return x
+    lane = jax.lax.broadcasted_iota(jnp.int32, x.shape, 1)
+    return jnp.where((lane >= hh * d) & (lane < (hh + 1) * d), x, jnp.zeros_like(x))
+
+
+def _by_head(xs, d, width):
+    """(rows, width) whose lanes of head ``hh`` are ``xs[hh]``'s, each (rows, width)
+    or a (rows, 1) column; ``xs[0]`` itself where the block is one head."""
+    out = xs[-1]
+    if len(xs) > 1:
+        lane = jax.lax.broadcasted_iota(jnp.int32, (out.shape[0], width), 1)
+        for hh in range(len(xs) - 2, -1, -1):
+            out = jnp.where(lane < (hh + 1) * d, xs[hh], out)
+    return out
 
 
 def _scores(rows, cols, scale, slope, off, masked, key_axis, mask_block=1):
@@ -231,12 +307,14 @@ def _walk(causal, q_idx, k_idx, nq, bq, bk, by_cols, sb, strip, commit):
 
 
 # ----------------------------------------------------------------------- forward kernel
-def _fwd_kernel(*refs, scale, causal, use_alibi, nq, nk, bq, bk, mask_block=1):
+def _fwd_kernel(*refs, d, scale, causal, use_alibi, nq, nk, bq, bk, mask_block=1):
     q_ref, k_ref, v_ref = refs[:3]
     slopes_ref = refs[3] if use_alibi else None
     o_ref, lse_ref, *scratch = refs[4 if use_alibi else 3:]
-    j = pl.program_id(1)
-    kb = pl.program_id(2)
+    width = o_ref.shape[-1]
+    heads = range(width // d)
+    j = pl.program_id(2)
+    kb = pl.program_id(3)
     if nk > 1:
         m_scr, l_scr, acc_scr = scratch
 
@@ -248,102 +326,128 @@ def _fwd_kernel(*refs, scale, causal, use_alibi, nq, nk, bq, bk, mask_block=1):
 
     def strip(r0, nr, parts, base_off):
         """Online-softmax step of q rows [r0, r0+nr) over the key columns in
-        ``parts``: (new running max, row sum and unnormalised output of these
-        columns against it), the statistics as (nr, 1) columns — one max for the
-        strip, not one per tile."""
-        slope = slopes_ref[0, 0, 0] if use_alibi else None
+        ``parts``, a head of the block at a time: (new running max and row sum of
+        these columns against it, a list entry a head, as (nr, 1) columns — one max
+        for the strip, not one per tile; unnormalised output, every head in its
+        lanes)."""
         q = q_ref[0, r0:r0 + nr, :]
-        ss = [_scores(q, k_ref[0, c0:c0 + nc, :], scale, slope,
-                      base_off + r0 - c0, masked, 1, mask_block)
-              for c0, nc, masked in parts]
-        # one kv block holds every key of its rows: no running max to start from
-        m = None if nk == 1 else m_scr[r0:r0 + nr, :]
-        for s in ss:
-            s_max = s.max(axis=-1, keepdims=True)
-            m = s_max if m is None else jnp.maximum(m, s_max)
-        l = acc = None
-        for s, (c0, nc, _) in zip(ss, parts):
-            v = v_ref[0, c0:c0 + nc, :]
-            p = jnp.exp(s - m)
-            pv = _dot(p.astype(v.dtype), v, (1, 0))
-            p_sum = p.sum(axis=-1, keepdims=True)
-            l = p_sum if l is None else l + p_sum
-            acc = pv if acc is None else acc + pv
-        return m, l, acc
+        ms, ls, accs = [], [], []
+        for hh in heads:
+            slope = slopes_ref[hh, 0, 0] if use_alibi else None
+            qh = _head_lanes(q, hh, d)
+            ss = [_scores(qh, k_ref[0, c0:c0 + nc, :], scale, slope,
+                          base_off + r0 - c0, masked, 1, mask_block)
+                  for c0, nc, masked in parts]
+            # one kv block holds every key of its rows: no running max to start from
+            m = None if nk == 1 else m_scr[hh, r0:r0 + nr, :]
+            for s in ss:
+                s_max = s.max(axis=-1, keepdims=True)
+                m = s_max if m is None else jnp.maximum(m, s_max)
+            l = acc = None
+            for s, (c0, nc, _) in zip(ss, parts):
+                v = v_ref[0, c0:c0 + nc, :]
+                p = jnp.exp(s - m)
+                pv = _dot(p.astype(v.dtype), v, (1, 0))     # every head's lanes of v
+                p_sum = p.sum(axis=-1, keepdims=True)
+                l = p_sum if l is None else l + p_sum
+                acc = pv if acc is None else acc + pv
+            ms.append(m)
+            ls.append(l)
+            accs.append(acc)
+        return ms, ls, _by_head(accs, d, width)
 
-    def write(r0, nr, m, l, acc):
-        l_safe = jnp.where(l > 0, l, 1.0)
-        o_ref[0, r0:r0 + nr, :] = (acc / l_safe).astype(o_ref.dtype)
-        # lse stored (bh, nq, 8, bq), rows along lanes: TPU block tiling needs the
-        # last two dims (sublane, lane) aligned; the 8 duplicate sublanes keep the
-        # layout legal
-        lse = (m + jnp.log(l_safe))[:, 0]
-        lse_ref[0, 0, :, r0:r0 + nr] = jnp.broadcast_to(lse[None, :], (8, nr))
+    def write(r0, nr, ms, ls, acc):
+        ls = [jnp.where(l > 0, l, 1.0) for l in ls]
+        o_ref[0, r0:r0 + nr, :] = (acc / _by_head(ls, d, width)).astype(o_ref.dtype)
+        # lse stored (rows, heads, nq, 8, bq), q rows along lanes: TPU block tiling
+        # needs the last two dims (sublane, lane) aligned; the 8 duplicate sublanes
+        # keep the layout legal
+        for hh in heads:
+            lse = (ms[hh] + jnp.log(ls[hh]))[:, 0]
+            lse_ref[0, hh, 0, :, r0:r0 + nr] = jnp.broadcast_to(lse[None, :], (8, nr))
 
     if nk == 1:
         _walk(causal, j, kb, nq, bq, bk, False, FWD_STRIP, strip, write)
         return
 
-    def carry(r0, nr, m, l, acc):
+    def carry(r0, nr, ms, ls, acc):
         """Rescale the rows' running sums to their new max and add the strip's."""
         rows = slice(r0, r0 + nr)
-        alpha = jnp.exp(m_scr[rows, :] - m)
-        acc_scr[rows, :] = alpha * acc_scr[rows, :] + acc
-        l_scr[rows, :] = alpha * l_scr[rows, :] + l
-        m_scr[rows, :] = m
+        alphas = []
+        for hh in heads:
+            alpha = jnp.exp(m_scr[hh, rows, :] - ms[hh])
+            l_scr[hh, rows, :] = alpha * l_scr[hh, rows, :] + ls[hh]
+            m_scr[hh, rows, :] = ms[hh]
+            alphas.append(alpha)
+        acc_scr[rows, :] = _by_head(alphas, d, width) * acc_scr[rows, :] + acc
 
     _walk(causal, j, kb, nq, bq, bk, False, FWD_STRIP, strip, carry)
 
     @pl.when(kb == nk - 1)
     def _finalize():
-        write(0, bq, m_scr[...], l_scr[...], acc_scr[...])
+        write(0, bq, [m_scr[hh] for hh in heads], [l_scr[hh] for hh in heads],
+              acc_scr[...])
 
 
-def _flash_fwd(q3, k3, v3, slopes3, scale, causal, block_q, block_k, mask_block=1):
-    """q3/k3/v3: (bh, t, d); slopes3: per-(b·h) alibi slopes broadcast to
-    (bh, 8, 128) for lane alignment, or None. Returns (o3, lse (bh, t))."""
-    bh, t, d = q3.shape
+def _compiler_params(hpb: int):
+    """Several heads a block keep their intermediates live side by side and a (bq, 1)
+    column of running max and sum each, a lane tile wide in VMEM: float32 operands
+    pass the 16 MiB default from 2k tokens on (19.6 MiB at four heads of 32)."""
+    return pltpu.CompilerParams(
+        dimension_semantics=("parallel", "parallel", "arbitrary", "arbitrary"),
+        vmem_limit_bytes=32 * 2 ** 20 if hpb > 1 else None)
+
+
+def _flash_fwd(q, k, v, slopes, fused, d, scale, causal, block_q, block_k,
+               mask_block=1):
+    """q/k/v: (rows, t, lanes) holding ``lanes // d`` heads of ``d`` each — (b, t, h*d)
+    flat or (b*h, t, d) — or, ``fused``, ONE (b, t, 3*lanes) array passed three times
+    and read at q | k | v's lane offsets. ``slopes``: alibi slopes (h, 8, 128), the
+    value duplicated for lane alignment, or None. Returns (o (rows, t, lanes),
+    lse (rows, heads, t))."""
+    rows, t, lanes = q.shape
+    lanes //= 3 if fused else 1
+    width, groups = _lane_groups(lanes, d)
+    hpb = width // d
     bq, bk = _block_sizes(t, block_q, block_k)
     nq, nk = t // bq, t // bk
-    grid = (bh, nq, nk)
-    use_alibi = slopes3 is not None
+    use_alibi = slopes is not None
 
-    k_index = _k_index_map(causal, bq, bk)
-    kernel = functools.partial(_fwd_kernel, scale=scale, causal=causal,
+    kernel = functools.partial(_fwd_kernel, d=d, scale=scale, causal=causal,
                                use_alibi=use_alibi, nq=nq, nk=nk, bq=bq, bk=bk,
                                mask_block=mask_block)
     in_specs = [
-        pl.BlockSpec((1, bq, d), lambda i, j, kb: (i, j, 0)),
-        pl.BlockSpec((1, bk, d), k_index),
-        pl.BlockSpec((1, bk, d), k_index),
+        pl.BlockSpec((1, bq, width), _outer_map()),
+        pl.BlockSpec((1, bk, width), _k_index_map(causal, bq, bk, fused * groups)),
+        pl.BlockSpec((1, bk, width), _k_index_map(causal, bq, bk, 2 * fused * groups)),
     ]
-    args = [q3, k3, v3]
+    args = [q, k, v]
     if use_alibi:
-        in_specs.append(pl.BlockSpec((1, 8, 128), lambda i, j, kb: (i, 0, 0)))
-        args.append(slopes3)
-    o3, lse = pl.pallas_call(
+        in_specs.append(pl.BlockSpec((hpb, 8, 128),
+                                     _slopes_map(slopes.shape[0] * d // lanes)))
+        args.append(slopes)
+    o, lse = pl.pallas_call(
         kernel,
-        grid=grid,
+        grid=(rows, groups, nq, nk),
         in_specs=in_specs,
         out_specs=[
-            pl.BlockSpec((1, bq, d), lambda i, j, kb: (i, j, 0)),
-            pl.BlockSpec((1, 1, 8, bq), lambda i, j, kb: (i, j, 0, 0)),
+            pl.BlockSpec((1, bq, width), _outer_map()),
+            pl.BlockSpec((1, hpb, 1, 8, bq), _stat_map),
         ],
         out_shape=[
-            jax.ShapeDtypeStruct((bh, t, d), q3.dtype),
-            jax.ShapeDtypeStruct((bh, nq, 8, bq), jnp.float32),
+            jax.ShapeDtypeStruct((rows, t, lanes), q.dtype),
+            jax.ShapeDtypeStruct((rows, lanes // d, nq, 8, bq), jnp.float32),
         ],
         scratch_shapes=[] if nk == 1 else [
-            pltpu.VMEM((bq, 1), jnp.float32),         # m
-            pltpu.VMEM((bq, 1), jnp.float32),         # l
-            pltpu.VMEM((bq, d), jnp.float32),         # acc
+            pltpu.VMEM((hpb, bq, 1), jnp.float32),    # m
+            pltpu.VMEM((hpb, bq, 1), jnp.float32),    # l
+            pltpu.VMEM((bq, width), jnp.float32),     # acc
         ],
-        compiler_params=pltpu.CompilerParams(
-            dimension_semantics=("parallel", "arbitrary", "arbitrary")),
+        compiler_params=_compiler_params(hpb),
         name="flash_fwd",
         interpret=_interpret(),
     )(*args)
-    return o3, lse[:, :, 0, :].reshape(bh, t)
+    return o, lse[:, :, :, 0, :].reshape(rows, lanes // d, t)
 
 
 # ---------------------------------------------------------------------- backward kernels
@@ -376,189 +480,208 @@ def _summed(out_refs, scratch, scales, step, n_steps, walk):
         store(0, out_refs[0].shape[1], *(scr[...] for scr in scratch))
 
 
-def _bwd_dq_kernel(*refs, scale, causal, use_alibi, nq, nk, bq, bk, mask_block=1):
+def _bwd_dq_kernel(*refs, d, scale, causal, use_alibi, nq, nk, bq, bk, mask_block=1):
     q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref = refs[:6]
     slopes_ref = refs[6] if use_alibi else None
     dq_ref, *scratch = refs[7 if use_alibi else 6:]
-    j = pl.program_id(1)
-    kb = pl.program_id(2)
+    width = dq_ref.shape[-1]
+    j = pl.program_id(2)
+    kb = pl.program_id(3)
 
     def strip(r0, nr, parts, base_off):
         # the recomputed s is bit-identical to the s the forward derived lse from:
         # same operands, same matmul policy
-        slope = slopes_ref[0, 0, 0] if use_alibi else None
         q = q_ref[0, r0:r0 + nr, :]
         do = do_ref[0, r0:r0 + nr, :]
-        lse = lse_ref[0, 0, 0, r0:r0 + nr][:, None]
-        delta = delta_ref[0, 0, 0, r0:r0 + nr][:, None]
-        dq = None
-        for c0, nc, masked in parts:
-            k = k_ref[0, c0:c0 + nc, :]
-            v = v_ref[0, c0:c0 + nc, :]
-            s = _scores(q, k, scale, slope, base_off + r0 - c0, masked, 1,
-                        mask_block)
-            p = jnp.exp(s - lse)                               # true probs
-            dp = _dot(do, v, (1, 1))
-            # ds without its factor ``scale``: applied to the (bq, d) result
-            ds = (p * (dp - delta)).astype(k.dtype)
-            part = _dot(ds, k, (1, 0))
-            dq = part if dq is None else dq + part
-        return (dq,)
+        dqs = []
+        for hh in range(width // d):
+            slope = slopes_ref[hh, 0, 0] if use_alibi else None
+            qh = _head_lanes(q, hh, d)
+            doh = _head_lanes(do, hh, d)
+            lse = lse_ref[0, hh, 0, 0, r0:r0 + nr][:, None]
+            delta = delta_ref[0, hh, 0, 0, r0:r0 + nr][:, None]
+            dq = None
+            for c0, nc, masked in parts:
+                k = k_ref[0, c0:c0 + nc, :]
+                v = v_ref[0, c0:c0 + nc, :]
+                s = _scores(qh, k, scale, slope, base_off + r0 - c0, masked, 1,
+                            mask_block)
+                p = jnp.exp(s - lse)                           # true probs
+                dp = _dot(doh, v, (1, 1))
+                # ds without its factor ``scale``: applied to the (bq, lanes) result
+                ds = (p * (dp - delta)).astype(k.dtype)
+                part = _dot(ds, k, (1, 0))                     # every head's lanes of k
+                dq = part if dq is None else dq + part
+            dqs.append(dq)
+        return (_by_head(dqs, d, width),)
 
     _summed((dq_ref,), scratch, (scale,), kb, nk, lambda commit: _walk(
         causal, j, kb, nq, bq, bk, False, BWD_STRIP, strip, commit))
 
 
-def _bwd_dkv_kernel(*refs, scale, causal, use_alibi, nq, bq, bk, mask_block=1):
+def _bwd_dkv_kernel(*refs, d, scale, causal, use_alibi, nq, bq, bk, mask_block=1):
     q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref = refs[:6]
     slopes_ref = refs[6] if use_alibi else None
     dk_ref, dv_ref, *scratch = refs[7 if use_alibi else 6:]
-    kb = pl.program_id(1)
-    qb = pl.program_id(2)
+    width = dk_ref.shape[-1]
+    kb = pl.program_id(2)
+    qb = pl.program_id(3)
 
     def strip(c0, nc, parts, base_off):
         """dk, dv of key columns [c0, c0+nc) from the q rows in ``parts``. Scores are
         formed keys-by-queries, so lse and delta (rows along lanes) broadcast as they
         are stored and every matmul takes its operands as they lie — no transpose of
         a (keys x queries) tile."""
-        slope = slopes_ref[0, 0, 0] if use_alibi else None
         k = k_ref[0, c0:c0 + nc, :]
         v = v_ref[0, c0:c0 + nc, :]
-        dk = dv = None
-        for r0, nr, masked in parts:
-            q = q_ref[0, r0:r0 + nr, :]
-            do = do_ref[0, r0:r0 + nr, :]
-            lse = lse_ref[0, 0, 0:1, r0:r0 + nr]               # (1, nr)
-            delta = delta_ref[0, 0, 0:1, r0:r0 + nr]
-            st = _scores(k, q, scale, slope, base_off + r0 - c0, masked, 0,
-                         mask_block)
-            pt = jnp.exp(st - lse)                             # (nc, nr)
-            dpt = _dot(v, do, (1, 1))
-            dst = (pt * (dpt - delta)).astype(q.dtype)         # see _bwd_dq_kernel
-            dv_part = _dot(pt.astype(do.dtype), do, (1, 0))
-            dk_part = _dot(dst, q, (1, 0))
-            dv = dv_part if dv is None else dv + dv_part
-            dk = dk_part if dk is None else dk + dk_part
-        return dk, dv
+        dks, dvs = [], []
+        for hh in range(width // d):
+            slope = slopes_ref[hh, 0, 0] if use_alibi else None
+            kh = _head_lanes(k, hh, d)
+            vh = _head_lanes(v, hh, d)
+            dk = dv = None
+            for r0, nr, masked in parts:
+                q = q_ref[0, r0:r0 + nr, :]
+                do = do_ref[0, r0:r0 + nr, :]
+                lse = lse_ref[0, hh, 0, 0:1, r0:r0 + nr]       # (1, nr)
+                delta = delta_ref[0, hh, 0, 0:1, r0:r0 + nr]
+                st = _scores(kh, q, scale, slope, base_off + r0 - c0, masked, 0,
+                             mask_block)
+                pt = jnp.exp(st - lse)                         # (nc, nr)
+                dpt = _dot(vh, do, (1, 1))
+                dst = (pt * (dpt - delta)).astype(q.dtype)     # see _bwd_dq_kernel
+                dv_part = _dot(pt.astype(do.dtype), do, (1, 0))
+                dk_part = _dot(dst, q, (1, 0))
+                dv = dv_part if dv is None else dv + dv_part
+                dk = dk_part if dk is None else dk + dk_part
+            dks.append(dk)
+            dvs.append(dv)
+        return _by_head(dks, d, width), _by_head(dvs, d, width)
 
     _summed((dk_ref, dv_ref), scratch, (scale, 1.0), qb, nq, lambda commit: _walk(
         causal, qb, kb, nq, bq, bk, True, BWD_STRIP, strip, commit))
 
 
-def _flash_bwd(q3, k3, v3, o3, lse, do3, slopes3, scale, causal, block_q, block_k,
-               mask_block=1):
-    bh, t, d = q3.shape
+def _flash_bwd(q, k, v, o, lse, do, slopes, fused, d, scale, causal, block_q,
+               block_k, mask_block=1):
+    """Operands as :func:`_flash_fwd`'s; ``o``/``do`` (rows, t, lanes), ``lse`` (rows,
+    heads, t). Returns dq, dk, dv, each (rows, t, lanes)."""
+    rows, t, lanes = o.shape
+    width, groups = _lane_groups(lanes, d)
+    hpb, heads = width // d, lanes // d
     bq, bk = _block_sizes(t, block_q, block_k)
     nq, nk = t // bq, t // bk
-    use_alibi = slopes3 is not None
-    delta = jnp.sum(do3.astype(jnp.float32) * o3.astype(jnp.float32), axis=-1)  # (bh, t)
-    lse_b = jnp.broadcast_to(lse.reshape(bh, nq, 1, bq), (bh, nq, 8, bq))
-    delta_b = jnp.broadcast_to(delta.reshape(bh, nq, 1, bq), (bh, nq, 8, bq))
-
-    k_index = _k_index_map(causal, bq, bk)
-    dq_in_specs = [
-        pl.BlockSpec((1, bq, d), lambda i, j, kb: (i, j, 0)),
-        pl.BlockSpec((1, bk, d), k_index),
-        pl.BlockSpec((1, bk, d), k_index),
-        pl.BlockSpec((1, bq, d), lambda i, j, kb: (i, j, 0)),
-        pl.BlockSpec((1, 1, 8, bq), lambda i, j, kb: (i, j, 0, 0)),
-        pl.BlockSpec((1, 1, 8, bq), lambda i, j, kb: (i, j, 0, 0)),
-    ]
-    dq_args = [q3, k3, v3, do3, lse_b, delta_b]
+    use_alibi = slopes is not None
+    delta = jnp.sum((do.astype(jnp.float32) * o.astype(jnp.float32)).reshape(
+        rows, t, heads, d), axis=-1).transpose(0, 2, 1)          # (rows, heads, t)
+    stat_shape = (rows, heads, nq, 8, bq)
+    lse_b = jnp.broadcast_to(lse.reshape(rows, heads, nq, 1, bq), stat_shape)
+    delta_b = jnp.broadcast_to(delta.reshape(rows, heads, nq, 1, bq), stat_shape)
+    k_off, v_off = fused * groups, 2 * fused * groups
+    args = [q, k, v, do, lse_b, delta_b]
+    slopes_spec = []
     if use_alibi:
-        dq_in_specs.append(pl.BlockSpec((1, 8, 128), lambda i, j, kb: (i, 0, 0)))
-        dq_args.append(slopes3)
+        slopes_spec = [pl.BlockSpec((hpb, 8, 128),
+                                    _slopes_map(slopes.shape[0] * d // lanes))]
+        args.append(slopes)
+    params = dict(d=d, scale=scale, causal=causal, use_alibi=use_alibi, nq=nq, bq=bq,
+                  bk=bk, mask_block=mask_block)
+    q_block, k_block = (1, bq, width), (1, bk, width)
+    stat_block = (1, hpb, 1, 8, bq)
+    sds = jax.ShapeDtypeStruct((rows, t, lanes), o.dtype)
+    compiler_params = _compiler_params(hpb)
+
     dq = pl.pallas_call(
-        functools.partial(_bwd_dq_kernel, scale=scale, causal=causal,
-                          use_alibi=use_alibi, nq=nq, nk=nk, bq=bq, bk=bk,
-                          mask_block=mask_block),
-        grid=(bh, nq, nk),
-        in_specs=dq_in_specs,
-        out_specs=pl.BlockSpec((1, bq, d), lambda i, j, kb: (i, j, 0)),
-        out_shape=jax.ShapeDtypeStruct((bh, t, d), q3.dtype),
-        scratch_shapes=[] if nk == 1 else [pltpu.VMEM((bq, d), jnp.float32)],
-        compiler_params=pltpu.CompilerParams(
-            dimension_semantics=("parallel", "arbitrary", "arbitrary")),
+        functools.partial(_bwd_dq_kernel, nk=nk, **params),
+        grid=(rows, groups, nq, nk),
+        in_specs=[
+            pl.BlockSpec(q_block, _outer_map()),
+            pl.BlockSpec(k_block, _k_index_map(causal, bq, bk, k_off)),
+            pl.BlockSpec(k_block, _k_index_map(causal, bq, bk, v_off)),
+            pl.BlockSpec(q_block, _outer_map()),
+            pl.BlockSpec(stat_block, _stat_map),
+            pl.BlockSpec(stat_block, _stat_map),
+        ] + slopes_spec,
+        out_specs=pl.BlockSpec(q_block, _outer_map()),
+        out_shape=sds,
+        scratch_shapes=[] if nk == 1 else [pltpu.VMEM((bq, width), jnp.float32)],
+        compiler_params=compiler_params,
         name="flash_bwd_dq",
         interpret=_interpret(),
-    )(*dq_args)
+    )(*args)
 
-    q_index = _q_index_map(causal, bq, bk)
-    lse_index = _q_index_map(causal, bq, bk, extra_dims=1)
-    dkv_in_specs = [
-        pl.BlockSpec((1, bq, d), q_index),
-        pl.BlockSpec((1, bk, d), lambda i, kb, qb: (i, kb, 0)),
-        pl.BlockSpec((1, bk, d), lambda i, kb, qb: (i, kb, 0)),
-        pl.BlockSpec((1, bq, d), q_index),
-        pl.BlockSpec((1, 1, 8, bq), lse_index),
-        pl.BlockSpec((1, 1, 8, bq), lse_index),
-    ]
-    dkv_args = [q3, k3, v3, do3, lse_b, delta_b]
-    if use_alibi:
-        dkv_in_specs.append(pl.BlockSpec((1, 8, 128), lambda i, kb, qb: (i, 0, 0)))
-        dkv_args.append(slopes3)
+    stat_index = _q_index_map(causal, bq, bk)
     dk, dv = pl.pallas_call(
-        functools.partial(_bwd_dkv_kernel, scale=scale, causal=causal,
-                          use_alibi=use_alibi, nq=nq, bq=bq, bk=bk,
-                          mask_block=mask_block),
-        grid=(bh, nk, nq),
-        in_specs=dkv_in_specs,
-        out_specs=[
-            pl.BlockSpec((1, bk, d), lambda i, kb, qb: (i, kb, 0)),
-            pl.BlockSpec((1, bk, d), lambda i, kb, qb: (i, kb, 0)),
-        ],
-        out_shape=[
-            jax.ShapeDtypeStruct((bh, t, d), k3.dtype),
-            jax.ShapeDtypeStruct((bh, t, d), v3.dtype),
-        ],
-        scratch_shapes=[] if nq == 1 else [pltpu.VMEM((bk, d), jnp.float32)] * 2,
-        compiler_params=pltpu.CompilerParams(
-            dimension_semantics=("parallel", "arbitrary", "arbitrary")),
+        functools.partial(_bwd_dkv_kernel, **params),
+        grid=(rows, groups, nk, nq),
+        in_specs=[
+            pl.BlockSpec(q_block, _q_index_map(causal, bq, bk, 0)),
+            pl.BlockSpec(k_block, _outer_map(k_off)),
+            pl.BlockSpec(k_block, _outer_map(v_off)),
+            pl.BlockSpec(q_block, _q_index_map(causal, bq, bk, 0)),
+            pl.BlockSpec(stat_block, stat_index),
+            pl.BlockSpec(stat_block, stat_index),
+        ] + slopes_spec,
+        out_specs=[pl.BlockSpec(k_block, _outer_map())] * 2,
+        out_shape=[sds, sds],
+        scratch_shapes=[] if nq == 1 else [pltpu.VMEM((bk, width), jnp.float32)] * 2,
+        compiler_params=compiler_params,
         name="flash_bwd_dkv",
         interpret=_interpret(),
-    )(*dkv_args)
+    )(*args)
     return dq, dk, dv
 
 
 # --------------------------------------------------------------------------- public op
-@functools.partial(jax.custom_vjp, nondiff_argnums=(4, 5, 6, 7, 8, 9))
-def _flash_core(q3, k3, v3, slopes3, scale, causal, use_alibi, block_q, block_k,
-                mask_block=1):
-    o3, _ = _flash_fwd(q3, k3, v3, slopes3 if use_alibi else None, scale, causal,
-                       block_q, block_k, mask_block)
-    return o3
+def _make_core(fused: bool):
+    """The differentiable kernel call: ``core(q, k, v, slopes, *static)``, or, ``fused``,
+    ``core(qkv, slopes, *static)`` with q | k | v along the lanes of one array, whose
+    gradient is dq | dk | dv likewise. ``static`` = (d, scale, causal, use_alibi,
+    block_q, block_k, mask_block)."""
+    n = 1 if fused else 3
+
+    def run(*args):
+        qkv, slopes, (d, scale, causal, use_alibi, *blocks) = args[:n], args[n], args[n + 1:]
+        return _flash_fwd(*(qkv * 3 if fused else qkv), slopes if use_alibi else None,
+                          fused, d, scale, causal, *blocks)
+
+    @functools.partial(jax.custom_vjp, nondiff_argnums=tuple(range(n + 1, n + 8)))
+    def core(*args):
+        return run(*args)[0]
+
+    def core_fwd(*args):
+        o, lse = run(*args)
+        # the two residuals no matmul gives back: a remat policy that names them
+        # (models/gpt2.py, "dots") keeps them and the backward runs no second forward
+        o = checkpoint_name(o, FLASH_OUT_NAME)
+        lse = checkpoint_name(lse, FLASH_LSE_NAME)
+        return o, (args[:n], o, lse, args[n])
+
+    def core_bwd(d, scale, causal, use_alibi, block_q, block_k, mask_block, res, do):
+        qkv, o, lse, slopes = res
+        grads = _flash_bwd(*(qkv * 3 if fused else qkv), o, lse, do,
+                           slopes if use_alibi else None, fused, d, scale, causal,
+                           block_q, block_k, mask_block)
+        if fused:
+            grads = (jnp.concatenate(grads, axis=-1),)
+        # alibi slopes are a fixed schedule, not trained — zero cotangent
+        return (*grads, jnp.zeros_like(slopes))
+
+    core.defvjp(core_fwd, core_bwd)
+    return core
 
 
-def _flash_core_fwd(q3, k3, v3, slopes3, scale, causal, use_alibi, block_q, block_k,
-                    mask_block=1):
-    o3, lse = _flash_fwd(q3, k3, v3, slopes3 if use_alibi else None, scale, causal,
-                         block_q, block_k, mask_block)
-    # the two residuals no matmul gives back: a remat policy that names them
-    # (models/gpt2.py, "dots") keeps them and the backward runs no second forward
-    o3 = checkpoint_name(o3, FLASH_OUT_NAME)
-    lse = checkpoint_name(lse, FLASH_LSE_NAME)
-    return o3, (q3, k3, v3, o3, lse, slopes3)
-
-
-def _flash_core_bwd(scale, causal, use_alibi, block_q, block_k, mask_block, res, do3):
-    q3, k3, v3, o3, lse, slopes3 = res
-    dq, dk, dv = _flash_bwd(q3, k3, v3, o3, lse, do3,
-                            slopes3 if use_alibi else None, scale, causal,
-                            block_q, block_k, mask_block)
-    # alibi slopes are a fixed schedule, not trained — zero cotangent
-    return dq, dk, dv, jnp.zeros_like(slopes3)
-
-
-_flash_core.defvjp(_flash_core_fwd, _flash_core_bwd)
+_flash_core = _make_core(fused=False)
+_flash_core_qkv = _make_core(fused=True)
 
 _DUMMY_SLOPES = np.zeros((1, 8, 128), np.float32)
 
 
-def _slopes3(alibi_slopes, b, h):
-    """(h,) per-head slopes → (b*h, 8, 128) f32 (value duplicated for TPU lane
-    alignment; the kernel reads element [0, 0, 0] of each head's block)."""
-    s = jnp.tile(jnp.asarray(alibi_slopes, jnp.float32), b)       # bh = bi*h + hi
-    return jnp.broadcast_to(s[:, None, None], (b * h, 8, 128))
+def _slopes_tiles(alibi_slopes):
+    """(h,) per-head slopes → (h, 8, 128) f32 (value duplicated for TPU lane
+    alignment; the kernel reads element [0, 0] of each head's tile)."""
+    s = jnp.asarray(alibi_slopes, jnp.float32)
+    return jnp.broadcast_to(s[:, None, None], s.shape + (8, 128))
 
 
 def flash_attention_local(q4, k4, v4, causal: bool = True,
@@ -577,15 +700,46 @@ def flash_attention_local(q4, k4, v4, causal: bool = True,
             f"and a sequence ({lt}) and tiles that whole blocks divide")
     scale = softmax_scale if softmax_scale is not None else 1.0 / float(np.sqrt(ld))
     use_alibi = alibi_slopes is not None
-    slopes3 = (_slopes3(alibi_slopes, lb, lh) if use_alibi
-               else jnp.asarray(_DUMMY_SLOPES))
+    static = (ld, scale, causal, use_alibi, block_q, block_k, mask_block)
+    slopes = _slopes_tiles(alibi_slopes) if use_alibi else jnp.asarray(_DUMMY_SLOPES)
+    if heads_a_block(lh, ld):
+        # bitcasts: the kernels pick the head in their index maps
+        o = _flash_core(*(x.reshape(lb, lt, lh * ld) for x in (q4, k4, v4)), slopes,
+                        *static)
+        return o.reshape(lb, lt, lh, ld)
 
     def to3(x):
         return x.transpose(0, 2, 1, 3).reshape(lb * lh, lt, ld)
 
-    o3 = _flash_core(to3(q4), to3(k4), to3(v4), slopes3, scale, causal, use_alibi,
-                     block_q, block_k, mask_block)
+    o3 = _flash_core(to3(q4), to3(k4), to3(v4), slopes, *static)
     return o3.reshape(lb, lh, lt, ld).transpose(0, 2, 1, 3)
+
+
+def _per_shard(local, arrays, slopes=None, heads: int = 0):
+    """``local(*arrays[, slopes])`` on each shard of the global mesh's batch axes
+    (axis 0 of the arrays) and, where ``heads`` (their axis 2) divide by it, of its
+    tensor axis; the plain call without such a mesh. A pallas_call is opaque to the
+    SPMD partitioner: under a sharded mesh it would force a full rematerialisation —
+    sequence stays unsharded here (ring_attention owns the seq axis)."""
+    from ...parallel.mesh import BATCH_AXES, AXIS_TENSOR, get_global_mesh
+    extra = () if slopes is None else (slopes,)
+    mesh = get_global_mesh()
+    if mesh is not None:
+        batch_axes = tuple(ax for ax in BATCH_AXES if mesh.size(ax) > 1)
+        bsz = int(np.prod([mesh.size(ax) for ax in batch_axes])) if batch_axes else 1
+        tp = mesh.size(AXIS_TENSOR)
+        use_tp = heads > 0 and tp > 1 and heads % tp == 0
+        manual = set(batch_axes) | ({AXIS_TENSOR} if use_tp else set())
+        if manual and arrays[0].shape[0] % bsz == 0:
+            tail = (AXIS_TENSOR if use_tp else None, None) if heads else (None,)
+            spec = P(batch_axes or None, None, *tail)
+            # slopes shard over the head (TP) axis: each shard sees its heads'
+            sspec = (P(AXIS_TENSOR if use_tp else None),) * len(extra)
+            mapped = shard_map(local, mesh=mesh.mesh, axis_names=manual,
+                               in_specs=(spec,) * len(arrays) + sspec, out_specs=spec,
+                               check_vma=False)
+            return mapped(*arrays, *extra)
+    return local(*arrays, *extra)
 
 
 def flash_attention(q: jnp.ndarray, k: jnp.ndarray, v: jnp.ndarray,
@@ -619,9 +773,8 @@ def flash_attention(q: jnp.ndarray, k: jnp.ndarray, v: jnp.ndarray,
         return xla_attention(q, k, v, causal=causal, mask=mask,
                              softmax_scale=softmax_scale,
                              dropout_rate=dropout_rate, dropout_rng=dropout_rng)
-    b, t, h, d = q.shape
-    scale = softmax_scale if softmax_scale is not None else 1.0 / float(np.sqrt(d))
-    use_alibi = alibi_slopes is not None
+    scale = (softmax_scale if softmax_scale is not None
+             else 1.0 / float(np.sqrt(q.shape[-1])))
 
     def local(q4, k4, v4, slopes=None):
         return flash_attention_local(q4, k4, v4, causal=causal, softmax_scale=scale,
@@ -629,31 +782,21 @@ def flash_attention(q: jnp.ndarray, k: jnp.ndarray, v: jnp.ndarray,
                                      block_q=block_q, block_k=block_k,
                                      mask_block=mask_block)
 
-    # A pallas_call is opaque to the SPMD partitioner: under a sharded mesh it would force a
-    # full rematerialisation. Run the kernel per-shard with shard_map over the batch (and TP
-    # head) axes instead — sequence stays unsharded here (ring_attention owns the seq axis).
-    from ...parallel.mesh import BATCH_AXES, AXIS_TENSOR, get_global_mesh
-    mesh = get_global_mesh()
-    if mesh is not None:
-        batch_axes = tuple(ax for ax in BATCH_AXES if mesh.size(ax) > 1)
-        bsz = int(np.prod([mesh.size(ax) for ax in batch_axes])) if batch_axes else 1
-        tp = mesh.size(AXIS_TENSOR)
-        use_tp = tp > 1 and h % tp == 0
-        manual = set(batch_axes) | ({AXIS_TENSOR} if use_tp else set())
-        if manual and b % max(bsz, 1) == 0:
-            spec = P(batch_axes or None, None, AXIS_TENSOR if use_tp else None, None)
-            if use_alibi:
-                # slopes shard over the head (TP) axis: each shard sees its heads'
-                sspec = P(AXIS_TENSOR if use_tp else None)
-                mapped = shard_map(
-                    lambda q4, k4, v4, s: local(q4, k4, v4, s),
-                    mesh=mesh.mesh, axis_names=manual,
-                    in_specs=(spec,) * 3 + (sspec,), out_specs=spec,
-                    check_vma=False)
-                return mapped(q, k, v, jnp.asarray(alibi_slopes, jnp.float32))
-            mapped = shard_map(local, mesh=mesh.mesh, axis_names=manual,
-                                   in_specs=(spec,) * 3, out_specs=spec,
-                                   check_vma=False)
-            return mapped(q, k, v)
-    return local(q, k, v, jnp.asarray(alibi_slopes, jnp.float32) if use_alibi
-                 else None)
+    return _per_shard(local, (q, k, v),
+                      None if alibi_slopes is None
+                      else jnp.asarray(alibi_slopes, jnp.float32), heads=q.shape[2])
+
+
+def flash_attention_qkv(qkv: jnp.ndarray, n_head: int, causal: bool = True) -> jnp.ndarray:
+    """Attention over a fused projection ``(b, t, 3*h*d)`` = q | k | v along the last
+    axis (GPT-2's ``c_attn``) → ``(b, t, h*d)``, what ``jnp.split`` +
+    :func:`flash_attention` give, with the kernels reading q, k and v out of the one
+    array by lane offsets in their index maps. For head shapes the flat layout holds
+    (:func:`heads_a_block`); heads are not sharded over the tensor axis."""
+    d = qkv.shape[-1] // (3 * n_head)
+    if not heads_a_block(n_head, d):
+        raise ValueError(f"{n_head} heads of {d} do not lie in whole lane tiles: split "
+                         "q, k, v and call flash_attention")
+    static = (d, 1.0 / float(np.sqrt(d)), causal, False, 1024, 1024, 1)
+    return _per_shard(
+        lambda x: _flash_core_qkv(x, jnp.asarray(_DUMMY_SLOPES), *static), (qkv,))

@@ -60,6 +60,32 @@ def check_flash_bwd() -> float:
     return max(_err(a, b) for a, b in zip(g1, g2))
 
 
+def check_flash_fused_qkv() -> float:
+    """The fused projection (b, t, 3*h*d) read as ONE operand at the training shape
+    (12 heads of 64, two a block) against split + ``flash_attention``: the output
+    and the gradient of the operand."""
+    import jax
+    import jax.numpy as jnp
+    from .attention.flash import flash_attention, flash_attention_qkv
+    b, t, h, d = 2, 1024, 12, 64
+    rng = np.random.RandomState(7)
+    qkv = jnp.asarray(rng.standard_normal((b, t, 3 * h * d)), jnp.bfloat16)
+    w = jnp.asarray(rng.standard_normal((b, t, h * d)), jnp.float32)
+
+    def split(x):
+        q, k, v = (y.reshape(b, t, h, d) for y in jnp.split(x, 3, axis=-1))
+        return flash_attention(q, k, v, causal=True).reshape(b, t, h * d)
+
+    def fused(x):
+        return flash_attention_qkv(x, h, causal=True)
+
+    def grad(fn):
+        return jax.jit(jax.grad(lambda x: (fn(x).astype(jnp.float32) * w).sum()))(qkv)
+
+    return max(_err(jax.jit(fused)(qkv), jax.jit(split)(qkv)),
+               _err(grad(fused), grad(split)))
+
+
 def check_flash_alibi() -> float:
     import jax
     import jax.numpy as jnp
@@ -199,6 +225,7 @@ KERNEL_CHECKS: Dict[str, Tuple] = {
     "flash_fwd": (check_flash_fwd, 0.02),       # fp32
     "flash_fwd_bf16": (check_flash_fwd_bf16, 0.05),  # bf16, training shape
     "flash_bwd": (check_flash_bwd, 0.05),       # bf16 grads, training shape
+    "flash_fused_qkv": (check_flash_fused_qkv, 0.05),  # bf16 out + grad, training shape
     "flash_alibi": (check_flash_alibi, 0.05),   # bf16
     "decode": (check_decode, 0.03),             # bf16
     "block_sparse": (check_block_sparse, 0.03),  # bf16

@@ -51,8 +51,9 @@ def xla_attention(q: jnp.ndarray, k: jnp.ndarray, v: jnp.ndarray,
 # b*t=8192 h=12 d=64 bf16, fwd: 2.6x at 1024 / 8.6x at 4096; fwd+bwd: 2.8x at 1024 /
 # 6.3x at 4096 (see tests/unit/ops/test_flash_crossover.py) — so the kernel floor only
 # excludes degenerate tiny shapes where block padding dominates. What causality skips
-# there: up to 1024 tokens a head is ONE kernel block, so nothing is skipped between
-# blocks; inside it the kernel leaves out the 512-row (forward) or 256-column (backward)
+# there: up to 1024 tokens a head (or the 128 // d_head heads of a lane tile, which the
+# kernels read in place from (b, t, h*d)) is ONE kernel block, so nothing is skipped
+# between blocks; inside it the kernel leaves out the 512-row (forward) or 256-column (backward)
 # sub-tiles above the diagonal — none at 256-512 tokens forward, a quarter of the square
 # at 1024 forward, 37.5 % backward — and masks only the sub-tiles on the diagonal. Whole
 # blocks above the diagonal are skipped from 2048 tokens on (``ops/attention/flash.py``).
@@ -64,6 +65,29 @@ def flash_eligible(t: int) -> bool:
     t % 128 != 0 degrades ``_block_sizes`` to tiny MXU-starved blocks, and below
     ``FLASH_MIN_SEQ`` block padding dominates — those shapes stay on XLA."""
     return t >= FLASH_MIN_SEQ and t % 128 == 0
+
+
+def resolves_to_flash(impl, t: int) -> bool:
+    """Whether attention ``impl`` at sequence length ``t`` is the Pallas flash kernel:
+    ``"flash"`` always, ``"auto"`` on a TPU at a ``flash_eligible`` length."""
+    return impl == "flash" or (impl == "auto" and jax.default_backend() == "tpu"
+                               and flash_eligible(t))
+
+
+def flash_reads_fused_qkv(impl, t: int, n_head: int, head_dim: int,
+                          dropout_rate: float = 0.0) -> bool:
+    """Whether a fused q | k | v projection goes to the flash kernels as ONE operand
+    (``ops/attention/flash.py: flash_attention_qkv``) instead of being split: the
+    attention is the kernel with nothing it hands to XLA (dropout), the heads lie in
+    whole lane tiles, and no tensor axis shards them (a contiguous shard of the fused
+    lanes mixes q, k and v)."""
+    if dropout_rate > 0.0 or not resolves_to_flash(impl, t):
+        return False
+    from ...parallel.mesh import AXIS_TENSOR, get_global_mesh
+    from ..attention.flash import heads_a_block
+    mesh = get_global_mesh()
+    return heads_a_block(n_head, head_dim) > 0 and (
+        mesh is None or mesh.size(AXIS_TENSOR) == 1)
 
 
 def _auto_attention(q, k, v, **kw):

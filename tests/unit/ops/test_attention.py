@@ -159,6 +159,136 @@ def test_flash_alibi_sharded_heads(eight_devices):
         set_global_mesh(None)
 
 
+# (heads, d_head) by the branch they take: heads read in place from (b, t, h*d), several a
+# 128-lane block or one; or, where lane tiles cannot address a head, the (b*h, t, d) call
+HEAD_SHAPES = [(4, 64), (4, 32), (2, 128), (3, 64), (2, 48)]
+HEAD_IDS = ["two-a-block", "four-a-block", "one-a-block", "odd-count-folded", "d48-folded"]
+
+
+def test_heads_a_block_by_shape():
+    from deepspeed_tpu.ops.attention.flash import heads_a_block
+    assert [heads_a_block(h, d) for h, d in HEAD_SHAPES] == [2, 4, 1, 0, 0]
+    assert heads_a_block(12, 64) == 2 and heads_a_block(32, 128) == 1   # GPT-2, BLOOM
+    assert heads_a_block(16, 256) == 1 and heads_a_block(1, 64) == 0
+
+
+def _flat_reference(kind, slopes, t):
+    from deepspeed_tpu.models.causal_lm import _alibi_attention_xla, block_causal_mask
+    if kind == "alibi":
+        return lambda q, k, v: _alibi_attention_xla(q, k, v, slopes)
+    if kind == "mask_block":
+        mask = jnp.asarray(block_causal_mask(t, 4))[None, None]
+        return lambda q, k, v: xla_attention(q, k, v, causal=False, mask=mask)
+    return lambda q, k, v: xla_attention(q, k, v, causal=True)
+
+
+@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16], ids=["f32", "bf16"])
+@pytest.mark.parametrize("block", [128, 1024], ids=["two-blocks", "one-block"])
+@pytest.mark.parametrize("kind", ["causal", "alibi", "mask_block"])
+@pytest.mark.parametrize("h,d", HEAD_SHAPES, ids=HEAD_IDS)
+def test_flash_head_layouts_match_xla(h, d, kind, block, dtype):
+    """Forward and dq/dk/dv on every branch of the operand layout, under a cotangent
+    that differs from row to row and from head to head: a head read from, or written
+    to, another head's lanes cannot pass."""
+    from deepspeed_tpu.models.causal_lm import alibi_slopes
+    rng = np.random.default_rng(21)
+    t = 256
+    q, k, v = (x.astype(dtype) for x in _qkv(rng, 2, t, h, d))
+    w = jnp.asarray(rng.normal(size=(2, t, h, d)).astype(np.float32))
+    # slopes of a power-of-two count (the closed form) cut to h heads
+    slopes = jnp.asarray(alibi_slopes(4)[:h]) if kind == "alibi" else None
+
+    def flash(*a):
+        return flash_attention(*a, causal=True, alibi_slopes=slopes, block_q=block,
+                               block_k=block, mask_block=4 if kind == "mask_block" else 1)
+
+    want = _flat_reference(kind, slopes, t)
+
+    def ref(*a):
+        return want(*(x.astype(jnp.float32) for x in a))
+
+    tol = dict(rtol=1e-4, atol=2e-5) if dtype == jnp.float32 else dict(rtol=4e-2, atol=4e-2)
+    o1 = flash(q, k, v)
+    assert o1.dtype == dtype and o1.shape == (2, t, h, d)
+    np.testing.assert_allclose(np.asarray(o1, np.float32), np.asarray(ref(q, k, v)), **tol)
+    for a, b in zip(_grads(flash, q, k, v, w), _grads(ref, q, k, v, w)):
+        np.testing.assert_allclose(np.asarray(a, np.float32), np.asarray(b, np.float32),
+                                   **tol)
+
+
+@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16], ids=["f32", "bf16"])
+@pytest.mark.parametrize("h,d", HEAD_SHAPES[:3], ids=HEAD_IDS[:3])
+def test_flash_fused_qkv_matches_split(h, d, dtype):
+    """One (b, t, 3*h*d) operand read at q | k | v's lane offsets against split +
+    ``flash_attention``: the same output, and the same gradient of the operand."""
+    from deepspeed_tpu.ops.attention.flash import flash_attention_qkv
+    rng = np.random.default_rng(22)
+    b, t = 2, 256
+    qkv = jnp.asarray(rng.normal(size=(b, t, 3 * h * d)).astype(np.float32)).astype(dtype)
+    w = jnp.asarray(rng.normal(size=(b, t, h * d)).astype(np.float32))
+
+    def split(x):
+        q, k, v = (y.reshape(b, t, h, d) for y in jnp.split(x, 3, axis=-1))
+        return flash_attention(q, k, v, causal=True).reshape(b, t, h * d)
+
+    def fused(x):
+        return flash_attention_qkv(x, h, causal=True)
+
+    tol = dict(rtol=1e-4, atol=2e-5) if dtype == jnp.float32 else dict(rtol=4e-2, atol=4e-2)
+    got, want = fused(qkv), split(qkv)
+    assert got.dtype == dtype and got.shape == (b, t, h * d)
+    np.testing.assert_allclose(np.asarray(got, np.float32), np.asarray(want, np.float32),
+                               **tol)
+    g1, g2 = (jax.grad(lambda x: (fn(x).astype(jnp.float32) * w).sum())(qkv)
+              for fn in (fused, split))
+    assert g1.shape == qkv.shape and g1.dtype == dtype
+    np.testing.assert_allclose(np.asarray(g1, np.float32), np.asarray(g2, np.float32), **tol)
+
+
+def test_flash_fused_qkv_refuses_heads_outside_lane_tiles():
+    from deepspeed_tpu.ops.attention.flash import flash_attention_qkv
+    with pytest.raises(ValueError, match="lane tiles"):
+        flash_attention_qkv(jnp.zeros((1, 128, 3 * 3 * 64), jnp.float32), 3)
+
+
+def test_flash_fused_qkv_under_a_batch_sharded_mesh(eight_devices):
+    """The fused entry goes through the same per-shard wrapper as ``flash_attention``:
+    batch axes manual, the lanes whole."""
+    from deepspeed_tpu.ops.attention.flash import flash_attention_qkv
+    set_global_mesh(MeshSpec({"fsdp": 4, "data": 2}, eight_devices))
+    try:
+        rng = np.random.default_rng(23)
+        h, d = 2, 64
+        qkv = jnp.asarray(rng.normal(size=(8, 128, 3 * h * d)).astype(np.float32))
+        q, k, v = (y.reshape(8, 128, h, d) for y in jnp.split(qkv, 3, axis=-1))
+        jaxpr = jax.make_jaxpr(lambda x: flash_attention_qkv(x, h))(qkv)
+        assert "shard_map" in str(jaxpr)
+        got = jax.jit(lambda x: flash_attention_qkv(x, h))(qkv)
+        np.testing.assert_allclose(
+            np.asarray(got), np.asarray(xla_attention(q, k, v, causal=True).reshape(
+                8, 128, h * d)), rtol=1e-5, atol=1e-5)
+    finally:
+        set_global_mesh(None)
+
+
+@pytest.mark.parametrize("impl,t,h,d,drop,tensor,want", [
+    ("flash", 128, 12, 64, 0.0, 1, True),
+    ("flash", 128, 12, 64, 0.1, 1, False),     # dropout is XLA's
+    ("flash", 128, 3, 64, 0.0, 1, False),      # heads outside lane tiles
+    ("flash", 128, 12, 64, 0.0, 2, False),     # a tensor axis would cut q | k | v
+    ("xla", 1024, 12, 64, 0.0, 1, False),
+    ("auto", 1024, 12, 64, 0.0, 1, False),     # auto is XLA off the TPU
+], ids=["flash", "dropout", "odd-heads", "tensor-axis", "xla", "auto-on-cpu"])
+def test_which_projections_reach_the_kernels_fused(eight_devices, impl, t, h, d, drop,
+                                                  tensor, want):
+    from deepspeed_tpu.ops.transformer.attention import flash_reads_fused_qkv
+    set_global_mesh(MeshSpec({"tensor": tensor, "data": 8 // tensor}, eight_devices))
+    try:
+        assert flash_reads_fused_qkv(impl, t, h, d, drop) is want
+    finally:
+        set_global_mesh(None)
+
+
 def test_flash_fallbacks():
     """Masks/dropout route to the XLA path (feature parity guard)."""
     rng = np.random.default_rng(3)

@@ -174,3 +174,48 @@ def test_sdars_block_chunk_holds_its_two_kernels_and_no_loop_but_its_own(
     assert len(loops) == 1, [line.split(" = ")[0].strip() for line in loops]
     assert text.count("tpu_custom_call") == 4       # two kernels a layer
     assert "decode_attention" in text and "moe_grouped_ffn" in text
+
+
+def _flash_cases():
+    """name -> (function of the flash module, operand shapes, dtype): the three
+    kernels at the cells' shapes, and the widest block the layout makes."""
+    def grad(fn):
+        return jax.grad(lambda *a: fn(*a).astype(jnp.float32).sum(),
+                        argnums=tuple(range(3)))
+
+    return {
+        # gpt2-125m.seq1k: c_attn's output as ONE operand, two heads of 64 a block
+        "gpt2-fused-gradient": (lambda f: jax.grad(lambda x: f.flash_attention_qkv(
+            x, 12).astype(jnp.float32).sum()), [(24, 1024, 3 * 768)], jnp.bfloat16),
+        "gpt2-split-gradient": (lambda f: grad(f.flash_attention_local),
+                                [(24, 1024, 12, 64)] * 3, jnp.bfloat16),
+        # bloom-7b1.docqa's 512-bucket prefill: one head of 128 a block, alibi
+        "bloom-prefill": (lambda f: functools.partial(
+            f.flash_attention_local, alibi_slopes=jnp.arange(32.0) / 32),
+            [(1, 512, 32, 128)] * 3, jnp.bfloat16),
+        # sdar-30b-a3b-chat.conv32's prefill: the block-causal mask
+        "sdar-prefill": (lambda f: functools.partial(f.flash_attention_local, mask_block=4),
+                         [(1, 512, 32, 128)] * 3, jnp.bfloat16),
+        # four float32 heads a block over several kv blocks: past the default 16 MiB
+        # of scoped VMEM (19.6), inside the limit the kernels ask for
+        "four-f32-heads-a-block": (lambda f: grad(f.flash_attention_local),
+                                   [(2, 2048, 4, 32)] * 3, jnp.float32),
+        # heads the lane tiles cannot address: the folded (b*h, t, d) call
+        "odd-head-count": (lambda f: grad(f.flash_attention_local),
+                           [(2, 1024, 3, 64)] * 3, jnp.bfloat16),
+    }
+
+
+@pytest.mark.parametrize("case", sorted(_flash_cases()))
+def test_the_flash_kernels_compile_reading_heads_in_place(one_chip, case, monkeypatch):
+    """Forward and both backward kernels with the head picked in their index maps:
+    Mosaic takes the lane-group blocks of (b, t, h*d) and of the fused (b, t, 3*h*d),
+    and the compiled program moves no head (no transpose) where the layout is flat."""
+    from deepspeed_tpu.ops.attention import flash
+    monkeypatch.setattr(flash, "_interpret", lambda: False)
+    make, shapes, dtype = _flash_cases()[case]
+    text = jax.jit(make(flash)).lower(*(
+        jax.ShapeDtypeStruct(s, dtype, sharding=one_chip) for s in shapes)).compile().as_text()
+    kernels = 3 if "gradient" in case or "heads" in case or "odd" in case else 1
+    assert text.count("tpu_custom_call") == kernels and "flash_fwd" in text
+    assert case == "odd-head-count" or " transpose(" not in text

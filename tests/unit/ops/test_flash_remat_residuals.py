@@ -122,3 +122,85 @@ def test_the_primal_path_carries_no_tag():
     assert not _count(primal, _tag)
     grad = jax.make_jaxpr(jax.grad(lambda q, k, v: flash_attention(q, k, v).sum()))(q, k, v)
     assert _count(grad, _tag) == {FLASH_OUT_NAME: 1, FLASH_LSE_NAME: 1}
+
+
+# ------------------------------------------------------------- no copies around the kernels
+def _wide_model_loss(**options):
+    """Two heads of 64: the shape whose heads the kernels read in place, two a block."""
+    cfg = GPT2Config(vocab_size=VOCAB, n_positions=SEQ, n_embd=128, n_layer=LAYERS,
+                     n_head=2, dtype=jnp.float32, attention_impl="flash", remat=True,
+                     remat_policy="dots", **options)
+    model = GPT2(cfg)
+    ids = jnp.asarray(np.random.RandomState(0).randint(0, VOCAB, (BATCH, SEQ)), jnp.int32)
+    params = model.init(jax.random.PRNGKey(0), ids)
+    return (lambda p: cross_entropy_loss(model.apply(p, ids)[:, :-1], ids[:, 1:])), params
+
+
+def _moves_heads(eqn):
+    """A transpose of a 4-D array: heads moved around the kernels."""
+    if eqn.primitive.name == "transpose" and eqn.invars[0].aval.ndim == 4:
+        return "transpose4"
+    return None
+
+
+def _cuts_the_projection(eqn):
+    """A split, slice or dynamic_slice of a (.., 3*n_embd) array, or the pad /
+    concatenate that its transpose would be, outside the kernels."""
+    wide = 3 * 128
+    if eqn.primitive.name in ("split", "slice", "dynamic_slice") and \
+            eqn.invars[0].aval.shape[-1:] == (wide,):
+        return eqn.primitive.name
+    if eqn.primitive.name == "pad" and eqn.outvars[0].aval.shape[-1:] == (wide,):
+        return "pad"
+    return None
+
+
+@pytest.mark.parametrize("scan_layers", [True, False], ids=["scanned", "unrolled"])
+def test_the_gradient_of_a_fused_layer_moves_no_head_and_cuts_no_projection(scan_layers):
+    loss, params = _wide_model_loss(scan_layers=scan_layers)
+    jaxpr = jax.make_jaxpr(jax.grad(loss))(params)
+    bodies = 1 if scan_layers else LAYERS
+    assert _count(jaxpr, _kernel) == {"flash_fwd": bodies, "flash_bwd_dq": bodies,
+                                      "flash_bwd_dkv": bodies}
+    assert _count(jaxpr, _tag) == {FLASH_OUT_NAME: bodies, FLASH_LSE_NAME: bodies}
+    assert not _count(jaxpr, _moves_heads)
+    assert not _count(jaxpr, _cuts_the_projection)
+    # dq | dk | dv go back to the projection as one concatenate a layer
+    joins = _count(jaxpr, lambda e: "concatenate" if e.primitive.name == "concatenate"
+                   and e.outvars[0].aval.shape[-1:] == (3 * 128,) else None)
+    assert joins == {"concatenate": bodies}
+
+
+def test_the_gradient_of_separate_projections_moves_no_head_either():
+    loss, params = _wide_model_loss(scan_layers=True, split_qkv=True)
+    jaxpr = jax.make_jaxpr(jax.grad(loss))(params)
+    assert _count(jaxpr, _kernel) == {"flash_fwd": 1, "flash_bwd_dq": 1, "flash_bwd_dkv": 1}
+    assert not _count(jaxpr, _moves_heads)
+
+
+def test_heads_outside_lane_tiles_are_still_transposed_into_the_kernels():
+    """The folded (b*h, t, d) call is the one place the transposes survive: the
+    module's own model (two heads of 32) takes it, and splits its projection."""
+    loss, params = _model_loss(remat=True, remat_policy="dots", scan_layers=True)
+    jaxpr = jax.make_jaxpr(jax.grad(loss))(params)
+    assert _count(jaxpr, _moves_heads)["transpose4"] >= 4
+
+
+def test_the_fused_layer_gives_the_gradients_of_the_split_one():
+    """Same parameters, c_attn read fused by the kernels or split for XLA attention:
+    the same loss and gradients to float32 rounding."""
+    loss, params = _wide_model_loss(scan_layers=True)
+    cfg = GPT2Config(vocab_size=VOCAB, n_positions=SEQ, n_embd=128, n_layer=LAYERS,
+                     n_head=2, dtype=jnp.float32, attention_impl="xla")
+    model = GPT2(cfg)
+    ids = jnp.asarray(np.random.RandomState(0).randint(0, VOCAB, (BATCH, SEQ)), jnp.int32)
+
+    def plain(p):
+        return cross_entropy_loss(model.apply(p, ids)[:, :-1], ids[:, 1:])
+
+    np.testing.assert_allclose(float(loss(params)), float(plain(params)), rtol=1e-5)
+    got, want = jax.grad(loss)(params), jax.grad(plain)(params)
+    for (path, a), b in zip(jax.tree_util.tree_leaves_with_path(got),
+                            jax.tree_util.tree_leaves(want)):
+        np.testing.assert_allclose(np.asarray(a), np.asarray(b), rtol=2e-3, atol=2e-5,
+                                   err_msg=jax.tree_util.keystr(path))
