@@ -233,13 +233,13 @@ class ChunkedDecodeExecutor:
         self.kv_page_size = int(kv_page_size)
         self.kv_total_pages = kv_total_pages
         kinds = engine.model_config.layer_kinds
-        # what the model's layers keep decides what the programs carry: a
-        # state-space layer a per-slot state, an expert layer its two counts
-        # and no cache. The prefix and slab movers (and the suffix prefill)
-        # read keys and values from EVERY layer, so prefix hits and
-        # speculation need a model whose layers all keep them: see the
-        # scheduler
-        self.kv_every_layer = all(k in "A*" for k in kinds)
+        # what the model's layers keep decides what the programs carry
+        # (``causal_lm.LAYER_KINDS``): a state-space or short-convolution
+        # layer a per-slot state, an expert layer its two counts and no
+        # cache. The prefix and slab movers (and the suffix prefill) read
+        # keys and values from EVERY layer, so prefix hits and speculation
+        # need a model whose layers all keep them: see the scheduler
+        self.kv_every_layer = engine.model_config.kv_every_layer
         self.with_stats = "E" in kinds
         # a model that generates by diffusion over blocks: the chunk counts
         # FORWARDS, a slot carries its block in flight between chunks, a

@@ -229,13 +229,15 @@ class ContinuousBatchingScheduler:
             # per slot that every token overwrites, so neither holds until the
             # pool keeps state snapshots; and the movers index every layer's
             # keys and values, which an expert layer does not keep.
-            if "M" in engine.model_config.layer_kinds:
-                why = ("the recurrent state of its state-space layers is "
-                       "overwritten by every token and was not kept (needs "
-                       "state snapshots)")
+            stateful = engine.model_config.slot_state_layers
+            if stateful:
+                why = (f"the per-slot state of its {' and '.join(stateful)} "
+                       "layers is overwritten by every token and was not kept "
+                       "(needs state snapshots)")
             else:
-                why = ("its expert layers keep no keys and values, which the "
-                       "prefix and slab movers read from every layer")
+                why = ("some of its layers keep no keys and values (expert "
+                       "layers), which the prefix and slab movers read from "
+                       "every layer")
             if cfg.prefix_cache is not None and cfg.prefix_cache.enabled:
                 raise ValueError(
                     "prefix_cache.enabled with this model: a prefix hit "

@@ -21,7 +21,7 @@ parentheses):
 - ``serving/decode_slot_steps_total``, ``serving/decode_tokens_kept_total``,
   ``serving/deliveries_total``, ``serving/deliveries_stalled_total``,
   ``serving/moe_assignments_total``, ``serving/moe_experts_touched_total``
-  (expert layers only), ``serving/ssm_state_bytes`` (state-space layers only),
+  (expert layers only), ``serving/ssm_state_bytes`` (layers with a per-slot state only),
   ``serving/block_forwards_total``, ``serving/blocks_committed_total``,
   ``serving/positions_unmasked_total``, ``serving/blocks_merged_total`` (a model
   that generates by diffusion over blocks only) — per

@@ -15,7 +15,7 @@ Two execution paths:
 
 import dataclasses
 from functools import partial
-from typing import Any, Dict, Optional, Tuple
+from typing import Any, Callable, Dict, Optional, Tuple
 
 import flax.linen as nn
 import jax
@@ -72,23 +72,32 @@ class CausalLMConfig:
     # "xla" = w[idx] gather + einsum (lets XLA pin small expert stacks in VMEM)
     moe_decode_impl: str = "pallas"
     # One MIXER a layer, chosen by a pattern string with a letter a layer
-    # ("M" Mamba-2, "*" attention, "E" latent mixture of experts); every
-    # layer is then ``x + mixer(norm(x))``. None = the classic layer
+    # (the keys of :data:`LAYER_KINDS`: "M" Mamba-2, "*" attention, "C" gated
+    # short convolution, "E" mixture of experts, "F" dense feed-forward);
+    # every layer is then ``x + mixer(norm(x))``. None = the classic layer
     # (attention, then feed-forward). The sizes below are read only by the
     # mixers the pattern names.
     layer_pattern: Optional[str] = None
     head_dim_override: Optional[int] = None  # attention head size where != n_embd / n_head
     qk_norm: bool = False                    # RMSNorm of q and k per head, before the rotation
-    # what an "E" layer is: "latent" (sigmoid router with a selection bias,
-    # experts in a latent space, a shared expert: ``moe/latent_moe.py``) or
-    # "gated" (softmax router, SwiGLU experts of the full width:
+    # what an "E" layer's experts are: "latent" (experts in a latent space
+    # beside a shared expert, behind the sigmoid router with a selection
+    # bias: ``moe/latent_moe.py``) or "gated" (SwiGLU experts of the full
+    # width, no shared expert, behind the router ``moe_router`` names:
     # ``moe/gated_moe.py``)
     moe_kind: str = "latent"
+    # the GATED mixture's router: "softmax" (probabilities over all experts,
+    # the top k renormalised: no selection bias, no scaling) or
+    # "sigmoid_bias" (``latent_moe.route``: sigmoid scores, the choice by
+    # score + a selection bias, weights from the scores alone, normalised
+    # over ``sum + moe_topk_eps`` and scaled by ``routed_scaling_factor``)
+    moe_router: str = "softmax"
+    moe_topk_eps: float = 1e-20
     mamba_num_heads: int = 0
     mamba_head_dim: int = 0
     ssm_state_size: int = 0
     ssm_n_groups: int = 1
-    conv_kernel: int = 4
+    conv_kernel: int = 4                     # "M" and "C": taps of the causal convolution
     ssm_chunk_size: int = 128
     n_routed_experts: int = 0                # the router's width
     experts_per_token: int = 0
@@ -127,14 +136,17 @@ class CausalLMConfig:
     mask_token_id: int = 0
 
     VALID_MOE_DECODE_IMPLS = ("pallas", "xla")
-    LAYER_KINDS = ("M", "*", "E")
     MOE_KINDS = ("latent", "gated")
+    MOE_ROUTERS = ("softmax", "sigmoid_bias")
     REMASKING = ("sequential", "low_confidence_static", "low_confidence_dynamic")
 
     def __post_init__(self):
         if self.moe_kind not in self.MOE_KINDS:
             raise ValueError(f"moe_kind={self.moe_kind!r} is not one of "
                              f"{self.MOE_KINDS}")
+        if self.moe_router not in self.MOE_ROUTERS:
+            raise ValueError(f"moe_router={self.moe_router!r} is not one of "
+                             f"{self.MOE_ROUTERS}")
         if self.gen_block_length:
             B, n = self.gen_block_length, self.gen_denoising_steps
             if B < 2 or n < 1 or B % n:
@@ -155,11 +167,11 @@ class CausalLMConfig:
                 f"moe_decode_impl={self.moe_decode_impl!r} is not one of "
                 f"{self.VALID_MOE_DECODE_IMPLS}")
         if self.layer_pattern is not None:
-            bad = sorted(set(self.layer_pattern) - set(self.LAYER_KINDS))
+            bad = sorted(set(self.layer_pattern) - set(PATTERN_KINDS))
             if bad or len(self.layer_pattern) != self.n_layer:
                 raise ValueError(
                     f"layer_pattern={self.layer_pattern!r} must be {self.n_layer} "
-                    f"letters of {self.LAYER_KINDS}")
+                    f"letters of {PATTERN_KINDS}")
             if self.experts_held is not None:
                 first, count = (int(v) for v in self.experts_held)
                 if not (0 <= first and count >= 1
@@ -171,14 +183,29 @@ class CausalLMConfig:
 
     def layer_kind(self, i: int) -> str:
         """``"A"`` for the classic layer (attention + feed-forward), else the
-        pattern's letter: what kind of state the layer keeps follows from it
-        (keys and values for "A" and "*", a recurrent state for "M", none
-        for "E")."""
+        pattern's letter, a key of :data:`LAYER_KINDS`: what the layer keeps
+        between a sequence's tokens, what it holds and which mixer it runs
+        are that entry's."""
         return "A" if self.layer_pattern is None else self.layer_pattern[i]
 
     @property
     def layer_kinds(self) -> str:
         return "".join(self.layer_kind(i) for i in range(self.n_layer))
+
+    @property
+    def kv_every_layer(self) -> bool:
+        """Every layer keeps keys and values (what the prefix and slab
+        movers, a suffix prefill and a speculative verify read)."""
+        return all(LAYER_KINDS[k].keeps == "kv" for k in self.layer_kinds)
+
+    @property
+    def slot_state_layers(self) -> Tuple[str, ...]:
+        """The names of the kinds of this model's layers that keep a
+        PER-SLOT state, one array a slot that every token overwrites (empty:
+        none does)."""
+        return tuple(dict.fromkeys(
+            LAYER_KINDS[k].name for k in self.layer_kinds
+            if LAYER_KINDS[k].keeps == "state"))
 
     @property
     def held_experts(self) -> Tuple[int, int]:
@@ -207,24 +234,9 @@ class CausalLMConfig:
     def num_params(self) -> int:
         d, L, v = self.n_embd, self.n_layer, self.vocab_size
         if self.layer_pattern is not None:
-            q = self.n_head * self.head_dim
-            inner = self.mamba_num_heads * self.mamba_head_dim
-            per = {
-                "*": d * q + 2 * d * self.kv_heads * self.head_dim + q * d,
-                "M": (d * (inner + self.conv_dim + self.mamba_num_heads)
-                      + (self.conv_kernel + 1) * self.conv_dim
-                      + 3 * self.mamba_num_heads + inner + inner * d),
-                "E": (d * self.n_routed_experts + self.n_routed_experts
-                      + 2 * d * self.moe_latent_size + 2 * d * self.moe_shared_width
-                      + self.held_experts[1] * 2 * self.moe_latent_size
-                      * self.moe_expert_width),
-            }
-            if self.moe_kind == "gated":
-                per["E"] = (d * self.n_routed_experts
-                            + self.held_experts[1] * 3 * d * self.moe_expert_width)
-            if self.qk_norm:
-                per["*"] += 2 * self.head_dim
-            return (v * d + sum(per[k] + d for k in self.layer_pattern) + d
+            # a mixer layer: its mixer (the table's count) and its norm
+            return (v * d + sum(LAYER_KINDS[k].params(self) + d
+                                for k in self.layer_pattern) + d
                     + (0 if self.tie_word_embeddings else v * d))
         f = self.ffn_dim
         mlp = d * f * (3 if self.gated_mlp else 2)
@@ -233,6 +245,86 @@ class CausalLMConfig:
         moe_extra = n_moe * (self.num_experts - 1) * 2 * d * f  # experts replace the FFN
         return (v * d + L * (attn + mlp) + moe_extra +
                 (0 if self.tie_word_embeddings else v * d))
+
+
+# ------------------------------------------------------------------- layer kinds
+@dataclasses.dataclass(frozen=True)
+class LayerKind:
+    """A row of :data:`LAYER_KINDS`: what a layer of that kind keeps between
+    a sequence's tokens (``keeps``: ``"kv"`` keys and values, rows that grow
+    with the sequence and live in pages; ``"state"`` one array a slot that
+    every token overwrites, made by ``state(cfg, rows, dtype)``;
+    ``"nothing"``, an empty cache), the parameters its mixer holds (``params(cfg)``, its norm
+    not counted) and the method of :class:`MixerLayer` that runs it."""
+    name: str
+    keeps: str
+    params: Optional[Callable] = None
+    state: Optional[Callable] = None
+    mixer: Optional[str] = None
+
+
+def _attention_params(cfg: CausalLMConfig) -> int:
+    d, q = cfg.n_embd, cfg.n_head * cfg.head_dim
+    return (d * q + 2 * d * cfg.kv_heads * cfg.head_dim + q * d
+            + (2 * cfg.head_dim if cfg.qk_norm else 0))
+
+
+def _mamba_params(cfg: CausalLMConfig) -> int:
+    d, inner = cfg.n_embd, cfg.mamba_num_heads * cfg.mamba_head_dim
+    return (d * (inner + cfg.conv_dim + cfg.mamba_num_heads)
+            + (cfg.conv_kernel + 1) * cfg.conv_dim
+            + 3 * cfg.mamba_num_heads + inner + inner * d)
+
+
+def _short_conv_params(cfg: CausalLMConfig) -> int:
+    d = cfg.n_embd
+    return d * 3 * d + cfg.conv_kernel * d + d * d
+
+
+def _experts_params(cfg: CausalLMConfig) -> int:
+    d, n, held = cfg.n_embd, cfg.n_routed_experts, cfg.held_experts[1]
+    if cfg.moe_kind == "gated":
+        return (d * n + (n if cfg.moe_router == "sigmoid_bias" else 0)
+                + held * 3 * d * cfg.moe_expert_width)
+    return (d * n + n + 2 * d * cfg.moe_latent_size + 2 * d * cfg.moe_shared_width
+            + held * 2 * cfg.moe_latent_size * cfg.moe_expert_width)
+
+
+def _ffn_params(cfg: CausalLMConfig) -> int:
+    d, f, mats = cfg.n_embd, cfg.ffn_dim, 3 if cfg.gated_mlp else 2
+    return d * f * mats + ((mats - 1) * f + d if cfg.mlp_bias else 0)
+
+
+def _mamba_state(cfg: CausalLMConfig, batch_size: int, dtype) -> Dict:
+    """A state-space layer's state for ``batch_size`` sequences: the last
+    ``conv_kernel - 1`` inputs of the convolution (serving type) and the
+    recurrent state (float32)."""
+    return {"conv": jnp.zeros((batch_size, cfg.conv_kernel - 1, cfg.conv_dim), dtype),
+            "ssm": jnp.zeros((batch_size, cfg.mamba_num_heads, cfg.mamba_head_dim,
+                              cfg.ssm_state_size), jnp.float32)}
+
+
+def _short_conv_state(cfg: CausalLMConfig, batch_size: int, dtype) -> Dict:
+    """A gated short convolution's state: the last ``conv_kernel - 1``
+    products ``B * u`` (serving type), and nothing else."""
+    return {"conv": jnp.zeros((batch_size, cfg.conv_kernel - 1, cfg.n_embd), dtype)}
+
+
+#: the ONE place that says what each kind of layer is: ``init_cache``,
+#: ``CausalLMConfig.num_params``, :class:`MixerLayer`, the serving executor
+#: (``kv_every_layer``) and the scheduler's refusals (``slot_state_layers``)
+#: read it, so a new kind is one entry and one method. "A" is the classic
+#: layer (attention and feed-forward in one module) and stands in no pattern.
+LAYER_KINDS: Dict[str, LayerKind] = {
+    "A": LayerKind("classic", "kv"),
+    "*": LayerKind("attention", "kv", _attention_params),
+    "M": LayerKind("state-space", "state", _mamba_params, _mamba_state, "_mamba"),
+    "C": LayerKind("short-convolution", "state", _short_conv_params,
+                   _short_conv_state, "_short_conv"),
+    "E": LayerKind("expert", "nothing", _experts_params, mixer="_experts"),
+    "F": LayerKind("feed-forward", "nothing", _ffn_params, mixer="_ffn"),
+}
+PATTERN_KINDS = tuple(k for k in LAYER_KINDS if k != "A")
 
 
 # ---------------------------------------------------------------- family constructors
@@ -355,6 +447,56 @@ def sdar_moe_cfg(*, hidden_size, num_hidden_layers, vocab_size,
         gen_denoising_steps=int(gen_denoising_steps), gen_remasking=gen_remasking,
         gen_confidence_threshold=float(gen_confidence_threshold),
         mask_token_id=int(mask_token_id), **kw)
+
+
+def lfm2_moe_cfg(*, hidden_size, num_hidden_layers, layer_types, num_dense_layers,
+                 vocab_size, num_attention_heads, num_key_value_heads,
+                 intermediate_size, moe_intermediate_size, num_experts,
+                 num_experts_per_tok, conv_L_cache=3, conv_bias=False,
+                 use_expert_bias=True, norm_topk_prob=True,
+                 routed_scaling_factor=1.0, norm_eps=1e-5, rope_theta=1e6,
+                 experts_held=None, **kw) -> CausalLMConfig:
+    """LFM2 mixtures of experts (``model_type: lfm2_moe``): the keywords are
+    the published config's. A published layer is an operator and then a
+    feed-forward, each ``x + f(rmsnorm(x))``: here a pair of mixer layers, "C"
+    (gated short convolution of ``conv_L_cache`` taps, no bias, no
+    activation) or "*" (grouped keys and values, RMSNorm of q and k per head
+    before a rotation over the whole head, no bias) as ``layer_types`` says,
+    then "F" (SwiGLU of width ``intermediate_size``) for the first
+    ``num_dense_layers`` layers and "E" after them (sigmoid router, the top
+    ``num_experts_per_tok`` of score + expert bias, weights from the scores
+    over their sum + 1e-6, SwiGLU experts of width ``moe_intermediate_size``,
+    no shared expert), so ``n_layer`` is twice ``num_hidden_layers``. The
+    FIRST ``num_hidden_layers`` entries of ``layer_types`` are built (a
+    pipeline's first stage where it is cut). RMSNorm, tied head. Set here and
+    not published: the sigmoid score, the tied head, the 1e-6, the q/k norm's
+    place (``benchmarks/chipbench/configs/lfm2-8b-a1b.json: assumed``)."""
+    if conv_bias or not use_expert_bias:
+        raise NotImplementedError(
+            "lfm2_moe is built without a convolution bias and with the expert "
+            f"bias (got conv_bias={conv_bias}, use_expert_bias={use_expert_bias})")
+    kinds = {"conv": "C", "full_attention": "*"}
+    n = int(num_hidden_layers)
+    pattern = "".join(kinds[t] + ("F" if i < int(num_dense_layers) else "E")
+                      for i, t in enumerate(layer_types[:n]))
+    if len(pattern) != 2 * n:
+        raise ValueError(f"layer_types names {len(layer_types)} layers, "
+                         f"num_hidden_layers={n}")
+    kw.setdefault("name", "lfm2-moe")
+    return CausalLMConfig(
+        n_embd=hidden_size, n_layer=2 * n, layer_pattern=pattern,
+        vocab_size=vocab_size, n_head=num_attention_heads,
+        n_kv_head=num_key_value_heads, qk_norm=True, pos_emb="rotary",
+        rotary_base=float(rope_theta), layernorm="rmsnorm", ln_eps=norm_eps,
+        qkv_bias=False, mlp_bias=False, gated_mlp=True, activation="silu",
+        d_ff=intermediate_size, tie_word_embeddings=True,
+        conv_kernel=int(conv_L_cache), moe_kind="gated",
+        moe_router="sigmoid_bias", moe_topk_eps=1e-6,
+        n_routed_experts=num_experts, experts_per_token=num_experts_per_tok,
+        moe_expert_width=moe_intermediate_size,
+        routed_scaling_factor=float(routed_scaling_factor),
+        norm_topk_prob=bool(norm_topk_prob),
+        experts_held=None if experts_held is None else tuple(experts_held), **kw)
 
 
 FAMILIES = {
@@ -847,13 +989,15 @@ class CausalLMLayer(nn.Module):
 
 class MixerLayer(CausalLMLayer):
     """A layer of ONE mixer, ``x + mixer(norm(x))``; ``kind`` is the letter
-    of the configuration's pattern: "M" a Mamba-2 mixer (state ``{"conv",
-    "ssm"}``), "*" this module's attention (state ``{"k", "v"}``, every cache
-    mode of :class:`CausalLMLayer`), "E" a latent mixture of experts (no
-    state; its two counts are sown into the ``stats`` collection).
-    ``seq_lens`` (b,) are the real lengths of right-padded rows in a prefill
-    or a block step: a recurrence must not run over the padding that a causal
-    mask forgives, and an expert layer routes it nowhere."""
+    of the configuration's pattern, a key of :data:`LAYER_KINDS`: "*" this
+    module's attention (keys and values, every cache mode of
+    :class:`CausalLMLayer`), "M" a Mamba-2 mixer (state ``{"conv", "ssm"}``),
+    "C" a gated short convolution (state ``{"conv"}``), "E" a mixture of
+    experts (no state; its two counts are sown into the ``stats``
+    collection), "F" the dense feed-forward (no state). ``seq_lens`` (b,)
+    are the real lengths of right-padded rows in a prefill or a block step: a
+    recurrence must not run over the padding that a causal mask forgives, and
+    an expert layer routes it nowhere."""
     kind: str = "*"
 
     @nn.compact
@@ -863,54 +1007,73 @@ class MixerLayer(CausalLMLayer):
                  kv_cap: Optional[int] = None, seq_lens=None,
                  block_step: bool = False, attn_mask=None):
         cfg = self.config
+        entry = LAYER_KINDS[self.kind]
         with scope("norm"):
             h = _norm(cfg, "norm")(x).astype(cfg.dtype)
-        out_std = cfg.init_std / (2 * cfg.n_layer) ** 0.5
         if self.kind == "*":
             out, new = self._attention(h, positions, cache, cache_len,
                                        prefix_fill, page_table, kv_cap,
                                        block_step, attn_mask)
-        elif self.kind == "M":
-            if prefix_fill:
+        else:
+            if entry.keeps == "state" and prefix_fill:
                 raise NotImplementedError(
-                    "a state-space layer cannot resume at a cache offset: its "
+                    f"a {entry.name} layer cannot resume at a cache offset: its "
                     "state after the prefix was not kept (no prefix hits, no "
                     "speculative verify on a model with such layers)")
-            from .mamba2 import Mamba2Mixer
-            out, new = Mamba2Mixer(
-                d_model=cfg.n_embd, num_heads=cfg.mamba_num_heads,
-                head_dim=cfg.mamba_head_dim, state_size=cfg.ssm_state_size,
-                n_groups=cfg.ssm_n_groups, conv_kernel=cfg.conv_kernel,
-                chunk_size=cfg.ssm_chunk_size, eps=cfg.ln_eps, dtype=cfg.dtype,
-                init_std=cfg.init_std, out_std=out_std, name="mamba")(
-                    h, cache=cache, seq_lens=seq_lens)
-        else:
-            valid = None
-            if seq_lens is not None and x.shape[1] > 1:
-                with scope("moe.plan"):
-                    valid = jnp.arange(x.shape[1])[None, :] < seq_lens[:, None]
-            if cfg.moe_kind == "gated":
-                from ..moe.gated_moe import GatedMoE
-                moe = GatedMoE(
-                    d_model=cfg.n_embd, n_routed=cfg.n_routed_experts,
-                    top_k=cfg.experts_per_token, expert_width=cfg.moe_expert_width,
-                    norm_topk=cfg.norm_topk_prob, experts_held=cfg.held_experts,
-                    dtype=cfg.dtype, init_std=cfg.init_std, out_std=out_std,
-                    name="moe")
-            else:
-                from ..moe.latent_moe import LatentMoE
-                moe = LatentMoE(
-                    d_model=cfg.n_embd, n_routed=cfg.n_routed_experts,
-                    top_k=cfg.experts_per_token, expert_width=cfg.moe_expert_width,
-                    shared_width=cfg.moe_shared_width, latent=cfg.moe_latent_size,
-                    scale=cfg.routed_scaling_factor, norm_topk=cfg.norm_topk_prob,
-                    experts_held=cfg.held_experts, dtype=cfg.dtype,
-                    init_std=cfg.init_std, out_std=out_std, name="moe")
-            out, stats = moe(h, valid)
-            self.sow("stats", "moe_counts", stats)
-            new = None if cache is None else {}
+            out, new = getattr(self, entry.mixer)(h, cache, seq_lens)
+            if entry.keeps == "nothing":
+                new = None if cache is None else {}
         with scope("residual"):
             return x + out.astype(x.dtype), new
+
+    @property
+    def _out_std(self) -> float:
+        return self.config.init_std / (2 * self.config.n_layer) ** 0.5
+
+    def _mamba(self, h, cache, seq_lens):
+        from .mamba2 import Mamba2Mixer
+        cfg = self.config
+        return Mamba2Mixer(
+            d_model=cfg.n_embd, num_heads=cfg.mamba_num_heads,
+            head_dim=cfg.mamba_head_dim, state_size=cfg.ssm_state_size,
+            n_groups=cfg.ssm_n_groups, conv_kernel=cfg.conv_kernel,
+            chunk_size=cfg.ssm_chunk_size, eps=cfg.ln_eps, dtype=cfg.dtype,
+            init_std=cfg.init_std, out_std=self._out_std, name="mamba")(
+                h, cache=cache, seq_lens=seq_lens)
+
+    def _short_conv(self, h, cache, seq_lens):
+        from .short_conv import ShortConvMixer
+        cfg = self.config
+        return ShortConvMixer(
+            d_model=cfg.n_embd, conv_kernel=cfg.conv_kernel, dtype=cfg.dtype,
+            init_std=cfg.init_std, out_std=self._out_std, name="conv")(
+                h, cache=cache, seq_lens=seq_lens)
+
+    def _ffn(self, h, cache, seq_lens):
+        return self._mlp(h), None
+
+    def _experts(self, h, cache, seq_lens):
+        cfg = self.config
+        valid = None
+        if seq_lens is not None and h.shape[1] > 1:
+            with scope("moe.plan"):
+                valid = jnp.arange(h.shape[1])[None, :] < seq_lens[:, None]
+        shared = dict(
+            d_model=cfg.n_embd, n_routed=cfg.n_routed_experts,
+            top_k=cfg.experts_per_token, expert_width=cfg.moe_expert_width,
+            scale=cfg.routed_scaling_factor, norm_topk=cfg.norm_topk_prob,
+            experts_held=cfg.held_experts, dtype=cfg.dtype,
+            init_std=cfg.init_std, out_std=self._out_std, name="moe")
+        if cfg.moe_kind == "gated":
+            from ..moe.gated_moe import GatedMoE
+            moe = GatedMoE(router=cfg.moe_router, topk_eps=cfg.moe_topk_eps, **shared)
+        else:
+            from ..moe.latent_moe import LatentMoE
+            moe = LatentMoE(shared_width=cfg.moe_shared_width,
+                            latent=cfg.moe_latent_size, **shared)
+        out, stats = moe(h, valid)
+        self.sow("stats", "moe_counts", stats)
+        return out, None
 
 
 def make_layer(cfg: CausalLMConfig, i: int, **kw):
@@ -1356,34 +1519,24 @@ def causal_lm_model(cfg: CausalLMConfig, sample_seq_len: Optional[int] = None,
                  segments=causal_lm_segments(cfg, layers_per_group))
 
 
-def init_state_cache(cfg: CausalLMConfig, batch_size: int, dtype=None) -> Dict:
-    """A state-space layer's state for ``batch_size`` sequences: the last
-    ``conv_kernel - 1`` inputs of the convolution (serving type) and the
-    recurrent state (float32)."""
-    dtype = dtype or cfg.dtype
-    return {"conv": jnp.zeros((batch_size, cfg.conv_kernel - 1, cfg.conv_dim), dtype),
-            "ssm": jnp.zeros((batch_size, cfg.mamba_num_heads, cfg.mamba_head_dim,
-                              cfg.ssm_state_size), jnp.float32)}
-
-
 def init_cache(cfg: CausalLMConfig, batch_size: int, max_len: Optional[int] = None,
                dtype=None, kv_shape=None):
-    """One cache a layer, typed by the layer's kind: fixed-capacity head-major
-    keys and values for attention (``kv_shape`` where the caller lays them out
-    otherwise: the paged pool's pages), ``{"conv", "ssm"}`` of ``batch_size``
-    sequences for a state-space layer, an empty dict for a layer that keeps
-    nothing."""
+    """One cache a layer, typed by what the layer's kind keeps
+    (:data:`LAYER_KINDS`): fixed-capacity head-major keys and values
+    (``kv_shape`` where the caller lays them out otherwise: the paged pool's
+    pages), the kind's per-slot state for ``batch_size`` sequences
+    (``{"conv", "ssm"}`` state-space, ``{"conv"}`` short convolution), an
+    empty dict for a layer that keeps nothing."""
     T = max_len or cfg.max_seq_len
     dtype = dtype or cfg.dtype
     shape = kv_shape or (batch_size, cfg.kv_heads, T, cfg.head_dim)
     out = []
     for kind in cfg.layer_kinds:
-        if kind == "M":
-            out.append(init_state_cache(cfg, batch_size, dtype))
-        elif kind == "E":
-            out.append({})
-        else:
+        entry = LAYER_KINDS[kind]
+        if entry.keeps == "kv":
             out.append({"k": jnp.zeros(shape, dtype), "v": jnp.zeros(shape, dtype)})
+        else:
+            out.append(entry.state(cfg, batch_size, dtype) if entry.state else {})
     return out
 
 

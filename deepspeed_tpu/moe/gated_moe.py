@@ -1,11 +1,17 @@
-"""Mixture of gated experts behind a softmax router, one chip's share of it.
+"""Mixture of gated experts of the full width, one chip's share of it.
 
-The router scores every expert of the layer (``n_routed``) in float32, ``p =
-softmax(h W_r)``, chooses the ``top_k`` largest and weights the chosen by
-their probabilities, renormalised to sum to one where ``norm_topk`` says so:
-no selection bias, no scaling, no shared expert (the Qwen3-MoE layer, which
-``sdar_moe`` keeps). An expert is a SwiGLU block of the full width, ``W_down
-(silu(h W_gate) * (h W_up))``, three matrices.
+An expert is a SwiGLU block, ``W_down (silu(h W_gate) * (h W_up))``, three
+matrices; there is no shared expert. The router is data (``router``), scoring
+every expert of the layer (``n_routed``) in float32:
+
+- ``"softmax"``: ``p = softmax(h W_r)``, the ``top_k`` largest, weighted by
+  their probabilities, renormalised to sum to one where ``norm_topk`` says
+  so: no selection bias, no scaling (the Qwen3-MoE layer, which ``sdar_moe``
+  keeps);
+- ``"sigmoid_bias"``: :func:`~.latent_moe.route`, ``s = sigmoid(h W_r)``, the
+  ``top_k`` largest of ``s + b`` (``b`` the layer's ``router_bias``, which
+  moves the choice and never a weight), weighted by ``s`` over ``sum +
+  topk_eps`` where ``norm_topk``, times ``scale`` (``lfm2_moe``).
 
 As :class:`~.latent_moe.LatentMoE` the layer is TOLD which experts it holds,
 ``experts_held = (first, count)``: it routes over all ``n_routed`` and sums
@@ -23,6 +29,7 @@ import jax.numpy as jnp
 
 from ..observability import scope
 from ..ops.moe.grouped_ffn import grouped_experts
+from .latent_moe import route
 
 
 def route_softmax(h, w_router, top_k: int, norm: bool):
@@ -47,6 +54,9 @@ class GatedMoE(nn.Module):
     dtype: Any
     init_std: float
     out_std: float
+    router: str = "softmax"
+    scale: float = 1.0            # the sigmoid_bias router's
+    topk_eps: float = 1e-20
 
     @nn.compact
     def __call__(self, h, valid: Optional[jnp.ndarray] = None):
@@ -57,6 +67,11 @@ class GatedMoE(nn.Module):
         d, f, dt = self.d_model, self.expert_width, self.dtype
         init = nn.initializers.normal(self.init_std)
         w_r = self.param("router", init, (d, self.n_routed), jnp.float32)
+        if self.router == "sigmoid_bias":
+            # seeded as the latent mixture seeds it: non-zero, small beside
+            # the scores' spread
+            b_r = self.param("router_bias", nn.initializers.normal(0.01),
+                             (self.n_routed,), jnp.float32)
         w_gate = self.param("experts_gate", init, (count, d, f), jnp.float32)
         w_up = self.param("experts_up", init, (count, d, f), jnp.float32)
         w_down = self.param("experts_down", nn.initializers.normal(self.out_std),
@@ -64,7 +79,11 @@ class GatedMoE(nn.Module):
         b_, t, _ = h.shape
         with scope("moe.router"):
             x = h.reshape(b_ * t, d).astype(dt)
-            idx, w = route_softmax(x, w_r, self.top_k, self.norm_topk)
+            if self.router == "sigmoid_bias":
+                idx, w = route(x, w_r, b_r, self.top_k, self.scale, self.norm_topk,
+                               self.topk_eps)
+            else:
+                idx, w = route_softmax(x, w_r, self.top_k, self.norm_topk)
         with scope("moe.experts"):
             args = (w_up.astype(dt), w_down.astype(dt), jax.nn.silu,
                     None if valid is None else valid.reshape(-1), w_gate.astype(dt))

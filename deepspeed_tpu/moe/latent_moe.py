@@ -31,16 +31,17 @@ def relu2(x):
     return jnp.square(jax.nn.relu(x))
 
 
-def route(h, w_router, bias, top_k: int, scale: float, norm: bool):
+def route(h, w_router, bias, top_k: int, scale: float, norm: bool,
+          eps: float = 1e-20):
     """``h`` (T, d). Returns the chosen experts ``idx`` (T, k) and their
     weights (T, k) float32: sigmoid scores, selection by score + bias, weights
-    from the scores alone."""
+    from the scores alone, over ``sum + eps`` where ``norm``, times ``scale``."""
     s = jax.nn.sigmoid(jnp.dot(h.astype(jnp.float32), w_router.astype(jnp.float32),
                                precision=jax.lax.Precision.HIGHEST))
     _, idx = jax.lax.top_k(s + bias.astype(jnp.float32), top_k)
     w = jnp.take_along_axis(s, idx, axis=-1)
     if norm:
-        w = w / (jnp.sum(w, axis=-1, keepdims=True) + 1e-20)
+        w = w / (jnp.sum(w, axis=-1, keepdims=True) + eps)
     return idx.astype(jnp.int32), w * scale
 
 
@@ -138,7 +139,9 @@ def level_expert_load(cfg, params, seed: int, batches: int = 8, tokens: int = 51
     and a stand-in that is to load its experts as a trained model does needs
     the same. ``cfg.level_random_experts`` asks for it where the stand-in
     weights are made; nothing else calls it. Returns the parameters and the
-    largest load over the mean, worst layer, before and after."""
+    largest load over the mean, worst layer, before and after. A layer counts
+    if its router has a selection bias (``moe["router_bias"]``: the latent
+    mixture, and the gated one behind the ``sigmoid_bias`` router)."""
     from ..models.causal_lm import _norm_mod, causal_lm_segments  # it imports this module
     segs = causal_lm_segments(cfg, layers_per_group=1)
     key = jax.random.PRNGKey(seed)
@@ -166,7 +169,7 @@ def level_expert_load(cfg, params, seed: int, batches: int = 8, tokens: int = 51
     before = after = 1.0
     for seg in segs[1:-1]:
         (name,) = seg.param_keys
-        if "moe" in params[name]:
+        if "router_bias" in params[name].get("moe", {}):
             layer = dict(params[name])
             old = layer["moe"]["router_bias"]
             new, b, a = level(scores_of(layer, jnp.concatenate(xs, axis=0)), old)

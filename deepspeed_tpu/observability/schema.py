@@ -81,8 +81,9 @@ TAGS: Dict[str, Tuple[str, str]] = {
                                                    "layers and steps: the "
                                                    "bytes the grouped expert "
                                                    "kernel had to read"),
-    "serving/ssm_state_bytes": (GAUGE, "bytes of the per-slot recurrent "
-                                       "state of the state-space layers"),
+    "serving/ssm_state_bytes": (GAUGE, "bytes of the per-slot state of the "
+                                       "state-space and short-convolution "
+                                       "layers (recurrent state, windows)"),
     # ------------------------- generation by diffusion over blocks (PR 31)
     "serving/block_forwards_total": (COUNTER, "forwards run by decode chunks "
                                               "of a model that generates by "
@@ -410,6 +411,14 @@ SCOPES: Dict[str, Tuple[str, str, str]] = {
     "ssm.update": (_STEP, "the one-token state update and the chunked scan",
                    _TABLE + ", beside ssm_decode_dev_ms_per_step"),
     "ssm.out": (_STEP, "the gated norm and the output projection", _TABLE),
+    "sconv.in": (_STEP, "a gated short convolution's input projection and "
+                        "the product B * u",
+                 _TABLE + ", shortconv_decode_dev_ms_per_step"),
+    "sconv.conv": (_STEP, "the causal taps over the window and the window's "
+                          "roll (or the state a prompt leaves)",
+                   _TABLE + ", shortconv_decode_dev_ms_per_step"),
+    "sconv.out": (_STEP, "the gate C * and the output projection",
+                  _TABLE + ", shortconv_decode_dev_ms_per_step"),
     "head": (_STEP, "final norm, the rows the logits are read at, the "
                     "vocabulary matmul",
              "decode_head_dev_ms_per_step, train_outside_layers_dev_ms, "
@@ -461,6 +470,7 @@ SCOPE_MODULES = (
     "deepspeed_tpu/models/gpt2.py",
     "deepspeed_tpu/models/causal_lm.py",
     "deepspeed_tpu/models/mamba2.py",
+    "deepspeed_tpu/models/short_conv.py",
     "deepspeed_tpu/moe/gated_moe.py",
     "deepspeed_tpu/moe/latent_moe.py",
     "deepspeed_tpu/ops/moe/grouped_ffn.py",

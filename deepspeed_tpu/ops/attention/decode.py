@@ -129,7 +129,8 @@ def decode_attention(q: jnp.ndarray, k_cache: jnp.ndarray, v_cache: jnp.ndarray,
     scale = softmax_scale if softmax_scale is not None else 1.0 / float(np.sqrt(d))
     if d % 128 != 0 and not _interpret():
         # Mosaic requires HBM DMA slices 128-aligned in the minor dim; head_dim 64 caches
-        # take the XLA path (still fused/online-softmax'd by XLA, just not hand-scheduled)
+        # take the XLA path (still fused/online-softmax'd by XLA, just not hand-scheduled):
+        # the cell lfm2-8b-a1b.conv32 (32Q/8KV x 64) decodes through here
         return decode_attention_xla(q, k_cache, v_cache, cache_len, softmax_scale)
     bk = min(block_k, T)
     while T % bk:

@@ -1,8 +1,8 @@
-"""The five compiled programs the device scopes are held on, at the rehearsal
+"""The six compiled programs the device scopes are held on, at the rehearsal
 widths of the benchmark's configurations, as ``(jitted function, the shapes
 of its first call)``: ``train_step`` (scanned layers, ``remat_policy: dots``),
 BLOOM's ``decode_chunk`` and one of its prefills, the hybrid's
-``decode_chunk`` and SDAR's block chunk. Each is taken from the program's own
+``decode_chunk``, SDAR's block chunk and LFM2's ``decode_chunk``. Each is taken from the program's own
 call path: the engine or the scheduler is built as the benchmark builds it,
 one step or one request is run, and every ``jax.jit`` the program makes
 meanwhile is recorded with the shapes of its first call. ``null_scopes``
@@ -21,9 +21,10 @@ from deepspeed_tpu.observability import schema
 ROOT = os.path.dirname(os.path.dirname(os.path.dirname(os.path.dirname(
     os.path.abspath(__file__)))))
 PROGRAMS = ("train_step", "bloom.decode_chunk", "bloom.prefill",
-            "hybrid.decode_chunk", "sdar.decode_chunk")
+            "hybrid.decode_chunk", "sdar.decode_chunk", "lfm2.decode_chunk")
 _CONFIGS = {"train_step": "gpt2-125m", "bloom": "bloom-7b1",
-            "hybrid": "nemotron-3-super-120b-a12b", "sdar": "sdar-30b-a3b-chat"}
+            "hybrid": "nemotron-3-super-120b-a12b", "sdar": "sdar-30b-a3b-chat",
+            "lfm2": "lfm2-8b-a1b"}
 
 
 class _Recorded:

@@ -238,6 +238,10 @@ _EXPECTED = {
                             "kv.append", "head", "kv.gather", "kv.copy_back"},
     "sdar.decode_chunk": {"attn.qkv", "attn.core", "kv.append", "moe.router", "moe.plan",
                           "moe.rows", "moe.experts", "head", "kv.gather", "kv.copy_back"},
+    "lfm2.decode_chunk": {"sconv.in", "sconv.conv", "sconv.out", "mlp.up", "mlp.act",
+                          "mlp.down", "moe.router", "moe.plan", "moe.rows", "moe.experts",
+                          "attn.qkv", "attn.core", "kv.append", "head", "kv.gather",
+                          "kv.copy_back"},
 }
 
 

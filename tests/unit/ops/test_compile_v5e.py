@@ -130,6 +130,33 @@ def test_the_gated_expert_kernel_compiles_at_sdars_published_widths(
     assert "tpu_custom_call" in text and "moe_grouped_ffn" in text
 
 
+@pytest.mark.parametrize("tokens", [32, 512])
+def test_the_gated_expert_kernel_compiles_at_lfm2s_published_widths(
+        one_chip, tokens, monkeypatch):
+    """``moe_grouped_ffn`` in its gated form at LFM2-8B-A1B's widths, the
+    cell ``lfm2-8b-a1b.conv32``'s kernel shape ``(32, 2048, 1792)``, tiles of
+    16 rows: a decode step of 32 slots and a 512-token prefill, top 4 of 32
+    experts. An expert is 22.02 MB, 2.3 x SDAR's: its three whole-matrix
+    blocks, double-buffered, are 44 MB of the kernel's 64 MiB of VMEM."""
+    from deepspeed_tpu.ops.moe import grouped_ffn as g
+    monkeypatch.setattr(g, "_interpret", lambda: False)
+    held, d, width, k = 32, 2048, 1792, 4
+    tm = g.tile_rows(tokens * k)
+    assert tm == 16
+    tiles = tokens * k // tm + held
+    assert 2 * 3 * d * width * 2 < g.VMEM_LIMIT_BYTES
+
+    def sds(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    up = sds((held, d, width), jnp.bfloat16)
+    text = jax.jit(functools.partial(g.grouped_ffn, act=jax.nn.silu, tm=tm)).lower(
+        sds((tiles * tm, d), jnp.bfloat16), sds((tiles,), jnp.int32),
+        sds((tiles,), jnp.int32), up, sds((held, width, d), jnp.bfloat16),
+        w_gate=up).compile().as_text()
+    assert "tpu_custom_call" in text and "moe_grouped_ffn" in text
+
+
 def test_sdars_block_chunk_holds_its_two_kernels_and_no_loop_but_its_own(
         one_chip, monkeypatch):
     """The decode chunk of generation by diffusion over blocks at the cell's
