@@ -335,7 +335,7 @@ def build_paged_decode_chunk(module, dequant, slot_select, chunk_size: int,
     steps (:func:`apply_model`) to the outputs.
 
     The caches of the layers that keep keys and values are GLOBAL KV pages
-    (``{"k": (P, hk, page, d), ...}``) and each step writes at the page-mapped
+    (``{"k": (P, hk / r, page, r * d), ...}``) and each step writes at the page-mapped
     row of the slot's static-shape ``page_table`` row — the table itself never
     changes inside a chunk (pages are bound at admission), so it rides as a
     loop constant. A slot's page COUNT is runtime data in the table, so page

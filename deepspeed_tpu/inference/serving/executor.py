@@ -298,7 +298,8 @@ class ChunkedDecodeExecutor:
                                dtype=self.engine.dtype,
                                total_pages=self.kv_total_pages)
             ph.set(pages=pool.total_pages, slots=self.slots,
-                   state_bytes=pool.state_nbytes)
+                   state_bytes=pool.state_nbytes,
+                   heads_per_row=pool.heads_per_row)
             return pool
 
     def reset_pool(self) -> None:
@@ -315,8 +316,10 @@ class ChunkedDecodeExecutor:
         # the fused kernel has no alibi bias (the layer would re-gather
         # the dense view EVERY step inside the loop — the fallback hoists
         # it once per chunk), no shard_map TP path (the fallback's dense
-        # steps route through _sharded_decode), and its dispatcher needs
-        # a lane-aligned head dim on-chip (fused_paged_for mirrors it);
+        # steps route through _sharded_decode), and its dispatcher asks a
+        # MODEL head size of whole lane tiles on-chip (fused_paged_for
+        # mirrors it; the pages' rows are whole tiles from d 32 on:
+        # ops/paged_attention.heads_per_row);
         # every excluded regime decodes strictly faster on the fallback.
         # So does a pool that holds a row for every slot's whole cap: on
         # the chip the kernel took 1.07-2.6x the fallback's time a step at

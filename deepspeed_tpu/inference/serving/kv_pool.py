@@ -1,8 +1,8 @@
 """The serve path's KV cache pool: fixed-size pages behind per-slot page tables.
 
 :class:`PagedKVPool` — one global pool of fixed-size KV **pages** per layer
-(``{"k": (P, hk, page, d), ...}``; the layout itself lives in
-``ops/paged_attention.py``) behind a static-shape per-slot page table. A slot
+(``{"k": (P, hk / r, page, r * d), ...}``; the layout itself, ``r`` heads a
+row, lives in ``ops/paged_attention.py``) behind a static-shape per-slot page table. A slot
 allocates only the pages its ``prompt + max_new`` needs (page-granular
 admission: occupancy tracks requested tokens, not the pow2-bucketed worst
 case), pages are refcounted so the prefix cache can **share** a prompt's pages
@@ -39,7 +39,8 @@ import numpy as np
 
 from ...models.causal_lm import init_cache
 from ...observability.trace import get_tracer
-from ...ops.paged_attention import pages_to_dense, write_dense_pages
+from ...ops.paged_attention import (heads_per_row, pages_to_dense,
+                                    write_dense_pages)
 
 
 NULL_PAGE = 0      # reserved sentinel: pads every table row; rows it could
@@ -140,7 +141,8 @@ class PagedKVPool:
         cfg = model_config
         self.n_layer = cfg.n_layer
         dtype = dtype or cfg.dtype
-        shape = (P, cfg.kv_heads, ps, cfg.head_dim)
+        self.heads_per_row = r = heads_per_row(cfg.head_dim, cfg.kv_heads)
+        shape = (P, cfg.kv_heads // r, ps, r * cfg.head_dim)
         # two kinds of state in one manager: pages for the layers that keep
         # keys and values, a per-slot array for the layers with a recurrent
         # state (bound to the slot, not to pages: it does not grow with the

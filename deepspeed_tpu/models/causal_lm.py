@@ -9,7 +9,7 @@ parallel residual, GQA, gated MLP, pre/post-LN — and per-family constructors p
 Two execution paths:
 - ``forward(params, ids)``: full-sequence logits (training/scoring, flash/xla attention);
 - ``prefill(params, ids)`` / ``decode_step(params, cache, tok)``: KV-cache serving path.
-  The cache is head-major ``(b, h_kv, T, d)`` feeding ``ops/attention/decode.py``'s fused
+  The cache is head-major rows (``ops/paged_attention.heads_per_row``) feeding ``ops/attention/decode.py``'s fused
   kernel (reference hot loop ``softmax_context``, ``csrc/transformer/inference``).
 """
 
@@ -24,7 +24,9 @@ import numpy as np
 from jax.sharding import PartitionSpec as P
 
 from ..observability import scope
-from ..ops.attention.decode import decode_attention, decode_attention_xla
+from ..ops.attention.decode import (decode_attention, decode_attention_xla,
+                                    pack_queries, unpack_outputs)
+from ..ops.paged_attention import heads_per_row, kv_rows
 from ..ops.transformer.attention import xla_attention
 from ..parallel.overlap import (RowParallelDense, chunked_expert_exchange,
                                 get_overlap_config, moe_overlap_chunks,
@@ -823,7 +825,7 @@ class CausalLMLayer(nn.Module):
         path: the prefix's prefill compute is skipped entirely).
 
         With ``page_table`` (decode only): ``cache`` holds GLOBAL KV pages
-        ``{"k": (P, hk, page, d), ...}`` and the ``(b, max_pages)`` table maps
+        ``{"k": (P, hk / r, page, r * d), ...}`` and the ``(b, max_pages)`` table maps
         each row's positions to physical pages — the step appends K/V at the
         page-mapped row and attends through the paged-attention op (XLA dense
         gather sliced to ``kv_cap`` rows = bit-identical to a contiguous
@@ -886,6 +888,9 @@ class CausalLMLayer(nn.Module):
 
         slopes = (jnp.asarray(alibi_slopes(cfg.n_head))
                   if cfg.pos_emb == "alibi" else None)
+        if cache is not None:
+            # the KV heads a row of this cache holds (heads_per_row made it)
+            r = cache["k"].shape[-1] // cfg.head_dim
 
         new_kv = None
         if block_step:
@@ -893,11 +898,11 @@ class CausalLMLayer(nn.Module):
                 raise NotImplementedError(
                     "a block step runs on the dense cache view, without alibi")
             with scope("attn.heads"):
-                k_hm = k.transpose(0, 2, 1, 3)
+                k_hm = kv_rows(k, r)
             with scope("kv.append"):
                 k_cache = _cache_update(cache["k"], k_hm, cache_len)
             with scope("attn.heads"):
-                v_hm = v.transpose(0, 2, 1, 3)
+                v_hm = kv_rows(v, r)
             with scope("kv.append"):
                 v_cache = _cache_update(cache["v"], v_hm, cache_len)
             new_kv = {"k": k_cache, "v": v_cache}
@@ -910,8 +915,8 @@ class CausalLMLayer(nn.Module):
             cap = int(kv_cap if kv_cap is not None
                       else page_table.shape[1] * cache["k"].shape[2])
             with scope("attn.heads"):
-                k_hm = k.transpose(0, 2, 1, 3)   # (b, hk, 1, d)
-                v_hm = v.transpose(0, 2, 1, 3)
+                k_hm = kv_rows(k, r)             # (b, hk / r, 1, r * d)
+                v_hm = kv_rows(v, r)
             with scope("kv.append"):
                 k_pages, v_pages = paged_cache_update(
                     cache["k"], cache["v"], k_hm, v_hm, page_table, cache_len)
@@ -930,8 +935,8 @@ class CausalLMLayer(nn.Module):
         elif cache is not None and t == 1:
             # decode: append to cache (head-major), fused decode kernel
             with scope("attn.heads"):
-                k_hm = k.transpose(0, 2, 1, 3)   # (b, hk, 1, d)
-                v_hm = v.transpose(0, 2, 1, 3)
+                k_hm = kv_rows(k, r)             # (b, hk / r, 1, r * d)
+                v_hm = kv_rows(v, r)
             with scope("kv.append"):
                 k_cache = _cache_update(cache["k"], k_hm, cache_len)
                 v_cache = _cache_update(cache["v"], v_hm, cache_len)
@@ -948,8 +953,8 @@ class CausalLMLayer(nn.Module):
             # [cache_len, cache_len + t) (OOB pad rows drop), attend each suffix
             # query over every cache row at position <= its own
             with scope("attn.heads"):
-                k_hm = k.transpose(0, 2, 1, 3)   # (b, hk, t, d)
-                v_hm = v.transpose(0, 2, 1, 3)
+                k_hm = kv_rows(k, r)             # (b, hk / r, t, r * d)
+                v_hm = kv_rows(v, r)
 
             def put(c, n, i):
                 return c.at[:, i, :].set(n.astype(c.dtype))
@@ -971,8 +976,8 @@ class CausalLMLayer(nn.Module):
                 # prefill: write the prompt's K/V (post-rotary) into the fixed cache
                 T = cache["k"].shape[2]
                 with scope("attn.heads"):
-                    k_hm = k.transpose(0, 2, 1, 3)
-                    v_hm = v.transpose(0, 2, 1, 3)
+                    k_hm = kv_rows(k, r)
+                    v_hm = kv_rows(v, r)
                 pad = ((0, 0), (0, 0), (0, T - t), (0, 0))
                 with scope("kv.append"):
                     new_kv = {"k": jnp.pad(k_hm, pad).astype(cache["k"].dtype),
@@ -1147,12 +1152,14 @@ def _prefix_attention_xla(q, k_cache, v_cache, offset, slopes=None):
     XLA attention path here; rows beyond ``offset + t - 1`` (stale slab pad /
     unwritten) are masked out by construction.
 
-    q: (b, t, h, d); k_cache/v_cache: (b, hk, T, d); offset: (b,)."""
+    q: (b, t, h, d); k_cache/v_cache: (b, hk / r, T, r * d), the queries
+    packed to their rows (``pack_queries``); offset: (b,)."""
     b, t, h, d = q.shape
     hk, T = k_cache.shape[1], k_cache.shape[2]
+    r = k_cache.shape[3] // d
     g = h // hk
     scale = 1.0 / float(np.sqrt(d))
-    q5 = q.reshape(b, t, hk, g, d).astype(jnp.float32)
+    q5 = pack_queries(q, r, hk).reshape(b, t, hk, g, r * d).astype(jnp.float32)
     s = jnp.einsum("btkgd,bkTd->bkgtT", q5,
                    k_cache.astype(jnp.float32)) * scale
     q_pos = offset[:, None] + jnp.arange(t)[None]                  # (b, t)
@@ -1164,7 +1171,7 @@ def _prefix_attention_xla(q, k_cache, v_cache, offset, slopes=None):
     s = jnp.where(mask[:, None, None], s, -1e30)
     p = jax.nn.softmax(s, axis=-1)
     o = jnp.einsum("bkgtT,bkTd->btkgd", p, v_cache.astype(jnp.float32))
-    return o.reshape(b, t, h, d).astype(q.dtype)
+    return unpack_outputs(o.reshape(b, t, h, r * d), r, hk).astype(q.dtype)
 
 
 def _block_decode(q, k_cache, v_cache, lens, block: int):
@@ -1177,7 +1184,7 @@ def _block_decode(q, k_cache, v_cache, lens, block: int):
     row: the decode kernel, with no mask of its own, given a length a block
     (one block: the one-length kernel)."""
     b, t, h, d = q.shape
-    hk = k_cache.shape[1]
+    hk = k_cache.shape[1] * (k_cache.shape[3] // d)    # KV heads, r a cache row
     g = h // hk
     with scope("attn.heads"):
         rows = q.reshape(b, t, hk, g, d).transpose(0, 2, 1, 3, 4).reshape(
@@ -1190,8 +1197,9 @@ def _block_decode(q, k_cache, v_cache, lens, block: int):
 
 
 def _cache_update(cache, new, cache_len):
-    """cache: (b, hk, T, d); new: (b, hk, t, d), ``t`` = 1 for a decode step and
-    the block for a block step; write at per-sequence position.
+    """cache: (b, hk, T, d) rows (``ops/paged_attention.heads_per_row``); new:
+    (b, hk, t, d), ``t`` = 1 for a decode step and the block for a block step;
+    write at per-sequence position.
 
     One ``dynamic_update_slice`` a sequence with the sequence's index static:
     each is one in-place write on a loop's carry, a position at or past ``T``
@@ -1247,12 +1255,14 @@ def _sharded_decode(q, k_cache, v_cache, lens, alibi=None):
 
 
 def decode_attention_xla_alibi(q, k_cache, v_cache, cache_len, slopes):
-    """Decode attention with alibi bias (jnp path; bloom decode)."""
+    """Decode attention with alibi bias (jnp path; bloom decode); the cache
+    in rows, the queries packed to them, as ``decode_attention_xla``."""
     b, h, d = q.shape
     hk, T = k_cache.shape[1], k_cache.shape[2]
+    r = k_cache.shape[3] // d
     g = h // hk
     scale = 1.0 / float(np.sqrt(d))
-    q4 = q.reshape(b, hk, g, d).astype(jnp.float32)
+    q4 = pack_queries(q, r, hk).reshape(b, hk, g, r * d).astype(jnp.float32)
     s = jnp.einsum("bkgd,bktd->bkgt", q4, k_cache.astype(jnp.float32)) * scale
     pos = jnp.arange(T)[None, None, None, :]
     cur = (cache_len[:, None, None, None] - 1).astype(jnp.float32)
@@ -1261,7 +1271,7 @@ def decode_attention_xla_alibi(q, k_cache, v_cache, cache_len, slopes):
     s = jnp.where(mask, s, -1e30)
     p = jax.nn.softmax(s, axis=-1)
     o = jnp.einsum("bkgt,bktd->bkgd", p, v_cache.astype(jnp.float32))
-    return o.reshape(b, h, d).astype(q.dtype)
+    return unpack_outputs(o.reshape(b, h, r * d), r, hk).astype(q.dtype)
 
 
 class CausalLM(nn.Module):
@@ -1522,14 +1532,17 @@ def causal_lm_model(cfg: CausalLMConfig, sample_seq_len: Optional[int] = None,
 def init_cache(cfg: CausalLMConfig, batch_size: int, max_len: Optional[int] = None,
                dtype=None, kv_shape=None):
     """One cache a layer, typed by what the layer's kind keeps
-    (:data:`LAYER_KINDS`): fixed-capacity head-major keys and values
-    (``kv_shape`` where the caller lays them out otherwise: the paged pool's
-    pages), the kind's per-slot state for ``batch_size`` sequences
-    (``{"conv", "ssm"}`` state-space, ``{"conv"}`` short convolution), an
-    empty dict for a layer that keeps nothing."""
+    (:data:`LAYER_KINDS`): fixed-capacity head-major keys and values in rows
+    of ``r`` heads, ``(batch_size, kv_heads / r, T, r * head_dim)``
+    (``ops/paged_attention.heads_per_row`` says ``r``; ``kv_shape`` where the
+    caller lays them out otherwise: the paged pool's pages), the kind's
+    per-slot state for ``batch_size`` sequences (``{"conv", "ssm"}``
+    state-space, ``{"conv"}`` short convolution), an empty dict for a layer
+    that keeps nothing."""
     T = max_len or cfg.max_seq_len
     dtype = dtype or cfg.dtype
-    shape = kv_shape or (batch_size, cfg.kv_heads, T, cfg.head_dim)
+    r = heads_per_row(cfg.head_dim, cfg.kv_heads)
+    shape = kv_shape or (batch_size, cfg.kv_heads // r, T, r * cfg.head_dim)
     out = []
     for kind in cfg.layer_kinds:
         entry = LAYER_KINDS[kind]

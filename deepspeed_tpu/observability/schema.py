@@ -334,7 +334,8 @@ SPANS: Dict[str, Tuple[str, str, Tuple[str, ...], str]] = {
                               "configuration with level_random_experts: the "
                               "selection bias levelled on random tokens)"),
     "setup.kv_pool": (PHASE, "device set-up",
-                      ("pool", "pages", "slots", "state_bytes"),
+                      ("pool", "pages", "slots", "state_bytes",
+                       "heads_per_row"),
                       "setup_engine_init_s"),
     "setup.program": (PHASE, "device set-up", ("program", "bucket"),
                       "setup_engine_init_s lines, beside setup_compile_s"),

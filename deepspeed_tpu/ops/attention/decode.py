@@ -6,10 +6,10 @@ TPU-native equivalent of the reference's ``softmax_context`` inference kernel
 attention over the cache with online softmax, masked by the per-sequence cache length —
 no (T,) score materialisation in HBM, no dynamic shapes (the cache is a fixed-capacity buffer).
 
-The cache is stored HEAD-MAJOR ``(b, h_kv, T, d)`` — the same layout transformation the
-reference performs in ``transform.cu`` — so each kv head's cache block is contiguous and the
-per-head matmuls batch cleanly on the MXU. Supports grouped-query attention (``h_kv <= h``) by
-batching the q heads of each kv group into one matmul.
+The cache is stored HEAD-MAJOR in rows of whole lane tiles, ``(b, h_kv / r, T, r * d)`` (``r`` KV
+heads a row: ``ops/paged_attention.heads_per_row``, the row rule's one home; ``r`` = 1 from d 128),
+the layout transformation of ``transform.cu``: a row's cache block is contiguous and the matmuls
+batch on the MXU. Grouped queries (``h_kv <= h``) and a row's ``r`` heads are one matmul's rows.
 """
 
 import functools
@@ -106,14 +106,59 @@ def _decode_kernel(len_ref, q_ref, k_hbm, v_hbm, o_ref, *, block_k, scale, n_len
     )
 
 
+def _own_lanes(r: int):
+    """(r, 1, r, 1) bool over (head j of a row, its queries, lane block j', lane):
+    the lanes that are head j's own."""
+    return jnp.eye(r, dtype=bool).reshape(r, 1, r, 1)
+
+
+def pack_queries(q, r: int, rows: int):
+    """Queries for a cache whose rows hold ``r`` KV heads side by side
+    (``ops/paged_attention.heads_per_row``): ``q`` ``(..., h, d)`` ->
+    ``(..., h, r * d)``, the query heads of KV head ``p * r + j`` lying in lanes
+    ``[j * d, (j + 1) * d)`` and exact zeros elsewhere, so that a row's ``r * h
+    / (rows * r)`` query heads attend the row together and each one's scores
+    are its own head's. ``rows`` = ``h_kv / r``, the cache's. The softmax
+    scale stays the MODEL's ``1 / sqrt(d)``: the caller takes it before.
+
+    Both helpers are a broadcast, a select and (back) a sum of ``r`` terms of
+    which ``r - 1`` are zero: exact, and plain enough for every backend. The
+    form with lane-offset slices and a ``stack`` read the wrong lanes for
+    every head but a row's first once compiled for the TPU (libtpu 0.0.34; the
+    CPU gave the right ones: PERF.md section 6, PR 42), which
+    ``ops/kernel_checks.py: check_decode`` now holds on the chip."""
+    if r == 1:
+        return q
+    *lead, h, d = q.shape
+    q = q.reshape(*lead, rows, r, h // (rows * r), 1, d)
+    return jnp.where(_own_lanes(r), q, 0).reshape(*lead, h, r * d)
+
+
+def unpack_outputs(o, r: int, rows: int):
+    """The inverse on the attention's output: of ``o`` ``(..., h, r * d)`` a
+    query head of KV head ``p * r + j`` keeps lanes ``[j * d, (j + 1) * d)``
+    (the others hold its weights over the row's other heads' values)."""
+    if r == 1:
+        return o
+    *lead, h, rd = o.shape
+    o = o.reshape(*lead, rows, r, h // (rows * r), r, rd // r)
+    return jnp.where(_own_lanes(r), o, 0).sum(axis=-2).reshape(*lead, h, rd // r)
+
+
+def _row_lens(cache_len, r: int):
+    """``cache_len`` for packed queries: a row's rows are its ``r`` heads' runs
+    one after another, so ``(b, n)`` lengths repeat ``r`` times; ``(b,)`` stays."""
+    return cache_len if r == 1 or cache_len.ndim == 1 else jnp.tile(cache_len, (1, r))
+
+
 def decode_attention(q: jnp.ndarray, k_cache: jnp.ndarray, v_cache: jnp.ndarray,
                      cache_len: jnp.ndarray, softmax_scale=None,
                      block_k: int = 128) -> jnp.ndarray:
     """One decode step of attention against the cache.
 
-    q: ``(b, h, d)`` (current position); k_cache/v_cache: ``(b, h_kv, T, d)`` head-major
-    fixed-capacity; cache_len: ``(b,)`` valid lengths (the current position is already
-    written to the cache). Returns ``(b, h, d)``.
+    q: ``(b, h, d)`` (current position); k_cache/v_cache: ``(b, h_kv / r, T, r * d)``
+    head-major fixed-capacity rows (``r`` is read off their width); cache_len: ``(b,)`` valid
+    lengths (the current position is already written to the cache). Returns ``(b, h, d)``.
 
     ``cache_len`` ``(b, n)``: ``n`` lengths a sequence. The ``h / h_kv`` rows of
     every key head are then ``n`` equal runs in a row, run ``j`` seeing rows ``[0,
@@ -123,20 +168,23 @@ def decode_attention(q: jnp.ndarray, k_cache: jnp.ndarray, v_cache: jnp.ndarray,
     """
     b, h, d = q.shape
     hk, T = k_cache.shape[1], k_cache.shape[2]
-    if not (h % hk == 0):
-        raise AssertionError(f"query heads {h} must be a multiple of kv heads {hk}")
-    g = h // hk
+    r = k_cache.shape[3] // d
+    if not (h % (hk * r) == 0):
+        raise AssertionError(f"query heads {h} must be a multiple of kv heads {hk * r}")
     scale = softmax_scale if softmax_scale is not None else 1.0 / float(np.sqrt(d))
     if d % 128 != 0 and not _interpret():
-        # Mosaic requires HBM DMA slices 128-aligned in the minor dim; head_dim 64 caches
-        # take the XLA path (still fused/online-softmax'd by XLA, just not hand-scheduled):
-        # the cell lfm2-8b-a1b.conv32 (32Q/8KV x 64) decodes through here
+        # the choice reads the MODEL's head size: a configuration states which kernels
+        # its decode chunk holds (lfm2-8b-a1b.conv32, 32Q/8KV x 64: none for attention),
+        # so a d 64 model's packed rows, 128 lanes wide as Mosaic's HBM slices must be,
+        # stay in XLA until that route moves (PERF.md section 7)
         return decode_attention_xla(q, k_cache, v_cache, cache_len, softmax_scale)
+    q = pack_queries(q, r, hk)
+    g = h // hk                                 # a row's r heads x their group
     bk = min(block_k, T)
     while T % bk:
         bk //= 2
-    q4 = q.reshape(b, hk, g, d)
-    lens = cache_len.astype(jnp.int32)
+    q4 = q.reshape(b, hk, g, r * d)
+    lens = _row_lens(cache_len.astype(jnp.int32), r)
     n_lens = 1 if lens.ndim == 1 else lens.shape[1]
     if g % n_lens:
         raise AssertionError(f"{g} rows a key head are not {n_lens} equal runs")
@@ -147,35 +195,37 @@ def decode_attention(q: jnp.ndarray, k_cache: jnp.ndarray, v_cache: jnp.ndarray,
         num_scalar_prefetch=1,
         grid=(b,),
         in_specs=[
-            pl.BlockSpec((1, hk, g, d), lambda i, lens_ref: (i, 0, 0, 0)),
+            pl.BlockSpec((1, hk, g, r * d), lambda i, lens_ref: (i, 0, 0, 0)),
             pl.BlockSpec(memory_space=pl.ANY),   # cache stays in HBM, DMA'd blockwise
             pl.BlockSpec(memory_space=pl.ANY),
         ],
-        out_specs=pl.BlockSpec((1, hk, g, d), lambda i, lens_ref: (i, 0, 0, 0)),
+        out_specs=pl.BlockSpec((1, hk, g, r * d), lambda i, lens_ref: (i, 0, 0, 0)),
     )
     out = pl.pallas_call(
         functools.partial(_decode_kernel, block_k=bk, scale=scale, n_lens=n_lens),
         grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((b, hk, g, d), q.dtype),
+        out_shape=jax.ShapeDtypeStruct((b, hk, g, r * d), q.dtype),
         name="decode_attention",
         interpret=_interpret(),
     )(lens, q4, k_cache, v_cache)
-    return out.reshape(b, h, d)
+    return unpack_outputs(out.reshape(b, h, r * d), r, hk)
 
 
 def decode_attention_xla(q, k_cache, v_cache, cache_len, softmax_scale=None):
     """jnp reference implementation (ground truth for kernel tests; fallback path).
 
-    Same head-major cache layout ``(b, h_kv, T, d)`` as the kernel, and the same
-    two forms of ``cache_len``."""
+    Same cache rows as the kernel (the module docstring has them), and the
+    same two forms of ``cache_len``."""
     b, h, d = q.shape
     hk, T = k_cache.shape[1], k_cache.shape[2]
+    r = k_cache.shape[3] // d
     g = h // hk
     scale = softmax_scale if softmax_scale is not None else 1.0 / float(np.sqrt(d))
-    q4 = q.reshape(b, hk, g, d).astype(jnp.float32)
+    q4 = pack_queries(q, r, hk).reshape(b, hk, g, r * d).astype(jnp.float32)
     k = k_cache.astype(jnp.float32)
     v = v_cache.astype(jnp.float32)
     s = jnp.einsum("bkgd,bktd->bkgt", q4, k) * scale
+    cache_len = _row_lens(cache_len, r)
     if cache_len.ndim == 1:
         cache_len = cache_len[:, None]
     row_len = jnp.repeat(cache_len, g // cache_len.shape[1], axis=1)   # (b, g)
@@ -183,4 +233,4 @@ def decode_attention_xla(q, k_cache, v_cache, cache_len, softmax_scale=None):
     s = jnp.where(mask, s, NEG_INF)
     p = jax.nn.softmax(s, axis=-1)
     o = jnp.einsum("bkgt,bktd->bkgd", p, v)
-    return o.reshape(b, h, d).astype(q.dtype)
+    return unpack_outputs(o.reshape(b, h, r * d), r, hk).astype(q.dtype)

@@ -111,7 +111,18 @@ def check_decode() -> float:
     vc = jnp.asarray(rng.standard_normal((b, hk, T, d)), jnp.bfloat16)
     lens = jnp.asarray(rng.randint(100, T, size=(b,)), jnp.int32)
     o1 = jax.jit(decode_attention)(q, kc, vc, lens)
-    return _err(o1, decode_attention_xla(q, kc, vc, lens))
+    err = _err(o1, decode_attention_xla(q, kc, vc, lens))
+    # rows of two d 64 heads (``paged_attention.heads_per_row``; XLA's path on
+    # the chip) at lfm2-8b-a1b.conv32's shape, against a head a row: what the
+    # CPU cannot hold, how the chip's compiler lowers the packing of the queries
+    from .paged_attention import kv_rows
+    b, h, hk, d = 32, 32, 8, 64
+    q = jnp.asarray(rng.standard_normal((b, h, d)), jnp.bfloat16)
+    k, v = (jnp.asarray(rng.standard_normal((b, T, hk, d)), jnp.bfloat16)
+            for _ in range(2))
+    lens = jnp.asarray(rng.randint(40, T, size=(b,)), jnp.int32)
+    rows = jax.jit(decode_attention)(q, kv_rows(k, 2), kv_rows(v, 2), lens)
+    return max(err, _err(rows, decode_attention_xla(q, kv_rows(k, 1), kv_rows(v, 1), lens)))
 
 
 def check_block_sparse() -> float:

@@ -2,7 +2,7 @@
 
 The paged sibling of ``ops/attention/decode.py`` — vLLM's PagedAttention idiom
 done TPU-style. The KV store is one global pool of fixed-size pages per layer,
-``{"k": (P, h_kv, page, d), "v": ...}``; each decode slot owns a **static-shape
+``{"k": (P, h_kv / r, page, r * d), "v": ...}``; each decode slot owns a **static-shape
 page table** row ``(max_pages,)`` of physical page indices (padded with the
 null-page sentinel 0 — page 0 is reserved, never allocated, and every row it
 could contribute is masked by ``cache_len``). All shapes are static: the page
@@ -11,11 +11,15 @@ constants, so a slot serving an 8-token prompt and one serving a 500-token
 prompt hit the SAME compiled chunk — page-count growth never mints a compile
 key (pinned by the analysis sweep's serving lane).
 
-This module is the one place that knows the page layout ``(P, h_kv, page,
-d)``: pages -> dense rows (:func:`gather_kv_dense`, :func:`pages_to_dense`),
+This module is the one place that knows the page layout ``(P, h_kv / r, page,
+r * d)``: how many KV heads ``r`` lie side by side in a row of the pages, of
+the dense view and of the contiguous cache (:func:`heads_per_row`, the row
+rule's one home; :func:`kv_rows` lays a projection's keys or values out so),
+pages -> dense rows (:func:`gather_kv_dense`, :func:`pages_to_dense`),
 dense rows -> whole pages (:func:`write_dense_pages`) and row -> (page,
 offset) (:func:`page_address`, :func:`paged_cache_update`). The pool's movers
-and the serve programs call these.
+and the serve programs call these; all but the first two take a row's head and
+lane extents from their operands, whatever ``r`` made them.
 
 Two implementations, PR-5 style:
 
@@ -44,7 +48,8 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 from ..utils.device import pallas_interpret as _interpret
-from .attention.decode import NEG_INF, decode_attention_xla
+from .attention.decode import (NEG_INF, decode_attention_xla, pack_queries,
+                               unpack_outputs)
 
 FORCE_FUSED_ENV = "DS_TPU_PAGED_FORCE_FUSED"
 
@@ -66,11 +71,41 @@ def fused_paged_for(head_dim: int) -> bool:
     return fused_paged_active() and (head_dim % 128 == 0 or _interpret())
 
 
+# ------------------------------------------------------------- the row rule
+def heads_per_row(head_dim: int, kv_heads: int) -> int:
+    """How many consecutive KV heads ``r`` a cache row holds side by side:
+    pages ``(P, kv_heads / r, page, r * head_dim)``, dense view and contiguous
+    cache ``(b, kv_heads / r, T, r * head_dim)``. A row is one whole 128-lane
+    tile where the head size divides 128 and is smaller (two heads at 64: the
+    same bytes, no padding, where a 64-wide row half-fills every tile it
+    touches); 1 from 128 on, where the heads do not come in whole rows, and
+    under a tensor-parallel mesh that splits the KV heads finer than a row.
+    ``init_cache`` and ``PagedKVPool`` ask; everything downstream reads ``r``
+    off its operands (a cache's last extent over the model's head size)."""
+    from ..parallel.mesh import AXIS_TENSOR, get_global_mesh
+    r = 128 // head_dim if head_dim < 128 and 128 % head_dim == 0 else 1
+    mesh = get_global_mesh()
+    tp = mesh.size(AXIS_TENSOR) if mesh is not None else 1
+    local = kv_heads // tp if kv_heads % tp == 0 else kv_heads
+    return r if local % r == 0 else 1
+
+
+def kv_rows(x, r: int):
+    """A projection's keys or values ``(b, t, h_kv, d)`` (after the per-head
+    norm and the rotation) as head-major cache rows ``(b, h_kv / r, t, r *
+    d)``: a row's ``r`` heads are adjacent in memory already."""
+    b, t, hk, d = x.shape
+    if r > 1:
+        x = x.reshape(b, t, hk // r, r * d)
+    return x.transpose(0, 2, 1, 3)
+
+
 # ------------------------------------------------------------- dense gather
 def gather_kv_dense(k_pages, v_pages, page_table, cap: int):
     """Reassemble the dense head-major cache view from pages.
 
-    ``k_pages``/``v_pages``: ``(P, hk, page, d)``; ``page_table``:
+    ``k_pages``/``v_pages``: ``(P, hk, page, d)`` (``hk`` rows of ``d`` lanes,
+    here and below: :func:`heads_per_row`); ``page_table``:
     ``(b, max_pages)`` int32. Returns ``(b, hk, cap, d)`` ×2 — rows sliced to
     EXACTLY ``cap`` so downstream attention math (reduction shapes included)
     is identical to a contiguous ``cap``-row cache's, keeping greedy
@@ -237,18 +272,19 @@ def paged_attention_fused(q, k_pages, v_pages, page_table, cache_len,
                           softmax_scale=None):
     """One decode step of paged attention through the Pallas kernel.
 
-    q: ``(b, h, d)``; k/v_pages: ``(P, hk, page, d)``; page_table:
+    q: ``(b, h, d)``; k/v_pages: ``(P, hk / r, page, r * d)``; page_table:
     ``(b, max_pages)``; cache_len: ``(b,)``. Interpret mode off-TPU."""
     b, h, d = q.shape
     hk, ps = k_pages.shape[1], k_pages.shape[2]
-    if h % hk != 0:
+    r = k_pages.shape[3] // d
+    if h % (hk * r) != 0:
         raise AssertionError(f"query heads {h} must be a multiple of kv "
-                             f"heads {hk}")
+                             f"heads {hk * r}")
     g = h // hk
     mp = page_table.shape[1]
     scale = (softmax_scale if softmax_scale is not None
              else 1.0 / float(np.sqrt(d)))
-    q4 = q.reshape(b, hk, g, d)
+    q4 = pack_queries(q, r, hk).reshape(b, hk, g, r * d)
     lens = cache_len.astype(jnp.int32)
     table = page_table.astype(jnp.int32).reshape(-1)
 
@@ -256,23 +292,23 @@ def paged_attention_fused(q, k_pages, v_pages, page_table, cache_len,
         num_scalar_prefetch=2,
         grid=(b,),
         in_specs=[
-            pl.BlockSpec((1, hk, g, d), lambda i, lens_ref, table_ref:
+            pl.BlockSpec((1, hk, g, r * d), lambda i, lens_ref, table_ref:
                          (i, 0, 0, 0)),
             pl.BlockSpec(memory_space=pl.ANY),   # pages stay in HBM
             pl.BlockSpec(memory_space=pl.ANY),
         ],
-        out_specs=pl.BlockSpec((1, hk, g, d), lambda i, lens_ref, table_ref:
+        out_specs=pl.BlockSpec((1, hk, g, r * d), lambda i, lens_ref, table_ref:
                                (i, 0, 0, 0)),
     )
     out = pl.pallas_call(
         functools.partial(_paged_decode_kernel, page=ps, max_pages=mp,
                           scale=scale),
         grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((b, hk, g, d), q.dtype),
+        out_shape=jax.ShapeDtypeStruct((b, hk, g, r * d), q.dtype),
         name="paged_decode",
         interpret=_interpret(),
     )(lens, table, q4, k_pages, v_pages)
-    return out.reshape(b, h, d)
+    return unpack_outputs(out.reshape(b, h, r * d), r, hk)
 
 
 def paged_attention(q, k_pages, v_pages, page_table, cache_len, cap: int,

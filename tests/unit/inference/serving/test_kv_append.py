@@ -34,28 +34,63 @@ def _lens(slots, rng):
     return [mixed, past]
 
 
+def _unpacked(rows, r):
+    """Cache rows (slots, hk / r, T, r * d) -> the heads apart, (slots, hk, T, d)."""
+    s, p, T, rd = rows.shape
+    return rows.reshape(s, p, T, r, rd // r).transpose(0, 1, 3, 2, 4).reshape(
+        s, p * r, T, rd // r)
+
+
 @pytest.mark.parametrize("dtype", [jnp.bfloat16, jnp.float32],
                          ids=["bf16", "f32"])
 @pytest.mark.parametrize("slots", [1, 2, 32])
-def test_the_append_writes_what_the_vmapped_update_wrote(slots, dtype):
+@pytest.mark.parametrize("hk,d,r", [(HK, D, 1), (4, 64, 2)], ids=["a-head-a-row", "two-heads-a-row"])
+def test_the_append_writes_what_the_vmapped_update_wrote(slots, dtype, hk, d, r):
+    """Whatever the row holds: with ``r`` KV heads side by side in it
+    (``ops/paged_attention.heads_per_row``) the step's keys are laid out in
+    rows once (``kv_rows``) and the same in-place write puts each head's ``d``
+    values where the vmapped update of the heads-apart cache put them."""
+    from deepspeed_tpu.ops.paged_attention import heads_per_row, kv_rows
+    assert heads_per_row(d, hk) == r
     rng = np.random.default_rng(slots)
-    cache = jnp.asarray(rng.standard_normal((slots, HK, CAP, D)), dtype)
-    # the step's keys arrive in the compute type and are cast to the cache's
-    new = jnp.asarray(rng.standard_normal((slots, HK, 1, D)), jnp.float32)
+    cache = kv_rows(jnp.asarray(rng.standard_normal((slots, CAP, hk, d)), dtype), r)
+    assert cache.shape == (slots, hk // r, CAP, r * d)
+    # the step's keys arrive in the compute type, as the projection wrote them,
+    # and are cast to the cache's
+    proj = jnp.asarray(rng.standard_normal((slots, 1, hk, d)), jnp.float32)
+    new = kv_rows(proj, r)
     for lens in _lens(slots, rng):
         lens = jnp.asarray(lens, jnp.int32)
-        want = np.asarray(_vmapped_update(cache, new, lens), np.float32)
+        want = np.asarray(_vmapped_update(_unpacked(cache, r), proj.transpose(0, 2, 1, 3),
+                                          lens), np.float32)
         for fn in (_cache_update, jax.jit(_cache_update)):
             got = fn(cache, new, lens)
             assert got.dtype == cache.dtype and got.shape == cache.shape
-            np.testing.assert_array_equal(np.asarray(got, np.float32), want)
+            np.testing.assert_array_equal(np.asarray(_unpacked(got, r), np.float32), want)
         # exactly one row a slot changed, the others are the cache's
         rows = np.minimum(np.asarray(lens), CAP - 1)
         same = np.ones((slots, CAP), bool)
         same[np.arange(slots), rows] = False
         np.testing.assert_array_equal(
             want.transpose(0, 2, 1, 3)[same],
-            np.asarray(cache, np.float32).transpose(0, 2, 1, 3)[same])
+            np.asarray(_unpacked(cache, r), np.float32).transpose(0, 2, 1, 3)[same])
+
+
+@pytest.mark.parametrize("hk,d,r", [(8, 64, 2), (2, 128, 1)], ids=["d64", "d128"])
+def test_the_append_lowers_to_one_full_row_write_a_sequence(hk, d, r):
+    """One ``dynamic_update_slice`` a sequence, its update a whole row of ``r
+    * d`` = 128 lanes for every row of heads: at d 64 half as many lanes-wide
+    writes as heads, none of them half a lane tile."""
+    from deepspeed_tpu.ops.paged_attention import heads_per_row
+    assert heads_per_row(d, hk) == r
+    slots = 5
+    cache = jnp.zeros((slots, hk // r, CAP, r * d), jnp.bfloat16)
+    new = jnp.zeros((slots, hk // r, 1, r * d), jnp.bfloat16)
+    text = jax.jit(_cache_update).lower(cache, new, jnp.zeros((slots,), jnp.int32)).as_text()
+    writes = [line for line in text.splitlines() if "dynamic_update_slice" in line]
+    assert len(writes) == slots
+    assert all(f"tensor<1x{hk // r}x1x128xbf16>" in line for line in writes)
+    assert "scatter" not in text and "while" not in text
 
 
 def test_the_append_on_a_loop_carry_matches_step_by_step():
@@ -164,3 +199,72 @@ def test_the_view_of_a_block_model_holds_two_blocks_past_the_cap(cap, block, row
     np.testing.assert_array_equal(got[1, :, :cap - block],
                                   np.asarray(cache)[1, :, :cap - block])
     np.testing.assert_array_equal(got[1, :, cap - block:cap + block], np.asarray(new)[1])
+
+
+# ------------------------------------------- rows of two heads in the chunk's loop (PR 42)
+def _sub_jaxprs(eqn):
+    for v in eqn.params.values():
+        for x in (v if isinstance(v, (list, tuple)) else [v]):
+            inner = getattr(x, "jaxpr", x)
+            if hasattr(inner, "eqns"):
+                yield inner
+
+
+def _eqns(jaxpr):
+    for eqn in jaxpr.eqns:
+        yield eqn
+        for inner in _sub_jaxprs(eqn):
+            yield from _eqns(inner)
+
+
+@pytest.mark.parametrize("hidden,heads,hk,r", [(256, 4, 2, 2), (512, 8, 8, 2), (256, 2, 2, 1)],
+                         ids=["d64-hk2", "d64-hk8", "d128"])
+def test_the_chunks_loop_moves_no_operand_the_size_of_a_view(hidden, heads, hk, r):
+    """The decode chunk of the tiny LFM2 at head 64 (and, ``r`` = 1, at 128):
+    the pool's pages and the dense view are rows of ``r`` heads, the step
+    packs its QUERIES to them, and inside the loop's body nothing as large as
+    a layer's view is transposed or reshaped: the cache is read where it
+    lies. Outside the loop the gather's page-to-row transpose stays, once a
+    chunk."""
+    from tests.unit import lfm2_tiny as lt
+    from deepspeed_tpu.inference.decode_fns import (build_paged_decode_chunk,
+                                                    make_slot_select_fn)
+    from deepspeed_tpu.inference.serving.kv_pool import PagedKVPool
+    from deepspeed_tpu.models.causal_lm import CausalLM
+    slots, cap, page, chunk = 3, 32, 8, 4
+    cfg = lt.config(hidden_size=hidden, num_attention_heads=heads,
+                    num_key_value_heads=hk, max_seq_len=cap)
+    d = hidden // heads
+    pool = PagedKVPool(cfg, slots, cap, page_size=page)
+    assert pool.heads_per_row == r
+    (pages,) = [c for c in pool.caches if "k" in c]
+    assert pages["k"].shape == (slots * (cap // page) + 1, hk // r, page, r * d)
+    module = CausalLM(cfg)
+    params = jax.eval_shape(lambda: module.init(
+        jax.random.PRNGKey(0), jnp.zeros((1, 8), jnp.int32))["params"])
+    fn = build_paged_decode_chunk(module, lambda p: p,
+                                  make_slot_select_fn(False, 1.0, 0, 1.0),
+                                  chunk, kv_cap=cap)
+    ints = jnp.zeros((slots,), jnp.int32)
+    jaxpr = jax.make_jaxpr(fn)(
+        params, jnp.zeros((slots, 1), jnp.int32), pool.caches,
+        jnp.asarray(pool.page_table), ints, ints.astype(bool), ints, ints, ints, ints,
+        jnp.zeros((2,), jnp.uint32)).jaxpr
+    # a fori_loop of static trip count is a scan in the jaxpr, a while once lowered
+    (loop,) = [e for e in jaxpr.eqns if e.primitive.name in ("while", "scan")]
+    view = slots * hk * cap * d
+
+    def cache_sized(e):         # the tiny model's weight matrices are as large: 2-D
+        aval = e.invars[0].aval
+        return aval.size >= view and aval.ndim >= 4
+
+    moved = [(e.primitive.name, e.invars[0].aval.shape) for inner in _sub_jaxprs(loop)
+             for e in _eqns(inner) if e.primitive.name in ("transpose", "reshape")
+             and cache_sized(e)]
+    assert not moved, moved
+    # the view the loop carries is rows of r heads, and each step appends whole rows
+    carried = [v.aval.shape for v in loop.invars if getattr(v.aval, "ndim", 0) == 4]
+    assert (slots, hk // r, cap, r * d) in carried
+    # outside it: the gather's own transpose of the gathered pages, k and v
+    outside = [e for e in jaxpr.eqns if e.primitive.name == "transpose" and cache_sized(e)]
+    assert len(outside) == 2

@@ -285,6 +285,97 @@ def test_dense_rows_to_pages_and_back(rows, batched):
         np.testing.assert_array_equal(np.asarray(k2[0]), dense[key])
 
 
+# --------------------------------------------- rows of several KV heads (PR 42)
+def _wide(n_head, **over):
+    """A GPT-2 of width 256: 4 heads x 64 (two rows of two heads) or 2 x 128."""
+    return gpt2_cfg(**{**TINY, "n_embd": 256, "n_head": n_head, **over})
+
+
+@pytest.mark.parametrize("n_head,r", [(4, 2), (2, 1)], ids=["d64", "d128"])
+def test_rows_of_heads_go_page_to_dense_to_page_bit_for_bit(n_head, r):
+    """The pool of a d 64 model holds two KV heads in every 128-lane row of
+    its pages (``heads_per_row``; one at d 128): a prefill's rows scatter in,
+    the chunk's batched gather and the slab API read them back as they were
+    laid out, and written back they give the same pages, bit for bit."""
+    from deepspeed_tpu.models.causal_lm import init_cache
+    from deepspeed_tpu.ops.paged_attention import heads_per_row, kv_rows
+    cfg = _wide(n_head, dtype=jnp.bfloat16)
+    d, cap, ps = 256 // n_head, 40, 8
+    assert heads_per_row(d, n_head) == r
+    pool = PagedKVPool(cfg, slots=2, cap=cap, page_size=ps)
+    assert pool.heads_per_row == r
+    assert pool.caches[0]["k"].shape == (11, n_head // r, ps, r * d) == \
+        (11, n_head // r, ps, 128)
+    assert pool.page_nbytes == 2 * cfg.n_layer * n_head * ps * d * 2
+    assert init_cache(cfg, 3, cap)[0]["k"].shape == (3, n_head // r, cap, 128)
+    rng = np.random.default_rng(r)
+    pool.acquire(tokens=8)
+    slot = pool.acquire(tokens=cap)
+    # as a prefill hands them over: the projection's (1, t, hk, d) laid out in rows
+    one = [{key: kv_rows(jnp.asarray(rng.standard_normal((1, cap, n_head, d)),
+                                     jnp.bfloat16), r) for key in ("k", "v")}
+           for _ in range(cfg.n_layer)]
+    pool.scatter_prefill(slot, one)
+    before = [{key: np.asarray(c[key], np.float32) for key in c} for c in pool.caches]
+    tbl = jnp.asarray(pool.page_table[slot])
+    for c, o in zip(pool.caches, one):
+        kd, vd = gather_kv_dense(c["k"], c["v"], tbl[None], cap)
+        np.testing.assert_array_equal(np.asarray(kd, np.float32),
+                                      np.asarray(o["k"], np.float32))
+        np.testing.assert_array_equal(np.asarray(vd, np.float32),
+                                      np.asarray(o["v"], np.float32))
+        again = write_dense_pages(c, {"k": kd, "v": vd}, tbl)
+        for key in ("k", "v"):
+            np.testing.assert_array_equal(np.asarray(again[key], np.float32),
+                                          np.asarray(c[key], np.float32))
+    # the slab API (the host tier's and the wire's format) is rows too
+    slab = pool.gather_prefix(slot, 21)
+    assert slab[0]["k"].shape == (n_head // r, 21, 128)
+    pool.release(0)                         # both slots were taken: reuse the first
+    fresh = pool.acquire(tokens=21)
+    pool.restore_prefix(fresh, slab)
+    for a, b in zip(slab, pool.gather_prefix(fresh, 21)):
+        for key in ("k", "v"):
+            np.testing.assert_array_equal(np.asarray(a[key], np.float32),
+                                          np.asarray(b[key], np.float32))
+    # and nothing of the first slot's pages moved meanwhile
+    for c, b4 in zip(pool.caches, before):
+        pages = pool.page_table[slot][:cap // ps]
+        np.testing.assert_array_equal(np.asarray(c["k"], np.float32)[pages], b4["k"][pages])
+
+
+@pytest.mark.parametrize("n_head,r", [(4, 2), (2, 1)], ids=["d64", "d128"])
+def test_the_pools_phase_says_how_many_heads_a_row_holds(n_head, r):
+    """``setup.kv_pool`` carries ``heads_per_row`` (declared in the schema): 2
+    for a d 64 model, 1 from d 128 on; and the scheduler over either pool
+    serves what ``engine.generate`` gives, a prefix hit included."""
+    from deepspeed_tpu.observability import schema
+    from deepspeed_tpu.observability.trace import get_tracer
+    assert "heads_per_row" in schema.SPANS["setup.kv_pool"][2]
+    eng = InferenceEngine(_wide(n_head), ds.inference.DeepSpeedInferenceConfig(
+        dtype="float32", max_out_tokens=CAP))
+    tracer = get_tracer()
+    tracer.reset()
+    tracer.enable()
+    try:
+        sched = _sched(eng, cache=True)
+        spans = list(tracer.spans)
+    finally:
+        tracer.disable()
+        tracer.reset()
+    (attrs,) = [s["attrs"] for s in spans if s["name"] == "setup.kv_pool"]
+    assert attrs["heads_per_row"] == sched.executor.pool.heads_per_row == r
+    rng = np.random.default_rng(11)
+    shared = rng.integers(0, 96, size=16).astype(np.int32)
+    prompts = [np.concatenate([shared, rng.integers(0, 96, size=n).astype(np.int32)])
+               for n in (4, 7)]
+    hs = [sched.submit(p, max_new_tokens=7) for p in prompts]
+    sched.run()
+    assert [h.prefix_hit_tokens for h in hs] == [0, 16]
+    for h, p in zip(hs, prompts):
+        np.testing.assert_array_equal(h.result(), _ref(eng, p, 7))
+
+
 # ------------------------------------------------------- kernel-vs-XLA parity
 def test_paged_attention_kernel_vs_xla():
     """The Pallas gather-by-page-index kernel (interpret mode on CPU — the

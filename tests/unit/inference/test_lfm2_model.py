@@ -278,3 +278,86 @@ def test_a_short_convolution_refuses_a_prefill_at_an_offset(tiny):
         module.apply({"params": params}, jnp.zeros((1, 4), jnp.int32),
                      caches=init_cache(cfg, 1, 16), cache_lens=jnp.asarray([4]),
                      prefix_fill=True)
+
+
+# --------------------------------------------- rows of two KV heads at head 64 (PR 42)
+HEAD64 = {2: dict(hidden_size=256, num_attention_heads=4, num_key_value_heads=2),
+          8: dict(hidden_size=512, num_attention_heads=8, num_key_value_heads=8)}
+
+
+@pytest.mark.parametrize("hk", [2, 8])
+def test_at_head_64_the_pool_keeps_two_heads_a_row_and_serves_the_same(hk):
+    """LFM2's attention at its published head size, 64 (the tiny model's is
+    16, whose two KV heads fill no row): the pool's pages and ``init_cache``'s
+    cache hold two KV heads in a 128-lane row, prefill then decode through
+    the pool stays inside this file's limit against the reference's whole
+    forward, and the scheduler gives ``engine.generate``'s tokens."""
+    from deepspeed_tpu.inference.serving.prefix_cache import PrefixCacheConfig
+    from deepspeed_tpu.inference.serving.scheduler import (
+        ContinuousBatchingScheduler, ServingConfig)
+    from deepspeed_tpu.models.causal_lm import init_cache
+    over = HEAD64[hk]
+    cfg = lt.config(**over)
+    assert cfg.head_dim == 64 and cfg.kv_heads == hk
+    module, params = lt.init(cfg)
+    ids = lt.ids(30, seed=3)[0]
+    got, pool = _served_logits(cfg, module, params, ids, 13)
+    want = REF.forward(params, {**lt.MODEL, **over}, ids)[12:]
+    assert got.shape == want.shape
+    assert float(jnp.abs(got - want).max()) < TOL * float(want.std())
+    assert pool.heads_per_row == 2
+    (pages,) = [c for c in pool.caches if "k" in c]
+    assert pages["k"].shape[1:] == (hk // 2, 8, 128)
+    (cache,) = [c for c in init_cache(cfg, 3, 64) if "k" in c]
+    assert cache["k"].shape == (3, hk // 2, 64, 128)
+    eng = _engine(level_random_experts=True, **over)
+    sched = ContinuousBatchingScheduler(eng, ServingConfig(
+        slots=2, chunk_size=4, max_seq_len=64, max_queue=8, kv_page_size=8,
+        prefix_cache=PrefixCacheConfig(enabled=False)))
+    prompts = [lt.ids(n, seed=n)[0] for n in (5, 16, 9)]
+    handles = [sched.submit(p, max_new_tokens=6 + i) for i, p in enumerate(prompts)]
+    sched.run()
+    for p, h in zip(prompts, handles):
+        alone = eng.generate(p[None], max_new_tokens=len(h.tokens))[0, p.size:]
+        assert list(h.tokens) == [int(t) for t in alone], p.size
+    want = REF.next_token_logits(eng.params, {**lt.MODEL, **over}, prompts[1],
+                                 [prompts[1].size - 1], vocab_block=128, pad_to=16)
+    assert int(want[0].argmax()) == handles[1].tokens[0]
+
+
+@pytest.mark.parametrize("speculate", [False, True], ids=["prefix-hit", "verify-round"])
+def test_a_gpt2_shaped_head_64_model_through_a_prefix_hit_and_a_verify_round(speculate):
+    """GPT-2's shape (every head its own keys, 64 wide: rows of two heads,
+    ``g`` = 1 a head): a prefill at an offset over bound prefix pages and the
+    speculative verify round both read the dense view in rows with packed
+    queries, and give ``engine.generate``'s tokens."""
+    from deepspeed_tpu.inference.config import DeepSpeedInferenceConfig
+    from deepspeed_tpu.inference.engine import InferenceEngine
+    from deepspeed_tpu.inference.serving.prefix_cache import PrefixCacheConfig
+    from deepspeed_tpu.inference.serving.scheduler import (
+        ContinuousBatchingScheduler, ServingConfig)
+    from deepspeed_tpu.models.causal_lm import gpt2_cfg
+    eng = InferenceEngine(
+        gpt2_cfg(vocab_size=96, max_seq_len=64, n_embd=256, n_layer=2, n_head=4,
+                 dtype=jnp.float32),
+        DeepSpeedInferenceConfig(dtype="float32", max_out_tokens=48), seed=5)
+    sched = ContinuousBatchingScheduler(eng, ServingConfig(
+        slots=2, chunk_size=3, max_seq_len=48, kv_page_size=8, speculate=speculate,
+        spec_k=4, prefix_cache=PrefixCacheConfig(
+            min_hit_tokens=4, min_insert_tokens=4, insert_on="prefill")))
+    assert sched.executor.pool.heads_per_row == 2
+    rng = np.random.default_rng(7)
+    shared = rng.integers(0, 96, size=13).astype(np.int32)     # ends inside a page: a COW
+    unit = rng.integers(0, 96, size=3).astype(np.int32)
+    prompts = [np.concatenate([shared, np.tile(unit, 3)]),
+               np.concatenate([shared, np.tile(unit, 2), unit[:2]])]
+    hs = []
+    for p in prompts:
+        hs.append(sched.submit(p, max_new_tokens=9))
+        sched.run()
+    assert hs[0].prefix_hit_tokens == 0 and hs[1].prefix_hit_tokens > 0
+    for p, h in zip(prompts, hs):
+        alone = np.asarray(eng.generate(p[None], max_new_tokens=9))[0, p.size:]
+        np.testing.assert_array_equal(h.result(), alone)
+    if speculate:
+        assert sched.telemetry.snapshot()["spec_accepted"] > 0

@@ -1,6 +1,8 @@
 """Attention kernel tests — numerical equivalence vs the jnp reference, the pattern of the
 reference's ``tests/unit/ops/`` kernel-vs-torch comparisons."""
 
+import functools
+
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -360,6 +362,87 @@ def test_decode_respects_cache_len():
     vc2 = vc.at[:, :, 7:].set(-999.0)
     o2 = decode_attention(q, kc2, vc2, lens, block_k=8)
     np.testing.assert_allclose(np.asarray(o1), np.asarray(o2), rtol=1e-6)
+
+
+# ----------------------------------------------------------- rows of several KV heads
+def _per_head_softmax(q, k, v, row_len):
+    """The plain thing, a head at a time on the UNPACKED cache: ``q`` (b, h,
+    d), ``k``/``v`` (b, hk, T, d), ``row_len`` (b, h) rows a query head sees."""
+    b, h, d = q.shape
+    hk, T = k.shape[1], k.shape[2]
+    out = np.zeros((b, h, d))
+    for i in range(b):
+        for j in range(h):
+            n = int(row_len[i, j])
+            s = k[i, j // (h // hk), :n] @ q[i, j] / np.sqrt(d)
+            w = np.exp(s - s.max())
+            out[i, j] = (w / w.sum()) @ v[i, j // (h // hk), :n]
+    return out
+
+
+PACKED = [(64, 8, 4), (64, 12, 1), (32, 4, 2), (64, 1, 8), (128, 4, 8)]
+
+
+#: ``cache_len`` as (b,), (b, 1) and (b, 2); one query head a KV head has no two runs
+PACKED_LENS = [(d, hk, g, n) for d, hk, g in PACKED for n in (0, 1, 2) if n < 2 or g % 2 == 0]
+
+
+@pytest.mark.parametrize("path", ["xla", "mosaic-interpret"])
+@pytest.mark.parametrize("d,hk,g,n_lens", PACKED_LENS, ids=[
+    f"d{d}-hk{hk}-g{g}-lens-b{n or ''}" for d, hk, g, n in PACKED_LENS])
+def test_packed_row_decode_attention_is_the_per_head_softmax(d, hk, g, n_lens, path):
+    """A cache whose rows hold ``r`` KV heads side by side, read by packed
+    queries, gives every head its own softmax over its own keys: against the
+    per-head numpy form on the unpacked cache, with one length a sequence and
+    with the runs of a block step, through the XLA path and the Mosaic body."""
+    from deepspeed_tpu.ops.paged_attention import heads_per_row, kv_rows
+    r = heads_per_row(d, hk)
+    assert r == {(64, 8): 2, (64, 12): 2, (32, 4): 4, (64, 1): 1, (128, 4): 1}[d, hk]
+    b, T = 3, 64
+    h = hk * g
+    rng = np.random.default_rng(d + hk + g)
+    q = rng.normal(size=(b, h, d)).astype(np.float32)
+    k = rng.normal(size=(b, T, hk, d)).astype(np.float32)
+    v = rng.normal(size=(b, T, hk, d)).astype(np.float32)
+    lens = np.array([[1, 9], [33, 40], [60, 64]], np.int32)[:, :max(n_lens, 1)]
+    # a head's g rows are n equal runs, run j seeing lens[:, j] rows
+    row_len = np.tile(np.repeat(lens, g // lens.shape[1], axis=1), (1, hk))
+    want = _per_head_softmax(q, k.transpose(0, 2, 1, 3), v.transpose(0, 2, 1, 3), row_len)
+    kc, vc = kv_rows(jnp.asarray(k), r), kv_rows(jnp.asarray(v), r)
+    assert kc.shape == (b, hk // r, T, r * d)
+    fn = decode_attention_xla if path == "xla" else functools.partial(
+        decode_attention, block_k=16)
+    got = fn(jnp.asarray(q), kc, vc, jnp.asarray(lens[:, 0] if n_lens == 0 else lens))
+    assert got.shape == (b, h, d)
+    np.testing.assert_allclose(np.asarray(got), want, rtol=2e-5, atol=2e-5)
+
+
+@pytest.mark.parametrize("d,hk,g", PACKED, ids=[f"d{d}-hk{hk}-g{g}" for d, hk, g in PACKED])
+def test_packed_queries_and_outputs_round_trip_exactly(d, hk, g):
+    from deepspeed_tpu.ops.attention.decode import pack_queries, unpack_outputs
+    from deepspeed_tpu.ops.paged_attention import heads_per_row
+    r = heads_per_row(d, hk)
+    rng = np.random.default_rng(d)
+    q = jnp.asarray(rng.normal(size=(2, 3, hk * g, d)), jnp.bfloat16)
+    packed = pack_queries(q, r, hk // r)
+    if r == 1:
+        assert packed is q and unpack_outputs(q, r, hk) is q
+        return
+    assert packed.shape == (2, 3, hk * g, r * d)
+    np.testing.assert_array_equal(np.asarray(unpack_outputs(packed, r, hk // r), np.float32),
+                                  np.asarray(q, np.float32))
+    # a query head of KV head p * r + j lies in lanes [j * d, (j + 1) * d), zeros elsewhere
+    lanes = np.asarray(packed, np.float32).reshape(2, 3, hk // r, r, g, r, d)
+    for j in range(r):
+        for other in range(r):
+            block = lanes[:, :, :, j, :, other]
+            assert (block != 0).all() if other == j else not block.any()
+    # a select and a sum, nothing else: lane-offset slices joined by a stack gave
+    # wrong lanes once compiled for the TPU (kernel_checks.check_decode holds it there)
+    for fn, x in ((pack_queries, q), (unpack_outputs, packed)):
+        used = {e.primitive.name for e in jax.make_jaxpr(
+            lambda x: fn(x, r, hk // r))(x).jaxpr.eqns}
+        assert not used & {"slice", "concatenate", "pad", "gather", "dynamic_slice"}, used
 
 
 # ------------------------------------------------------------------------ model integration
