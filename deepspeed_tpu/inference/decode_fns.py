@@ -263,7 +263,11 @@ def build_paged_spec_verify(module, dequant, kv_cap: int, overlap=None):
 def build_decode_loop(module, dequant, select, gen_cap: int, overlap=None):
     """Whole-batch run-to-completion decode: ONE ``lax.while_loop`` for all remaining
     tokens, EOS termination as an on-device reduction in the loop condition
-    (``InferenceEngine.generate``'s decode shape)."""
+    (``InferenceEngine.generate``'s decode shape). Returns ``(buf, n, caches)``:
+    the caches come back so that a caller that donates them gives the loop its
+    argument's buffers to update in place; one that does not pays a second copy
+    of them as the loop's carry (at 64 rows of Granite's recurrent state 6 GB,
+    more than the chip has beside the weights)."""
 
     def decode_loop_inner(params, tok0, caches, lens, n_new, eos, rng):
         # HOISTED param prep: on the XLA fallback path ``dequant`` collapses
@@ -298,8 +302,8 @@ def build_decode_loop(module, dequant, select, gen_cap: int, overlap=None):
         # lens is each sequence's append position: the prompt's true length (generated
         # tokens overwrite right-pad slots in the cache; decode masks by cache_len)
         state = (jnp.int32(1), tok0, caches, lens, finished0, buf)
-        n, _, _, _, _, buf = jax.lax.while_loop(cond, body, state)
-        return buf, n
+        n, _, caches, _, _, buf = jax.lax.while_loop(cond, body, state)
+        return buf, n, caches
 
     def decode_loop(*args):
         # overlap_scope is a trace-time effect: the while_loop body traces

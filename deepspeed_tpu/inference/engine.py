@@ -409,16 +409,18 @@ class InferenceEngine:
         decode_loop = build_decode_loop(self.module, self._dequant, select, gen_cap,
                                         overlap=self.comm_overlap)
 
-        # No donation on either fn: prefill rebuilds cache buffers (pad-write) and the loop
-        # reuses its carry buffers internally — donating caches cannot alias any output
-        # (they are not returned) and only produces "donated buffer not usable" warnings.
+        # The loop's caches are donated and come back (``generate`` drops them): the
+        # loop then updates its argument's buffers in place instead of carrying a
+        # second copy of them. No donation on prefill (it rebuilds the cache buffers,
+        # pad-write) nor on the block loop, which returns no caches to alias.
+        loop_jit = jax.jit(decode_loop, donate_argnums=(2,))
         if self.model_config.gen_block_length:
             # the loop the serving chunk's forwards are: the same body
-            decode_loop = build_block_decode_loop(
+            loop_jit = jax.jit(build_block_decode_loop(
                 self.module, self._dequant,
                 make_slot_select_fn(do_sample, temperature, top_k, top_p), gen_cap,
-                overlap=self.comm_overlap)
-        fns = (jax.jit(prefill), jax.jit(decode_loop))
+                overlap=self.comm_overlap))
+        fns = (jax.jit(prefill), loop_jit)
         self._fns[key] = fns
         return fns
 
@@ -561,8 +563,8 @@ class InferenceEngine:
         # cache room is guaranteed: cap >= t + max_new_tokens, and the last appended KV
         # lands at position t + max_new_tokens - 2 < cap
         t1 = time.perf_counter()
-        buf, n = decode_loop(self.params, tok0, caches, lens,
-                             np.int32(max_new_tokens), eos, rng)
+        buf, n, _ = decode_loop(self.params, tok0, caches, lens,
+                                np.int32(max_new_tokens), eos, rng)
         n = int(n)
         gen = np.asarray(buf)[:b, :n]                   # host sync ends the decode clock
         decode_time = time.perf_counter() - t1

@@ -306,7 +306,11 @@ class ChunkedDecodeExecutor:
         """Discard the pool (e.g. after a failed dispatch that may have consumed
         donated buffers) and rebuild it fresh, every slot free. This also
         voids every page the prefix cache holds references to — the scheduler
-        clears its cache alongside (``_rebuild_pool``)."""
+        clears its cache alongside (``_rebuild_pool``). The old pool's
+        arrays go first: where a pool is most of the chip beside the weights
+        (Granite's 64 slots of recurrent state: 5.97 GB) two of them do not
+        fit."""
+        self.pool = None
         self.pool = self._build_pool()
 
     # ------------------------------------------------------------- compiled fns

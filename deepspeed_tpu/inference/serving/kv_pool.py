@@ -262,7 +262,9 @@ class PagedKVPool:
         if self.state_nbytes:
             # a recurrent state has no length to mask it by: the slot's is
             # cleared here (and written whole again at the next admission)
-            self.caches = _state_zero_jit()(self.caches, np.int32(slot))
+            with get_tracer().span("serving.clear_state", slot=int(slot),
+                                   state_bytes=self.state_nbytes // self.slots):
+                self.caches = _state_zero_jit()(self.caches, np.int32(slot))
 
     def _decref(self, page: int) -> None:
         if page == NULL_PAGE:

@@ -59,6 +59,9 @@ class CausalLMConfig:
     lm_head_bias: bool = False               # GPT-J ties nothing and biases the head
     dtype: Any = jnp.bfloat16
     init_std: float = 0.02
+    # the matrices that write to the residual stream (``o_proj``, ``fc_out``, a
+    # mixer's output projection); None = GPT-2's ``init_std / sqrt(2 n_layer)``
+    out_init_std: Optional[float] = None
     name: str = "causal-lm"
     # MoE serving (reference ``ops/transformer/inference/moe_inference.py``): every
     # ``moe_layer_interval``-th layer's FFN is a gated expert mixture. 0 experts = dense.
@@ -82,6 +85,16 @@ class CausalLMConfig:
     layer_pattern: Optional[str] = None
     head_dim_override: Optional[int] = None  # attention head size where != n_embd / n_head
     qk_norm: bool = False                    # RMSNorm of q and k per head, before the rotation
+    # Granite's four scalars, each at what every other family has: the
+    # embedding's rows are scaled by ``embedding_multiplier``, a mixer layer is
+    # ``x + residual_multiplier * mixer(norm(x))``, attention's scores are
+    # scaled by ``attention_multiplier`` (None = ``1 / sqrt(head_dim)``; read
+    # by every attention path through :attr:`attn_scale` and nowhere else),
+    # and the logits are divided by ``logits_scaling``
+    embedding_multiplier: float = 1.0
+    residual_multiplier: float = 1.0
+    attention_multiplier: Optional[float] = None
+    logits_scaling: float = 1.0
     # what an "E" layer's experts are: "latent" (experts in a latent space
     # beside a shared expert, behind the sigmoid router with a selection
     # bias: ``moe/latent_moe.py``) or "gated" (SwiGLU experts of the full
@@ -168,6 +181,10 @@ class CausalLMConfig:
             raise ValueError(
                 f"moe_decode_impl={self.moe_decode_impl!r} is not one of "
                 f"{self.VALID_MOE_DECODE_IMPLS}")
+        if self.residual_multiplier != 1 and self.layer_pattern is None:
+            raise ValueError(
+                f"residual_multiplier={self.residual_multiplier} scales a mixer "
+                "layer's branch: the classic layer (layer_pattern None) has none")
         if self.layer_pattern is not None:
             bad = sorted(set(self.layer_pattern) - set(PATTERN_KINDS))
             if bad or len(self.layer_pattern) != self.n_layer:
@@ -228,6 +245,20 @@ class CausalLMConfig:
     @property
     def kv_heads(self) -> int:
         return self.n_kv_head or self.n_head
+
+    @property
+    def attn_scale(self) -> float:
+        """What the scores ``q . k`` are multiplied by before the softmax: the
+        ONE place every attention path of the model takes it from."""
+        if self.attention_multiplier is not None:
+            return float(self.attention_multiplier)
+        return 1.0 / float(np.sqrt(self.head_dim))
+
+    @property
+    def out_std(self) -> float:
+        if self.out_init_std is not None:
+            return float(self.out_init_std)
+        return self.init_std / (2 * self.n_layer) ** 0.5
 
     @property
     def ffn_dim(self) -> int:
@@ -501,6 +532,79 @@ def lfm2_moe_cfg(*, hidden_size, num_hidden_layers, layer_types, num_dense_layer
         experts_held=None if experts_held is None else tuple(experts_held), **kw)
 
 
+def granite_hybrid_cfg(*, hidden_size, num_hidden_layers, layer_types, vocab_size,
+                       num_attention_heads, num_key_value_heads,
+                       shared_intermediate_size, mamba_n_heads, mamba_d_head,
+                       mamba_d_state, mamba_n_groups, mamba_d_conv, mamba_chunk_size,
+                       embedding_multiplier, residual_multiplier,
+                       attention_multiplier, logits_scaling, mamba_expand=2,
+                       mamba_conv_bias=True, mamba_proj_bias=False,
+                       attention_bias=False, num_local_experts=0,
+                       position_embedding_type="nope", hidden_act="silu",
+                       normalization_function="rmsnorm", rms_norm_eps=1e-5,
+                       tie_word_embeddings=True, intermediate_size=None,
+                       num_experts_per_tok=0, rope_theta=None, rope_scaling=None,
+                       max_position_embeddings=None,
+                       model_type="granitemoehybrid", **kw) -> CausalLMConfig:
+    """Granite 4.0 hybrids without experts (``model_type: granitemoehybrid``,
+    ``num_local_experts`` 0): the keywords are the published config's. A
+    published layer is a mixer and then a SwiGLU feed-forward of width
+    ``shared_intermediate_size``, each ``x + residual_multiplier *
+    f(rmsnorm(x))``: here a pair of mixer layers, "M" (Mamba-2: ``mamba_n_heads``
+    heads of ``mamba_d_head``, ``mamba_n_groups`` groups of B and C, a
+    convolution of ``mamba_d_conv`` taps with its bias) or "*" (grouped keys
+    and values, no position encoding, no bias, scores scaled by
+    ``attention_multiplier``) as ``layer_types`` says, then "F", so
+    ``n_layer`` is twice ``num_hidden_layers``. The embedding's rows are
+    scaled by ``embedding_multiplier`` and the tied head's logits divided by
+    ``logits_scaling``. Set here and not published: the recurrent state in
+    float32, ``dt`` not clamped. Taken and not read, so that the whole
+    published config can be passed: ``intermediate_size`` and
+    ``num_experts_per_tok`` (the absent experts'), ``rope_theta`` and
+    ``rope_scaling`` (no positions), ``max_position_embeddings`` (the caller's
+    ``max_seq_len`` says what is served), ``model_type``. What it does not
+    build it refuses."""
+    refused = {
+        "num_local_experts": (num_local_experts, 0),
+        "position_embedding_type": (position_embedding_type, "nope"),
+        "mamba_proj_bias": (mamba_proj_bias, False),
+        "attention_bias": (attention_bias, False),
+        "mamba_conv_bias": (mamba_conv_bias, True),
+        "hidden_act": (hidden_act, "silu"),
+        "normalization_function": (normalization_function, "rmsnorm"),
+        "mamba_expand * hidden_size": (mamba_expand * hidden_size,
+                                       mamba_n_heads * mamba_d_head),
+    }
+    bad = {k: got for k, (got, built) in refused.items() if got != built}
+    if bad:
+        raise NotImplementedError(
+            "granitemoehybrid is built without experts, positions or projection "
+            "biases, with the convolution's bias, SiLU, RMSNorm and an inner "
+            f"width of heads x head size (got {bad})")
+    kinds = {"mamba": "M", "attention": "*"}
+    n = int(num_hidden_layers)
+    if len(layer_types) != n or set(layer_types) - set(kinds):
+        raise ValueError(f"layer_types names {len(layer_types)} layers of "
+                         f"{sorted(set(layer_types))}, num_hidden_layers={n} of "
+                         f"{sorted(kinds)}")
+    kw.setdefault("name", "granite-hybrid")
+    return CausalLMConfig(
+        n_embd=hidden_size, n_layer=2 * n,
+        layer_pattern="".join(kinds[t] + "F" for t in layer_types),
+        vocab_size=vocab_size, n_head=num_attention_heads,
+        n_kv_head=num_key_value_heads, pos_emb="none", layernorm="rmsnorm",
+        ln_eps=rms_norm_eps, qkv_bias=False, mlp_bias=False, gated_mlp=True,
+        activation="silu", d_ff=shared_intermediate_size,
+        tie_word_embeddings=bool(tie_word_embeddings),
+        mamba_num_heads=mamba_n_heads, mamba_head_dim=mamba_d_head,
+        ssm_state_size=mamba_d_state, ssm_n_groups=mamba_n_groups,
+        conv_kernel=int(mamba_d_conv), ssm_chunk_size=int(mamba_chunk_size),
+        embedding_multiplier=float(embedding_multiplier),
+        residual_multiplier=float(residual_multiplier),
+        attention_multiplier=float(attention_multiplier),
+        logits_scaling=float(logits_scaling), **kw)
+
+
 FAMILIES = {
     "gpt2": gpt2_cfg, "bloom": bloom_cfg, "opt": opt_cfg,
     "gpt_neox": gptneox_cfg, "gptj": gptj_cfg, "llama": llama_cfg,
@@ -540,6 +644,19 @@ def apply_rotary(x, positions, base: float, pct: float):
     x_rot = x_rot.astype(jnp.float32)
     out = x_rot * cos + rotate_half(x_rot) * sin
     return jnp.concatenate([out.astype(x.dtype), x_pass], axis=-1)
+
+
+def embed_rows(cfg: CausalLMConfig, wte, ids):
+    """The embedding's rows in the model's type, scaled by
+    ``embedding_multiplier`` where the family has one."""
+    x = wte[ids]
+    if cfg.embedding_multiplier != 1:
+        x = x * cfg.embedding_multiplier
+    return x.astype(cfg.dtype)
+
+
+def scale_logits(cfg: CausalLMConfig, logits):
+    return logits if cfg.logits_scaling == 1 else logits / cfg.logits_scaling
 
 
 def _norm(cfg: CausalLMConfig, name: str):
@@ -630,7 +747,7 @@ class CausalLMLayer(nn.Module):
         cfg = self.config
         act = _act(cfg)
         init = nn.initializers.normal(cfg.init_std)
-        proj_init = nn.initializers.normal(cfg.init_std / (2 * cfg.n_layer) ** 0.5)
+        proj_init = nn.initializers.normal(cfg.out_std)
         if cfg.gated_mlp:
             with scope("mlp.up"):
                 gate = QuantDense(cfg.ffn_dim, use_bias=cfg.mlp_bias,
@@ -888,6 +1005,7 @@ class CausalLMLayer(nn.Module):
 
         slopes = (jnp.asarray(alibi_slopes(cfg.n_head))
                   if cfg.pos_emb == "alibi" else None)
+        scale = cfg.attn_scale           # every path below is handed this one
         if cache is not None:
             # the KV heads a row of this cache holds (heads_per_row made it)
             r = cache["k"].shape[-1] // cfg.head_dim
@@ -906,7 +1024,8 @@ class CausalLMLayer(nn.Module):
             with scope("kv.append"):
                 v_cache = _cache_update(cache["v"], v_hm, cache_len)
             new_kv = {"k": k_cache, "v": v_cache}
-            o = _block_decode(q, k_cache, v_cache, cache_len, cfg.gen_block_length)
+            o = _block_decode(q, k_cache, v_cache, cache_len, cfg.gen_block_length,
+                              scale)
         elif cache is not None and t == 1 and page_table is not None:
             # paged decode: append at the page-mapped row, attend by page index
             from ..ops.paged_attention import (gather_kv_dense,
@@ -927,11 +1046,11 @@ class CausalLMLayer(nn.Module):
                     kd, vd = gather_kv_dense(k_pages, v_pages, page_table, cap)
                 with scope("attn.core"):
                     o = decode_attention_xla_alibi(q[:, 0], kd, vd, lens1,
-                                                   slopes)[:, None]
+                                                   slopes, scale)[:, None]
             else:
                 with scope("attn.core"):
                     o = paged_attention(q[:, 0], k_pages, v_pages, page_table,
-                                        lens1, cap)[:, None]
+                                        lens1, cap, scale)[:, None]
         elif cache is not None and t == 1:
             # decode: append to cache (head-major), fused decode kernel
             with scope("attn.heads"):
@@ -943,7 +1062,7 @@ class CausalLMLayer(nn.Module):
             new_kv = {"k": k_cache, "v": v_cache}
             with scope("attn.core"):
                 o = _sharded_decode(q[:, 0], k_cache, v_cache, cache_len + 1,
-                                    alibi=slopes)[:, None]
+                                    alibi=slopes, scale=scale)[:, None]
         elif cache is not None and prefix_fill and cfg.gen_block_length:
             raise NotImplementedError(
                 "a prefill at a cache offset is causal: a model that generates "
@@ -965,13 +1084,14 @@ class CausalLMLayer(nn.Module):
                 v_cache = jax.vmap(put)(cache["v"], v_hm, idx)
             new_kv = {"k": k_cache, "v": v_cache}
             with scope("attn.core"):
-                o = _prefix_attention_xla(q, k_cache, v_cache, cache_len, slopes)
+                o = _prefix_attention_xla(q, k_cache, v_cache, cache_len, slopes,
+                                          scale)
         else:
             if cache is not None and attn_mask is not None:
                 raise NotImplementedError("attn_mask is for a forward without a cache")
             with scope("attn.core"):
                 o = _bias_attention(q, k, v, slopes, cfg.gen_block_length or 1,
-                                    attn_mask)
+                                    attn_mask, scale)
             if cache is not None:
                 # prefill: write the prompt's K/V (post-rotary) into the fixed cache
                 T = cache["k"].shape[2]
@@ -984,7 +1104,7 @@ class CausalLMLayer(nn.Module):
                               "v": jnp.pad(v_hm, pad).astype(cache["v"].dtype)}
         with scope("attn.heads"):
             o = o.reshape(b, t, cfg.n_head * cfg.head_dim)
-        proj_init = nn.initializers.normal(cfg.init_std / (2 * cfg.n_layer) ** 0.5)
+        proj_init = nn.initializers.normal(cfg.out_std)
         with scope("attn.out"):
             attn_out = RowParallelDense(cfg.n_embd, use_bias=cfg.mlp_bias,
                                         dtype=cfg.dtype, kernel_init=proj_init,
@@ -993,7 +1113,8 @@ class CausalLMLayer(nn.Module):
 
 
 class MixerLayer(CausalLMLayer):
-    """A layer of ONE mixer, ``x + mixer(norm(x))``; ``kind`` is the letter
+    """A layer of ONE mixer, ``x + residual_multiplier * mixer(norm(x))`` (the
+    multiplier 1 but for Granite); ``kind`` is the letter
     of the configuration's pattern, a key of :data:`LAYER_KINDS`: "*" this
     module's attention (keys and values, every cache mode of
     :class:`CausalLMLayer`), "M" a Mamba-2 mixer (state ``{"conv", "ssm"}``),
@@ -1029,11 +1150,11 @@ class MixerLayer(CausalLMLayer):
             if entry.keeps == "nothing":
                 new = None if cache is None else {}
         with scope("residual"):
+            if cfg.residual_multiplier != 1:
+                # one rounding to the stream's type, not one a factor and one a sum
+                out = cfg.residual_multiplier * out.astype(jnp.float32)
+                return (x + out).astype(x.dtype), new
             return x + out.astype(x.dtype), new
-
-    @property
-    def _out_std(self) -> float:
-        return self.config.init_std / (2 * self.config.n_layer) ** 0.5
 
     def _mamba(self, h, cache, seq_lens):
         from .mamba2 import Mamba2Mixer
@@ -1043,7 +1164,7 @@ class MixerLayer(CausalLMLayer):
             head_dim=cfg.mamba_head_dim, state_size=cfg.ssm_state_size,
             n_groups=cfg.ssm_n_groups, conv_kernel=cfg.conv_kernel,
             chunk_size=cfg.ssm_chunk_size, eps=cfg.ln_eps, dtype=cfg.dtype,
-            init_std=cfg.init_std, out_std=self._out_std, name="mamba")(
+            init_std=cfg.init_std, out_std=cfg.out_std, name="mamba")(
                 h, cache=cache, seq_lens=seq_lens)
 
     def _short_conv(self, h, cache, seq_lens):
@@ -1051,7 +1172,7 @@ class MixerLayer(CausalLMLayer):
         cfg = self.config
         return ShortConvMixer(
             d_model=cfg.n_embd, conv_kernel=cfg.conv_kernel, dtype=cfg.dtype,
-            init_std=cfg.init_std, out_std=self._out_std, name="conv")(
+            init_std=cfg.init_std, out_std=cfg.out_std, name="conv")(
                 h, cache=cache, seq_lens=seq_lens)
 
     def _ffn(self, h, cache, seq_lens):
@@ -1068,7 +1189,7 @@ class MixerLayer(CausalLMLayer):
             top_k=cfg.experts_per_token, expert_width=cfg.moe_expert_width,
             scale=cfg.routed_scaling_factor, norm_topk=cfg.norm_topk_prob,
             experts_held=cfg.held_experts, dtype=cfg.dtype,
-            init_std=cfg.init_std, out_std=self._out_std, name="moe")
+            init_std=cfg.init_std, out_std=cfg.out_std, name="moe")
         if cfg.moe_kind == "gated":
             from ..moe.gated_moe import GatedMoE
             moe = GatedMoE(router=cfg.moe_router, topk_eps=cfg.moe_topk_eps, **shared)
@@ -1095,10 +1216,11 @@ def block_causal_mask(t: int, block: int):
     return pos[None, :] <= pos[:, None]
 
 
-def _bias_attention(q, k, v, slopes, mask_block: int = 1, attn_mask=None):
+def _bias_attention(q, k, v, slopes, mask_block: int, attn_mask, scale):
     """Full-sequence causal attention, optionally with per-head alibi slopes.
     ``mask_block`` > 1 makes the mask block-causal (:func:`block_causal_mask`);
-    ``attn_mask`` (t, t) bool replaces the mask altogether (XLA path).
+    ``attn_mask`` (t, t) bool replaces the mask altogether (XLA path);
+    ``scale`` multiplies the scores on whichever path is taken.
 
     The alibi bias rides INSIDE the Pallas flash kernel (no (h, t, s) bias tensor in
     HBM — the reference fuses the same bias into ``softmax_kernels.cu``). Lengths
@@ -1118,20 +1240,23 @@ def _bias_attention(q, k, v, slopes, mask_block: int = 1, attn_mask=None):
             raise NotImplementedError("alibi with a mask that is not causal")
         if attn_mask is None:
             attn_mask = jnp.asarray(block_causal_mask(t, mask_block))
-        return xla_attention(q, k, v, causal=False, mask=attn_mask[None, None])
+        return xla_attention(q, k, v, causal=False, mask=attn_mask[None, None],
+                             softmax_scale=scale)
     if flash_eligible(t):
         return flash_attention(q, k, v, causal=True, alibi_slopes=slopes,
-                               mask_block=mask_block)
+                               softmax_scale=scale, mask_block=mask_block)
     if slopes is None:
-        return xla_attention(q, k, v, causal=True)
-    return _alibi_attention_xla(q, k, v, slopes)
+        return xla_attention(q, k, v, causal=True, softmax_scale=scale)
+    return _alibi_attention_xla(q, k, v, slopes, scale)
 
 
-def _alibi_attention_xla(q, k, v, slopes):
+def _alibi_attention_xla(q, k, v, slopes, scale=None):
     """XLA reference path for alibi attention (short/unaligned sequences; also the
-    numerical reference the flash-alibi kernel is tested against)."""
-    d = q.shape[-1]
-    scale = 1.0 / float(np.sqrt(d))
+    numerical reference the flash-alibi kernel is tested against, which is why
+    it has the kernel's own default for ``scale``; the model always hands its
+    ``attn_scale``)."""
+    if scale is None:
+        scale = q.shape[-1] ** -0.5
     t, s = q.shape[1], k.shape[1]
     rows = jnp.arange(t)[:, None]
     cols = jnp.arange(s)[None, :]
@@ -1144,7 +1269,7 @@ def _alibi_attention_xla(q, k, v, slopes):
     return jnp.einsum("bhts,bshd->bthd", probs, v)
 
 
-def _prefix_attention_xla(q, k_cache, v_cache, offset, slopes=None):
+def _prefix_attention_xla(q, k_cache, v_cache, offset, slopes, scale):
     """Suffix-prefill attention: queries at global positions ``offset + i``
     over the full KV cache (restored prefix rows + just-written suffix rows),
     masked ``key_pos <= query_pos`` — the t×T generalisation of
@@ -1158,7 +1283,6 @@ def _prefix_attention_xla(q, k_cache, v_cache, offset, slopes=None):
     hk, T = k_cache.shape[1], k_cache.shape[2]
     r = k_cache.shape[3] // d
     g = h // hk
-    scale = 1.0 / float(np.sqrt(d))
     q5 = pack_queries(q, r, hk).reshape(b, t, hk, g, r * d).astype(jnp.float32)
     s = jnp.einsum("btkgd,bkTd->bkgtT", q5,
                    k_cache.astype(jnp.float32)) * scale
@@ -1174,7 +1298,7 @@ def _prefix_attention_xla(q, k_cache, v_cache, offset, slopes=None):
     return unpack_outputs(o.reshape(b, t, h, r * d), r, hk).astype(q.dtype)
 
 
-def _block_decode(q, k_cache, v_cache, lens, block: int):
+def _block_decode(q, k_cache, v_cache, lens, block: int, scale=None):
     """Attention of whole blocks of queries against the cache: ``q`` (b, t, h,
     d), ``t`` a multiple of ``block``; every query of a sequence's ``j``-th
     block sees the same rows ``[0, lens + (j + 1) * block)`` (the committed
@@ -1191,7 +1315,7 @@ def _block_decode(q, k_cache, v_cache, lens, block: int):
             b, hk * t * g, d)
     with scope("attn.core"):
         ends = lens[:, None] + block * jnp.arange(1, t // block + 1)[None]   # (b, blocks)
-        o = _sharded_decode(rows, k_cache, v_cache, ends)
+        o = _sharded_decode(rows, k_cache, v_cache, ends, scale=scale)
     with scope("attn.heads"):
         return o.reshape(b, hk, t, g, d).transpose(0, 2, 1, 3, 4).reshape(b, t, h, d)
 
@@ -1216,7 +1340,7 @@ def _cache_update(cache, new, cache_len):
     return cache
 
 
-def _sharded_decode(q, k_cache, v_cache, lens, alibi=None):
+def _sharded_decode(q, k_cache, v_cache, lens, alibi=None, *, scale):
     """Wrap the decode kernel in shard_map over batch/TP axes (pallas is opaque to SPMD).
 
     Alibi slopes travel as a per-head input sharded over the tensor axis, so each TP shard
@@ -1238,30 +1362,32 @@ def _sharded_decode(q, k_cache, v_cache, lens, alibi=None):
             lspec = P(batch_axes or None)
             if alibi is None:
                 mapped = shard_map(
-                    lambda q_l, k_l, v_l, l_l: decode_attention(q_l, k_l, v_l, l_l),
+                    lambda q_l, k_l, v_l, l_l: decode_attention(q_l, k_l, v_l, l_l,
+                                                                scale),
                     mesh=mesh.mesh, axis_names=manual,
                     in_specs=(qspec, cspec, cspec, lspec), out_specs=qspec,
                     check_vma=False)
                 return mapped(q, k_cache, v_cache, lens)
             mapped = shard_map(
-                decode_attention_xla_alibi, mesh=mesh.mesh, axis_names=manual,
+                partial(decode_attention_xla_alibi, scale=scale),
+                mesh=mesh.mesh, axis_names=manual,
                 in_specs=(qspec, cspec, cspec, lspec, P(tpax)), out_specs=qspec,
                 check_vma=False)
             return mapped(q, k_cache, v_cache, lens, jnp.asarray(alibi))
 
     if alibi is not None:
-        return decode_attention_xla_alibi(q, k_cache, v_cache, lens, jnp.asarray(alibi))
-    return decode_attention(q, k_cache, v_cache, lens)
+        return decode_attention_xla_alibi(q, k_cache, v_cache, lens,
+                                          jnp.asarray(alibi), scale)
+    return decode_attention(q, k_cache, v_cache, lens, scale)
 
 
-def decode_attention_xla_alibi(q, k_cache, v_cache, cache_len, slopes):
+def decode_attention_xla_alibi(q, k_cache, v_cache, cache_len, slopes, scale):
     """Decode attention with alibi bias (jnp path; bloom decode); the cache
     in rows, the queries packed to them, as ``decode_attention_xla``."""
     b, h, d = q.shape
     hk, T = k_cache.shape[1], k_cache.shape[2]
     r = k_cache.shape[3] // d
     g = h // hk
-    scale = 1.0 / float(np.sqrt(d))
     q4 = pack_queries(q, r, hk).reshape(b, hk, g, r * d).astype(jnp.float32)
     s = jnp.einsum("bkgd,bktd->bkgt", q4, k_cache.astype(jnp.float32)) * scale
     pos = jnp.arange(T)[None, None, None, :]
@@ -1308,7 +1434,7 @@ class CausalLM(nn.Module):
         wte = self.param("wte", nn.initializers.normal(cfg.init_std),
                          (cfg.vocab_size, cfg.n_embd), jnp.float32)
         with scope("embed"):
-            x = wte[input_ids].astype(cfg.dtype)
+            x = embed_rows(cfg, wte, input_ids)
             if cfg.pos_emb == "learned":
                 wpe = self.param("wpe", nn.initializers.normal(cfg.init_std),
                                  (cfg.max_seq_len, cfg.n_embd), jnp.float32)
@@ -1342,6 +1468,7 @@ class CausalLM(nn.Module):
                                   dtype=jnp.float32,
                                   kernel_init=nn.initializers.normal(cfg.init_std),
                                   name="lm_head")(x.astype(jnp.float32))
+            logits = scale_logits(cfg, logits)
         if caches is None:
             return logits
         return logits, new_caches
@@ -1401,7 +1528,7 @@ def causal_lm_segments(cfg: CausalLMConfig, layers_per_group: int = 2):
         p = dict(zip(embed_keys, p))
         ids = batch["input_ids"]
         with scope("embed"):
-            x = p["wte"][ids].astype(cfg.dtype)
+            x = embed_rows(cfg, p["wte"], ids)
             if cfg.pos_emb == "learned":
                 x = x + jnp.take(p["wpe"], _positions(ids), axis=0).astype(cfg.dtype)
             if cfg.embed_layernorm:
@@ -1478,6 +1605,7 @@ def causal_lm_segments(cfg: CausalLMConfig, layers_per_group: int = 2):
                 logits = x.astype(jnp.float32) @ p["lm_head"]["kernel"]
                 if cfg.lm_head_bias:
                     logits = logits + p["lm_head"]["bias"]
+            logits = scale_logits(cfg, logits)
         ids = batch["input_ids"]
         with scope("loss"):
             labels = batch.get("labels")

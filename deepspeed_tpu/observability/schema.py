@@ -236,6 +236,10 @@ SPANS: Dict[str, Tuple[str, str, Tuple[str, ...], str]] = {
                                ("slot", "prefix_len", "promoted"),
                                "attribution phase kv_restore (the host "
                                "tier's slab restored into fresh pages)"),
+    "serving.clear_state": (BOTH, "serve scheduler", ("slot", "state_bytes"),
+                            "breakdown idle gaps (a released slot's per-slot "
+                            "state zero-filled: state_bytes are the slot's own, "
+                            "one write a state array)"),
     "serving.prefill": (BOTH, "compiled steps",
                         ("request_id", "bucket", "tokens", "prefix_len",
                          "moe_assignments", "moe_experts_touched",
