@@ -23,7 +23,7 @@ import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
 
 from ..observability import scope
-from ..ops.attention.flash import (FLASH_LSE_NAME, FLASH_OUT_NAME,
+from ..ops.attention.flash import (FLASH_LSE_NAME, FLASH_OUT_NAME, FLASH_QKV_NAME,
                                    flash_attention_qkv)
 from ..ops.transformer.attention import flash_reads_fused_qkv, get_attention_impl
 from .base import Model
@@ -381,7 +381,8 @@ class GPT2(nn.Module):
                 policies = jax.checkpoint_policies
                 policy = policies.save_from_both_policies(
                     policies.dots_with_no_batch_dims_saveable,
-                    policies.save_only_these_names(FLASH_OUT_NAME, FLASH_LSE_NAME))
+                    policies.save_only_these_names(
+                        FLASH_OUT_NAME, FLASH_LSE_NAME, FLASH_QKV_NAME))
             block = nn.remat(Block, prevent_cse=False, static_argnums=(2,), policy=policy)
         if cfg.scan_layers:
             x, _ = nn.scan(

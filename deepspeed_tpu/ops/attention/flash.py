@@ -64,11 +64,14 @@ from ...utils.device import pallas_interpret as _interpret
 from ...utils.jax_compat import shard_map
 
 NEG_INF = -1e30
-# ``checkpoint_name`` tags of the forward rule's two residuals that come out of no
-# matmul: the kernel's output, in the layout of its operands ((b, t, h*d) flat,
-# (b*h, t, d) else), and its log-sum-exp (rows, heads in the lanes, t), float32
+# ``checkpoint_name`` tags of the forward rule's residuals that come out of no matmul:
+# the kernel's output, in the layout of its operands ((b, t, h*d) flat, (b*h, t, d)
+# else), its log-sum-exp (rows, heads in the lanes, t), float32, and the fused
+# projection (b, t, 3*h*d) it read, bias and all (a policy that kept the projection's
+# matmul output instead would add the bias again in every layer's backward)
 FLASH_OUT_NAME = "flash_out"
 FLASH_LSE_NAME = "flash_lse"
+FLASH_QKV_NAME = "flash_qkv"
 
 
 def _block_sizes(t: int, block_q: int, block_k: int):
@@ -650,6 +653,10 @@ def _make_core(fused: bool):
         return run(*args)[0]
 
     def core_fwd(*args):
+        if fused:
+            # the operand as the kernels read it: kept under its name, the backward
+            # reads it too and adds no bias to the projection's matmul output again
+            args = (checkpoint_name(args[0], FLASH_QKV_NAME), *args[1:])
         o, lse = run(*args)
         # the two residuals no matmul gives back: a remat policy that names them
         # (models/gpt2.py, "dots") keeps them and the backward runs no second forward

@@ -247,6 +247,110 @@ def test_flash_fused_qkv_matches_split(h, d, dtype):
     np.testing.assert_allclose(np.asarray(g1, np.float32), np.asarray(g2, np.float32), **tol)
 
 
+# (h, d, b, t): GPT-2's head shape (two heads a block, six lane groups a row), the 1.3B
+# configuration's (one a block), a sequence of two q and two kv blocks of 1024, and two
+# lane groups of two kv blocks each
+BIASED_SHAPES = [(12, 64, 2, 256), (4, 128, 2, 256), (2, 64, 1, 2048), (4, 64, 2, 2048)]
+BIASED_IDS = ["gpt2-heads", "one-head-a-block", "several-blocks", "groups-of-blocks"]
+
+
+def _split_path(b, t, h, d):
+    def split(x, bias=None):
+        x = x if bias is None else x + bias
+        q, k, v = (y.reshape(b, t, h, d) for y in jnp.split(x, 3, axis=-1))
+        return flash_attention(q, k, v, causal=True).reshape(b, t, h * d)
+    return split
+
+
+@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16], ids=["f32", "bf16"])
+@pytest.mark.parametrize("h,d,b,t", BIASED_SHAPES, ids=BIASED_IDS)
+def test_flash_fused_qkv_under_the_projections_bias(h, d, b, t, dtype):
+    """What ``Block`` runs: ``flash_attention_qkv(qkv0 + c, h)`` against
+    ``flash_attention`` on ``split(qkv0 + c)``: the output, the gradient of ``qkv0``
+    (dq | dk | dv, the split path's three joined) and that of the bias (its sum over
+    the rows)."""
+    from deepspeed_tpu.ops.attention.flash import flash_attention_qkv
+    rng = np.random.default_rng(24)
+    qkv0 = jnp.asarray(rng.normal(size=(b, t, 3 * h * d)).astype(np.float32)).astype(dtype)
+    bias = jnp.asarray(rng.normal(size=(3 * h * d,)).astype(np.float32)).astype(dtype)
+    w = jnp.asarray(rng.normal(size=(b, t, h * d)).astype(np.float32))
+    split = _split_path(b, t, h, d)
+
+    def fused(x, c):
+        return flash_attention_qkv(x + c, h, causal=True)
+
+    def grads(fn, *a):
+        return jax.grad(lambda x, c: (fn(x, c).astype(jnp.float32) * w).sum(),
+                        argnums=(0, 1))(*a)
+
+    tol = dict(rtol=1e-4, atol=2e-5) if dtype == jnp.float32 else dict(rtol=4e-2, atol=4e-2)
+    got = fused(qkv0, bias)
+    assert got.dtype == dtype and got.shape == (b, t, h * d)
+    # the reference adds and differentiates in float32: a bf16 sum over b*t rows is no
+    # yardstick for the bias's gradient
+    qkv32, bias32 = qkv0.astype(jnp.float32), bias.astype(jnp.float32)
+    np.testing.assert_allclose(np.asarray(got, np.float32),
+                               np.asarray(split(qkv32, bias32)), **tol)
+    (dx, dc), (dx_want, dc_want) = grads(fused, qkv0, bias), grads(split, qkv32, bias32)
+    assert dx.shape == qkv0.shape and dx.dtype == dtype
+    assert dc.shape == bias.shape and dc.dtype == dtype
+    np.testing.assert_allclose(np.asarray(dx, np.float32), np.asarray(dx_want), **tol)
+    size = max(1.0, float(jnp.abs(dc_want).max()))
+    np.testing.assert_allclose(np.asarray(dc, np.float32) / size,
+                               np.asarray(dc_want) / size, **tol)
+    # the same kernels on the same values
+    same_dx, same_dc = grads(split, qkv0, bias)
+    assert np.array_equal(np.asarray(dx, np.float32), np.asarray(same_dx, np.float32))
+    assert np.array_equal(np.asarray(dc, np.float32), np.asarray(same_dc, np.float32))
+
+
+def test_flash_fused_qkv_without_causality():
+    """Every q block reaches every kv block (two q and two kv blocks of 1024)."""
+    from deepspeed_tpu.ops.attention.flash import flash_attention_qkv
+    rng = np.random.default_rng(27)
+    b, t, h, d = 1, 2048, 2, 64
+    qkv = jnp.asarray(rng.normal(size=(b, t, 3 * h * d)).astype(np.float32))
+    w = jnp.asarray(rng.normal(size=(b, t, h * d)).astype(np.float32))
+
+    def split(x):
+        q, k, v = (y.reshape(b, t, h, d) for y in jnp.split(x, 3, axis=-1))
+        return flash_attention(q, k, v, causal=False).reshape(b, t, h * d)
+
+    g1, g2 = (jax.grad(lambda x: (fn(x) * w).sum())(qkv) for fn in (
+        lambda x: flash_attention_qkv(x, h, causal=False), split))
+    assert np.array_equal(np.asarray(g1), np.asarray(g2))
+
+
+def test_flash_fused_qkv_under_a_batch_sharded_mesh_with_its_bias(eight_devices):
+    """The bias is whole on every shard of the batch; its gradient is summed over them."""
+    from deepspeed_tpu.ops.attention.flash import flash_attention_qkv
+    set_global_mesh(MeshSpec({"fsdp": 4, "data": 2}, eight_devices))
+    try:
+        rng = np.random.default_rng(26)
+        b, t, h, d = 8, 128, 2, 64
+        qkv0 = jnp.asarray(rng.normal(size=(b, t, 3 * h * d)).astype(np.float32))
+        bias = jnp.asarray(rng.normal(size=(3 * h * d,)).astype(np.float32))
+        w = jnp.asarray(rng.normal(size=(b, t, h * d)).astype(np.float32))
+
+        def ref(x, c):
+            q, k, v = (y.reshape(b, t, h, d) for y in jnp.split(x + c, 3, axis=-1))
+            return xla_attention(q, k, v, causal=True).reshape(b, t, h * d)
+
+        def fused(x, c):
+            return flash_attention_qkv(x + c, h, causal=True)
+
+        def grads(fn):
+            return jax.jit(jax.grad(lambda x, c: (fn(x, c) * w).sum(),
+                                    argnums=(0, 1)))(qkv0, bias)
+
+        assert "shard_map" in str(jax.make_jaxpr(fused)(qkv0, bias))
+        for a, want in zip(grads(fused), grads(ref)):
+            np.testing.assert_allclose(np.asarray(a), np.asarray(want), rtol=1e-4,
+                                       atol=1e-4)
+    finally:
+        set_global_mesh(None)
+
+
 def test_flash_fused_qkv_refuses_heads_outside_lane_tiles():
     from deepspeed_tpu.ops.attention.flash import flash_attention_qkv
     with pytest.raises(ValueError, match="lane tiles"):

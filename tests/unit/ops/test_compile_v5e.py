@@ -214,6 +214,14 @@ def _flash_cases():
         # gpt2-125m.seq1k: c_attn's output as ONE operand, two heads of 64 a block
         "gpt2-fused-gradient": (lambda f: jax.grad(lambda x: f.flash_attention_qkv(
             x, 12).astype(jnp.float32).sum()), [(24, 1024, 3 * 768)], jnp.bfloat16),
+        # the same under c_attn's bias, as Block runs it
+        "gpt2-fused-bias-gradient": (lambda f: jax.grad(
+            lambda x, c: f.flash_attention_qkv(x + c, 12).astype(jnp.float32).sum(),
+            argnums=(0, 1)), [(24, 1024, 3 * 768), (3 * 768,)], jnp.bfloat16),
+        # configs/gpt2-1.3b-zero3.json unsharded: 16 heads of 128, one a block
+        "gpt2-1.3b-fused-bias-gradient": (lambda f: jax.grad(
+            lambda x, c: f.flash_attention_qkv(x + c, 16).astype(jnp.float32).sum(),
+            argnums=(0, 1)), [(4, 1024, 3 * 2048), (3 * 2048,)], jnp.bfloat16),
         "gpt2-split-gradient": (lambda f: grad(f.flash_attention_local),
                                 [(24, 1024, 12, 64)] * 3, jnp.bfloat16),
         # bloom-7b1.docqa's 512-bucket prefill: one head of 128 a block, alibi
@@ -223,6 +231,11 @@ def _flash_cases():
         # sdar-30b-a3b-chat.conv32's prefill: the block-causal mask
         "sdar-prefill": (lambda f: functools.partial(f.flash_attention_local, mask_block=4),
                          [(1, 512, 32, 128)] * 3, jnp.bfloat16),
+        # lfm2-8b-a1b.conv32's and granite-4.0-h-micro.conv64's: two heads of 64 a block,
+        # Granite's scale
+        "d64-prefill": (lambda f: functools.partial(f.flash_attention_local,
+                                                    softmax_scale=1 / 64),
+                        [(1, 512, 32, 64)] * 3, jnp.bfloat16),
         # four float32 heads a block over several kv blocks: past the default 16 MiB
         # of scoped VMEM (19.6), inside the limit the kernels ask for
         "four-f32-heads-a-block": (lambda f: grad(f.flash_attention_local),
@@ -246,3 +259,69 @@ def test_the_flash_kernels_compile_reading_heads_in_place(one_chip, case, monkey
     kernels = 3 if "gradient" in case or "heads" in case or "odd" in case else 1
     assert text.count("tpu_custom_call") == kernels and "flash_fwd" in text
     assert case == "odd-head-count" or " transpose(" not in text
+
+
+def _pallas_calls(fn, shapes, dtype):
+    """(name, grid, operands, results, block sizes of each, aliases, which grid axes
+    are ``parallel`` and which ``arbitrary``) of every ``pallas_call`` ``fn`` traces
+    to: what a kernel is called WITH, as the trace has it and no compiler has touched
+    it."""
+    from deepspeed_tpu.analysis.jaxpr_passes import subjaxprs
+    found = []
+
+    def walk(jaxpr):
+        for eqn in jaxpr.eqns:
+            if eqn.primitive.name == "pallas_call":
+                grid = eqn.params["grid_mapping"]
+                found.append((eqn.params["name"], grid.grid, grid.num_inputs,
+                              grid.num_outputs, tuple(
+                                  tuple(getattr(size, "block_size", size)
+                                        for size in block.block_shape)
+                                  for block in grid.block_mappings),
+                              tuple(eqn.params["input_output_aliases"]),
+                              "".join(axis[0] for axis in eqn.params["compiler_params"][
+                                  "mosaic_tpu"].dimension_semantics)))
+            for sub in subjaxprs(eqn):
+                walk(sub)
+
+    walk(jax.make_jaxpr(fn)(*(jax.ShapeDtypeStruct(s, dtype) for s in shapes)).jaxpr)
+    return found
+
+
+def _tiles(rows, width=128):
+    return (1, rows, width)
+
+
+# What each serving prefill and the two training gradients (the fused projection's and
+# the split one's: the same blocks, k and v found by a lane offset) call the kernels
+# with: PR 45 changed what a remat policy KEEPS of the fused path and no call.
+_STATS = (1, 2, 1, 8, 1024)
+_GRADIENT = [
+    ("flash_fwd", (24, 6, 1, 1), 3, 2, (_tiles(1024),) * 4 + (_STATS,), (), "ppaa"),
+    ("flash_bwd_dq", (24, 6, 1, 1), 6, 1,
+     (_tiles(1024),) * 4 + (_STATS,) * 2 + (_tiles(1024),), (), "ppaa"),
+    ("flash_bwd_dkv", (24, 6, 1, 1), 6, 2,
+     (_tiles(1024),) * 4 + (_STATS,) * 2 + (_tiles(1024),) * 2, (), "ppaa")]
+_CALLS = {
+    "bloom-prefill": [
+        ("flash_fwd", (1, 32, 1, 1), 4, 2,
+         (_tiles(512),) * 3 + ((1, 8, 128), _tiles(512), (1, 1, 1, 8, 512)), (), "ppaa")],
+    "sdar-prefill": [
+        ("flash_fwd", (1, 32, 1, 1), 3, 2,
+         (_tiles(512),) * 4 + ((1, 1, 1, 8, 512),), (), "ppaa")],
+    "d64-prefill": [
+        ("flash_fwd", (1, 16, 1, 1), 3, 2,
+         (_tiles(512),) * 4 + ((1, 2, 1, 8, 512),), (), "ppaa")],
+    "gpt2-split-gradient": _GRADIENT,
+    "gpt2-fused-gradient": _GRADIENT,
+}
+
+
+@pytest.mark.parametrize("case", sorted(_CALLS))
+def test_the_kernels_are_called_with_the_blocks_they_were(case):
+    from deepspeed_tpu.ops.attention import flash
+    make, shapes, dtype = _flash_cases()[case]
+    calls = _pallas_calls(make(flash), shapes, dtype)
+    assert [call[0] for call in calls] == [call[0] for call in _CALLS[case]]
+    for got, want in zip(calls, _CALLS[case]):
+        assert got == want, f"{got[0]}: grid, operands, results, blocks, aliases, axes"

@@ -15,6 +15,7 @@ from jax._src.ad_checkpoint import saved_residuals
 from deepspeed_tpu.analysis.jaxpr_passes import subjaxprs
 from deepspeed_tpu.models.gpt2 import GPT2, GPT2Config, cross_entropy_loss
 from deepspeed_tpu.ops.attention.flash import (FLASH_LSE_NAME, FLASH_OUT_NAME,
+                                               FLASH_QKV_NAME,
                                                flash_attention)
 from deepspeed_tpu.parallel.mesh import MeshSpec, set_global_mesh
 
@@ -162,13 +163,76 @@ def test_the_gradient_of_a_fused_layer_moves_no_head_and_cuts_no_projection(scan
     bodies = 1 if scan_layers else LAYERS
     assert _count(jaxpr, _kernel) == {"flash_fwd": bodies, "flash_bwd_dq": bodies,
                                       "flash_bwd_dkv": bodies}
-    assert _count(jaxpr, _tag) == {FLASH_OUT_NAME: bodies, FLASH_LSE_NAME: bodies}
+    assert _count(jaxpr, _tag) == {FLASH_OUT_NAME: bodies, FLASH_LSE_NAME: bodies,
+                                   FLASH_QKV_NAME: bodies}
     assert not _count(jaxpr, _moves_heads)
     assert not _count(jaxpr, _cuts_the_projection)
     # dq | dk | dv go back to the projection as one concatenate a layer
-    joins = _count(jaxpr, lambda e: "concatenate" if e.primitive.name == "concatenate"
-                   and e.outvars[0].aval.shape[-1:] == (3 * 128,) else None)
+    joins = _count(jaxpr, lambda e: _wide(e, "concatenate"))
     assert joins == {"concatenate": bodies}
+
+
+def _wide(eqn, name):
+    """An equation ``name`` whose result is as wide as the fused projection."""
+    if eqn.primitive.name == name and eqn.outvars[0].aval.shape[-1:] == (3 * 128,):
+        return name
+    return None
+
+
+@pytest.mark.parametrize("scan_layers", [True, False], ids=["scanned", "unrolled"])
+def test_the_backward_of_a_fused_layer_adds_no_bias_and_multiplies_no_projection_again(
+        scan_layers):
+    """Under ``dots`` the kept residual is the projection the kernels READ (named
+    ``flash_qkv``), bias and all: c_attn's bias is added once a layer, in the forward,
+    and its matmul runs once; the bias's gradient is one ``reduce_sum`` a layer, c_attn's
+    own."""
+    loss, params = _wide_model_loss(scan_layers=scan_layers)
+    jaxpr = jax.make_jaxpr(jax.grad(loss))(params)
+    bodies = 1 if scan_layers else LAYERS
+    assert _count(jaxpr, lambda e: _wide(e, "add")) == {"add": bodies}
+    # c_attn's forward product and no second one (its two gradients are 128 wide)
+    assert _count(jaxpr, lambda e: _wide(e, "dot_general")) == {"dot_general": bodies}
+    sums = _count(jaxpr, lambda e: "sum" if e.primitive.name == "reduce_sum"
+                  and e.outvars[0].aval.shape == (3 * 128,) else None)
+    assert sums == {"sum": bodies}
+
+
+def test_the_fused_layer_saves_one_projection_a_layer_the_one_the_kernels_read():
+    """51 residuals of the two unrolled layers: the 53 of PR 45's parent, of their
+    shapes, less c_attn's bias a layer (nothing adds it again). The kept (b, t,
+    3*n_embd) array was c_attn's matmul output; now it is the operand the kernels read,
+    kept under its name, and no second one beside it."""
+    loss, params = _wide_model_loss(scan_layers=False)
+    saved = saved_residuals(loss, params)
+    assert len(saved) == 51
+    assert not [why for _, why in saved if "['c_attn']['bias']" in why]
+    wide = [why for aval, why in saved if aval.shape == (BATCH, SEQ, 3 * 128)]
+    assert len(wide) == LAYERS and all("flash_attention_qkv" in why for why in wide)
+    by_shape = collections.Counter(aval.shape for aval, _ in saved)
+    assert by_shape[(BATCH, SEQ, 128)] == 10 and by_shape[(BATCH, SEQ, 512)] == 2
+    assert by_shape[(BATCH, 2, SEQ)] == LAYERS          # the log-sum-exp
+
+
+def test_the_parameter_tree_is_the_one_nn_dense_made():
+    """The fused branch holds c_attn as ``nn.Dense`` does everywhere else, so a
+    checkpoint written by any branch loads into any other: the flash model's tree
+    against the XLA attention path's, leaf for leaf, with initial values from a key."""
+    from deepspeed_tpu.models.gpt2 import gpt2_model
+    ids = jnp.zeros((BATCH, SEQ), jnp.int32)
+    trees = []
+    for impl in ("flash", "xla"):
+        cfg = GPT2Config(vocab_size=VOCAB, n_positions=SEQ, n_embd=128, n_layer=LAYERS,
+                         n_head=2, dtype=jnp.bfloat16, attention_impl=impl)
+        trees.append(GPT2(cfg).init(jax.random.PRNGKey(7), ids))
+    fused, dense = (jax.tree_util.tree_leaves_with_path(t) for t in trees)
+    assert [jax.tree_util.keystr(k) for k, _ in fused] == \
+        [jax.tree_util.keystr(k) for k, _ in dense]
+    assert any("c_attn']['kernel" in jax.tree_util.keystr(k) for k, _ in fused)
+    for (path, a), (_, b) in zip(fused, dense):
+        assert a.shape == b.shape and a.dtype == b.dtype == jnp.float32
+        assert np.array_equal(np.asarray(a), np.asarray(b)), jax.tree_util.keystr(path)
+    assert sum(int(np.prod(a.shape)) for _, a in fused) == cfg.num_params()
+    assert gpt2_model(cfg, sample_seq_len=SEQ) is not None
 
 
 def test_the_gradient_of_separate_projections_moves_no_head_either():
