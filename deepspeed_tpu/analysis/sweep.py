@@ -190,21 +190,18 @@ def spec_lane(report: Report) -> None:
     """Speculative-decoding contracts: the one-compile-per-(slots, pages,
     page, cap, k, sampling)-key property across a GROWN-k workload (per-slot
     draft length is runtime data — a dry proposer, a cap-edge slot and a
-    full-k window all ride the same compiled verify), donation audit on the
-    verify fn's donated pool caches, and the dequant-hoist loop-invariance
-    pin on the verify body's paged-writeback loop (int8 engine)."""
-    import jax
+    full-k window all ride the same compiled verify) and the donation audit
+    on the verify fn's donated pool caches. The verify round holds no loop
+    (its rows go back to the pages as slab writes), so no dequant-hoist pin."""
     import jax.numpy as jnp
     import numpy as np
     from ..inference.config import DeepSpeedInferenceConfig
-    from ..inference.decode_fns import build_paged_spec_verify
     from ..inference.engine import InferenceEngine
     from ..inference.serving.scheduler import (ContinuousBatchingScheduler,
                                                ServingConfig)
     from ..parallel.mesh import set_global_mesh
     from ..models.causal_lm import gpt2_cfg
     from .donation import donation_findings
-    from .jaxpr_passes import loop_body_findings
     from .retrace import CompileCacheLint
 
     cfg = gpt2_cfg(**_TINY, dtype=jnp.float32)
@@ -243,30 +240,6 @@ def spec_lane(report: Report) -> None:
              jnp.ones((S,), jnp.int32), jnp.zeros((S,), bool))
     report.add(donation_findings(engine._fns[vkey], vargs,
                                  target="serve_spec_verify_paged"))
-
-    # loop-invariance: dequant hoisted out of the verify body's paged
-    # KV-writeback loop (int8 engine) — the spec analogue of the decode pins
-    raw = jax.tree_util.tree_map(np.asarray, engine.params)
-    engine_q = InferenceEngine((cfg, raw), DeepSpeedInferenceConfig(
-        dtype="float32", max_out_tokens=_CAP,
-        weight_quant={"enabled": True, "bits": 8}))
-    verify = build_paged_spec_verify(engine_q.module, engine_q._dequant,
-                                     kv_cap=_CAP,
-                                     overlap=engine_q.comm_overlap)
-    int8_invar = lambda a: getattr(a, "dtype", None) == jnp.int8  # noqa: E731
-    qargs = (engine_q.params, jnp.zeros((S, k + 1), jnp.int32),
-             ex.pool.caches, jnp.zeros((S, mp), jnp.int32),
-             jnp.zeros((S,), jnp.int32), jnp.ones((S,), jnp.int32),
-             jnp.zeros((S,), bool))
-    findings, n_loops = loop_body_findings(
-        verify, qargs, invar_predicate=int8_invar, what="dequant-hoist",
-        site="spec_verify")
-    res = PassResult("loop_invariance", "spec_verify", findings, n_loops)
-    if n_loops == 0:
-        res.findings.append(Finding(
-            "loop_invariance", SEVERITY_ERROR, "spec_verify",
-            "no loop found — the dequant-hoist pin target vanished"))
-    report.add(res)
     set_global_mesh(None)
 
 

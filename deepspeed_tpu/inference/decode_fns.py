@@ -202,30 +202,24 @@ def build_paged_spec_verify(module, dequant, kv_cap: int, overlap=None):
     accept rule needs the target's distribution after each draft prefix.
 
     Then ONLY the valid window rows ``[lens, lens + valid)`` of live slots
-    are mirrored back through the page table (the chunk's end-of-chunk
-    writeback idiom). ``valid (S,)`` is ``spec_len + 1`` — the cur-token row
-    plus the real (un-padded) draft rows; pad rows, inactive slots, and rows
-    at/past ``kv_cap`` route to the out-of-range page index and the scatter
-    drops them, so released or shared pages are never written.
+    go back through the page table, as the chunk's do at its end: one slab
+    write a page (:func:`~deepspeed_tpu.ops.paged_attention.write_view_rows`;
+    no loop, so nothing for ``dequant`` to be hoisted out of). ``valid (S,)``
+    is ``spec_len + 1`` — the cur-token row plus the real (un-padded) draft
+    rows; where a row is a pad row, an inactive slot's, or at/past
+    ``kv_cap``, the slab write keeps what the page held, so released or
+    shared pages never change.
 
     Rollback is the caller's job and is free: rows written past the accepted
     prefix stay stale-but-masked (attention masks ``>= cache_len``) and are
     overwritten by later appends — committing is a ``cache_len`` advance,
     rejecting is not advancing. Returns ``(logits (S, t, V), new_caches)``.
-
-    The mirror is a ``fori_loop`` over the window rows — the loop the
-    analysis sweep's dequant pin targets: ``dequant`` collapses the quantized
-    params ONCE above it, so int8 payloads must never appear as loop-body
-    inputs (the same loop-invariance contract as the decode-chunk body).
     """
-    from ..ops.paged_attention import gather_kv_dense, page_address
+    from ..ops.paged_attention import gather_kv_dense, write_view_rows
 
     def spec_verify(params, ids, caches, page_table, lens, valid, active):
-        # hoisted: dequant once per verify dispatch, never inside the mirror
         params = dequant(params)
         b, t = ids.shape
-        ps = caches[0]["k"].shape[2]
-        P_total = caches[0]["k"].shape[0]
         with scope("kv.gather"):
             dense = [dict(zip(("k", "v"),
                               gather_kv_dense(c["k"], c["v"], page_table, kv_cap)))
@@ -237,24 +231,10 @@ def build_paged_spec_verify(module, dequant, kv_cap: int, overlap=None):
                 caches=dense, cache_lens=lens,
                 logits_positions=None, prefix_fill=True)
 
-        @scope("kv.copy_back")
-        def mirror(j, pages):
-            rows = lens + j
-            pidx, off = page_address(page_table, rows, kv_cap, ps, P_total,
-                                     live=lambda: active & (j < valid))
-            idx = jnp.minimum(rows, kv_cap - 1)[:, None, None, None]
-            out = []
-            for c, dn in zip(pages, dense):
-                k_new = jnp.take_along_axis(dn["k"], idx, axis=2)[:, :, 0, :]
-                v_new = jnp.take_along_axis(dn["v"], idx, axis=2)[:, :, 0, :]
-                out.append(
-                    {"k": c["k"].at[pidx, :, off, :].set(
-                        k_new.astype(c["k"].dtype)),
-                     "v": c["v"].at[pidx, :, off, :].set(
-                        v_new.astype(c["v"].dtype))})
-            return out
-
-        new_caches = jax.lax.fori_loop(0, t, mirror, list(caches))
+        with scope("kv.copy_back"):
+            new_caches = write_view_rows(
+                caches, dense, page_table, lens,
+                jnp.where(active, valid, 0).astype(lens.dtype), t, kv_cap)
         return logits, new_caches
 
     return spec_verify
@@ -314,6 +294,24 @@ def build_decode_loop(module, dequant, select, gen_cap: int, overlap=None):
     return decode_loop
 
 
+def _copy_back(caches, dense, page_table, lens_in, lens, span: int, kv_cap: int):
+    """The end of a chunk that ran on the dense view: the rows a slot
+    appended or committed in it, ``[lens_in, lens)`` (at most ``span``, below
+    ``kv_cap``), go from the view into the slot's pages as slab writes
+    (:func:`~deepspeed_tpu.ops.paged_attention.write_view_rows`); a layer
+    with per-slot state hands on the loop's carry, which IS its state."""
+    from ..ops.paged_attention import write_view_rows
+    paged = [i for i, c in enumerate(caches) if "k" in c]
+    with scope("kv.copy_back"):
+        written = write_view_rows([caches[i] for i in paged],
+                                  [dense[i] for i in paged], page_table,
+                                  lens_in, lens - lens_in, span, kv_cap)
+    out = list(dense)
+    for i, pages in zip(paged, written):
+        out[i] = pages
+    return out
+
+
 def build_paged_decode_chunk(module, dequant, slot_select, chunk_size: int,
                              kv_cap: int, overlap=None, fused: bool = False,
                              with_stats: bool = False):
@@ -357,11 +355,13 @@ def build_paged_decode_chunk(module, dequant, slot_select, chunk_size: int,
     idea as the dequant hoist — and carried through the steps; each step runs
     the contiguous-cache decode math of ``engine.generate`` on the carry
     (greedy bit-identity with it is then structural, not analytical) and its
-    appended K/V rows are mirrored into the pages at the end of the chunk so
-    they stay the source of truth across chunks. A per-step gather cost S·cap
+    appended K/V rows go into the pages at the end of the chunk, one slab
+    write a page a slot (:func:`_copy_back`: a row a slot never advanced
+    past, or at/past ``kv_cap``, keeps what the page held), so they stay the
+    source of truth across chunks. A per-step gather cost S·cap
     bytes every step; per-chunk it is 1/K of that. ``kv_cap`` bounds the dense
     view at exactly ``cap`` rows."""
-    from ..ops.paged_attention import gather_kv_dense, page_address
+    from ..ops.paged_attention import gather_kv_dense
     stats0 = (jnp.zeros((2,), jnp.int32),) if with_stats else ()
 
     def decode_chunk(params, toks, caches, page_table, lens, active, remaining,
@@ -388,11 +388,8 @@ def build_paged_decode_chunk(module, dequant, slot_select, chunk_size: int,
             return (buf, toks, caches, lens, active, remaining, steps) + out[7:]
 
         # XLA fallback: hoisted per-chunk gather, contiguous-cache steps over
-        # the dense carry, ONE end-of-chunk mirror of the appended rows back
-        # into the pages — the pages leave/enter the loop nowhere
-        paged = [c for c in caches if "k" in c]
-        ps = paged[0]["k"].shape[2]
-        P_total = paged[0]["k"].shape[0]
+        # the dense carry, the appended rows written back into the pages at
+        # the end of the chunk — the pages leave/enter the loop nowhere
         lens_in = lens
         with scope("kv.gather"):
             dense = [dict(zip(("k", "v"),
@@ -410,27 +407,8 @@ def build_paged_decode_chunk(module, dequant, slot_select, chunk_size: int,
                 0, chunk_size, body,
                 (toks, dense, lens, active, remaining, steps, buf) + stats0)
         toks, dense, lens, active, remaining, steps, buf = out[:7]
-        # mirror rows [lens_in, lens) (this chunk's appends) into the pages;
-        # rows a slot never advanced past, or beyond cap, are dropped
-        with scope("kv.copy_back"):
-            done = lens - lens_in
-        new_caches = []
-        for c, dn in zip(caches, dense):
-            if "k" not in c:             # per-slot state: the loop's carry IS it
-                new_caches.append(dn)
-                continue
-            k_p, v_p = c["k"], c["v"]
-            with scope("kv.copy_back"):
-                for j in range(chunk_size):
-                    rows = lens_in + j
-                    pidx, off = page_address(page_table, rows, kv_cap, ps, P_total,
-                                             live=lambda: j < done)
-                    idx = jnp.minimum(rows, kv_cap - 1)[:, None, None, None]
-                    k_new = jnp.take_along_axis(dn["k"], idx, axis=2)[:, :, 0, :]
-                    v_new = jnp.take_along_axis(dn["v"], idx, axis=2)[:, :, 0, :]
-                    k_p = k_p.at[pidx, :, off, :].set(k_new.astype(k_p.dtype))
-                    v_p = v_p.at[pidx, :, off, :].set(v_new.astype(v_p.dtype))
-            new_caches.append({"k": k_p, "v": v_p})
+        new_caches = _copy_back(caches, dense, page_table, lens_in, lens,
+                                chunk_size, kv_cap)
         return (buf, toks, new_caches, lens, active, remaining, steps) + out[7:]
 
     return decode_chunk
@@ -598,9 +576,8 @@ def build_block_decode_chunk(module, dequant, slot_select, forwards: int,
     page), the forwards run on it (a block's rows are written before they
     count: ``lens`` moves only on a commit), and the blocks COMMITTED in the
     chunk, rows ``[lens_in, lens_out)`` of a slot, are copied back into its
-    pages at the end, a block at a time: a block never straddles a page (the
-    page size is a multiple of the block), so each is one in-place slab
-    write, and a block that was not committed goes to the null page. The
+    pages at the end, one in-place slab write a page (:func:`_copy_back`; a
+    block that was not committed leaves its page as it was). The
     block in flight stays out of the pages: its rows are rewritten by the
     next forward. Per-slot state between chunks: the block's tokens ``blk``,
     which are still ``masked``, and how many of them the prompt gave
@@ -608,7 +585,6 @@ def build_block_decode_chunk(module, dequant, slot_select, forwards: int,
     opened, by the next chunk's first forward."""
     from ..ops.paged_attention import gather_kv_dense
     cfg = module.config
-    B = cfg.gen_block_length
     width = block_chunk_width(cfg, forwards)
     rows_view = block_view_rows(cfg, kv_cap)
     stats0 = (jnp.zeros((2,), jnp.int32),) if with_stats else ()
@@ -634,28 +610,8 @@ def build_block_decode_chunk(module, dequant, slot_select, forwards: int,
                 (blk, masked, skip, dense, lens, active, remaining, steps, buf,
                  jnp.zeros((3,), jnp.int32)) + stats0)
         blk, masked, skip, dense, lens, active, remaining, steps, buf, counts = out[:10]
-        new_caches = []
-        for c, dn in zip(caches, dense):
-            if "k" not in c:
-                new_caches.append(dn)
-                continue
-            pages = dict(c)
-            with scope("kv.copy_back"):
-                for j in range(width // B):
-                    row0 = lens_in + j * B
-                    live = row0 < lens
-                    row0 = jnp.minimum(row0, kv_cap - B)
-                    page = jnp.where(live, jnp.take_along_axis(
-                        page_table, (row0 // ps)[:, None], axis=1)[:, 0], 0)
-                    off = row0 % ps
-                    rows = (row0[:, None] + jnp.arange(B)[None])[:, None, :, None]
-                    for key in ("k", "v"):
-                        slabs = jnp.take_along_axis(dn[key], rows, axis=2)  # (S, hk, B, d)
-                        for i in range(S):
-                            pages[key] = jax.lax.dynamic_update_slice(
-                                pages[key], slabs[i:i + 1].astype(pages[key].dtype),
-                                (page[i], 0, off[i], 0))
-            new_caches.append(pages)
+        new_caches = _copy_back(caches, dense, page_table, lens_in, lens, width,
+                                kv_cap)
         return (buf, blk, masked, skip, new_caches, lens, active, remaining, steps,
                 counts) + out[10:]
 

@@ -41,7 +41,7 @@ from ...observability import profiler as obs_profiler
 from ...observability.trace import get_tracer, scope
 from ...utils.fault_injection import fault_point
 from ...ops.paged_attention import (FORCE_FUSED_ENV, fused_paged_for,
-                                    page_address, pages_to_dense)
+                                    pages_to_dense, write_view_rows)
 from ...parallel.mesh import AXIS_TENSOR, get_global_mesh
 from ..decode_fns import (block_chunk_width, build_block_decode_chunk,
                           build_paged_decode_chunk, build_paged_spec_verify,
@@ -115,6 +115,37 @@ def _packed_chunk(chunk):
         return packed, caches
 
     return decode_chunk
+
+
+def _suffix_prefill(prefix_prefill, select, cap: int):
+    """The cache-hit prefill's program
+    (:meth:`ChunkedDecodeExecutor._suffix_prefill_fn_paged`) over
+    ``decode_fns.build_prefix_prefill``'s function: ``ctl`` is ``(prefix_len,
+    suffix_len, seed)`` and then the slot's page-table row."""
+
+    def suffix_prefill(params, caches, ids, ctl, base_key):
+        with scope("chunk.pack"):
+            prefix_len, suffix_len, seed = ctl[0:1], ctl[1:2], ctl[2:3]
+            tbl = ctl[PRE_COLS:]        # the slot's page-table row
+        one = []
+        with scope("kv.gather"):
+            for c in caches:
+                k = pages_to_dense(c["k"], tbl)
+                v = pages_to_dense(c["v"], tbl)
+                one.append({"k": k[None, :, :cap, :],
+                            "v": v[None, :, :cap, :]})
+        logits, new_one = prefix_prefill(params, ids, one, prefix_len,
+                                         suffix_len)
+        tok0 = select(logits, base_key, seed, jnp.zeros_like(seed))
+        # ONLY the suffix rows [prefix, prefix + bucket) go back, as slab
+        # writes; a row at or past cap keeps what its page held
+        t = ids.shape[1]
+        with scope("kv.copy_back"):
+            out = write_view_rows(caches, new_one, tbl[None], prefix_len,
+                                  jnp.full_like(prefix_len, t), t, cap)
+        return tok0[0], out
+
+    return suffix_prefill
 
 
 def _packed_block_chunk(chunk, block: int):
@@ -397,8 +428,9 @@ class ChunkedDecodeExecutor:
         """Cache-hit prefill: the slot's pages (shared prefix pages bound
         zero-copy at admission + its COW/fresh pages) are gathered into the
         dense batch-1 view INSIDE the dispatch, the suffix forward runs at
-        the prefix offset, and ONLY the suffix rows scatter back to their
-        page-mapped positions — shared pages are read, never written. The
+        the prefix offset, and ONLY the suffix rows go back to their
+        page-mapped positions, one slab write a page (``write_view_rows``)
+        — a shared page is rewritten with its own content at most. The
         POOL pages flow through and are donated; one compile per
         (pages, page, cap, suffix-bucket, sampling) key."""
         key = ("serve_suffix_prefill_paged", self.pool.total_pages,
@@ -409,42 +441,8 @@ class ChunkedDecodeExecutor:
             prefix_prefill = build_prefix_prefill(
                 engine.module, engine._dequant,
                 overlap=getattr(engine, "comm_overlap", None))
-            select = self._slot_select
-            cap = self.cap
-            ps, P_total = self.pool.page_size, self.pool.total_pages
-
-            def suffix_prefill(params, caches, ids, ctl, base_key):
-                with scope("chunk.pack"):
-                    prefix_len, suffix_len, seed = ctl[0:1], ctl[1:2], ctl[2:3]
-                    tbl = ctl[PRE_COLS:]        # the slot's page-table row
-                one = []
-                with scope("kv.gather"):
-                    for c in caches:
-                        k = pages_to_dense(c["k"], tbl)
-                        v = pages_to_dense(c["v"], tbl)
-                        one.append({"k": k[None, :, :cap, :],
-                                    "v": v[None, :, :cap, :]})
-                logits, new_one = prefix_prefill(params, ids, one, prefix_len,
-                                                 suffix_len)
-                tok0 = select(logits, base_key, seed, jnp.zeros_like(seed))
-                # scatter ONLY the suffix rows [prefix, prefix + bucket) back;
-                # rows beyond cap are dropped (the dense path's OOB-pad-drop
-                # contract)
-                t = ids.shape[1]
-                out = []
-                with scope("kv.copy_back"):
-                    rows = prefix_len[0] + jnp.arange(t)
-                    pidx, off = page_address(tbl, rows, cap, ps, P_total)
-                    for c, n in zip(caches, new_one):
-                        kv = {}
-                        for key_ in ("k", "v"):
-                            vals = jnp.take(n[key_][0], rows, axis=1,
-                                            mode="clip").transpose(1, 0, 2)
-                            kv[key_] = c[key_].at[pidx, :, off, :].set(
-                                vals.astype(c[key_].dtype))
-                        out.append(kv)
-                return tok0[0], out
-
+            suffix_prefill = _suffix_prefill(prefix_prefill,
+                                             self._slot_select, self.cap)
             fns[key] = jax.jit(suffix_prefill, donate_argnums=(1,))
         return fns[key]
 

@@ -16,8 +16,9 @@ r * d)``: how many KV heads ``r`` lie side by side in a row of the pages, of
 the dense view and of the contiguous cache (:func:`heads_per_row`, the row
 rule's one home; :func:`kv_rows` lays a projection's keys or values out so),
 pages -> dense rows (:func:`gather_kv_dense`, :func:`pages_to_dense`),
-dense rows -> whole pages (:func:`write_dense_pages`) and row -> (page,
-offset) (:func:`page_address`, :func:`paged_cache_update`). The pool's movers
+dense rows -> whole pages (:func:`write_dense_pages`), a slot's view rows ->
+in-place page slabs (:func:`write_view_rows`) and one row -> (page, offset)
+(:func:`paged_cache_update`). The pool's movers
 and the serve programs call these; all but the first two take a row's head and
 lane extents from their operands, whatever ``r`` made them.
 
@@ -145,27 +146,65 @@ def write_dense_pages(pages, dense, tbl):
         for key in ("k", "v")}
 
 
-def page_address(page_table, rows, kv_cap: int, page_size: int,
-                 total_pages: int, live=None):
-    """Dense rows -> ``(page index, offset in the page)`` for a scatter into
-    the pages. ``page_table (S, max_pages)`` with ``rows (S,)`` (a row a
-    slot), or one slot's ``(max_pages,)`` with ``rows (t,)``. A row at or
-    beyond ``kv_cap``, or one ``live`` rules out, gets the out-of-range page
-    ``total_pages``: the scatter drops it, so released or shared pages are
-    never written. ``live`` is a function returning the mask, called once the
-    page position is traced: the chunk and the verify round keep the
-    operation order, and so the compile-cache keys, they had with this
-    arithmetic written out in them."""
-    page_pos = jnp.clip(rows // page_size, 0, page_table.shape[-1] - 1)
-    keep = rows < kv_cap if live is None else live() & (rows < kv_cap)
-    if page_table.ndim == 1:
-        pidx = jnp.where(keep, page_table[page_pos], total_pages)
-    else:
-        pidx = jnp.where(keep,
-                         jnp.take_along_axis(page_table, page_pos[:, None],
-                                             axis=1)[:, 0],
-                         total_pages)
-    return pidx, rows % page_size
+def write_view_rows(pages, view, page_table, start, count, span: int,
+                    kv_cap: int):
+    """Dense view rows -> the pages, as in-place page slabs: rows ``[start[s],
+    start[s] + count[s])`` of every slot ``s``, below ``kv_cap``, and no other.
+
+    ``pages`` and ``view`` are pytrees with the same leaves in the same order
+    (the k and v arrays of every layer): ``(P, hk, page, d)`` pages and their
+    ``(S, hk, rows, d)`` dense views, ``rows >= kv_cap``; ``page_table (S,
+    max_pages)``; ``start``, ``count`` ``(S,)`` with ``count <= span``, the
+    static bound that says how many pages a slot's rows can reach: ``n =
+    ceil(span / page) + 1`` (a chunk of 8 on pages of 16: two). The page
+    positions, page indices and row masks are computed once, ``(S, n)`` at a
+    time, and shared by every array. For each of a slot's ``n`` pages an array
+    takes the page's rows out of the view with one ``dynamic_slice`` at a
+    page-aligned row (the slot index static, no gather), keeps the page's
+    present rows wherever the mask says no, and writes the page back with one
+    ``dynamic_update_slice``: nothing the shape of the pages is copied or
+    re-laid-out (an ``.at[page, :, row, :].set`` scatter makes the TPU
+    compiler transpose the whole pool and back, every call). A slot with
+    ``count`` 0 and a row at or past ``kv_cap`` rewrite a page with its own
+    content, so a shared, released or null page never changes value."""
+    leaves, tree = jax.tree_util.tree_flatten(pages)
+    ps = leaves[0].shape[2]
+    S, mp = page_table.shape
+    n = min(-(-span // ps) + 1, mp)
+    # rows past the table's last page are at or past kv_cap: clamping the
+    # first page back over the table's end loses none that would be written
+    pos = (jnp.clip(start // ps, 0, mp - n)[:, None]
+           + jnp.arange(n, dtype=start.dtype)[None])              # (S, n)
+    pidx = jnp.take_along_axis(page_table, pos, axis=1)           # (S, n)
+    row0 = pos * ps
+    rows = row0[:, :, None] + jnp.arange(ps, dtype=start.dtype)   # (S, n, page)
+    end = jnp.minimum(start + count, kv_cap)[:, None, None]
+    keep = ((rows >= start[:, None, None]) & (rows < end))[:, :, None, :, None]
+    views = jax.tree_util.tree_leaves(view)
+    ragged = -views[0].shape[2] % ps
+    if ragged:      # a view that ends inside a page: its last slab's slice
+        views = [jnp.pad(vw, ((0, 0), (0, 0), (0, ragged), (0, 0)))   # would slide back
+                 for vw in views]
+    for s in range(S):
+        for i in range(n):
+            leaves = _write_slab(leaves, views, s, pidx[s, i], row0[s, i],
+                                 keep[s:s + 1, i])
+    return tree.unflatten(leaves)
+
+
+@jax.jit
+def _write_slab(pages, views, s, page, row, keep):
+    """One page of one slot, in every array: :func:`write_view_rows`'s unit,
+    jitted so that a program which writes a thousand of them traces and lowers
+    ONE (the compiler inlines the calls: ``s`` is a constant again there)."""
+    out = []
+    for pg, vw in zip(pages, views):
+        at, slab = (page, 0, 0, 0), (1,) + pg.shape[1:]
+        new = jax.lax.dynamic_slice(vw, (s, 0, row, 0), slab)
+        old = jax.lax.dynamic_slice(pg, at, slab)
+        out.append(jax.lax.dynamic_update_slice(
+            pg, jnp.where(keep, new.astype(pg.dtype), old), at))
+    return out
 
 
 def paged_attention_xla(q, k_pages, v_pages, page_table, cache_len, cap: int,

@@ -28,11 +28,12 @@ from deepspeed_tpu.inference.serving import (ChaosEvent, ChaosSchedule,
                                              Router, RouterConfig,
                                              ServingConfig)
 from deepspeed_tpu.models.causal_lm import gpt2_cfg
-from deepspeed_tpu.ops.paged_attention import (gather_kv_dense, page_address,
+from deepspeed_tpu.ops.paged_attention import (gather_kv_dense,
                                                paged_attention_fused,
                                                paged_attention_xla,
                                                pages_to_dense,
-                                               write_dense_pages)
+                                               write_dense_pages,
+                                               write_view_rows)
 from deepspeed_tpu.ops.attention.decode import decode_attention_xla
 
 pytestmark = pytest.mark.paged_kv
@@ -192,63 +193,85 @@ def test_clear_releases_cached_pages(engine):
 
 
 # ------------------------------------------------------------- page layout
-def _np_address(table, rows, live, cap, ps, total):
-    """Row by row: ``(table[row // ps], row % ps)``, or the out-of-range
-    page where the row is dropped."""
-    pidx = np.full(rows.shape, total, np.int64)
-    for i, r in enumerate(rows):
-        row_table = table if table.ndim == 1 else table[i]
-        if live[i] and r < cap:
-            pidx[i] = row_table[r // ps]
-    return pidx, rows % ps
+def _np_write_rows(pages, view, table, start, count, cap):
+    """Row by row: row ``r`` of slot ``s``'s view goes to ``(table[s, r //
+    ps], r % ps)`` for ``start[s] <= r < start[s] + count[s]``, ``r < cap``."""
+    out, ps = pages.copy(), pages.shape[2]
+    for s in range(table.shape[0]):
+        for r in range(start[s], start[s] + count[s]):
+            if r < cap:
+                out[table[s, r // ps], :, r % ps, :] = view[s, :, r, :]
+    return out
 
 
-@pytest.mark.parametrize("site", ["chunk", "verify", "suffix"])
-def test_page_address_matches_numpy_and_its_scatter_drops_dead_rows(site):
-    """The three call sites' forms: the chunk's mirror (row ``lens + j`` of
-    every slot, live while ``j < done``), the verify round's (``active &
-    (j < valid)``) and the suffix prefill's (one slot's table, a vector of
-    rows). Rows at or past ``cap``, inactive slots and rows past ``done`` get
-    the out-of-range page, and a scatter through the addresses leaves every
-    page they do not name as it was."""
-    ps, mp, total, cap, hk, d = 4, 3, 9, 10, 2, 3      # cap ends inside page 2
-    rng = np.random.default_rng(1)
-    table = np.array([[5, 2, 7], [1, 8, 3], [4, 6, 0]], np.int32)
-    pages = rng.normal(size=(total, hk, ps, d)).astype(np.float32)
-    if site == "suffix":
-        tbl = table[1]
-        rows = 6 + np.arange(8)                        # 6..13: four past cap
-        live = np.ones(8, bool)
-        pidx, off = page_address(jnp.asarray(tbl), jnp.asarray(rows), cap, ps,
-                                 total)
-        want = _np_address(tbl, rows, live, cap, ps, total)
+_TABLE = np.array([[5, 2, 7], [1, 8, 3], [4, 6, 9]], np.int32)
+# name: (page table, cap, start, count, span); pages of 4 rows, 10 in the pool
+_WRITES = {
+    # rows 3..6 and 6..9 cross from one page into the next
+    "span_straddles_a_page": (_TABLE, 12, [3, 6, 0], [4, 4, 4], 4),
+    # the chunk's form: slot 0 stopped after one step, slot 2 took none
+    "slot_stopped_mid_chunk": (_TABLE, 12, [2, 5, 9], [1, 4, 0], 4),
+    "inactive_slots": (_TABLE, 12, [0, 7, 11], [0, 0, 0], 4),
+    # slot 0 starts AT the cap, slot 1 past it, slot 2 runs over it
+    "start_at_and_past_the_cap": (_TABLE, 12, [12, 15, 10], [2, 2, 4], 4),
+    # cap 10 ends inside page 2: rows 10 and 11 of it are never written,
+    # and the view's 10 rows end inside the page's slab
+    "cap_ends_inside_a_page": (_TABLE, 10, [8, 9, 6], [4, 1, 3], 4),
+    # the suffix prefill's form: one slot's table, a bucket of 8 rows from 6
+    "one_slot_a_vector_of_rows": (_TABLE[1:2], 10, [6], [8], 8),
+    # the verify round's: active & (j < valid) as where(active, valid, 0)
+    "verify_round_mask": (_TABLE, 12, [3, 8, 5], [3, 0, 1], 5),
+    # a span of more pages than the table has: every page of the slot
+    "span_wider_than_the_table": (_TABLE[:1], 12, [1], [11], 16),
+    # page 5 is slot 0's and slot 1's first (a shared prefix): slot 0 is
+    # inactive INSIDE it, slot 1 appends behind it; 0 is the null page
+    "shared_page_left_bit_equal": (
+        np.array([[5, 2, 0], [5, 8, 3]], np.int32), 12, [2, 4], [0, 3], 4),
+}
+
+
+@pytest.mark.parametrize("case", sorted(_WRITES) + ["two_heads_a_row"])
+def test_view_rows_go_into_their_pages_and_nothing_else_changes(case):
+    """``write_view_rows`` against the row-by-row loop, bit for bit, in the
+    forms its four callers give it (the chunk's and the block chunk's ``[lens_in,
+    lens)``, the verify round's valid rows of active slots, the suffix
+    prefill's one slot): every row the loop writes, and no other element of
+    any page, shared, null or unnamed. ``two_heads_a_row``: the view's rows
+    hold two KV heads of 64 side by side (``kv_rows(x, 2)``) and each lands
+    in its half of the page's 128 lanes."""
+    from deepspeed_tpu.ops.paged_attention import kv_rows
+    ps, total = 4, 10
+    rng = np.random.default_rng(len(case))
+    if case == "two_heads_a_row":
+        table, cap, start, count, span = _WRITES["span_straddles_a_page"]
+        x = rng.normal(size=(3, cap, 4, 64)).astype(np.float32)   # (S, t, hk, d)
+        view = np.asarray(kv_rows(jnp.asarray(x), 2))
+        assert view.shape == (3, 2, cap, 128)
     else:
-        lens = np.array([3, 8, 11], np.int32)          # slot 2 is past cap
-        j = 1
-        if site == "chunk":
-            done = np.array([2, 2, 2], np.int32)
-            done[0] = 1                                # slot 0 stopped at j=1
-            live = j < done
-            mask = lambda: j < jnp.asarray(done)       # noqa: E731
-        else:
-            active = np.array([True, False, True])
-            valid = np.array([3, 3, 3], np.int32)
-            live = active & (j < valid)
-            mask = lambda: jnp.asarray(active) & (j < jnp.asarray(valid))  # noqa: E731
-        rows = lens + j
-        pidx, off = page_address(jnp.asarray(table), jnp.asarray(rows), cap,
-                                 ps, total, live=mask)
-        want = _np_address(table, rows, live, cap, ps, total)
-    np.testing.assert_array_equal(np.asarray(pidx), want[0])
-    np.testing.assert_array_equal(np.asarray(off), want[1])
-    assert (want[0] == total).any() and (want[0] != total).any()
-    new = rng.normal(size=(len(rows), hk, d)).astype(np.float32)
-    got = jnp.asarray(pages).at[pidx, :, off, :].set(jnp.asarray(new))
-    ref = pages.copy()
-    for i, (pg, o) in enumerate(zip(*want)):
-        if pg != total:
-            ref[pg, :, o, :] = new[i]
-    np.testing.assert_array_equal(np.asarray(got), ref)
+        table, cap, start, count, span = _WRITES[case]
+        view = rng.normal(size=(table.shape[0], 2, cap, 3)).astype(np.float32)
+    start, count = np.asarray(start, np.int32), np.asarray(count, np.int32)
+    before = {key: rng.normal(size=(total,) + view.shape[1:2] + (ps,)
+                              + view.shape[3:]).astype(np.float32)
+              for key in ("k", "v")}
+    views = {"k": view, "v": -view}
+    after = jax.jit(write_view_rows, static_argnums=(5, 6))(
+        [before], [views], jnp.asarray(table), jnp.asarray(start),
+        jnp.asarray(count), span, cap)[0]
+    for key in ("k", "v"):
+        want = _np_write_rows(before[key], views[key], table, start, count, cap)
+        np.testing.assert_array_equal(np.asarray(after[key]), want)
+    written = np.asarray(after["k"]) != before["k"]
+    if case == "inactive_slots":
+        assert not written.any()
+    else:
+        assert written.any() and not written[0].any()       # never the null page
+    if case == "shared_page_left_bit_equal":
+        np.testing.assert_array_equal(np.asarray(after["k"])[5], before["k"][5])
+    if case == "two_heads_a_row":
+        s, r = 1, 7                                    # slot 1's row 7: page 8, row 3
+        got = np.asarray(after["k"])[table[s, r // ps], :, r % ps, :]
+        np.testing.assert_array_equal(got.reshape(4, 64), x[s, r])
 
 
 @pytest.mark.parametrize("rows,batched", [(16, False), (13, True), (5, False)])

@@ -65,34 +65,67 @@ def _hybrid_attention_layer():
         routed_scaling_factor=5, dtype=jnp.bfloat16)
 
 
+def _granite_attention_layer():
+    from deepspeed_tpu.models.causal_lm import granite_hybrid_cfg
+    return granite_hybrid_cfg(
+        hidden_size=2048, num_hidden_layers=1, layer_types=["attention"],
+        vocab_size=100352, num_attention_heads=32, num_key_value_heads=8,
+        shared_intermediate_size=8192, mamba_n_heads=64, mamba_d_head=64,
+        mamba_d_state=128, mamba_n_groups=1, mamba_d_conv=4, mamba_chunk_size=256,
+        embedding_multiplier=12, residual_multiplier=0.22,
+        attention_multiplier=0.015625, logits_scaling=8, dtype=jnp.bfloat16)
+
+
+def _abstract_model(cfg, slots, cap, pages, page, sds):
+    """A module with its parameters and its pool's caches as shapes on the
+    described chip (the pool's rows as ``heads_per_row`` lays them out)."""
+    from deepspeed_tpu.models.causal_lm import CausalLM, init_cache
+    from deepspeed_tpu.ops.paged_attention import heads_per_row
+    module = CausalLM(cfg)
+    params = jax.eval_shape(lambda: module.init(
+        jax.random.PRNGKey(0), jnp.zeros((1, 8), jnp.int32))["params"])
+    params = jax.tree_util.tree_map(lambda x: sds(x.shape, cfg.dtype), params)
+    r = heads_per_row(cfg.head_dim, cfg.kv_heads)
+    kv_shape = (pages, cfg.kv_heads // r, page, r * cfg.head_dim)
+    caches = jax.tree_util.tree_map(
+        lambda x: sds(x.shape, x.dtype), jax.eval_shape(lambda: init_cache(
+            cfg, slots, cap, kv_shape=kv_shape)))
+    return module, params, caches, kv_shape
+
+
+def _pool_relayouts(text, kv_shape):
+    """The compiled lines that scatter into, or copy, an array of the pages'
+    shape: what an ``.at[page, :, row, :].set`` into the pool compiles to (the
+    whole pool transposed for the scatter and back, every call)."""
+    shape = " = bf16[" + ",".join(str(n) for n in kv_shape) + "]"
+    return [line.strip()[:160] for line in text.splitlines()
+            if shape in line and (" scatter(" in line or " copy(" in line)]
+
+
 @pytest.mark.parametrize("make_cfg,slots,cap,pages,kernels", [
     (_bloom_layers, 2, 576, 73, 0),               # bloom-7b1's cells, 2 of 30 layers
     (_hybrid_attention_layer, 32, 2048, 4097, 1),  # the hybrid's one attention layer
-], ids=["bloom-7b1", "nemotron-h-attention"])
+    (_granite_attention_layer, 64, 2048, 8193, 0),  # Granite's, two d 64 heads a row
+], ids=["bloom-7b1", "nemotron-h-attention", "granite-attention"])
 def test_the_decode_chunk_holds_no_loop_but_its_own(
         one_chip, make_cfg, slots, cap, pages, kernels, monkeypatch):
     """The dense-view decode chunk at the cells' shapes: a step appends its
     K/V rows without a loop over the slots (a scatter the TPU compiler
     expands into a serial ``while`` of trip count = slots, twice a layer a
     step), so the chunk's own ``while`` is the only one; BLOOM's chunk holds
-    no Mosaic kernel, the hybrid's attention layer its ``decode_attention``."""
+    no Mosaic kernel, the hybrid's attention layer its ``decode_attention``.
+    The chunk's rows go back into the pages as slab writes: no scatter, and
+    no copy of a pages-shaped array (``write_view_rows``)."""
     from deepspeed_tpu.inference.decode_fns import (build_paged_decode_chunk,
                                                     make_slot_select_fn)
-    from deepspeed_tpu.models.causal_lm import CausalLM, init_cache
     from deepspeed_tpu.ops.attention import decode
     monkeypatch.setattr(decode, "_interpret", lambda: False)
     cfg, page, chunk = make_cfg(), 16, 8
-    module = CausalLM(cfg)
 
     def sds(shape, dtype=jnp.int32):
         return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
 
-    params = jax.eval_shape(lambda: module.init(
-        jax.random.PRNGKey(0), jnp.zeros((1, 8), jnp.int32))["params"])
-    params = jax.tree_util.tree_map(lambda x: sds(x.shape, cfg.dtype), params)
-    caches = jax.tree_util.tree_map(
-        lambda x: sds(x.shape, x.dtype), jax.eval_shape(lambda: init_cache(
-            cfg, slots, cap, kv_shape=(pages, cfg.kv_heads, page, cfg.head_dim))))
+    module, params, caches, kv_shape = _abstract_model(cfg, slots, cap, pages, page, sds)
     fn = build_paged_decode_chunk(module, lambda p: p,
                                   make_slot_select_fn(False, 1.0, 0, 1.0),
                                   chunk, kv_cap=cap)
@@ -105,6 +138,34 @@ def test_the_decode_chunk_holds_no_loop_but_its_own(
     assert 'op_name="jit(decode_chunk)/while"' in loops[0]
     assert text.count("tpu_custom_call") == kernels
     assert not kernels or "decode_attention" in text
+    assert " scatter(" not in text and _pool_relayouts(text, kv_shape) == []
+
+
+@pytest.mark.parametrize("bucket", [8, 64])
+def test_the_suffix_prefill_writes_its_rows_without_relaying_the_pool(
+        one_chip, bucket, monkeypatch):
+    """The prefix-hit prefill at BLOOM's shapes (the pool of 73 pages, cap
+    576, the smallest and the largest suffix bucket; 2 of 30 layers): the
+    suffix's rows go into the slot's pages as slab writes, so the program
+    scatters into and copies no pages-shaped array (the forward's scatter of
+    the suffix's rows into the one-slot VIEW stays)."""
+    from deepspeed_tpu.inference.decode_fns import (build_prefix_prefill,
+                                                    make_slot_select_fn)
+    from deepspeed_tpu.inference.serving.executor import PRE_COLS, _suffix_prefill
+    from deepspeed_tpu.ops.attention import decode
+    monkeypatch.setattr(decode, "_interpret", lambda: False)
+    cfg, cap, pages, page = _bloom_layers(), 576, 73, 16
+
+    def sds(shape, dtype=jnp.int32):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    module, params, caches, kv_shape = _abstract_model(cfg, 2, cap, pages, page, sds)
+    fn = _suffix_prefill(build_prefix_prefill(module, lambda p: p),
+                         make_slot_select_fn(False, 1.0, 0, 1.0), cap)
+    text = jax.jit(fn, donate_argnums=(1,)).lower(
+        params, caches, sds((1, bucket)), sds((PRE_COLS + cap // page,)),
+        sds((2,), jnp.uint32)).compile().as_text()
+    assert _pool_relayouts(text, kv_shape) == []
 
 
 @pytest.mark.parametrize("tokens", [128, 512])
@@ -166,9 +227,10 @@ def test_sdars_block_chunk_holds_its_two_kernels_and_no_loop_but_its_own(
     rows of one group, a length a run) and the gated ``moe_grouped_ffn``; the
     blocks' rows are appended and the committed blocks copied back to their
     pages without a loop over the slots."""
-    from deepspeed_tpu.inference.decode_fns import (build_block_decode_chunk,
+    from deepspeed_tpu.inference.decode_fns import (block_view_rows,
+                                                    build_block_decode_chunk,
                                                     make_slot_select_fn)
-    from deepspeed_tpu.models.causal_lm import CausalLM, init_cache, sdar_moe_cfg
+    from deepspeed_tpu.models.causal_lm import sdar_moe_cfg
     from deepspeed_tpu.ops.attention import decode
     from deepspeed_tpu.ops.moe import grouped_ffn as g
     monkeypatch.setattr(decode, "_interpret", lambda: False)
@@ -178,17 +240,11 @@ def test_sdars_block_chunk_holds_its_two_kernels_and_no_loop_but_its_own(
                        num_experts=128, num_experts_per_tok=8,
                        moe_intermediate_size=768, dtype=jnp.bfloat16)
     slots, cap, pages, page, forwards, B = 32, 2048, 4097, 16, 10, 4
-    module = CausalLM(cfg)
 
     def sds(shape, dtype=jnp.int32):
         return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
 
-    params = jax.eval_shape(lambda: module.init(
-        jax.random.PRNGKey(0), jnp.zeros((1, 8), jnp.int32))["params"])
-    params = jax.tree_util.tree_map(lambda x: sds(x.shape, cfg.dtype), params)
-    caches = jax.tree_util.tree_map(
-        lambda x: sds(x.shape, x.dtype), jax.eval_shape(lambda: init_cache(
-            cfg, slots, cap, kv_shape=(pages, cfg.kv_heads, page, cfg.head_dim))))
+    module, params, caches, kv_shape = _abstract_model(cfg, slots, cap, pages, page, sds)
     fn = build_block_decode_chunk(module, lambda p: p,
                                   make_slot_select_fn(False, 1.0, 0, 1.0),
                                   forwards, kv_cap=cap, with_stats=True)
@@ -201,6 +257,10 @@ def test_sdars_block_chunk_holds_its_two_kernels_and_no_loop_but_its_own(
     assert len(loops) == 1, [line.split(" = ")[0].strip() for line in loops]
     assert text.count("tpu_custom_call") == 4       # two kernels a layer
     assert "decode_attention" in text and "moe_grouped_ffn" in text
+    # the committed blocks' slabs come out of the view by dynamic_slice: no
+    # gather re-lays the VIEW out either
+    view = (slots, kv_shape[1], block_view_rows(cfg, cap), kv_shape[3])
+    assert _pool_relayouts(text, kv_shape) == [] and _pool_relayouts(text, view) == []
 
 
 def _flash_cases():
