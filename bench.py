@@ -49,8 +49,7 @@ def kernel_gate(mode: str):
     the per-kernel max-abs-err dict; raises on any failure."""
     from deepspeed_tpu.ops.kernel_checks import run_kernel_checks
     names = {"train": ("flash_fwd", "flash_fwd_bf16", "flash_bwd", "block_sparse"),
-             "inference": ("flash_fwd", "flash_alibi", "decode", "paged_mha",
-                           "paged_gqa")}[mode]
+             "inference": ("flash_fwd", "flash_alibi", "decode")}[mode]
     return run_kernel_checks(names)
 
 

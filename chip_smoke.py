@@ -17,9 +17,9 @@ a seed), in ONE process — a chip belongs to one process at a time:
    -> requests of mixed prompt lengths submitted while others decode: every
    request ends ``finished`` with exactly the tokens asked for, one prefix
    hit, and one greedy request token-identical to ``engine.generate``;
-4. the paged kernel in situ — the same scheduler on a rotary model at the
+4. the decode kernel in situ — the same scheduler on a rotary model at the
    same widths cut to 4 layers, whose decode chunk must hold the Mosaic
-   paged-attention call inside its loop;
+   ``decode_attention`` call inside its loop, once a layer, and no other;
 5. with four chips: GPT-2 1.3B ZeRO-3 over fsdp=4 (no offload) and BLOOM-7B at
    tp=4 through the same scheduler, with per-device placement evidence.
 
@@ -305,8 +305,8 @@ def phase_serve(tag, shape, prompts_cfg, on_tpu, probe):
     print(f"[{tag}]   memory: weights {cfg.num_params() * 2 / 1e9:.2f} GB + "
           f"pool {pages} pages x {PAGE} tok x {kv_tok} B = "
           f"{pages * PAGE * kv_tok / 1e9:.2f} GB (slots {slots} x cap {cap}) + "
-          f"dense-gather view {slots * cap * kv_tok / 1e9:.2f} GB where the "
-          f"chunk takes that route; / {tp} device(s)", flush=True)
+          f"the chunk's dense view {slots * cap * kv_tok / 1e9:.2f} GB; "
+          f"/ {tp} device(s)", flush=True)
 
     config = {"dtype": "bfloat16", "max_out_tokens": cap}
     if tp > 1:
@@ -376,7 +376,6 @@ def phase_serve(tag, shape, prompts_cfg, on_tpu, probe):
     sites = {"prefill", "suffix_prefill", "decode_chunk", "decode_loop"}
     found = report_sites(tag, probe.new_modules(), sites)
     if on_tpu:
-        alibi = cfg.pos_emb == "alibi"
         long_ids = f"1x{next(b for b in buckets if pc['long'] <= b)}"
         short_ids = f"1x{next(b for b in buckets if pc['short'] <= b)}"
         check("flash_fwd" in kernels_of(found, "prefill", long_ids),
@@ -385,15 +384,14 @@ def phase_serve(tag, shape, prompts_cfg, on_tpu, probe):
               f"prefill {short_ids} traced a Mosaic kernel; sub-256 buckets "
               "are routed to XLA attention")
         chunk = kernels_of(found, "decode_chunk")
-        if alibi or tp > 1:
-            # by design: no alibi bias / no shard_map path in the paged kernel
-            check("paged_decode" not in chunk,
-                  "alibi/TP decode chunk unexpectedly holds the paged kernel")
-        else:
-            k = chunk.get("paged_decode")
-            check(k is not None and k.in_loop and k.count == cfg.n_layer,
-                  f"decode chunk lacks the Mosaic paged kernel inside its "
-                  f"loop, once per layer: {chunk}")
+        # every chunk steps on the dense view: ALiBi's decode is XLA's (the
+        # kernel has no bias), any other model's the Mosaic decode kernel
+        want = set() if cfg.pos_emb == "alibi" else {"decode_attention"}
+        check(set(chunk) == want
+              and all(k.in_loop and k.count == cfg.n_layer
+                      for k in chunk.values()),
+              f"decode chunk should hold {sorted(want) or 'no Mosaic kernel'} "
+              f"inside its loop, once per layer: {chunk}")
     hbm(tag)
 
 

@@ -313,7 +313,7 @@ def _copy_back(caches, dense, page_table, lens_in, lens, span: int, kv_cap: int)
 
 
 def build_paged_decode_chunk(module, dequant, slot_select, chunk_size: int,
-                             kv_cap: int, overlap=None, fused: bool = False,
+                             kv_cap: int, overlap=None,
                              with_stats: bool = False):
     """Fixed-shape chunked decode over a slot-batch: exactly ``chunk_size``
     steps, every shape static, one compile per (slots, total-pages, page, cap,
@@ -337,22 +337,17 @@ def build_paged_decode_chunk(module, dequant, slot_select, chunk_size: int,
     steps (:func:`apply_model`) to the outputs.
 
     The caches of the layers that keep keys and values are GLOBAL KV pages
-    (``{"k": (P, hk / r, page, r * d), ...}``) and each step writes at the page-mapped
-    row of the slot's static-shape ``page_table`` row — the table itself never
-    changes inside a chunk (pages are bound at admission), so it rides as a
-    loop constant. A slot's page COUNT is runtime data in the table, so page
-    growth across requests never mints a compile key (pinned by the analysis
-    sweep's serving lane). A layer with a recurrent state carries its per-slot
+    (``{"k": (P, hk / r, page, r * d), ...}``), read and written through the
+    slot's static-shape ``page_table`` row — the table itself never changes
+    inside a chunk (pages are bound at admission). A slot's page COUNT is
+    runtime data in the table, so page growth across requests never mints a
+    compile key (pinned by the analysis sweep's serving lane). A layer with a recurrent state carries its per-slot
     ``{"conv", "ssm"}`` arrays through the loop as they are, and a layer that
     keeps nothing an empty dict.
 
-    ``fused=True`` (TPU / ``DS_TPU_PAGED_FORCE_FUSED=1``): each step attends
-    straight against the pages through the Pallas gather-by-page-index kernel
-    — the dense view never materialises.
-
-    ``fused=False`` (the XLA fallback): the dense per-slot view is gathered
-    ONCE per chunk — hoisted out of the ``fori_loop``, same loop-invariance
-    idea as the dequant hoist — and carried through the steps; each step runs
+    The dense per-slot view is gathered ONCE per chunk — hoisted out of the
+    ``fori_loop``, same loop-invariance idea as the dequant hoist — and
+    carried through the steps; each step runs
     the contiguous-cache decode math of ``engine.generate`` on the carry
     (greedy bit-identity with it is then structural, not analytical) and its
     appended K/V rows go into the pages at the end of the chunk, one slab
@@ -372,22 +367,7 @@ def build_paged_decode_chunk(module, dequant, slot_select, chunk_size: int,
         S = toks.shape[0]
         buf = jnp.zeros((S, chunk_size), jnp.int32)
 
-        if fused:
-            def step_model(toks, caches, lens):
-                return apply_model(module, params, with_stats, toks,
-                                   positions=lens[:, None], caches=caches,
-                                   cache_lens=lens, page_table=page_table,
-                                   kv_cap=kv_cap)
-
-            body = _chunk_body(step_model, slot_select, base_key, seeds, eos_ids)
-            with overlap_scope(overlap):
-                out = jax.lax.fori_loop(
-                    0, chunk_size, body,
-                    (toks, caches, lens, active, remaining, steps, buf) + stats0)
-            toks, caches, lens, active, remaining, steps, buf = out[:7]
-            return (buf, toks, caches, lens, active, remaining, steps) + out[7:]
-
-        # XLA fallback: hoisted per-chunk gather, contiguous-cache steps over
+        # the hoisted per-chunk gather, contiguous-cache steps over
         # the dense carry, the appended rows written back into the pages at
         # the end of the chunk — the pages leave/enter the loop nowhere
         lens_in = lens

@@ -26,7 +26,6 @@ they were donated into the wedged dispatch — so the caller must ``reset_pool``
 (the scheduler's decode-failure path already does).
 """
 
-import os
 import threading
 import time
 from dataclasses import dataclass
@@ -40,9 +39,7 @@ from ...models.causal_lm import init_cache
 from ...observability import profiler as obs_profiler
 from ...observability.trace import get_tracer, scope
 from ...utils.fault_injection import fault_point
-from ...ops.paged_attention import (FORCE_FUSED_ENV, fused_paged_for,
-                                    pages_to_dense, write_view_rows)
-from ...parallel.mesh import AXIS_TENSOR, get_global_mesh
+from ...ops.paged_attention import pages_to_dense, write_view_rows
 from ..decode_fns import (block_chunk_width, build_block_decode_chunk,
                           build_paged_decode_chunk, build_paged_spec_verify,
                           build_prefill, build_prefix_prefill,
@@ -346,35 +343,15 @@ class ChunkedDecodeExecutor:
 
     # ------------------------------------------------------------- compiled fns
     def _chunk_fn(self):
-        mesh = get_global_mesh()
-        cfg = self.engine.model_config
-        # the fused kernel has no alibi bias (the layer would re-gather
-        # the dense view EVERY step inside the loop — the fallback hoists
-        # it once per chunk), no shard_map TP path (the fallback's dense
-        # steps route through _sharded_decode), and its dispatcher asks a
-        # MODEL head size of whole lane tiles on-chip (fused_paged_for
-        # mirrors it; the pages' rows are whole tiles from d 32 on:
-        # ops/paged_attention.heads_per_row);
-        # every excluded regime decodes strictly faster on the fallback.
-        # So does a pool that holds a row for every slot's whole cap: on
-        # the chip the kernel took 1.07-2.6x the fallback's time a step at
-        # every page of keys from 8 to 128 KiB (32 slots, pages of 16, cap
-        # 2048: PERF.md, PR 27), so it is taken where the dense view would
-        # need more rows than the pool has (an oversubscribed pool, what
-        # paging is for); the env override forces it
-        oversubscribed = self.slots * self.cap > \
-            (self.pool.total_pages - 1) * self.pool.page_size
-        fused = fused_paged_for(cfg.head_dim) \
-            and (oversubscribed or os.environ.get(FORCE_FUSED_ENV, "0") == "1") \
-            and getattr(cfg, "pos_emb", None) != "alibi" \
-            and (mesh is None or mesh.size(AXIS_TENSOR) <= 1)
+        # every pool decodes on the dense view gathered once a chunk: on the
+        # chip a kernel that gathered by page index inside its grid took
+        # 1.07-2.6x the view's time a step (PERF.md section 6, PR 27)
         # one compile per (slots, pages, page, cap, chunk, sampling) key:
         # per-request page COUNTS are runtime table data, so mixed-length
         # traffic and page growth never mint a new key (sweep-pinned).
-        # The fused flag is part of the key — tests toggle the env var.
         key = ("serve_chunk_paged", self.slots, self.pool.total_pages,
                self.pool.page_size, self.cap, self.chunk_size,
-               self.sampling, fused)
+               self.sampling)
         fns = self.engine._fns
         if key not in fns and self.block:
             # always on the dense view: every query of a block sees the same
@@ -391,7 +368,7 @@ class ChunkedDecodeExecutor:
                 self.engine.module, self.engine._dequant,
                 self._slot_select, self.chunk_size, kv_cap=self.cap,
                 overlap=getattr(self.engine, "comm_overlap", None),
-                fused=fused, with_stats=self.with_stats)
+                with_stats=self.with_stats)
             fns[key] = jax.jit(_packed_chunk(chunk),
                                donate_argnums=(2,))          # pages
         return fns[key]

@@ -931,8 +931,7 @@ class CausalLMLayer(nn.Module):
     @nn.compact
     def __call__(self, x, positions, cache: Optional[Dict] = None,
                  cache_len: Optional[jnp.ndarray] = None,
-                 prefix_fill: bool = False, page_table=None,
-                 kv_cap: Optional[int] = None, block_step: bool = False,
+                 prefix_fill: bool = False, block_step: bool = False,
                  attn_mask=None):
         """x: (b, t, d). With ``cache`` given (decode): t==1, attention against the cache.
         With ``prefix_fill`` (static): suffix prefill at a nonzero cache offset —
@@ -940,20 +939,12 @@ class CausalLMLayer(nn.Module):
         ``[0, cache_len)``, the t suffix tokens write their K/V at rows
         ``cache_len + i`` and attend over prefix + suffix (the prefix-cache hit
         path: the prefix's prefill compute is skipped entirely).
-
-        With ``page_table`` (decode only): ``cache`` holds GLOBAL KV pages
-        ``{"k": (P, hk / r, page, r * d), ...}`` and the ``(b, max_pages)`` table maps
-        each row's positions to physical pages — the step appends K/V at the
-        page-mapped row and attends through the paged-attention op (XLA dense
-        gather sliced to ``kv_cap`` rows = bit-identical to a contiguous
-        cache; Pallas gather-by-page-index kernel on TPU).
         Returns (y, new_cache_kv or None)."""
         cfg = self.config
         with scope("norm"):
             h_in = _norm(cfg, "ln_attn")(x).astype(cfg.dtype)
         attn_out, new_kv = self._attention(h_in, positions, cache, cache_len,
-                                           prefix_fill, page_table, kv_cap,
-                                           block_step, attn_mask)
+                                           prefix_fill, block_step, attn_mask)
 
         mlp = self._moe_mlp if self.is_moe else self._mlp
         if cfg.parallel_residual:
@@ -975,13 +966,16 @@ class CausalLMLayer(nn.Module):
         return y, new_kv
 
     def _attention(self, h_in, positions, cache, cache_len, prefix_fill,
-                   page_table, kv_cap, block_step=False, attn_mask=None):
-        """Causal self-attention on the normed input, in whichever of the
-        four cache modes ``__call__`` describes; returns the projected
-        output and the layer's new keys and values (or None).
+                   block_step=False, attn_mask=None):
+        """Causal self-attention on the normed input, in one of its cache
+        modes: the whole sequence (without a cache, or with one to fill: a
+        prefill), one token against the dense cache (decode; a served slot's
+        cache is the chunk's dense view of its pages), a prefill at a cache
+        offset (``prefix_fill``); returns the projected output and the
+        layer's new keys and values (or None).
 
         A model that generates by diffusion over blocks
-        (``cfg.gen_block_length``) has a fifth mode, ``block_step``: ``t`` is
+        (``cfg.gen_block_length``) has one more, ``block_step``: ``t`` is
         one block or several in a row, their keys and values are written at
         rows ``[cache_len, cache_len + t)`` of the dense cache (which must
         hold them: ``_cache_update`` clamps) and every query of the ``j``-th
@@ -1012,7 +1006,7 @@ class CausalLMLayer(nn.Module):
 
         new_kv = None
         if block_step:
-            if cache is None or page_table is not None or slopes is not None:
+            if cache is None or slopes is not None:
                 raise NotImplementedError(
                     "a block step runs on the dense cache view, without alibi")
             with scope("attn.heads"):
@@ -1026,31 +1020,6 @@ class CausalLMLayer(nn.Module):
             new_kv = {"k": k_cache, "v": v_cache}
             o = _block_decode(q, k_cache, v_cache, cache_len, cfg.gen_block_length,
                               scale)
-        elif cache is not None and t == 1 and page_table is not None:
-            # paged decode: append at the page-mapped row, attend by page index
-            from ..ops.paged_attention import (gather_kv_dense,
-                                               paged_attention,
-                                               paged_cache_update)
-            cap = int(kv_cap if kv_cap is not None
-                      else page_table.shape[1] * cache["k"].shape[2])
-            with scope("attn.heads"):
-                k_hm = kv_rows(k, r)             # (b, hk / r, 1, r * d)
-                v_hm = kv_rows(v, r)
-            with scope("kv.append"):
-                k_pages, v_pages = paged_cache_update(
-                    cache["k"], cache["v"], k_hm, v_hm, page_table, cache_len)
-            new_kv = {"k": k_pages, "v": v_pages}
-            lens1 = cache_len + 1
-            if slopes is not None:
-                with scope("kv.gather"):
-                    kd, vd = gather_kv_dense(k_pages, v_pages, page_table, cap)
-                with scope("attn.core"):
-                    o = decode_attention_xla_alibi(q[:, 0], kd, vd, lens1,
-                                                   slopes, scale)[:, None]
-            else:
-                with scope("attn.core"):
-                    o = paged_attention(q[:, 0], k_pages, v_pages, page_table,
-                                        lens1, cap, scale)[:, None]
         elif cache is not None and t == 1:
             # decode: append to cache (head-major), fused decode kernel
             with scope("attn.heads"):
@@ -1129,8 +1098,7 @@ class MixerLayer(CausalLMLayer):
     @nn.compact
     def __call__(self, x, positions, cache: Optional[Dict] = None,
                  cache_len: Optional[jnp.ndarray] = None,
-                 prefix_fill: bool = False, page_table=None,
-                 kv_cap: Optional[int] = None, seq_lens=None,
+                 prefix_fill: bool = False, seq_lens=None,
                  block_step: bool = False, attn_mask=None):
         cfg = self.config
         entry = LAYER_KINDS[self.kind]
@@ -1138,8 +1106,7 @@ class MixerLayer(CausalLMLayer):
             h = _norm(cfg, "norm")(x).astype(cfg.dtype)
         if self.kind == "*":
             out, new = self._attention(h, positions, cache, cache_len,
-                                       prefix_fill, page_table, kv_cap,
-                                       block_step, attn_mask)
+                                       prefix_fill, block_step, attn_mask)
         else:
             if entry.keeps == "state" and prefix_fill:
                 raise NotImplementedError(
@@ -1405,8 +1372,8 @@ class CausalLM(nn.Module):
 
     @nn.compact
     def __call__(self, input_ids, positions=None, caches=None, cache_lens=None,
-                 logits_positions=None, prefix_fill=False, page_table=None,
-                 kv_cap=None, seq_lens=None, block_step=False, attn_mask=None):
+                 logits_positions=None, prefix_fill=False, seq_lens=None,
+                 block_step=False, attn_mask=None):
         """``seq_lens`` (b,): the real lengths of right-padded rows of a
         prefill, for layers whose state a padded token would advance, and of
         a block step, whose padding the expert layers leave out.
@@ -1451,8 +1418,7 @@ class CausalLM(nn.Module):
                 extra.update(block_step=block_step, attn_mask=attn_mask)
             x, new_kv = make_layer(cfg, i, name=f"layers_{i}")(
                 x, positions, cache=layer_cache, cache_len=cache_lens,
-                prefix_fill=prefix_fill, page_table=page_table,
-                kv_cap=kv_cap, **extra)
+                prefix_fill=prefix_fill, **extra)
             new_caches.append(new_kv)
 
         with scope("head"):

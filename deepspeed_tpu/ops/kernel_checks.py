@@ -185,27 +185,6 @@ def check_moe_grouped_ffn(tokens: int = 32) -> float:
     return _err(got, want)
 
 
-def _check_paged(hk: int) -> float:
-    """Serving geometry: d_head 128, 16-token pages (``ServingConfig``'s
-    default), ragged lengths incl. one mid-page and one exactly on a page
-    edge; ``hk`` < 32 is the GQA case (several query heads per KV head)."""
-    import jax
-    import jax.numpy as jnp
-    from .paged_attention import paged_attention_fused, paged_attention_xla
-    rng = np.random.RandomState(4)
-    b, h, d, page, max_pages = 4, 32, 128, 16, 40
-    n_pages = b * max_pages + 1                        # + the null page 0
-    q = jnp.asarray(rng.standard_normal((b, h, d)), jnp.bfloat16)
-    kp = jnp.asarray(rng.standard_normal((n_pages, hk, page, d)), jnp.bfloat16)
-    vp = jnp.asarray(rng.standard_normal((n_pages, hk, page, d)), jnp.bfloat16)
-    table = jnp.asarray(rng.permutation(np.arange(1, n_pages)).reshape(
-        b, max_pages), jnp.int32)
-    lens = jnp.asarray([1, 37, 512, 640], jnp.int32)
-    o1 = jax.jit(paged_attention_fused)(q, kp, vp, table, lens)
-    return _err(o1, paged_attention_xla(q, kp, vp, table, lens,
-                                        cap=page * max_pages))
-
-
 def _check_qmm(bits: int, m: int) -> float:
     """Fused dequant GEMM at a 7B projection's shape (k = n = 4096, group
     128): ``m`` = 8 is the decode regime (one row block), 512 the m-blocked
@@ -246,8 +225,6 @@ KERNEL_CHECKS: Dict[str, Tuple] = {
     # PR 27), so the tolerance is a bf16 step of an output near 4, not a model
     "moe_grouped_ffn_decode": (check_moe_grouped_ffn, 0.03),
     "moe_grouped_ffn_prefill": (partial(check_moe_grouped_ffn, tokens=512), 0.03),
-    "paged_mha": (partial(_check_paged, hk=32), 0.03),   # bf16
-    "paged_gqa": (partial(_check_paged, hk=8), 0.03),    # bf16
     # bf16 activations x dequantized weights, f32 accumulate; relative to
     # the output scale sqrt(k): the kernel rounds w to bf16 before the dot,
     # the reference keeps it f32

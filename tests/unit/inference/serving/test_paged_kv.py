@@ -1,6 +1,7 @@
 """Paged KV memory tests: page allocator (alloc/free/exhaustion/refusal),
 refcount lifecycle + copy-on-write boundary page, the page layout's three
-mappings against a numpy reference, paged-attention kernel-vs-XLA parity,
+mappings against a numpy reference, the gathered view against the contiguous
+cache through the decode math, one decode chunk for every pool,
 hit/miss/retry/drain/migration bit-exactness, the page-bind chaos seam
 (``when=restore`` extended to the bind path), the slab serialization API
 roundtrip, and the front-door ``--kv-page-size`` validation.
@@ -29,8 +30,6 @@ from deepspeed_tpu.inference.serving import (ChaosEvent, ChaosSchedule,
                                              ServingConfig)
 from deepspeed_tpu.models.causal_lm import gpt2_cfg
 from deepspeed_tpu.ops.paged_attention import (gather_kv_dense,
-                                               paged_attention_fused,
-                                               paged_attention_xla,
                                                pages_to_dense,
                                                write_dense_pages,
                                                write_view_rows)
@@ -399,85 +398,65 @@ def test_the_pools_phase_says_how_many_heads_a_row_holds(n_head, r):
         np.testing.assert_array_equal(h.result(), _ref(eng, p, 7))
 
 
-# ------------------------------------------------------- kernel-vs-XLA parity
-def test_paged_attention_kernel_vs_xla():
-    """The Pallas gather-by-page-index kernel (interpret mode on CPU — the
-    DS_TPU_PAGED_FORCE_FUSED=1 routing) against the XLA dense-gather ground
-    truth, and the ground truth against the slot-row kernel's own XLA
-    reference over the equivalent dense cache."""
+# ------------------------------------------------------- the one decode route
+def test_the_gathered_view_decodes_as_the_contiguous_cache():
+    """The view ``gather_kv_dense`` returns, through ``decode_attention_xla``,
+    is what the contiguous cache of the same rows gives, bit for bit: tables
+    in any page order, a slot that ends inside a page, one padded with the
+    null page, and a cap that is not a page multiple."""
     rng = np.random.default_rng(0)
     P, hk, ps, d, b, g, cap = 9, 2, 8, 16, 3, 2, 20
-    mp = 3
     k_pages = jnp.asarray(rng.standard_normal((P, hk, ps, d)), jnp.float32)
     v_pages = jnp.asarray(rng.standard_normal((P, hk, ps, d)), jnp.float32)
-    table = jnp.asarray([[1, 2, 3], [4, 5, 0], [6, 7, 8]], jnp.int32)
+    tables = np.asarray([[3, 1, 2], [4, 5, 0], [8, 6, 7]], np.int32)
     lens = jnp.asarray([20, 13, 17], jnp.int32)
     q = jnp.asarray(rng.standard_normal((b, hk * g, d)), jnp.float32)
 
-    ref = paged_attention_xla(q, k_pages, v_pages, table, lens, cap)
-    kd, vd = gather_kv_dense(k_pages, v_pages, table, cap)
-    dense = decode_attention_xla(q, kd, vd, lens)
-    np.testing.assert_array_equal(np.asarray(ref), np.asarray(dense))
-
-    fused = paged_attention_fused(q, k_pages, v_pages, table, lens)
-    np.testing.assert_allclose(np.asarray(fused), np.asarray(ref),
-                               rtol=2e-5, atol=2e-5)
-
-
-def test_force_fused_env_routes_kernel(monkeypatch):
-    from deepspeed_tpu.ops import paged_attention as pa
-    monkeypatch.delenv(pa.FORCE_FUSED_ENV, raising=False)
-    assert not pa.fused_paged_active()            # CPU default: XLA path
-    monkeypatch.setenv(pa.FORCE_FUSED_ENV, "1")
-    assert pa.fused_paged_active()                # tests route interpret mode
+    kd, vd = gather_kv_dense(k_pages, v_pages, jnp.asarray(tables), cap)
+    assert kd.shape == vd.shape == (b, hk, cap, d)
+    # the contiguous cache the same tokens would have filled, built in numpy
+    contiguous = [np.stack([np.concatenate([np.asarray(pages)[t] for t in row],
+                                           axis=1)[:, :cap]
+                            for row in tables])
+                  for pages in (k_pages, v_pages)]
+    np.testing.assert_array_equal(np.asarray(kd), contiguous[0])
+    np.testing.assert_array_equal(np.asarray(vd), contiguous[1])
+    np.testing.assert_array_equal(
+        np.asarray(decode_attention_xla(q, kd, vd, lens)),
+        np.asarray(decode_attention_xla(q, *map(jnp.asarray, contiguous), lens)))
 
 
-def test_fused_chunk_path_runs_and_matches(engine, monkeypatch):
-    """DS_TPU_PAGED_FORCE_FUSED=1 routes the whole serving chunk through the
-    per-step paged-attention kernel (interpret mode on CPU) — the fused
-    compile key is distinct, the chunk runs, and a SHORT greedy decode
-    matches the XLA path (few steps on purpose: the online-softmax kernel
-    differs in the last ulp, and a long free run could compound one
-    near-tie argmax flip into a diverged suffix — single-step numerics are
-    pinned by the kernel parity test above)."""
-    from deepspeed_tpu.ops import paged_attention as pa
-    rng = np.random.default_rng(43)
-    p = rng.integers(0, 96, size=6).astype(np.int32)
-    out = {}
-    for fused in (False, True):
-        if fused:
-            monkeypatch.setenv(pa.FORCE_FUSED_ENV, "1")
-        else:
-            monkeypatch.delenv(pa.FORCE_FUSED_ENV, raising=False)
-        sched = _sched(engine)
-        h = sched.submit(p, max_new_tokens=3)
-        sched.run()
-        assert h.state.value == "finished"
-        out[fused] = h.result()
-    keys = [k for k in engine._fns if k[0] == "serve_chunk_paged"]
-    assert any(k[-1] is True for k in keys) and any(k[-1] is False
-                                                   for k in keys)
-    np.testing.assert_array_equal(out[False], out[True])
+def test_the_model_has_no_mode_that_takes_pages(engine):
+    """The model steps on dense caches only: a page table is not an argument
+    it has (the mode is gone, not merely unreachable)."""
+    with pytest.raises(TypeError, match="page_table"):
+        engine.module.apply({"params": engine.params},
+                            jnp.zeros((2, 1), jnp.int32),
+                            page_table=jnp.zeros((2, 6), jnp.int32))
 
 
-@pytest.mark.parametrize("pages,fused", [(None, False), (9, True)])
-def test_on_the_chip_the_kernel_is_taken_only_by_an_oversubscribed_pool(
-        engine, monkeypatch, pages, fused):
-    """Where the kernel is active without being forced (a TPU backend; here
-    ``fused_paged_active`` is patched), the chunk takes it only if the dense
-    view would need more rows than the pool holds: measured on the chip, the
-    dense view is the faster route at every page size (PERF.md, PR 27)."""
-    from deepspeed_tpu.ops import paged_attention as pa
-    monkeypatch.delenv(pa.FORCE_FUSED_ENV, raising=False)
-    monkeypatch.setattr(pa, "fused_paged_active", lambda: True)
-    over = {} if pages is None else dict(kv_total_pages=pages)
-    sched = _sched(engine, **over)
-    ex = sched.executor
-    assert (ex.slots * ex.cap > (ex.pool.total_pages - 1) * ex.pool.page_size) is fused
+@pytest.mark.parametrize("pages", [None, 9])
+def test_every_pool_decodes_through_the_one_chunk(engine, monkeypatch, pages):
+    """A pool that holds a row for every slot's whole cap and an
+    OVERSUBSCRIBED one (fewer pages than ``slots x cap`` rows) finish their
+    requests token for token with ``engine.generate``, through ONE compiled
+    chunk whose key names the pool's shape and nothing about a route."""
+    rng = np.random.default_rng(47)
+    prompts = [rng.integers(0, 96, size=n).astype(np.int32) for n in (6, 13, 9)]
+    refs = [_ref(engine, p, 7) for p in prompts]
     monkeypatch.setattr(engine, "_fns", {})
-    ex._chunk_fn()
-    (key,) = engine._fns
-    assert key[-1] is fused
+    sched = _sched(engine, kv_total_pages=pages)
+    ex = sched.executor
+    assert (ex.slots * ex.cap > (ex.pool.total_pages - 1) * ex.pool.page_size) \
+        is (pages is not None)
+    hs = [sched.submit(p, max_new_tokens=7) for p in prompts]
+    sched.run()
+    for h, ref in zip(hs, refs):
+        assert h.state.value == "finished"
+        np.testing.assert_array_equal(h.result(), ref)
+    (key,) = [k for k in engine._fns if k[0] == "serve_chunk_paged"]
+    assert key == ("serve_chunk_paged", ex.slots, ex.pool.total_pages,
+                   ex.pool.page_size, ex.cap, ex.chunk_size, ex.sampling)
 
 
 # --------------------------------------------------- end-to-end bit-exactness

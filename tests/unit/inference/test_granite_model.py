@@ -93,7 +93,7 @@ def test_a_model_that_drops_a_multiplier_fails_the_comparison(tiny, dropped):
 
 
 def _attention_only(**over):
-    """Attention in every published layer: the one model all five cache modes
+    """Attention in every published layer: the one model all four cache modes
     can run (a Mamba layer refuses a prefill at an offset)."""
     model = {**gt.MODEL, "layer_types": ["attention"] * 4, **over}
     cfg = gt.config(max_seq_len=512, **{k: model[k] for k in ("layer_types", *over)})
@@ -103,7 +103,6 @@ def _attention_only(**over):
 def _paths(cfg, module, params, ids):
     """The logits at the last 8 positions of ``ids`` (1, t), by the path the
     test names, and the positions they are at."""
-    from deepspeed_tpu.inference.serving.kv_pool import PagedKVPool
     from deepspeed_tpu.models.causal_lm import init_cache
     t = ids.shape[1]
     v = {"params": params}
@@ -111,23 +110,13 @@ def _paths(cfg, module, params, ids):
     def whole():
         return module.apply(v, jnp.asarray(ids))[0, -8:]
 
-    def cached(paged):
-        # prefill all but the last 8, then one token a step: the dense cache
-        # (``decode_attention``) or the pool's pages (``paged_attention``)
+    def cached():
+        # prefill all but the last 8, then one token a step on the dense
+        # cache (``decode_attention``)
         lens = jnp.asarray([t - 8])
         logits, caches = _prefill(module)(v, jnp.asarray(ids[:, :t - 8]),
                                           init_cache(cfg, 1, 64), lens)
-        rows, table = [], None
-        if paged:
-            pool = PagedKVPool(cfg, 1, 64, page_size=8)
-            assert pool.acquire(tokens=64) == 0
-            pool.scatter_prefill(0, caches)
-            caches, table = pool.caches, jnp.asarray(pool.page_table)
-            step = jax.jit(lambda v, tok, c, n: module.apply(
-                v, tok, positions=n[:, None], caches=c, cache_lens=n,
-                page_table=table, kv_cap=64))
-        else:
-            step = _decode(module)
+        rows, step = [], _decode(module)
         for i in range(t - 8, t):
             logits, caches = step(v, jnp.asarray(ids[:, i:i + 1]), caches, lens)
             rows.append(logits[0, 0])
@@ -145,17 +134,15 @@ def _paths(cfg, module, params, ids):
             logits_positions=jnp.arange(8)[None])
         return logits[0]
 
-    return {"xla_prefill": whole, "flash_prefill": whole,
-            "decode": lambda: cached(False), "paged_decode": lambda: cached(True),
+    return {"xla_prefill": whole, "flash_prefill": whole, "decode": cached,
             "prefill_at_an_offset": at_an_offset}
 
 
 @pytest.mark.parametrize("path,t", [("xla_prefill", 24), ("flash_prefill", 256),
-                                    ("decode", 24), ("paged_decode", 24),
-                                    ("prefill_at_an_offset", 24)])
+                                    ("decode", 24), ("prefill_at_an_offset", 24)])
 def test_the_attention_multiplier_reaches_every_attention_path(path, t):
     """The configuration's ONE scale (``CausalLMConfig.attn_scale``) is what
-    the XLA products, the flash kernel, the decode kernel, the paged decode
+    the XLA products, the flash kernel, the decode kernel
     and the prefill at an offset multiply the scores by: each path agrees with
     the reference at the published kind of value, and a model left at ``1 /
     sqrt(head size)`` is told apart on that very path."""

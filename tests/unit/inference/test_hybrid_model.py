@@ -108,7 +108,7 @@ def _served_logits(cfg, module, params, ids, prompt_len):
     from deepspeed_tpu.inference.serving.kv_pool import PagedKVPool
     from deepspeed_tpu.models.causal_lm import init_cache
     from deepspeed_tpu.ops.paged_attention import (gather_kv_dense,
-                                                   paged_cache_update)
+                                                   write_view_rows)
     cap, slots, slot = 64, 3, 1
     pool = PagedKVPool(cfg, slots, cap, page_size=8)
     for _ in range(slot + 1):
@@ -132,23 +132,20 @@ def _served_logits(cfg, module, params, ids, prompt_len):
         # on (on the CPU a jax array made from a numpy array can alias it)
         toks_d, lens_d = jnp.asarray(toks), jnp.asarray(lens)
         table = jnp.asarray(pool.page_table)
-        # the XLA route of the chunk: attention over the dense view
+        # the chunk's route: attention over the dense view
         caches = [dict(zip(("k", "v"), gather_kv_dense(
             c["k"], c["v"], table, cap))) if "k" in c else c
             for c in pool.caches]
         logits, new = decode({"params": params}, toks_d, caches, lens_d)
-        # mirror the appended row back, as the chunk does
-        out = []
-        for c, n in zip(pool.caches, new):
-            if "k" not in c:
-                out.append(n)
-                continue
-            idx = lens_d[:, None, None, None]
-            k_new = jnp.take_along_axis(n["k"], idx, axis=2)
-            v_new = jnp.take_along_axis(n["v"], idx, axis=2)
-            kp, vp = paged_cache_update(c["k"], c["v"], k_new, v_new,
-                                        table, lens_d)
-            out.append({"k": kp, "v": vp})
+        # mirror the appended row back, as the chunk does: the slot's one
+        # new row of the view into its page, a state layer's carry as it is
+        paged = [j for j, c in enumerate(pool.caches) if "k" in c]
+        written = write_view_rows(
+            [pool.caches[j] for j in paged], [new[j] for j in paged], table,
+            lens_d, jnp.asarray(np.arange(slots) == slot, jnp.int32), 1, cap)
+        out = list(new)
+        for j, pages in zip(paged, written):
+            out[j] = pages
         pool.caches = out
         rows.append(logits[slot, 0])
         lens = lens + (np.arange(slots) == slot).astype(np.int32)   # a NEW array

@@ -76,6 +76,14 @@ def _granite_attention_layer():
         attention_multiplier=0.015625, logits_scaling=8, dtype=jnp.bfloat16)
 
 
+def _neox_layers():
+    """``chip_smoke.py``'s rotary model (``NEOX_4L``), 2 of its 4 layers: 32
+    key heads of 128 with ONE query row each."""
+    from deepspeed_tpu.models.causal_lm import gptneox_cfg
+    return gptneox_cfg(vocab_size=50432, max_seq_len=576, n_embd=4096,
+                       n_layer=2, n_head=32, dtype=jnp.bfloat16)
+
+
 def _abstract_model(cfg, slots, cap, pages, page, sds):
     """A module with its parameters and its pool's caches as shapes on the
     described chip (the pool's rows as ``heads_per_row`` lays them out)."""
@@ -106,16 +114,22 @@ def _pool_relayouts(text, kv_shape):
     (_bloom_layers, 2, 576, 73, 0),               # bloom-7b1's cells, 2 of 30 layers
     (_hybrid_attention_layer, 32, 2048, 4097, 1),  # the hybrid's one attention layer
     (_granite_attention_layer, 64, 2048, 8193, 0),  # Granite's, two d 64 heads a row
-], ids=["bloom-7b1", "nemotron-h-attention", "granite-attention"])
+    (_neox_layers, 4, 576, 145, 2),               # chip_smoke's rotary model, a full pool
+    (_neox_layers, 4, 576, 73, 2),                # and an oversubscribed one: the same chunk
+], ids=["bloom-7b1", "nemotron-h-attention", "granite-attention", "neox-mha",
+        "neox-mha-oversubscribed"])
 def test_the_decode_chunk_holds_no_loop_but_its_own(
         one_chip, make_cfg, slots, cap, pages, kernels, monkeypatch):
     """The dense-view decode chunk at the cells' shapes: a step appends its
     K/V rows without a loop over the slots (a scatter the TPU compiler
     expands into a serial ``while`` of trip count = slots, twice a layer a
     step), so the chunk's own ``while`` is the only one; BLOOM's chunk holds
-    no Mosaic kernel, the hybrid's attention layer its ``decode_attention``.
-    The chunk's rows go back into the pages as slab writes: no scatter, and
-    no copy of a pages-shaped array (``write_view_rows``)."""
+    no Mosaic kernel, the hybrid's attention layer its ``decode_attention``,
+    a rotary model of 128-wide heads one a layer, whether or not its pool
+    holds a row for every slot's whole cap (the regime a gather-by-page-index
+    kernel had until PR 47). The chunk's rows go back into the pages as slab
+    writes: no scatter, and no copy of a pages-shaped array
+    (``write_view_rows``)."""
     from deepspeed_tpu.inference.decode_fns import (build_paged_decode_chunk,
                                                     make_slot_select_fn)
     from deepspeed_tpu.ops.attention import decode
