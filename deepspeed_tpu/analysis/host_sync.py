@@ -65,7 +65,9 @@ HOT_PATH_SPECS: Tuple[HotPathSpec, ...] = (
                 ("build_prefill", "build_prefix_prefill",
                  "build_decode_loop", "build_paged_decode_chunk")),
     HotPathSpec("deepspeed_tpu/inference/serving/executor.py",
-                ("ChunkedDecodeExecutor._chunk_fn",
+                ("_packed_chunk", "_packed_block_chunk", "_prefill",
+                 "_suffix_prefill",
+                 "ChunkedDecodeExecutor._chunk_fn",
                  "ChunkedDecodeExecutor._prefill_fn",
                  "ChunkedDecodeExecutor._suffix_prefill_fn_paged",
                  "ChunkedDecodeExecutor.prefill_into_slot",

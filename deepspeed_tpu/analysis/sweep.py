@@ -64,7 +64,7 @@ def serving_lane(report: Report) -> None:
     one-compile-per-(slots, pages, page, cap, chunk, sampling)-key property
     across a MIXED-LENGTH workload — page-count growth must ride the page
     table (runtime data), never mint a new compile key; donation on the
-    chunk, the suffix prefill and the pool's movers; the dequant-hoist
+    chunk, both prefills and the pool's movers; the dequant-hoist
     loop-invariance pin on BOTH decode bodies (while-loop generate and
     scan-lowered chunk, int8 engine); and the trace-time host-sync guard."""
     import jax
@@ -124,18 +124,23 @@ def serving_lane(report: Report) -> None:
     workload()                # mixed lengths again: zero new compiles allowed
     report.add(lint.findings())
 
-    # donation: the real chunk fn, the suffix prefill (prefix-cache hit path)
-    # and the pool's donated movers
+    # donation: the real chunk fn, the two prefills (a miss's, whose batch-1
+    # cache is written over the last one's, and the prefix-cache hit's, which
+    # is handed the pool) and the pool's donated movers
     S, mp = ex.slots, ex.pool.max_pages
     chunk_args = (engine.params, jnp.zeros((S, CTL_COLS + mp), jnp.int32),
                   ex.pool.caches, ex._base_key)
     report.add(donation_findings(ex._chunk_fn(), chunk_args,
                                  target="serve_chunk"))
-    sfn = ex._suffix_prefill_fn_paged(8)
     sargs = (engine.params, ex.pool.caches, jnp.zeros((1, 8), jnp.int32),
              jnp.asarray([4, 4, 0] + [0] * mp, jnp.int32), ex._base_key)
-    report.add(donation_findings(sfn, sargs, target="serve_suffix_prefill"))
+    report.add(donation_findings(ex._suffix_prefill_fn_paged(8), sargs,
+                                 target="serve_suffix_prefill"))
     one = init_cache(cfg, 1, _CAP, dtype=engine.dtype)
+    report.add(donation_findings(
+        ex._prefill_fn(8), (engine.params, one, jnp.zeros((1, 8), jnp.int32),
+                            jnp.asarray([4, 0], jnp.int32), ex._base_key),
+        target="serve_prefill"))
     report.add(donation_findings(ex.pool._scatter_fn,
                                  (ex.pool.caches, one,
                                   jnp.zeros((mp,), jnp.int32), 0),

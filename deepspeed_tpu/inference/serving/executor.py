@@ -34,6 +34,7 @@ from typing import Optional, Tuple
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax.sharding import NamedSharding, PartitionSpec
 
 from ...models.causal_lm import init_cache
 from ...observability import profiler as obs_profiler
@@ -112,6 +113,30 @@ def _packed_chunk(chunk):
         return packed, caches
 
     return decode_chunk
+
+
+def _prefill(prefill_logits, select, cfg, cap: int, dtype):
+    """The cache-miss prefill's program
+    (:meth:`ChunkedDecodeExecutor._prefill_fn`) over
+    ``decode_fns.build_prefill``'s function: the stand-alone prefill on a
+    batch-1 cache of its own, the forward ``engine.generate``'s is to the bit.
+    ``one`` is read by nothing: it is the last miss's batch-1 cache, donated,
+    and this one's is written over its buffers, so that the program's 60-80
+    results are allocated by no one. ``ctl`` is ``(len, seed)``."""
+
+    def prefill(params, one, ids, ctl, base_key):
+        del one
+        with scope("chunk.pack"):
+            caches = init_cache(cfg, 1, cap, dtype=dtype)
+            seed = ctl[1:2]
+            lens0 = ctl[0:1]
+        logits, new_caches, *stats = prefill_logits(params, ids, caches, lens0)
+        tok0 = select(logits, base_key, seed, jnp.zeros_like(seed))
+        # the first token, and the expert layers' two counts behind it
+        with scope("chunk.pack"):
+            return jnp.concatenate([tok0[0], *stats]), new_caches
+
+    return prefill
 
 
 def _suffix_prefill(prefix_prefill, select, cap: int):
@@ -281,6 +306,7 @@ class ChunkedDecodeExecutor:
                 "block is committed whole and never straddles a page")
         self.last_prefill_moe = None    # the last prefill's counts (or None)
         self.pool = self._build_pool()
+        self._one = None                # the miss prefill's batch-1 cache
         self._slot_select = make_slot_select_fn(*self.sampling)
         self._base_key = jax.random.PRNGKey(base_seed)
         self.chunk_deadline_s = chunk_deadline_s
@@ -382,24 +408,24 @@ class ChunkedDecodeExecutor:
                                            overlap=getattr(engine,
                                                            "comm_overlap", None),
                                            with_stats=self.with_stats)
-            select = self._slot_select
-            cfg = engine.model_config
-            cap, dtype = self.cap, engine.dtype
-
-            def prefill(params, ids, ctl, base_key):
-                with scope("chunk.pack"):
-                    caches = init_cache(cfg, 1, cap, dtype=dtype)
-                    seed = ctl[1:2]
-                    lens0 = ctl[0:1]
-                logits, new_caches, *stats = prefill_logits(params, ids, caches,
-                                                            lens0)
-                tok0 = select(logits, base_key, seed, jnp.zeros_like(seed))
-                # the first token, and the expert layers' two counts behind it
-                with scope("chunk.pack"):
-                    return jnp.concatenate([tok0[0], *stats]), new_caches
-
-            fns[key] = jax.jit(prefill)
+            prefill = _prefill(prefill_logits, self._slot_select,
+                               engine.model_config, self.cap, engine.dtype)
+            fns[key] = jax.jit(prefill, donate_argnums=(1,), keep_unused=True)
         return fns[key]
+
+    def _one_cache(self):
+        """The batch-1 cache a miss's prefill writes its results over: handed
+        to it donated, handed back as its result, read by the pool's scatter.
+        A prefill that allocated its 60-80 result arrays anew held the device
+        idle 3-4 ms under its dispatch (PERF.md section 6, PR 50). Committed
+        to the engine's mesh as a program's result is: an uncommitted operand
+        would be another signature to jit."""
+        if self._one is None:
+            engine = self.engine
+            self._one = jax.device_put(
+                init_cache(engine.model_config, 1, self.cap, dtype=engine.dtype),
+                NamedSharding(engine.mesh_spec.mesh, PartitionSpec()))
+        return self._one
 
     def _suffix_prefill_fn_paged(self, bucket: int):
         """Cache-hit prefill: the slot's pages (shared prefix pages bound
@@ -484,6 +510,7 @@ class ChunkedDecodeExecutor:
         phase. ``parent`` places the ring span when the caller is the
         watchdog's worker thread."""
         tracer = get_tracer()
+        self.pool.programs += 1
         with tracer.span("serving.dispatch", parent=parent, program=program):
             if fn in self._called:
                 return fn(*args)
@@ -495,6 +522,10 @@ class ChunkedDecodeExecutor:
                           prefix_len: int = 0, prefix_slab=None,
                           request_id: int = -1) -> Tuple[int, float]:
         """Prefill ``prompt`` (1-D int tokens) and scatter its KV into ``slot``.
+
+        A miss is the stand-alone prefill (its batch-1 cache written over the
+        last miss's, donated: nothing is allocated under the dispatch) and,
+        after the first token's stamp, the pool's scatter of that cache.
 
         With ``prefix_len > 0`` (prefix-cache hit) the slot's first
         ``prefix_len`` rows are already there — shared pages bound at
@@ -565,13 +596,23 @@ class ChunkedDecodeExecutor:
                     ctl[PRE_COLS:] = self.pool.page_table[slot]
                     args = (self.engine.params, self.pool.caches,
                             *jax.device_put((ids, ctl)), self._base_key)
-                out, caches = self._dispatch(fn, args, "suffix_prefill",
-                                             bucket)
-                self.pool.caches = caches
-                with tracer.span("serving.fetch", program="suffix_prefill",
-                                 arrays=1):
-                    # lint: host-sync-ok (honest TTFT: first token synced on purpose)
-                    tok0 = int(np.asarray(out)[0])
+                handed = self.pool.caches
+                try:
+                    out, caches = self._dispatch(fn, args, "suffix_prefill",
+                                                 bucket)
+                    self.pool.caches = caches
+                    with tracer.span("serving.fetch", program="suffix_prefill",
+                                     arrays=1):
+                        # lint: host-sync-ok (honest TTFT: first token synced on purpose)
+                        tok0 = int(np.asarray(out)[0])
+                except BaseException:
+                    # the pool was the program's from its dispatch on: what
+                    # it was handed is bound again, so that the buffers say
+                    # what became of them (``PagedKVPool.consumed``: deleted
+                    # once the dispatch took them, whatever failed after, a
+                    # device error that surfaces at the fetch included)
+                    self.pool.caches = handed
+                    raise
             obs_profiler.tick("prefill")
             return tok0, sp.t1
         bucket = self.bucket_for(t)
@@ -584,13 +625,17 @@ class ChunkedDecodeExecutor:
                              arrays=2):
                 ctl = np.empty(2, np.int32)
                 ctl[:] = t, seed
-                args = (self.engine.params, *jax.device_put((ids, ctl)),
-                        self._base_key)
+                args = (self.engine.params, self._one_cache(),
+                        *jax.device_put((ids, ctl)), self._base_key)
+            # the batch-1 cache is the program's from here (donated): after a
+            # failure it is built anew, the pool was not touched
+            self._one = None
             out, one_caches = self._dispatch(fn, args, "prefill", bucket)
             with tracer.span("serving.fetch", program="prefill", arrays=1):
                 # lint: host-sync-ok (honest TTFT: first token synced on
                 # purpose; the expert counts ride in the same array)
                 out = np.asarray(out)
+            self._one = one_caches
             tok0 = int(out[0])
             self.last_prefill_moe = None
             if self.with_stats:

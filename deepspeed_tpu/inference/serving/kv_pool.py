@@ -143,6 +143,7 @@ class PagedKVPool:
         dtype = dtype or cfg.dtype
         self.heads_per_row = r = heads_per_row(cfg.head_dim, cfg.kv_heads)
         shape = (P, cfg.kv_heads // r, ps, r * cfg.head_dim)
+        self.programs = 0     # compiled programs dispatched for this pool
         # two kinds of state in one manager: pages for the layers that keep
         # keys and values, a per-slot array for the layers with a recurrent
         # state (bound to the slot, not to pages: it does not grow with the
@@ -227,8 +228,7 @@ class PagedKVPool:
             if cow:                                    # boundary page: COW
                 src = int(prefix_pages[shared_full])
                 dst = self._free_pages.pop(0)
-                self.caches = self._cow_fn(self.caches, np.int32(src),
-                                           np.int32(dst))
+                self._move(self._cow_fn, np.int32(src), np.int32(dst))
                 self.cow_copies_total += 1
                 self._ref[dst] = 1
                 row[n] = dst
@@ -264,7 +264,7 @@ class PagedKVPool:
             # cleared here (and written whole again at the next admission)
             with get_tracer().span("serving.clear_state", slot=int(slot),
                                    state_bytes=self.state_nbytes // self.slots):
-                self.caches = _state_zero_jit()(self.caches, np.int32(slot))
+                self._move(_state_zero_jit(), np.int32(slot))
 
     def _decref(self, page: int) -> None:
         if page == NULL_PAGE:
@@ -305,14 +305,29 @@ class PagedKVPool:
     def table_row(self, slot: int) -> np.ndarray:
         return self.page_table[slot]
 
+    def _move(self, mover, *args) -> None:
+        """Dispatch one of the compiled movers on the arrays (donated to it)
+        and bind what it hands back."""
+        self.programs += 1
+        self.caches = mover(self.caches, *args)
+
+    @property
+    def consumed(self) -> bool:
+        """A program that was handed the arrays (donated) took them along and
+        failed: nothing can read or write this pool again, the owner rebuilds
+        it. The buffers say so themselves: what a dispatch was handed stays
+        bound until it has handed its result back, and is bound again by an
+        owner whose fetch of that result failed. False after a failure that
+        came before the dispatch took anything."""
+        return any(a.is_deleted() for a in jax.tree_util.tree_leaves(self.caches))
+
     # ------------------------------------------------------ prefill scatter-in
     def scatter_prefill(self, slot: int, one_caches: List[Dict[str, Any]]) \
             -> None:
         """Write a prefill's dense batch-1 per-layer cache into the slot's
         pages (and the slot's per-slot state, where a layer keeps one)."""
-        self.caches = self._scatter_fn(self.caches, one_caches,
-                                       jnp.asarray(self.page_table[slot]),
-                                       np.int32(slot))
+        self._move(self._scatter_fn, one_caches,
+                   jnp.asarray(self.page_table[slot]), np.int32(slot))
 
     # --------------------------------------------------------- slab I/O (wire)
     def gather_prefix(self, slot: int, rows: int) -> List[Dict[str, Any]]:
@@ -354,8 +369,8 @@ class PagedKVPool:
         if n > int(self._slot_npages[slot]):
             raise ValueError(f"slot {slot} holds {self._slot_npages[slot]} "
                              f"pages, slab needs {n}")
-        self.caches = _paged_restore_jit(R)(
-            self.caches, slab, jnp.asarray(self.page_table[slot, :n]))
+        self._move(_paged_restore_jit(R), slab,
+                   jnp.asarray(self.page_table[slot, :n]))
 
     def promote_prefix(self, slot: int, slab: List[Dict[str, Any]],
                        matched: int) -> None:
@@ -390,8 +405,8 @@ class PagedKVPool:
                     k, v = np.pad(k, pad), np.pad(v, pad)
                 fixed.append({"k": k, "v": v})
             slab = fixed
-        self.caches = _paged_restore_jit(R)(
-            self.caches, slab, jnp.asarray(self.page_table[slot, :n]))
+        self._move(_paged_restore_jit(R), slab,
+                   jnp.asarray(self.page_table[slot, :n]))
 
     # ------------------------------------------------------------------ metrics
     @property

@@ -591,6 +591,7 @@ class ContinuousBatchingScheduler:
         cfg = self.config
         tracer = self._tracer
         pool = self.executor.pool
+        programs = pool.programs
         matched, entry = 0, None
         if self.prefix_cache is not None:
             with tracer.span("serving.prefix_lookup") as lk:
@@ -622,10 +623,15 @@ class ContinuousBatchingScheduler:
             return self.executor.prefill_into_slot(
                 slot, handle.prompt, handle.seed, request_id=handle.id)
 
+        def no_retry_on_a_consumed_pool(_, exc):
+            if pool.consumed:       # a second try would run on deleted buffers
+                raise exc
+
         try:
             tok0, first_token_at = retry_with_backoff(
                 attempt, retries=cfg.transient_retries,
-                base_delay=cfg.retry_base_delay)
+                base_delay=cfg.retry_base_delay,
+                on_retry=no_retry_on_a_consumed_pool)
         except Exception as e:
             span.set(outcome="error")
             # retry budget exhausted: fail THIS request and (for a
@@ -636,27 +642,27 @@ class ContinuousBatchingScheduler:
                          f"{handle.id}: {type(e).__name__}: {e}")
             now = time.monotonic()
             self._finalize(handle, RequestState.CANCELLED, "error", now)
-            if entry is not None:
-                # cache-hit path: the suffix-prefill dispatch DONATES the
-                # pool caches (unlike the miss path's batch-1 prefill), so
-                # a failure here may have consumed them — zero-filling the
-                # slot or restoring into the old binding would crash the
-                # loop on deleted buffers. Same recovery as a failed
-                # decode chunk: fail the in-flight requests, rebuild the
-                # pool, keep serving (a router retries them elsewhere).
-                logger.error("[serving] failed prefill was a prefix-cache "
-                             "hit (donated pool dispatch); failing "
+            if pool.consumed:
+                # a program of the admission had the pool DONATED to it and
+                # took it along (a hit's suffix prefill, a copy or a restore
+                # before it, a miss's scatter): same recovery as a failed
+                # decode chunk — fail the in-flight requests, rebuild the
+                # pool, keep serving (a router retries them elsewhere)
+                logger.error("[serving] the failed admission consumed the KV "
+                             "pool; failing "
                              f"{sum(h is not None for h in self._slot_req)}"
-                             " in-flight request(s) and rebuilding the "
-                             "KV pool")
+                             " in-flight request(s) and rebuilding it")
                 self._fail_in_flight(now)
                 self._rebuild_pool()
-            else:
+            else:           # nothing had taken the pool yet: it stands
                 self._release(slot)
             if not isinstance(e, TRANSIENT_FAULTS):
                 raise
             return False
-        span.set(outcome="ok")
+        # the compiled programs it dispatched: a miss's prefill and scatter;
+        # a hit's suffix prefill and, before it, a boundary page's copy or a
+        # promoted slab's restore
+        span.set(outcome="ok", programs=pool.programs - programs)
         handle.state = RequestState.RUNNING
         handle.slot = slot
         if tok0 is not None:

@@ -222,8 +222,10 @@ SPANS: Dict[str, Tuple[str, str, Tuple[str, ...], str]] = {
                       "breakdown idle gaps (deadline and cancellation sweeps)"),
     "serving.admit": (BOTH, "serve scheduler",
                       ("request_id", "queue_wait_ms", "prompt_tokens",
-                       "prefix_len", "slot", "outcome"),
-                      "sched_admit_host_ms; attribution phase admission"),
+                       "prefix_len", "slot", "outcome", "programs"),
+                      "sched_admit_host_ms; attribution phase admission "
+                      "(programs: the compiled programs the admission "
+                      "dispatched, 2 on a miss: prefill and scatter)"),
     "serving.prefix_lookup": (BOTH, "serve scheduler",
                               ("hit", "matched_tokens"),
                               "sched_admit_host_ms lines; attribution phase "

@@ -443,12 +443,14 @@ def test_sweep_lane_runs_clean_on_real_programs(lane_name, eight_devices):
                 "host_sync_trace"} <= names
         donation = {r.target: r.checked for r in report.results
                     if r.name == "donation"}
-        # the chunk and the suffix prefill the cells run, and the pool's
-        # donated movers
-        assert set(donation) == {"serve_chunk", "serve_suffix_prefill",
-                                 "kv_pool.scatter", "kv_pool.cow",
-                                 "kv_pool.state_zero_fill"}
-        assert sum(donation.values()) >= 8 and min(donation.values()) >= 1
+        # the chunk and the two prefills the cells run (a miss's, handed the
+        # last miss's batch-1 cache, and a prefix hit's, handed the pool), and
+        # the pool's donated movers
+        assert set(donation) == {"serve_chunk", "serve_prefill",
+                                 "serve_suffix_prefill", "kv_pool.scatter",
+                                 "kv_pool.cow", "kv_pool.state_zero_fill"}
+        assert sum(donation.values()) >= 12 and min(donation.values()) >= 1
+        assert donation["serve_prefill"] == donation["serve_chunk"]
     elif lane_name == "train_lane":
         assert {"retrace", "donation"} <= names
         don = next(r for r in report.results if r.name == "donation")

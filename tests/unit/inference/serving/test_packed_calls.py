@@ -7,6 +7,12 @@ and hands back what the host reads as ONE int32 array.
   signatures, and placed and fetched array by array as ``run_chunk`` did);
 - the ``arrays`` attribute of ``serving.place_inputs`` / ``serving.fetch``
   counts the crossings: 1 and 1 for a chunk, 2 and 1 for a prefill;
+- a miss's prefill allocates no result (PR 50): it is handed the last miss's
+  batch-1 cache, donated, and writes its own over it, every leaf aliased; the
+  scatter follows the first token's stamp; ``serving.admit`` counts
+  ``programs``; a slot that held a longer request serves the next as
+  ``engine.generate`` does; a failed admission rebuilds the pool only if its
+  buffers are gone, whether the failure shows at the dispatch or at the fetch;
 - the lowered programs keep what the benchmark's readers find them by: their
   names, and the padded prompt as ``@main``'s first int32 argument of rank 2.
 """
@@ -25,9 +31,14 @@ from deepspeed_tpu.inference.serving import executor as ex_mod
 from deepspeed_tpu.inference.serving.executor import ChunkResult
 from deepspeed_tpu.inference.serving.prefix_cache import PrefixCacheConfig
 from deepspeed_tpu.models.causal_lm import gpt2_cfg
+from deepspeed_tpu.analysis.donation import assert_all_donated
+from deepspeed_tpu.inference.serving.scheduler import RequestState
 from deepspeed_tpu.observability.trace import get_tracer
+from deepspeed_tpu.utils import fault_injection as fi
 from benchmarks.chipbench.probe import first_int_arg_shape
+from tests.unit import granite_tiny as gt
 from tests.unit import hybrid_tiny as ht
+from tests.unit import lfm2_tiny as lt
 
 pytestmark = pytest.mark.serving
 
@@ -195,7 +206,8 @@ def test_the_lowered_programs_keep_their_names_and_the_prompt_leads(engines,
     table = ex.pool.max_pages
     params, key = ex.engine.params, ex._base_key
     cases = {
-        "prefill": (ex._prefill_fn(16), (params, ids, vec(2), key)),
+        "prefill": (ex._prefill_fn(16),
+                    (params, ex._one_cache(), ids, vec(2), key)),
         "decode_chunk": (
             ex._chunk_fn(),
             (params, jax.ShapeDtypeStruct((SLOTS, ex_mod.CTL_COLS + table),
@@ -213,3 +225,161 @@ def test_the_lowered_programs_keep_their_names_and_the_prompt_leads(engines,
             assert shape == f"{SLOTS}x{ex_mod.CTL_COLS + table}"
         else:
             assert shape == "1x16", (name, shape)
+
+
+def _under(ring, span):
+    """The spans of ``ring`` below ``span``, at any depth."""
+    ids, out = {span["span_id"]}, []
+    for s in sorted(ring, key=lambda s: s["ts"]):
+        if s["parent_id"] in ids:
+            ids.add(s["span_id"])
+            out.append(s)
+    return out
+
+
+@pytest.mark.parametrize("experts", [False, True], ids=["dense", "experts"])
+def test_a_miss_prefill_writes_its_cache_over_the_last_ones_and_allocates_none(
+        engines, experts):
+    tracer = get_tracer().enable()
+    sched = _scheduler(engines[experts], False)
+    ex = sched.executor
+    first = ex._one_cache()
+    _serve(sched, engines[experts].model_config.vocab_size)
+    ring = list(tracer.spans)
+    admits = [s for s in ring if s["name"] == "serving.admit"]
+    assert [a["attrs"]["programs"] for a in admits] == [2, 2, 2, 2]
+    for a in admits:
+        below = _under(ring, a)
+        assert [s["attrs"]["program"] for s in below
+                if s["name"] == "serving.dispatch"] == ["prefill"]
+        # the scatter is a program of the pool's own, after the stamp
+        prefill, scatter = (next(s for s in below if s["name"] == name)
+                            for name in ("serving.prefill", "serving.scatter_prefill"))
+        assert scatter["ts"] >= prefill["ts"] + prefill["dur"]
+    # the batch-1 cache is operand 1, donated, and every leaf of it (keys and
+    # values, and the hybrid's per-slot state) is aliased to a result: the
+    # first one's buffers went into the admissions, one stands after them
+    leaves = jax.tree_util.tree_leaves(ex._one_cache())
+    assert all(a.is_deleted() for a in jax.tree_util.tree_leaves(first))
+    assert not any(a.is_deleted() for a in leaves)
+    args = (ex.engine.params, ex._one_cache(), jnp.zeros((1, 16), jnp.int32),
+            jnp.zeros((2,), jnp.int32), ex._base_key)
+    audit = assert_all_donated(ex._prefill_fn(16), args, target="serve_prefill")
+    assert audit.checked == len(leaves) == (6 if experts else 4)
+    assert audit.findings == []
+
+
+def _engine_of(kind):
+    conf = ds.inference.DeepSpeedInferenceConfig(dtype="float32",
+                                                 max_out_tokens=CAP)
+    cfg = {"kv": lambda: gpt2_cfg(**DENSE), "granite": gt.config,
+           "lfm2": lt.config}[kind]()
+    return InferenceEngine(cfg, conf, seed=3)
+
+
+@pytest.mark.parametrize("kind", ["kv", "granite", "lfm2"])
+def test_a_slot_that_held_a_longer_request_serves_the_next_as_generate_does(kind):
+    """A slot whose pages held another request's rows (here one that filled
+    every page of the pool to the cap) and whose batch-1 cache held that
+    request's too: the next request reads none of them, its state is written
+    whole."""
+    engine = _engine_of(kind)
+    rng = np.random.default_rng(9)
+    vocab = engine.model_config.vocab_size
+    long, short = (rng.integers(1, vocab, size=n).astype(np.int32)
+                   for n in (40, 5))
+    sched = ContinuousBatchingScheduler(engine, ServingConfig(
+        slots=1, chunk_size=CHUNK, max_seq_len=CAP, kv_page_size=8,
+        prefix_cache=PrefixCacheConfig(enabled=False)))
+    first = sched.submit(long, max_new_tokens=CAP - long.size)
+    sched.run()
+    assert len(first.tokens) == CAP - long.size and sched.executor.pool.free_pages == 8
+    dirty = [float(jnp.abs(c["k"][1:]).min(axis=(1, 2, 3)).max())
+             for c in sched.executor.pool.caches if "k" in c]
+    assert dirty and min(dirty) > 0          # every page but the null one was written
+    second = sched.submit(short, max_new_tokens=12)
+    sched.run()
+    for prompt, h in ((long, first), (short, second)):
+        alone = engine.generate(prompt[None], max_new_tokens=len(h.tokens))
+        assert list(h.tokens) == [int(t) for t in alone[0, prompt.size:]]
+
+
+class _Poisoned:
+    """A result whose fetch fails: how a device error of an asynchronous
+    dispatch reaches the host."""
+
+    def __array__(self, *args, **kwargs):
+        raise OSError("the device failed; the fetch says so")
+
+
+@pytest.mark.parametrize("when", ["before-dispatch", "in-dispatch", "in-fetch"])
+@pytest.mark.parametrize("hit", [False, True], ids=["miss", "hit"])
+def test_a_failed_admission_rebuilds_the_pool_only_if_its_buffers_are_gone(
+        engines, hit, when, monkeypatch):
+    """One rule for a hit and a miss, read off the buffers the dispatch was
+    handed: a ``serving.prefill`` fault fires before any dispatch and a miss's
+    prefill is never handed the pool, so the other stream, the pool and the
+    cached prefix stay; a hit's suffix prefill that dies after the donation,
+    at its dispatch or only at the fetch of its result, took the pool's
+    buffers with it: no second try runs on them, the in-flight requests fail,
+    the pool is rebuilt and the scheduler keeps serving."""
+    fi.reset_faults()
+    engine = engines[False]
+    consumed = hit and when != "before-dispatch"
+    sched = ContinuousBatchingScheduler(engine, ServingConfig(
+        slots=SLOTS, chunk_size=CHUNK, max_seq_len=CAP, kv_page_size=8,
+        transient_retries=0 if when == "before-dispatch" else 1,
+        retry_base_delay=0.001, prefix_cache=PrefixCacheConfig(enabled=hit)))
+    ex = sched.executor
+    shared = np.arange(1, 17, dtype=np.int32)
+    done = sched.submit(np.concatenate([shared, [40, 41]]).astype(np.int32),
+                        max_new_tokens=3)
+    sched.run()                                   # warms, and fills the cache
+    running = sched.submit(np.arange(50, 59, dtype=np.int32), max_new_tokens=12)
+    sched.step()
+    assert running.state == RequestState.RUNNING and done.state == RequestState.FINISHED
+    pool = ex.pool
+    victim_prompt = np.concatenate([shared, [60, 61, 62]]).astype(np.int32)
+    if when == "before-dispatch":
+        with fi.inject("serving.prefill", fi.FaultSpec(kind="io_error",
+                                                       max_faults=1)):
+            victim = sched.submit(victim_prompt, max_new_tokens=4)
+            sched.step()
+        assert fi.faults_fired("serving.prefill") == 1
+    else:
+        dispatch, tries = ex._dispatch, []
+
+        def dies(fn, args, program, *rest):
+            out = dispatch(fn, args, program, *rest)
+            if not program.endswith("prefill"):
+                return out
+            tries.append(program)
+            if when == "in-dispatch":
+                raise OSError("the dispatch died with its operands in it")
+            return (_Poisoned(),) + tuple(out[1:])
+
+        monkeypatch.setattr(ex, "_dispatch", dies)
+        victim = sched.submit(victim_prompt, max_new_tokens=4)
+        sched.step()
+        monkeypatch.undo()
+        # a pool that went with the first try is not handed to a second
+        assert len(tries) == (1 if consumed else 2)
+    assert victim.state == RequestState.CANCELLED and victim.finish_reason == "error"
+    assert pool.consumed is consumed
+    assert (ex.pool is pool) is not consumed
+    if consumed:
+        assert running.state == RequestState.CANCELLED
+        assert ex.pool.free_slots == SLOTS
+    else:
+        assert ex.pool.free_slots == SLOTS - 1
+        sched.run()
+        alone = engine.generate(running.prompt[None], max_new_tokens=12)
+        assert list(running.tokens) == [int(t) for t in alone[0, running.prompt.size:]]
+    again = sched.submit(victim_prompt, max_new_tokens=4)
+    sched.run()
+    assert again.state == RequestState.FINISHED
+    # the cached prefix lived in the old pool's pages: it went with them
+    assert again.prefix_hit_tokens == (16 if hit and not consumed else 0)
+    alone = engine.generate(victim_prompt[None], max_new_tokens=4)
+    assert list(again.tokens) == [int(t) for t in alone[0, victim_prompt.size:]]
+    fi.reset_faults()

@@ -243,7 +243,7 @@ TABLE_B = {
     "serving.step": ("step", "queue_depth", "active_slots"),
     "serving.sweep": (),
     "serving.admit": ("request_id", "queue_wait_ms", "prompt_tokens", "prefix_len",
-                      "slot", "outcome"),
+                      "slot", "outcome", "programs"),
     "serving.prefix_lookup": ("hit", "matched_tokens"),
     "serving.page_table": ("op", "pages_fresh", "pages_shared", "cow"),
     "serving.prefill": ("request_id", "bucket", "tokens", "prefix_len"),
@@ -309,6 +309,11 @@ class TestServeStepInATrace:
         hit, miss = by_id[served["a"].id], by_id[served["b"].id]
         assert hit[3]["prefix_len"] == 19 and miss[3]["prefix_len"] == 0
         assert hit[3]["outcome"] == miss[3]["outcome"] == "ok"
+        # a miss dispatches its prefill and, after the stamp, the scatter; this
+        # hit's match ends inside a page, so a page is copied before its prefill
+        assert (miss[3]["programs"], hit[3]["programs"]) == (2, 2)
+        for admit in (hit, miss):
+            assert len(_under(served["events"], admit, "serving.dispatch")) == 1
         (suffix,) = _under(served["events"], hit, "serving.suffix_prefill")
         (whole,) = _under(served["events"], miss, "serving.prefill")
         assert (suffix[3]["bucket"], suffix[3]["tokens"], suffix[3]["prefix_len"]) == (8, 3, 19)

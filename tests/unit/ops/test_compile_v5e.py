@@ -53,10 +53,10 @@ def _bloom_layers():
                      dtype=jnp.bfloat16)
 
 
-def _hybrid_attention_layer():
+def _hybrid_attention_layer(pattern="*"):
     from deepspeed_tpu.models.causal_lm import nemotron_h_cfg
     return nemotron_h_cfg(
-        hidden_size=4096, hybrid_override_pattern="*", vocab_size=131072,
+        hidden_size=4096, hybrid_override_pattern=pattern, vocab_size=131072,
         num_attention_heads=32, num_key_value_heads=2, head_dim=128,
         mamba_num_heads=128, mamba_head_dim=64, ssm_state_size=128, n_groups=8,
         conv_kernel=4, chunk_size=128, n_routed_experts=512,
@@ -65,10 +65,11 @@ def _hybrid_attention_layer():
         routed_scaling_factor=5, dtype=jnp.bfloat16)
 
 
-def _granite_attention_layer():
+def _granite_attention_layer(layer_types=("attention",)):
     from deepspeed_tpu.models.causal_lm import granite_hybrid_cfg
     return granite_hybrid_cfg(
-        hidden_size=2048, num_hidden_layers=1, layer_types=["attention"],
+        hidden_size=2048, num_hidden_layers=len(layer_types),
+        layer_types=list(layer_types),
         vocab_size=100352, num_attention_heads=32, num_key_value_heads=8,
         shared_intermediate_size=8192, mamba_n_heads=64, mamba_d_head=64,
         mamba_d_state=128, mamba_n_groups=1, mamba_d_conv=4, mamba_chunk_size=256,
@@ -180,6 +181,45 @@ def test_the_suffix_prefill_writes_its_rows_without_relaying_the_pool(
         params, caches, sds((1, bucket)), sds((PRE_COLS + cap // page,)),
         sds((2,), jnp.uint32)).compile().as_text()
     assert _pool_relayouts(text, kv_shape) == []
+
+
+@pytest.mark.parametrize("make_cfg,slots,cap,pages,bucket", [
+    (_bloom_layers, 2, 576, 73, 64),        # chat's largest bucket, 2 of 30 layers
+    (_bloom_layers, 2, 576, 73, 512),       # docqa's miss: the document's bucket
+    (functools.partial(_granite_attention_layer, ("mamba", "attention")),
+     64, 2048, 8193, 1024),                 # Granite: a Mamba and an attention layer
+    (functools.partial(_hybrid_attention_layer, "M*"), 32, 2048, 4097, 256),
+], ids=["bloom-7b1-64", "bloom-7b1-512", "granite-1024", "nemotron-h-256"])
+def test_the_miss_prefill_writes_its_results_over_the_cache_it_is_handed(
+        one_chip, make_cfg, slots, cap, pages, bucket, monkeypatch):
+    """The cache-miss prefill at the cells' shapes: the stand-alone prefill,
+    handed the last miss's batch-1 cache donated, aliases every leaf of it to
+    a result (keys and values of ``cap`` rows, a Mamba layer's window and
+    state), so that the program allocates none of its 60-80 result arrays: on
+    the chip their allocation held the device idle 3-4 ms an admission."""
+    from deepspeed_tpu.analysis.donation import _alias_param_positions, _flat_args_info
+    from deepspeed_tpu.inference.decode_fns import build_prefill, make_slot_select_fn
+    from deepspeed_tpu.inference.serving.executor import _prefill
+    from deepspeed_tpu.models.causal_lm import init_cache
+    from deepspeed_tpu.ops.attention import decode
+    monkeypatch.setattr(decode, "_interpret", lambda: False)
+    cfg, page = make_cfg(), 16
+
+    def sds(shape, dtype=jnp.int32):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    module, params, _, _ = _abstract_model(cfg, slots, cap, pages, page, sds)
+    one = jax.tree_util.tree_map(
+        lambda x: sds(x.shape, x.dtype),
+        jax.eval_shape(lambda: init_cache(cfg, 1, cap, dtype=cfg.dtype)))
+    fn = _prefill(build_prefill(module, lambda p: p),
+                  make_slot_select_fn(False, 1.0, 0, 1.0), cfg, cap, cfg.dtype)
+    lowered = jax.jit(fn, donate_argnums=(1,), keep_unused=True).lower(
+        params, one, sds((1, bucket)), sds((2,)), sds((2,), jnp.uint32))
+    text = lowered.compile().as_text()
+    donated = [i for i, (_, info) in enumerate(_flat_args_info(lowered)) if info.donated]
+    assert len(donated) == len(jax.tree_util.tree_leaves(one)) > 0
+    assert len(_alias_param_positions(text)) == len(donated)
 
 
 @pytest.mark.parametrize("tokens", [128, 512])
