@@ -47,6 +47,7 @@ from typing import Deque, List, Optional
 import numpy as np
 
 from ...observability.trace import CAT_SERVING, get_tracer
+from ...ops.attention.decode import live_rows
 from ...utils.fault_injection import fault_point, retry_with_backoff
 from ...utils.logging import logger
 from ..decode_fns import open_block
@@ -755,6 +756,12 @@ class ContinuousBatchingScheduler:
         at_start = dict(chunk=chunk_idx, active_slots=len(streams),
                         request_ids=" ".join(str(h.id) for _, h in streams),
                         slot_steps_run=slot_steps)
+        if not spec and not self.block:
+            # the cache rows a step of this chunk's attention walks at most:
+            # the batch's longest length at the chunk's end, in whole blocks
+            # (``decode_attention_live``; over the cap, the share it reads)
+            at_start["attn_rows"] = live_rows(int(self._lens.max()) + width,
+                                              self.cap)
         with (tracer.span("serving.spec_verify", **at_start) if spec
               else tracer.span("serving.decode_chunk", **at_start)) as span:
             try:
@@ -905,6 +912,7 @@ class ContinuousBatchingScheduler:
     def _release(self, slot: int) -> None:
         self._slot_req[slot] = None
         self._active[slot] = False
+        self._lens[slot] = 0      # a free slot's length sets no step's trip count
         self._remaining[slot] = 0
         self._steps[slot] = 0
         self._eos[slot] = -1

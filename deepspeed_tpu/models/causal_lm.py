@@ -24,7 +24,7 @@ import numpy as np
 from jax.sharding import PartitionSpec as P
 
 from ..observability import scope
-from ..ops.attention.decode import (decode_attention, decode_attention_xla,
+from ..ops.attention.decode import (decode_attention, decode_attention_live,
                                     pack_queries, unpack_outputs)
 from ..ops.paged_attention import heads_per_row, kv_rows
 from ..ops.transformer.attention import xla_attention
@@ -1240,7 +1240,7 @@ def _prefix_attention_xla(q, k_cache, v_cache, offset, slopes, scale):
     """Suffix-prefill attention: queries at global positions ``offset + i``
     over the full KV cache (restored prefix rows + just-written suffix rows),
     masked ``key_pos <= query_pos`` — the t×T generalisation of
-    ``decode_attention_xla_alibi``'s 1×T shape. fp32 softmax like every other
+    ``decode_attention_xla``'s 1×T shape. fp32 softmax like every other
     XLA attention path here; rows beyond ``offset + t - 1`` (stale slab pad /
     unwritten) are masked out by construction.
 
@@ -1308,14 +1308,23 @@ def _cache_update(cache, new, cache_len):
 
 
 def _sharded_decode(q, k_cache, v_cache, lens, alibi=None, *, scale):
-    """Wrap the decode kernel in shard_map over batch/TP axes (pallas is opaque to SPMD).
-
-    Alibi slopes travel as a per-head input sharded over the tensor axis, so each TP shard
-    sees exactly its heads' slopes."""
+    """One-token attention over the cache, under ``shard_map`` over the batch
+    and tensor axes where a mesh has them (pallas is opaque to SPMD, and a
+    shard's own longest length is all the live-rows form need walk). ALiBi
+    takes the served XLA form with the heads' slopes, which travel as a
+    per-head input sharded over the tensor axis, so that each shard sees its
+    own heads' slopes; everything else the decode kernel's entry."""
     from ..parallel.mesh import AXIS_TENSOR, BATCH_AXES, get_global_mesh
     b, h, d = q.shape
     mesh = get_global_mesh()
 
+    def attend(q, k_cache, v_cache, lens, slopes=None):
+        if slopes is None:
+            return decode_attention(q, k_cache, v_cache, lens, scale)
+        return decode_attention_live(q, k_cache, v_cache, lens, scale, slopes)
+
+    operands = (q, k_cache, v_cache, lens) + (
+        () if alibi is None else (jnp.asarray(alibi),))
     if mesh is not None:
         batch_axes = tuple(ax for ax in BATCH_AXES if mesh.size(ax) > 1)
         bsz = int(np.prod([mesh.size(ax) for ax in batch_axes])) if batch_axes else 1
@@ -1326,45 +1335,11 @@ def _sharded_decode(q, k_cache, v_cache, lens, alibi=None, *, scale):
             tpax = AXIS_TENSOR if use_tp else None
             qspec = P(batch_axes or None, tpax, None)
             cspec = P(batch_axes or None, tpax, None, None)
-            lspec = P(batch_axes or None)
-            if alibi is None:
-                mapped = shard_map(
-                    lambda q_l, k_l, v_l, l_l: decode_attention(q_l, k_l, v_l, l_l,
-                                                                scale),
-                    mesh=mesh.mesh, axis_names=manual,
-                    in_specs=(qspec, cspec, cspec, lspec), out_specs=qspec,
-                    check_vma=False)
-                return mapped(q, k_cache, v_cache, lens)
-            mapped = shard_map(
-                partial(decode_attention_xla_alibi, scale=scale),
-                mesh=mesh.mesh, axis_names=manual,
-                in_specs=(qspec, cspec, cspec, lspec, P(tpax)), out_specs=qspec,
-                check_vma=False)
-            return mapped(q, k_cache, v_cache, lens, jnp.asarray(alibi))
-
-    if alibi is not None:
-        return decode_attention_xla_alibi(q, k_cache, v_cache, lens,
-                                          jnp.asarray(alibi), scale)
-    return decode_attention(q, k_cache, v_cache, lens, scale)
-
-
-def decode_attention_xla_alibi(q, k_cache, v_cache, cache_len, slopes, scale):
-    """Decode attention with alibi bias (jnp path; bloom decode); the cache
-    in rows, the queries packed to them, as ``decode_attention_xla``."""
-    b, h, d = q.shape
-    hk, T = k_cache.shape[1], k_cache.shape[2]
-    r = k_cache.shape[3] // d
-    g = h // hk
-    q4 = pack_queries(q, r, hk).reshape(b, hk, g, r * d).astype(jnp.float32)
-    s = jnp.einsum("bkgd,bktd->bkgt", q4, k_cache.astype(jnp.float32)) * scale
-    pos = jnp.arange(T)[None, None, None, :]
-    cur = (cache_len[:, None, None, None] - 1).astype(jnp.float32)
-    s = s + slopes.reshape(1, hk, g, 1) * (pos - cur)
-    mask = pos < cache_len[:, None, None, None]
-    s = jnp.where(mask, s, -1e30)
-    p = jax.nn.softmax(s, axis=-1)
-    o = jnp.einsum("bkgt,bktd->bkgd", p, v_cache.astype(jnp.float32))
-    return unpack_outputs(o.reshape(b, h, r * d), r, hk).astype(q.dtype)
+            specs = (qspec, cspec, cspec, P(batch_axes or None), P(tpax))
+            return shard_map(attend, mesh=mesh.mesh, axis_names=manual,
+                             in_specs=specs[:len(operands)], out_specs=qspec,
+                             check_vma=False)(*operands)
+    return attend(*operands)
 
 
 class CausalLM(nn.Module):

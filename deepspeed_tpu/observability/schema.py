@@ -261,9 +261,10 @@ SPANS: Dict[str, Tuple[str, str, Tuple[str, ...], str]] = {
                               "prefix cache)"),
     "serving.decode_chunk": (BOTH, "compiled steps",
                              ("chunk", "active_slots", "request_ids",
-                              "slot_steps_run", "tokens_kept", "deliveries",
-                              "stalled_deliveries", "moe_assignments",
-                              "moe_experts_touched", "forwards",
+                              "slot_steps_run", "attn_rows", "tokens_kept",
+                              "deliveries", "stalled_deliveries",
+                              "moe_assignments", "moe_experts_touched",
+                              "forwards",
                               "blocks_committed", "positions_unmasked",
                               "block_length", "blocks_merged"),
                              "decode_wasted_step_pct, delivery_stalled_pct, "

@@ -177,7 +177,7 @@ def decode_attention(q: jnp.ndarray, k_cache: jnp.ndarray, v_cache: jnp.ndarray,
         # its decode chunk holds (lfm2-8b-a1b.conv32, 32Q/8KV x 64: none for attention),
         # so a d 64 model's packed rows, 128 lanes wide as Mosaic's HBM slices must be,
         # stay in XLA until that route moves (PERF.md section 7)
-        return decode_attention_xla(q, k_cache, v_cache, cache_len, softmax_scale)
+        return decode_attention_live(q, k_cache, v_cache, cache_len, softmax_scale)
     q = pack_queries(q, r, hk)
     g = h // hk                                 # a row's r heads x their group
     bk = min(block_k, T)
@@ -211,26 +211,136 @@ def decode_attention(q: jnp.ndarray, k_cache: jnp.ndarray, v_cache: jnp.ndarray,
     return unpack_outputs(out.reshape(b, h, r * d), r, hk)
 
 
-def decode_attention_xla(q, k_cache, v_cache, cache_len, softmax_scale=None):
-    """jnp reference implementation (ground truth for kernel tests; fallback path).
-
-    Same cache rows as the kernel (the module docstring has them), and the
-    same two forms of ``cache_len``."""
+def _rows_and_lens(q, k_cache, cache_len):
+    """What both XLA forms start from: the packed queries ``(b, hk, g, r * d)``
+    in float32 and every query row's visible length ``(b, g)``."""
     b, h, d = q.shape
-    hk, T = k_cache.shape[1], k_cache.shape[2]
+    hk = k_cache.shape[1]
     r = k_cache.shape[3] // d
     g = h // hk
-    scale = softmax_scale if softmax_scale is not None else 1.0 / float(np.sqrt(d))
     q4 = pack_queries(q, r, hk).reshape(b, hk, g, r * d).astype(jnp.float32)
-    k = k_cache.astype(jnp.float32)
-    v = v_cache.astype(jnp.float32)
-    s = jnp.einsum("bkgd,bktd->bkgt", q4, k) * scale
-    cache_len = _row_lens(cache_len, r)
+    cache_len = _row_lens(cache_len.astype(jnp.int32), r)
     if cache_len.ndim == 1:
         cache_len = cache_len[:, None]
-    row_len = jnp.repeat(cache_len, g // cache_len.shape[1], axis=1)   # (b, g)
-    mask = jnp.arange(T)[None, None, None, :] < row_len[:, None, :, None]
-    s = jnp.where(mask, s, NEG_INF)
-    p = jax.nn.softmax(s, axis=-1)
-    o = jnp.einsum("bkgt,bktd->bkgd", p, v)
+    return q4, jnp.repeat(cache_len, g // cache_len.shape[1], axis=1), (r, hk, g)
+
+
+def _row_bias(row_len, T: int, slopes):
+    """What is added to the float32 scores ``(b, hk, g, T)``, as ``(b, 1 or
+    hk, g, T)``: ``NEG_INF`` at the rows a query does not see (a score under it
+    is ``NEG_INF`` exactly), else 0, or with ALiBi ``slopes`` ``(h,)`` a head's
+    slope times the row's distance from the query's own position, the last row
+    it sees. Small, and the same for every layer of a step."""
+    pos = jnp.arange(T, dtype=jnp.int32)
+    seen = row_len[:, None, :, None]                                # (b, 1, g, 1)
+    bias = 0.0
+    if slopes is not None:
+        g = row_len.shape[1]
+        bias = (jnp.asarray(slopes, jnp.float32).reshape(1, -1, g, 1)
+                * (pos - (seen - 1)).astype(jnp.float32))
+    return jnp.where(pos < seen, bias, NEG_INF)
+
+
+def _seen(s):
+    """The scores that are of rows their query sees (:func:`_row_bias`)."""
+    return s > 0.5 * NEG_INF
+
+
+def live_block(T: int) -> int:
+    """The rows a block of :func:`decode_attention_live` holds, from the cap
+    alone: the largest multiple of 16 (a bf16 tile's rows) that divides ``T``
+    and is at most a sixth of it, held between 64 and 256; a cap with no such
+    divisor is one block. 96 of 576, 256 of 2048: by the chip (``PERF.md``
+    section 6, PR 51: an iteration costs ~2.5 us and its rows ~0.08 us each,
+    so small blocks win where most of the cap is empty and large ones where
+    the longest sequence fills it)."""
+    most = min(max(T // 6, 64), 256, T)
+    for rows in range(most // 16 * 16, 0, -16):
+        if T % rows == 0:
+            return rows
+    return T
+
+
+def live_rows(longest: int, T: int) -> int:
+    """The cache rows :func:`decode_attention_live` walks for a batch whose
+    longest sequence sees ``longest`` rows: whole blocks, at most the cap."""
+    block = live_block(T)
+    return min(T, -(-longest // block) * block)
+
+
+@functools.partial(jax.jit, static_argnames=("softmax_scale", "block"))
+def decode_attention_live(q, k_cache, v_cache, cache_len, softmax_scale=None,
+                          slopes=None, *, block=None):
+    """The served XLA form: one-token attention over the batch's LIVE rows.
+
+    Operands as :func:`decode_attention` (both forms of ``cache_len``, packed
+    rows), with optional ALiBi ``slopes`` ``(h,)``. The cache is walked in
+    blocks of ``B = live_block(T)`` rows, ``j = 0 .. ceil(max(cache_len) / B) -
+    1``: a block of K and V is sliced at its block-aligned row and converted
+    to float32 (the whole cap never is), scored, masked by every query's own
+    length and folded into an online softmax (running max, sum and output in
+    float32, as ``_decode_kernel`` does for its ``block_k``). The trip count
+    is the batch's: every sequence's blocks up to the longest one's.
+
+    What the serving parity leans on: a sequence's output is BIT-EQUAL
+    whatever the trip count and whatever ``T`` is, for the same ``B``. A
+    block wholly past a sequence's length gives it scores of ``NEG_INF``
+    under a running max it already has, so ``exp(m - m) = 1`` scales its sum
+    and output and exact zeros are added to them. (Not so for a whole-cap
+    softmax over a shorter static slice: its reduction's shape changes.)
+    A sequence that sees no row at all attends nothing: zeros.
+
+    Jitted, so that a program of many layers traces and lowers the walk ONCE
+    and calls it a layer (XLA inlines the calls: the compiled step is the
+    same): traced a layer, the ``while``'s body took BLOOM's chunk of 30
+    layers 6.5 s more of every set-up on the chip's host (``PERF.md`` section
+    6, PR 51). ``softmax_scale`` is therefore a Python number, not an array.
+
+    ``block`` is the tests' (one ``B`` under two caps); callers leave it."""
+    b, h, d = q.shape
+    T = k_cache.shape[2]
+    scale = softmax_scale if softmax_scale is not None else 1.0 / float(np.sqrt(d))
+    q4, row_len, (r, hk, g) = _rows_and_lens(q, k_cache, cache_len)
+    B = live_block(T) if block is None else block
+    if T % B:
+        raise AssertionError(f"blocks of {B} rows do not tile a cache of {T}")
+    bias = _row_bias(row_len, T, slopes)
+
+    def body(j, carry):
+        m, l, acc = carry
+        k_blk = jax.lax.dynamic_slice_in_dim(k_cache, j * B, B, axis=2)
+        v_blk = jax.lax.dynamic_slice_in_dim(v_cache, j * B, B, axis=2)
+        s = (jnp.einsum("bkgd,bktd->bkgt", q4, k_blk.astype(jnp.float32)) * scale
+             + jax.lax.dynamic_slice_in_dim(bias, j * B, B, axis=3))
+        m_new = jnp.maximum(m, jnp.max(s, axis=-1))
+        alpha = jnp.exp(m - m_new)
+        p = jnp.where(_seen(s), jnp.exp(s - m_new[..., None]), 0.0)
+        l_new = l * alpha + jnp.sum(p, axis=-1)
+        acc_new = acc * alpha[..., None] + jnp.einsum(
+            "bkgt,bktd->bkgd", p, v_blk.astype(jnp.float32))
+        return m_new, l_new, acc_new
+
+    blocks = jnp.minimum((jnp.max(row_len) + B - 1) // B, T // B)
+    m0 = jnp.full((b, hk, g), NEG_INF, jnp.float32)
+    l0 = jnp.zeros((b, hk, g), jnp.float32)
+    acc0 = jnp.zeros((b, hk, g, r * d), jnp.float32)
+    _, l, acc = jax.lax.fori_loop(0, blocks, body, (m0, l0, acc0))
+    o = acc / jnp.where(l > 0, l, 1.0)[..., None]
+    return unpack_outputs(o.reshape(b, h, r * d), r, hk).astype(q.dtype)
+
+
+def decode_attention_xla(q, k_cache, v_cache, cache_len, softmax_scale=None,
+                         slopes=None):
+    """The plain whole-cap form: the ground truth the kernel's and the served
+    form's tests compare against (``ops/kernel_checks.py`` on the chip), never
+    served. Same cache rows as the kernel (the module docstring has them),
+    the same two forms of ``cache_len``, optional ALiBi ``slopes`` ``(h,)``;
+    a sequence that sees no row gets zeros, as from the kernel."""
+    b, h, d = q.shape
+    scale = softmax_scale if softmax_scale is not None else 1.0 / float(np.sqrt(d))
+    q4, row_len, (r, hk, g) = _rows_and_lens(q, k_cache, cache_len)
+    s = (jnp.einsum("bkgd,bktd->bkgt", q4, k_cache.astype(jnp.float32)) * scale
+         + _row_bias(row_len, k_cache.shape[2], slopes))
+    p = jnp.where(_seen(s), jax.nn.softmax(s, axis=-1), 0.0)
+    o = jnp.einsum("bkgt,bktd->bkgd", p, v_cache.astype(jnp.float32))
     return unpack_outputs(o.reshape(b, h, r * d), r, hk).astype(q.dtype)

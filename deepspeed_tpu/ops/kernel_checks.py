@@ -125,6 +125,47 @@ def check_decode() -> float:
     return max(err, _err(rows, decode_attention_xla(q, kv_rows(k, 1), kv_rows(v, 1), lens)))
 
 
+def check_decode_live() -> float:
+    """The served XLA form (``decode_attention_live``: blocks up to the batch's
+    longest length, an online softmax) against the plain whole-cap form, as
+    the chip's compiler lowers both: at bloom-7b1's shape with ALiBi, and at
+    rows of two d 64 heads (lfm2-8b-a1b.conv32's) against a head a row, with
+    lengths on, one under and one over a block's edge. And what serving
+    parity leans on: a sequence's bits do not move with the batch's trip
+    count (its neighbour one block long, then the whole cap)."""
+    import jax.numpy as jnp
+    from ..models.causal_lm import alibi_slopes
+    from .attention.decode import (decode_attention_live, decode_attention_xla,
+                                   live_block)
+    from .paged_attention import kv_rows
+    rng = np.random.RandomState(0)
+    b, h, d, T = 2, 32, 128, 576
+    B = live_block(T)
+    q = jnp.asarray(rng.standard_normal((b, h, d)), jnp.bfloat16)
+    kc, vc = (jnp.asarray(rng.standard_normal((b, h, T, d)), jnp.bfloat16)
+              for _ in range(2))
+    slopes = jnp.asarray(alibi_slopes(h))
+    live = lambda *a: decode_attention_live(*a, None, slopes)   # jitted itself
+    err = 0.0
+    for lens in ([B - 1, B + 1], [B, 3 * B + 7], [69, T]):
+        lens = jnp.asarray(lens, jnp.int32)
+        err = max(err, _err(live(q, kc, vc, lens),
+                            decode_attention_xla(q, kc, vc, lens, None, slopes)))
+    short = live(q, kc, vc, jnp.asarray([70, 75], jnp.int32))
+    beside = live(q, kc, vc, jnp.asarray([70, T], jnp.int32))
+    if not bool(jnp.array_equal(short[0], beside[0])):
+        return float("inf")
+    b, h, hk, d, T = 32, 32, 8, 64, 2048
+    B = live_block(T)
+    q = jnp.asarray(rng.standard_normal((b, h, d)), jnp.bfloat16)
+    k, v = (jnp.asarray(rng.standard_normal((b, T, hk, d)), jnp.bfloat16)
+            for _ in range(2))
+    edges = [B - 1, B, B + 1, 2 * B - 1, 2 * B + 1, 1, T, 405]
+    lens = jnp.asarray(edges + list(rng.randint(40, 1100, size=b - len(edges))), jnp.int32)
+    rows = decode_attention_live(q, kv_rows(k, 2), kv_rows(v, 2), lens)
+    return max(err, _err(rows, decode_attention_xla(q, kv_rows(k, 1), kv_rows(v, 1), lens)))
+
+
 def check_block_sparse() -> float:
     import jax
     import jax.numpy as jnp
@@ -218,6 +259,7 @@ KERNEL_CHECKS: Dict[str, Tuple] = {
     "flash_fused_qkv": (check_flash_fused_qkv, 0.05),  # bf16 out + grad, training shape
     "flash_alibi": (check_flash_alibi, 0.05),   # bf16
     "decode": (check_decode, 0.03),             # bf16
+    "decode_live": (check_decode_live, 0.03),   # bf16; XLA's served form
     "block_sparse": (check_block_sparse, 0.03),  # bf16
     "moe_decode_ffn": (check_moe_decode_ffn, 0.03),  # bf16
     # bf16 operands, f32 accumulation and f32 out on both sides: the kernel
@@ -233,6 +275,11 @@ KERNEL_CHECKS: Dict[str, Tuple] = {
     "qmm_int4_decode": (partial(_check_qmm, bits=4, m=8), 0.02),
     "qmm_int4_prefill": (partial(_check_qmm, bits=4, m=512), 0.02),
 }
+
+
+#: checks of a served XLA form: they compile NO Mosaic kernel (``chip_smoke.py``'s
+#: gate holds every other check to at least one)
+XLA_FORMS = frozenset({"decode_live"})
 
 
 def run_kernel_checks(names: Optional[Iterable[str]] = None) -> Dict[str, float]:

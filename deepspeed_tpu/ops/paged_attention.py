@@ -22,9 +22,19 @@ lane extents from their operands, whatever ``r`` made them.
 
 No attention runs here. Every serve program attends over the dense view: the
 gathered view is element-identical to the contiguous cache ``engine.generate``
-decodes over (and sliced to exactly ``cap`` rows), so greedy decode through it
-is **bit-identical** to it — the property every serving parity test leans on —
-and the attention ops are ``ops/attention/decode.py``'s. No kernel gathers
+decodes over, and both run the SAME attention op of ``ops/attention/decode.py``
+on it, so greedy decode through it is **bit-identical** to it — the property
+every serving parity test leans on. What that rests on, by op: the Mosaic
+kernel and XLA's live-rows form (``decode_attention_live``: ALiBi, heads of
+64) both walk the rows in blocks under an online softmax, and a sequence's
+output is bit-equal whatever the number of blocks walked and whatever the
+cap, for one block size (a block past a sequence's length adds exact zeros),
+so the two sides need equal BLOCKS, which equal caps give them
+(``live_block(T)``; the view is sliced to exactly ``cap`` rows, and
+``generate``'s cache has the engine's ``max_out_tokens`` = ``cap``), not
+equal trip counts or batches; the whole-cap forms left (a hit's suffix
+prefill, the verify round: ``_prefix_attention_xla``) still rest on equal
+reduction SHAPES, ``[:cap]``. No kernel gathers
 K/V by page index inside its grid: on the chip one that did (a page a DMA)
 took 1.07-2.6x the view's time a step (``PERF.md`` section 6, PR 27; section
 7 says what one worth having needs).
@@ -70,10 +80,10 @@ def gather_kv_dense(k_pages, v_pages, page_table, cap: int):
     ``k_pages``/``v_pages``: ``(P, hk, page, d)`` (``hk`` rows of ``d`` lanes,
     here and below: :func:`heads_per_row`); ``page_table``:
     ``(b, max_pages)`` int32. Returns ``(b, hk, cap, d)`` ×2 — rows sliced to
-    EXACTLY ``cap`` so downstream attention math (reduction shapes included)
-    is identical to a contiguous ``cap``-row cache's, keeping greedy
-    bit-exact even when ``cap`` is not a page multiple (pages round it up
-    internally)."""
+    EXACTLY ``cap`` so downstream attention math (block sizes and, for the
+    whole-cap forms, reduction shapes) is identical to a contiguous
+    ``cap``-row cache's, keeping greedy bit-exact even when ``cap`` is not a
+    page multiple (pages round it up internally)."""
     kp = k_pages[page_table]                       # (b, mp, hk, page, d)
     vp = v_pages[page_table]
     b, mp, hk, ps, d = kp.shape

@@ -169,6 +169,8 @@ def test_every_attention_function_takes_the_scale_it_is_handed():
     flash-alibi kernel's reference keeps the kernel's default."""
     import inspect
     from deepspeed_tpu.models import causal_lm as clm
+    from deepspeed_tpu.ops.attention.decode import (decode_attention_live,
+                                                    decode_attention_xla)
     ks = jax.random.split(jax.random.PRNGKey(0), 3)
     q = jax.random.normal(ks[0], (1, 8, 4, 128))
     k = jax.random.normal(ks[1], (1, 8, 4, 128))
@@ -180,8 +182,10 @@ def test_every_attention_function_takes_the_scale_it_is_handed():
         "alibi": lambda q, s: clm._alibi_attention_xla(q, k, val, slopes, s),
         "prefix": lambda q, s: clm._prefix_attention_xla(
             q, hm(k), hm(val), jnp.asarray([0]), None, s),
-        "decode_alibi": lambda q, s: clm.decode_attention_xla_alibi(
-            q[:, -1], hm(k), hm(val), lens, slopes, s),
+        "decode_alibi": lambda q, s: decode_attention_live(
+            q[:, -1], hm(k), hm(val), lens, s, slopes),
+        "decode_alibi_plain": lambda q, s: decode_attention_xla(
+            q[:, -1], hm(k), hm(val), lens, s, slopes),
     }
     for name, call in calls.items():
         for s in (128 ** -0.5, 1.0 / 128):
@@ -189,8 +193,7 @@ def test_every_attention_function_takes_the_scale_it_is_handed():
         assert float(jnp.abs(call(q, 1.0 / 128) - call(q, 128 ** -0.5)).max()) > 1e-2, name
     usual = calls["alibi"](q, None)
     assert float(jnp.abs(calls["alibi"](q, 128 ** -0.5) - usual).max()) < 1e-6
-    for fn in (clm._bias_attention, clm._prefix_attention_xla, clm._sharded_decode,
-               clm.decode_attention_xla_alibi):
+    for fn in (clm._bias_attention, clm._prefix_attention_xla, clm._sharded_decode):
         assert inspect.signature(fn).parameters["scale"].default is inspect.Parameter.empty
     assert not hasattr(clm, "_softmax_scale")
 

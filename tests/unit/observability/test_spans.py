@@ -249,7 +249,8 @@ TABLE_B = {
     "serving.prefill": ("request_id", "bucket", "tokens", "prefix_len"),
     "serving.suffix_prefill": ("request_id", "bucket", "tokens", "prefix_len"),
     "serving.decode_chunk": ("chunk", "active_slots", "request_ids", "slot_steps_run",
-                             "tokens_kept", "deliveries", "stalled_deliveries"),
+                             "attn_rows", "tokens_kept", "deliveries",
+                             "stalled_deliveries"),
     "serving.place_inputs": ("program",),
     "serving.dispatch": ("program",),
     "serving.fetch": ("program",),
@@ -401,6 +402,42 @@ class TestWatchdogKeepsTheSpanOnTheCallersThread:
             == ["serving.place_inputs", "serving.dispatch", "serving.fetch"]
         assert {s["tid"] for s in kids if s["name"] != "serving.place_inputs"} \
             == {"ds-serve-chunk-watchdog"}
+
+
+class TestAttnRowsIsTheRowsAStepsAttentionWalks:
+    def test_the_chunk_span_carries_the_longest_length_in_whole_blocks(self):
+        """Two requests of known lengths: ``attn_rows`` of every
+        ``serving.decode_chunk`` span is the longest slot's length at
+        dispatch plus the chunk, rounded up to ``live_block(cap)``, at most
+        the cap; a released slot's length no longer counts."""
+        import jax.numpy as jnp
+        from deepspeed_tpu.inference.config import DeepSpeedInferenceConfig
+        from deepspeed_tpu.inference.engine import InferenceEngine
+        from deepspeed_tpu.inference.serving import (ContinuousBatchingScheduler,
+                                                     ServingConfig)
+        from deepspeed_tpu.models.causal_lm import gpt2_cfg
+        from deepspeed_tpu.ops.attention.decode import live_block
+        cap = 512
+        B = live_block(cap)
+        assert B == 64
+        engine = InferenceEngine(
+            gpt2_cfg(vocab_size=96, max_seq_len=cap, n_embd=32, n_layer=1, n_head=4,
+                     dtype=jnp.float32),
+            DeepSpeedInferenceConfig(dtype="float32", max_out_tokens=cap))
+        sched = ContinuousBatchingScheduler(engine, ServingConfig(
+            slots=2, chunk_size=CHUNK, max_seq_len=cap))
+        tracer = get_tracer().enable()
+        rng = np.random.default_rng(0)
+        long = sched.submit(rng.integers(1, 90, 250).tolist(), max_new_tokens=9)
+        short = sched.submit(rng.integers(1, 90, 30).tolist(), max_new_tokens=17)
+        sched.run()
+        assert (len(long.tokens), len(short.tokens)) == (9, 17)
+        chunks = [s["attrs"] for s in tracer.spans if s["name"] == "serving.decode_chunk"]
+        # both run two chunks (250 + 4 -> 256, 254 + 4 -> 320: over a block's
+        # edge), then the short one alone from 38 and 42 rows
+        assert [c["active_slots"] for c in chunks] == [2, 2, 1, 1]
+        assert [c["attn_rows"] for c in chunks] == [4 * B, 5 * B, B, B]
+        assert all(c["attn_rows"] <= cap for c in chunks)
 
 
 # ------------------------------------------------------------ the train step

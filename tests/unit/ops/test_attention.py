@@ -8,8 +8,9 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from deepspeed_tpu.ops.attention import (decode_attention, decode_attention_xla,
-                                         flash_attention, ring_attention)
+from deepspeed_tpu.ops.attention import (decode_attention, decode_attention_live,
+                                         decode_attention_xla, flash_attention,
+                                         ring_attention)
 from deepspeed_tpu.ops.transformer.attention import xla_attention
 from deepspeed_tpu.parallel.mesh import MeshSpec, set_global_mesh
 
@@ -466,6 +467,174 @@ def test_decode_respects_cache_len():
     vc2 = vc.at[:, :, 7:].set(-999.0)
     o2 = decode_attention(q, kc2, vc2, lens, block_k=8)
     np.testing.assert_allclose(np.asarray(o1), np.asarray(o2), rtol=1e-6)
+
+
+# ------------------------------------------- the served XLA form: live rows, in blocks
+def _live_case(rng, T, r, lens, b=None, hk=2, group=2, dtype=jnp.bfloat16):
+    """Queries and a cache of ``T`` rows of ``r`` KV heads of ``128 // r``
+    lanes: ``hk`` rows, ``group`` query heads a KV head."""
+    d = 128 // r
+    b = len(lens) if b is None else b
+    h = hk * r * group
+    q = jnp.asarray(rng.normal(size=(b, h, d)), dtype)
+    k, v = (jnp.asarray(rng.normal(size=(b, hk, T, r * d)), dtype) for _ in range(2))
+    return q, k, v, h
+
+
+@pytest.mark.parametrize("T", [576, 96, 2048])
+@pytest.mark.parametrize("r", [1, 2], ids=["d128", "d64-rows-of-two"])
+@pytest.mark.parametrize("alibi", [False, True], ids=["plain", "alibi"])
+@pytest.mark.parametrize("n_lens", [0, 2], ids=["lens-b", "lens-b2"])
+def test_live_rows_decode_attention_is_the_whole_cap_form(T, r, alibi, n_lens):
+    """``decode_attention_live`` (blocks up to the batch's longest length, an
+    online softmax) against the plain whole-cap ``decode_attention_xla``: with
+    and without ALiBi, a head a row and two, one length a sequence and two
+    runs, lengths of 0 and of ``T``, one under and one over a block's edge."""
+    from deepspeed_tpu.models.causal_lm import alibi_slopes
+    from deepspeed_tpu.ops.attention.decode import live_block
+    B = live_block(T)
+    assert T % B == 0 and (B % 16 == 0 or B == T)
+    one = [0, B - 1, min(B + 1, T), T, 1, T // 2]
+    rng = np.random.default_rng(T + r)
+    q, k, v, h = _live_case(rng, T, r, one)
+    lens = jnp.asarray(one, jnp.int32)
+    if n_lens:      # a second run that sees up to a block more (a block step's shape)
+        lens = jnp.stack([lens, jnp.minimum(lens + B // 2, T)], axis=1)
+    slopes = jnp.asarray(alibi_slopes(h)) if alibi else None
+    got = decode_attention_live(q, k, v, lens, None, slopes)
+    want = decode_attention_xla(q, k, v, lens, None, slopes)
+    assert got.shape == q.shape and got.dtype == q.dtype
+    np.testing.assert_allclose(np.asarray(got, np.float32), np.asarray(want, np.float32),
+                               rtol=2e-2, atol=2e-3)
+    if not n_lens:      # a sequence that sees no row attends nothing
+        assert not np.asarray(got, np.float32)[0].any()
+
+
+@pytest.mark.parametrize("alibi", [False, True], ids=["plain", "alibi"])
+@pytest.mark.parametrize("r", [1, 2], ids=["d128", "d64-rows-of-two"])
+def test_live_rows_form_in_float32_agrees_to_rounding(r, alibi):
+    """The same comparison where nothing rounds to bf16: 1e-5."""
+    from deepspeed_tpu.models.causal_lm import alibi_slopes
+    rng = np.random.default_rng(r)
+    lens = [3, 64, 65, 200, 576]
+    q, k, v, h = _live_case(rng, 576, r, lens, dtype=jnp.float32)
+    slopes = jnp.asarray(alibi_slopes(h)) if alibi else None
+    got = decode_attention_live(q, k, v, jnp.asarray(lens), None, slopes)
+    want = decode_attention_xla(q, k, v, jnp.asarray(lens), None, slopes)
+    np.testing.assert_allclose(np.asarray(got), np.asarray(want), rtol=1e-5, atol=1e-5)
+
+
+@pytest.mark.parametrize("alibi", [False, True], ids=["plain", "alibi"])
+@pytest.mark.parametrize("r", [1, 2], ids=["d128", "d64-rows-of-two"])
+def test_a_sequences_output_is_bit_equal_whatever_the_cap_is(r, alibi):
+    """What serving parity leans on, (i): the same sequence in a cache of 576
+    and of 2048 rows, walked in equal blocks, gives the same BITS (the rows
+    past its length differ between the two caches)."""
+    from deepspeed_tpu.models.causal_lm import alibi_slopes
+    rng = np.random.default_rng(10 + r)
+    lens = jnp.asarray([70, 129, 300], jnp.int32)
+    q, k, v, h = _live_case(rng, 576, r, lens)
+    slopes = jnp.asarray(alibi_slopes(h)) if alibi else None
+    wide = [jnp.concatenate([a, jnp.asarray(rng.normal(size=a.shape[:2] + (2048 - 576,)
+                                                         + a.shape[3:]), a.dtype)], axis=2)
+            for a in (k, v)]
+    small = decode_attention_live(q, k, v, lens, None, slopes, block=64)
+    large = decode_attention_live(q, *wide, lens, None, slopes, block=64)
+    np.testing.assert_array_equal(np.asarray(small, np.float32), np.asarray(large, np.float32))
+
+
+@pytest.mark.parametrize("alibi", [False, True], ids=["plain", "alibi"])
+@pytest.mark.parametrize("r", [1, 2], ids=["d128", "d64-rows-of-two"])
+def test_a_sequences_output_is_bit_equal_whatever_the_trip_count_is(r, alibi):
+    """(ii): a sequence alone, and beside one EIGHT blocks longer (whose
+    length sets the batch's trip count): the same bits, under ``jit`` too."""
+    from deepspeed_tpu.models.causal_lm import alibi_slopes
+    T, B = 2048, 128
+    rng = np.random.default_rng(20 + r)
+    q, k, v, h = _live_case(rng, T, r, [0, 0])
+    slopes = jnp.asarray(alibi_slopes(h)) if alibi else None
+    short = B + 5
+    fn = jax.jit(functools.partial(decode_attention_live, block=B))
+    alone = fn(q[:1], k[:1], v[:1], jnp.asarray([short]), None, slopes)
+    beside = fn(q, k, v, jnp.asarray([short, short + 8 * B]), None, slopes)
+    np.testing.assert_array_equal(np.asarray(alone, np.float32)[0],
+                                  np.asarray(beside, np.float32)[0])
+
+
+def test_live_rows_form_does_not_read_past_the_longest_length():
+    """Rows past the batch's longest length are never read: poisoned with
+    NaN they change nothing (the whole-cap form multiplies them by zero and
+    gives NaN)."""
+    rng = np.random.default_rng(30)
+    q, k, v, _ = _live_case(rng, 576, 1, [70, 90])
+    lens = jnp.asarray([70, 90], jnp.int32)
+    clean = decode_attention_live(q, k, v, lens)
+    from deepspeed_tpu.ops.attention.decode import live_block
+    edge = -(-90 // live_block(576)) * live_block(576)
+    k2, v2 = (a.at[:, :, edge:].set(jnp.nan) for a in (k, v))
+    np.testing.assert_array_equal(np.asarray(clean, np.float32),
+                                  np.asarray(decode_attention_live(q, k2, v2, lens), np.float32))
+    assert np.isnan(np.asarray(decode_attention_xla(q, k2, v2, lens), np.float32)).all()
+
+
+def test_the_served_form_never_converts_the_whole_cache():
+    """The StableHLO of the served form at BLOOM's shape: a ``while`` whose
+    body converts ONE block of K and of V to float32; no float32 tensor has
+    the cache's 576 rows of 128 lanes (the whole-cap form's first op makes
+    two), and what is float32 with 576 columns is the scores' bias alone."""
+    import re
+    from deepspeed_tpu.models.causal_lm import alibi_slopes
+    from deepspeed_tpu.ops.attention.decode import live_block
+    b, h, T, d = 2, 32, 576, 128
+    args = (jax.ShapeDtypeStruct((b, h, d), jnp.bfloat16),
+            jax.ShapeDtypeStruct((b, h, T, d), jnp.bfloat16),
+            jax.ShapeDtypeStruct((b, h, T, d), jnp.bfloat16),
+            jax.ShapeDtypeStruct((b,), jnp.int32))
+    slopes = jnp.asarray(alibi_slopes(h))
+    live = jax.jit(lambda *a: decode_attention_live(*a, None, slopes)).lower(*args).as_text()
+    whole = jax.jit(lambda *a: decode_attention_xla(*a, None, slopes)).lower(*args).as_text()
+    cache_f32 = re.compile(r"tensor<[0-9x]*576x128xf32>")
+    assert len(cache_f32.findall(whole)) >= 2 and not cache_f32.findall(live)
+    assert "stablehlo.while" in live and "stablehlo.while" not in whole
+    assert f"tensor<{b}x{h}x{live_block(T)}x{d}xf32>" in live
+    with_576 = set(re.findall(r"tensor<[0-9x]*576[0-9x]*xf32>", live))
+    assert with_576 <= {f"tensor<{b}x{h}x1x576xf32>", f"tensor<{b}x1x1x576xf32>",
+                        "tensor<576xf32>"}, with_576
+
+
+def test_an_alibi_models_decode_chunk_lowers_with_no_mosaic_kernel(monkeypatch):
+    """What the benchmark's ``check_routes`` will see of BLOOM's chunk: the
+    live-rows form is XLA's, ONE function with its ``while`` that every layer
+    calls inside the chunk's loop, and no Mosaic kernel, with the interpreter
+    off as on the chip."""
+    import re
+    from deepspeed_tpu.analysis.lowered import mosaic_calls
+    from deepspeed_tpu.inference.decode_fns import (build_paged_decode_chunk,
+                                                    make_slot_select_fn)
+    from deepspeed_tpu.models.causal_lm import CausalLM, bloom_cfg, init_cache
+    from deepspeed_tpu.ops.attention import decode
+    monkeypatch.setattr(decode, "_interpret", lambda: False)
+    cfg = bloom_cfg(n_layer=2, n_embd=64, n_head=4, vocab_size=128, dtype=jnp.bfloat16)
+    slots, cap, page, pages = 2, 96, 16, 13
+    module = CausalLM(cfg)
+    params = jax.eval_shape(lambda: module.init(
+        jax.random.PRNGKey(0), jnp.zeros((1, 8), jnp.int32))["params"])
+    caches = jax.eval_shape(lambda: init_cache(
+        cfg, slots, cap, kv_shape=(pages, cfg.kv_heads, page, cfg.head_dim)))
+    fn = build_paged_decode_chunk(module, lambda p: p,
+                                  make_slot_select_fn(False, 1.0, 0, 1.0), 4, kv_cap=cap)
+    i32 = lambda *shape: jax.ShapeDtypeStruct(shape, jnp.int32)
+    text = jax.jit(fn).lower(
+        params, i32(slots, 1), caches, i32(slots, cap // page), i32(slots),
+        jax.ShapeDtypeStruct((slots,), jnp.bool_), i32(slots), i32(slots), i32(slots),
+        i32(slots), jax.ShapeDtypeStruct((2,), jnp.uint32)).as_text()
+    assert mosaic_calls(text) == [] and "tpu_custom_call" not in text
+    # the form is a jitted function: traced and lowered ONCE for all layers
+    # (a while's body traced a layer cost BLOOM's chunk 6.5 s of set-up on
+    # the chip's host: PERF.md section 6, PR 51), called once a layer
+    assert text.count("stablehlo.while") == 2
+    assert len(re.findall(r"call @decode_attention_live\b", text)) == cfg.n_layer
+    assert len(re.findall(r"func\.func private @decode_attention_live\b", text)) == 1
 
 
 # ----------------------------------------------------------- rows of several KV heads

@@ -111,20 +111,23 @@ def _pool_relayouts(text, kv_shape):
             if shape in line and (" scatter(" in line or " copy(" in line)]
 
 
-@pytest.mark.parametrize("make_cfg,slots,cap,pages,kernels", [
-    (_bloom_layers, 2, 576, 73, 0),               # bloom-7b1's cells, 2 of 30 layers
-    (_hybrid_attention_layer, 32, 2048, 4097, 1),  # the hybrid's one attention layer
-    (_granite_attention_layer, 64, 2048, 8193, 0),  # Granite's, two d 64 heads a row
-    (_neox_layers, 4, 576, 145, 2),               # chip_smoke's rotary model, a full pool
-    (_neox_layers, 4, 576, 73, 2),                # and an oversubscribed one: the same chunk
+@pytest.mark.parametrize("make_cfg,slots,cap,pages,kernels,live", [
+    (_bloom_layers, 2, 576, 73, 0, 2),            # bloom-7b1's cells, 2 of 30 layers
+    (_hybrid_attention_layer, 32, 2048, 4097, 1, 0),  # the hybrid's one attention layer
+    (_granite_attention_layer, 64, 2048, 8193, 0, 1),  # Granite's, two d 64 heads a row
+    (_neox_layers, 4, 576, 145, 2, 0),            # chip_smoke's rotary model, a full pool
+    (_neox_layers, 4, 576, 73, 2, 0),             # and an oversubscribed one: the same chunk
 ], ids=["bloom-7b1", "nemotron-h-attention", "granite-attention", "neox-mha",
         "neox-mha-oversubscribed"])
 def test_the_decode_chunk_holds_no_loop_but_its_own(
-        one_chip, make_cfg, slots, cap, pages, kernels, monkeypatch):
+        one_chip, make_cfg, slots, cap, pages, kernels, live, monkeypatch):
     """The dense-view decode chunk at the cells' shapes: a step appends its
     K/V rows without a loop over the slots (a scatter the TPU compiler
     expands into a serial ``while`` of trip count = slots, twice a layer a
-    step), so the chunk's own ``while`` is the only one; BLOOM's chunk holds
+    step), so the chunk's own ``while`` is the only one but for the walk over
+    live blocks of XLA's decode attention, one a layer where a layer takes it
+    (ALiBi, heads of 64: ``decode_attention_live``), which converts a block of
+    the view to float32 and never the view; BLOOM's chunk holds
     no Mosaic kernel, the hybrid's attention layer its ``decode_attention``,
     a rotary model of 128-wide heads one a layer, whether or not its pool
     holds a row for every slot's whole cap (the regime a gather-by-page-index
@@ -149,8 +152,13 @@ def test_the_decode_chunk_holds_no_loop_but_its_own(
         sds((slots,), jnp.bool_), sds((slots,)), sds((slots,)), sds((slots,)),
         sds((slots,)), sds((2,), jnp.uint32)).compile().as_text()
     loops = [line for line in text.splitlines() if " while(" in line]
-    assert len(loops) == 1, [line.split(" = ")[0].strip() for line in loops]
-    assert 'op_name="jit(decode_chunk)/while"' in loops[0]
+    walks = [line for line in loops
+             if "ds.attn.core/jit(decode_attention_live)/while" in line]
+    assert len(loops) == 1 + live == 1 + len(walks), [
+        line.split(" = ")[0].strip() for line in loops]
+    assert sum('op_name="jit(decode_chunk)/while"' in line for line in loops) == 1
+    rows = cfg.head_dim * (128 // cfg.head_dim if cfg.head_dim < 128 else 1)
+    assert f"f32[{slots},{cfg.kv_heads * cfg.head_dim // rows},{cap},{rows}]" not in text
     assert text.count("tpu_custom_call") == kernels
     assert not kernels or "decode_attention" in text
     assert " scatter(" not in text and _pool_relayouts(text, kv_shape) == []
