@@ -98,7 +98,8 @@ class CausalLMConfig:
     # what an "E" layer's experts are: "latent" (experts in a latent space
     # beside a shared expert, behind the sigmoid router with a selection
     # bias: ``moe/latent_moe.py``) or "gated" (SwiGLU experts of the full
-    # width, no shared expert, behind the router ``moe_router`` names:
+    # width behind the router ``moe_router`` names, beside a gated shared
+    # expert of ``moe_shared_width`` where that is not 0:
     # ``moe/gated_moe.py``)
     moe_kind: str = "latent"
     # the GATED mixture's router: "softmax" (probabilities over all experts,
@@ -318,7 +319,8 @@ def _experts_params(cfg: CausalLMConfig) -> int:
     d, n, held = cfg.n_embd, cfg.n_routed_experts, cfg.held_experts[1]
     if cfg.moe_kind == "gated":
         return (d * n + (n if cfg.moe_router == "sigmoid_bias" else 0)
-                + held * 3 * d * cfg.moe_expert_width)
+                + held * 3 * d * cfg.moe_expert_width
+                + 3 * d * cfg.moe_shared_width)
     return (d * n + n + 2 * d * cfg.moe_latent_size + 2 * d * cfg.moe_shared_width
             + held * 2 * cfg.moe_latent_size * cfg.moe_expert_width)
 
@@ -545,27 +547,41 @@ def granite_hybrid_cfg(*, hidden_size, num_hidden_layers, layer_types, vocab_siz
                        tie_word_embeddings=True, intermediate_size=None,
                        num_experts_per_tok=0, rope_theta=None, rope_scaling=None,
                        max_position_embeddings=None,
-                       model_type="granitemoehybrid", **kw) -> CausalLMConfig:
-    """Granite 4.0 hybrids without experts (``model_type: granitemoehybrid``,
-    ``num_local_experts`` 0): the keywords are the published config's. A
-    published layer is a mixer and then a SwiGLU feed-forward of width
-    ``shared_intermediate_size``, each ``x + residual_multiplier *
+                       model_type="granitemoehybrid", experts_held=None,
+                       **kw) -> CausalLMConfig:
+    """Granite 4.0 hybrids (``model_type: granitemoehybrid``), with experts
+    and without: the keywords are the published config's. A published layer
+    is a mixer and then a feed-forward, each ``x + residual_multiplier *
     f(rmsnorm(x))``: here a pair of mixer layers, "M" (Mamba-2: ``mamba_n_heads``
     heads of ``mamba_d_head``, ``mamba_n_groups`` groups of B and C, a
     convolution of ``mamba_d_conv`` taps with its bias) or "*" (grouped keys
     and values, no position encoding, no bias, scores scaled by
-    ``attention_multiplier``) as ``layer_types`` says, then "F", so
-    ``n_layer`` is twice ``num_hidden_layers``. The embedding's rows are
+    ``attention_multiplier``) as ``layer_types`` says, and then, with
+    ``num_local_experts`` 0, "F" (a SwiGLU of width
+    ``shared_intermediate_size``), else "E": a softmax router over
+    ``num_local_experts`` SwiGLU experts of width ``intermediate_size``, the
+    top ``num_experts_per_tok`` renormalised, BESIDE a shared SwiGLU of width
+    ``shared_intermediate_size`` under the same norm, their sum under one
+    ``residual_multiplier`` (``moe/gated_moe.py``; ``experts_held = (first,
+    count)`` is the share of the experts this program holds, None = all). So
+    ``n_layer`` is twice ``num_hidden_layers``, and ``layer_types`` names
+    exactly that many. The embedding's rows are
     scaled by ``embedding_multiplier`` and the tied head's logits divided by
     ``logits_scaling``. Set here and not published: the recurrent state in
     float32, ``dt`` not clamped. Taken and not read, so that the whole
-    published config can be passed: ``intermediate_size`` and
+    published config can be passed: without experts ``intermediate_size`` and
     ``num_experts_per_tok`` (the absent experts'), ``rope_theta`` and
     ``rope_scaling`` (no positions), ``max_position_embeddings`` (the caller's
     ``max_seq_len`` says what is served), ``model_type``. What it does not
     build it refuses."""
+    n_experts, top_k = int(num_local_experts), int(num_experts_per_tok or 0)
+    if n_experts and (intermediate_size is None or not 1 <= top_k <= n_experts):
+        raise NotImplementedError(
+            f"granitemoehybrid with num_local_experts={n_experts} needs the "
+            "experts' width (intermediate_size) and 1 <= num_experts_per_tok <= "
+            f"num_local_experts (got intermediate_size={intermediate_size}, "
+            f"num_experts_per_tok={num_experts_per_tok})")
     refused = {
-        "num_local_experts": (num_local_experts, 0),
         "position_embedding_type": (position_embedding_type, "nope"),
         "mamba_proj_bias": (mamba_proj_bias, False),
         "attention_bias": (attention_bias, False),
@@ -578,7 +594,7 @@ def granite_hybrid_cfg(*, hidden_size, num_hidden_layers, layer_types, vocab_siz
     bad = {k: got for k, (got, built) in refused.items() if got != built}
     if bad:
         raise NotImplementedError(
-            "granitemoehybrid is built without experts, positions or projection "
+            "granitemoehybrid is built without positions or projection "
             "biases, with the convolution's bias, SiLU, RMSNorm and an inner "
             f"width of heads x head size (got {bad})")
     kinds = {"mamba": "M", "attention": "*"}
@@ -588,9 +604,15 @@ def granite_hybrid_cfg(*, hidden_size, num_hidden_layers, layer_types, vocab_siz
                          f"{sorted(set(layer_types))}, num_hidden_layers={n} of "
                          f"{sorted(kinds)}")
     kw.setdefault("name", "granite-hybrid")
+    if n_experts:
+        kw.update(moe_kind="gated", moe_router="softmax", norm_topk_prob=True,
+                  n_routed_experts=n_experts, experts_per_token=top_k,
+                  moe_expert_width=int(intermediate_size),
+                  moe_shared_width=int(shared_intermediate_size))
     return CausalLMConfig(
         n_embd=hidden_size, n_layer=2 * n,
-        layer_pattern="".join(kinds[t] + "F" for t in layer_types),
+        layer_pattern="".join(kinds[t] + ("E" if n_experts else "F")
+                              for t in layer_types),
         vocab_size=vocab_size, n_head=num_attention_heads,
         n_kv_head=num_key_value_heads, pos_emb="none", layernorm="rmsnorm",
         ln_eps=rms_norm_eps, qkv_bias=False, mlp_bias=False, gated_mlp=True,
@@ -602,7 +624,8 @@ def granite_hybrid_cfg(*, hidden_size, num_hidden_layers, layer_types, vocab_siz
         embedding_multiplier=float(embedding_multiplier),
         residual_multiplier=float(residual_multiplier),
         attention_multiplier=float(attention_multiplier),
-        logits_scaling=float(logits_scaling), **kw)
+        logits_scaling=float(logits_scaling),
+        experts_held=None if experts_held is None else tuple(experts_held), **kw)
 
 
 FAMILIES = {
@@ -1159,7 +1182,8 @@ class MixerLayer(CausalLMLayer):
             init_std=cfg.init_std, out_std=cfg.out_std, name="moe")
         if cfg.moe_kind == "gated":
             from ..moe.gated_moe import GatedMoE
-            moe = GatedMoE(router=cfg.moe_router, topk_eps=cfg.moe_topk_eps, **shared)
+            moe = GatedMoE(router=cfg.moe_router, topk_eps=cfg.moe_topk_eps,
+                           shared_width=cfg.moe_shared_width, **shared)
         else:
             from ..moe.latent_moe import LatentMoE
             moe = LatentMoE(shared_width=cfg.moe_shared_width,

@@ -1,8 +1,13 @@
 """Mixture of gated experts of the full width, one chip's share of it.
 
 An expert is a SwiGLU block, ``W_down (silu(h W_gate) * (h W_up))``, three
-matrices; there is no shared expert. The router is data (``router``), scoring
-every expert of the layer (``n_routed``) in float32:
+matrices. A shared expert is data of the layer (``shared_width``; 0: none, as
+in ``sdar_moe`` and ``lfm2_moe``): one more SwiGLU block of that width,
+``shared_gate`` / ``shared_up`` / ``shared_down``, that reads the SAME normed
+input as router and experts and is added to their sum before the layer
+returns (``granitemoehybrid`` with experts), under the scope ``moe.shared``.
+The router is data (``router``), scoring every expert of the layer
+(``n_routed``) in float32:
 
 - ``"softmax"``: ``p = softmax(h W_r)``, the ``top_k`` largest, weighted by
   their probabilities, renormalised to sum to one where ``norm_topk`` says
@@ -57,6 +62,7 @@ class GatedMoE(nn.Module):
     router: str = "softmax"
     scale: float = 1.0            # the sigmoid_bias router's
     topk_eps: float = 1e-20
+    shared_width: int = 0         # the gated shared expert's; 0 = none
 
     @nn.compact
     def __call__(self, h, valid: Optional[jnp.ndarray] = None):
@@ -76,6 +82,11 @@ class GatedMoE(nn.Module):
         w_up = self.param("experts_up", init, (count, d, f), jnp.float32)
         w_down = self.param("experts_down", nn.initializers.normal(self.out_std),
                             (count, f, d), jnp.float32)
+        if self.shared_width:
+            s_gate = self.param("shared_gate", init, (d, self.shared_width), jnp.float32)
+            s_up = self.param("shared_up", init, (d, self.shared_width), jnp.float32)
+            s_down = self.param("shared_down", nn.initializers.normal(self.out_std),
+                                (self.shared_width, d), jnp.float32)
         b_, t, _ = h.shape
         with scope("moe.router"):
             x = h.reshape(b_ * t, d).astype(dt)
@@ -88,5 +99,13 @@ class GatedMoE(nn.Module):
             args = (w_up.astype(dt), w_down.astype(dt), jax.nn.silu,
                     None if valid is None else valid.reshape(-1), w_gate.astype(dt))
         out, stats = grouped_experts(x, idx, w, first, count, *args)
+        if self.shared_width:
+            with scope("moe.shared"):
+                mid = jax.nn.silu(jnp.dot(x, s_gate.astype(dt),
+                                          preferred_element_type=jnp.float32)) \
+                    * jnp.dot(x, s_up.astype(dt), preferred_element_type=jnp.float32)
+                # one rounding of the sum to the stream's type, not one a term
+                out = out + jnp.dot(mid.astype(dt), s_down.astype(dt),
+                                    preferred_element_type=jnp.float32)
         with scope("moe.rows"):
             return out.astype(dt).reshape(b_, t, d), stats

@@ -411,8 +411,10 @@ SCOPES: Dict[str, Tuple[str, str, str]] = {
     "moe.experts": (_STEP, "the expert FFNs (moe_grouped_ffn; the classic "
                            "layer's dispatch einsums)",
                     _TABLE + ", beside moe_decode_dev_ms_per_step"),
-    "moe.shared": (_STEP, "the latent projections around the experts and "
-                          "the shared expert", _TABLE),
+    "moe.shared": (_STEP, "the shared expert beside the routed ones: in the "
+                          "latent mixture with the projections to and from "
+                          "the latent space, in the gated mixture its three "
+                          "matmuls on the layer's own normed input", _TABLE),
     "ssm.in": (_STEP, "a Mamba-2 mixer's input projection and its split",
                _TABLE),
     "ssm.conv": (_STEP, "the causal convolution and its window", _TABLE),

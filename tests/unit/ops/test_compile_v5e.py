@@ -280,6 +280,36 @@ def test_the_gated_expert_kernel_compiles_at_lfm2s_published_widths(
     assert "tpu_custom_call" in text and "moe_grouped_ffn" in text
 
 
+@pytest.mark.parametrize("tokens", [32, 512])
+def test_the_gated_expert_kernel_compiles_at_granite_smalls_published_widths(
+        one_chip, tokens, monkeypatch):
+    """``moe_grouped_ffn`` in its gated form at granite-4.0-h-small's widths,
+    the cell ``granite-4.0-h-small.conv32``'s kernel shape ``(36, 4096, 768)``
+    (the chip's 36 of 72 experts), the top 10: a decode step of 32 slots is
+    320 assignments, 56 tiles of 16 rows; a 512-token prefill takes tiles of
+    32. The fourth expert size on the chip (9.4, 11.0, 22.0 and this 18.9
+    MB): three whole-matrix blocks, double-buffered, are 37.7 MB of the
+    kernel's 64 MiB of VMEM."""
+    from deepspeed_tpu.ops.moe import grouped_ffn as g
+    monkeypatch.setattr(g, "_interpret", lambda: False)
+    held, d, width, k = 36, 4096, 768, 10
+    tm = g.tile_rows(tokens * k)
+    assert tm == (16 if tokens == 32 else 32)
+    tiles = tokens * k // tm + held
+    assert (tokens, tiles) in ((32, 56), (512, 196))
+    assert 2 * 3 * d * width * 2 == 37_748_736 < g.VMEM_LIMIT_BYTES
+
+    def sds(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    up = sds((held, d, width), jnp.bfloat16)
+    text = jax.jit(functools.partial(g.grouped_ffn, act=jax.nn.silu, tm=tm)).lower(
+        sds((tiles * tm, d), jnp.bfloat16), sds((tiles,), jnp.int32),
+        sds((tiles,), jnp.int32), up, sds((held, width, d), jnp.bfloat16),
+        w_gate=up).compile().as_text()
+    assert "tpu_custom_call" in text and "moe_grouped_ffn" in text
+
+
 def test_sdars_block_chunk_holds_its_two_kernels_and_no_loop_but_its_own(
         one_chip, monkeypatch):
     """The decode chunk of generation by diffusion over blocks at the cell's
