@@ -4,8 +4,13 @@ TPU-first re-design of the model class the reference optimises (Megatron GPT-2 i
 canonical workload; see reference ``tests/model/Megatron_GPT2`` and the inference containers
 ``module_inject/containers/gpt2.py``). Design choices for XLA/TPU:
 
-- ``nn.scan`` over a single Block definition: one compiled layer body regardless of depth,
-  which keeps compile time flat and later gives pipeline stages a natural split point.
+- ``nn.scan`` over a single Block definition: ONE traced layer body and ONE parameter
+  stack (every leaf of ``h`` stacked on axis 0, the ``layers`` axis) regardless of depth,
+  which keeps tracing flat and gives checkpoints, ZeRO and pipeline stages one layout.
+  The COMPILED body is one only where a mesh axis shards a layer's parameters or its
+  carry: there the loop stays a ``while``, which bounds how many gathered layers are
+  alive at once. Where the parameters are whole on the device the loop is lowered
+  unrolled (``unroll_layer_loop``): no ``while``, so no stack of saved activations.
 - optional ``jax.checkpoint`` (remat) per layer — the analogue of the reference's activation
   checkpointing (``runtime/activation_checkpointing/checkpointing.py``).
 - bf16 compute / fp32 params via the engine's dtype policy; softmax and layernorm run fp32.
@@ -22,7 +27,7 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
 
-from ..observability import scope
+from ..observability import get_tracer, scope
 from ..ops.attention.flash import (FLASH_LSE_NAME, FLASH_OUT_NAME, FLASH_QKV_NAME,
                                    flash_attention_qkv)
 from ..ops.transformer.attention import flash_reads_fused_qkv, get_attention_impl
@@ -353,6 +358,24 @@ def _pin_replicated(w):
         w, mesh.sharding(P(*([None] * w.ndim))))
 
 
+def unroll_layer_loop() -> bool:
+    """Is the scan over layers lowered with every trip in straight-line code (no
+    ``while``)? Exactly when no mesh axis that can shard a layer's parameters or its
+    carry is larger than 1: no mesh, or one whose only axis above 1 is ``data``. A
+    ``while`` stacks every activation its backward needs in ONE ``(n_layer, ...)`` array
+    a kind, written a layer by ``dynamic_update_slice`` and sliced (and, in front of a
+    Mosaic kernel, copied) back out: 12.6 of the 170.3 ms of the 125M step (PERF.md
+    section 6, PR 54). Straight-line code keeps each in a buffer of its own. Under a
+    sharding axis the ``while`` is what keeps the scheduler from hoisting every layer's
+    all-gather to the front, so there it stays, trip by trip. Depth has no bound of its
+    own: cold compile and program size grow by ~2.5-2.9 s and ~10 MB a layer whatever
+    the width (24 layers at d 2048 for a described v5e: 5.7 -> 69.6 s, 13 -> 227 MB),
+    and a depth whose parameters fit one chip unsharded keeps both small beside a run."""
+    from ..parallel.mesh import AXIS_DATA, MESH_AXES, get_global_mesh
+    mesh = get_global_mesh()
+    return mesh is None or all(mesh.size(ax) == 1 for ax in MESH_AXES if ax != AXIS_DATA)
+
+
 class GPT2(nn.Module):
     config: GPT2Config
 
@@ -385,12 +408,22 @@ class GPT2(nn.Module):
                         FLASH_OUT_NAME, FLASH_LSE_NAME, FLASH_QKV_NAME))
             block = nn.remat(Block, prevent_cse=False, static_argnums=(2,), policy=policy)
         if cfg.scan_layers:
+            # an initialisation saves no activation: its program stays one body (unrolled,
+            # the 125M's loaded 1.5 s slower from the compile cache and gained nothing)
+            unrolled = False
+            if not self.is_initializing():
+                unrolled = unroll_layer_loop()
+                phase = get_tracer().current()
+                if phase is not None:   # the set-up phase a program is traced under says which
+                    phase.set(layer_loop="unrolled" if unrolled else "scan",
+                              layers=cfg.n_layer)
             x, _ = nn.scan(
                 lambda mdl, carry, _: (
                     _pin_batch_sharding(mdl(carry, deterministic)), None),
                 variable_axes={"params": 0},
                 split_rngs={"params": True, "dropout": True},
                 length=cfg.n_layer,
+                unroll=cfg.n_layer if unrolled else 1,
                 metadata_params={nn.PARTITION_NAME: "layers"},
             )(block(cfg, name="h"), x, None)
         else:

@@ -330,8 +330,10 @@ SPANS: Dict[str, Tuple[str, str, Tuple[str, ...], str]] = {
                              "setup_engine_init_s lines"),
     "setup.place_state": (PHASE, "device set-up", (),
                           "setup_engine_init_s lines"),
-    "setup.build_train_step": (PHASE, "device set-up", (),
-                               "setup_engine_init_s"),
+    # layer_loop, layers: which way ``models/gpt2.py: unroll_layer_loop`` sent the
+    # scan over layers ("unrolled" | "scan") in the step traced under the phase
+    "setup.build_train_step": (PHASE, "device set-up", ("layer_loop", "layers"),
+                               "setup_engine_init_s (the attributes: its lines)"),
     "setup.inference_engine_init": (PHASE, "device set-up", (),
                                     "setup_engine_init_s"),
     "setup.place_params": (PHASE, "device set-up", (),

@@ -5,6 +5,7 @@ nothing here is a result or a time. One file, one fixture: only the worker
 that is given this file loads the TPU's compiler."""
 
 import functools
+import re
 
 import jax
 import jax.numpy as jnp
@@ -411,6 +412,46 @@ def test_the_flash_kernels_compile_reading_heads_in_place(one_chip, case, monkey
     kernels = 3 if "gradient" in case or "heads" in case or "odd" in case else 1
     assert text.count("tpu_custom_call") == kernels and "flash_fwd" in text
     assert case == "odd-head-count" or " transpose(" not in text
+
+
+def _gpt2_125m_gradient(one_chip, monkeypatch, unrolled=None):
+    """``gpt2-125m.seq1k``'s forward + backward (the engine's loss, ``dots`` remat,
+    micro 24 x seq 1024) compiled for the described chip; ``unrolled`` stands in for
+    ``models/gpt2.py: unroll_layer_loop``'s answer where a test wants the other one."""
+    from deepspeed_tpu.models import gpt2
+    from deepspeed_tpu.ops.attention import flash
+    monkeypatch.setattr(flash, "_interpret", lambda: False)
+    if unrolled is not None:
+        monkeypatch.setattr(gpt2, "unroll_layer_loop", lambda: unrolled)
+    model = gpt2.gpt2_model(gpt2.GPT2Config(
+        vocab_size=50304, dtype=jnp.bfloat16, attention_impl="flash", remat=True,
+        remat_policy="dots"))
+    params = jax.tree_util.tree_map(
+        lambda leaf: jax.ShapeDtypeStruct(leaf.shape, jnp.bfloat16, sharding=one_chip),
+        jax.eval_shape(model.init_fn, jax.random.PRNGKey(0)))
+    batch = {"input_ids": jax.ShapeDtypeStruct((24, 1024), jnp.int32, sharding=one_chip)}
+    rng = jax.ShapeDtypeStruct((2,), jnp.uint32, sharding=one_chip)
+    compiled = jax.jit(jax.value_and_grad(model.loss_fn)).lower(params, batch, rng).compile()
+    text = compiled.as_text()
+    stack_writes = re.findall(r"= \w+\[12,24,1024,[^\]]*\]\S* dynamic-update-slice\(", text)
+    return text, stack_writes, compiled.memory_analysis().temp_size_in_bytes
+
+
+def test_the_125m_gradient_holds_no_loop_and_no_stack_of_saved_activations(
+        one_chip, monkeypatch):
+    """Where the parameters are whole on the device the layers are lowered unrolled:
+    no ``while``, so no ``(12, 24, 1024, ...)`` stack that every saved activation is
+    written into and sliced (and, for the kernels, copied) out of; a kernel call a
+    layer where the loop's body held one; less temporary memory than the loop."""
+    text, stack_writes, temp = _gpt2_125m_gradient(one_chip, monkeypatch)
+    assert " while(" not in text and stack_writes == []
+    assert text.count("tpu_custom_call") == 36
+    assert all(text.count(kernel) >= 12
+               for kernel in ("flash_fwd", "flash_bwd_dq", "flash_bwd_dkv"))
+    looped, looped_writes, looped_temp = _gpt2_125m_gradient(one_chip, monkeypatch, unrolled=False)
+    assert looped.count(" while(") == 2 and len(looped_writes) >= 5
+    assert looped.count("tpu_custom_call") == 3
+    assert temp < looped_temp
 
 
 def _pallas_calls(fn, shapes, dtype):
