@@ -60,7 +60,7 @@ class BareAssertRule(AstRule):
 _EMIT_FUNCS = {"write_events", "record_events", "record", "emit", "_write",
                "counter", "gauge", "histogram"}
 _TAG_RE = re.compile(r"^(serving|router|Train|inference|latency|flight"
-                     r"|anomaly)/[A-Za-z0-9_{}*./]+$")
+                     r"|anomaly|host)/[A-Za-z0-9_{}*./]+$")
 
 
 def _literal_tag(node: ast.AST) -> Optional[str]:
@@ -128,13 +128,13 @@ def iter_emission_tags_from_tree(tree: ast.Module
 
 
 _SPAN_FUNCS = {"span", "phase", "begin", "start_span", "record_span",
-               "instant"}
+               "record_pause", "instant"}
 
 
 def iter_span_names_from_tree(tree: ast.Module) -> Iterator[Tuple[str, int]]:
     """Yield ``(name, lineno)`` for the literal first argument of every
     tracer call that names a span (``.span`` / ``.phase`` / ``.begin`` /
-    ``.start_span`` / ``.record_span`` / ``.instant``)."""
+    ``.start_span`` / ``.record_span`` / ``.record_pause`` / ``.instant``)."""
     for node in ast.walk(tree):
         if isinstance(node, ast.Call) and isinstance(node.func, ast.Attribute) \
                 and node.func.attr in _SPAN_FUNCS and node.args \

@@ -242,6 +242,11 @@ class ChunkResult:
     block_counts: Optional[np.ndarray] = None   # and (blocks committed,
     #   positions unmasked, commits that opened their next block in the same
     #   forward) over the chunk's forwards
+    stamps: Optional[Tuple[float, float, float]] = None   # ``time.monotonic``
+    #   stamps the spans took: the end of ``serving.dispatch``, the start and
+    #   the end of ``serving.fetch`` (``elapsed`` ends at the last; the
+    #   scheduler's fetch wait and turnaround are differences of these and of
+    #   no other clock reading)
 
 
 @dataclass
@@ -504,19 +509,25 @@ class ChunkedDecodeExecutor:
 
     # -------------------------------------------------------------------- steps
     def _dispatch(self, fn, args, program: str, bucket: int = 0, parent=None):
-        """Call a compiled function under ``serving.dispatch``. The first call
-        this executor makes of ``fn`` is python tracing + lowering + compile
-        (or a cache load) as the host sees it: kept as a ``setup.program``
-        phase. ``parent`` places the ring span when the caller is the
-        watchdog's worker thread."""
+        """Call a compiled function under ``serving.dispatch``; returns its
+        result and the span's end stamp. The first call this executor makes
+        of ``fn`` is python tracing + lowering + compile (or a cache load) as
+        the host sees it: kept as a ``setup.program`` phase. ``parent`` places
+        the ring span when the caller is the watchdog's worker thread.
+        ``seq`` is the pool's running count of dispatched programs: the
+        device runs them in that order."""
         tracer = get_tracer()
         self.pool.programs += 1
-        with tracer.span("serving.dispatch", parent=parent, program=program):
+        with tracer.span("serving.dispatch", parent=parent, program=program,
+                         seq=self.pool.programs) as sp:
             if fn in self._called:
-                return fn(*args)
-            self._called.add(fn)
-            with tracer.phase("setup.program", program=program, bucket=bucket):
-                return fn(*args)
+                out = fn(*args)
+            else:
+                self._called.add(fn)
+                with tracer.phase("setup.program", program=program,
+                                  bucket=bucket):
+                    out = fn(*args)
+        return out, sp.t1
 
     def prefill_into_slot(self, slot: int, prompt: np.ndarray, seed: int = 0,
                           prefix_len: int = 0, prefix_slab=None,
@@ -598,8 +609,8 @@ class ChunkedDecodeExecutor:
                             *jax.device_put((ids, ctl)), self._base_key)
                 handed = self.pool.caches
                 try:
-                    out, caches = self._dispatch(fn, args, "suffix_prefill",
-                                                 bucket)
+                    (out, caches), _ = self._dispatch(fn, args,
+                                                      "suffix_prefill", bucket)
                     self.pool.caches = caches
                     with tracer.span("serving.fetch", program="suffix_prefill",
                                      arrays=1):
@@ -630,7 +641,7 @@ class ChunkedDecodeExecutor:
             # the batch-1 cache is the program's from here (donated): after a
             # failure it is built anew, the pool was not touched
             self._one = None
-            out, one_caches = self._dispatch(fn, args, "prefill", bucket)
+            (out, one_caches), _ = self._dispatch(fn, args, "prefill", bucket)
             with tracer.span("serving.fetch", program="prefill", arrays=1):
                 # lint: host-sync-ok (honest TTFT: first token synced on
                 # purpose; the expert counts ride in the same array)
@@ -696,7 +707,7 @@ class ChunkedDecodeExecutor:
             ctl[:, head:] = self.pool.page_table
             args = (self.engine.params, jax.device_put(ctl), caches_in,
                     self._base_key)
-        (packed,), caches, t1 = self._dispatch_watched(
+        (packed,), caches, stamps = self._dispatch_watched(
             self._timed(fn, args, "decode_chunk", "serving.chunk_compute"))
         self._warm_chunk = True
         obs_profiler.tick("decode_chunk")
@@ -714,7 +725,7 @@ class ChunkedDecodeExecutor:
                            active=state[:, OUT_ACTIVE] != 0,
                            remaining=state[:, OUT_REMAINING],
                            steps=state[:, OUT_STEPS],
-                           elapsed=t1 - placed.t1,
+                           elapsed=stamps[2] - placed.t1, stamps=stamps,
                            moe=packed[S, :2] if self.with_stats else None,
                            block=in_flight, block_counts=counts)
 
@@ -723,7 +734,7 @@ class ChunkedDecodeExecutor:
         :meth:`_dispatch_watched`: injected stalls, compile + dispatch (hung
         compile), and host fetch (hung collective). ``fn`` returns a tuple
         that ends in the pool's caches; the callable returns ``(the other
-        outputs as host arrays, caches, stamp of the fetch's end)``: a chunk
+        outputs as host arrays, caches, ChunkResult.stamps)``: a chunk
         has one other output, its packed result. It may run on the watchdog's
         worker thread, so its spans are handed the caller's open span."""
         tracer = get_tracer()
@@ -734,8 +745,8 @@ class ChunkedDecodeExecutor:
             if self._stall_next > 0:
                 stall, self._stall_next = self._stall_next, 0.0
                 time.sleep(stall)
-            *outs, caches = self._dispatch(fn, args, program,
-                                           self.chunk_size, parent)
+            (*outs, caches), dispatched = self._dispatch(
+                fn, args, program, self.chunk_size, parent)
             with tracer.span("serving.fetch", parent=parent, program=program,
                              arrays=len(outs)) as fetched:
                 for x in outs:
@@ -747,7 +758,7 @@ class ChunkedDecodeExecutor:
                 # retires/admits between chunks and a verify round's accept
                 # rule needs the window logits; this fetch IS the boundary)
                 host = tuple(np.asarray(x) for x in outs)
-            return host, caches, fetched.t1
+            return host, caches, (dispatched, fetched.t0, fetched.t1)
 
         return timed
 
@@ -792,7 +803,7 @@ class ChunkedDecodeExecutor:
             placed.set(arrays=len(args) - 2)    # all but params and caches
         # the mid-verify chaos/injection seam: after the proposer built the
         # window, before/through the verify dispatch + logits fetch
-        (logits,), caches, t1 = self._dispatch_watched(
+        (logits,), caches, stamps = self._dispatch_watched(
             self._timed(fn, args, "spec_verify", "serving.spec_verify"))
         self._warm_chunk = True
         obs_profiler.tick("spec_verify")
@@ -834,5 +845,5 @@ class ChunkedDecodeExecutor:
         return SpecResult(buf=buf, toks=toks_out.reshape(-1, 1),
                           lens=lens_out, active=active_out,
                           remaining=remaining_out, steps=steps_out,
-                          elapsed=t1 - placed.t1,
+                          elapsed=stamps[2] - placed.t1, stamps=stamps,
                           proposed=proposed, accepted=accepted)

@@ -294,6 +294,11 @@ class ContinuousBatchingScheduler:
         # been done at its stream's last delivery (or its own first token)
         self._prefills_done = 0
         self._prefills_seen = np.zeros(S, np.int64)
+        # the chunk cycle: the last chunk's fetch return (None once a step
+        # ran no chunk: an empty server's wait is no turnaround) and the
+        # seconds of ``serving.admit`` since, which a turnaround leaves out
+        self._fetched_at: Optional[float] = None
+        self._admit_s = 0.0
 
     # ---------------------------------------------------------------- frontend
     def submit(self, prompt, max_new_tokens: Optional[int] = None,
@@ -578,6 +583,7 @@ class ContinuousBatchingScheduler:
                 tracer.record_span("queue_wait", handle._span, handle.arrival,
                                    span.t0)
                 outcome = self._admit_one(handle, need_tokens, span)
+            self._admit_s += span.t1 - span.t0
             if outcome is None:     # no slot after all: the head waits
                 break
             admitted = admitted or outcome
@@ -728,6 +734,8 @@ class ContinuousBatchingScheduler:
 
     # ----------------------------------------------------------------- decode
     def _decode_chunk(self) -> bool:
+        prev_fetched, self._fetched_at = self._fetched_at, None
+        admit_s, self._admit_s = self._admit_s, 0.0
         if not self._active.any():
             return False
         cfg = self.config
@@ -794,6 +802,12 @@ class ContinuousBatchingScheduler:
                           if self._prefills_seen[slot] < self._prefills_done)
             span.set(tokens_kept=total, deliveries=len(delivered),
                      stalled_deliveries=stalled)
+            if res.stamps is not None:
+                # the chunk cycle on the host's clock, from the stamps the
+                # executor's spans took (no clock is read for it)
+                span.set(**self.telemetry.on_cycle(
+                    *res.stamps, prev_fetched=prev_fetched, admit_s=admit_s))
+                self._fetched_at = res.stamps[2]
             if res.moe is not None:
                 span.set(moe_assignments=int(res.moe[0]),
                          moe_experts_touched=int(res.moe[1]))

@@ -30,7 +30,13 @@ parentheses):
   ``serving.decode_chunk`` span);
 - ``serving/spec_*`` — per verify round, speculation enabled only; the
   emission site lives in ``inference.speculative.emit_spec_events`` (the
-  subsystem that owns the semantics), this class only keeps the counters.
+  subsystem that owns the semantics), this class only keeps the counters;
+- ``serving/chunk_fetch_wait_ms``, ``serving/chunk_turnaround_ms`` — per decode
+  chunk (chunk idx): the chunk cycle on the host's clock, from the stamps the
+  spans take; ``host/stalls_total``, ``host/stall_ms_total`` — a chunk whose
+  fetch wait or turnaround ran far over its running median
+  (:data:`STALL_MULTIPLE`, :data:`STALL_FLOOR_MS`), kept as ``host.stall``
+  in ``tracer.pauses`` (:meth:`ServingTelemetry.on_cycle`).
 
 Latency distributions are **fixed-log-bucket histograms**, not lists: memory
 stays O(1) over a week-long soak (the pre-PR-10 ``ttfts``/``tpots`` Python
@@ -43,6 +49,7 @@ from collections import deque
 from typing import Dict, Iterable, Optional
 
 from ...observability.metrics import Histogram, RegistryFeed
+from ...observability.trace import get_tracer
 from ..speculative import SpecStats, emit_spec_events
 
 
@@ -74,6 +81,21 @@ def adaptive_retry_after(floor_s: float, cap_s: float, queue_depth: int,
     else:
         hint = (queue_depth + 1) / drain_rate
     return float(min(max(hint, floor_s), cap_s))
+
+
+#: A chunk's phase is a stall when it ran over BOTH this multiple of the
+#: phase's running median and :data:`STALL_FLOOR_MS`. The fetch wait is the
+#: device's chunk as the host sees it, the same K steps every time (90-260 ms
+#: in the benchmark's cells, within a few per cent of its median), so half
+#: as much again is no chunk. The turnaround is 1-8 ms of host work that a
+#: profiler or the machine's slow mode stretch two- to fourfold and a
+#: finished request's harvest doubles: neither is a stall, a collection of
+#: 0.13 s or a process that stood still is.
+STALL_MULTIPLE = {"fetch": 1.5, "turnaround": 8.0}
+STALL_FLOOR_MS = 50.0
+#: the running medians are read off the histograms every so many chunks; no
+#: stall is called before the first reading
+STALL_REFRESH_CHUNKS = 16
 
 
 class ServingTelemetry:
@@ -122,6 +144,13 @@ class ServingTelemetry:
         # the spec_* event emission itself lives in inference.speculative
         self.spec = SpecStats()
         self.spec_enabled = False
+        # the chunk cycle on the host's clock (ms), this scheduler's own: the
+        # running medians a stall is held against come from these two
+        self.chunk_ms = {"fetch": Histogram(), "turnaround": Histogram()}
+        self._typical_ms: Dict[str, float] = {}
+        self.stalls = 0
+        self.stall_ms = 0.0
+        self._stalls_written = -1
         # completion timestamps (bounded): the observed drain rate behind the
         # load-adaptive QueueFullError.retry_after hint
         self._finish_times = deque(maxlen=64)
@@ -245,6 +274,49 @@ class ServingTelemetry:
             self._write([("serving/tokens_per_sec", tokens / elapsed,
                           self._chunk_idx)])
 
+    def on_cycle(self, dispatched: float, fetch_t0: float, fetched: float,
+                 prev_fetched: Optional[float] = None,
+                 admit_s: float = 0.0) -> Dict[str, float]:
+        """One decode chunk's cycle, from the stamps its spans took
+        (``ChunkResult.stamps``): the wait in ``serving.fetch`` and, where
+        the previous step ran a chunk too (``prev_fetched``: its fetch's
+        return), the host's turnaround from there to this chunk's
+        ``serving.dispatch`` return, the ``admit_s`` seconds of
+        ``serving.admit`` between the two left out. Both go into their
+        histograms; one that ran far over its running median is kept as a
+        ``host.stall``. Returns the chunk span's end-of-span attributes."""
+        idx = self._chunk_idx + 1
+        attrs = {"fetch_wait_ms": round((fetched - fetch_t0) * 1e3, 3)}
+        events = [("serving/chunk_fetch_wait_ms", attrs["fetch_wait_ms"], idx)]
+        self._phase("fetch", fetch_t0, fetched, attrs["fetch_wait_ms"])
+        if prev_fetched is not None:
+            ms = round((dispatched - prev_fetched - admit_s) * 1e3, 3)
+            attrs.update(turnaround_ms=ms, admit_ms=round(admit_s * 1e3, 3))
+            events.append(("serving/chunk_turnaround_ms", ms, idx))
+            self._phase("turnaround", prev_fetched, dispatched, ms)
+        if self.stalls != self._stalls_written:     # and once at 0
+            self._stalls_written = self.stalls
+            events += [("host/stalls_total", float(self.stalls), idx),
+                       ("host/stall_ms_total", self.stall_ms, idx)]
+        self._write(events)
+        return attrs
+
+    def _phase(self, phase: str, t0: float, t1: float, ms: float) -> None:
+        hist = self.chunk_ms[phase]
+        typical = self._typical_ms.get(phase)
+        hist.observe(ms)
+        if hist.count % STALL_REFRESH_CHUNKS == 0:
+            self._typical_ms[phase] = hist.percentile(50)
+        if typical is None or ms <= max(STALL_MULTIPLE[phase] * typical,
+                                        STALL_FLOOR_MS):
+            return
+        self.stalls += 1
+        self.stall_ms += ms - typical       # what the stall cost: the time over
+        tracer = get_tracer()
+        tracer.record_pause("host.stall", t0, t1, phase=phase, ms=ms,
+                            typical_ms=round(typical, 3),
+                            gc_ms=round(tracer.gc_ms_between(t0, t1), 3))
+
     def on_spec(self, proposed: int, accepted: int, tokens: int,
                 draft_s: float, verify_s: float) -> None:
         """Per-verify-round speculative accounting (one round == one target
@@ -333,6 +405,10 @@ class ServingTelemetry:
             "decode_slot_steps": self.decode_slot_steps,
             "deliveries": self.deliveries,
             "deliveries_stalled": self.deliveries_stalled,
+            "host_stalls": self.stalls,
+            "chunk_fetch_wait_ms_p50": self.chunk_ms["fetch"].percentile(50),
+            "chunk_turnaround_ms_p50":
+                self.chunk_ms["turnaround"].percentile(50),
             "tokens_per_sec": (self.tokens_total / self.decode_seconds
                                if self.decode_seconds > 0 else 0.0),
             "ttft_ms_p50": self.ttft_ms.percentile(50),

@@ -148,7 +148,10 @@ class MetricsRegistry:
     def __init__(self):
         self._metrics: Dict[str, object] = {}
         self._monitors: List[object] = []
-        self._lock = threading.Lock()
+        # re-entrant: making an instrument allocates, an allocation may start
+        # a collection, and the collector's hook (``trace._gc_hook``) asks
+        # for its own two instruments on the same thread
+        self._lock = threading.RLock()
 
     # -------------------------------------------------------------- instruments
     def _get(self, tag: str, kind: Optional[str] = None):

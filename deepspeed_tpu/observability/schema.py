@@ -143,6 +143,25 @@ TAGS: Dict[str, Tuple[str, str]] = {
     "host/child_rss_bytes": (GAUGE, "max child RSS across hosted replicas"),
     "host/pipe_lag_ms": (GAUGE, "max heartbeat pipe transit+age across "
                                 "hosted replicas"),
+    # ------------------------- host pauses and the chunk cycle (PR 55)
+    "host/gc_pause_ms": (HISTOGRAM, "pause of one collection of python's "
+                                    "garbage collector, every generation "
+                                    "(the gc.callbacks hook)"),
+    "host/gc_collections_total": (COUNTER, "collections of python's garbage "
+                                           "collector since the hook went in"),
+    "host/stalls_total": (COUNTER, "decode chunks whose fetch wait or "
+                                   "turnaround ran far over its running "
+                                   "median (kept as host.stall)"),
+    "host/stall_ms_total": (COUNTER, "milliseconds those chunks' stalled "
+                                     "phase ran over its running median"),
+    "serving/chunk_fetch_wait_ms": (HISTOGRAM, "host wait in a decode "
+                                               "chunk's serving.fetch (the "
+                                               "device's chunk, as the host "
+                                               "sees it)"),
+    "serving/chunk_turnaround_ms": (HISTOGRAM, "host time from a chunk's "
+                                               "fetch return to the next "
+                                               "chunk's dispatch return, "
+                                               "admissions left out"),
     # ------------------------------------------ socket replica transport (PR 16)
     "net/frames_total": (COUNTER, "wire frames moved (sent + decoded) per "
                                   "socket link"),
@@ -206,10 +225,16 @@ TAGS: Dict[str, Tuple[str, str]] = {
 BOTH = "xplane+ring"
 RING = "ring"
 PHASE = "xplane+ring+phases"
+#: a host pause: kept in ``tracer.pauses`` with the tracer on or off. A
+#: collection reaches the xplane (generation 2 only) and never the ring; a
+#: stall is known only when it is over, so the ring and never the xplane.
+PAUSE_GC = "xplane+pauses"
+PAUSE_STALL = "ring+pauses"
 
 #: span name -> (sinks, layer, attributes, what reads it). THE span schema:
 #: every name a ``span`` / ``phase`` / ``begin`` / ``start_span`` /
-#: ``record_span`` / ``instant`` call in :data:`SPAN_MODULES` uses. ``reads``
+#: ``record_span`` / ``record_pause`` / ``instant`` call in
+#: :data:`SPAN_MODULES` uses. ``reads``
 #: names the benchmark metric (``benchmarks/chipbench/layer_metrics/``) or
 #: the operator feature the span exists for.
 SPANS: Dict[str, Tuple[str, str, Tuple[str, ...], str]] = {
@@ -266,9 +291,13 @@ SPANS: Dict[str, Tuple[str, str, Tuple[str, ...], str]] = {
                               "moe_assignments", "moe_experts_touched",
                               "forwards",
                               "blocks_committed", "positions_unmasked",
-                              "block_length", "blocks_merged"),
+                              "block_length", "blocks_merged",
+                              "fetch_wait_ms", "turnaround_ms", "admit_ms"),
                              "decode_wasted_step_pct, delivery_stalled_pct, "
                              "sched_fetch_idle_ms_per_step, "
+                             "sched_chunk_turnaround_host_ms (fetch_wait_ms, "
+                             "turnaround_ms, admit_ms: the chunk cycle on "
+                             "the host's clock), "
                              "moe_experts_touched_per_step, "
                              "moe_ffn_roofline_pct, block_tokens_per_forward, "
                              "block_forward_hbm_roofline_pct, "
@@ -276,7 +305,8 @@ SPANS: Dict[str, Tuple[str, str, Tuple[str, ...], str]] = {
     "serving.spec_verify": (BOTH, "compiled steps",
                             ("chunk", "active_slots", "request_ids",
                              "slot_steps_run", "tokens_kept", "deliveries",
-                             "stalled_deliveries"),
+                             "stalled_deliveries", "fetch_wait_ms",
+                             "turnaround_ms", "admit_ms"),
                             "as serving.decode_chunk, for a speculative "
                             "verify round"),
     # arrays: the device arrays that crossed the host-device boundary in the
@@ -285,9 +315,16 @@ SPANS: Dict[str, Tuple[str, str, Tuple[str, ...], str]] = {
     "serving.place_inputs": (BOTH, "serve scheduler", ("program", "arrays"),
                              "sched_fetch_idle_ms_per_step and "
                              "sched_admit_host_ms lines"),
-    "serving.dispatch": (BOTH, "serve scheduler", ("program",),
+    # seq: the executor's running count of dispatched programs
+    # (``pool.programs``), so that a reader pairs dispatches with the
+    # device's executions by ORDER and never across the two planes' clocks
+    "serving.dispatch": (BOTH, "serve scheduler", ("program", "seq"),
+                         "sched_chunk_gap_dev_ms lines (chunk_cycles: the "
+                         "causal bounds on the host plane's offset); "
                          "sched_fetch_idle_ms_per_step lines"),
     "serving.fetch": (BOTH, "serve scheduler", ("program", "arrays"),
+                      "sched_chunk_gap_dev_ms lines (chunk_cycles: the "
+                      "fetch's return bounds the offset from above); "
                       "sched_fetch_idle_ms_per_step; sched_admit_host_ms "
                       "lines"),
     "serving.harvest": (BOTH, "serve scheduler", ("finished",),
@@ -308,6 +345,14 @@ SPANS: Dict[str, Tuple[str, str, Tuple[str, ...], str]] = {
                      "one of its requests saw it"),
     "retire": (RING, "serve scheduler", ("state", "reason"),
                "flight recorder (why a request left its slot)"),
+    # host pauses (process-wide: a training process keeps host.gc too)
+    "host.gc": (PAUSE_GC, "serve scheduler", ("generation", "collected"),
+                "host_pause_pct; breakdown idle gaps (generation 2 is an "
+                "annotation: the innermost host event over its gap)"),
+    "host.stall": (PAUSE_STALL, "serve scheduler",
+                   ("phase", "ms", "typical_ms", "gc_ms"),
+                   "host_pause_pct (phase fetch | turnaround; gc_ms: the "
+                   "collector's part of it)"),
     # ------------------------------------------------------------- train engine
     "train_step": (BOTH, "train engine",
                    ("step", "bytes_on_wire", "overlap_ratio", "offload"),
@@ -355,6 +400,8 @@ SPAN_MODULES = (
     "deepspeed_tpu/inference/serving/scheduler.py",
     "deepspeed_tpu/inference/serving/executor.py",
     "deepspeed_tpu/inference/serving/kv_pool.py",
+    "deepspeed_tpu/inference/serving/telemetry.py",
+    "deepspeed_tpu/observability/trace.py",
     "deepspeed_tpu/inference/engine.py",
     "deepspeed_tpu/runtime/engine.py",
 )
@@ -600,6 +647,7 @@ EMITTER_MODULES = (
     "deepspeed_tpu/runtime/engine.py",
     "deepspeed_tpu/inference/engine.py",
     "deepspeed_tpu/observability/metrics.py",
+    "deepspeed_tpu/observability/trace.py",
     "deepspeed_tpu/observability/attribution.py",
     "deepspeed_tpu/observability/flight.py",
     "deepspeed_tpu/observability/anomaly.py",

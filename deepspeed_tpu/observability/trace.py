@@ -12,7 +12,7 @@ also records the span in the ring below, under the same name with the same
 attributes; request-scoped spans carry ``request_id`` (train steps ``step``) in
 both sinks, which is how a ring export and an xplane are joined.
 
-Three kinds of span:
+Four kinds of span:
 
 - **scoped** (:meth:`Tracer.span`): a ``with`` block on one thread. Both
   sinks. Without ``parent`` it nests (in the ring) under the innermost scoped
@@ -31,6 +31,15 @@ Three kinds of span:
   runs before any profiler is armed and with the tracer off, so these are
   kept unconditionally in :attr:`Tracer.phases` with ``time.monotonic`` start
   and end (and go to both sinks like any scoped span).
+- **pauses** (:meth:`Tracer.record_pause`): the rare moments in which the
+  HOST stood still: a collection of python's garbage collector (``host.gc``,
+  stamped by the one ``gc.callbacks`` hook below) and a decode chunk whose
+  fetch or turnaround ran far over its running median (``host.stall``, found
+  by ``serving/telemetry.py`` in retrospect). Every benchmark run has the
+  ring off and a profiler armed for 3 of its 51 seconds, so these are kept
+  unconditionally too, in :attr:`Tracer.pauses`: the second kept list, same
+  record shape as a phase, a bounded drop-oldest deque of its own so that a
+  server's week of pauses never pushes out its set-up phases.
 
 The ring answers "where did this one request's 1.9 s go?": the serving column
 (``replica_request`` -> ``queue_wait`` / ``serving.admit`` {``serving.prefix_lookup``,
@@ -64,6 +73,7 @@ Perfetto / ``chrome://tracing``) and a JSONL stream (one finished span per
 line) for tailing.
 """
 
+import gc
 import itertools
 import json
 import os
@@ -75,7 +85,7 @@ from typing import Dict, List, Optional
 import jax
 from jax.profiler import TraceAnnotation
 
-from . import schema
+from . import metrics, schema
 
 # span categories (Chrome "cat" field) — one per subsystem lane
 CAT_SERVING = "serving"
@@ -83,10 +93,19 @@ CAT_ROUTER = "router"
 CAT_TRAIN = "train"
 CAT_AUTOSCALE = "autoscale"
 CAT_SETUP = "setup"
+CAT_HOST = "host"
 
 #: set-up phases kept per process (tens in practice: one per engine stage and
 #: one per compiled serving program)
 MAX_PHASES = 1024
+
+#: host pauses kept per process, newest last (tens a minute at most: full
+#: collections, young ones of a millisecond and more, stalled chunks)
+MAX_PAUSES = 2048
+
+#: a young collection (generation 0 or 1) is kept from this pause on; a full
+#: one (generation 2) always
+GC_KEEP_MS = 1.0
 
 
 class SpanContext:
@@ -222,6 +241,9 @@ class Tracer:
         self._local = threading.local()
         # set-up phases: kept whether or not the tracer is enabled
         self._phases: "deque[Dict]" = deque(maxlen=MAX_PHASES)
+        # host pauses: kept likewise, in a deque of their own
+        self._pauses: "deque[Dict]" = deque(maxlen=MAX_PAUSES)
+        self.pauses_dropped = 0
 
     # ------------------------------------------------------------------ admin
     def enable(self, pid_label: Optional[str] = None,
@@ -371,6 +393,40 @@ class Tracer:
         name or ``None``), ``attrs``."""
         return list(self._phases)
 
+    def record_pause(self, name: str, t0: float, t1: float, ring: bool = True,
+                     **attrs) -> None:
+        """A host pause between two ``time.monotonic`` readings, known only
+        when it is over: kept in :attr:`pauses` whether or not the tracer is
+        enabled and, while it is (and ``ring``), committed to the ring as the
+        root of a trace of its own. The collector's hook passes ``ring=False``:
+        it runs wherever an allocation happens to, the ring's lock included."""
+        if len(self._pauses) == self._pauses.maxlen:
+            self.pauses_dropped += 1
+        self._pauses.append({"name": name, "t0": t0, "t1": t1,
+                             "attrs": attrs})
+        if self.enabled and ring:
+            self._commit(name, CAT_HOST, self.new_trace_id(), self._new_id(),
+                         None, self.ts_us(t0), max((t1 - t0) * 1e6, 0.0),
+                         attrs, threading.current_thread().name)
+
+    @property
+    def pauses(self) -> List[Dict]:
+        """Host pauses so far, oldest first: ``name`` (``host.gc`` |
+        ``host.stall``), ``t0``/``t1`` (``time.monotonic``), ``attrs``. At
+        most :data:`MAX_PAUSES`; :attr:`pauses_dropped` counts the rest."""
+        return list(self._pauses)
+
+    def gc_ms_between(self, t0: float, t1: float) -> float:
+        """Milliseconds of kept collector pauses inside ``[t0, t1]``: how
+        much of a stall the collector explains."""
+        total = 0.0
+        for p in reversed(self._pauses):
+            if p["t1"] <= t0:
+                break
+            if p["name"] == "host.gc":
+                total += max(0.0, min(p["t1"], t1) - max(p["t0"], t0))
+        return total * 1e3
+
     def record_span(self, name: str, parent, t0: float, t1: float,
                     cat: Optional[str] = None, attrs: Optional[Dict] = None,
                     tid: Optional[str] = None) -> None:
@@ -487,6 +543,69 @@ def chrome_events_from(spans: List[Dict]) -> List[Dict]:
 
 _tracer = Tracer()
 
+# ------------------------------------------------------------- the collector
+#: the collection under way, ``(time.monotonic at its start, its annotation
+#: or None)``: the collector runs one at a time, start and stop on one thread
+_gc_open = None
+#: None until the process's tracer is first asked for, then whether the hook
+#: is in ``gc.callbacks`` (False once removed by hand: nothing puts it back)
+_gc_hooked = None
+
+
+def _gc_hook(phase: str, info: Dict) -> None:
+    """The ONE ``gc.callbacks`` entry: every collection's pause goes into the
+    registry (``host/gc_pause_ms``, ``host/gc_collections_total``), a full one
+    is a ``host.gc`` annotation for an armed profiler, and a full one or a
+    pause of :data:`GC_KEEP_MS` and more is kept in ``tracer.pauses``.
+    Generations 0 and 1 open no annotation: a TraceMe a young collection
+    would flood a trace. It runs inside the collector, on whatever thread
+    allocated: instruments are touched directly (no monitor fan-out) and the
+    ring not at all."""
+    global _gc_open
+    if phase == "start":
+        ann = None
+        t0 = time.monotonic()
+        if info["generation"] == 2:
+            ann = TraceAnnotation("host.gc", generation=2)
+            ann.__enter__()
+        _gc_open = (t0, ann)
+        return
+    t1 = time.monotonic()
+    if _gc_open is None:        # hooked while a collection was under way
+        return
+    (t0, ann), _gc_open = _gc_open, None
+    if ann is not None:
+        ann.set_metadata(collected=info["collected"])
+        ann.__exit__(None, None, None)
+    ms = (t1 - t0) * 1e3
+    registry = metrics.get_registry()
+    registry.histogram("host/gc_pause_ms").observe(ms)
+    registry.counter("host/gc_collections_total").inc()
+    if info["generation"] == 2 or ms >= GC_KEEP_MS:
+        tracer = _tracer
+        if hasattr(tracer, "record_pause"):      # a test's stand-in may not
+            tracer.record_pause("host.gc", t0, t1, ring=False,
+                                generation=info["generation"],
+                                collected=info["collected"])
+
+
+def install_gc_hook() -> None:
+    """Put :func:`_gc_hook` into ``gc.callbacks`` (once, however often it is
+    called)."""
+    global _gc_hooked
+    if _gc_hook not in gc.callbacks:
+        gc.callbacks.append(_gc_hook)
+    _gc_hooked = True
+
+
+def uninstall_gc_hook() -> None:
+    """Take the hook out again (tests); :func:`get_tracer` does not put it
+    back, :func:`install_gc_hook` does."""
+    global _gc_hooked, _gc_open
+    if _gc_hook in gc.callbacks:
+        gc.callbacks.remove(_gc_hook)
+    _gc_hooked, _gc_open = False, None
+
 
 def scope(name: str):
     """Name the device ops traced under it ``ds.<name>``: a ``with`` block
@@ -501,4 +620,8 @@ def scope(name: str):
 
 
 def get_tracer() -> Tracer:
+    """The process's tracer; the first call hooks the collector's pauses
+    into it (:func:`install_gc_hook`), in a training process as in a server."""
+    if _gc_hooked is None:
+        install_gc_hook()
     return _tracer

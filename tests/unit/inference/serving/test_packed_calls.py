@@ -350,13 +350,13 @@ def test_a_failed_admission_rebuilds_the_pool_only_if_its_buffers_are_gone(
         dispatch, tries = ex._dispatch, []
 
         def dies(fn, args, program, *rest):
-            out = dispatch(fn, args, program, *rest)
+            out, returned_at = dispatch(fn, args, program, *rest)
             if not program.endswith("prefill"):
-                return out
+                return out, returned_at
             tries.append(program)
             if when == "in-dispatch":
                 raise OSError("the dispatch died with its operands in it")
-            return (_Poisoned(),) + tuple(out[1:])
+            return (_Poisoned(),) + tuple(out[1:]), returned_at
 
         monkeypatch.setattr(ex, "_dispatch", dies)
         victim = sched.submit(victim_prompt, max_new_tokens=4)

@@ -145,10 +145,13 @@ class TestSpanSchema:
         import json
         sinks, layer, attrs, reads = schema.SPANS[name]
         bench = json.load(open(os.path.join(REPO, "BENCHMARK.json")))
-        assert sinks in (schema.BOTH, schema.RING, schema.PHASE)
+        assert sinks in (schema.BOTH, schema.RING, schema.PHASE, schema.PAUSE_GC,
+                         schema.PAUSE_STALL)
         assert layer in {m["layer"] for m in bench["per_layer"]}
         assert reads and isinstance(attrs, tuple)
         assert (sinks == schema.PHASE) == name.startswith("setup.")
+        # the second kept list: host pauses (PR 55)
+        assert (sinks in (schema.PAUSE_GC, schema.PAUSE_STALL)) == name.startswith("host.")
         perf = open(os.path.join(REPO, "PERF.md")).read()
         assert f"`{name}`" in perf, f"PERF.md section 3 has no row for {name}"
 
@@ -250,9 +253,9 @@ TABLE_B = {
     "serving.suffix_prefill": ("request_id", "bucket", "tokens", "prefix_len"),
     "serving.decode_chunk": ("chunk", "active_slots", "request_ids", "slot_steps_run",
                              "attn_rows", "tokens_kept", "deliveries",
-                             "stalled_deliveries"),
+                             "stalled_deliveries", "fetch_wait_ms"),
     "serving.place_inputs": ("program",),
-    "serving.dispatch": ("program",),
+    "serving.dispatch": ("program", "seq"),
     "serving.fetch": ("program",),
     "serving.scatter_prefill": (),
     "serving.prefix_insert": (),
