@@ -26,7 +26,10 @@ tile and every grid block below it is unmasked arithmetic.
 Backward recomputes probabilities blockwise from the saved logsumexp (dq kernel gridded
 over q blocks × kv blocks, dk/dv kernel over kv blocks × q blocks, the latter forming its
 scores keys-by-queries so no tile is transposed) — no stored attention matrix, matching
-the activation-memory profile that makes long sequences feasible.
+the activation-memory profile that makes long sequences feasible. ``delta``, the rows'
+sums of ``do * o`` a head, is made in no XLA op: the dq kernel reads ``o`` beside ``do``
+under q's blocks, forms the float32 product and sum strip by strip, uses the column and
+writes it out as its second result; the dk/dv kernel, called after it, reads that array.
 
 Operand layout. A ``(b, t, h, d)`` array is, bit for bit, ``(b, t, h*d)``: the kernels take
 q, k, v (and write o, dq, dk, dv) in that flat layout, where the projection before them wrote
@@ -45,7 +48,9 @@ Which head shapes take which branch (:func:`heads_a_block`):
 
 :func:`flash_attention_qkv` reads a fused projection ``(b, t, 3*h*d)`` = q | k | v as ONE
 operand: the same index maps with a lane offset (k at group ``G + g``, v at ``2G + g``), so
-no split copies the three out. ``lse`` and ``delta`` stay rows-along-lanes, a row set a head.
+no split copies the three out. ``lse`` (the forward's result, spread over 8 sublanes by the
+one XLA op in front of the backward kernels) and ``delta`` (the dq kernel's, written that
+way) are ``(rows, heads, q blocks, 8, bq)``: rows along lanes, a row set a head.
 
 On CPU (tests) kernels run in interpreter mode automatically.
 """
@@ -483,11 +488,17 @@ def _summed(out_refs, scratch, scales, step, n_steps, walk):
         store(0, out_refs[0].shape[1], *(scr[...] for scr in scratch))
 
 
+def _head_row_sums(x, hh, d):
+    """Sums of ``x`` (rows, lanes) over head ``hh``'s ``d`` lanes, a (rows, 1) column."""
+    return _head_lanes(x, hh, d).sum(axis=-1, keepdims=True)
+
+
 def _bwd_dq_kernel(*refs, d, scale, causal, use_alibi, nq, nk, bq, bk, mask_block=1):
-    q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref = refs[:6]
+    q_ref, k_ref, v_ref, do_ref, o_ref, lse_ref = refs[:6]
     slopes_ref = refs[6] if use_alibi else None
-    dq_ref, *scratch = refs[7 if use_alibi else 6:]
+    dq_ref, delta_ref, *scratch = refs[7 if use_alibi else 6:]
     width = dq_ref.shape[-1]
+    heads = range(width // d)
     j = pl.program_id(2)
     kb = pl.program_id(3)
 
@@ -496,13 +507,15 @@ def _bwd_dq_kernel(*refs, d, scale, causal, use_alibi, nq, nk, bq, bk, mask_bloc
         # same operands, same matmul policy
         q = q_ref[0, r0:r0 + nr, :]
         do = do_ref[0, r0:r0 + nr, :]
-        dqs = []
-        for hh in range(width // d):
+        # delta, the rows' sums of do * o a head: float32 product, float32 sum
+        do_o = do.astype(jnp.float32) * o_ref[0, r0:r0 + nr, :].astype(jnp.float32)
+        dqs, deltas = [], []
+        for hh in heads:
             slope = slopes_ref[hh, 0, 0] if use_alibi else None
             qh = _head_lanes(q, hh, d)
             doh = _head_lanes(do, hh, d)
             lse = lse_ref[0, hh, 0, 0, r0:r0 + nr][:, None]
-            delta = delta_ref[0, hh, 0, 0, r0:r0 + nr][:, None]
+            delta = _head_row_sums(do_o, hh, d)
             dq = None
             for c0, nc, masked in parts:
                 k = k_ref[0, c0:c0 + nc, :]
@@ -516,10 +529,23 @@ def _bwd_dq_kernel(*refs, d, scale, causal, use_alibi, nq, nk, bq, bk, mask_bloc
                 part = _dot(ds, k, (1, 0))                     # every head's lanes of k
                 dq = part if dq is None else dq + part
             dqs.append(dq)
-        return (_by_head(dqs, d, width),)
+            deltas.append(delta)
+        return _by_head(dqs, d, width), deltas
 
-    _summed((dq_ref,), scratch, (scale,), kb, nk, lambda commit: _walk(
-        causal, j, kb, nq, bq, bk, False, BWD_STRIP, strip, commit))
+    def walk(commit):
+        def take(r0, nr, dq, deltas):
+            # delta goes out for the dkv kernel as the forward stores lse: rows along
+            # lanes, 8 duplicate sublanes. Its block does not move along the kv axis,
+            # and every kv block a q block visits (its first is block 0, causal or
+            # not) writes the same values
+            for hh in heads:
+                delta_ref[0, hh, 0, :, r0:r0 + nr] = jnp.broadcast_to(
+                    deltas[hh][:, 0][None, :], (8, nr))
+            commit(r0, nr, dq)
+
+        _walk(causal, j, kb, nq, bq, bk, False, BWD_STRIP, strip, take)
+
+    _summed((dq_ref,), scratch, (scale,), kb, nk, walk)
 
 
 def _bwd_dkv_kernel(*refs, d, scale, causal, use_alibi, nq, bq, bk, mask_block=1):
@@ -568,25 +594,24 @@ def _bwd_dkv_kernel(*refs, d, scale, causal, use_alibi, nq, bq, bk, mask_block=1
 def _flash_bwd(q, k, v, o, lse, do, slopes, fused, d, scale, causal, block_q,
                block_k, mask_block=1):
     """Operands as :func:`_flash_fwd`'s; ``o``/``do`` (rows, t, lanes), ``lse`` (rows,
-    heads, t). Returns dq, dk, dv, each (rows, t, lanes)."""
+    heads, t). Returns dq, dk, dv, each (rows, t, lanes). ``flash_bwd_dq`` reads ``o``
+    beside ``do``, makes ``delta`` (the rows' sums of ``do * o`` a head) from the two and
+    hands it to ``flash_bwd_dkv`` as its second result: no XLA op stands between the
+    forward's residuals and the two kernels but ``lse``'s broadcast over 8 sublanes."""
     rows, t, lanes = o.shape
     width, groups = _lane_groups(lanes, d)
     hpb, heads = width // d, lanes // d
     bq, bk = _block_sizes(t, block_q, block_k)
     nq, nk = t // bq, t // bk
     use_alibi = slopes is not None
-    delta = jnp.sum((do.astype(jnp.float32) * o.astype(jnp.float32)).reshape(
-        rows, t, heads, d), axis=-1).transpose(0, 2, 1)          # (rows, heads, t)
     stat_shape = (rows, heads, nq, 8, bq)
     lse_b = jnp.broadcast_to(lse.reshape(rows, heads, nq, 1, bq), stat_shape)
-    delta_b = jnp.broadcast_to(delta.reshape(rows, heads, nq, 1, bq), stat_shape)
     k_off, v_off = fused * groups, 2 * fused * groups
-    args = [q, k, v, do, lse_b, delta_b]
-    slopes_spec = []
+    slopes_spec, slopes_arg = [], []
     if use_alibi:
         slopes_spec = [pl.BlockSpec((hpb, 8, 128),
                                     _slopes_map(slopes.shape[0] * d // lanes))]
-        args.append(slopes)
+        slopes_arg = [slopes]
     params = dict(d=d, scale=scale, causal=causal, use_alibi=use_alibi, nq=nq, bq=bq,
                   bk=bk, mask_block=mask_block)
     q_block, k_block = (1, bq, width), (1, bk, width)
@@ -594,7 +619,7 @@ def _flash_bwd(q, k, v, o, lse, do, slopes, fused, d, scale, causal, block_q,
     sds = jax.ShapeDtypeStruct((rows, t, lanes), o.dtype)
     compiler_params = _compiler_params(hpb)
 
-    dq = pl.pallas_call(
+    dq, delta = pl.pallas_call(
         functools.partial(_bwd_dq_kernel, nk=nk, **params),
         grid=(rows, groups, nq, nk),
         in_specs=[
@@ -602,16 +627,17 @@ def _flash_bwd(q, k, v, o, lse, do, slopes, fused, d, scale, causal, block_q,
             pl.BlockSpec(k_block, _k_index_map(causal, bq, bk, k_off)),
             pl.BlockSpec(k_block, _k_index_map(causal, bq, bk, v_off)),
             pl.BlockSpec(q_block, _outer_map()),
-            pl.BlockSpec(stat_block, _stat_map),
+            pl.BlockSpec(q_block, _outer_map()),
             pl.BlockSpec(stat_block, _stat_map),
         ] + slopes_spec,
-        out_specs=pl.BlockSpec(q_block, _outer_map()),
-        out_shape=sds,
+        out_specs=[pl.BlockSpec(q_block, _outer_map()),
+                   pl.BlockSpec(stat_block, _stat_map)],
+        out_shape=[sds, jax.ShapeDtypeStruct(stat_shape, jnp.float32)],
         scratch_shapes=[] if nk == 1 else [pltpu.VMEM((bq, width), jnp.float32)],
         compiler_params=compiler_params,
         name="flash_bwd_dq",
         interpret=_interpret(),
-    )(*args)
+    )(q, k, v, do, o, lse_b, *slopes_arg)
 
     stat_index = _q_index_map(causal, bq, bk)
     dk, dv = pl.pallas_call(
@@ -631,7 +657,7 @@ def _flash_bwd(q, k, v, o, lse, do, slopes, fused, d, scale, causal, block_q,
         compiler_params=compiler_params,
         name="flash_bwd_dkv",
         interpret=_interpret(),
-    )(*args)
+    )(q, k, v, do, lse_b, delta, *slopes_arg)
     return dq, dk, dv
 
 
@@ -670,7 +696,15 @@ def _make_core(fused: bool):
                            slopes if use_alibi else None, fused, d, scale, causal,
                            block_q, block_k, mask_block)
         if fused:
-            grads = (jnp.concatenate(grads, axis=-1),)
+            # dq | dk | dv as the sum of the three padded to the projection's lanes: XLA
+            # fuses that into the operand reads of the projection's backward, where a
+            # concatenate whose operands are all results of multi-result kernel calls
+            # (flash_bwd_dq's second is delta) is written out first, 1 ms a step of the
+            # 125M's at (24, 1024, 2304) (PERF.md section 6, PR 56)
+            lanes = grads[0].shape[-1]
+            dq, dk, dv = (jnp.pad(g, ((0, 0), (0, 0), (i * lanes, (2 - i) * lanes)))
+                          for i, g in enumerate(grads))
+            grads = (dq + dk + dv,)
         # alibi slopes are a fixed schedule, not trained — zero cotangent
         return (*grads, jnp.zeros_like(slopes))
 

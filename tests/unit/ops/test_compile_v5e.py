@@ -448,6 +448,11 @@ def test_the_125m_gradient_holds_no_loop_and_no_stack_of_saved_activations(
     assert text.count("tpu_custom_call") == 36
     assert all(text.count(kernel) >= 12
                for kernel in ("flash_fwd", "flash_bwd_dq", "flash_bwd_dkv"))
+    # delta is made inside flash_bwd_dq: no float32 do * o re-laid for a reduce_sum;
+    # and dq | dk | dv are joined inside their consumers, not written out by three
+    # in-place updates of a (24, 1024, 2304) buffer a layer
+    assert "f32[24,1024,768]{1,2,0" not in text
+    assert not re.findall(r"bf16\[24,1024,2304\]\S* dynamic-update-slice\(", text)
     looped, looped_writes, looped_temp = _gpt2_125m_gradient(one_chip, monkeypatch, unrolled=False)
     assert looped.count(" while(") == 2 and len(looped_writes) >= 5
     assert looped.count("tpu_custom_call") == 3
@@ -491,8 +496,9 @@ def _tiles(rows, width=128):
 _STATS = (1, 2, 1, 8, 1024)
 _GRADIENT = [
     ("flash_fwd", (24, 6, 1, 1), 3, 2, (_tiles(1024),) * 4 + (_STATS,), (), "ppaa"),
-    ("flash_bwd_dq", (24, 6, 1, 1), 6, 1,
-     (_tiles(1024),) * 4 + (_STATS,) * 2 + (_tiles(1024),), (), "ppaa"),
+    # q, k, v, do, o and lse in; dq and delta out (PR 56: delta is made here)
+    ("flash_bwd_dq", (24, 6, 1, 1), 6, 2,
+     (_tiles(1024),) * 5 + (_STATS,) + (_tiles(1024), _STATS), (), "ppaa"),
     ("flash_bwd_dkv", (24, 6, 1, 1), 6, 2,
      (_tiles(1024),) * 4 + (_STATS,) * 2 + (_tiles(1024),) * 2, (), "ppaa")]
 _CALLS = {

@@ -145,14 +145,11 @@ def _moves_heads(eqn):
 
 
 def _cuts_the_projection(eqn):
-    """A split, slice or dynamic_slice of a (.., 3*n_embd) array, or the pad /
-    concatenate that its transpose would be, outside the kernels."""
-    wide = 3 * 128
+    """A split, slice or dynamic_slice of a (.., 3*n_embd) array outside the
+    kernels."""
     if eqn.primitive.name in ("split", "slice", "dynamic_slice") and \
-            eqn.invars[0].aval.shape[-1:] == (wide,):
+            eqn.invars[0].aval.shape[-1:] == (3 * 128,):
         return eqn.primitive.name
-    if eqn.primitive.name == "pad" and eqn.outvars[0].aval.shape[-1:] == (wide,):
-        return "pad"
     return None
 
 
@@ -167,9 +164,54 @@ def test_the_gradient_of_a_fused_layer_moves_no_head_and_cuts_no_projection(scan
                                    FLASH_QKV_NAME: bodies}
     assert not _count(jaxpr, _moves_heads)
     assert not _count(jaxpr, _cuts_the_projection)
-    # dq | dk | dv go back to the projection as one concatenate a layer
-    joins = _count(jaxpr, lambda e: _wide(e, "concatenate"))
-    assert joins == {"concatenate": bodies}
+    # dq | dk | dv go back to the projection as the sum of the three, each padded to
+    # its lanes (what XLA fuses into the projection's backward: PR 56), and no
+    # concatenate; the pads are the join's three and no slice's transpose
+    joins = _count(jaxpr, lambda e: _wide(e, "pad") or _wide(e, "concatenate"))
+    assert joins == {"pad": 3 * bodies}
+
+
+def _makes_delta_in_xla(eqn):
+    """``delta`` made outside the kernels: a product of two (b, t, h*d) arrays
+    (``do * o``), any array with the heads apart (b, t, h, d), or a ``reduce_sum``
+    over such an array's trailing axis."""
+    flat, heads = (BATCH, SEQ, 128), (BATCH, SEQ, 2, 64)
+    if eqn.primitive.name == "mul" and all(
+            getattr(v.aval, "shape", None) == flat for v in eqn.invars):
+        return "mul"
+    if eqn.primitive.name == "reduce_sum" and eqn.invars[0].aval.shape == heads \
+            and tuple(eqn.params["axes"]) == (3,):
+        return "reduce_sum"
+    if any(v.aval.shape == heads for v in eqn.outvars):
+        return "heads apart"
+    return None
+
+
+@pytest.mark.parametrize("scan_layers", [True, False], ids=["scanned", "unrolled"])
+def test_the_gradient_of_a_fused_layer_makes_delta_in_no_xla_op(scan_layers):
+    """``flash_bwd_dq`` makes ``delta`` from ``do`` and the kept ``o`` and hands it to
+    ``flash_bwd_dkv``. Outside the ``pallas_call``s (whose bodies work on 2-D tiles,
+    which the matcher's shapes leave out) the gradient of the fused attention
+    multiplies no two (b, t, h*d) arrays, and a whole layer's (whose norms do
+    multiply such arrays) takes no head apart and sums over no head's lanes; the
+    matcher finds all three in the XLA form of the same lines."""
+    from deepspeed_tpu.ops.attention.flash import flash_attention_qkv
+    x = jnp.zeros((BATCH, SEQ, 128), jnp.float32)
+    qkv, w = jnp.zeros((BATCH, SEQ, 3 * 128), jnp.float32), jnp.zeros((128, 128))
+    attention = jax.make_jaxpr(jax.grad(
+        lambda qkv, w: (flash_attention_qkv(qkv, 2) @ w).sum()))(qkv, w)
+    assert _count(attention, _kernel) == {"flash_fwd": 1, "flash_bwd_dq": 1,
+                                          "flash_bwd_dkv": 1}
+    assert not _count(attention, _makes_delta_in_xla)
+    loss, params = _wide_model_loss(scan_layers=scan_layers)
+    layer = _count(jax.make_jaxpr(jax.grad(loss))(params), _makes_delta_in_xla)
+    assert set(layer) == {"mul"}
+
+    def xla_delta(do, o):
+        return jnp.sum((do * o).reshape(BATCH, SEQ, 2, 64), axis=-1)
+
+    assert _count(jax.make_jaxpr(xla_delta)(x, x), _makes_delta_in_xla) == {
+        "mul": 1, "reduce_sum": 1, "heads apart": 1}
 
 
 def _wide(eqn, name):
@@ -189,7 +231,8 @@ def test_the_backward_of_a_fused_layer_adds_no_bias_and_multiplies_no_projection
     loss, params = _wide_model_loss(scan_layers=scan_layers)
     jaxpr = jax.make_jaxpr(jax.grad(loss))(params)
     bodies = 1 if scan_layers else LAYERS
-    assert _count(jaxpr, lambda e: _wide(e, "add")) == {"add": bodies}
+    # one add of the bias a layer, beside the two that join dq | dk | dv
+    assert _count(jaxpr, lambda e: _wide(e, "add")) == {"add": 3 * bodies}
     # c_attn's forward product and no second one (its two gradients are 128 wide)
     assert _count(jaxpr, lambda e: _wide(e, "dot_general")) == {"dot_general": bodies}
     sums = _count(jaxpr, lambda e: "sum" if e.primitive.name == "reduce_sum"
