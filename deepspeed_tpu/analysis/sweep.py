@@ -147,7 +147,7 @@ def serving_lane(report: Report) -> None:
                                  target="kv_pool.scatter"))
     report.add(donation_findings(ex.pool._cow_fn, (ex.pool.caches, 1, 2),
                                  target="kv_pool.cow"))
-    report.add(donation_findings(kvp._state_zero_jit(), (ex.pool.caches, 0),
+    report.add(donation_findings(kvp._state_zero_jit(ex.pool.keeps), (ex.pool.caches, 0),
                                  target="kv_pool.state_zero_fill"))
 
     # loop-invariance: dequant hoisted out of BOTH decode bodies (int8 engine)

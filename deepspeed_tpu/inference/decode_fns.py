@@ -294,14 +294,25 @@ def build_decode_loop(module, dequant, select, gen_cap: int, overlap=None):
     return decode_loop
 
 
-def _copy_back(caches, dense, page_table, lens_in, lens, span: int, kv_cap: int):
+def _dense_view(keeps, caches, page_table, rows: int):
+    """The caches a chunk's steps run on: the dense per-slot view (``rows``
+    rows) of the layers that keep keys and values in pages (``keeps``:
+    ``CausalLMConfig.layer_keeps``), every other layer's cache as it is."""
+    from ..ops.paged_attention import gather_kv_dense
+    return [dict(zip(("k", "v"),
+                     gather_kv_dense(c["k"], c["v"], page_table, rows)))
+            if keep == "kv" else c for keep, c in zip(keeps, caches)]
+
+
+def _copy_back(keeps, caches, dense, page_table, lens_in, lens, span: int,
+               kv_cap: int):
     """The end of a chunk that ran on the dense view: the rows a slot
     appended or committed in it, ``[lens_in, lens)`` (at most ``span``, below
     ``kv_cap``), go from the view into the slot's pages as slab writes
     (:func:`~deepspeed_tpu.ops.paged_attention.write_view_rows`); a layer
     with per-slot state hands on the loop's carry, which IS its state."""
     from ..ops.paged_attention import write_view_rows
-    paged = [i for i, c in enumerate(caches) if "k" in c]
+    paged = [i for i, keep in enumerate(keeps) if keep == "kv"]
     with scope("kv.copy_back"):
         written = write_view_rows([caches[i] for i in paged],
                                   [dense[i] for i in paged], page_table,
@@ -356,7 +367,7 @@ def build_paged_decode_chunk(module, dequant, slot_select, chunk_size: int,
     source of truth across chunks. A per-step gather cost S·cap
     bytes every step; per-chunk it is 1/K of that. ``kv_cap`` bounds the dense
     view at exactly ``cap`` rows."""
-    from ..ops.paged_attention import gather_kv_dense
+    keeps = module.config.layer_keeps
     stats0 = (jnp.zeros((2,), jnp.int32),) if with_stats else ()
 
     def decode_chunk(params, toks, caches, page_table, lens, active, remaining,
@@ -372,9 +383,7 @@ def build_paged_decode_chunk(module, dequant, slot_select, chunk_size: int,
         # the end of the chunk — the pages leave/enter the loop nowhere
         lens_in = lens
         with scope("kv.gather"):
-            dense = [dict(zip(("k", "v"),
-                              gather_kv_dense(c["k"], c["v"], page_table, kv_cap)))
-                     if "k" in c else c for c in caches]
+            dense = _dense_view(keeps, caches, page_table, kv_cap)
 
         def step_model(toks, dense, lens):
             return apply_model(module, params, with_stats, toks,
@@ -387,7 +396,7 @@ def build_paged_decode_chunk(module, dequant, slot_select, chunk_size: int,
                 0, chunk_size, body,
                 (toks, dense, lens, active, remaining, steps, buf) + stats0)
         toks, dense, lens, active, remaining, steps, buf = out[:7]
-        new_caches = _copy_back(caches, dense, page_table, lens_in, lens,
+        new_caches = _copy_back(keeps, caches, dense, page_table, lens_in, lens,
                                 chunk_size, kv_cap)
         return (buf, toks, new_caches, lens, active, remaining, steps) + out[7:]
 
@@ -563,8 +572,8 @@ def build_block_decode_chunk(module, dequant, slot_select, forwards: int,
     which are still ``masked``, and how many of them the prompt gave
     (``skip``); a block with nothing masked is committed, and the next one
     opened, by the next chunk's first forward."""
-    from ..ops.paged_attention import gather_kv_dense
     cfg = module.config
+    keeps = cfg.layer_keeps
     width = block_chunk_width(cfg, forwards)
     rows_view = block_view_rows(cfg, kv_cap)
     stats0 = (jnp.zeros((2,), jnp.int32),) if with_stats else ()
@@ -574,14 +583,13 @@ def build_block_decode_chunk(module, dequant, slot_select, forwards: int,
         params = dequant(params)
         S = blk.shape[0]
         buf = jnp.zeros((S, width), jnp.int32)
-        ps = next(c["k"].shape[2] for c in caches if "k" in c)
+        ps = next(c["k"].shape[2] for keep, c in zip(keeps, caches)
+                  if keep == "kv")
         lens_in = lens
         with scope("kv.gather"):
             # the null page (0) behind every slot's own: the view's spare rows
             table = jnp.pad(page_table, ((0, 0), (0, -(-(rows_view - kv_cap) // ps))))
-            dense = [dict(zip(("k", "v"),
-                              gather_kv_dense(c["k"], c["v"], table, rows_view)))
-                     if "k" in c else c for c in caches]
+            dense = _dense_view(keeps, caches, table, rows_view)
         body = _block_body(cfg, _block_step_model(module, params, with_stats),
                            slot_select, base_key, seeds, eos_ids, steps, width)
         with overlap_scope(overlap):
@@ -590,8 +598,8 @@ def build_block_decode_chunk(module, dequant, slot_select, forwards: int,
                 (blk, masked, skip, dense, lens, active, remaining, steps, buf,
                  jnp.zeros((3,), jnp.int32)) + stats0)
         blk, masked, skip, dense, lens, active, remaining, steps, buf, counts = out[:10]
-        new_caches = _copy_back(caches, dense, page_table, lens_in, lens, width,
-                                kv_cap)
+        new_caches = _copy_back(keeps, caches, dense, page_table, lens_in, lens,
+                                width, kv_cap)
         return (buf, blk, masked, skip, new_caches, lens, active, remaining, steps,
                 counts) + out[10:]
 

@@ -53,15 +53,16 @@ NULL_PAGE = 0      # reserved sentinel: pads every table row; rows it could
 # each time — measured at ~0.15 s per pool, which dominated short serving
 # runs. Geometry (page size, table width, layer count) is recovered from the
 # argument shapes, so one compiled mover serves every same-shaped pool.
+# ``keeps`` is ``CausalLMConfig.layer_keeps``: what each layer's cache is.
 @functools.lru_cache(maxsize=None)
-def _paged_scatter_jit():
+def _paged_scatter_jit(keeps):
     def scatter(caches, one, tbl, slot):
         # write a prefill's dense batch-1 cache into the slot's pages; rows
         # beyond cap pad with zeros into the (dead) null page. A layer's
-        # per-slot state (no "k") is written whole into row ``slot``.
+        # per-slot state is written whole into row ``slot``.
         out = []
-        for c, o in zip(caches, one):
-            if "k" not in c:
+        for keep, c, o in zip(keeps, caches, one):
+            if keep != "kv":
                 out.append({key: c[key].at[slot].set(o[key][0].astype(c[key].dtype))
                             for key in c})
                 continue
@@ -72,20 +73,21 @@ def _paged_scatter_jit():
 
 
 @functools.lru_cache(maxsize=None)
-def _state_zero_jit():
+def _state_zero_jit(keeps):
     def zero_fill(caches, slot):
-        return [c if "k" in c else
-                {key: c[key].at[slot].set(0.0) for key in c} for c in caches]
+        return [c if keep == "kv" else
+                {key: c[key].at[slot].set(0.0) for key in c}
+                for keep, c in zip(keeps, caches)]
 
     return jax.jit(zero_fill, donate_argnums=(0,))
 
 
 @functools.lru_cache(maxsize=None)
-def _paged_cow_jit():
+def _paged_cow_jit(keeps):
     def cow(caches, src, dst):
         return [{"k": c["k"].at[dst].set(c["k"][src]),
-                 "v": c["v"].at[dst].set(c["v"][src])} if "k" in c else c
-                for c in caches]
+                 "v": c["v"].at[dst].set(c["v"][src])} if keep == "kv" else c
+                for keep, c in zip(keeps, caches)]
 
     return jax.jit(cow, donate_argnums=(0,))
 
@@ -149,9 +151,11 @@ class PagedKVPool:
         # state (bound to the slot, not to pages: it does not grow with the
         # sequence), nothing for the rest
         self.caches = init_cache(cfg, self.slots, dtype=dtype, kv_shape=shape)
-        self.kv_layers = sum(1 for c in self.caches if "k" in c)
-        self.state_nbytes = sum(int(a.nbytes) for c in self.caches
-                                if "k" not in c for a in c.values())
+        self.keeps = keeps = cfg.layer_keeps
+        self.kv_layers = sum(1 for keep in keeps if keep == "kv")
+        self.state_nbytes = sum(int(a.nbytes)
+                                for keep, c in zip(keeps, self.caches)
+                                if keep != "kv" for a in c.values())
         self.page_nbytes = 2 * self.kv_layers * cfg.kv_heads * ps * \
             cfg.head_dim * jnp.dtype(dtype).itemsize
         # host allocator state
@@ -167,8 +171,8 @@ class PagedKVPool:
         # its buffers cannot alias any page); the jitted movers are
         # module-level shape-keyed singletons — rebuilding a pool after a
         # failure (or per serving lane) must not re-pay XLA compiles
-        self._scatter_fn = _paged_scatter_jit()
-        self._cow_fn = _paged_cow_jit()
+        self._scatter_fn = _paged_scatter_jit(keeps)
+        self._cow_fn = _paged_cow_jit(keeps)
 
     # --------------------------------------------------------------- allocator
     def pages_for(self, tokens: int) -> int:
@@ -264,7 +268,7 @@ class PagedKVPool:
             # cleared here (and written whole again at the next admission)
             with get_tracer().span("serving.clear_state", slot=int(slot),
                                    state_bytes=self.state_nbytes // self.slots):
-                self._move(_state_zero_jit(), np.int32(slot))
+                self._move(_state_zero_jit(self.keeps), np.int32(slot))
 
     def _decref(self, page: int) -> None:
         if page == NULL_PAGE:

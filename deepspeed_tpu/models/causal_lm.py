@@ -213,6 +213,13 @@ class CausalLMConfig:
         return "".join(self.layer_kind(i) for i in range(self.n_layer))
 
     @property
+    def layer_keeps(self) -> Tuple[str, ...]:
+        """What each layer keeps between a sequence's tokens, a layer an
+        entry (:attr:`LayerKind.keeps`): the one question the serve programs
+        and the pool's movers ask of a layer's cache."""
+        return tuple(LAYER_KINDS[k].keeps for k in self.layer_kinds)
+
+    @property
     def kv_every_layer(self) -> bool:
         """Every layer keeps keys and values (what the prefix and slab
         movers, a suffix prefill and a speculative verify read)."""
