@@ -296,12 +296,13 @@ def build_decode_loop(module, dequant, select, gen_cap: int, overlap=None):
 
 def _dense_view(keeps, caches, page_table, rows: int):
     """The caches a chunk's steps run on: the dense per-slot view (``rows``
-    rows) of the layers that keep keys and values in pages (``keeps``:
-    ``CausalLMConfig.layer_keeps``), every other layer's cache as it is."""
-    from ..ops.paged_attention import gather_kv_dense
-    return [dict(zip(("k", "v"),
-                     gather_kv_dense(c["k"], c["v"], page_table, rows)))
-            if keep == "kv" else c for keep, c in zip(keeps, caches)]
+    rows) of the layers that keep rows in pages (``keeps``:
+    ``CausalLMConfig.layer_keeps``; keys and values, or a latent layer's one
+    row a token), every other layer's cache as it is."""
+    from ..models.causal_lm import PAGED
+    from ..ops.paged_attention import gather_pages_dense
+    return [dict(zip(c, gather_pages_dense(tuple(c.values()), page_table, rows)))
+            if keep in PAGED else c for keep, c in zip(keeps, caches)]
 
 
 def _copy_back(keeps, caches, dense, page_table, lens_in, lens, span: int,
@@ -311,8 +312,9 @@ def _copy_back(keeps, caches, dense, page_table, lens_in, lens, span: int,
     ``kv_cap``), go from the view into the slot's pages as slab writes
     (:func:`~deepspeed_tpu.ops.paged_attention.write_view_rows`); a layer
     with per-slot state hands on the loop's carry, which IS its state."""
+    from ..models.causal_lm import PAGED
     from ..ops.paged_attention import write_view_rows
-    paged = [i for i, keep in enumerate(keeps) if keep == "kv"]
+    paged = [i for i, keep in enumerate(keeps) if keep in PAGED]
     with scope("kv.copy_back"):
         written = write_view_rows([caches[i] for i in paged],
                                   [dense[i] for i in paged], page_table,

@@ -108,6 +108,12 @@ class InferenceEngine:
             if params is None:
                 with get_tracer().phase("setup.init_params"):
                     params = self._init_params_segmented(cfg, seed)
+                if cfg.home_random_routers:
+                    from ..moe.latent_moe import home_random_routers
+                    with get_tracer().phase("setup.balance_experts"):
+                        params = home_random_routers(cfg, params, seed)
+                    log_dist("stand-in weights: every token id has its home "
+                             "experts (home_random_routers)", ranks=[0])
                 if cfg.level_random_experts:
                     from ..moe.latent_moe import level_expert_load
                     with get_tracer().phase("setup.balance_experts"):

@@ -37,7 +37,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from ...models.causal_lm import init_cache
+from ...models.causal_lm import PAGED, init_cache
 from ...observability.trace import get_tracer
 from ...ops.paged_attention import (heads_per_row, pages_to_dense,
                                     write_dense_pages)
@@ -62,7 +62,7 @@ def _paged_scatter_jit(keeps):
         # per-slot state is written whole into row ``slot``.
         out = []
         for keep, c, o in zip(keeps, caches, one):
-            if keep != "kv":
+            if keep not in PAGED:
                 out.append({key: c[key].at[slot].set(o[key][0].astype(c[key].dtype))
                             for key in c})
                 continue
@@ -75,7 +75,7 @@ def _paged_scatter_jit(keeps):
 @functools.lru_cache(maxsize=None)
 def _state_zero_jit(keeps):
     def zero_fill(caches, slot):
-        return [c if keep == "kv" else
+        return [c if keep in PAGED else
                 {key: c[key].at[slot].set(0.0) for key in c}
                 for keep, c in zip(keeps, caches)]
 
@@ -85,9 +85,8 @@ def _state_zero_jit(keeps):
 @functools.lru_cache(maxsize=None)
 def _paged_cow_jit(keeps):
     def cow(caches, src, dst):
-        return [{"k": c["k"].at[dst].set(c["k"][src]),
-                 "v": c["v"].at[dst].set(c["v"][src])} if keep == "kv" else c
-                for keep, c in zip(keeps, caches)]
+        return [{key: c[key].at[dst].set(c[key][src]) for key in c}
+                if keep in PAGED else c for keep, c in zip(keeps, caches)]
 
     return jax.jit(cow, donate_argnums=(0,))
 
@@ -152,12 +151,19 @@ class PagedKVPool:
         # sequence), nothing for the rest
         self.caches = init_cache(cfg, self.slots, dtype=dtype, kv_shape=shape)
         self.keeps = keeps = cfg.layer_keeps
-        self.kv_layers = sum(1 for keep in keeps if keep == "kv")
+        self.kv_layers = sum(1 for keep in keeps if keep in PAGED)
         self.state_nbytes = sum(int(a.nbytes)
                                 for keep, c in zip(keeps, self.caches)
-                                if keep != "kv" for a in c.values())
-        self.page_nbytes = 2 * self.kv_layers * cfg.kv_heads * ps * \
-            cfg.head_dim * jnp.dtype(dtype).itemsize
+                                if keep not in PAGED for a in c.values())
+        # a page of every paged layer: keys and values (2 x kv_heads x
+        # head_dim lanes a token), or a latent layer's one row a token
+        self.page_nbytes = sum(int(a.nbytes) // P
+                               for keep, c in zip(keeps, self.caches)
+                               if keep in PAGED for a in c.values())
+        # bytes a token a layer as a latent layer stores them (0: none does)
+        self.latent_row_nbytes = next(
+            (int(c["k"].shape[3]) * c["k"].dtype.itemsize
+             for keep, c in zip(keeps, self.caches) if keep == "latent"), 0)
         # host allocator state
         self.page_table = np.full((self.slots, mp), NULL_PAGE, np.int32)
         self._free_slots: List[int] = list(range(self.slots))
@@ -457,4 +463,5 @@ class PagedKVPool:
             "total_pages": float(self.total_pages - 1),
             "page_size": float(self.page_size),
             "state_bytes": float(self.state_nbytes),
+            "latent_row_bytes": float(self.latent_row_nbytes),
         }

@@ -235,6 +235,11 @@ class ContinuousBatchingScheduler:
                 why = (f"the per-slot state of its {' and '.join(stateful)} "
                        "layers is overwritten by every token and was not kept "
                        "(needs state snapshots)")
+            elif engine.model_config.latent_layers:
+                why = ("its latent-attention layers keep one latent row a "
+                       "token, which only the one-token decode attends (in "
+                       "absorbed form): a hit's suffix prefill and the verify "
+                       "round have no such form yet")
             else:
                 why = ("some of its layers keep no keys and values (expert "
                        "layers), which the prefix and slab movers read from "

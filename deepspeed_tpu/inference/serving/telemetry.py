@@ -22,6 +22,7 @@ parentheses):
   ``serving/deliveries_total``, ``serving/deliveries_stalled_total``,
   ``serving/moe_assignments_total``, ``serving/moe_experts_touched_total``
   (expert layers only), ``serving/ssm_state_bytes`` (layers with a per-slot state only),
+  ``serving/kv_latent_row_bytes`` (latent-attention layers only),
   ``serving/block_forwards_total``, ``serving/blocks_committed_total``,
   ``serving/positions_unmasked_total``, ``serving/blocks_merged_total`` (a model
   that generates by diffusion over blocks only) — per
@@ -191,6 +192,9 @@ class ServingTelemetry:
             if paged_stats.get("state_bytes"):
                 ev += [("serving/ssm_state_bytes",
                         float(paged_stats["state_bytes"]), self._tick)]
+            if paged_stats.get("latent_row_bytes"):
+                ev += [("serving/kv_latent_row_bytes",
+                        float(paged_stats["latent_row_bytes"]), self._tick)]
         if self.moe_assignments:
             ev += [("serving/moe_assignments_total",
                     float(self.moe_assignments), self._tick),

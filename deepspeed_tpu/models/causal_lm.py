@@ -26,7 +26,9 @@ from jax.sharding import PartitionSpec as P
 from ..observability import scope
 from ..ops.attention.decode import (decode_attention, decode_attention_live,
                                     pack_queries, unpack_outputs)
-from ..ops.paged_attention import heads_per_row, kv_rows
+from ..ops.attention.latent import (latent_decode_attention, rotate_pairs,
+                                    yarn_inv_freq, yarn_mscale)
+from ..ops.paged_attention import heads_per_row, kv_rows, latent_row_lanes
 from ..ops.transformer.attention import xla_attention
 from ..parallel.overlap import (RowParallelDense, chunked_expert_exchange,
                                 get_overlap_config, moe_overlap_chunks,
@@ -77,8 +79,9 @@ class CausalLMConfig:
     # "xla" = w[idx] gather + einsum (lets XLA pin small expert stacks in VMEM)
     moe_decode_impl: str = "pallas"
     # One MIXER a layer, chosen by a pattern string with a letter a layer
-    # (the keys of :data:`LAYER_KINDS`: "M" Mamba-2, "*" attention, "C" gated
-    # short convolution, "E" mixture of experts, "F" dense feed-forward);
+    # (the keys of :data:`LAYER_KINDS`: "M" Mamba-2, "*" attention, "L" latent
+    # attention, "C" gated short convolution, "E" mixture of experts, "F"
+    # dense feed-forward);
     # every layer is then ``x + mixer(norm(x))``. None = the classic layer
     # (attention, then feed-forward). The sizes below are read only by the
     # mixers the pattern names.
@@ -95,6 +98,19 @@ class CausalLMConfig:
     residual_multiplier: float = 1.0
     attention_multiplier: Optional[float] = None
     logits_scaling: float = 1.0
+    # LATENT attention ("L" layers; ``ops/attention/latent.py``): every head's
+    # keys and values are expanded from ONE normed latent of ``kv_lora_rank``
+    # a token; a query and a key have ``qk_nope_head_dim`` lanes without a
+    # position and ``qk_rope_head_dim`` rotated ones (the key's rotary part is
+    # one for all heads), a value ``v_head_dim``. There is no query latent.
+    # ``rope_yarn``: the numbers of a ``deepseek_yarn`` scaling, ``(factor,
+    # original_max_position_embeddings, beta_fast, beta_slow, mscale,
+    # mscale_all_dim)``, or None: plain rotary frequencies at ``rotary_base``
+    kv_lora_rank: int = 0
+    qk_nope_head_dim: int = 0
+    qk_rope_head_dim: int = 0
+    v_head_dim: int = 0
+    rope_yarn: Optional[Tuple[float, ...]] = None
     # what an "E" layer's experts are: "latent" (experts in a latent space
     # beside a shared expert, behind the sigmoid router with a selection
     # bias: ``moe/latent_moe.py``) or "gated" (SwiGLU experts of the full
@@ -129,6 +145,10 @@ class CausalLMConfig:
     # init, level each expert layer's load on random tokens by its selection
     # bias, as training leaves it (``moe/latent_moe.py: level_expert_load``)
     level_random_experts: bool = False
+    # RANDOM weights only: give every token id home experts that its routers
+    # find behind a margin no rounding crosses (``moe/latent_moe.py:
+    # home_random_routers``); False = routers as seeded
+    home_random_routers: bool = False
     # Rows a GREEDY ``InferenceEngine.generate`` decodes at, at least (the
     # rows it adds hold nothing and are cut off). XLA rounds a row of a matmul
     # differently by how many rows the matmul has, so a deployment that wants
@@ -235,6 +255,29 @@ class CausalLMConfig:
             if LAYER_KINDS[k].keeps == "state"))
 
     @property
+    def latent_layers(self) -> bool:
+        """Some layer keeps latent rows (one row a token for all heads)."""
+        return "latent" in self.layer_keeps
+
+    @property
+    def latent_row_width(self) -> int:
+        """A latent row as published: the latent and the shared rotary key."""
+        return self.kv_lora_rank + self.qk_rope_head_dim
+
+    def latent_rope(self) -> Tuple[np.ndarray, float]:
+        """The ``qk_rope_head_dim / 2`` rotary frequencies of a latent layer
+        and what its cos and sin are multiplied by (``m(factor, mscale) /
+        m(factor, mscale_all_dim)`` under ``deepseek_yarn``, else 1)."""
+        dim = self.qk_rope_head_dim
+        if self.rope_yarn is None:
+            return (1.0 / self.rotary_base
+                    ** (np.arange(0, dim, 2, dtype=np.float32) / dim)), 1.0
+        factor, original, fast, slow, mscale, mscale_all = self.rope_yarn
+        return (yarn_inv_freq(dim, self.rotary_base, factor, int(original), fast,
+                              slow),
+                yarn_mscale(factor, mscale) / yarn_mscale(factor, mscale_all))
+
+    @property
     def held_experts(self) -> Tuple[int, int]:
         return self.experts_held or (0, self.n_routed_experts)
 
@@ -260,6 +303,13 @@ class CausalLMConfig:
         ONE place every attention path of the model takes it from."""
         if self.attention_multiplier is not None:
             return float(self.attention_multiplier)
+        if self.kv_lora_rank:
+            # a latent layer's queries and keys are nope + rope lanes wide, and
+            # ``deepseek_yarn`` multiplies the scale by m(factor, mscale_all_dim)^2
+            scale = (self.qk_nope_head_dim + self.qk_rope_head_dim) ** -0.5
+            if self.rope_yarn is not None and self.rope_yarn[5]:
+                scale *= yarn_mscale(self.rope_yarn[0], self.rope_yarn[5]) ** 2
+            return float(scale)
         return 1.0 / float(np.sqrt(self.head_dim))
 
     @property
@@ -293,7 +343,9 @@ class CausalLMConfig:
 class LayerKind:
     """A row of :data:`LAYER_KINDS`: what a layer of that kind keeps between
     a sequence's tokens (``keeps``: ``"kv"`` keys and values, rows that grow
-    with the sequence and live in pages; ``"state"`` one array a slot that
+    with the sequence and live in pages; ``"latent"`` ONE row a token for all
+    heads, in pages likewise, ``{"k"}`` alone
+    (``ops/paged_attention.latent_row_lanes``); ``"state"`` one array a slot that
     every token overwrites, made by ``state(cfg, rows, dtype)``;
     ``"nothing"``, an empty cache), the parameters its mixer holds (``params(cfg)``, its norm
     not counted) and the method of :class:`MixerLayer` that runs it."""
@@ -308,6 +360,13 @@ def _attention_params(cfg: CausalLMConfig) -> int:
     d, q = cfg.n_embd, cfg.n_head * cfg.head_dim
     return (d * q + 2 * d * cfg.kv_heads * cfg.head_dim + q * d
             + (2 * cfg.head_dim if cfg.qk_norm else 0))
+
+
+def _latent_attention_params(cfg: CausalLMConfig) -> int:
+    d, h, rank = cfg.n_embd, cfg.n_head, cfg.kv_lora_rank
+    nope, rope, v = cfg.qk_nope_head_dim, cfg.qk_rope_head_dim, cfg.v_head_dim
+    return (d * h * (nope + rope) + d * (rank + rope) + rank
+            + rank * h * (nope + v) + h * v * d)
 
 
 def _mamba_params(cfg: CausalLMConfig) -> int:
@@ -360,6 +419,7 @@ def _short_conv_state(cfg: CausalLMConfig, batch_size: int, dtype) -> Dict:
 LAYER_KINDS: Dict[str, LayerKind] = {
     "A": LayerKind("classic", "kv"),
     "*": LayerKind("attention", "kv", _attention_params),
+    "L": LayerKind("latent-attention", "latent", _latent_attention_params),
     "M": LayerKind("state-space", "state", _mamba_params, _mamba_state, "_mamba"),
     "C": LayerKind("short-convolution", "state", _short_conv_params,
                    _short_conv_state, "_short_conv"),
@@ -367,6 +427,8 @@ LAYER_KINDS: Dict[str, LayerKind] = {
     "F": LayerKind("feed-forward", "nothing", _ffn_params, mixer="_ffn"),
 }
 PATTERN_KINDS = tuple(k for k in LAYER_KINDS if k != "A")
+#: the ``keeps`` whose rows live in the pool's pages, behind a slot's page table
+PAGED = ("kv", "latent")
 
 
 # ---------------------------------------------------------------- family constructors
@@ -632,6 +694,92 @@ def granite_hybrid_cfg(*, hidden_size, num_hidden_layers, layer_types, vocab_siz
         residual_multiplier=float(residual_multiplier),
         attention_multiplier=float(attention_multiplier),
         logits_scaling=float(logits_scaling),
+        experts_held=None if experts_held is None else tuple(experts_held), **kw)
+
+
+def sarvam_mla_cfg(*, hidden_size, num_hidden_layers, vocab_size,
+                   num_attention_heads, kv_lora_rank, qk_nope_head_dim,
+                   qk_rope_head_dim, v_head_dim, intermediate_size,
+                   moe_intermediate_size, num_experts, num_experts_per_tok,
+                   num_shared_experts=1, first_k_dense_replace=1,
+                   routed_scaling_factor=1.0, moe_router_enable_expert_bias=True,
+                   norm_topk_prob=True, use_qk_norm=True, rope_theta=10000.0,
+                   rope_scaling=None, rms_norm_eps=1e-6, hidden_act="silu",
+                   tie_word_embeddings=False, q_head_dim=None, head_dim=None,
+                   q_lora_rank=None, n_group=None, topk_group=None,
+                   max_position_embeddings=None, attn_implementation=None,
+                   default_theta=None, model_type="sarvam_mla",
+                   experts_held=None, **kw) -> CausalLMConfig:
+    """Sarvam's latent-attention mixtures (``model_type: sarvam_mla``;
+    DeepSeek-V2's layer without a query latent): the keywords are the
+    published config's. A published layer is attention and then a
+    feed-forward, each ``x + f(rmsnorm(x))``: here the pair of mixer layers
+    "L" (latent attention: ``num_attention_heads`` heads whose keys and
+    values are expanded from one normed latent of ``kv_lora_rank`` a token,
+    queries and keys of ``qk_nope_head_dim`` lanes and ``qk_rope_head_dim``
+    rotated ones, the key's rotary part one for all heads, values of
+    ``v_head_dim``, no bias) and then "F" (a SwiGLU of ``intermediate_size``)
+    for the first ``first_k_dense_replace`` layers and "E" after them: sigmoid
+    scores, the top ``num_experts_per_tok`` of score + expert bias, weights
+    from the scores over their sum times ``routed_scaling_factor``, SwiGLU
+    experts of ``moe_intermediate_size`` beside ``num_shared_experts`` shared
+    ones (one SwiGLU of that many widths, added unscaled). So ``n_layer`` is
+    twice ``num_hidden_layers``. Rotary positions are ``deepseek_yarn``'s
+    (``rope_scaling``) or plain; RMSNorm; untied head as published. Set here
+    and not published: ``use_qk_norm`` is the RMSNorm over the latent, the
+    sigmoid score and the 1e-20 under the weights' sum (the family's
+    ``noaux_tc``), rotary pairs ``(2i, 2i + 1)``
+    (``benchmarks/chipbench/configs/sarvam-105b.json: assumed``). Taken and
+    not read: ``max_position_embeddings`` (the caller's ``max_seq_len``),
+    ``attn_implementation``, ``default_theta``, ``model_type``. What it does
+    not build it refuses."""
+    rope = dict(rope_scaling or {})
+    kind = rope.get("type", rope.get("rope_type"))
+    refused = {
+        "q_lora_rank": (q_lora_rank, None),
+        "n_group": (n_group if n_group not in (0, 1) else None, None),
+        "topk_group": (topk_group if topk_group not in (0, 1) else None, None),
+        "rope_scaling.type": (kind, "deepseek_yarn" if rope else None),
+        "hidden_act": (hidden_act, "silu"),
+        "use_qk_norm": (bool(use_qk_norm), True),
+        "moe_router_enable_expert_bias": (bool(moe_router_enable_expert_bias), True),
+        "q_head_dim": (q_head_dim or qk_nope_head_dim + qk_rope_head_dim,
+                       qk_nope_head_dim + qk_rope_head_dim),
+        "head_dim": (head_dim or kv_lora_rank + qk_rope_head_dim,
+                     kv_lora_rank + qk_rope_head_dim),
+    }
+    bad = {k: got for k, (got, built) in refused.items() if got != built}
+    if bad:
+        raise NotImplementedError(
+            "sarvam_mla is built without a query latent and without expert "
+            "groups, with deepseek_yarn or plain rotary positions, SiLU, the "
+            "latent's norm, the expert bias, queries of nope + rope lanes and "
+            f"a cached row of rank + rope lanes (got {bad})")
+    n, dense = int(num_hidden_layers), int(first_k_dense_replace)
+    yarn = None
+    if rope:
+        yarn = (float(rope["factor"]),
+                float(rope["original_max_position_embeddings"]),
+                float(rope.get("beta_fast", 32)), float(rope.get("beta_slow", 1)),
+                float(rope.get("mscale", 1)), float(rope.get("mscale_all_dim", 0)))
+    kw.setdefault("name", "sarvam-mla")
+    return CausalLMConfig(
+        n_embd=hidden_size, n_layer=2 * n,
+        layer_pattern="".join("L" + ("F" if i < dense else "E") for i in range(n)),
+        vocab_size=vocab_size, n_head=num_attention_heads,
+        head_dim_override=v_head_dim, kv_lora_rank=int(kv_lora_rank),
+        qk_nope_head_dim=int(qk_nope_head_dim),
+        qk_rope_head_dim=int(qk_rope_head_dim), v_head_dim=int(v_head_dim),
+        rope_yarn=yarn, pos_emb="rotary", rotary_base=float(rope_theta),
+        layernorm="rmsnorm", ln_eps=rms_norm_eps, qkv_bias=False, mlp_bias=False,
+        gated_mlp=True, activation="silu", d_ff=intermediate_size,
+        tie_word_embeddings=bool(tie_word_embeddings), moe_kind="gated",
+        moe_router="sigmoid_bias", n_routed_experts=num_experts,
+        experts_per_token=num_experts_per_tok,
+        moe_expert_width=moe_intermediate_size,
+        moe_shared_width=int(num_shared_experts) * int(moe_intermediate_size),
+        routed_scaling_factor=float(routed_scaling_factor),
+        norm_topk_prob=bool(norm_topk_prob),
         experts_held=None if experts_held is None else tuple(experts_held), **kw)
 
 
@@ -1137,6 +1285,16 @@ class MixerLayer(CausalLMLayer):
         if self.kind == "*":
             out, new = self._attention(h, positions, cache, cache_len,
                                        prefix_fill, block_step, attn_mask)
+        elif self.kind == "L":
+            if prefix_fill or block_step:
+                raise NotImplementedError(
+                    "a latent-attention layer has no prefill at a cache offset "
+                    "and no block step: the rows it keeps are latents, which "
+                    "only the one-token decode attends in absorbed form (no "
+                    "prefix hits, no speculative verify on a model with such "
+                    "layers)")
+            out, new = self._latent_attention(h, positions, cache, cache_len,
+                                              attn_mask)
         else:
             if entry.keeps == "state" and prefix_fill:
                 raise NotImplementedError(
@@ -1152,6 +1310,82 @@ class MixerLayer(CausalLMLayer):
                 out = cfg.residual_multiplier * out.astype(jnp.float32)
                 return (x + out).astype(x.dtype), new
             return x + out.astype(x.dtype), new
+
+    def _latent_attention(self, h_in, positions, cache, cache_len, attn_mask):
+        """Latent attention on the normed input (``ops/attention/latent.py``
+        has the row and the absorbed form). A token's query is ``n_head`` x
+        (nope | rope) lanes; ``kv_a_proj`` gives its latent, normed, and the
+        ONE rotary key all heads share: the row the cache keeps, ``{"k": (b,
+        1, T, lanes)}``, zero lanes behind it. One token against the cache
+        (decode) is ABSORBED, scope ``attn.latent``: ``kv_b_k`` takes the
+        queries into the latent, the rows are read once for scores and values
+        (:func:`latent_decode_attention`), ``kv_b_v`` expands the result. A
+        whole sequence (prefill, forward) is EXPANDED, scope
+        ``attn.latent_expand``: keys ``[k^n_i ; k^r]`` and values a head from
+        the latent, then causal attention (:func:`_latent_attention_core`);
+        with a cache to fill only the rows are returned. ``kv_b_k`` and
+        ``kv_b_v`` are ``kv_b_proj``'s two halves a head, kept apart."""
+        cfg = self.config
+        b, t, _ = h_in.shape
+        H, rank = cfg.n_head, cfg.kv_lora_rank
+        nope, rope, dv = cfg.qk_nope_head_dim, cfg.qk_rope_head_dim, cfg.v_head_dim
+        init = nn.initializers.normal(cfg.init_std)
+        with scope("attn.qkv"):
+            q = QuantDense(H * (nope + rope), use_bias=False, dtype=cfg.dtype,
+                           kernel_init=init, site="wq.q_proj", name="q_proj")(h_in)
+            kva = QuantDense(rank + rope, use_bias=False, dtype=cfg.dtype,
+                             kernel_init=init, site="wq.kv_a_proj",
+                             name="kv_a_proj")(h_in)
+        w_uk = self.param("kv_b_k", init, (rank, H, nope), jnp.float32).astype(cfg.dtype)
+        w_uv = self.param("kv_b_v", init, (rank, H, dv), jnp.float32).astype(cfg.dtype)
+        lanes = latent_row_lanes(rank + rope)
+        with scope("attn.heads"):
+            q = q.reshape(b, t, H, nope + rope)
+            c = nn.RMSNorm(epsilon=cfg.ln_eps, dtype=jnp.float32,
+                           name="kv_a_norm")(kva[..., :rank]).astype(cfg.dtype)
+            inv_freq, mscale = cfg.latent_rope()
+            q_n = q[..., :nope]
+            q_r = rotate_pairs(q[..., nope:], positions, inv_freq, mscale)
+            k_r = rotate_pairs(kva[:, :, None, rank:], positions, inv_freq, mscale)
+            row = jnp.concatenate(
+                [c, k_r[:, :, 0], jnp.zeros((b, t, lanes - rank - rope), c.dtype)],
+                axis=-1)[:, None]                            # (b, 1, t, lanes)
+        scale = cfg.attn_scale
+        new = None
+        if cache is not None and t == 1:
+            with scope("kv.append"):
+                rows = _cache_update(cache["k"], row, cache_len)
+            new = {"k": rows}
+            with scope("attn.latent"):
+                q_abs = jnp.einsum("bhn,lhn->bhl", q_n[:, 0], w_uk)
+                q_row = jnp.concatenate(
+                    [q_abs, q_r[:, 0],
+                     jnp.zeros((b, H, lanes - rank - rope), q_abs.dtype)],
+                    axis=-1).astype(rows.dtype)
+                o = latent_decode_attention(q_row, rows, cache_len + 1, scale)
+                o = jnp.einsum("bhl,lhv->bhv", o[..., :rank].astype(cfg.dtype),
+                               w_uv)[:, None]
+        else:
+            with scope("attn.latent_expand"):
+                k_n = jnp.einsum("btl,lhn->bthn", c, w_uk)
+                v = jnp.einsum("btl,lhv->bthv", c, w_uv)
+                k = jnp.concatenate(
+                    [k_n, jnp.broadcast_to(k_r, (b, t, H, rope))], axis=-1)
+                q = jnp.concatenate([q_n, q_r], axis=-1)
+            with scope("attn.core"):
+                o = _latent_attention_core(q, k, v, attn_mask, scale)
+            if cache is not None:
+                T = cache["k"].shape[2]
+                with scope("kv.append"):
+                    new = {"k": jnp.pad(row, ((0, 0), (0, 0), (0, T - t), (0, 0)))
+                           .astype(cache["k"].dtype)}
+        with scope("attn.heads"):
+            o = o.reshape(b, t, H * dv)
+        with scope("attn.out"):
+            out = RowParallelDense(cfg.n_embd, use_bias=False, dtype=cfg.dtype,
+                                   kernel_init=nn.initializers.normal(cfg.out_std),
+                                   span="tp.o_proj", name="o_proj")(o)
+        return out, new
 
     def _mamba(self, h, cache, seq_lens):
         from .mamba2 import Mamba2Mixer
@@ -1246,6 +1480,25 @@ def _bias_attention(q, k, v, slopes, mask_block: int, attn_mask, scale):
     if slopes is None:
         return xla_attention(q, k, v, causal=True, softmax_scale=scale)
     return _alibi_attention_xla(q, k, v, slopes, scale)
+
+
+def _latent_attention_core(q, k, v, attn_mask, scale):
+    """Whole-sequence causal attention of a latent layer's EXPANDED heads:
+    queries and keys ``(b, t, h, nope + rope)``, values ``(b, t, h, v)``. At a
+    ``flash_eligible`` length, values of whole 128-lane tiles, the flash kernel,
+    which takes queries and keys wider than its values where both are: 192-lane
+    queries and keys are padded to 256 with zero lanes (a zero lane adds
+    nothing to a score). Else, and under ``attn_mask``, XLA's products."""
+    from ..ops.attention.flash import flash_attention
+    from ..ops.transformer.attention import flash_eligible, xla_attention
+    if attn_mask is not None:
+        return xla_attention(q, k, v, causal=False, mask=attn_mask[None, None],
+                             softmax_scale=scale)
+    if not flash_eligible(q.shape[1]) or v.shape[-1] % 128:
+        return xla_attention(q, k, v, causal=True, softmax_scale=scale)
+    pad = ((0, 0),) * 3 + ((0, -q.shape[-1] % 128),)
+    return flash_attention(jnp.pad(q, pad), jnp.pad(k, pad), v, causal=True,
+                           softmax_scale=scale)
 
 
 def _alibi_attention_xla(q, k, v, slopes, scale=None):
@@ -1638,7 +1891,9 @@ def init_cache(cfg: CausalLMConfig, batch_size: int, max_len: Optional[int] = No
     caller lays them out otherwise: the paged pool's pages), the kind's
     per-slot state for ``batch_size`` sequences (``{"conv", "ssm"}``
     state-space, ``{"conv"}`` short convolution), an empty dict for a layer
-    that keeps nothing."""
+    that keeps nothing; a latent layer's one row a token ``{"k": (batch_size,
+    1, T, lanes)}`` (``ops/paged_attention.latent_row_lanes``; under
+    ``kv_shape`` its first and third extents)."""
     T = max_len or cfg.max_seq_len
     dtype = dtype or cfg.dtype
     r = heads_per_row(cfg.head_dim, cfg.kv_heads)
@@ -1648,6 +1903,11 @@ def init_cache(cfg: CausalLMConfig, batch_size: int, max_len: Optional[int] = No
         entry = LAYER_KINDS[kind]
         if entry.keeps == "kv":
             out.append({"k": jnp.zeros(shape, dtype), "v": jnp.zeros(shape, dtype)})
+        elif entry.keeps == "latent":
+            # one row a token for all heads, where a "kv" layer has hk rows
+            out.append({"k": jnp.zeros(
+                (shape[0], 1, shape[2], latent_row_lanes(cfg.latent_row_width)),
+                dtype)})
         else:
             out.append(entry.state(cfg, batch_size, dtype) if entry.state else {})
     return out

@@ -181,3 +181,87 @@ def level_expert_load(cfg, params, seed: int, batches: int = 8, tokens: int = 51
             before, after = max(before, float(b)), max(after, float(a))
         xs = [run(seg, x, feed, None) for x, feed in zip(xs, feeds)]
     return params, before, after
+
+
+# a home's router weight on its code lane: x a lane of init_std (0.02) x the
+# norm's factor (2-5 on a stream of 0.2-0.5 a lane) = a logit of +-40 to +-100
+# against the seeded rows' spread of ~1.3: sigmoid's 1 and 0 in float32
+HOME_GAIN = 1024.0
+# the matrices of a mixer layer that WRITE to the residual stream
+_STREAM_WRITERS = ("o_proj", "fc_out")
+_MOE_STREAM_WRITERS = ("experts_down", "shared_down")
+
+
+def home_random_routers(cfg, params, seed: int):
+    """Finish RANDOMLY initialised parameters of a model whose experts sit
+    behind ``sigmoid_bias`` routers so that NO rounding moves a router's
+    choice: every token id gets ``experts_per_token`` home experts a layer,
+    and the router finds them with a margin of tens of score spreads.
+
+    Why: a seeded router's ``top_k`` of ``n`` scores has its k-th and k+1-th a
+    twentieth of a spread apart, so the half per cent that bfloat16 leaves on
+    a residual stream moves about one choice in ten, and ONE moved choice of a
+    random stand-in swaps a whole expert term: a comparison with a float32
+    reference then reads its router's luck, a spread of a logit, whatever the
+    precision of anything else. A trained model's neighbours in score are
+    alike; a stand-in's are not. How, with the layer's own arithmetic (router
+    matmul, sigmoid, bias, ``top_k``, weights over their sum, scaling):
+
+    - lanes ``[0, n)`` of the stream carry the token's CODE: the embedding's
+      row holds ``+init_std`` on the lanes of its homes and ``-init_std`` on
+      the others (the size every other lane of a row has), homes drawn from
+      the seed, ``experts_per_token`` of ``n`` without replacement, so loads
+      are level by construction and the selection bias stays as seeded;
+    - nothing writes there: the columns ``[0, n)`` of every matrix that
+      writes to the stream (``o_proj``, ``fc_out``, an expert's and the shared
+      expert's down matrix) are zero, so the code reaches every router as the
+      embedding gave it, in the program and in any reference alike;
+    - rows ``[0, n)`` of a layer's router are ``HOME_GAIN`` times a
+      permutation of its own (another set of homes a layer); its other rows
+      keep their seeded weights. A home scores ``sigmoid(+gain x lane)``, 1,
+      every other expert ``sigmoid(-gain x lane)``, 0.
+
+    ``cfg.home_random_routers`` asks for it where the stand-in weights are made;
+    nothing else calls it. Returns the parameters."""
+    n, k = cfg.n_routed_experts, cfg.experts_per_token
+    if not 0 < n <= cfg.n_embd:
+        raise ValueError(f"home_random_routers needs {n} code lanes in a stream of {cfg.n_embd}")
+    key = jax.random.fold_in(jax.random.PRNGKey(seed), 0x686F6D65)
+
+    def put(old, fn, *args):
+        # the old array gives up its buffer: the tree is the caller's to
+        # replace, and at the published widths a second copy of the down
+        # matrices raised the set-up's peak by 0.8 GB
+        sharding = getattr(old, "sharding", None)
+        new = jax.jit(fn, donate_argnums=0)(old, *args)
+        return new if sharding is None else jax.device_put(new, sharding)
+
+    def code(wte):
+        draw = jax.random.uniform(key, (wte.shape[0], n))
+        home = draw <= jnp.sort(draw, axis=-1)[:, k - 1:k]
+        return wte.at[:, :n].set(jnp.where(home, cfg.init_std, -cfg.init_std).astype(wte.dtype))
+
+    def homes(router, layer_key):
+        perm = jax.random.permutation(layer_key, n)
+        rows = HOME_GAIN * jax.nn.one_hot(perm, router.shape[1])
+        return router.at[:n].set(rows.astype(router.dtype))
+
+    def unwritten(w):
+        return w.at[..., :n].set(0)
+
+    params = dict(params)
+    params["wte"] = put(params["wte"], code)
+    for i in range(cfg.n_layer):
+        name = f"layers_{i}"
+        layer = dict(params[name])
+        for w in _STREAM_WRITERS:
+            if w in layer:
+                layer[w] = {**layer[w], "kernel": put(layer[w]["kernel"], unwritten)}
+        if "moe" in layer:
+            moe = dict(layer["moe"])
+            moe["router"] = put(moe["router"], homes, jax.random.fold_in(key, i))
+            for w in _MOE_STREAM_WRITERS:
+                moe[w] = put(moe[w], unwritten)
+            layer["moe"] = moe
+        params[name] = layer
+    return params

@@ -84,6 +84,11 @@ TAGS: Dict[str, Tuple[str, str]] = {
     "serving/ssm_state_bytes": (GAUGE, "bytes of the per-slot state of the "
                                        "state-space and short-convolution "
                                        "layers (recurrent state, windows)"),
+    "serving/kv_latent_row_bytes": (GAUGE, "kv.latent_row_bytes: bytes a token "
+                                           "a layer as a latent-attention "
+                                           "layer stores them (one row for "
+                                           "all heads, zero lanes counted); "
+                                           "absent from a per-head pool"),
     # ------------------------- generation by diffusion over blocks (PR 31)
     "serving/block_forwards_total": (COUNTER, "forwards run by decode chunks "
                                               "of a model that generates by "
@@ -386,7 +391,8 @@ SPANS: Dict[str, Tuple[str, str, Tuple[str, ...], str]] = {
     "setup.balance_experts": (PHASE, "device set-up", (),
                               "setup_engine_init_s lines (random weights of a "
                               "configuration with level_random_experts: the "
-                              "selection bias levelled on random tokens)"),
+                              "selection bias levelled on random tokens; or "
+                              "with home_random_routers: home experts a token id)"),
     "setup.kv_pool": (PHASE, "device set-up",
                       ("pool", "pages", "slots", "state_bytes",
                        "heads_per_row"),
@@ -446,6 +452,13 @@ SCOPES: Dict[str, Tuple[str, str, str]] = {
                   "decode_attn_dev_ms_per_step"),
     "attn.out": (_STEP, "the attention's output projection",
                  "train_matmul_roofline_pct"),
+    "attn.latent": (_STEP, "a latent layer's absorbed one-token attention: "
+                           "queries into the latent, scores and values over "
+                           "the cached rows, the value expansion",
+                    "latent_attn_dev_ms_per_step"),
+    "attn.latent_expand": (_STEP, "a latent layer's keys and values expanded "
+                                  "from the prompt's latents (prefill, forward)",
+                           _TABLE),
     "kv.append": (_STEP, "new keys and values written into the cache",
                   "decode_attn_dev_ms_per_step"),
     "mlp.up": (_STEP, "the MLP's first projection, with the gate",

@@ -320,7 +320,9 @@ def _fwd_kernel(*refs, d, scale, causal, use_alibi, nq, nk, bq, bk, mask_block=1
     slopes_ref = refs[3] if use_alibi else None
     o_ref, lse_ref, *scratch = refs[4 if use_alibi else 3:]
     width = o_ref.shape[-1]
-    heads = range(width // d)
+    # the heads of a block, by the queries' lanes: the output's are the same
+    # but where values are narrower or wider than queries and keys (one head)
+    heads = range(q_ref.shape[-1] // d)
     j = pl.program_id(2)
     kb = pl.program_id(3)
     if nk > 1:
@@ -412,11 +414,15 @@ def _flash_fwd(q, k, v, slopes, fused, d, scale, causal, block_q, block_k,
     flat or (b*h, t, d) — or, ``fused``, ONE (b, t, 3*lanes) array passed three times
     and read at q | k | v's lane offsets. ``slopes``: alibi slopes (h, 8, 128), the
     value duplicated for lane alignment, or None. Returns (o (rows, t, lanes),
-    lse (rows, heads, t))."""
+    lse (rows, heads, t)). ``v`` may hold heads of another width than q's and k's
+    ``d`` (:func:`flash_attention_local` says when): a lane group is then one head in
+    all three, ``o`` has v's lanes."""
     rows, t, lanes = q.shape
     lanes //= 3 if fused else 1
     width, groups = _lane_groups(lanes, d)
     hpb = width // d
+    v_lanes = v.shape[-1] // (3 if fused else 1)
+    v_width = v_lanes // groups          # == width but for values of another width
     bq, bk = _block_sizes(t, block_q, block_k)
     nq, nk = t // bq, t // bk
     use_alibi = slopes is not None
@@ -427,7 +433,7 @@ def _flash_fwd(q, k, v, slopes, fused, d, scale, causal, block_q, block_k,
     in_specs = [
         pl.BlockSpec((1, bq, width), _outer_map()),
         pl.BlockSpec((1, bk, width), _k_index_map(causal, bq, bk, fused * groups)),
-        pl.BlockSpec((1, bk, width), _k_index_map(causal, bq, bk, 2 * fused * groups)),
+        pl.BlockSpec((1, bk, v_width), _k_index_map(causal, bq, bk, 2 * fused * groups)),
     ]
     args = [q, k, v]
     if use_alibi:
@@ -439,17 +445,17 @@ def _flash_fwd(q, k, v, slopes, fused, d, scale, causal, block_q, block_k,
         grid=(rows, groups, nq, nk),
         in_specs=in_specs,
         out_specs=[
-            pl.BlockSpec((1, bq, width), _outer_map()),
+            pl.BlockSpec((1, bq, v_width), _outer_map()),
             pl.BlockSpec((1, hpb, 1, 8, bq), _stat_map),
         ],
         out_shape=[
-            jax.ShapeDtypeStruct((rows, t, lanes), q.dtype),
+            jax.ShapeDtypeStruct((rows, t, v_lanes), q.dtype),
             jax.ShapeDtypeStruct((rows, lanes // d, nq, 8, bq), jnp.float32),
         ],
         scratch_shapes=[] if nk == 1 else [
             pltpu.VMEM((hpb, bq, 1), jnp.float32),    # m
             pltpu.VMEM((hpb, bq, 1), jnp.float32),    # l
-            pltpu.VMEM((bq, width), jnp.float32),     # acc
+            pltpu.VMEM((bq, v_width), jnp.float32),   # acc
         ],
         compiler_params=_compiler_params(hpb),
         name="flash_fwd",
@@ -734,6 +740,12 @@ def flash_attention_local(q4, k4, v4, causal: bool = True,
     ``shard_map`` manual region (e.g. the TP pipeline stage_fn), where the public
     :func:`flash_attention`'s own shard_map wrapper would illegally nest."""
     lb, lt, lh, ld = q4.shape
+    dv = v4.shape[-1]
+    if dv != ld and (ld % 128 or dv % 128 or alibi_slopes is not None):
+        raise NotImplementedError(
+            f"values of {dv} lanes beside queries and keys of {ld}: both must be "
+            "whole 128-lane tiles (pad queries and keys with zero lanes), without "
+            "alibi")
     if mask_block > 1 and (not causal or lt % mask_block
                            or min(_block_sizes(lt, block_q, block_k)) % mask_block):
         raise ValueError(
@@ -743,6 +755,14 @@ def flash_attention_local(q4, k4, v4, causal: bool = True,
     use_alibi = alibi_slopes is not None
     static = (ld, scale, causal, use_alibi, block_q, block_k, mask_block)
     slopes = _slopes_tiles(alibi_slopes) if use_alibi else jnp.asarray(_DUMMY_SLOPES)
+    if dv != ld:
+        # values narrower or wider than queries and keys (a latent layer's expanded
+        # heads): the forward kernel alone, a head a lane group in every operand; no
+        # gradient is defined (serving prefill only)
+        o, _ = _flash_fwd(q4.reshape(lb, lt, lh * ld), k4.reshape(lb, lt, lh * ld),
+                          v4.reshape(lb, lt, lh * dv), None, False, ld, scale, causal,
+                          block_q, block_k, mask_block)
+        return o.reshape(lb, lt, lh, dv)
     if heads_a_block(lh, ld):
         # bitcasts: the kernels pick the head in their index maps
         o = _flash_core(*(x.reshape(lb, lt, lh * ld) for x in (q4, k4, v4)), slopes,

@@ -30,6 +30,23 @@ from ...utils.device import pallas_interpret as _interpret
 VMEM_LIMIT_BYTES = 64 * 2 ** 20
 
 
+def width_blocks(l: int, f: int, mats: int, itemsize: int) -> int:
+    """Into how many blocks of its width ``f`` an expert is cut so that two
+    experts' weight blocks in flight and the tile's activations fit
+    ``VMEM_LIMIT_BYTES``: 1 for every gated expert of ``l`` 4096 up to a width
+    of 1194 in bf16 (56 MiB / (2 x 3 x 4096 x 2 B): whole matrices, one kernel
+    call); 2 for sarvam's gated 4096 x 2048
+    (three blocks of 16 MiB: 96 MiB in flight whole, 48 halved). An expert
+    splits exactly over its width, ``sum_b (act(x Wg_b) * (x W1_b)) W2_b``,
+    so the kernel runs once a block on that block of every matrix (picked in
+    the index maps: no copy) and the results are added."""
+    n = 1
+    while 2 * mats * l * (f // n) * itemsize + 8 * 2 ** 20 > VMEM_LIMIT_BYTES \
+            and f % (2 * n) == 0 and (f // (2 * n)) % 128 == 0:
+        n *= 2
+    return n
+
+
 def tile_rows(assignments: int) -> int:
     """Rows a tile: 16 (one bf16 sublane tile) while the step is bound by the
     weights it reads, 32 once a prompt brings tens of rows an expert."""
@@ -137,33 +154,44 @@ def grouped_ffn(x_rows, tile_expert, tile_valid, w1, w2, act, tm: int,
     the expert is gated (``(act(x Wg) * (x W1)) W2``; None: ``act(x W1) W2``).
     Returns (NT * tm, l) float32; rows of padding hold whatever token 0 gives
     and are never gathered back. The kernel's name in a trace is
-    ``moe_grouped_ffn`` in both forms."""
+    ``moe_grouped_ffn`` in both forms. Experts too wide for the kernel's VMEM
+    are cut over their width (:func:`width_blocks`): one kernel call a block,
+    their results added."""
     R, l = x_rows.shape
     f = w1.shape[2]
     NT = R // tm
     if not _interpret() and (l % 128 or f % 128):
         return grouped_ffn_xla(x_rows, tile_expert, tile_valid, w1, w2, act, tm,
                                w_gate)
-    up = pl.BlockSpec((None, l, f), lambda i, te, tv: (te[i], 0, 0))
     gate = [] if w_gate is None else [w_gate]
-    grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=2,
-        grid=(NT,),
-        in_specs=[pl.BlockSpec((tm, l), lambda i, te, tv: (i, 0))]
-        + [up] * (len(gate) + 1)
-        + [pl.BlockSpec((None, f, l), lambda i, te, tv: (te[i], 0, 0))],
-        out_specs=pl.BlockSpec((tm, l), lambda i, te, tv: (i, 0)),
-    )
-    return pl.pallas_call(
-        functools.partial(_kernel, act=act),
-        out_shape=jax.ShapeDtypeStruct((R, l), jnp.float32),
-        grid_spec=grid_spec,
-        compiler_params=pltpu.CompilerParams(
-            dimension_semantics=("arbitrary",),
-            vmem_limit_bytes=VMEM_LIMIT_BYTES),
-        name="moe_grouped_ffn",
-        interpret=_interpret(),
-    )(tile_expert, tile_valid, x_rows, *gate, w1, w2)
+    n = width_blocks(l, f, 2 + len(gate), w1.dtype.itemsize)
+    fb = f // n
+
+    def call(b):
+        up = pl.BlockSpec((None, l, fb), lambda i, te, tv: (te[i], 0, b))
+        grid_spec = pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=2,
+            grid=(NT,),
+            in_specs=[pl.BlockSpec((tm, l), lambda i, te, tv: (i, 0))]
+            + [up] * (len(gate) + 1)
+            + [pl.BlockSpec((None, fb, l), lambda i, te, tv: (te[i], b, 0))],
+            out_specs=pl.BlockSpec((tm, l), lambda i, te, tv: (i, 0)),
+        )
+        return pl.pallas_call(
+            functools.partial(_kernel, act=act),
+            out_shape=jax.ShapeDtypeStruct((R, l), jnp.float32),
+            grid_spec=grid_spec,
+            compiler_params=pltpu.CompilerParams(
+                dimension_semantics=("arbitrary",),
+                vmem_limit_bytes=VMEM_LIMIT_BYTES),
+            name="moe_grouped_ffn",
+            interpret=_interpret(),
+        )(tile_expert, tile_valid, x_rows, *gate, w1, w2)
+
+    out = call(0)
+    for b in range(1, n):
+        out = out + call(b)
+    return out
 
 
 def grouped_experts(x, idx, w, first: int, count: int, w1, w2, act, valid=None,

@@ -63,6 +63,21 @@ def heads_per_row(head_dim: int, kv_heads: int) -> int:
     return r if local % r == 0 else 1
 
 
+def latent_row_lanes(width: int) -> int:
+    """Lanes of a LATENT layer's cache row (``models/causal_lm.LAYER_KINDS``:
+    ``keeps == "latent"``): one row a token for ALL heads, ``[latent | shared
+    rotary key]`` = ``width`` lanes as published (576 for a rank of 512 and 64
+    rotary lanes), rounded up to whole 128-lane tiles with zero lanes (640:
+    4.5 tiles would leave every row's last tile half filled and every page
+    slab ragged). Such a layer's pages are ``{"k": (P, 1, page, lanes)}``: one
+    "head", no ``v`` array (the values are expanded from the row's latent
+    lanes), dense view and contiguous cache ``(b, 1, T, lanes)``. Everything
+    below takes a row's extents from its operands, so the pages are gathered
+    (:func:`gather_pages_dense`) and written (:func:`write_dense_pages`,
+    :func:`write_view_rows`) as every other row is."""
+    return -(-int(width) // 128) * 128
+
+
 def kv_rows(x, r: int):
     """A projection's keys or values ``(b, t, h_kv, d)`` (after the per-head
     norm and the rotation) as head-major cache rows ``(b, h_kv / r, t, r *
@@ -74,22 +89,28 @@ def kv_rows(x, r: int):
 
 
 # ------------------------------------------------------------- dense gather
-def gather_kv_dense(k_pages, v_pages, page_table, cap: int):
-    """Reassemble the dense head-major cache view from pages.
+def gather_pages_dense(arrays, page_table, cap: int) -> list:
+    """Reassemble the dense head-major cache view from pages, for every array
+    a layer keeps in them (keys and values; a latent layer's one array of
+    rows).
 
-    ``k_pages``/``v_pages``: ``(P, hk, page, d)`` (``hk`` rows of ``d`` lanes,
-    here and below: :func:`heads_per_row`); ``page_table``:
-    ``(b, max_pages)`` int32. Returns ``(b, hk, cap, d)`` ×2 — rows sliced to
-    EXACTLY ``cap`` so downstream attention math (block sizes and, for the
-    whole-cap forms, reduction shapes) is identical to a contiguous
-    ``cap``-row cache's, keeping greedy bit-exact even when ``cap`` is not a
-    page multiple (pages round it up internally)."""
-    kp = k_pages[page_table]                       # (b, mp, hk, page, d)
-    vp = v_pages[page_table]
-    b, mp, hk, ps, d = kp.shape
-    k = kp.transpose(0, 2, 1, 3, 4).reshape(b, hk, mp * ps, d)
-    v = vp.transpose(0, 2, 1, 3, 4).reshape(b, hk, mp * ps, d)
-    return k[:, :, :cap, :], v[:, :, :cap, :]
+    ``arrays``: each ``(P, hk, page, d)`` (``hk`` rows of ``d`` lanes, here
+    and below: :func:`heads_per_row`); ``page_table``: ``(b, max_pages)``
+    int32. Returns a ``(b, hk, cap, d)`` each — rows sliced to EXACTLY ``cap``
+    so downstream attention math (block sizes and, for the whole-cap forms,
+    reduction shapes) is identical to a contiguous ``cap``-row cache's,
+    keeping greedy bit-exact even when ``cap`` is not a page multiple (pages
+    round it up internally). All gathers first, then the re-laying, then the
+    slices: the order every key/value program has been lowered in."""
+    got = [a[page_table] for a in arrays]          # (b, mp, hk, page, d)
+    b, mp, hk, ps, d = got[0].shape
+    dense = [p.transpose(0, 2, 1, 3, 4).reshape(b, hk, mp * ps, d) for p in got]
+    return [x[:, :, :cap, :] for x in dense]
+
+
+def gather_kv_dense(k_pages, v_pages, page_table, cap: int):
+    """:func:`gather_pages_dense` of a layer's keys and values."""
+    return tuple(gather_pages_dense((k_pages, v_pages), page_table, cap))
 
 
 def pages_to_dense(pages, tbl):
@@ -105,17 +126,18 @@ def write_dense_pages(pages, dense, tbl):
     """The inverse, a layer at a time: overwrite pages ``tbl (n,)`` of
     ``pages {"k", "v"}: (P, hk, page, d)`` with the dense rows ``dense {"k",
     "v"}: (hk, R, d)`` (or the batch of one, ``(1, hk, R, d)``, a prefill
-    returns), zero-padded to ``n`` whole pages."""
+    returns), zero-padded to ``n`` whole pages. Every array the layer keeps
+    in pages is written (a latent layer has ``"k"`` alone)."""
     n, ps = tbl.shape[0], pages["k"].shape[2]
     blocks = {}
-    for key in ("k", "v"):
+    for key in pages:
         x = dense[key][0] if dense[key].ndim == 4 else dense[key]
         hk, R, d = x.shape
         blocks[key] = jnp.pad(x, ((0, 0), (0, n * ps - R), (0, 0))) \
             .reshape(hk, n, ps, d)
     return {key: pages[key].at[tbl].set(
         blocks[key].transpose(1, 0, 2, 3).astype(pages[key].dtype))
-        for key in ("k", "v")}
+        for key in pages}
 
 
 def write_view_rows(pages, view, page_table, start, count, span: int,

@@ -191,9 +191,13 @@ def lowered_texts():
     key leaves op names out, so with it on the null build would LOAD the
     scoped build's executable, names and all (what ``utils/device.py`` keys
     the cache against)."""
+    from jax._src import compilation_cache
     cache = {}
     was = jax.config.jax_enable_compilation_cache
     jax.config.update("jax_enable_compilation_cache", False)
+    # jax decides ONCE a process whether the cache is used: a test that ran
+    # earlier in this worker with the cache on would keep it on here
+    compilation_cache.reset_cache()
 
     def get(program, null=False):
         if (program, null) not in cache:
@@ -205,6 +209,7 @@ def lowered_texts():
 
     yield get
     jax.config.update("jax_enable_compilation_cache", was)
+    compilation_cache.reset_cache()
 
 
 @pytest.mark.parametrize("program", sp.PROGRAMS)

@@ -524,3 +524,104 @@ def test_the_kernels_are_called_with_the_blocks_they_were(case):
     assert [call[0] for call in calls] == [call[0] for call in _CALLS[case]]
     for got, want in zip(calls, _CALLS[case]):
         assert got == want, f"{got[0]}: grid, operands, results, blocks, aliases, axes"
+
+
+# ------------------------------------------------------------- sarvam-105b (PR 57)
+@pytest.mark.parametrize("tokens", [32, 4096])
+def test_the_gated_expert_kernel_compiles_at_sarvams_published_widths(
+        one_chip, tokens, monkeypatch):
+    """``moe_grouped_ffn`` in its gated form at sarvam-105b's widths, the cell
+    ``sarvam-105b.doc4k32``'s kernel shape ``(16, 4096, 2048)`` (the chip's 16
+    of 128 experts), the top 8: the fifth and largest expert size on the chip
+    (50.3 MB). Three whole-matrix blocks, double-buffered, are 96 MiB: past
+    the kernel's 64 MiB, so the experts' width is cut in two
+    (``width_blocks``) and the kernel called once a half, 48 MiB in flight;
+    every size the chip had before stays whole, one call."""
+    from deepspeed_tpu.ops.moe import grouped_ffn as g
+    monkeypatch.setattr(g, "_interpret", lambda: False)
+    held, d, width, k = 16, 4096, 2048, 8
+    tm = g.tile_rows(tokens * k)
+    tiles = tokens * k // tm + held
+    assert g.width_blocks(d, width, 3, 2) == 2
+    assert 2 * 3 * d * (width // 2) * 2 + 8 * 2 ** 20 < g.VMEM_LIMIT_BYTES
+    for l, f, mats in ((1024, 2688, 2), (2048, 768, 3), (2048, 1792, 3), (4096, 768, 3)):
+        assert g.width_blocks(l, f, mats, 2) == 1
+
+    def sds(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    up = sds((held, d, width), jnp.bfloat16)
+    text = jax.jit(functools.partial(g.grouped_ffn, act=jax.nn.silu, tm=tm)).lower(
+        sds((tiles * tm, d), jnp.bfloat16), sds((tiles,), jnp.int32),
+        sds((tiles,), jnp.int32), up, sds((held, width, d), jnp.bfloat16),
+        w_gate=up).compile().as_text()
+    assert text.count("tpu_custom_call") == 2 and "moe_grouped_ffn" in text
+    # no half of a matrix is copied out: the halves are picked in the index maps
+    assert f"bf16[{held},{d},{width // 2}]" not in text
+
+
+@pytest.mark.parametrize("tokens", [512, 4096])
+def test_the_flash_forward_compiles_with_queries_and_keys_wider_than_values(
+        one_chip, tokens, monkeypatch):
+    """sarvam-105b's expanded prefill: 64 heads, queries and keys of 192 lanes
+    padded to 256 (two lane tiles a head), values of 128, the head picked in the
+    index maps of all four operands: one forward kernel, no transpose."""
+    from deepspeed_tpu.ops.attention import flash
+    monkeypatch.setattr(flash, "_interpret", lambda: False)
+    wide = jax.ShapeDtypeStruct((1, tokens, 64, 256), jnp.bfloat16, sharding=one_chip)
+    narrow = jax.ShapeDtypeStruct((1, tokens, 64, 128), jnp.bfloat16, sharding=one_chip)
+    compiled = jax.jit(functools.partial(flash.flash_attention_local,
+                                         softmax_scale=0.135234)).lower(
+        wide, wide, narrow).compile()
+    text = compiled.as_text()
+    assert text.count("tpu_custom_call") == 1 and "flash_fwd" in text
+    assert " transpose(" not in text
+    assert f"bf16[1,{tokens},8192]" in text          # the output has the values' lanes
+
+
+def test_sarvams_decode_chunk_walks_the_latent_rows_without_relaying_them(
+        one_chip, monkeypatch):
+    """Two published layers of sarvam-105b (the dense one and an expert layer)
+    at the cell's shapes, 32 slots x cap 6144 on pages of 16: the chunk's own
+    ``while``, one walk over live blocks a latent layer
+    (``latent_decode_attention``) and no other loop; the expert kernel (a call
+    a half of the experts' width) is the only Mosaic kernel; a step appends its row without a scatter, nothing the
+    shape of the pages is copied, and the view is never made float32 (the two
+    products take the rows as stored)."""
+    import json
+    import os
+    from deepspeed_tpu.inference.decode_fns import (build_paged_decode_chunk,
+                                                    make_slot_select_fn)
+    from deepspeed_tpu.models.causal_lm import sarvam_mla_cfg
+    from deepspeed_tpu.ops.moe import grouped_ffn as g
+    monkeypatch.setattr(g, "_interpret", lambda: False)
+    root = os.path.dirname(os.path.dirname(os.path.dirname(os.path.dirname(
+        os.path.abspath(__file__)))))
+    with open(os.path.join(root, "benchmarks", "chipbench", "configs",
+                           "sarvam-105b.json")) as f:
+        model = json.load(f)["model"]
+    slots, cap, pages, page, chunk = 32, 6144, 12289, 16, 8
+    cfg = sarvam_mla_cfg(max_seq_len=cap, **dict(model, num_hidden_layers=2))
+    assert cfg.layer_pattern == "LFLE"
+
+    def sds(shape, dtype=jnp.int32):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    module, params, caches, _ = _abstract_model(cfg, slots, cap, pages, page, sds)
+    assert caches[0]["k"].shape == (pages, 1, page, 640) and caches[1] == {}
+    fn = build_paged_decode_chunk(module, lambda p: p,
+                                  make_slot_select_fn(False, 1.0, 0, 1.0),
+                                  chunk, kv_cap=cap, with_stats=True)
+    text = jax.jit(fn, donate_argnums=(2,)).lower(
+        params, sds((slots, 1)), caches, sds((slots, cap // page)), sds((slots,)),
+        sds((slots,), jnp.bool_), sds((slots,)), sds((slots,)), sds((slots,)),
+        sds((slots,)), sds((2,), jnp.uint32)).compile().as_text()
+    loops = [line for line in text.splitlines() if " while(" in line]
+    walks = [line for line in loops
+             if "ds.attn.latent/jit(latent_decode_attention)/while" in line]
+    assert len(loops) == 3 and len(walks) == 2, [
+        line.split(" = ")[0].strip() for line in loops]
+    assert text.count("tpu_custom_call") == 2 and "moe_grouped_ffn" in text   # two halves
+    assert " scatter(" not in text
+    assert _pool_relayouts(text, (pages, 1, page, 640)) == []
+    assert f"f32[{slots},{cap},640]" not in text and f"f32[{slots},1,{cap},640]" not in text
