@@ -40,6 +40,7 @@ from ...models.causal_lm import init_cache
 from ...observability import profiler as obs_profiler
 from ...observability.trace import get_tracer, scope
 from ...utils.fault_injection import fault_point
+from ...ops.moe.grouped_ffn import plan_rows
 from ...ops.paged_attention import pages_to_dense, write_view_rows
 from ..decode_fns import (block_chunk_width, build_block_decode_chunk,
                           build_paged_decode_chunk, build_paged_spec_verify,
@@ -235,6 +236,7 @@ class ChunkResult:
     moe: Optional[np.ndarray] = None   # (assignments on held experts, distinct
     #   held experts read) over the chunk's steps and expert layers; None for
     #   a model without expert layers
+    moe_plan_rows: int = 0   # rows their dispatch plans laid out (static)
     block: Optional[Tuple[np.ndarray, np.ndarray, np.ndarray]] = None   # a
     #   model that generates by blocks: each slot's block in flight after the
     #   chunk, ``(tokens (S, B), still masked (S, B) bool, given by the
@@ -310,6 +312,11 @@ class ChunkedDecodeExecutor:
                 f"({self.cap}) and the page size ({self.kv_page_size}): a "
                 "block is committed whole and never straddles a page")
         self.last_prefill_moe = None    # the last prefill's counts (or None)
+        self.last_prefill_plan_rows = 0  # and the rows its plans laid out
+        # what a decode chunk's plans lay out: its forwards x a forward's rows
+        # (a block model's forward carries two blocks a slot)
+        self._chunk_plan_rows = self.chunk_size * self.moe_plan_rows(
+            self.slots * max(1, 2 * self.block)) if self.with_stats else 0
         self.pool = self._build_pool()
         self._one = None                # the miss prefill's batch-1 cache
         self._slot_select = make_slot_select_fn(*self.sampling)
@@ -651,6 +658,7 @@ class ChunkedDecodeExecutor:
             self.last_prefill_moe = None
             if self.with_stats:
                 self.last_prefill_moe = out[1:]
+                self.last_prefill_plan_rows = self.moe_plan_rows(bucket)
                 sp.set(moe_assignments=int(out[1]),
                        moe_experts_touched=int(out[2]))
             if self.block:
@@ -662,6 +670,14 @@ class ChunkedDecodeExecutor:
             self.pool.scatter_prefill(slot, one_caches)
         obs_profiler.tick("prefill")
         return tok0, sp.t1
+
+    def moe_plan_rows(self, tokens: int) -> int:
+        """Rows the expert layers' dispatch plans lay out in ONE forward of
+        ``tokens`` tokens (``grouped_ffn.plan_rows``: the static worst case;
+        the programs' own counts say what of it was live)."""
+        cfg = self.engine.model_config
+        return cfg.layer_kinds.count("E") * plan_rows(
+            tokens * cfg.experts_per_token, cfg.held_experts[1])
 
     def run_chunk(self, toks: np.ndarray, lens: np.ndarray, active: np.ndarray,
                   remaining: np.ndarray, eos_ids: np.ndarray, seeds: np.ndarray,
@@ -727,6 +743,7 @@ class ChunkedDecodeExecutor:
                            steps=state[:, OUT_STEPS],
                            elapsed=stamps[2] - placed.t1, stamps=stamps,
                            moe=packed[S, :2] if self.with_stats else None,
+                           moe_plan_rows=self._chunk_plan_rows,
                            block=in_flight, block_counts=counts)
 
     def _timed(self, fn, args, program: str, fault: str):

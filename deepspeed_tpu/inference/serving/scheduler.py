@@ -686,7 +686,8 @@ class ContinuousBatchingScheduler:
         handle.prefix_hit_tokens = prefix_len
         self._prefills_done += 1
         self._prefills_seen[slot] = self._prefills_done
-        self.telemetry.on_moe(self.executor.last_prefill_moe)
+        self.telemetry.on_moe(self.executor.last_prefill_moe,
+                              self.executor.last_prefill_plan_rows)
         self.telemetry.on_prefix(entry is not None,
                                  handle.prefix_hit_tokens,
                                  enabled=self.prefix_cache is not None)
@@ -865,7 +866,7 @@ class ContinuousBatchingScheduler:
             harvest.set(finished=finished)
         self.telemetry.on_chunk(total, res.elapsed, slot_steps=slot_steps,
                                 deliveries=len(delivered), stalled=stalled)
-        self.telemetry.on_moe(res.moe)
+        self.telemetry.on_moe(res.moe, res.moe_plan_rows)
         if res.block_counts is not None:
             self.telemetry.on_blocks(width, res.block_counts)
         if spec:

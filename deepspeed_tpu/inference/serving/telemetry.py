@@ -20,7 +20,8 @@ parentheses):
   cache (host-RAM rung) enabled only;
 - ``serving/decode_slot_steps_total``, ``serving/decode_tokens_kept_total``,
   ``serving/deliveries_total``, ``serving/deliveries_stalled_total``,
-  ``serving/moe_assignments_total``, ``serving/moe_experts_touched_total``
+  ``serving/moe_assignments_total``, ``serving/moe_experts_touched_total``,
+  ``serving/moe_plan_rows_total``
   (expert layers only), ``serving/ssm_state_bytes`` (layers with a per-slot state only),
   ``serving/kv_latent_row_bytes`` (latent-attention layers only),
   ``serving/block_forwards_total``, ``serving/blocks_committed_total``,
@@ -130,6 +131,7 @@ class ServingTelemetry:
         # expert layers (a model without them leaves these at 0 and unpublished)
         self.moe_assignments = 0
         self.moe_experts_touched = 0
+        self.moe_plan_rows = 0
         self.block_forwards = 0
         self.blocks_committed = 0
         self.positions_unmasked = 0
@@ -199,7 +201,9 @@ class ServingTelemetry:
             ev += [("serving/moe_assignments_total",
                     float(self.moe_assignments), self._tick),
                    ("serving/moe_experts_touched_total",
-                    float(self.moe_experts_touched), self._tick)]
+                    float(self.moe_experts_touched), self._tick),
+                   ("serving/moe_plan_rows_total",
+                    float(self.moe_plan_rows), self._tick)]
         if self.block_forwards:
             ev += [("serving/block_forwards_total", float(self.block_forwards),
                     self._tick),
@@ -245,13 +249,15 @@ class ServingTelemetry:
         else:
             self.prefix_misses += 1
 
-    def on_moe(self, stats) -> None:
+    def on_moe(self, stats, plan_rows: int = 0) -> None:
         """``stats`` = (assignments on held experts, distinct held experts
         read) of one compiled program, summed over its expert layers and
-        steps; None from a model without expert layers."""
+        steps; None from a model without expert layers. ``plan_rows``: the
+        rows its dispatch plans laid out for them (a static count)."""
         if stats is not None:
             self.moe_assignments += int(stats[0])
             self.moe_experts_touched += int(stats[1])
+            self.moe_plan_rows += int(plan_rows)
 
     def on_blocks(self, forwards: int, counts) -> None:
         """One decode chunk of a model that generates by diffusion over

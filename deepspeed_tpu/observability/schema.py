@@ -76,6 +76,12 @@ TAGS: Dict[str, Tuple[str, str]] = {
                                                "that fell on experts this "
                                                "program holds, over prefills "
                                                "and decode chunks"),
+    "serving/moe_plan_rows_total": (COUNTER, "rows the expert layers' dispatch "
+                                             "plans laid out (a program's "
+                                             "static worst case x its expert "
+                                             "layers and forwards, counted on "
+                                             "the host): moe_assignments_total "
+                                             "over this is the live share"),
     "serving/moe_experts_touched_total": (COUNTER, "distinct held experts "
                                                    "read, summed over expert "
                                                    "layers and steps: the "

@@ -200,30 +200,48 @@ def check_moe_decode_ffn() -> float:
     return _err(o1, moe_decode_ffn_xla(x, idx, w1, b1, w2, b2, act))
 
 
-def check_moe_grouped_ffn(tokens: int = 32) -> float:
+def check_moe_grouped_ffn(tokens: int = 32, held: int = 128, of: int = 512,
+                          k: int = 22, lat: int = 1024, f: int = 2688,
+                          gated: bool = False) -> float:
     """The grouped expert kernel at the serving benchmark's shapes (128 held
     experts of 512, 22 a token, latent 1024 -> 2688 -> 1024, bf16, squared
     ReLU): 32 tokens is a decode step of 32 slots (tiles of 16 rows), 512 a
-    prompt bucket (tiles of 32). Against the ``jax.numpy`` form on the same
-    plan; rows of tiles past the last real one are compared too (zeros)."""
+    prompt bucket (tiles of 32); with ``gated`` the three-matrix SiLU form.
+    Against the ``jax.numpy`` form on the same plan, over the rows of the
+    LIVE tiles: a tile past the last real one is neither fetched nor written
+    (the kernel's cost follows what is held, not the worst case the shapes
+    allow), so its rows hold whatever the memory held and nothing reads
+    them. The reference copies a tile's expert, so it walks the live tiles a
+    GiB of copies at a time."""
     import jax
     import jax.numpy as jnp
     from ..moe.latent_moe import relu2
     from .moe.grouped_ffn import (dispatch_plan, grouped_ffn, grouped_ffn_xla,
                                   tile_rows)
     rng = np.random.RandomState(6)
-    e, lat, f, k = 128, 1024, 2688, 22
-    idx = jnp.asarray(np.stack([rng.permutation(512)[:k] for _ in range(tokens)]),
+    idx = jnp.asarray(np.stack([rng.permutation(of)[:k] for _ in range(tokens)]),
                       jnp.int32)
     z = jnp.asarray(rng.standard_normal((tokens, lat)), jnp.bfloat16)
-    w1 = jax.random.normal(jax.random.PRNGKey(1), (e, lat, f), jnp.bfloat16) * lat ** -0.5
-    w2 = jax.random.normal(jax.random.PRNGKey(2), (e, f, lat), jnp.bfloat16) * f ** -0.5
+    w1 = jax.random.normal(jax.random.PRNGKey(1), (held, lat, f), jnp.bfloat16) * lat ** -0.5
+    w2 = jax.random.normal(jax.random.PRNGKey(2), (held, f, lat), jnp.bfloat16) * f ** -0.5
+    wg = jax.random.normal(jax.random.PRNGKey(3), (held, lat, f), jnp.bfloat16) \
+        * lat ** -0.5 if gated else None
+    act = jax.nn.silu if gated else relu2
     tm = tile_rows(tokens * k)
-    plan = jax.jit(partial(dispatch_plan, first=0, count=e, tm=tm))(idx)
-    args = (z[plan["row_token"]], plan["tile_expert"], plan["tile_valid"], w1, w2)
-    got = jax.jit(partial(grouped_ffn, act=relu2, tm=tm))(*args)
-    want = jax.jit(partial(grouped_ffn_xla, act=relu2, tm=tm))(*args)
-    return _err(got, want)
+    plan = jax.jit(partial(dispatch_plan, first=0, count=held, tm=tm))(idx)
+    x_rows, te, tv = z[plan["row_token"]], plan["tile_expert"], plan["tile_valid"]
+    got = jax.jit(partial(grouped_ffn, act=act, tm=tm))(x_rows, te, tv, w1, w2,
+                                                        w_gate=wg)
+    live = int(tv.sum())
+    per = max(1, 2 ** 30 // ((2 + gated) * lat * f * 2))
+    want = jax.jit(partial(grouped_ffn_xla, act=act, tm=tm))
+    err = 0.0
+    for t in range(0, live, per):
+        n = min(per, live - t)
+        rows = slice(t * tm, (t + n) * tm)
+        err = max(err, _err(got[rows], want(x_rows[rows], te[t:t + n], tv[t:t + n],
+                                            w1, w2, w_gate=wg)))
+    return err
 
 
 def _check_qmm(bits: int, m: int) -> float:
@@ -267,6 +285,11 @@ KERNEL_CHECKS: Dict[str, Tuple] = {
     # PR 27), so the tolerance is a bf16 step of an output near 4, not a model
     "moe_grouped_ffn_decode": (check_moe_grouped_ffn, 0.03),
     "moe_grouped_ffn_prefill": (partial(check_moe_grouped_ffn, tokens=512), 0.03),
+    # sarvam-105b.doc4k32's prefill: 4,096 tokens x 8, 16 held of 128, gated
+    # 4096 x 2048 in two width blocks, ~1 tile in 8 live
+    "moe_grouped_ffn_long_prefill": (partial(
+        check_moe_grouped_ffn, tokens=4096, held=16, of=128, k=8, lat=4096,
+        f=2048, gated=True), 0.03),
     # bf16 activations x dequantized weights, f32 accumulate; relative to
     # the output scale sqrt(k): the kernel rounds w to bf16 before the dot,
     # the reference keeps it f32

@@ -9,7 +9,8 @@ expert reuse the block already in VMEM and an expert nobody chose is never
 fetched. Per tile: ``act(x W1_e) W2_e``, or with a gate matrix the gated form
 ``(act(x Wg_e) * (x W1_e)) W2_e`` (the same kernel with one weight block more).
 Tiles beyond the last real one (the grid is sized for the worst case) point at
-the last real tile's expert — no fetch — and write zeros.
+the last real tile's expert, rows and output block — no fetch — and write
+nothing: a layer call costs what is held here, not what the shapes allow.
 
 ``dispatch_plan`` also says where each assignment's row went, so the caller
 gathers its ``k`` rows back per token and weights them: no scatter-add.
@@ -28,6 +29,10 @@ from ...utils.device import pallas_interpret as _interpret
 # 2 x (W1 + W2, and the gate's where there is one) blocks of an expert in
 # flight plus the tile's activations
 VMEM_LIMIT_BYTES = 64 * 2 ** 20
+
+# the most assignments (T * k) whose plan the dense comparisons count faster
+# than the prefix sums: see ``plan_by_prefix_sums``
+DENSE_PLAN_UP_TO = 4096
 
 
 def width_blocks(l: int, f: int, mats: int, itemsize: int) -> int:
@@ -53,6 +58,30 @@ def tile_rows(assignments: int) -> int:
     return 16 if assignments <= 2048 else 32
 
 
+def plan_rows(assignments: int, count: int) -> int:
+    """Rows :func:`dispatch_plan` lays out for ``assignments`` = T * k over
+    ``count`` held experts at :func:`tile_rows`: the worst case, every
+    assignment held and each expert's last tile part filled. A static shape;
+    what is live of it the plan says (``tile_valid``)."""
+    tm = tile_rows(assignments)
+    return (assignments // tm + min(count, assignments)) * tm
+
+
+def plan_by_prefix_sums(assignments: int) -> bool:
+    """Which way :func:`dispatch_plan` counts. The dense comparisons cost
+    ``A x A`` and ``R x A`` operations, the prefix sums ``A x count`` and one
+    scatter of ``A`` numbers whose cost hardly falls with ``A``. One plan on
+    a v5e, ms, dense | prefix sums (PR 58, 16 to 128 held experts; the held
+    count moved neither column past the other): A 704 0.018 | 0.020, 1,408
+    0.022 | 0.026, 2,048 0.022 | 0.052, 4,096 0.052-0.057 | 0.064-0.093,
+    5,632 0.149 | 0.074, 8,192 0.20-0.25 | 0.09-0.12, 11,264 0.413 | 0.144,
+    16,384 0.692 | 0.124, 32,768 2.60-2.80 | 0.21-0.36. So a decode step
+    (A 128 to 2,048) and a short prompt count densely, with no sort and no
+    scatter; a prompt of more than ``DENSE_PLAN_UP_TO`` assignments by
+    prefix sums."""
+    return assignments > DENSE_PLAN_UP_TO
+
+
 def dispatch_plan(idx, first: int, count: int, tm: int, valid=None):
     """Lay the assignments that fall on experts ``[first, first + count)``
     out in tiles of ``tm`` rows, one expert a tile.
@@ -63,7 +92,12 @@ def dispatch_plan(idx, first: int, count: int, tm: int, valid=None):
     padding); ``pos`` (T, k) the row of each assignment, R where it is not
     held here; ``held`` (T, k) bool; ``tile_expert`` / ``tile_valid`` (NT,)
     int32; ``n_assigned`` and ``n_touched`` (scalars): assignments on held
-    experts and distinct held experts with at least one."""
+    experts and distinct held experts with at least one.
+
+    One plan, two ways to count it, chosen by the static ``A = T * k``
+    (:func:`plan_by_prefix_sums`): an assignment's rank among its expert's and
+    the token of a row are dense comparisons at a decode step's sizes and
+    prefix sums with one scatter at a long prompt's."""
     T, k = idx.shape
     A = T * k
     NT = A // tm + min(count, A)
@@ -72,16 +106,24 @@ def dispatch_plan(idx, first: int, count: int, tm: int, valid=None):
     held = (local >= 0) & (local < count)
     if valid is not None:
         held = held & valid[:, None]
-    # No sort and no scatter: both are slow on the chip beside a few dense
-    # comparisons of these sizes (A x A, A x count, R x A), which fuse.
     key = jnp.where(held, local, count).reshape(A).astype(jnp.int32)
     a = jnp.arange(A, dtype=jnp.int32)
     experts = jnp.arange(count, dtype=jnp.int32)
     on = key[:, None] == experts[None, :]                             # (A, count)
-    here = jnp.sum(on, axis=0, dtype=jnp.int32)
-    # rank of an assignment among the earlier ones of its expert
-    rank = jnp.sum((key[:, None] == key[None, :]) & (a[None, :] < a[:, None]),
-                   axis=1, dtype=jnp.int32)
+    prefix = plan_by_prefix_sums(A)
+    if prefix:
+        # the running count of each expert's assignments, read at the
+        # assignment's own column
+        running = jnp.cumsum(on, axis=0, dtype=jnp.int32)
+        here = running[-1]
+        rank = jnp.sum(jnp.where(on, running, 0), axis=1) - 1
+    else:
+        # No sort and no scatter: both are slow on the chip beside a few dense
+        # comparisons of these sizes (A x A, A x count, R x A), which fuse.
+        here = jnp.sum(on, axis=0, dtype=jnp.int32)
+        # rank of an assignment among the earlier ones of its expert
+        rank = jnp.sum((key[:, None] == key[None, :]) & (a[None, :] < a[:, None]),
+                       axis=1, dtype=jnp.int32)
     padded = (here + tm - 1) // tm * tm
     pad_end = jnp.sum(jnp.where(experts[None, :] <= experts[:, None],
                                 padded[None, :], 0), axis=1)          # cumsum
@@ -89,9 +131,15 @@ def dispatch_plan(idx, first: int, count: int, tm: int, valid=None):
     dest = jnp.where(key < count,
                      jnp.sum(jnp.where(on, pad_start[None, :], 0), axis=1) + rank,
                      R).astype(jnp.int32)
-    rows = jnp.arange(R, dtype=jnp.int32)
-    row_token = jnp.sum(jnp.where(dest[None, :] == rows[:, None],
-                                  (a // k)[None, :], 0), axis=1, dtype=jnp.int32)
+    if prefix:
+        # the inverse of ``dest``: held assignments have a row each, the others
+        # (R: past the end) are dropped
+        row_token = jnp.zeros((R,), jnp.int32).at[dest].set(
+            a // k, mode="drop", unique_indices=True)
+    else:
+        rows = jnp.arange(R, dtype=jnp.int32)
+        row_token = jnp.sum(jnp.where(dest[None, :] == rows[:, None],
+                                      (a // k)[None, :], 0), axis=1, dtype=jnp.int32)
     pos = dest.reshape(T, k)
     n_tiles = pad_end[-1] // tm
     tiles = jnp.arange(NT, dtype=jnp.int32)
@@ -126,9 +174,13 @@ def grouped_ffn_xla(x_rows, tile_expert, tile_valid, w1, w2, act, tm: int,
     return y.reshape(NT * tm, -1)
 
 
-def _kernel(te_ref, tv_ref, x_ref, *refs, act):
-    """``refs``: the expert's weight blocks ``[gate,] w1, w2`` and the output."""
-    *gate, w1_ref, w2_ref, o_ref = refs
+def _kernel(te_ref, tv_ref, last_ref, x_ref, *refs, act, gated: bool):
+    """``refs``: the expert's weight blocks ``[gate,] w1, w2``, then the
+    result of the width blocks before this one where there is one (added to
+    this block's product), and the output. A tile past the last real one
+    writes nothing: its blocks are the last real tile's, still in VMEM."""
+    *gate, w1_ref, w2_ref = refs[:2 + gated]
+    *before, o_ref = refs[2 + gated:]
     i = pl.program_id(0)
 
     @pl.when(tv_ref[i] == 1)
@@ -139,12 +191,9 @@ def _kernel(te_ref, tv_ref, x_ref, *refs, act):
                             preferred_element_type=jnp.float32)) * h
         else:
             h = act(h)
-        o_ref[...] = jnp.dot(h.astype(w2_ref.dtype), w2_ref[...],
-                             preferred_element_type=jnp.float32)
-
-    @pl.when(tv_ref[i] == 0)
-    def _():
-        o_ref[...] = jnp.zeros_like(o_ref)
+        y = jnp.dot(h.astype(w2_ref.dtype), w2_ref[...],
+                    preferred_element_type=jnp.float32)
+        o_ref[...] = before[0][...] + y if before else y
 
 
 def grouped_ffn(x_rows, tile_expert, tile_valid, w1, w2, act, tm: int,
@@ -153,10 +202,15 @@ def grouped_ffn(x_rows, tile_expert, tile_valid, w1, w2, act, tm: int,
     ``w2`` (e, f, l) the held experts, ``w_gate`` (e, l, f) their gates where
     the expert is gated (``(act(x Wg) * (x W1)) W2``; None: ``act(x W1) W2``).
     Returns (NT * tm, l) float32; rows of padding hold whatever token 0 gives
-    and are never gathered back. The kernel's name in a trace is
-    ``moe_grouped_ffn`` in both forms. Experts too wide for the kernel's VMEM
-    are cut over their width (:func:`width_blocks`): one kernel call a block,
-    their results added."""
+    and rows of the tiles past the last real one (``tile_valid`` 0: the plan
+    puts every real tile first) whatever the memory held: neither is ever
+    gathered back, and a tile that is not real costs no fetch and no write
+    (its ``x_rows`` and output blocks are the last real tile's, like its
+    expert). The kernel's name in a trace is ``moe_grouped_ffn`` in both
+    forms. Experts too wide for the kernel's VMEM are cut over their width
+    (:func:`width_blocks`): one kernel call a block, each adding its product
+    to the result of the one before, which it is handed as an aliased operand
+    (no ``(R, l)`` sum outside the kernel)."""
     R, l = x_rows.shape
     f = w1.shape[2]
     NT = R // tm
@@ -166,31 +220,39 @@ def grouped_ffn(x_rows, tile_expert, tile_valid, w1, w2, act, tm: int,
     gate = [] if w_gate is None else [w_gate]
     n = width_blocks(l, f, 2 + len(gate), w1.dtype.itemsize)
     fb = f // n
+    # the last real tile (0 where no assignment is held: nothing is written)
+    last = jnp.maximum(jnp.sum(tile_valid, dtype=jnp.int32) - 1, 0).reshape(1)
 
-    def call(b):
-        up = pl.BlockSpec((None, l, fb), lambda i, te, tv: (te[i], 0, b))
+    def rows(i, te, tv, last):
+        return (jnp.minimum(i, last[0]), 0)
+
+    def call(b, *before):
+        up = pl.BlockSpec((None, l, fb), lambda i, te, tv, last: (te[i], 0, b))
         grid_spec = pltpu.PrefetchScalarGridSpec(
-            num_scalar_prefetch=2,
+            num_scalar_prefetch=3,
             grid=(NT,),
-            in_specs=[pl.BlockSpec((tm, l), lambda i, te, tv: (i, 0))]
+            in_specs=[pl.BlockSpec((tm, l), rows)]
             + [up] * (len(gate) + 1)
-            + [pl.BlockSpec((None, fb, l), lambda i, te, tv: (te[i], b, 0))],
-            out_specs=pl.BlockSpec((tm, l), lambda i, te, tv: (i, 0)),
+            + [pl.BlockSpec((None, fb, l), lambda i, te, tv, last: (te[i], b, 0))]
+            + [pl.BlockSpec((tm, l), rows)] * len(before),
+            out_specs=pl.BlockSpec((tm, l), rows),
         )
+        operands = (tile_expert, tile_valid, last, x_rows, *gate, w1, w2, *before)
         return pl.pallas_call(
-            functools.partial(_kernel, act=act),
+            functools.partial(_kernel, act=act, gated=bool(gate)),
             out_shape=jax.ShapeDtypeStruct((R, l), jnp.float32),
             grid_spec=grid_spec,
+            input_output_aliases={len(operands) - 1: 0} if before else {},
             compiler_params=pltpu.CompilerParams(
                 dimension_semantics=("arbitrary",),
                 vmem_limit_bytes=VMEM_LIMIT_BYTES),
             name="moe_grouped_ffn",
             interpret=_interpret(),
-        )(tile_expert, tile_valid, x_rows, *gate, w1, w2)
+        )(*operands)
 
     out = call(0)
     for b in range(1, n):
-        out = out + call(b)
+        out = call(b, out)
     return out
 
 

@@ -46,7 +46,11 @@ def test_the_gated_kernel_agrees_with_its_jnp_form_and_with_each_expert(T, tm):
     args = (x, plan["tile_expert"], plan["tile_valid"], up, down, jax.nn.silu, tm)
     a = grouped_ffn(*args, w_gate=gate)
     b = grouped_ffn_xla(*args, w_gate=gate)
-    np.testing.assert_allclose(np.asarray(a), np.asarray(b), rtol=1e-5, atol=1e-5)
+    # rows of the live tiles: a tile past the last real one is not written
+    live = np.repeat(np.asarray(plan["tile_valid"]) == 1, tm)
+    assert 0 < live.sum() < live.size
+    np.testing.assert_allclose(np.asarray(a)[live], np.asarray(b)[live],
+                               rtol=1e-5, atol=1e-5)
     pos, held = np.asarray(plan["pos"]), np.asarray(plan["held"])
     for t, j in zip(*np.nonzero(held)):
         e = int(idx[t, j]) - 4
@@ -54,11 +58,10 @@ def test_the_gated_kernel_agrees_with_its_jnp_form_and_with_each_expert(T, tm):
         want = (g / (1 + np.exp(-g)) * (np.asarray(z[t]) @ np.asarray(up[e]))) \
             @ np.asarray(down[e])
         np.testing.assert_allclose(np.asarray(a[pos[t, j]]), want, rtol=1e-4, atol=1e-4)
-    dead = np.repeat(np.asarray(plan["tile_valid"]) == 0, tm)
-    assert not np.asarray(a)[dead].any()
     plain = grouped_ffn(*args)
-    assert np.abs(np.asarray(plain) - np.asarray(a)).max() > 1e-2
-    np.testing.assert_allclose(np.asarray(plain), np.asarray(grouped_ffn_xla(*args)),
+    assert np.abs(np.asarray(plain)[live] - np.asarray(a)[live]).max() > 1e-2
+    np.testing.assert_allclose(np.asarray(plain)[live],
+                               np.asarray(grouped_ffn_xla(*args))[live],
                                rtol=1e-5, atol=1e-5)
 
 

@@ -94,7 +94,12 @@ def test_the_grouped_kernel_agrees_with_its_jnp_form(T, tm):
     x = z[plan["row_token"]]
     a = grouped_ffn(x, plan["tile_expert"], plan["tile_valid"], w1, w2, relu2, tm)
     b = grouped_ffn_xla(x, plan["tile_expert"], plan["tile_valid"], w1, w2, relu2, tm)
-    np.testing.assert_allclose(np.asarray(a), np.asarray(b), rtol=1e-5, atol=1e-5)
+    # over the rows of the live tiles: a tile past the last real one is not
+    # written (PR 58), and nothing gathers its rows back
+    live = np.repeat(np.asarray(plan["tile_valid"]) == 1, tm)
+    assert 0 < live.sum() < live.size
+    np.testing.assert_allclose(np.asarray(a)[live], np.asarray(b)[live],
+                               rtol=1e-5, atol=1e-5)
     # and each held assignment's row is that expert's feed-forward of its token
     pos, held = np.asarray(plan["pos"]), np.asarray(plan["held"])
     for t, j in zip(*np.nonzero(held)):
@@ -102,9 +107,6 @@ def test_the_grouped_kernel_agrees_with_its_jnp_form(T, tm):
         want = np.square(np.maximum(np.asarray(z[t]) @ np.asarray(w1[e]), 0)) \
             @ np.asarray(w2[e])
         np.testing.assert_allclose(np.asarray(a[pos[t, j]]), want, rtol=1e-4, atol=1e-4)
-    # rows of tiles beyond the last real one are zero
-    dead = np.repeat(np.asarray(plan["tile_valid"]) == 0, tm)
-    assert not np.asarray(a)[dead].any()
 
 
 def _moe_layer(cfg, params, index, x):

@@ -42,10 +42,14 @@ def test_the_grouped_expert_kernel_compiles_at_the_published_widths(
         sds((held, width, latent), jnp.bfloat16)).compile()
     text = compiled.as_text()
     assert "tpu_custom_call" in text and "moe_grouped_ffn" in text
-    # the plan lowers for the chip too (no sort, no scatter in it)
+    # the plan lowers for the chip too: no sort, and no scatter at a decode
+    # step's sizes; the 512-token bucket's 11,264 assignments count by prefix
+    # sums and ONE scatter since PR 58, because the chip read that plan at
+    # 0.144 ms where the dense comparisons take 0.413 (``plan_by_prefix_sums``)
     plan = jax.jit(functools.partial(g.dispatch_plan, first=0, count=held, tm=tm)).lower(
         sds((tokens, k), jnp.int32)).compile().as_text()
-    assert " sort(" not in plan and " scatter(" not in plan
+    assert " sort(" not in plan
+    assert plan.count(" scatter(") == g.plan_by_prefix_sums(tokens * k) == (tokens == 512)
 
 
 def _bloom_layers():
@@ -558,6 +562,47 @@ def test_the_gated_expert_kernel_compiles_at_sarvams_published_widths(
     assert text.count("tpu_custom_call") == 2 and "moe_grouped_ffn" in text
     # no half of a matrix is copied out: the halves are picked in the index maps
     assert f"bf16[{held},{d},{width // 2}]" not in text
+
+
+def test_sarvams_prefill_expert_layer_pays_for_what_is_held(one_chip, monkeypatch):
+    """One expert layer of ``sarvam-105b.doc4k32``'s 4,096-token prefill
+    (4,096 x 8 assignments, 16 held of 128, 4096 x 2048 gated) compiles for
+    the described v5e under ``VMEM_LIMIT_BYTES``, and its program does not do
+    the worst case's work (PR 58): the plan holds no ``A x A`` and no ``R x
+    A`` comparison (prefix sums and one scatter at this size), the two width
+    blocks are two kernel calls of which the second is handed the first's
+    result aliased, and no ``f32[33280,4096]`` is added outside them."""
+    from deepspeed_tpu.ops.moe import grouped_ffn as g
+    monkeypatch.setattr(g, "_interpret", lambda: False)
+    tokens, held, d, width, k = 4096, 16, 4096, 2048, 8
+    rows = g.plan_rows(tokens * k, held)
+    assert rows == 33280 and g.plan_by_prefix_sums(tokens * k)
+
+    def sds(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    def layer(x, idx, w, up, down, gate):
+        return g.grouped_experts(x, idx, w, 0, held, up, down, jax.nn.silu, None, gate)
+
+    up = sds((held, d, width), jnp.bfloat16)
+    compiled = jax.jit(layer).lower(
+        sds((tokens, d), jnp.bfloat16), sds((tokens, k), jnp.int32),
+        sds((tokens, k), jnp.float32), up, sds((held, width, d), jnp.bfloat16),
+        up).compile()
+    text = compiled.as_text()
+    calls = [line for line in text.splitlines()
+             if "tpu_custom_call" in line and "moe_grouped_ffn" in line]
+    assert len(calls) == 2 and text.count("tpu_custom_call") == 2
+    # call 1 reads call 0's result and writes over it
+    first = re.match(r"\s*(%[\w.\-]+) = ", calls[0]).group(1)
+    assert first + ")" in calls[1] or first + "," in calls[1]
+    assert "output_to_operand_aliasing={{}: (7, {})}" in calls[1]
+    result = f"f32[{rows},{d}]"
+    assert not [line.strip()[:120] for line in text.splitlines()
+                if result in line.split(" = ")[-1][:40] and " add(" in line]
+    assert f"[{tokens * k},{tokens * k}]" not in text
+    assert f"[{rows},{tokens * k}]" not in text
+    assert text.count(" scatter(") == 1 and " sort(" not in text
 
 
 @pytest.mark.parametrize("tokens", [512, 4096])
