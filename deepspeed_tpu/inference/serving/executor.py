@@ -366,6 +366,8 @@ class ChunkedDecodeExecutor:
             ph.set(pages=pool.total_pages, slots=self.slots,
                    state_bytes=pool.state_nbytes,
                    heads_per_row=pool.heads_per_row)
+            if pool.ring_nbytes:
+                ph.set(ring_bytes=pool.ring_nbytes)
             return pool
 
     def reset_pool(self) -> None:
@@ -666,6 +668,10 @@ class ChunkedDecodeExecutor:
                 # is yielded: what the head gave is not a token of this model
                 tok0 = None
                 sp.set(blocks_committed=t // self.block)
+            if self.engine.model_config.prefill_stop is not None:
+                # a prefill that stops early: the layers up to the stop ran
+                # at every position of the bucket, those after it at one
+                sp.set(positions_self=bucket, positions_cross=1)
         with tracer.span("serving.scatter_prefill"):
             self.pool.scatter_prefill(slot, one_caches)
         obs_profiler.tick("prefill")

@@ -37,10 +37,9 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from ...models.causal_lm import PAGED, init_cache
+from ...models.causal_lm import LAYER_KINDS, PAGED, init_cache
 from ...observability.trace import get_tracer
-from ...ops.paged_attention import (heads_per_row, pages_to_dense,
-                                    write_dense_pages)
+from ...ops.paged_attention import pages_to_dense, write_dense_pages
 
 
 NULL_PAGE = 0      # reserved sentinel: pads every table row; rows it could
@@ -142,19 +141,23 @@ class PagedKVPool:
         cfg = model_config
         self.n_layer = cfg.n_layer
         dtype = dtype or cfg.dtype
-        self.heads_per_row = r = heads_per_row(cfg.head_dim, cfg.kv_heads)
+        self.heads_per_row = r = cfg.cache_row_heads
         shape = (P, cfg.kv_heads // r, ps, r * cfg.head_dim)
         self.programs = 0     # compiled programs dispatched for this pool
         # two kinds of state in one manager: pages for the layers that keep
         # keys and values, a per-slot array for the layers with a recurrent
-        # state (bound to the slot, not to pages: it does not grow with the
-        # sequence), nothing for the rest
+        # state or a ring of their last rows (bound to the slot, not to
+        # pages: it does not grow with the sequence), nothing for the rest
         self.caches = init_cache(cfg, self.slots, dtype=dtype, kv_shape=shape)
         self.keeps = keeps = cfg.layer_keeps
         self.kv_layers = sum(1 for keep in keeps if keep in PAGED)
         self.state_nbytes = sum(int(a.nbytes)
                                 for keep, c in zip(keeps, self.caches)
                                 if keep not in PAGED for a in c.values())
+        # the part of the per-slot state that is windowed layers' rings
+        self.ring_nbytes = sum(int(a.nbytes)
+                               for kind, c in zip(cfg.layer_kinds, self.caches)
+                               if LAYER_KINDS[kind].ring for a in c.values())
         # a page of every paged layer: keys and values (2 x kv_heads x
         # head_dim lanes a token), or a latent layer's one row a token
         self.page_nbytes = sum(int(a.nbytes) // P
@@ -271,7 +274,8 @@ class PagedKVPool:
             self._free_slots.append(slot)
         if self.state_nbytes:
             # a recurrent state has no length to mask it by: the slot's is
-            # cleared here (and written whole again at the next admission)
+            # cleared here (and written whole again at the next admission; a
+            # ring's length does mask it, and it is cleared with the rest)
             with get_tracer().span("serving.clear_state", slot=int(slot),
                                    state_bytes=self.state_nbytes // self.slots):
                 self._move(_state_zero_jit(self.keeps), np.int32(slot))
@@ -463,5 +467,6 @@ class PagedKVPool:
             "total_pages": float(self.total_pages - 1),
             "page_size": float(self.page_size),
             "state_bytes": float(self.state_nbytes),
+            "ring_bytes": float(self.ring_nbytes),
             "latent_row_bytes": float(self.latent_row_nbytes),
         }

@@ -22,7 +22,7 @@ parentheses):
   ``serving/deliveries_total``, ``serving/deliveries_stalled_total``,
   ``serving/moe_assignments_total``, ``serving/moe_experts_touched_total``,
   ``serving/moe_plan_rows_total``
-  (expert layers only), ``serving/ssm_state_bytes`` (layers with a per-slot state only),
+  (expert layers only), ``serving/ssm_state_bytes`` (layers with a per-slot state only), ``serving/kv_ring_bytes`` (windowed layers only),
   ``serving/kv_latent_row_bytes`` (latent-attention layers only),
   ``serving/block_forwards_total``, ``serving/blocks_committed_total``,
   ``serving/positions_unmasked_total``, ``serving/blocks_merged_total`` (a model
@@ -194,6 +194,9 @@ class ServingTelemetry:
             if paged_stats.get("state_bytes"):
                 ev += [("serving/ssm_state_bytes",
                         float(paged_stats["state_bytes"]), self._tick)]
+            if paged_stats.get("ring_bytes"):
+                ev += [("serving/kv_ring_bytes",
+                        float(paged_stats["ring_bytes"]), self._tick)]
             if paged_stats.get("latent_row_bytes"):
                 ev += [("serving/kv_latent_row_bytes",
                         float(paged_stats["latent_row_bytes"]), self._tick)]

@@ -34,6 +34,7 @@ from ..parallel.overlap import (RowParallelDense, chunked_expert_exchange,
                                 get_overlap_config, moe_overlap_chunks,
                                 raw_or_param)
 from .base import Model
+from .mamba1 import project, serving_values
 from ..utils.jax_compat import shard_map
 
 
@@ -79,9 +80,7 @@ class CausalLMConfig:
     # "xla" = w[idx] gather + einsum (lets XLA pin small expert stacks in VMEM)
     moe_decode_impl: str = "pallas"
     # One MIXER a layer, chosen by a pattern string with a letter a layer
-    # (the keys of :data:`LAYER_KINDS`: "M" Mamba-2, "*" attention, "L" latent
-    # attention, "C" gated short convolution, "E" mixture of experts, "F"
-    # dense feed-forward);
+    # (the keys of :data:`LAYER_KINDS`, which says what each is);
     # every layer is then ``x + mixer(norm(x))``. None = the classic layer
     # (attention, then feed-forward). The sizes below are read only by the
     # mixers the pattern names.
@@ -125,6 +124,20 @@ class CausalLMConfig:
     # over ``sum + moe_topk_eps`` and scaled by ``routed_scaling_factor``)
     moe_router: str = "softmax"
     moe_topk_eps: float = 1e-20
+    # DIFFERENTIAL attention (Ye et al., arXiv 2410.05258, as SambaY has it):
+    # every attention kind of the model ("*", "W", "X") takes its heads in
+    # adjacent PAIRS, two softmax maps over one pair of key heads and one
+    # 2 x head_dim value, subtracted under a learned lambda and normed
+    # (:meth:`MixerLayer._diff_attention`); its biases are ``qkv_bias``'s.
+    # ``sliding_window``: the keys a "W" layer's query sees, itself and the
+    # ``sliding_window - 1`` before it
+    diff_attention: bool = False
+    sliding_window: int = 0
+    # Mamba-1 ("S" layers; ``models/mamba1.py``): ``mamba1_d_inner`` channels,
+    # ``ssm_state_size`` state lanes a channel, ``mamba1_dt_rank``,
+    # ``conv_kernel`` taps. A gated memory unit ("G") is as wide
+    mamba1_d_inner: int = 0
+    mamba1_dt_rank: int = 0
     mamba_num_heads: int = 0
     mamba_head_dim: int = 0
     ssm_state_size: int = 0
@@ -212,6 +225,7 @@ class CausalLMConfig:
                 raise ValueError(
                     f"layer_pattern={self.layer_pattern!r} must be {self.n_layer} "
                     f"letters of {PATTERN_KINDS}")
+            self._check_streams()
             if self.experts_held is not None:
                 first, count = (int(v) for v in self.experts_held)
                 if not (0 <= first and count >= 1
@@ -220,6 +234,31 @@ class CausalLMConfig:
                         f"experts_held={self.experts_held} is no share of "
                         f"{self.n_routed_experts} experts")
                 self.experts_held = (first, count)
+
+    def _check_streams(self):
+        """A layer that READS an earlier layer (:attr:`LayerKind.reads`) has one
+        that writes what it reads before it; the paired kinds have pairs."""
+        pattern = self.layer_pattern
+        for i, k in enumerate(pattern):
+            reads = LAYER_KINDS[k].reads
+            if reads and not any(LAYER_KINDS[j].writes == reads for j in pattern[:i]):
+                raise ValueError(
+                    f"layer_pattern={pattern!r}: layer {i} is a {LAYER_KINDS[k].name} "
+                    f"layer, which reads the {reads} of an earlier layer, and no "
+                    "layer before it hands one on")
+        paired = set(pattern) & {"W", "X"}
+        if paired and not self.diff_attention:
+            raise ValueError(f"layers {sorted(paired)} are differential attention's: "
+                             "diff_attention must be set")
+        if self.diff_attention:
+            pairs, rows = self.n_head // 2, self.kv_heads // 2
+            if self.n_head % 2 or self.kv_heads % 2 or pairs % rows:
+                raise ValueError(
+                    f"differential attention pairs adjacent heads: {self.n_head} "
+                    f"query and {self.kv_heads} key heads are not whole pairs, "
+                    "the query pairs a multiple of the key pairs")
+        if "W" in pattern and self.sliding_window < 1:
+            raise ValueError("a windowed layer needs sliding_window >= 1")
 
     def layer_kind(self, i: int) -> str:
         """``"A"`` for the classic layer (attention + feed-forward), else the
@@ -253,6 +292,35 @@ class CausalLMConfig:
         return tuple(dict.fromkeys(
             LAYER_KINDS[k].name for k in self.layer_kinds
             if LAYER_KINDS[k].keeps == "state"))
+
+    @property
+    def cache_row_heads(self) -> int:
+        """KV heads a cache row holds side by side (``init_cache`` and the
+        pool ask): a differential pair where attention is differential (a
+        row IS ``[k1 ; k2]``), else ``ops/paged_attention.heads_per_row``."""
+        return 2 if self.diff_attention else heads_per_row(self.head_dim,
+                                                           self.kv_heads)
+
+    def mixer_depth(self, i: int) -> int:
+        """Layer ``i``'s index among the layers that are no feed-forward
+        ("F", "E"): the published layer index where every mixer is followed
+        by one (differential attention's ``lambda_init`` reads it)."""
+        return sum(1 for k in self.layer_kinds[:i] if k not in "FE")
+
+    @property
+    def prefill_stop(self) -> Optional[int]:
+        """The layer from which a prefill needs ONE position a sequence: the
+        last layer that keeps anything, where that is a full differential
+        attention layer whose cache the layers after it re-read (they read
+        other positions through that cache alone). None: every layer runs at
+        every position."""
+        kinds = self.layer_kinds
+        last = max((i for i, keep in enumerate(self.layer_keeps)
+                    if keep != "nothing"), default=None)
+        if (last is None or kinds[last] != "*" or not self.diff_attention
+                or not any(LAYER_KINDS[k].reads == "kv" for k in kinds[last + 1:])):
+            return None
+        return last
 
     @property
     def latent_layers(self) -> bool:
@@ -326,8 +394,9 @@ class CausalLMConfig:
         d, L, v = self.n_embd, self.n_layer, self.vocab_size
         if self.layer_pattern is not None:
             # a mixer layer: its mixer (the table's count) and its norm
-            return (v * d + sum(LAYER_KINDS[k].params(self) + d
-                                for k in self.layer_pattern) + d
+            norm = d if self.layernorm == "rmsnorm" else 2 * d
+            return (v * d + sum(LAYER_KINDS[k].params(self) + norm
+                                for k in self.layer_pattern) + norm
                     + (0 if self.tie_word_embeddings else v * d))
         f = self.ffn_dim
         mlp = d * f * (3 if self.gated_mlp else 2)
@@ -348,18 +417,52 @@ class LayerKind:
     (``ops/paged_attention.latent_row_lanes``); ``"state"`` one array a slot that
     every token overwrites, made by ``state(cfg, rows, dtype)``;
     ``"nothing"``, an empty cache), the parameters its mixer holds (``params(cfg)``, its norm
-    not counted) and the method of :class:`MixerLayer` that runs it."""
+    not counted) and the method of :class:`MixerLayer` that runs it. ``ring``: the
+    state is a ring of the last ``sliding_window`` rows of keys and values.
+    ``writes`` / ``reads``: what the layer hands on to later layers of the same
+    forward, and what it reads of an earlier one (``"kv"`` the cache of a layer
+    that keeps keys and values, ``"memory"`` a state-space layer's scan output):
+    the streams :class:`CausalLM` carries beside ``x``."""
     name: str
     keeps: str
     params: Optional[Callable] = None
     state: Optional[Callable] = None
     mixer: Optional[str] = None
+    ring: bool = False
+    writes: Optional[str] = None
+    reads: Optional[str] = None
 
 
 def _attention_params(cfg: CausalLMConfig) -> int:
     d, q = cfg.n_embd, cfg.n_head * cfg.head_dim
+    if cfg.diff_attention:
+        return _cross_attention_params(cfg) + 2 * _kv_proj_params(cfg)
     return (d * q + 2 * d * cfg.kv_heads * cfg.head_dim + q * d
             + (2 * cfg.head_dim if cfg.qk_norm else 0))
+
+
+def _kv_proj_params(cfg: CausalLMConfig) -> int:
+    width = cfg.kv_heads * cfg.head_dim
+    return cfg.n_embd * width + (width if cfg.qkv_bias else 0)
+
+
+def _cross_attention_params(cfg: CausalLMConfig) -> int:
+    """Differential attention without keys and values of its own: the query
+    and output projections, four lambda vectors, the norm over a pair."""
+    d, q = cfg.n_embd, cfg.n_head * cfg.head_dim
+    return (d * q + q * d + (q + d if cfg.qkv_bias else 0) + 4 * cfg.head_dim
+            + 2 * cfg.head_dim)
+
+
+def _mamba1_params(cfg: CausalLMConfig) -> int:
+    d, c, n, rank = (cfg.n_embd, cfg.mamba1_d_inner, cfg.ssm_state_size,
+                     cfg.mamba1_dt_rank)
+    return (d * 2 * c + (cfg.conv_kernel + 1) * c + c * (rank + 2 * n)
+            + rank * c + c + n * c + c + c * d)
+
+
+def _gated_memory_params(cfg: CausalLMConfig) -> int:
+    return 2 * cfg.n_embd * cfg.mamba1_d_inner
 
 
 def _latent_attention_params(cfg: CausalLMConfig) -> int:
@@ -405,6 +508,23 @@ def _mamba_state(cfg: CausalLMConfig, batch_size: int, dtype) -> Dict:
                               cfg.ssm_state_size), jnp.float32)}
 
 
+def _mamba1_state(cfg: CausalLMConfig, batch_size: int, dtype) -> Dict:
+    """A Mamba-1 layer's state: the convolution's last ``conv_kernel - 1``
+    inputs (serving type) and the recurrent state, the channels on the lanes
+    (float32)."""
+    c = cfg.mamba1_d_inner
+    return {"conv": jnp.zeros((batch_size, cfg.conv_kernel - 1, c), dtype),
+            "ssm": jnp.zeros((batch_size, cfg.ssm_state_size, c), jnp.float32)}
+
+
+def _window_state(cfg: CausalLMConfig, batch_size: int, dtype) -> Dict:
+    """A windowed layer's ring: the last ``sliding_window`` tokens' keys and
+    values in rows of a head pair, token ``t`` at row ``t mod sliding_window``
+    (the model has no positions, so the order of the rows is free)."""
+    shape = (batch_size, cfg.kv_heads // 2, cfg.sliding_window, 2 * cfg.head_dim)
+    return {"k": jnp.zeros(shape, dtype), "v": jnp.zeros(shape, dtype)}
+
+
 def _short_conv_state(cfg: CausalLMConfig, batch_size: int, dtype) -> Dict:
     """A gated short convolution's state: the last ``conv_kernel - 1``
     products ``B * u`` (serving type), and nothing else."""
@@ -418,11 +538,21 @@ def _short_conv_state(cfg: CausalLMConfig, batch_size: int, dtype) -> Dict:
 #: layer (attention and feed-forward in one module) and stands in no pattern.
 LAYER_KINDS: Dict[str, LayerKind] = {
     "A": LayerKind("classic", "kv"),
-    "*": LayerKind("attention", "kv", _attention_params),
-    "L": LayerKind("latent-attention", "latent", _latent_attention_params),
+    "*": LayerKind("attention", "kv", _attention_params, mixer="_self_attention",
+                   writes="kv"),
+    "L": LayerKind("latent-attention", "latent", _latent_attention_params,
+                   mixer="_latent"),
     "M": LayerKind("state-space", "state", _mamba_params, _mamba_state, "_mamba"),
+    "S": LayerKind("selective-state-space", "state", _mamba1_params,
+                   _mamba1_state, "_mamba1", writes="memory"),
     "C": LayerKind("short-convolution", "state", _short_conv_params,
                    _short_conv_state, "_short_conv"),
+    "W": LayerKind("window-attention", "state", _attention_params, _window_state,
+                   "_diff_attention", ring=True),
+    "X": LayerKind("cross-attention", "nothing", _cross_attention_params,
+                   mixer="_diff_attention", reads="kv"),
+    "G": LayerKind("gated-memory", "nothing", _gated_memory_params,
+                   mixer="_gated_memory", reads="memory"),
     "E": LayerKind("expert", "nothing", _experts_params, mixer="_experts"),
     "F": LayerKind("feed-forward", "nothing", _ffn_params, mixer="_ffn"),
 }
@@ -783,6 +913,78 @@ def sarvam_mla_cfg(*, hidden_size, num_hidden_layers, vocab_size,
         experts_held=None if experts_held is None else tuple(experts_held), **kw)
 
 
+def phi4flash_cfg(*, hidden_size, num_hidden_layers, vocab_size,
+                  num_attention_heads, num_key_value_heads, intermediate_size,
+                  sliding_window, mb_per_layer=2, mamba_d_state=16, mamba_d_conv=4,
+                  mamba_expand=2, mamba_dt_rank="auto", mamba_conv_bias=True,
+                  mamba_proj_bias=False, layer_norm_eps=1e-5, hidden_act="silu",
+                  tie_word_embeddings=True, mlp_bias=False, lm_head_bias=False,
+                  attention_bias=True, embd_pdrop=0.0, resid_pdrop=0.0,
+                  attention_dropout=0.0, max_position_embeddings=None,
+                  model_type="phi4flash", **kw) -> CausalLMConfig:
+    """Phi-4-mini-flash (``model_type: phi4flash``; SambaY, arXiv 2507.06607):
+    the keywords are the published config's and, from ``mamba_d_state`` to
+    ``attention_bias``, the defaults of the family's configuration class. A
+    published layer is a mixer and then a SwiGLU, each ``x + f(layernorm(x))``:
+    here a pair of mixer layers, ``n_layer`` twice ``num_hidden_layers``. The
+    mixer of published layer ``l`` of ``n``, as the family's class lays them
+    out (``n % 4 == 0``): even ``l`` up to ``n / 2`` "S" (Mamba-1:
+    ``mamba_expand * hidden_size`` channels, ``mamba_d_state`` state lanes,
+    ``mamba_dt_rank`` = ``ceil(hidden_size / 16)``, ``mamba_d_conv`` taps with
+    a bias), odd ``l`` below ``n / 2`` "W" (differential attention over the
+    ``sliding_window`` keys up to the query's own), ``l = n / 2 + 1`` "*"
+    (differential attention over every key: the ONE cache that grows), odd
+    ``l`` from ``n / 2 + 3`` "X" (differential cross-attention: queries of its
+    own over layer ``n / 2 + 1``'s keys and values), even ``l`` from ``n / 2 +
+    2`` "G" (gated memory unit on the scan output of layer ``n / 2``, the last
+    Mamba). No position encoding anywhere; LayerNorm with bias; tied head.
+    Set here and not published: float32 recurrent state
+    (``benchmarks/chipbench/configs/phi-4-mini-flash-reasoning.json:
+    assumed``). Taken and not read: ``max_position_embeddings`` (the caller's
+    ``max_seq_len``), ``model_type``. What it does not build it refuses."""
+    refused = {
+        "mb_per_layer": (mb_per_layer, 2),
+        "embd_pdrop": (float(embd_pdrop), 0.0),
+        "resid_pdrop": (float(resid_pdrop), 0.0),
+        "attention_dropout": (float(attention_dropout), 0.0),
+        "hidden_act": (hidden_act, "silu"),
+        "mamba_conv_bias": (bool(mamba_conv_bias), True),
+        "mamba_proj_bias": (bool(mamba_proj_bias), False),
+        "mlp_bias": (bool(mlp_bias), False),
+        "lm_head_bias": (bool(lm_head_bias), False),
+        "num_hidden_layers % 4": (int(num_hidden_layers) % 4, 0),
+    }
+    bad = {k: got for k, (got, built) in refused.items() if got != built}
+    if bad:
+        raise NotImplementedError(
+            "phi4flash is built with a Mamba layer every second layer "
+            "(mb_per_layer 2), without dropout, with SiLU, the convolution's "
+            "bias and no other bias in a Mamba layer, the SwiGLU or the head, "
+            f"and a depth that fours divide (got {bad})")
+    n = int(num_hidden_layers)
+    half = n // 2
+
+    def mixer(l: int) -> str:
+        if l % 2 == 0:
+            return "S" if l <= half else "G"
+        return "W" if l < half else "*" if l == half + 1 else "X"
+
+    rank = (-(-int(hidden_size) // 16) if mamba_dt_rank == "auto"
+            else int(mamba_dt_rank))
+    kw.setdefault("name", "phi4flash")
+    return CausalLMConfig(
+        n_embd=hidden_size, n_layer=2 * n,
+        layer_pattern="".join(mixer(l) + "F" for l in range(n)),
+        vocab_size=vocab_size, n_head=num_attention_heads,
+        n_kv_head=num_key_value_heads, pos_emb="none", layernorm="layernorm",
+        ln_eps=layer_norm_eps, qkv_bias=bool(attention_bias), mlp_bias=False,
+        gated_mlp=True, activation="silu", d_ff=intermediate_size,
+        tie_word_embeddings=bool(tie_word_embeddings), diff_attention=True,
+        sliding_window=int(sliding_window),
+        mamba1_d_inner=int(mamba_expand) * int(hidden_size), mamba1_dt_rank=rank,
+        ssm_state_size=int(mamba_d_state), conv_kernel=int(mamba_d_conv), **kw)
+
+
 FAMILIES = {
     "gpt2": gpt2_cfg, "bloom": bloom_cfg, "opt": opt_cfg,
     "gpt_neox": gptneox_cfg, "gptj": gptj_cfg, "llama": llama_cfg,
@@ -877,6 +1079,31 @@ class QuantDense(nn.Module):
                                      parallel="column", site=self.site)
         y = x.astype(self.dtype) @ kernel.astype(self.dtype)
         return y if bias is None else y + bias.astype(self.dtype)
+
+
+class RoundedDense(nn.Module):
+    """``nn.Dense``'s parameter tree (``kernel``, ``bias``) with every
+    rounding to the serving type an explicit op (``mamba1.project``): the
+    product accumulated in float32 and rounded, the bias added and the sum
+    rounded again, whatever the compiler fuses around it. The result is the
+    serving type's. For the layers whose serving chunk and ``generate`` loop
+    must round alike on the chip (PERF.md section 6, PR 59)."""
+    features: int
+    use_bias: bool = True
+    dtype: Any = jnp.float32
+    kernel_init: Any = nn.initializers.lecun_normal()
+
+    @nn.compact
+    def __call__(self, x):
+        kernel = self.param("kernel", self.kernel_init, (x.shape[-1], self.features),
+                            jnp.float32)
+        y = project(x, kernel, self.dtype)
+        if self.use_bias:
+            bias = self.param("bias", nn.initializers.zeros, (self.features,),
+                              jnp.float32)
+            y = serving_values(y + bias.astype(self.dtype).astype(jnp.float32),
+                               self.dtype)
+        return y.astype(self.dtype)
 
 
 class _ExpertWeights(nn.Module):
@@ -1259,51 +1486,66 @@ class CausalLMLayer(nn.Module):
         return attn_out, new_kv
 
 
+@dataclasses.dataclass
+class LayerCall:
+    """What :class:`MixerLayer` hands the method that runs its mixer beside
+    the normed input: the arguments of its own call. ``carried`` holds what
+    earlier layers of this forward handed on (:attr:`LayerKind.writes`);
+    ``last_at`` (b,) is set for the ONE layer at which a prefill stops
+    (:attr:`CausalLMConfig.prefill_stop`): the mixer writes its cache for
+    every position and gives its output at that position alone."""
+    positions: Any
+    cache: Optional[Dict]
+    cache_len: Any
+    prefix_fill: bool
+    seq_lens: Any
+    block_step: bool
+    attn_mask: Any
+    carried: Dict
+    last_at: Any = None
+
+
+def _take_rows(x, at):
+    """``x`` (b, t, ...) at position ``at`` (b,) of each row: ``(b, 1, ...)``."""
+    return x[jnp.arange(x.shape[0]), at][:, None]
+
+
 class MixerLayer(CausalLMLayer):
     """A layer of ONE mixer, ``x + residual_multiplier * mixer(norm(x))`` (the
-    multiplier 1 but for Granite); ``kind`` is the letter
-    of the configuration's pattern, a key of :data:`LAYER_KINDS`: "*" this
-    module's attention (keys and values, every cache mode of
-    :class:`CausalLMLayer`), "M" a Mamba-2 mixer (state ``{"conv", "ssm"}``),
-    "C" a gated short convolution (state ``{"conv"}``), "E" a mixture of
-    experts (no state; its two counts are sown into the ``stats``
-    collection), "F" the dense feed-forward (no state). ``seq_lens`` (b,)
-    are the real lengths of right-padded rows in a prefill or a block step: a
-    recurrence must not run over the padding that a causal mask forgives, and
-    an expert layer routes it nowhere."""
+    multiplier 1 but for Granite); ``kind`` is the letter of the
+    configuration's pattern, a key of :data:`LAYER_KINDS`, whose entry names
+    the method that runs the mixer (``mixer(h, call) -> (out, new cache)``)
+    and says what the layer keeps, hands on and reads; ``index`` is the
+    layer's place in the pattern. ``seq_lens`` (b,) are the real lengths of
+    right-padded rows in a prefill or a block step: a recurrence must not run
+    over the padding that a causal mask forgives, and an expert layer routes
+    it nowhere. ``carried`` and ``last_at``: :class:`LayerCall`."""
     kind: str = "*"
+    index: int = 0
 
     @nn.compact
     def __call__(self, x, positions, cache: Optional[Dict] = None,
                  cache_len: Optional[jnp.ndarray] = None,
                  prefix_fill: bool = False, seq_lens=None,
-                 block_step: bool = False, attn_mask=None):
+                 block_step: bool = False, attn_mask=None, carried=None,
+                 last_at=None):
         cfg = self.config
         entry = LAYER_KINDS[self.kind]
         with scope("norm"):
             h = _norm(cfg, "norm")(x).astype(cfg.dtype)
-        if self.kind == "*":
-            out, new = self._attention(h, positions, cache, cache_len,
-                                       prefix_fill, block_step, attn_mask)
-        elif self.kind == "L":
-            if prefix_fill or block_step:
-                raise NotImplementedError(
-                    "a latent-attention layer has no prefill at a cache offset "
-                    "and no block step: the rows it keeps are latents, which "
-                    "only the one-token decode attends in absorbed form (no "
-                    "prefix hits, no speculative verify on a model with such "
-                    "layers)")
-            out, new = self._latent_attention(h, positions, cache, cache_len,
-                                              attn_mask)
-        else:
-            if entry.keeps == "state" and prefix_fill:
-                raise NotImplementedError(
-                    f"a {entry.name} layer cannot resume at a cache offset: its "
-                    "state after the prefix was not kept (no prefix hits, no "
-                    "speculative verify on a model with such layers)")
-            out, new = getattr(self, entry.mixer)(h, cache, seq_lens)
-            if entry.keeps == "nothing":
-                new = None if cache is None else {}
+        if entry.keeps == "state" and prefix_fill:
+            raise NotImplementedError(
+                f"a {entry.name} layer cannot resume at a cache offset: its "
+                "state after the prefix was not kept (no prefix hits, no "
+                "speculative verify on a model with such layers)")
+        out, new = getattr(self, entry.mixer)(h, LayerCall(
+            positions, cache, cache_len, prefix_fill, seq_lens, block_step,
+            attn_mask, {} if carried is None else carried, last_at))
+        if entry.keeps == "nothing":
+            new = None if cache is None else {}
+        if last_at is not None:
+            with scope("residual"):
+                x = _take_rows(x, last_at)
         with scope("residual"):
             if cfg.residual_multiplier != 1:
                 # one rounding to the stream's type, not one a factor and one a sum
@@ -1387,7 +1629,208 @@ class MixerLayer(CausalLMLayer):
                                    span="tp.o_proj", name="o_proj")(o)
         return out, new
 
-    def _mamba(self, h, cache, seq_lens):
+    def _self_attention(self, h, call):
+        if self.config.diff_attention:
+            return self._diff_attention(h, call)
+        return self._attention(h, call.positions, call.cache, call.cache_len,
+                               call.prefix_fill, call.block_step, call.attn_mask)
+
+    def _latent(self, h, call):
+        if call.prefix_fill or call.block_step:
+            raise NotImplementedError(
+                "a latent-attention layer has no prefill at a cache offset "
+                "and no block step: the rows it keeps are latents, which "
+                "only the one-token decode attends in absorbed form (no "
+                "prefix hits, no speculative verify on a model with such "
+                "layers)")
+        return self._latent_attention(h, call.positions, call.cache,
+                                      call.cache_len, call.attn_mask)
+
+    def _read(self, call, what: str, like):
+        """What an earlier layer of this forward handed on under ``what``
+        (:attr:`LayerKind.reads`). A layer initialised alone (a segment of
+        ``causal_lm_segments``) reads ``like()``: its parameters do not
+        depend on it."""
+        if what not in call.carried:
+            if self.is_initializing():
+                return like()
+            raise ValueError(
+                f"layer {self.index} is a {LAYER_KINDS[self.kind].name} layer: it "
+                f"reads the {what} an earlier layer of the same forward hands "
+                "on, and none did in this call")
+        return call.carried[what]
+
+    def _diff_attention(self, h_in, call):
+        """Differential attention on the normed input, the model's three
+        attention kinds. Heads come in adjacent PAIRS: query pair ``i`` is
+        heads ``(2i, 2i + 1) = (q1, q2)``, key pair ``j = i // (pairs / rows)``
+        is KV heads ``(2j, 2j + 1) = (k1, k2)``, and ``V = [v1 ; v2]``; ``a1 =
+        softmax(q1 k1^T s) V``, ``a2 = softmax(q2 k2^T s) V`` under one mask,
+        and the pair's output is ``(1 - lam0) * RMSNorm(a1 - lam * a2)`` with
+        ``lam = exp(lq1 . lk1) - exp(lq2 . lk2) + lam0`` and ``lam0 = 0.8 - 0.6
+        exp(-0.3 depth)``. A cache row holds one key PAIR side by side
+        (``cache_row_heads``), so with ``q1' = [q1 ; 0]`` and ``q2' = [0 ;
+        q2]`` the two maps are two ordinary query heads of ``2 head_dim``
+        lanes over that row (the zero lanes add exact zeros to the scores):
+        the decode kernel, the flash kernel and XLA's products as they are.
+
+        - "*" keeps keys and values in pages and hands its cache on
+          (``carried["kv"]``): the dense rows and each sequence's length
+          where there is a cache and ONE query a sequence (decode; the
+          layer a prefill stops at, ``call.last_at``), else the sequence's
+          own keys and values;
+        - "X" has queries alone and attends what "*" handed on;
+        - "W" sees the ``sliding_window`` keys up to its own: a ring of that
+          many rows a slot, token ``t`` at row ``t mod window`` (no
+          positions, so a softmax over the rows in any order is the same).
+          A prefill attends under the band and leaves each sequence's last
+          rows in the ring, by ``seq_lens``.
+
+        Scopes: ``attn.shared`` the attention over the one cache, ``attn.window``
+        the ring's write and attention and a prefill's band, ``attn.diff`` the
+        combination."""
+        cfg, kind = self.config, self.kind
+        b, t, _ = h_in.shape
+        hd, H = cfg.head_dim, cfg.n_head
+        rows, lanes = cfg.kv_heads // 2, 2 * cfg.head_dim
+        cache, W = call.cache, cfg.sliding_window
+        if call.prefix_fill or call.block_step or call.attn_mask is not None:
+            raise NotImplementedError(
+                "differential attention has the whole-sequence, the prefill and "
+                "the one-token modes: no prefill at a cache offset, no block "
+                "step, no mask of the caller's")
+        init = nn.initializers.normal(cfg.init_std)
+
+        def dense(width, name, init=init):
+            return RoundedDense(width, use_bias=cfg.qkv_bias, dtype=cfg.dtype,
+                                kernel_init=init, name=name)
+
+        with scope("attn.qkv"):
+            h_q = h_in if call.last_at is None else _take_rows(h_in, call.last_at)
+            q = dense(H * hd, "q_proj")(h_q)
+            if kind != "X":
+                k = dense(2 * rows * hd, "k_proj")(h_in)
+                v = dense(2 * rows * hd, "v_proj")(h_in)
+        lam_init = nn.initializers.normal(0.1)
+        lq1, lk1, lq2, lk2 = (self.param(f"lambda_{n}", lam_init, (hd,), jnp.float32)
+                              for n in ("q1", "k1", "q2", "k2"))
+        subln = self.param("subln", nn.initializers.ones, (lanes,), jnp.float32)
+        tq = q.shape[1]
+        with scope("attn.heads"):
+            qp = pair_queries(q.reshape(b, tq, H, hd))           # (b, tq, H, lanes)
+            if kind != "X":
+                k = k.reshape(b, t, rows, lanes)
+                v = v.reshape(b, t, rows, lanes)
+        scale = cfg.attn_scale
+        one_query = cache is not None and tq == 1
+        new = None
+        if kind == "W" and one_query:
+            with scope("attn.window"):
+                at = call.cache_len % W
+                ring_k = _cache_update(cache["k"], k.transpose(0, 2, 1, 3), at)
+                ring_v = _cache_update(cache["v"], v.transpose(0, 2, 1, 3), at)
+                new = {"k": ring_k, "v": ring_v}
+                o = _sharded_decode(qp[:, 0], ring_k, ring_v,
+                                    jnp.minimum(call.cache_len + 1, W),
+                                    scale=scale)[:, None]
+        elif kind == "W":
+            with scope("attn.window"):
+                o = _band_attention(qp, k, v, W, scale)
+                if cache is not None:
+                    lens = (jnp.full((b,), t, jnp.int32) if call.seq_lens is None
+                            else call.seq_lens)
+                    new = {"k": _ring_rows(k, lens, W).astype(cache["k"].dtype),
+                           "v": _ring_rows(v, lens, W).astype(cache["v"].dtype)}
+        elif kind == "*" and one_query and t == 1:
+            with scope("kv.append"):
+                k_cache = _cache_update(cache["k"], k.transpose(0, 2, 1, 3),
+                                        call.cache_len)
+                v_cache = _cache_update(cache["v"], v.transpose(0, 2, 1, 3),
+                                        call.cache_len)
+            new = {"k": k_cache, "v": v_cache}
+            call.carried["kv"] = dict(new, lens=call.cache_len + 1)
+            with scope("attn.shared"):
+                o = _sharded_decode(qp[:, 0], k_cache, v_cache, call.cache_len + 1,
+                                    scale=scale)[:, None]
+        elif kind == "*":
+            if cache is not None:
+                T = cache["k"].shape[2]
+                pad = ((0, 0), (0, 0), (0, T - t), (0, 0))
+                with scope("kv.append"):
+                    new = {"k": jnp.pad(k.transpose(0, 2, 1, 3), pad)
+                           .astype(cache["k"].dtype),
+                           "v": jnp.pad(v.transpose(0, 2, 1, 3), pad)
+                           .astype(cache["v"].dtype)}
+            if call.last_at is not None:
+                # the layer a prefill stops at: one query a sequence over
+                # the rows it has just written, as a decode step reads them
+                call.carried["kv"] = dict(new, lens=call.seq_lens)
+                with scope("attn.shared"):
+                    o = _sharded_decode(qp[:, 0], new["k"], new["v"], call.seq_lens,
+                                        scale=scale)[:, None]
+            else:
+                call.carried["kv"] = {"k": k, "v": v}
+                with scope("attn.shared"):
+                    o = _band_attention(qp, k, v, None, scale)
+        else:
+            shared = self._read(call, "kv", lambda: {
+                "k": jnp.zeros((b, tq, rows, lanes), cfg.dtype),
+                "v": jnp.zeros((b, tq, rows, lanes), cfg.dtype)})
+            with scope("attn.shared"):
+                if "lens" in shared:
+                    if tq != 1:
+                        raise NotImplementedError(
+                            "a cross-attention layer attends the cache's rows "
+                            "one query a sequence")
+                    o = _sharded_decode(qp[:, 0], shared["k"], shared["v"],
+                                        shared["lens"], scale=scale)[:, None]
+                else:
+                    o = _band_attention(qp, shared["k"], shared["v"], None, scale)
+        with scope("attn.diff"):
+            lam0 = 0.8 - 0.6 * float(np.exp(-0.3 * cfg.mixer_depth(self.index)))
+            lam = (jnp.exp(jnp.sum(lq1 * lk1)) - jnp.exp(jnp.sum(lq2 * lk2))
+                   + lam0).astype(jnp.float32)
+            o = o.reshape(b, tq, H // 2, 2, lanes).astype(jnp.float32)
+            diff = o[:, :, :, 0] - lam * o[:, :, :, 1]
+            diff = diff * jax.lax.rsqrt(
+                jnp.mean(diff * diff, axis=-1, keepdims=True) + cfg.ln_eps)
+            o = serving_values((1.0 - lam0) * diff * subln, cfg.dtype).astype(
+                cfg.dtype).reshape(b, tq, H * hd)
+        with scope("attn.out"):
+            out = dense(cfg.n_embd, "o_proj", nn.initializers.normal(cfg.out_std))(o)
+        return out, new
+
+    def _mamba1(self, h, call):
+        from .mamba1 import Mamba1Mixer
+        cfg = self.config
+        out, new, memory = Mamba1Mixer(
+            d_model=cfg.n_embd, d_inner=cfg.mamba1_d_inner,
+            state_size=cfg.ssm_state_size, dt_rank=cfg.mamba1_dt_rank,
+            conv_kernel=cfg.conv_kernel, dtype=cfg.dtype, init_std=cfg.init_std,
+            out_std=cfg.out_std, name="mamba")(
+                h, cache=call.cache, seq_lens=call.seq_lens)
+        call.carried["memory"] = memory
+        return out, new
+
+    def _gated_memory(self, h, call):
+        """The gated memory unit: ``W_out (silu(W_in u_t) * m_t)`` with
+        ``m_t`` the scan output that the last Mamba-1 layer before it handed
+        on, at the same position. It keeps nothing between tokens."""
+        cfg = self.config
+        c = cfg.mamba1_d_inner
+        w_in = self.param("in_proj", nn.initializers.normal(cfg.init_std),
+                          (cfg.n_embd, c), jnp.float32)
+        w_out = self.param("out_proj", nn.initializers.normal(cfg.out_std),
+                           (c, cfg.n_embd), jnp.float32)
+        memory = self._read(call, "memory",
+                            lambda: jnp.zeros(h.shape[:2] + (c,), jnp.float32))
+        with scope("gmu.gate"):
+            gate = jax.nn.silu(project(h, w_in, cfg.dtype))
+            gated = serving_values(gate * memory, cfg.dtype).astype(cfg.dtype)
+        with scope("gmu.out"):
+            return project(gated, w_out, cfg.dtype).astype(cfg.dtype), None
+
+    def _mamba(self, h, call):
         from .mamba2 import Mamba2Mixer
         cfg = self.config
         return Mamba2Mixer(
@@ -1396,21 +1839,22 @@ class MixerLayer(CausalLMLayer):
             n_groups=cfg.ssm_n_groups, conv_kernel=cfg.conv_kernel,
             chunk_size=cfg.ssm_chunk_size, eps=cfg.ln_eps, dtype=cfg.dtype,
             init_std=cfg.init_std, out_std=cfg.out_std, name="mamba")(
-                h, cache=cache, seq_lens=seq_lens)
+                h, cache=call.cache, seq_lens=call.seq_lens)
 
-    def _short_conv(self, h, cache, seq_lens):
+    def _short_conv(self, h, call):
         from .short_conv import ShortConvMixer
         cfg = self.config
         return ShortConvMixer(
             d_model=cfg.n_embd, conv_kernel=cfg.conv_kernel, dtype=cfg.dtype,
             init_std=cfg.init_std, out_std=cfg.out_std, name="conv")(
-                h, cache=cache, seq_lens=seq_lens)
+                h, cache=call.cache, seq_lens=call.seq_lens)
 
-    def _ffn(self, h, cache, seq_lens):
+    def _ffn(self, h, call):
         return self._mlp(h), None
 
-    def _experts(self, h, cache, seq_lens):
+    def _experts(self, h, call):
         cfg = self.config
+        seq_lens = call.seq_lens
         valid = None
         if seq_lens is not None and h.shape[1] > 1:
             with scope("moe.plan"):
@@ -1439,7 +1883,7 @@ def make_layer(cfg: CausalLMConfig, i: int, **kw):
     kind = cfg.layer_kind(i)
     if kind == "A":
         return CausalLMLayer(cfg, is_moe=cfg.is_moe_layer(i), **kw)
-    return MixerLayer(cfg, kind=kind, **kw)
+    return MixerLayer(cfg, kind=kind, index=i, **kw)
 
 
 def block_causal_mask(t: int, block: int):
@@ -1480,6 +1924,58 @@ def _bias_attention(q, k, v, slopes, mask_block: int, attn_mask, scale):
     if slopes is None:
         return xla_attention(q, k, v, causal=True, softmax_scale=scale)
     return _alibi_attention_xla(q, k, v, slopes, scale)
+
+
+def pair_queries(q):
+    """Differential attention's queries for cache rows that hold a key PAIR
+    side by side: ``q`` ``(..., h, d)`` -> ``(..., h, 2 d)``, an even head in
+    the first ``d`` lanes, an odd one in the last, exact zeros elsewhere, so
+    that each scores its own key of the pair and weighs the pair's whole
+    ``2 d`` value."""
+    *lead, h, d = q.shape
+    q = q.reshape(*lead, h // 2, 2, 1, d)
+    own = jnp.eye(2, dtype=bool).reshape(2, 2, 1)
+    return jnp.where(own, q, 0).reshape(*lead, h, 2 * d)
+
+
+def _band_attention(q, k, v, window, scale):
+    """Whole-sequence causal attention of ``q`` (b, t, h, d) over rows ``k``,
+    ``v`` (b, t, rows, d), ``h / rows`` query heads a row; ``window``: a query
+    sees the ``window`` keys up to its own (None: all of them). The flash
+    kernel at a ``flash_eligible`` length of whole-tile heads (under a window
+    in blocks of about its size, so that what lies behind the band is
+    skipped), else XLA's products under the mask."""
+    from ..ops.attention.flash import flash_attention
+    from ..ops.transformer.attention import flash_eligible, xla_attention
+    g = q.shape[2] // k.shape[2]
+    k = jnp.repeat(k, g, axis=2)
+    v = jnp.repeat(v, g, axis=2)
+    t = q.shape[1]
+    if flash_eligible(t) and q.shape[-1] % 128 == 0:
+        if window is None:
+            return flash_attention(q, k, v, causal=True, softmax_scale=scale)
+        block = min(1024, max(128, 1 << (int(window).bit_length() - 1)))
+        return flash_attention(q, k, v, causal=True, softmax_scale=scale,
+                               block_q=block, block_k=block, window=int(window))
+    if window is None:
+        return xla_attention(q, k, v, causal=True, softmax_scale=scale)
+    i, j = np.arange(t)[:, None], np.arange(t)[None, :]
+    band = jnp.asarray((j <= i) & (j > i - window))
+    return xla_attention(q, k, v, causal=False, mask=band[None, None],
+                         softmax_scale=scale)
+
+
+def _ring_rows(x, seq_lens, window: int):
+    """The ring a right-padded prompt leaves: of ``x`` (b, t, rows, d) each
+    sequence's last ``min(len, window)`` valid positions, position ``p`` at
+    ring row ``p mod window``, as ``(b, rows, window, d)`` (rows no position
+    of the sequence maps to are zeros, and the length masks them)."""
+    t = x.shape[1]
+    row = jnp.arange(window)[None]                              # (1, window)
+    last = seq_lens[:, None] - 1 - row
+    pos = row + (last // window) * window
+    got = jnp.take_along_axis(x, jnp.clip(pos, 0, t - 1)[:, :, None, None], axis=1)
+    return jnp.where((last >= 0)[:, :, None, None], got, 0).transpose(0, 2, 1, 3)
 
 
 def _latent_attention_core(q, k, v, attn_mask, scale):
@@ -1668,23 +2164,45 @@ class CausalLM(nn.Module):
             if cfg.embed_layernorm:
                 x = _norm(cfg, "ln_embed")(x).astype(cfg.dtype)
 
+        # what a later layer may read of an earlier one, carried beside ``x``
+        # (:attr:`LayerKind.writes`): the memory of the last Mamba-1 layer,
+        # the cache of the last layer that keeps keys and values
+        carried = {}
+        # a prefill (a cache to fill, logits at one position a sequence)
+        # stops at ``stop``: that layer writes its cache for every position,
+        # and from its output on only the position the logits are read at
+        # runs, of ``x`` and of the memory
+        stop = None
+        if (caches is not None and t > 1 and seq_lens is not None
+                and logits_positions is not None and logits_positions.ndim == 1
+                and not prefix_fill and not block_step):
+            stop = cfg.prefill_stop
         new_caches = []
         for i in range(cfg.n_layer):
             layer_cache = None if caches is None else caches[i]
             # only a layer of one mixer is told the rows' real lengths
-            extra = {} if cfg.layer_kind(i) == "A" else {"seq_lens": seq_lens}
+            extra = ({} if cfg.layer_kind(i) == "A"
+                     else {"seq_lens": seq_lens, "carried": carried})
             if block_step or attn_mask is not None:
                 extra.update(block_step=block_step, attn_mask=attn_mask)
+            if i == stop:
+                extra.update(last_at=logits_positions)
             x, new_kv = make_layer(cfg, i, name=f"layers_{i}")(
                 x, positions, cache=layer_cache, cache_len=cache_lens,
                 prefix_fill=prefix_fill, **extra)
+            if i == stop and "memory" in carried:
+                with scope("residual"):
+                    carried["memory"] = _take_rows(carried["memory"],
+                                                   logits_positions)
             new_caches.append(new_kv)
 
         with scope("head"):
             x = _norm(cfg, "ln_f")(x)
-            if logits_positions is not None and logits_positions.ndim == 2:
+            # (a prefill that stopped left ``x`` the one position already)
+            if stop is None and logits_positions is not None \
+                    and logits_positions.ndim == 2:
                 x = jnp.take_along_axis(x, logits_positions[..., None], axis=1)
-            elif logits_positions is not None:
+            elif stop is None and logits_positions is not None:
                 x = x[jnp.arange(b), logits_positions][:, None]    # (b, 1, d)
             if cfg.tie_word_embeddings:
                 logits = x.astype(jnp.float32) @ wte.T
@@ -1795,7 +2313,9 @@ def causal_lm_segments(cfg: CausalLMConfig, layers_per_group: int = 2):
     for lo in range(0, cfg.n_layer, layers_per_group):
         hi = min(lo + layers_per_group, cfg.n_layer)
         keys = tuple(f"layers_{i}" for i in range(lo, hi))
+        # (differential attention's lambda_init reads the layer's depth)
         flags = tuple((cfg.layer_kind(i), cfg.is_moe_layer(i))
+                      + ((cfg.mixer_depth(i),) if cfg.diff_attention else ())
                       for i in range(lo, hi))
         group_init, group_apply = _fns_for(flags, lo)
         segs.append(Segment(name=f"layers[{lo}:{hi}]", kind="mid", param_keys=keys,
@@ -1890,13 +2410,14 @@ def init_cache(cfg: CausalLMConfig, batch_size: int, max_len: Optional[int] = No
     (``ops/paged_attention.heads_per_row`` says ``r``; ``kv_shape`` where the
     caller lays them out otherwise: the paged pool's pages), the kind's
     per-slot state for ``batch_size`` sequences (``{"conv", "ssm"}``
-    state-space, ``{"conv"}`` short convolution), an empty dict for a layer
+    state-space, ``{"conv"}`` short convolution, ``{"k", "v"}`` a windowed
+    layer's ring), an empty dict for a layer
     that keeps nothing; a latent layer's one row a token ``{"k": (batch_size,
     1, T, lanes)}`` (``ops/paged_attention.latent_row_lanes``; under
     ``kv_shape`` its first and third extents)."""
     T = max_len or cfg.max_seq_len
     dtype = dtype or cfg.dtype
-    r = heads_per_row(cfg.head_dim, cfg.kv_heads)
+    r = cfg.cache_row_heads
     shape = kv_shape or (batch_size, cfg.kv_heads // r, T, r * cfg.head_dim)
     out = []
     for kind in cfg.layer_kinds:

@@ -88,8 +88,13 @@ TAGS: Dict[str, Tuple[str, str]] = {
                                                    "bytes the grouped expert "
                                                    "kernel had to read"),
     "serving/ssm_state_bytes": (GAUGE, "bytes of the per-slot state of the "
-                                       "state-space and short-convolution "
-                                       "layers (recurrent state, windows)"),
+                                       "state-space, short-convolution and "
+                                       "windowed-attention layers (recurrent "
+                                       "state, windows, rings)"),
+    "serving/kv_ring_bytes": (GAUGE, "kv.ring_bytes: the part of the per-slot "
+                                     "state that is windowed layers' rings "
+                                     "of keys and values; absent from a "
+                                     "model without such layers"),
     "serving/kv_latent_row_bytes": (GAUGE, "kv.latent_row_bytes: bytes a token "
                                            "a layer as a latent-attention "
                                            "layer stores them (one row for "
@@ -281,10 +286,14 @@ SPANS: Dict[str, Tuple[str, str, Tuple[str, ...], str]] = {
     "serving.prefill": (BOTH, "compiled steps",
                         ("request_id", "bucket", "tokens", "prefix_len",
                          "moe_assignments", "moe_experts_touched",
-                         "blocks_committed"),
+                         "blocks_committed", "positions_self",
+                         "positions_cross"),
                         "sched_admit_host_ms by bucket; attribution phase "
                         "prefill; moe_experts_touched_per_step lines; "
-                        "block_tokens_per_forward lines"),
+                        "block_tokens_per_forward lines; "
+                        "prefill_cross_decoder_dev_ms lines (a prefill that "
+                        "stops early: positions the layers up to the stop "
+                        "ran at, and the layers after it)"),
     "serving.suffix_prefill": (BOTH, "compiled steps",
                                ("request_id", "bucket", "tokens",
                                 "prefix_len"),
@@ -401,7 +410,7 @@ SPANS: Dict[str, Tuple[str, str, Tuple[str, ...], str]] = {
                               "with home_random_routers: home experts a token id)"),
     "setup.kv_pool": (PHASE, "device set-up",
                       ("pool", "pages", "slots", "state_bytes",
-                       "heads_per_row"),
+                       "heads_per_row", "ring_bytes"),
                       "setup_engine_init_s"),
     "setup.program": (PHASE, "device set-up", ("program", "bucket"),
                       "setup_engine_init_s lines, beside setup_compile_s"),
@@ -465,6 +474,19 @@ SCOPES: Dict[str, Tuple[str, str, str]] = {
     "attn.latent_expand": (_STEP, "a latent layer's keys and values expanded "
                                   "from the prompt's latents (prefill, forward)",
                            _TABLE),
+    "attn.shared": (_STEP, "differential attention over the ONE cache that a "
+                           "full layer keeps and the cross layers after it "
+                           "re-read (a decode step's eight reads of it; a "
+                           "whole sequence's causal flash or products)",
+                    "shared_kv_attn_dev_ms_per_step, shared_kv_attn_roofline_pct"),
+    "attn.window": (_STEP, "a windowed layer's ring: the token's row written "
+                           "at t mod window, the attention over the ring; in "
+                           "a prefill the banded flash call and the ring the "
+                           "prompt leaves",
+                    "window_attn_dev_ms_per_step"),
+    "attn.diff": (_STEP, "differential attention's combination: lambda, the "
+                         "subtraction of a pair's two maps, the norm over "
+                         "the pair", _TABLE),
     "kv.append": (_STEP, "new keys and values written into the cache",
                   "decode_attn_dev_ms_per_step"),
     "mlp.up": (_STEP, "the MLP's first projection, with the gate",
@@ -483,12 +505,23 @@ SCOPES: Dict[str, Tuple[str, str, str]] = {
                           "latent mixture with the projections to and from "
                           "the latent space, in the gated mixture its three "
                           "matmuls on the layer's own normed input", _TABLE),
-    "ssm.in": (_STEP, "a Mamba-2 mixer's input projection and its split",
-               _TABLE),
+    "ssm.in": (_STEP, "a state-space mixer's (Mamba-2, Mamba-1) input "
+                      "projection and its split", _TABLE),
     "ssm.conv": (_STEP, "the causal convolution and its window", _TABLE),
-    "ssm.update": (_STEP, "the one-token state update and the chunked scan",
-                   _TABLE + ", beside ssm_decode_dev_ms_per_step"),
-    "ssm.out": (_STEP, "the gated norm and the output projection", _TABLE),
+    "ssm.select": (_STEP, "Mamba-1's selection: x_proj, dt_proj, the softplus "
+                          "(B, C and dt of a token from its own input)",
+                   _TABLE),
+    "ssm.update": (_STEP, "the one-token state update; a prefill's scan "
+                          "(Mamba-2's chunked form, Mamba-1's selective_scan "
+                          "kernel)",
+                   _TABLE + ", beside ssm_decode_dev_ms_per_step; "
+                            "selective_scan_roofline_pct"),
+    "ssm.out": (_STEP, "the gate (Mamba-2: and its norm) and the output "
+                       "projection", _TABLE),
+    "gmu.gate": (_STEP, "a gated memory unit's input projection and the gate "
+                        "silu(W_in u) * m on the memory an earlier Mamba-1 "
+                        "layer handed on", _TABLE),
+    "gmu.out": (_STEP, "a gated memory unit's output projection", _TABLE),
     "sconv.in": (_STEP, "a gated short convolution's input projection and "
                         "the product B * u",
                  _TABLE + ", shortconv_decode_dev_ms_per_step"),
@@ -548,6 +581,7 @@ SCOPE_MODULES = (
     "deepspeed_tpu/models/gpt2.py",
     "deepspeed_tpu/models/causal_lm.py",
     "deepspeed_tpu/models/mamba2.py",
+    "deepspeed_tpu/models/mamba1.py",
     "deepspeed_tpu/models/short_conv.py",
     "deepspeed_tpu/moe/gated_moe.py",
     "deepspeed_tpu/moe/latent_moe.py",

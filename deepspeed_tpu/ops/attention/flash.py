@@ -105,6 +105,21 @@ def _below_diagonal(q_idx, k_idx, bq, bk):
     return (k_idx + 1) * bk - 1 <= q_idx * bq
 
 
+def _window_k_lo(q_idx, bq, bk, window):
+    """First kv-block index a q block's band reaches: query ``i`` sees the
+    ``window`` keys ``(i - window, i]``, so the block's first row sees none
+    before ``q_idx * bq - (window - 1)``."""
+    return jnp.maximum(q_idx * bq - (window - 1), 0) // bk
+
+
+def _inside_band(q_idx, k_idx, bq, bk, window):
+    """Whether every score of grid block (q_idx, k_idx) lies inside the band:
+    below the diagonal, and its first key no earlier than what the block's
+    LAST q row still sees."""
+    return jnp.logical_and(_below_diagonal(q_idx, k_idx, bq, bk),
+                           k_idx * bk >= (q_idx + 1) * bq - window)
+
+
 def heads_a_block(h: int, d: int) -> int:
     """Heads one kernel block holds where ``h`` heads of ``d`` lie flat in the lanes
     ``(b, t, h*d)``: 1 where a head is whole lane tiles, ``128 // d`` where heads fill
@@ -141,14 +156,17 @@ def _slopes_map(fold):
     return lambda i, g, a, c: (i % fold + g, 0, 0)
 
 
-def _k_index_map(causal, bq, bk, off=0):
+def _k_index_map(causal, bq, bk, off=0, window=None):
     """kv-block index map: under causality, blocks above the diagonal clamp to the
     last needed block — same index as the previous grid step, so the pipeline skips
     the copy while ``pl.when`` skips the compute. Shared by fwd and bwd-dq so the
-    two cannot drift."""
+    two cannot drift. Under a ``window`` the blocks wholly behind the band clamp
+    forward to the first block it reaches, likewise."""
     def k_index(i, g, j, kb):
         if causal:
             kb = jnp.minimum(kb, _causal_k_hi(j, bq, bk))
+        if window is not None:
+            kb = jnp.maximum(kb, _window_k_lo(j, bq, bk, window))
         return (i, kb, off + g)
     return k_index
 
@@ -247,7 +265,8 @@ def _by_head(xs, d, width):
     return out
 
 
-def _scores(rows, cols, scale, slope, off, masked, key_axis, mask_block=1):
+def _scores(rows, cols, scale, slope, off, masked, key_axis, mask_block=1,
+            window=None):
     """Scaled scores ``rows @ cols^T`` of one tile; keys run along ``key_axis`` of
     the result and ``off`` = (first q row) - (first key column) in sequence
     positions. The alibi term ``slope * (key - query)`` rides every tile; iota,
@@ -256,7 +275,8 @@ def _scores(rows, cols, scale, slope, off, masked, key_axis, mask_block=1):
     block of that many positions is not later than the query's, so a query sees
     up to the end of its own block; tiles start on block boundaries (every tile
     size is a multiple of the block), so the query's place in its block is its
-    row's in the tile."""
+    row's in the tile. ``window``: a key more than ``window - 1`` positions
+    behind its query is not seen either (the band's other edge)."""
     s = _dot(rows, cols, (1, 1)) * scale
     if slope is None and not masked:
         return s
@@ -269,12 +289,14 @@ def _scores(rows, cols, scale, slope, off, masked, key_axis, mask_block=1):
     if masked and mask_block > 1:
         reach = mask_block - 1 - jax.lax.rem(query, mask_block)
         s = jnp.where(dist <= reach, s, NEG_INF)
+    elif masked and window is not None:
+        s = jnp.where(jnp.logical_and(dist <= 0, dist > -window), s, NEG_INF)
     elif masked:
         s = jnp.where(dist <= 0, s, NEG_INF)
     return s
 
 
-def _walk(causal, q_idx, k_idx, nq, bq, bk, by_cols, sb, strip, commit):
+def _walk(causal, q_idx, k_idx, nq, bq, bk, by_cols, sb, strip, commit, window=None):
     """Run grid block (q_idx, k_idx) in strips of ``sb`` q rows, or of key columns
     where ``by_cols``: ``strip(start, size, parts, base_off)`` gives one strip's
     contribution as a tuple of arrays with ``size`` leading rows, and
@@ -285,7 +307,12 @@ def _walk(causal, q_idx, k_idx, nq, bq, bk, by_cols, sb, strip, commit):
     strip is formed before the first is committed, so none waits on a buffer
     another wrote and the compiler overlaps one strip's matmuls with another's
     softmax. ``base_off`` = (block's first q row) - (block's first key column):
-    0, and static, in a crossed block of equal sides."""
+    0, and static, in a crossed block of equal sides. Under a ``window`` (causal)
+    a block wholly inside the band is walked unmasked, one that either edge of the
+    band crosses is masked all over, and one behind the band is skipped as one
+    above the diagonal is (a row that sees no key of a crossed block carries
+    nothing on from it: its running max stays ``NEG_INF`` there, and the block of
+    its own diagonal, which comes after, rescales what it summed by exactly 0)."""
     whole, other = (bk, bq) if by_cols else (bq, bk)
     sb = _strip_size(whole, sb)
     base_off = q_idx * bq - k_idx * bk
@@ -297,6 +324,16 @@ def _walk(causal, q_idx, k_idx, nq, bq, bk, by_cols, sb, strip, commit):
 
     if not causal:
         run(_rect_strips(whole, other, sb, False), base_off)
+        return
+    if window is not None:
+        inside = _inside_band(q_idx, k_idx, bq, bk, window)
+        reached = jnp.logical_and(k_idx >= _window_k_lo(q_idx, bq, bk, window),
+                                  k_idx <= _causal_k_hi(q_idx, bq, bk))
+        if bq + bk - 1 <= window:                 # else no block can be inside
+            pl.when(inside)(lambda: run(_rect_strips(whole, other, sb, False),
+                                        base_off))
+        pl.when(jnp.logical_and(reached, jnp.logical_not(inside)))(
+            lambda: run(_rect_strips(whole, other, sb, True), base_off))
         return
     below = _below_diagonal(q_idx, k_idx, bq, bk)
     crossed = jnp.logical_and(jnp.logical_not(below),
@@ -315,7 +352,8 @@ def _walk(causal, q_idx, k_idx, nq, bq, bk, by_cols, sb, strip, commit):
 
 
 # ----------------------------------------------------------------------- forward kernel
-def _fwd_kernel(*refs, d, scale, causal, use_alibi, nq, nk, bq, bk, mask_block=1):
+def _fwd_kernel(*refs, d, scale, causal, use_alibi, nq, nk, bq, bk, mask_block=1,
+                window=None):
     q_ref, k_ref, v_ref = refs[:3]
     slopes_ref = refs[3] if use_alibi else None
     o_ref, lse_ref, *scratch = refs[4 if use_alibi else 3:]
@@ -346,7 +384,7 @@ def _fwd_kernel(*refs, d, scale, causal, use_alibi, nq, nk, bq, bk, mask_block=1
             slope = slopes_ref[hh, 0, 0] if use_alibi else None
             qh = _head_lanes(q, hh, d)
             ss = [_scores(qh, k_ref[0, c0:c0 + nc, :], scale, slope,
-                          base_off + r0 - c0, masked, 1, mask_block)
+                          base_off + r0 - c0, masked, 1, mask_block, window)
                   for c0, nc, masked in parts]
             # one kv block holds every key of its rows: no running max to start from
             m = None if nk == 1 else m_scr[hh, r0:r0 + nr, :]
@@ -377,7 +415,7 @@ def _fwd_kernel(*refs, d, scale, causal, use_alibi, nq, nk, bq, bk, mask_block=1
             lse_ref[0, hh, 0, :, r0:r0 + nr] = jnp.broadcast_to(lse[None, :], (8, nr))
 
     if nk == 1:
-        _walk(causal, j, kb, nq, bq, bk, False, FWD_STRIP, strip, write)
+        _walk(causal, j, kb, nq, bq, bk, False, FWD_STRIP, strip, write, window)
         return
 
     def carry(r0, nr, ms, ls, acc):
@@ -391,7 +429,7 @@ def _fwd_kernel(*refs, d, scale, causal, use_alibi, nq, nk, bq, bk, mask_block=1
             alphas.append(alpha)
         acc_scr[rows, :] = _by_head(alphas, d, width) * acc_scr[rows, :] + acc
 
-    _walk(causal, j, kb, nq, bq, bk, False, FWD_STRIP, strip, carry)
+    _walk(causal, j, kb, nq, bq, bk, False, FWD_STRIP, strip, carry, window)
 
     @pl.when(kb == nk - 1)
     def _finalize():
@@ -409,14 +447,15 @@ def _compiler_params(hpb: int):
 
 
 def _flash_fwd(q, k, v, slopes, fused, d, scale, causal, block_q, block_k,
-               mask_block=1):
+               mask_block=1, window=None):
     """q/k/v: (rows, t, lanes) holding ``lanes // d`` heads of ``d`` each — (b, t, h*d)
     flat or (b*h, t, d) — or, ``fused``, ONE (b, t, 3*lanes) array passed three times
     and read at q | k | v's lane offsets. ``slopes``: alibi slopes (h, 8, 128), the
     value duplicated for lane alignment, or None. Returns (o (rows, t, lanes),
     lse (rows, heads, t)). ``v`` may hold heads of another width than q's and k's
     ``d`` (:func:`flash_attention_local` says when): a lane group is then one head in
-    all three, ``o`` has v's lanes."""
+    all three, ``o`` has v's lanes. ``window`` (causal, forward only): query ``i``
+    sees keys ``(i - window, i]``; None leaves the call as it was."""
     rows, t, lanes = q.shape
     lanes //= 3 if fused else 1
     width, groups = _lane_groups(lanes, d)
@@ -429,11 +468,14 @@ def _flash_fwd(q, k, v, slopes, fused, d, scale, causal, block_q, block_k,
 
     kernel = functools.partial(_fwd_kernel, d=d, scale=scale, causal=causal,
                                use_alibi=use_alibi, nq=nq, nk=nk, bq=bq, bk=bk,
-                               mask_block=mask_block)
+                               mask_block=mask_block,
+                               **({} if window is None else {"window": window}))
     in_specs = [
         pl.BlockSpec((1, bq, width), _outer_map()),
-        pl.BlockSpec((1, bk, width), _k_index_map(causal, bq, bk, fused * groups)),
-        pl.BlockSpec((1, bk, v_width), _k_index_map(causal, bq, bk, 2 * fused * groups)),
+        pl.BlockSpec((1, bk, width),
+                     _k_index_map(causal, bq, bk, fused * groups, window)),
+        pl.BlockSpec((1, bk, v_width),
+                     _k_index_map(causal, bq, bk, 2 * fused * groups, window)),
     ]
     args = [q, k, v]
     if use_alibi:
@@ -718,6 +760,23 @@ def _make_core(fused: bool):
     return core
 
 
+@functools.partial(jax.custom_vjp, nondiff_argnums=(3,))
+def _flash_windowed(q, k, v, static):
+    """The forward kernel under a window, ``static`` = (d, scale, block_q, block_k,
+    window): forward only, and the backward says so by name."""
+    d, scale, block_q, block_k, window = static
+    return _flash_fwd(q, k, v, None, False, d, scale, True, block_q, block_k, 1,
+                      window)[0]
+
+
+def _flash_windowed_fwd(q, k, v, static):
+    raise NotImplementedError(
+        "flash_attention(window=...) is forward only: the backward kernels walk "
+        "the causal triangle and would count keys behind the band")
+
+
+_flash_windowed.defvjp(_flash_windowed_fwd, lambda static, res, do: None)
+
 _flash_core = _make_core(fused=False)
 _flash_core_qkv = _make_core(fused=True)
 
@@ -735,12 +794,23 @@ def flash_attention_local(q4, k4, v4, causal: bool = True,
                           softmax_scale: Optional[float] = None,
                           alibi_slopes: Optional[jnp.ndarray] = None,
                           block_q: int = 1024, block_k: int = 1024,
-                          mask_block: int = 1):
+                          mask_block: int = 1, window: Optional[int] = None):
     """Per-shard kernel invocation with NO mesh dispatch — for callers already inside a
     ``shard_map`` manual region (e.g. the TP pipeline stage_fn), where the public
     :func:`flash_attention`'s own shard_map wrapper would illegally nest."""
     lb, lt, lh, ld = q4.shape
     dv = v4.shape[-1]
+    if window is not None:
+        if (not causal or window < 1 or alibi_slopes is not None or mask_block > 1
+                or dv != ld or not heads_a_block(lh, ld)):
+            raise NotImplementedError(
+                f"window={window} is the causal band (i - window, i] of heads that lie "
+                "in whole lane tiles: without alibi, without mask_block, values as wide "
+                "as keys")
+        scale = softmax_scale if softmax_scale is not None else 1.0 / float(np.sqrt(ld))
+        o = _flash_windowed(*(x.reshape(lb, lt, lh * ld) for x in (q4, k4, v4)),
+                            (ld, scale, block_q, block_k, int(window)))
+        return o.reshape(lb, lt, lh, ld)
     if dv != ld and (ld % 128 or dv % 128 or alibi_slopes is not None):
         raise NotImplementedError(
             f"values of {dv} lanes beside queries and keys of {ld}: both must be "
@@ -809,7 +879,7 @@ def flash_attention(q: jnp.ndarray, k: jnp.ndarray, v: jnp.ndarray,
                     dropout_rate: float = 0.0, dropout_rng=None,
                     alibi_slopes: Optional[jnp.ndarray] = None,
                     block_q: int = 1024, block_k: int = 1024,
-                    mask_block: int = 1) -> jnp.ndarray:
+                    mask_block: int = 1, window: Optional[int] = None) -> jnp.ndarray:
     """Drop-in replacement for ``xla_attention``: q/k/v ``(b, t, h, d)`` → ``(b, t, h, d)``.
 
     ``alibi_slopes`` (h,) adds the per-head alibi distance bias ``slope*(col-row)``
@@ -820,14 +890,19 @@ def flash_attention(q: jnp.ndarray, k: jnp.ndarray, v: jnp.ndarray,
     attention dropout, cross-attention with different kv length). ``mask_block`` > 1
     (with ``causal``) is the block-causal mask of generation by diffusion over blocks:
     key ``j`` is seen by query ``i`` iff ``j // mask_block <= i // mask_block``; only the
-    tiles on the diagonal change, so what causality skips stays skipped. There is no
+    tiles on the diagonal change, so what causality skips stays skipped. ``window``
+    (with ``causal``; forward only): query ``i`` sees the ``window`` keys ``(i - window,
+    i]``, itself and the ``window - 1`` before it; the kv blocks wholly behind the band
+    are skipped as those above the diagonal are, the blocks an edge of the band crosses
+    are masked. There is no
     sequence-length guard: K/V blocks stream through the grid pipeline, so VMEM use is
     O(block) regardless of t.
     """
     from ..transformer.attention import xla_attention
     if mask is not None or dropout_rate > 0.0 or q.shape[1] != k.shape[1]:
-        if alibi_slopes is not None or mask_block > 1:
+        if alibi_slopes is not None or mask_block > 1 or window is not None:
             raise NotImplementedError(
+                "window is kernel-only" if window is not None else
                 "mask_block is kernel-only" if mask_block > 1 else
                 "alibi_slopes is kernel-only: combine it with mask/dropout/"
                 "cross-attention via the model-level XLA bias path instead")
@@ -841,7 +916,7 @@ def flash_attention(q: jnp.ndarray, k: jnp.ndarray, v: jnp.ndarray,
         return flash_attention_local(q4, k4, v4, causal=causal, softmax_scale=scale,
                                      alibi_slopes=slopes,
                                      block_q=block_q, block_k=block_k,
-                                     mask_block=mask_block)
+                                     mask_block=mask_block, window=window)
 
     return _per_shard(local, (q, k, v),
                       None if alibi_slopes is None

@@ -1,4 +1,6 @@
-"""One-token update of a state-space layer's recurrent state (decode).
+"""One-token update of a state-space layer's recurrent state (decode) where the
+decay is ONE number a head (Mamba-2; a decay that differs by state lane, Mamba-1's,
+is ``selective_scan.selective_step``).
 
     h' = exp(dt A) h + (dt x) (x) B          y = C . h' + D x
 

@@ -104,7 +104,7 @@ def test_the_builder_lays_the_published_keys_out_as_pairs_of_mixer_layers(tiny):
                                            ["conv"], []]
     assert caches[0]["conv"].shape == (2, 2, 64) and caches[4]["k"].shape == (2, 2, 16, 16)
     assert cfg.slot_state_layers == ("short-convolution",) and not cfg.kv_every_layer
-    assert set(PATTERN_KINDS) == set(LAYER_KINDS) - {"A"} == set("*LMCEF")
+    assert set(PATTERN_KINDS) == set(LAYER_KINDS) - {"A"} >= set("*LMCEF")   # PR 59 added rows
     # the published 24 layers, and the 12 the benchmark's stage keeps
     published = dict(lt.MODEL, num_hidden_layers=24, layer_types=(
         ["conv", "conv", "full_attention", "conv"] * 4 + ["conv", "conv",

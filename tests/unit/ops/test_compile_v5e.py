@@ -94,12 +94,11 @@ def _abstract_model(cfg, slots, cap, pages, page, sds):
     """A module with its parameters and its pool's caches as shapes on the
     described chip (the pool's rows as ``heads_per_row`` lays them out)."""
     from deepspeed_tpu.models.causal_lm import CausalLM, init_cache
-    from deepspeed_tpu.ops.paged_attention import heads_per_row
     module = CausalLM(cfg)
     params = jax.eval_shape(lambda: module.init(
         jax.random.PRNGKey(0), jnp.zeros((1, 8), jnp.int32))["params"])
     params = jax.tree_util.tree_map(lambda x: sds(x.shape, cfg.dtype), params)
-    r = heads_per_row(cfg.head_dim, cfg.kv_heads)
+    r = cfg.cache_row_heads
     kv_shape = (pages, cfg.kv_heads // r, page, r * cfg.head_dim)
     caches = jax.tree_util.tree_map(
         lambda x: sds(x.shape, x.dtype), jax.eval_shape(lambda: init_cache(
@@ -670,3 +669,133 @@ def test_sarvams_decode_chunk_walks_the_latent_rows_without_relaying_them(
     assert " scatter(" not in text
     assert _pool_relayouts(text, (pages, 1, page, 640)) == []
     assert f"f32[{slots},{cap},640]" not in text and f"f32[{slots},1,{cap},640]" not in text
+
+
+# ------------------------------------------------ phi-4-mini-flash (SambaY), PR 59
+def _phi4flash(layers=8):
+    import json
+    import os
+    from deepspeed_tpu.models.causal_lm import phi4flash_cfg
+    root = os.path.dirname(os.path.dirname(os.path.dirname(os.path.dirname(
+        os.path.abspath(__file__)))))
+    with open(os.path.join(root, "benchmarks", "chipbench", "configs",
+                           "phi-4-mini-flash-reasoning.json")) as f:
+        model = json.load(f)["model"]
+    return phi4flash_cfg(max_seq_len=6144, dtype=jnp.bfloat16,
+                         **dict(model, num_hidden_layers=layers))
+
+
+@pytest.mark.parametrize("tokens", [512, 4096])
+def test_the_selective_scan_compiles_at_the_published_widths(one_chip, tokens, monkeypatch):
+    """``selective_scan`` at Phi-4-mini-flash's Mamba-1 widths (5,120 channels,
+    16 state lanes) for the cell's two prefill buckets: ten channel tiles of
+    512, time blocks of 128, the state in VMEM scratch."""
+    import importlib
+    ss = importlib.import_module("deepspeed_tpu.ops.ssm.selective_scan")
+    monkeypatch.setattr(ss, "_interpret", lambda: False)
+    c, n = 5120, 16
+
+    def sds(*shape):
+        return jax.ShapeDtypeStruct(shape, jnp.float32, sharding=one_chip)
+
+    text = jax.jit(lambda *a: ss.selective_scan(*a)).lower(
+        sds(1, tokens, c), sds(1, tokens, c), sds(n, c), sds(1, tokens, n),
+        sds(1, tokens, n), sds(c)).compile().as_text()
+    assert text.count("tpu_custom_call") == 1 and "selective_scan" in text
+    assert " while(" not in text
+
+
+@pytest.mark.parametrize("tokens", [512, 4096])
+def test_the_flash_forward_compiles_under_a_window(one_chip, tokens, monkeypatch):
+    """A windowed layer's prefill: 40 padded query heads of a differential
+    pair's 128 lanes, window 512 in blocks of 512 (what ``_band_attention``
+    asks for)."""
+    from deepspeed_tpu.ops.attention import flash
+    monkeypatch.setattr(flash, "_interpret", lambda: False)
+    x = jax.ShapeDtypeStruct((1, tokens, 40, 128), jnp.bfloat16, sharding=one_chip)
+    text = jax.jit(lambda q, k, v: flash.flash_attention(
+        q, k, v, causal=True, softmax_scale=0.125, block_q=512, block_k=512,
+        window=512)).lower(x, x, x).compile().as_text()
+    assert text.count("tpu_custom_call") == 1 and "flash_fwd" in text
+
+
+def test_phi4flashs_decode_chunk_reads_one_view_through_the_decode_kernel(
+        one_chip, monkeypatch):
+    """Eight published layers of Phi-4-mini-flash (``S W S W S * G X``: every
+    kind) at the cell's shapes, 32 slots x cap 6144 on pages of 16: the chunk's
+    own ``while`` and no other loop; ``decode_attention`` four times a step
+    (two rings, the full layer, the cross layer over the SAME view),
+    ``selective_step`` three times (a Mamba-1 layer's update, a kernel for its
+    rounding) and no other Mosaic kernel; ONE layer's pages are gathered, a step appends its
+    rows without a scatter and nothing the shape of the pages is copied."""
+    from deepspeed_tpu.inference.decode_fns import (build_paged_decode_chunk,
+                                                    make_slot_select_fn)
+    import importlib
+    from deepspeed_tpu.ops.attention import decode
+    ss = importlib.import_module("deepspeed_tpu.ops.ssm.selective_scan")
+    monkeypatch.setattr(decode, "_interpret", lambda: False)
+    monkeypatch.setattr(ss, "_interpret", lambda: False)
+    slots, cap, pages, page, chunk = 32, 6144, 12289, 16, 8
+    cfg = _phi4flash(8)
+    assert cfg.layer_pattern == "SFWFSFWFSF*FGFXF"
+
+    def sds(shape, dtype=jnp.int32):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    module, params, caches, kv_shape = _abstract_model(cfg, slots, cap, pages, page, sds)
+    assert kv_shape == (pages, 10, page, 128)
+    assert caches[2]["k"].shape == (slots, 10, 512, 128)          # a ring a slot
+    assert caches[0]["ssm"].shape == (slots, 16, 5120) and caches[14] == {}
+    fn = build_paged_decode_chunk(module, lambda p: p,
+                                  make_slot_select_fn(False, 1.0, 0, 1.0),
+                                  chunk, kv_cap=cap)
+    text = jax.jit(fn, donate_argnums=(2,)).lower(
+        params, sds((slots, 1)), caches, sds((slots, cap // page)), sds((slots,)),
+        sds((slots,), jnp.bool_), sds((slots,)), sds((slots,)), sds((slots,)),
+        sds((slots,)), sds((2,), jnp.uint32)).compile().as_text()
+    assert len([line for line in text.splitlines() if " while(" in line]) == 1
+    assert text.count("tpu_custom_call") == 7
+    assert "decode_attention" in text and "selective_step" in text
+    assert " scatter(" not in text
+    assert _pool_relayouts(text, kv_shape) == []
+
+
+def test_phi4flashs_prefill_stops_at_the_full_layer(one_chip, monkeypatch):
+    """The same eight layers' 4,096-token miss prefill: three selective scans,
+    two banded flash calls, and ``decode_attention`` twice (the full layer's
+    one query a sequence and the cross layer's, over the rows just written);
+    the memory unit's and the cross layer's matmuls have one row, and every
+    leaf of the donated batch-1 cache is aliased to a result."""
+    import importlib
+    from deepspeed_tpu.analysis.donation import _alias_param_positions, _flat_args_info
+    from deepspeed_tpu.inference.decode_fns import build_prefill, make_slot_select_fn
+    from deepspeed_tpu.inference.serving.executor import _prefill
+    from deepspeed_tpu.models.causal_lm import init_cache
+    from deepspeed_tpu.ops.attention import decode, flash
+    ss = importlib.import_module("deepspeed_tpu.ops.ssm.selective_scan")
+    for mod in (decode, flash, ss):
+        monkeypatch.setattr(mod, "_interpret", lambda: False)
+    cfg, cap, bucket = _phi4flash(8), 6144, 4096
+
+    def sds(shape, dtype=jnp.int32):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    module, params, _, _ = _abstract_model(cfg, 32, cap, 12289, 16, sds)
+    one = jax.tree_util.tree_map(
+        lambda x: sds(x.shape, x.dtype),
+        jax.eval_shape(lambda: init_cache(cfg, 1, cap, dtype=cfg.dtype)))
+    fn = _prefill(build_prefill(module, lambda p: p),
+                  make_slot_select_fn(False, 1.0, 0, 1.0), cfg, cap, cfg.dtype)
+    lowered = jax.jit(fn, donate_argnums=(1,), keep_unused=True).lower(
+        params, one, sds((1, bucket)), sds((2,)), sds((2,), jnp.uint32))
+    text = lowered.compile().as_text()
+    assert text.count("tpu_custom_call") == 7 and " while(" not in text
+    for kernel in ("selective_scan", "flash_fwd", "decode_attention"):
+        assert kernel in text, kernel
+    hlo = lowered.as_text()
+    assert f"tensor<1x{bucket}x10240xbf16>" in hlo   # a Mamba in_proj at every position
+    assert "tensor<1x1x5120xbf16>" in hlo           # the memory unit's gate at one
+    assert f"tensor<1x{bucket}x20480xbf16>" not in hlo and "tensor<1x1x10240xbf16>" in hlo
+    donated = [i for i, (_, info) in enumerate(_flat_args_info(lowered)) if info.donated]
+    assert len(donated) == len(jax.tree_util.tree_leaves(one)) > 0
+    assert len(_alias_param_positions(text)) == len(donated)
