@@ -40,7 +40,7 @@ from ...models.causal_lm import init_cache
 from ...observability import profiler as obs_profiler
 from ...observability.trace import get_tracer, scope
 from ...utils.fault_injection import fault_point
-from ...ops.moe.grouped_ffn import plan_rows
+from ...ops.moe.grouped_ffn import plan_rows, tile_rows
 from ...ops.paged_attention import pages_to_dense, write_view_rows
 from ..decode_fns import (block_chunk_width, build_block_decode_chunk,
                           build_paged_decode_chunk, build_paged_spec_verify,
@@ -315,8 +315,9 @@ class ChunkedDecodeExecutor:
         self.last_prefill_plan_rows = 0  # and the rows its plans laid out
         # what a decode chunk's plans lay out: its forwards x a forward's rows
         # (a block model's forward carries two blocks a slot)
+        self._chunk_tokens = self.slots * max(1, 2 * self.block)
         self._chunk_plan_rows = self.chunk_size * self.moe_plan_rows(
-            self.slots * max(1, 2 * self.block)) if self.with_stats else 0
+            self._chunk_tokens) if self.with_stats else 0
         self.pool = self._build_pool()
         self._one = None                # the miss prefill's batch-1 cache
         self._slot_select = make_slot_select_fn(*self.sampling)
@@ -534,7 +535,13 @@ class ChunkedDecodeExecutor:
             else:
                 self._called.add(fn)
                 with tracer.phase("setup.program", program=program,
-                                  bucket=bucket):
+                                  bucket=bucket) as ph:
+                    # the tokens of one forward: a prefill's bucket, a chunk's
+                    # slot-batch (a verify's rows are not reckoned here)
+                    tokens = {"prefill": bucket, "suffix_prefill": bucket,
+                              "decode_chunk": self._chunk_tokens}.get(program)
+                    if self.with_stats and tokens:
+                        ph.set(moe_tile_rows=self.moe_tile_rows(tokens))
                     out = fn(*args)
         return out, sp.t1
 
@@ -662,7 +669,8 @@ class ChunkedDecodeExecutor:
                 self.last_prefill_moe = out[1:]
                 self.last_prefill_plan_rows = self.moe_plan_rows(bucket)
                 sp.set(moe_assignments=int(out[1]),
-                       moe_experts_touched=int(out[2]))
+                       moe_experts_touched=int(out[2]),
+                       moe_tile_rows=self.moe_tile_rows(bucket))
             if self.block:
                 # the whole blocks of the prompt are committed and no token
                 # is yielded: what the head gave is not a token of this model
@@ -683,7 +691,15 @@ class ChunkedDecodeExecutor:
         the programs' own counts say what of it was live)."""
         cfg = self.engine.model_config
         return cfg.layer_kinds.count("E") * plan_rows(
-            tokens * cfg.experts_per_token, cfg.held_experts[1])
+            tokens * cfg.experts_per_token, cfg.held_experts[1],
+            cfg.n_routed_experts)
+
+    def moe_tile_rows(self, tokens: int) -> int:
+        """The height of the expert kernel's tiles in a forward of ``tokens``
+        tokens (``grouped_ffn.tile_rows``: static, from the assignments and
+        the router's width)."""
+        cfg = self.engine.model_config
+        return tile_rows(tokens * cfg.experts_per_token, cfg.n_routed_experts)
 
     def run_chunk(self, toks: np.ndarray, lens: np.ndarray, active: np.ndarray,
                   remaining: np.ndarray, eos_ids: np.ndarray, seeds: np.ndarray,

@@ -98,7 +98,7 @@ class GatedMoE(nn.Module):
         with scope("moe.experts"):
             args = (w_up.astype(dt), w_down.astype(dt), jax.nn.silu,
                     None if valid is None else valid.reshape(-1), w_gate.astype(dt))
-        out, stats = grouped_experts(x, idx, w, first, count, *args)
+        out, stats = grouped_experts(x, idx, w, first, count, w_r.shape[1], *args)
         if self.shared_width:
             with scope("moe.shared"):
                 mid = jax.nn.silu(jnp.dot(x, s_gate.astype(dt),

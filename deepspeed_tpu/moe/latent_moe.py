@@ -91,7 +91,7 @@ class LatentMoE(nn.Module):
         with scope("moe.experts"):
             args = (w1.astype(dt), w2.astype(dt), self.act,
                     None if valid is None else valid.reshape(-1))
-        r, stats = grouped_experts(z, idx, w, first, count, *args)
+        r, stats = grouped_experts(z, idx, w, first, count, w_r.shape[1], *args)
         with scope("moe.shared"):
             shared = self.act(jnp.dot(x, s1.astype(dt),
                                       preferred_element_type=jnp.float32))

@@ -286,6 +286,7 @@ SPANS: Dict[str, Tuple[str, str, Tuple[str, ...], str]] = {
     "serving.prefill": (BOTH, "compiled steps",
                         ("request_id", "bucket", "tokens", "prefix_len",
                          "moe_assignments", "moe_experts_touched",
+                         "moe_tile_rows",
                          "blocks_committed", "positions_self",
                          "positions_cross"),
                         "sched_admit_host_ms by bucket; attribution phase "
@@ -412,8 +413,11 @@ SPANS: Dict[str, Tuple[str, str, Tuple[str, ...], str]] = {
                       ("pool", "pages", "slots", "state_bytes",
                        "heads_per_row", "ring_bytes"),
                       "setup_engine_init_s"),
-    "setup.program": (PHASE, "device set-up", ("program", "bucket"),
-                      "setup_engine_init_s lines, beside setup_compile_s"),
+    "setup.program": (PHASE, "device set-up",
+                      ("program", "bucket", "moe_tile_rows"),
+                      "setup_engine_init_s lines, beside setup_compile_s "
+                      "(moe_tile_rows: the height of the expert kernel's "
+                      "tiles the program was built with)"),
 }
 
 #: modules whose span call sites the lint walks (repo-relative paths)

@@ -227,7 +227,7 @@ def check_moe_grouped_ffn(tokens: int = 32, held: int = 128, of: int = 512,
     wg = jax.random.normal(jax.random.PRNGKey(3), (held, lat, f), jnp.bfloat16) \
         * lat ** -0.5 if gated else None
     act = jax.nn.silu if gated else relu2
-    tm = tile_rows(tokens * k)
+    tm = tile_rows(tokens * k, of)
     plan = jax.jit(partial(dispatch_plan, first=0, count=held, tm=tm))(idx)
     x_rows, te, tv = z[plan["row_token"]], plan["tile_expert"], plan["tile_valid"]
     got = jax.jit(partial(grouped_ffn, act=act, tm=tm))(x_rows, te, tv, w1, w2,
@@ -285,8 +285,9 @@ KERNEL_CHECKS: Dict[str, Tuple] = {
     # PR 27), so the tolerance is a bf16 step of an output near 4, not a model
     "moe_grouped_ffn_decode": (check_moe_grouped_ffn, 0.03),
     "moe_grouped_ffn_prefill": (partial(check_moe_grouped_ffn, tokens=512), 0.03),
-    # sarvam-105b.doc4k32's prefill: 4,096 tokens x 8, 16 held of 128, gated
-    # 4096 x 2048 in two width blocks, ~1 tile in 8 live
+    # sarvam-105b.doc4k32's prefill: 4,096 tokens x 8, 16 held of 128 (256
+    # rows an expert: tiles of 128), gated 4096 x 2048 in two width blocks,
+    # ~1 tile in 7 live
     "moe_grouped_ffn_long_prefill": (partial(
         check_moe_grouped_ffn, tokens=4096, held=16, of=128, k=8, lat=4096,
         f=2048, gated=True), 0.03),

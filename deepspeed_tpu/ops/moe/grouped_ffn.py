@@ -27,7 +27,7 @@ from jax.experimental.pallas import tpu as pltpu
 from ...utils.device import pallas_interpret as _interpret
 
 # 2 x (W1 + W2, and the gate's where there is one) blocks of an expert in
-# flight plus the tile's activations
+# flight plus the tile's activations (``tile_bytes``)
 VMEM_LIMIT_BYTES = 64 * 2 ** 20
 
 # the most assignments (T * k) whose plan the dense comparisons count faster
@@ -35,35 +35,80 @@ VMEM_LIMIT_BYTES = 64 * 2 ** 20
 DENSE_PLAN_UP_TO = 4096
 
 
-def width_blocks(l: int, f: int, mats: int, itemsize: int) -> int:
+def tile_bytes(tm: int, l: int, f: int, itemsize: int) -> int:
+    """VMEM a tile's activations take beside the weight blocks: its ``(tm,
+    l)`` rows, its float32 output block and the result of the width block
+    before handed in, each double-buffered, and the ``(tm, f)`` float32
+    products of the gate and the up matrix with the cast the down product
+    reads."""
+    return tm * (2 * l * (itemsize + 4 + 4) + f * (4 + 4 + itemsize))
+
+
+def width_blocks(l: int, f: int, mats: int, itemsize: int, tm: int) -> int:
     """Into how many blocks of its width ``f`` an expert is cut so that two
-    experts' weight blocks in flight and the tile's activations fit
-    ``VMEM_LIMIT_BYTES``: 1 for every gated expert of ``l`` 4096 up to a width
-    of 1194 in bf16 (56 MiB / (2 x 3 x 4096 x 2 B): whole matrices, one kernel
-    call); 2 for sarvam's gated 4096 x 2048
-    (three blocks of 16 MiB: 96 MiB in flight whole, 48 halved). An expert
-    splits exactly over its width, ``sum_b (act(x Wg_b) * (x W1_b)) W2_b``,
-    so the kernel runs once a block on that block of every matrix (picked in
-    the index maps: no copy) and the results are added."""
+    experts' weight blocks in flight and the activations of a tile of ``tm``
+    rows (:func:`tile_bytes`) fit ``VMEM_LIMIT_BYTES``: 1 for every size the
+    cells had before sarvam (the largest, LFM2's gated 2048 x 1792: 42 MiB of
+    blocks); 2 for sarvam's gated 4096 x 2048 (three blocks of 16 MiB: 96 MiB
+    in flight whole, 48 halved) at every height :func:`tile_rows` gives: a
+    128-row tile's activations are 11.25 MiB beside them, and the chip's
+    compiler takes that; 256 rows (22.5 MiB) it refuses beside two blocks,
+    so they would take four. Two blocks at 128 rows is also what the chip
+    read fastest for the whole layer call at T 4096: 6.34 ms against 6.89 at
+    128 rows x four blocks and 6.86 at 256 x four (each block more is one
+    more pass over the live rows; PR 60). An expert splits
+    exactly over its width, ``sum_b (act(x Wg_b) * (x W1_b)) W2_b``, so the
+    kernel runs once a block on that block of every matrix (picked in the
+    index maps: no copy) and the results are added."""
     n = 1
-    while 2 * mats * l * (f // n) * itemsize + 8 * 2 ** 20 > VMEM_LIMIT_BYTES \
-            and f % (2 * n) == 0 and (f // (2 * n)) % 128 == 0:
+    while 2 * mats * l * (f // n) * itemsize + tile_bytes(tm, l, f // n, itemsize) \
+            > VMEM_LIMIT_BYTES and f % (2 * n) == 0 and (f // (2 * n)) % 128 == 0:
         n *= 2
     return n
 
 
-def tile_rows(assignments: int) -> int:
-    """Rows a tile: 16 (one bf16 sublane tile) while the step is bound by the
-    weights it reads, 32 once a prompt brings tens of rows an expert."""
-    return 16 if assignments <= 2048 else 32
+# rows an expert is expected to get (A / E) from which a tile is 128 rows high
+TALL_TILES_FROM = 128
 
 
-def plan_rows(assignments: int, count: int) -> int:
+def tile_rows(assignments: int, experts: int) -> int:
+    """Rows a tile, from the static ``A = T * k`` and the router's width
+    ``E``: 16 (one bf16 sublane tile) while the step is bound by the weights
+    it reads (up to 2,048 assignments: every decode step), 32 once a prompt
+    brings tens of rows an expert, 128 (the height of the MXU's weight tile)
+    once an expert is expected a whole such tile, ``A / E >=
+    TALL_TILES_FROM``: the kernel's time goes by the tiles it walks (a
+    128 x 128 weight tile is loaded to stream the tile's rows through it),
+    the gathers' by the rows laid out, half a tile an expert of them padding.
+
+    ONE layer call on a v5e (plan, ``x[row_token]``, the kernel calls,
+    ``take`` and the sum; the kernel alone in brackets), ms by height, 16 of
+    128 experts of 4096 x 2048 gated held, top 8 (sarvam; two width blocks):
+    A / E 64: 32 rows 2.71 (2.17) | 64 rows 2.47 (1.42) | 128 rows 2.50
+    (1.40) | 256 rows 3.30; A / E 128: 4.24 (2.21) | 3.82 (1.79) | 3.68
+    (1.63) | 4.28; A / E 256: 7.37 (3.36) | 6.47 (2.46) | 6.34 (2.32) | 6.86
+    (256 rows take four width blocks). 36 of 72 experts of 4096 x 768 gated
+    held, top 10 (granite-small; one block, half of all rows held): A / E
+    71: 2.09 (1.30) | 2.12 | 2.06 (1.16) | 2.44; A / E 142: 3.66 (1.57) |
+    3.72 | 3.82 (1.69) | 3.54; A / E 284: 6.21 (2.16) | 6.18 | 6.32 (2.21) |
+    6.65. So the wide expert gains 8 to 14 % of the layer from 64 rows an
+    expert up, and the narrow one, whose 32-row tiles already run at half
+    the MXU's rate, loses 2 to 4 % to the padding (8,704 rows laid at 142
+    rows an expert where 32-row tiles lay 5,664): the height follows ``A /
+    E`` alone, from a whole tile's worth of rows (PR 60, call 245). Every
+    height gives a row the same bits."""
+    if assignments <= 2048:
+        return 16
+    return 32 if assignments < TALL_TILES_FROM * experts else 128
+
+
+def plan_rows(assignments: int, count: int, experts: int) -> int:
     """Rows :func:`dispatch_plan` lays out for ``assignments`` = T * k over
-    ``count`` held experts at :func:`tile_rows`: the worst case, every
-    assignment held and each expert's last tile part filled. A static shape;
-    what is live of it the plan says (``tile_valid``)."""
-    tm = tile_rows(assignments)
+    ``count`` held experts of the router's ``experts`` at :func:`tile_rows`:
+    the worst case, every assignment held and each expert's last tile part
+    filled. A static shape; what is live of it the plan says
+    (``tile_valid``)."""
+    tm = tile_rows(assignments, experts)
     return (assignments // tm + min(count, assignments)) * tm
 
 
@@ -218,7 +263,7 @@ def grouped_ffn(x_rows, tile_expert, tile_valid, w1, w2, act, tm: int,
         return grouped_ffn_xla(x_rows, tile_expert, tile_valid, w1, w2, act, tm,
                                w_gate)
     gate = [] if w_gate is None else [w_gate]
-    n = width_blocks(l, f, 2 + len(gate), w1.dtype.itemsize)
+    n = width_blocks(l, f, 2 + len(gate), w1.dtype.itemsize, tm)
     fb = f // n
     # the last real tile (0 where no assignment is held: nothing is written)
     last = jnp.maximum(jnp.sum(tile_valid, dtype=jnp.int32) - 1, 0).reshape(1)
@@ -256,16 +301,18 @@ def grouped_ffn(x_rows, tile_expert, tile_valid, w1, w2, act, tm: int,
     return out
 
 
-def grouped_experts(x, idx, w, first: int, count: int, w1, w2, act, valid=None,
-                    w_gate=None):
-    """What an expert layer that holds experts ``[first, first + count)``
-    adds for tokens ``x`` (T, l): plan, ONE kernel call, and each token's
-    ``k`` rows gathered back and weighted (``idx``, ``w`` (T, k): the experts
-    each token chose over ALL experts, and their weights). Assignments that
+def grouped_experts(x, idx, w, first: int, count: int, experts: int, w1, w2,
+                    act, valid=None, w_gate=None):
+    """What an expert layer that holds experts ``[first, first + count)`` of
+    its router's ``experts`` adds for tokens ``x`` (T, l): plan, ONE kernel
+    call, and each token's ``k`` rows gathered back and weighted (``idx``,
+    ``w`` (T, k): the experts each token chose over ALL experts, and their
+    weights; the tiles' height follows ``idx.size / experts``, the rows an
+    expert is expected: :func:`tile_rows`). Assignments that
     fall on experts held elsewhere add nothing. Returns ``(T, l)`` float32 and
     ``(assignments on held experts, distinct held experts touched)``."""
     from ...observability import scope   # here: the kernel's lines above stay put
-    tm = tile_rows(idx.size)
+    tm = tile_rows(idx.size, experts)
     with scope("moe.plan"):
         plan = dispatch_plan(idx, first, count, tm, valid)
     with scope("moe.rows"):

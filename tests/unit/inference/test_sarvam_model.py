@@ -362,7 +362,8 @@ def test_the_rows_the_plans_lay_out_are_counted_beside_what_is_held(engine):
                        cfg.held_experts[1])
     assert layers == 2 and held == 8
     ex, tel = sched.executor, sched.telemetry
-    assert ex.moe_plan_rows(16) == layers * plan_rows(16 * k, held)
+    assert ex.moe_plan_rows(16) == layers * plan_rows(16 * k, held,
+                                                      cfg.n_routed_experts)
     sched.submit(st.ids(9, seed=9)[0], max_new_tokens=6)
     sched.step()                                    # the prefill and one chunk
     prefill = ex.moe_plan_rows(ex.bucket_for(9))
@@ -373,6 +374,52 @@ def test_the_rows_the_plans_lay_out_are_counted_beside_what_is_held(engine):
     assert (tel.moe_plan_rows - prefill) % chunk == 0
     assert 0 < tel.moe_assignments < tel.moe_plan_rows
     assert "moe_plan_rows_total" in get_registry().prometheus_text()
+
+
+def test_a_prefill_says_the_height_of_the_tiles_it_was_built_with(engine, monkeypatch):
+    """``moe_tile_rows`` (PR 60) beside ``moe_assignments`` on
+    ``serving.prefill``, and on the ``setup.program`` phase of every program
+    with expert layers: ``grouped_ffn.tile_rows`` of the program's static
+    assignments and the router's width, which the expert layers take from
+    their router matrix."""
+    from deepspeed_tpu.inference.serving.prefix_cache import PrefixCacheConfig
+    from deepspeed_tpu.inference.serving.scheduler import (
+        ContinuousBatchingScheduler, ServingConfig)
+    from deepspeed_tpu.observability import get_tracer
+    from deepspeed_tpu.observability.schema import SPANS
+    from deepspeed_tpu.ops.moe import grouped_ffn as g
+    assert "moe_tile_rows" in SPANS["serving.prefill"][2]
+    assert "moe_tile_rows" in SPANS["setup.program"][2]
+    cfg = engine.model_config
+    k, experts = cfg.experts_per_token, cfg.n_routed_experts
+    seen = []
+    whole = g.tile_rows
+    monkeypatch.setattr(g, "tile_rows", lambda a, e: seen.append((a, e)) or whole(a, e))
+    tracer = get_tracer()
+    tracer.reset()
+    tracer.enable()
+    try:
+        sched = ContinuousBatchingScheduler(engine, ServingConfig(
+            slots=2, chunk_size=4, max_seq_len=48, kv_page_size=8,
+            prefix_cache=PrefixCacheConfig(enabled=False)))
+        sched.submit(st.ids(19, seed=4)[0], max_new_tokens=5)
+        sched.run()
+        spans, phases = list(tracer.spans), tracer.phases
+    finally:
+        tracer.disable()
+        tracer.reset()
+    ex = sched.executor
+    (prefill,) = [s["attrs"] for s in spans if s["name"] == "serving.prefill"]
+    assert prefill["bucket"] == 32 and prefill["moe_assignments"] > 0
+    assert prefill["moe_tile_rows"] == ex.moe_tile_rows(32) == whole(32 * k, experts) == 16
+    built = {p["attrs"]["program"]: p["attrs"]["moe_tile_rows"] for p in phases
+             if p["name"] == "setup.program"}
+    assert built == {"prefill": 16, "decode_chunk": 16}
+    # a forward this long with this router would be built with the tall tiles
+    assert ex.moe_tile_rows(2048) == 128 and 2048 * k / experts >= g.TALL_TILES_FROM
+    # the layers traced with the router's width, read off their router matrix
+    assert (32 * k, experts) in seen and (2 * k, experts) in seen
+    assert {e for _, e in seen} == {experts}
 
 
 def test_a_latent_layer_refuses_a_prefill_at_a_cache_offset(tiny):
@@ -478,7 +525,9 @@ def test_an_expert_cut_over_its_width_adds_up_to_the_whole_expert(gated, monkeyp
     2048 experts take two: three whole blocks, double-buffered, pass the kernel's
     VMEM) gives what one call on whole matrices gives."""
     from deepspeed_tpu.ops.moe import grouped_ffn as g
-    assert g.width_blocks(4096, 2048, 3, 2) == 2 and g.width_blocks(4096, 768, 3, 2) == 1
+    assert g.width_blocks(4096, 2048, 3, 2, 16) == 2 \
+        and g.width_blocks(4096, 2048, 3, 2, 128) == 2 \
+        and g.width_blocks(4096, 768, 3, 2, 128) == 1
     key = jax.random.PRNGKey(3)
     e, l, f, tm, tiles = 3, 128, 512, 16, 5
     x = jax.random.normal(key, (tiles * tm, l))
@@ -488,14 +537,15 @@ def test_an_expert_cut_over_its_width_adds_up_to_the_whole_expert(gated, monkeyp
     tv = jnp.asarray([1, 1, 1, 1, 0], jnp.int32)
     kw = dict(act=jax.nn.silu, tm=tm, w_gate=wg if gated else None)
     mats = 3 if gated else 2
-    assert g.width_blocks(l, f, mats, 4) == 1
+    assert g.width_blocks(l, f, mats, 4, tm) == 1
     whole = g.grouped_ffn(x, te, tv, w1, w2, **kw)
     want = g.grouped_ffn_xla(x, te, tv, w1, w2, jax.nn.silu, tm, wg if gated else None)
     live = slice(0, 4 * tm)                              # the invalid tile is not written
     assert float(jnp.abs(whole[live] - want[live]).max()) < 1e-4
     # a VMEM that holds two experts' blocks only at a quarter of their width
-    monkeypatch.setattr(g, "VMEM_LIMIT_BYTES", 2 * mats * l * (f // 4) * 4 + 8 * 2 ** 20)
-    assert g.width_blocks(l, f, mats, 4) == 4
+    monkeypatch.setattr(g, "VMEM_LIMIT_BYTES",
+                        2 * mats * l * (f // 4) * 4 + g.tile_bytes(tm, l, f // 4, 4))
+    assert g.width_blocks(l, f, mats, 4, tm) == 4
     cut = g.grouped_ffn(x, te, tv, w1, w2, **kw)
     assert float(jnp.abs(cut[live] - whole[live]).max()) \
         < 1e-4 * float(jnp.abs(whole[live]).max())

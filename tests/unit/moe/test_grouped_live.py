@@ -45,7 +45,7 @@ def test_the_plan_is_one_plan_whichever_way_it_is_counted(
     k = 8
     T = _crossover(k) - (side == "below")
     assert T * k in (4096, 4104) and g.plan_by_prefix_sums(T * k) is (side == "above")
-    tm = g.tile_rows(T * k)
+    tm = g.tile_rows(T * k, n)
     idx, valid = _routers(case, T, k, n, first, count, np.random.RandomState(T + n))
     plans = {}
     for prefix in (False, True):
@@ -60,7 +60,7 @@ def test_the_plan_is_one_plan_whichever_way_it_is_counted(
         held = held & np.asarray(valid)[:, None]
     assert plan["n_assigned"] == held.sum()
     assert bool(plan["n_assigned"] == 0) is (case == "none_held")
-    R = g.plan_rows(T * k, count)
+    R = g.plan_rows(T * k, count, n)
     assert plan["row_token"].shape == (R,) and (plan["pos"][~held] == R).all()
     # a held assignment's row is fed by its token
     t, j = np.nonzero(held)
@@ -123,17 +123,18 @@ def test_nothing_reads_a_row_of_a_dead_tile(blocks, monkeypatch):
     x, idx, w, w1, w2, wg = _layer_operands()
     monkeypatch.setattr(g, "width_blocks", lambda *a: blocks)
     served = g.grouped_ffn
-    tm = g.tile_rows(idx.size)
+    tm = g.tile_rows(idx.size, 32)
 
     def poisoned(x_rows, te, tv, *a, **kw):
         dead = jnp.repeat(tv == 0, tm)
         assert bool(dead.any())
         return jnp.where(dead[:, None], jnp.nan, served(x_rows, te, tv, *a, **kw))
 
-    want, stats = g.grouped_experts(x, idx, w, first, count, w1, w2, jax.nn.silu,
-                                    None, wg)
+    want, stats = g.grouped_experts(x, idx, w, first, count, 32, w1, w2,
+                                    jax.nn.silu, None, wg)
     monkeypatch.setattr(g, "grouped_ffn", poisoned)
-    got, _ = g.grouped_experts(x, idx, w, first, count, w1, w2, jax.nn.silu, None, wg)
+    got, _ = g.grouped_experts(x, idx, w, first, count, 32, w1, w2, jax.nn.silu, None,
+                               wg)
     assert np.isfinite(np.asarray(got)).all() and np.array_equal(got, want)
     assert int(stats[0]) == int(((idx >= first) & (idx < first + count)).sum())
 
@@ -144,16 +145,19 @@ def test_a_layer_that_holds_no_assignment_adds_nothing(blocks, monkeypatch):
     nothing is gathered, the layer's share is exactly zero."""
     x, idx, w, w1, w2, wg = _layer_operands()
     monkeypatch.setattr(g, "width_blocks", lambda *a: blocks)
-    out, stats = g.grouped_experts(x, idx % 8, w, 8, 8, w1, w2, jax.nn.silu, None, wg)
+    out, stats = g.grouped_experts(x, idx % 8, w, 8, 8, 32, w1, w2, jax.nn.silu, None,
+                                   wg)
     assert not np.asarray(out).any() and list(np.asarray(stats)) == [0, 0]
 
 
-@pytest.mark.parametrize("assignments,count,rows", [
-    (256, 16, (16 + 16) * 16), (32768, 16, 33280), (704, 128, (44 + 128) * 16),
-    (11264, 128, (352 + 128) * 32), (3, 8, (0 + 3) * 16)])
-def test_the_rows_a_plan_lays_out_are_its_static_worst_case(assignments, count, rows):
-    assert g.plan_rows(assignments, count) == rows
-    tm = g.tile_rows(assignments)
+@pytest.mark.parametrize("assignments,count,experts,rows", [
+    (256, 16, 128, (16 + 16) * 16), (32768, 16, 128, (256 + 16) * 128),
+    (704, 128, 512, (44 + 128) * 16), (11264, 128, 512, (352 + 128) * 32),
+    (3, 8, 8, (0 + 3) * 16)])
+def test_the_rows_a_plan_lays_out_are_its_static_worst_case(assignments, count,
+                                                            experts, rows):
+    assert g.plan_rows(assignments, count, experts) == rows
+    tm = g.tile_rows(assignments, experts)
     shapes = jax.eval_shape(functools.partial(g.dispatch_plan, first=0, count=count,
                                               tm=tm),
                             jax.ShapeDtypeStruct((assignments, 1), jnp.int32))

@@ -30,7 +30,7 @@ def test_the_grouped_expert_kernel_compiles_at_the_published_widths(
     from deepspeed_tpu.ops.moe import grouped_ffn as g
     monkeypatch.setattr(g, "_interpret", lambda: False)     # compile, do not interpret
     held, latent, width, k = 128, 1024, 2688, 22
-    tm = g.tile_rows(tokens * k)
+    tm = g.tile_rows(tokens * k, 512)
     tiles = tokens * k // tm + held
 
     def sds(shape, dtype):
@@ -243,7 +243,7 @@ def test_the_gated_expert_kernel_compiles_at_sdars_published_widths(
     from deepspeed_tpu.ops.moe import grouped_ffn as g
     monkeypatch.setattr(g, "_interpret", lambda: False)
     held, d, width, k = 128, 2048, 768, 8
-    tm = g.tile_rows(tokens * k)
+    tm = g.tile_rows(tokens * k, 128)
     tiles = tokens * k // tm + held
 
     def sds(shape, dtype):
@@ -268,7 +268,7 @@ def test_the_gated_expert_kernel_compiles_at_lfm2s_published_widths(
     from deepspeed_tpu.ops.moe import grouped_ffn as g
     monkeypatch.setattr(g, "_interpret", lambda: False)
     held, d, width, k = 32, 2048, 1792, 4
-    tm = g.tile_rows(tokens * k)
+    tm = g.tile_rows(tokens * k, 32)
     assert tm == 16
     tiles = tokens * k // tm + held
     assert 2 * 3 * d * width * 2 < g.VMEM_LIMIT_BYTES
@@ -297,7 +297,7 @@ def test_the_gated_expert_kernel_compiles_at_granite_smalls_published_widths(
     from deepspeed_tpu.ops.moe import grouped_ffn as g
     monkeypatch.setattr(g, "_interpret", lambda: False)
     held, d, width, k = 36, 4096, 768, 10
-    tm = g.tile_rows(tokens * k)
+    tm = g.tile_rows(tokens * k, 72)
     assert tm == (16 if tokens == 32 else 32)
     tiles = tokens * k // tm + held
     assert (tokens, tiles) in ((32, 56), (512, 196))
@@ -530,7 +530,7 @@ def test_the_kernels_are_called_with_the_blocks_they_were(case):
 
 
 # ------------------------------------------------------------- sarvam-105b (PR 57)
-@pytest.mark.parametrize("tokens", [32, 4096])
+@pytest.mark.parametrize("tokens", [32, 512, 2048, 4096, 6143])
 def test_the_gated_expert_kernel_compiles_at_sarvams_published_widths(
         one_chip, tokens, monkeypatch):
     """``moe_grouped_ffn`` in its gated form at sarvam-105b's widths, the cell
@@ -539,16 +539,20 @@ def test_the_gated_expert_kernel_compiles_at_sarvams_published_widths(
     (50.3 MB). Three whole-matrix blocks, double-buffered, are 96 MiB: past
     the kernel's 64 MiB, so the experts' width is cut in two
     (``width_blocks``) and the kernel called once a half, 48 MiB in flight;
-    every size the chip had before stays whole, one call."""
+    every size the chip had before stays whole, one call. A decode step's
+    tiles are 16 rows, the 512 bucket's 32, and from 128 rows an expert (the
+    2,048 bucket on: the cell's 4,096) 128 rows, still in two width blocks."""
     from deepspeed_tpu.ops.moe import grouped_ffn as g
     monkeypatch.setattr(g, "_interpret", lambda: False)
     held, d, width, k = 16, 4096, 2048, 8
-    tm = g.tile_rows(tokens * k)
+    tm = g.tile_rows(tokens * k, 128)
+    assert tm == {32: 16, 512: 32}.get(tokens, 128)     # 128 rows an expert and more
     tiles = tokens * k // tm + held
-    assert g.width_blocks(d, width, 3, 2) == 2
-    assert 2 * 3 * d * (width // 2) * 2 + 8 * 2 ** 20 < g.VMEM_LIMIT_BYTES
+    assert g.width_blocks(d, width, 3, 2, tm) == 2
+    assert 2 * 3 * d * (width // 2) * 2 + g.tile_bytes(tm, d, width // 2, 2) \
+        < g.VMEM_LIMIT_BYTES
     for l, f, mats in ((1024, 2688, 2), (2048, 768, 3), (2048, 1792, 3), (4096, 768, 3)):
-        assert g.width_blocks(l, f, mats, 2) == 1
+        assert {g.width_blocks(l, f, mats, 2, rows) for rows in (16, 32, 128)} == {1}
 
     def sds(shape, dtype):
         return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
@@ -567,21 +571,24 @@ def test_sarvams_prefill_expert_layer_pays_for_what_is_held(one_chip, monkeypatc
     """One expert layer of ``sarvam-105b.doc4k32``'s 4,096-token prefill
     (4,096 x 8 assignments, 16 held of 128, 4096 x 2048 gated) compiles for
     the described v5e under ``VMEM_LIMIT_BYTES``, and its program does not do
-    the worst case's work (PR 58): the plan holds no ``A x A`` and no ``R x
-    A`` comparison (prefix sums and one scatter at this size), the two width
+    the worst case's work (PR 58), at the height ``tile_rows`` gives 256 rows
+    an expert (128: PR 60) and ``width_blocks`` at that height (two): the
+    plan holds no ``A x A`` and no ``R x A`` comparison (prefix sums and one scatter at this size), the two width
     blocks are two kernel calls of which the second is handed the first's
-    result aliased, and no ``f32[33280,4096]`` is added outside them."""
+    result aliased, and no ``f32[34816,4096]`` is added outside them."""
     from deepspeed_tpu.ops.moe import grouped_ffn as g
     monkeypatch.setattr(g, "_interpret", lambda: False)
     tokens, held, d, width, k = 4096, 16, 4096, 2048, 8
-    rows = g.plan_rows(tokens * k, held)
-    assert rows == 33280 and g.plan_by_prefix_sums(tokens * k)
+    rows = g.plan_rows(tokens * k, held, 128)
+    assert rows == 34816 and g.tile_rows(tokens * k, 128) == 128
+    assert g.width_blocks(d, width, 3, 2, 128) == 2 and g.plan_by_prefix_sums(tokens * k)
 
     def sds(shape, dtype):
         return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
 
     def layer(x, idx, w, up, down, gate):
-        return g.grouped_experts(x, idx, w, 0, held, up, down, jax.nn.silu, None, gate)
+        return g.grouped_experts(x, idx, w, 0, held, 128, up, down, jax.nn.silu, None,
+                                 gate)
 
     up = sds((held, d, width), jnp.bfloat16)
     compiled = jax.jit(layer).lower(
@@ -602,6 +609,36 @@ def test_sarvams_prefill_expert_layer_pays_for_what_is_held(one_chip, monkeypatc
     assert f"[{tokens * k},{tokens * k}]" not in text
     assert f"[{rows},{tokens * k}]" not in text
     assert text.count(" scatter(") == 1 and " sort(" not in text
+
+
+@pytest.mark.parametrize("tm,blocks", [(128, 2), (128, 4), (256, 2), (256, 4)])
+def test_the_tile_the_kernel_is_given_is_counted_against_its_vmem(
+        one_chip, tm, blocks, monkeypatch):
+    """``width_blocks`` reckons the tile it is given (``tile_bytes``), and the
+    chip's compiler agrees on both sides of ``VMEM_LIMIT_BYTES`` at sarvam's
+    expert: 128 rows fit beside two width blocks in flight (and four), 256
+    rows only beside four (22.5 MiB of activations + 48 MiB of blocks)."""
+    from deepspeed_tpu.ops.moe import grouped_ffn as g
+    monkeypatch.setattr(g, "_interpret", lambda: False)
+    held, d, width, tiles = 16, 4096, 2048, 40
+    fits = blocks >= g.width_blocks(d, width, 3, 2, tm)
+    assert fits is ((tm, blocks) != (256, 2))
+    assert (2 * 3 * d * (width // blocks) * 2 + g.tile_bytes(tm, d, width // blocks, 2)
+            <= g.VMEM_LIMIT_BYTES) is fits
+    monkeypatch.setattr(g, "width_blocks", lambda *a: blocks)
+
+    def sds(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    up = sds((held, d, width), jnp.bfloat16)
+    lowered = jax.jit(functools.partial(g.grouped_ffn, act=jax.nn.silu, tm=tm)).lower(
+        sds((tiles * tm, d), jnp.bfloat16), sds((tiles,), jnp.int32),
+        sds((tiles,), jnp.int32), up, sds((held, width, d), jnp.bfloat16), w_gate=up)
+    if fits:
+        assert lowered.compile().as_text().count("tpu_custom_call") == blocks
+    else:
+        with pytest.raises(Exception, match="vmem"):
+            lowered.compile()
 
 
 @pytest.mark.parametrize("tokens", [512, 4096])
