@@ -1,7 +1,9 @@
 """Serving load generator + chaos soak harness: Poisson (or Markov-modulated
 bursty) arrivals through the continuous-batching scheduler — or, with
 ``--replicas N``, through the multi-replica router under scheduled fault
-injection — BENCH-style JSON on stdout.
+injection — one JSON object on stdout. The tests drive it in-process at
+``--smoke`` size for its counts and parity; it is never the source of a
+rate (the benchmark is ``benchmarks/chipbench/``).
 
 Drives the real frontend (admission, backpressure, slot recycling, and in
 router mode health supervision + checkpointless retry) with open-loop traffic:
@@ -14,8 +16,8 @@ admission-control effects, not just raw decode speed.
 
 Shared-prefix traces (``--prefix-pool N --prefix-len L``): every prompt is one
 of N pool "system prompts" of L tokens plus a short random tail — real serving
-traffic's shape, and the acceptance harness for the radix prefix KV cache
-(``--prefix-cache``). The BENCH JSON then splits TTFT into **hit vs miss**
+traffic's shape, and what the radix prefix KV cache's tests run
+(``--prefix-cache``). The JSON then splits TTFT into **hit vs miss**
 percentiles (a request is a hit when its first token came from a
 restored-prefix suffix prefill, ``handle.prefix_hit_tokens > 0``) and reports
 the measured hit-rate plus the engine-side ``prefix_cache_report``.
@@ -29,11 +31,10 @@ Time-varying offered load (``--arrival schedule:<rate@dur,...>``): a piecewise
 Poisson schedule — e.g. ``schedule:2@3,10@2,2@3`` offers 2 req/s for 3 s, then
 10 req/s for 2 s, then 2 req/s again, cycling until ``--requests`` arrivals are
 drawn. ``schedule+bursty:<...>`` composes the Markov ON/OFF modulation on top
-of the piecewise base rate. The BENCH JSON then carries per-window TTFT/TPOT
+of the piecewise base rate. The JSON then carries per-window TTFT/TPOT
 percentiles plus ``replica_seconds`` (attached replicas integrated over the
-run) — the harness the autoscale bench lane is judged with. A chaos ``surge``
-event (``surge:mult=4,at=1.0,s=2.0``) multiplies the offered rate inside its
-window on any arrival mode.
+run). A chaos ``surge`` event (``surge:mult=4,at=1.0,s=2.0``) multiplies the
+offered rate inside its window on any arrival mode.
 
 Autoscaling (``--autoscale --min-replicas N --max-replicas M``): the router
 starts at N replicas and an :class:`~.autoscale.Autoscaler` closes the
@@ -42,15 +43,12 @@ scale-down through graceful retire — migrated requests stay bit-exact and the
 run still requires ``lost == 0``). ``--slo-admission`` (+ ``--deadline-s``)
 turns on SLO-aware admission: requests whose estimated completion misses their
 deadline are shed at the front door with a load-adaptive ``retry_after`` (the
-client counts them, it does not resubmit a doomed deadline). ``--bench-autoscale``
-runs the acceptance A/B — autoscaled vs static-min vs static-max under a 5x
-load swing, plus an SLO-admission lane — and emits ``BENCH_AUTOSCALE`` JSON
-with the gates in-file.
+client counts them, it does not resubmit a doomed deadline).
 
 Chaos soak (``--replicas >= 2 --chaos "<spec>"``, grammar in
 ``inference.serving.chaos``): scheduled replica kills/stalls run against the
 router mid-load — including ``kill:replica=i,when=restore``, which lands the
-kill between a prefix-slab restore and its suffix prefill; the BENCH JSON then
+kill between a prefix-slab restore and its suffix prefill; the JSON then
 carries the no-loss accounting — ``retried`` / ``evicted`` / ``lost`` (the run
 fails unless ``lost == 0``) — and, for greedy runs, ``parity_ok``: every
 evicted-and-retried request's final output is re-checked bit-identical against
@@ -60,19 +58,14 @@ to EVERY request (the prefix-cache bit-exactness acceptance gate).
 Observability (PR 10, ``docs/OBSERVABILITY.md``): ``--trace-out FILE`` enables
 the request-scoped span tracer for the run and writes a Perfetto-loadable
 Chrome trace on exit (documented alongside ``--jsonl-metrics`` — one is the
-span stream, the other the metric stream of the same spine). ``--obs-ab`` runs
-the tracing-overhead acceptance A/B instead of a single run: the same arrival
-trace is replayed ``--obs-reps`` times per arm, arms interleaved
-(off, on, off, on, ...) over ONE engine (shared compile cache, so the A/B
-measures tracing, not compilation), and the BENCH JSON gates
-tracing-enabled TPOT within 2% of tracing-off (``BENCH_OBS_r10.json``).
+span stream, the other the metric stream of the same spine).
 
 ``--smoke`` shrinks everything (tiny model, few requests) to a seconds-long run —
 the mode the serving tests execute in-process.
 
 Output: one JSON object, ``{"metric": "serving_tokens_per_sec", "value": ...,
 "unit": "tok/s", ...}`` with the telemetry snapshot nested under ``"detail"``
-(also written to ``--out FILE`` when given, e.g. ``BENCH_PREFIX_r09.json``).
+(also written to ``--out FILE`` when given).
 """
 
 import argparse
@@ -151,20 +144,8 @@ def make_prompts(args, rng):
     n = args.requests
     sizes = draw_lengths(rng, n, args.min_prompt, args.max_prompt,
                          getattr(args, "prompt_dist", None))
-    if getattr(args, "prompt_style", None) == "repetitive":
-        # speculative-bench trace: each prompt tiles a short random unit, so
-        # its suffix recurs verbatim earlier in the stream — the regime the
-        # self-speculative n-gram proposer exists for (and the shape of
-        # structured/templated real prompts)
-        tails = []
-        for s in sizes:
-            unit = rng.integers(0, args.vocab_size,
-                                size=int(rng.integers(3, 6))).astype(np.int32)
-            reps = -(-int(s) // unit.size)
-            tails.append(np.tile(unit, reps)[:int(s)])
-    else:
-        tails = [rng.integers(0, args.vocab_size,
-                              size=int(s)).astype(np.int32) for s in sizes]
+    tails = [rng.integers(0, args.vocab_size,
+                          size=int(s)).astype(np.int32) for s in sizes]
     if not args.prefix_pool:
         return tails, [None] * n
     pool = [rng.integers(0, args.vocab_size, size=args.prefix_len
@@ -172,12 +153,6 @@ def make_prompts(args, rng):
     picks = rng.integers(0, args.prefix_pool, size=n)
     prompts = [np.concatenate([pool[int(p)], t])
                for p, t in zip(picks, tails)]
-    if getattr(args, "session_style", None) == "tenant":
-        # many-tenant shared-prefix trace (the fleet-KV-economy A/B shape):
-        # every request is its own session, so session affinity carries NO
-        # locality signal — only prefix-aware dispatch can steer a shared
-        # prefix back to the replica whose cache already holds it
-        return prompts, [f"tenant{i}" for i in range(n)]
     # session = pool id: the router's affinity then concentrates each shared
     # prefix on one replica — the locality hook the per-replica caches need
     return prompts, [f"pool{int(p)}" for p in picks]
@@ -369,9 +344,8 @@ def run_load(front, args, chaos=None, autoscaler=None, supervisor=None) -> dict:
     if autoscaler is not None:
         # idle tail: a real deployment stays up after the storm — keep the
         # control loop running (bounded) so the scale-DOWN half of the cycle
-        # is part of the run. Tail replica-seconds accrue to the autoscaled
-        # lane's bill (they are real provisioned capacity), which only makes
-        # the >=2x static-overpay gate harder to pass, never easier.
+        # is part of the run. Tail replica-seconds accrue to the run's bill
+        # (they are real provisioned capacity).
         tail0 = time.monotonic()
         while (len(front.replicas) > autoscaler.config.min_replicas
                and time.monotonic() - tail0 < 8.0):
@@ -390,15 +364,10 @@ def run_load(front, args, chaos=None, autoscaler=None, supervisor=None) -> dict:
     wall_total = time.monotonic() - t0
     snap = front.snapshot() if is_router else front.telemetry.snapshot()
     snap["wall_total_s"] = wall_total            # incl. the scale-down tail
-    # exact (non-bucketed) per-run percentiles from the raw handles: the
-    # telemetry histogram quantizes to ~8% log buckets — fine for dashboards,
-    # too coarse for the obs-overhead A/B's 2% gate
-    tpots = [h.tpot * 1e3 for h in handles.values() if h.tpot is not None]
-    ttfts = [h.ttft * 1e3 for h in handles.values() if h.ttft is not None]
     # coordinated-omission-honest latency: measured from the GENERATOR's
     # scheduled arrival, not the (possibly late) submit stamp — under
     # overload the client loop itself backs up, and submit-relative TTFT
-    # would hide exactly the queueing the autoscale bench exists to expose
+    # would hide exactly that queueing
     e2e = {i: (handles[i].first_token_at - arrivals[i]) * 1e3
            for i in handles if handles[i].first_token_at is not None}
     e2es = list(e2e.values())
@@ -406,13 +375,6 @@ def run_load(front, args, chaos=None, autoscaler=None, supervisor=None) -> dict:
                                if e2es else None)
     snap["ttft_e2e_ms_p95"] = (float(np.percentile(e2es, 95))
                                if e2es else None)
-    snap["tpot_ms_p50_exact"] = (float(np.percentile(tpots, 50))
-                                 if tpots else None)
-    snap["tpot_ms_mean_exact"] = float(np.mean(tpots)) if tpots else None
-    snap["ttft_ms_p50_exact"] = (float(np.percentile(ttfts, 50))
-                                 if ttfts else None)
-    snap["ttft_ms_p95_exact"] = (float(np.percentile(ttfts, 95))
-                                 if ttfts else None)
     snap["wall_s"] = wall
     snap["submitted"] = len(handles)
     snap["backpressure_events"] = resubmits      # client-side resubmissions
@@ -421,7 +383,7 @@ def run_load(front, args, chaos=None, autoscaler=None, supervisor=None) -> dict:
     snap["shed_retry_after_ok"] = all(v > 0 for v in shed.values())
     # replica-seconds: the autoscaler's own integration is authoritative when
     # one is attached (one quantity, one owner); the local integration covers
-    # the static lanes that have no autoscaler
+    # a run that has no autoscaler
     snap["replica_seconds"] = (autoscaler.replica_seconds
                                if autoscaler is not None else replica_seconds)
     snap["mean_replicas"] = (snap["replica_seconds"] / wall_total
@@ -440,8 +402,8 @@ def run_load(front, args, chaos=None, autoscaler=None, supervisor=None) -> dict:
     if supervisor is not None:
         snap["hosts"] = supervisor.report()
     if any(w is not None for w in widx):
-        # per-schedule-window percentiles: the signal the autoscale bench is
-        # judged on (a window's TTFT under surge vs the steady windows)
+        # per-schedule-window percentiles (a window's TTFT under surge vs
+        # the steady windows)
         schedule = args.schedule_windows
         snap["windows"] = []
         for w, (rate, dur) in enumerate(schedule):
@@ -546,115 +508,71 @@ def host_config(args):
                                       if args.prefix_cache else None),
                       prefix_tier_mb=(args.prefix_tier_mb
                                       if args.prefix_cache
-                                      and getattr(args, "prefix_tier_mb", 0.0)
-                                      else None),
+                                      and args.prefix_tier_mb else None),
                       kv_page_size=args.kv_page_size,
                       chunk_deadline_s=args.chunk_deadline)
 
 
-def spawn_hosts(args, n, wait=True, env=None, transport=None):
-    """N subprocess replica hosts (spawns overlap; optionally block until
-    every versioned hello lands). ``env`` overlays the child environment —
-    the hook the hosts bench uses to pace children into the device-bound
-    regime via the ``DS_TPU_FAULT_SPEC`` contract. ``transport`` overrides
-    ``--host-transport``: ``"socket"`` spawns children that carry protocol
-    v1 over the CRC-framed TCP transport (serving.net) instead of the
-    stdio pipe."""
-    import dataclasses
+def spawn_hosts(args, n):
+    """N subprocess replica hosts (spawns overlap; blocks until every
+    versioned hello lands). ``--host-transport socket`` spawns children that
+    carry protocol v1 over the CRC-framed TCP transport (serving.net) instead
+    of the stdio pipe."""
     from deepspeed_tpu.inference.serving import (HostedReplica,
                                                  SocketHostedReplica)
-    cfg = host_config(args)
-    if env:
-        cfg = dataclasses.replace(cfg, env=dict(env))
-    sock = (transport or getattr(args, "host_transport",
-                                 "stdio")) == "socket"
-    cls = SocketHostedReplica if sock else HostedReplica
-    hosts = [cls(cfg) for _ in range(n)]
-    if wait:
-        for h in hosts:
-            h.wait_ready()
+    cls = (SocketHostedReplica if args.host_transport == "socket"
+           else HostedReplica)
+    hosts = [cls(host_config(args)) for _ in range(n)]
+    for h in hosts:
+        h.wait_ready()
     return hosts
 
 
-def close_hosts(front_or_hosts):
-    """Stop every hosted replica's child via the escalation ladder (accepts a
-    Router or a bare host list; a single-scheduler front is a no-op)."""
-    replicas = getattr(front_or_hosts, "replicas", None)
-    if replicas is None:
-        replicas = (front_or_hosts
-                    if isinstance(front_or_hosts, (list, tuple)) else [])
-    for r in replicas:
+def close_hosts(front):
+    """Stop every hosted replica's child via the escalation ladder (a
+    single-scheduler front is a no-op)."""
+    for r in getattr(front, "replicas", ()):
         if getattr(r, "is_hosted", False):
             r.close()
 
 
-def _build_router(args, serving_cfg, monitor=None, n_static=None, slo=None,
-                  shared_engine=None, engine_pool=None, host_pool=None):
-    """Router (+ optional Autoscaler/ReplicaSupervisor) for a loadgen lane.
-    ``n_static`` overrides the replica count (the bench's static comparison
-    lanes); with ``--autoscale`` and no override, the router starts at
-    ``--min-replicas`` and the autoscaler may grow it to ``--max-replicas``
-    through the engine factory (weights shared with replica 0 — bit-identical
-    replicas). ``engine_pool`` supplies pre-built (warmed) engines: lanes
-    draw their replicas from it and the factory hands out currently-unattached
-    pool engines — the bench's stand-in for a fleet whose images are warm, so
-    the A/B measures the control loop, not XLA compiles the serial in-process
-    pump would otherwise absorb mid-surge. With ``--host-replicas`` (or a
-    ``host_pool`` of pre-spawned ready hosts — the warm-fleet stand-in for
-    child processes, whose boot is jax import + XLA warm) the members are
+def _build_router(args, serving_cfg, monitor):
+    """Router (+ optional Autoscaler/ReplicaSupervisor) for a loadgen run.
+    With ``--autoscale`` the router starts at ``--min-replicas`` and the
+    autoscaler may grow it to ``--max-replicas`` through the engine factory
+    (weights shared with replica 0 — bit-identical replicas). With
+    ``--host-replicas`` (or ``--replica-endpoint``) the members are
     subprocess :class:`HostedReplica`\\ s under a :class:`ReplicaSupervisor`,
     and scale-ups attach hosts instead of engines."""
     from deepspeed_tpu.inference.serving import (Autoscaler, AutoscaleConfig,
                                                  HostedReplica,
                                                  ReplicaSupervisor, Router,
                                                  RouterConfig,
+                                                 SocketHostedReplica,
                                                  SupervisorConfig)
-    if serving_cfg is None:     # hosted lanes: the child carries its own
-        from deepspeed_tpu.inference.serving import ServingConfig
-        serving_cfg = ServingConfig(max_queue=args.max_queue)
-    endpoints = getattr(args, "replica_endpoint", None)
-    hosted = bool(host_pool) or getattr(args, "host_replicas", False) \
-        or bool(endpoints)
-    autoscaled = n_static is None and args.autoscale
+    endpoints = args.replica_endpoint
+    hosted = args.host_replicas or bool(endpoints)
     # with --autoscale an explicit --replicas sets the STARTING size (bounded
     # below by --min-replicas) rather than being silently discarded
-    n0 = (n_static if n_static is not None
-          else (max(args.min_replicas, args.replicas) if args.autoscale
-                else args.replicas))
+    n0 = (max(args.min_replicas, args.replicas) if args.autoscale
+          else args.replicas)
     if hosted:
-        members = list(host_pool[:n0]) if host_pool else []
-        if not members and endpoints:
-            # adopt running socket children: each endpoint is one member,
-            # dialed (not spawned) — geometry flags must match the remote's
-            from deepspeed_tpu.inference.serving import SocketHostedReplica
-            members = [SocketHostedReplica(host_config(args), endpoint=ep)
-                       for ep in endpoints[:n0]]
-            for m in members:
-                m.wait_ready()
+        # adopt running socket children: each endpoint is one member,
+        # dialed (not spawned) — geometry flags must match the remote's
+        members = [SocketHostedReplica(host_config(args), endpoint=ep)
+                   for ep in (endpoints or [])[:n0]]
+        for m in members:
+            m.wait_ready()
         if len(members) < n0:
-            # top-ups clone the pool's child environment (e.g. the hosts
-            # bench's pacing overlay) — a differently-configured sibling
-            # would skew every per-replica comparison
-            members += spawn_hosts(
-                args, n0 - len(members),
-                env=(members[0].config.env
-                     if members and not endpoints else None))
-        first = None
-    elif engine_pool:
-        first = engine_pool[0]
-        members = list(engine_pool[:n0])
-        while len(members) < n0:
-            members.append(build_engine(args, params=first.params))
+            members += spawn_hosts(args, n0 - len(members))
     else:
-        first = (shared_engine if shared_engine is not None
-                 else build_engine(args))
+        first = build_engine(args)
         members = [first] + [build_engine(args, params=first.params)
                              for _ in range(n0 - 1)]
     rcfg = RouterConfig(
         serving=serving_cfg, max_queue=args.max_queue,
-        slo_admission=bool(args.slo_admission if slo is None else slo),
-        prefix_aware_routing=bool(getattr(args, "prefix_aware_routing",
-                                          False)))
+        slo_admission=args.slo_admission,
+        prefix_aware_routing=args.prefix_aware_routing)
     if args.smoke:
         if hosted:
             # heartbeats ride a 50ms child stream: a 0.15s flatline bound
@@ -673,7 +591,7 @@ def _build_router(args, serving_cfg, monitor=None, n_static=None, slo=None,
             scfg.backoff_base_s = min(scfg.backoff_base_s, 0.3)
         supervisor = ReplicaSupervisor(front, scfg)
     autoscaler = None
-    if autoscaled:
+    if args.autoscale:
         acfg = AutoscaleConfig(min_replicas=args.min_replicas,
                                max_replicas=args.max_replicas,
                                ttft_p95_slo_ms=args.ttft_slo_ms)
@@ -686,329 +604,20 @@ def _build_router(args, serving_cfg, monitor=None, n_static=None, slo=None,
             acfg.occupancy_low = 0.45   # slots=1 pools: per-replica share of
             #   a 0.8x-capacity trough spread over 2-3 replicas
         if hosted:
-            spare = list(host_pool or [])
+            # grow-by-spawn always spawns locally, matching the fleet's
+            # transport (an endpoint fleet grows with a local socket
+            # child — nobody listens at a new address)
+            grown = (SocketHostedReplica
+                     if args.host_transport == "socket" or endpoints
+                     else HostedReplica)
 
             def factory():
-                attached = {id(r) for r in front.replicas}
-                for h in spare:
-                    if id(h) not in attached and h.alive:
-                        return h           # warm fleet: pre-spawned + ready
-                # cold boot inherits the fleet's config (incl. any pacing
-                # env): an unpaced sibling in a paced fleet would be
-                # host-CPU-bound and skew the latency gate
-                cfg = (spare[0].config if spare
-                       else (front.replicas[0].config
-                             if front.replicas
-                             and getattr(front.replicas[0], "is_hosted",
-                                         False)
-                             else host_config(args)))
-                if getattr(args, "host_transport", "stdio") == "socket" \
-                        or endpoints:
-                    # grow-by-spawn always spawns locally, matching the
-                    # fleet's transport (an endpoint fleet grows with a
-                    # local socket child — nobody listens at a new address)
-                    from deepspeed_tpu.inference.serving import \
-                        SocketHostedReplica
-                    return SocketHostedReplica(cfg)
-                return HostedReplica(cfg)
-        elif engine_pool:
-            spare = list(engine_pool)
-
-            def factory():
-                attached = {id(r.engine) for r in front.replicas}
-                for e in spare:
-                    if id(e) not in attached:
-                        return e
-                return build_engine(args, params=first.params)
+                return grown(host_config(args))
         else:
             def factory():
                 return build_engine(args, params=first.params)
         autoscaler = Autoscaler(front, factory, acfg)
     return front, autoscaler, supervisor
-
-
-def _run_autoscale_bench(args, serving_cfg, monitor) -> int:
-    """Elastic-control-plane acceptance A/B (``BENCH_AUTOSCALE`` JSON).
-
-    The same offered-load swing (a piecewise schedule whose peak is 5x the
-    trough unless ``--arrival schedule:...`` overrides it) is replayed over:
-
-    - ``static_min`` — fixed ``--min-replicas``: expected to BREACH the TTFT
-      gate under the surge window (under-provisioned);
-    - ``static_max`` — fixed ``--max-replicas``: holds latency but pays for
-      peak capacity the whole run (>= 2x the autoscaled replica-seconds);
-    - ``autoscaled`` — starts at min, scales with load: must hold TTFT p95
-      within the gate (2x the static_max p95 — the well-provisioned latency
-      with noise headroom) at well under static_max's replica-seconds, with
-      ``lost == 0`` across every scale-down and bit-exact parity on every
-      migrated request;
-    - ``slo_fifo`` / ``slo_admission`` — ``static_min`` capacity with
-      per-request deadlines, FIFO vs SLO-aware admission: FIFO expires
-      requests late (post-admission deadline misses), SLO admission sheds the
-      infeasible ones at the front door with a load-adaptive ``retry_after``
-      and cuts late expiries to ~0.
-    """
-    import copy
-    import dataclasses
-    if args.smoke:
-        # one slot per replica + long generations pin per-replica capacity
-        # low enough (tens of ms per request) that the 5x swing genuinely
-        # overloads static-min on a warm CPU host — the base smoke's 2-6
-        # token requests serve in single-digit ms and no sane swing binds
-        args.slots, args.min_new, args.max_new = 1, 24, 40
-        args.max_seq_len = max(args.max_seq_len, 96)
-        serving_cfg = dataclasses.replace(serving_cfg, slots=1,
-                                          max_seq_len=args.max_seq_len)
-        args.requests = max(args.requests, 40)
-    # a deep router queue: overload must show up as queue WAIT (what TTFT and
-    # the deadline lanes measure), not as reject-and-resubmit bounce that
-    # hides the latency in client backoff
-    args.max_queue = max(args.max_queue, 64)
-    # one warmed engine pool shared by every lane: each engine pays its
-    # prefill-bucket + chunk compiles BEFORE t0 (the stand-in for a fleet
-    # with warm images — mid-surge XLA compiles inside the serial in-process
-    # pump would otherwise dominate every latency number the A/B gates on)
-    from deepspeed_tpu.inference.serving import ContinuousBatchingScheduler
-    pool = [build_engine(args)]
-    pool += [build_engine(args, params=pool[0].params)
-             for _ in range(max(args.max_replicas, args.min_replicas) - 1)]
-    rng_w = np.random.default_rng(12345)
-    mean_new = int(0.5 * (args.min_new + args.max_new))
-    print(f"[bench-autoscale] warming {len(pool)} engine(s)...",
-          file=sys.stderr)
-    for eng in pool:
-        sched = ContinuousBatchingScheduler(eng, serving_cfg)
-        for _ in range(2):
-            sched.submit(rng_w.integers(0, args.vocab_size,
-                                        size=args.max_prompt
-                                        ).astype(np.int32),
-                         max_new_tokens=mean_new)
-        while sched.busy:
-            sched.step()
-    cap = None
-    req_floor = args.requests          # a user-supplied budget is a floor for
-    #   every (re-)offer, never silently shrunk
-    if args.schedule_windows is None:
-        # self-calibrating swing: measure one warm replica's closed-loop
-        # service rate, then offer 0.5x capacity in the troughs and 2.5x in
-        # the surge (a 5x swing straddling capacity) — fixed rates would be
-        # vacuous on a fast host and unserveable on a slow one
-        K = 16                         # saturating burst: true peak rate, not
-        rates = []                     # ramp-diluted; best-of-2 because one
-        for _ in range(2):             # transient machine pause under-reads
-            sched = ContinuousBatchingScheduler(
-                pool[0], dataclasses.replace(serving_cfg, max_queue=64))
-            t_cal = time.monotonic()
-            cal = [sched.submit(rng_w.integers(0, args.vocab_size,
-                                               size=args.max_prompt
-                                               ).astype(np.int32),
-                                max_new_tokens=mean_new) for _ in range(K)]
-            while sched.busy:
-                sched.step()
-            if not all(h.state.value == "finished" for h in cal):
-                raise RuntimeError("calibration requests did not finish")
-            rates.append(K / (time.monotonic() - t_cal))
-        cap = max(rates)
-        # a 5x swing straddling capacity: trough at 0.4x (one replica is
-        # genuinely enough — a hotter trough legitimately NEEDS two replicas
-        # and the >=2x provisioning-saving story collapses), surge at 2x
-        # (reliably past one replica's rate, inside max_replicas'); then a
-        # LONG trough — the steady-state the autoscaled lane amortizes its
-        # peak provisioning over
-        lo, hi = round(0.4 * cap, 2), round(2.0 * cap, 2)
-        args.arrival = f"schedule:{lo}@2,{hi}@1,{lo}@10"
-        args.schedule_windows = parse_schedule(args.arrival.split(":", 1)[1])
-        # the request budget must SPAN the schedule: truncating the final
-        # trough shrinks the steady-state the mean-replicas gate divides by
-        args.requests = min(520, max(req_floor, int(12 * lo + hi)))
-        print(f"[bench-autoscale] calibrated capacity ~{cap:.1f} req/s "
-              f"per replica; arrival {args.arrival}, "
-              f"{args.requests} requests", file=sys.stderr)
-
-    def lane(name, n_static=None, slo=False, deadline=None, autoscale=None,
-             chaos=None):
-        a = copy.copy(args)
-        a.autoscale = args.autoscale if autoscale is None else autoscale
-        a.deadline_s = deadline
-        front, autoscaler, supervisor = _build_router(
-            a, serving_cfg, monitor, n_static=n_static, slo=slo,
-            engine_pool=pool)
-        print(f"[bench-autoscale] lane {name}...", file=sys.stderr)
-        snap = run_load(front, a, chaos=chaos, autoscaler=autoscaler,
-                        supervisor=supervisor)
-        snap["lane"] = name
-        return snap
-
-    args.autoscale = True          # the autoscaled lanes need the scaler
-    from deepspeed_tpu.inference.serving import ChaosSchedule, parse_chaos
-
-    def _attempt():
-        static_min = lane("static_min", n_static=args.min_replicas,
-                          autoscale=False)
-        static_max = lane("static_max", n_static=args.max_replicas,
-                          autoscale=False)
-        autoscaled = lane("autoscaled")
-        # soak lane: same trace again, but the first scaled-up replica is
-        # killed the moment it goes RETIRING (mid-scale-down) — the
-        # drain/hand-off parity contract must hold even when the drained
-        # replica dies under it. A separate lane on purpose: the kill +
-        # eviction churn would handicap the clean lane's latency numbers the
-        # static comparison is gated on.
-        kill_chaos = ChaosSchedule(
-            parse_chaos(f"kill:replica={args.min_replicas},when=draining"))
-        chaos_lane = lane("autoscaled_chaos", chaos=kill_chaos)
-        # deadline that binds under the surge but clears unloaded service: 3x
-        # the measured per-request service time (the calibrated capacity's
-        # inverse); an overall-p50-derived deadline would either fold surge
-        # queueing into "normal" or sit below real service and miss at idle
-        if args.deadline_s is not None:
-            deadline = float(args.deadline_s)
-        elif cap is not None:
-            deadline = 3.0 / cap
-        else:
-            w0 = (static_min.get("windows") or [{}])[0]
-            ttft_ms = (w0.get("ttft_ms_p50")
-                       or static_min["ttft_ms_p50_exact"] or 1e3)
-            tpot_ms = (w0.get("tpot_ms_p50")
-                       or static_min["tpot_ms_p50_exact"] or 50.0)
-            mean_new = 0.5 * (args.min_new + args.max_new)
-            deadline = (ttft_ms + mean_new * tpot_ms) / 1e3 * 2.5
-        slo_fifo = lane("slo_fifo", n_static=args.min_replicas, slo=False,
-                        deadline=deadline, autoscale=False)
-        slo_adm = lane("slo_admission", n_static=args.min_replicas, slo=True,
-                       deadline=deadline, autoscale=False)
-        return (static_min, static_max, autoscaled, chaos_lane, kill_chaos,
-                deadline, slo_fifo, slo_adm)
-
-    lanes = _attempt()
-    if cap is not None:
-        # this machine's throughput can swing several-x between runs: when
-        # the surge turned out vacuous (nothing breached, nothing missed a
-        # deadline), the OFFERED trace measured the calibration drift, not
-        # the control plane — re-offer once, 1.5x hotter
-        asr0 = lanes[2].get("autoscale") or {}
-        fifo0 = lanes[6].get("deadline_missed", lanes[6].get("expired", 0))
-        if asr0.get("scale_ups", 0) == 0 or fifo0 == 0:
-            lo2, hi2 = round(0.6 * cap, 2), round(3.0 * cap, 2)
-            args.arrival = f"schedule:{lo2}@2,{hi2}@1,{lo2}@10"
-            args.schedule_windows = parse_schedule(
-                args.arrival.split(":", 1)[1])
-            args.requests = min(520, max(req_floor, int(12 * lo2 + hi2)))
-            print(f"[bench-autoscale] vacuous surge (ups="
-                  f"{asr0.get('scale_ups', 0)}, fifo_misses={fifo0}); "
-                  f"re-offering at {args.arrival}", file=sys.stderr)
-            lanes = _attempt()
-    (static_min, static_max, autoscaled, chaos_lane, kill_chaos, deadline,
-     slo_fifo, slo_adm) = lanes
-
-    def p95(s):
-        # coordinated-omission-honest tail (scheduled-arrival-relative)
-        return s.get("ttft_e2e_ms_p95")
-
-    # the latency gate: the elastic lane must land inside the STATIC ENVELOPE
-    # — no worse than the under-provisioned tail, near the well-provisioned
-    # tail (2.5x noise headroom) when CPU scheduler pauses don't dominate —
-    # plus the control loop's DOCUMENTED reaction window (detection +
-    # up-cooldown + retire grace): an elastic deployment can never beat an
-    # always-provisioned one inside the window it is still allowed to be
-    # scaling in. The STRONG separation claim (autoscaled far below
-    # static_min) is declared unmeasurable in this harness (harness_note).
-    transient_ms = 1e3 * (autoscaled.get("autoscale") or {}).get(
-        "transient_s", 0.0)
-    gate_ms = (max(2.5 * p95(static_max), p95(static_min)) + transient_ms
-               if p95(static_max) and p95(static_min) else None)
-    mr_auto = autoscaled.get("mean_replicas") or 0.0
-
-    def static_ok(s):
-        # the acceptance contract: a static deployment either breaches the
-        # latency gate or provisions >= 2x the autoscaled lane's capacity
-        # (mean attached replicas over its run — replica-seconds normalized
-        # to a common horizon, since lane walls differ)
-        breaches = gate_ms is not None and (p95(s) or 0.0) > gate_ms
-        overpays = mr_auto > 0 and \
-            (s.get("mean_replicas") or 0.0) >= 2.0 * mr_auto
-        return breaches or overpays
-
-    asr = autoscaled.get("autoscale") or {}
-    gates = {
-        # NOTE (harness limit, same class as the CPU-host caveats on
-        # BENCH_WQ/BENCH_PREFIX): replicas here are pumped SERIALLY in one
-        # process on one host, so aggregate capacity does not scale with
-        # replica count and the static-MIN lane cannot be made to breach a
-        # latency gate the autoscaled lane holds — that half of the latency
-        # claim needs parallel replica hosts (filed in ROADMAP). What this
-        # artifact does gate: the control loop scales both ways on live
-        # signals, every scale-down migrates bit-exactly with lost == 0, the
-        # peak-sized static deployment provisions >= 2x the autoscaled
-        # capacity-seconds, and SLO admission sheds infeasible deadlines at
-        # the front door instead of expiring them late.
-        "harness_note": "serial in-process pump: replica count does not add "
-                        "host parallelism; static_min latency lane is "
-                        "informational",
-        "ttft_gate_ms": gate_ms,
-        "autoscaled_ttft_p95_ms": p95(autoscaled),
-        "autoscaled_holds_gate": bool(
-            gate_ms is not None and p95(autoscaled) is not None
-            and p95(autoscaled) <= gate_ms),
-        "static_min_ttft_p95_ms": p95(static_min),
-        "static_max_ttft_p95_ms": p95(static_max),
-        "replica_seconds": {"autoscaled": autoscaled["replica_seconds"],
-                            "static_min": static_min["replica_seconds"],
-                            "static_max": static_max["replica_seconds"]},
-        "mean_replicas": {"autoscaled": mr_auto,
-                          "static_min": static_min.get("mean_replicas"),
-                          "static_max": static_max.get("mean_replicas")},
-        "static_min_breaches_or_overpays": static_ok(static_min),
-        "static_max_breaches_or_overpays": static_ok(static_max),
-        "scale_ups": asr.get("scale_ups", 0),
-        "scale_downs": asr.get("scale_downs", 0),
-        "scaled_both_ways": (asr.get("scale_ups", 0) >= 1
-                             and asr.get("scale_downs", 0) >= 1),
-        "autoscaled_lost": autoscaled["lost"],
-        "chaos_lane_lost": chaos_lane["lost"],
-        "lost_zero_across_scale_downs": (autoscaled["lost"] == 0
-                                         and chaos_lane["lost"] == 0),
-        "autoscaled_parity_ok": (autoscaled.get("parity_ok", True)
-                                 and chaos_lane.get("parity_ok", True)),
-        "scale_down_kill_fired": kill_chaos.exhausted,
-        "deadline_s": deadline,
-        "fifo_deadline_misses": slo_fifo.get("deadline_missed",
-                                             slo_fifo.get("expired", 0)),
-        "slo_deadline_misses": slo_adm.get("deadline_missed",
-                                           slo_adm.get("expired", 0)),
-        "slo_shed": slo_adm.get("shed", 0),
-        "slo_shed_client": slo_adm.get("shed_client", 0),
-        "slo_shed_carries_retry_after": slo_adm.get("shed_retry_after_ok",
-                                                    False),
-        # ~0: at least a 5x cut vs FIFO (allowing the handful the estimator's
-        # warm-up lag admits), and always strictly fewer than FIFO
-        "slo_misses_near_zero": (
-            slo_adm.get("deadline_missed", 0) <= max(
-                5, slo_fifo.get("deadline_missed", 0) // 5)
-            and slo_adm.get("deadline_missed", 0)
-            < slo_fifo.get("deadline_missed", 1)),
-        "fifo_misses_nonzero": slo_fifo.get("deadline_missed", 0) > 0,
-        "slo_sheds_at_admission": slo_adm.get("shed_client", 0) > 0,
-    }
-    ok = all(bool(gates[k]) for k in
-             ("autoscaled_holds_gate", "static_max_breaches_or_overpays",
-              "scaled_both_ways", "lost_zero_across_scale_downs",
-              "autoscaled_parity_ok", "scale_down_kill_fired",
-              "fifo_misses_nonzero", "slo_misses_near_zero",
-              "slo_sheds_at_admission", "slo_shed_carries_retry_after"))
-    out = {"metric": "autoscale_ttft_p95_ms", "value": p95(autoscaled),
-           "unit": "ms", "smoke": bool(args.smoke),
-           "arrival": args.arrival, "autoscale_gates": gates,
-           "gates_ok": ok,
-           "detail": {"static_min": static_min, "static_max": static_max,
-                      "autoscaled": autoscaled,
-                      "autoscaled_chaos": chaos_lane, "slo_fifo": slo_fifo,
-                      "slo_admission": slo_adm}}
-    if args.out:
-        with open(args.out, "w") as f:
-            json.dump(out, f, indent=1)
-    print(json.dumps(out))
-    return 0 if ok else 1
 
 
 def main(argv=None) -> int:
@@ -1055,8 +664,7 @@ def main(argv=None) -> int:
                     help="re-check EVERY request bit-identical vs cache-off "
                          "per-request generate (greedy acceptance gate)")
     ap.add_argument("--out", default=None,
-                    help="also write the BENCH JSON to this file "
-                         "(e.g. BENCH_PREFIX_r09.json)")
+                    help="also write the JSON to this file")
     ap.add_argument("--slots", type=int, default=4)
     ap.add_argument("--chunk-size", type=int, default=8)
     ap.add_argument("--kv-page-size", type=int, default=None,
@@ -1075,19 +683,6 @@ def main(argv=None) -> int:
                     help="mixed-length generation budgets, same grammar as "
                          "--prompt-dist; default uniform "
                          "[--min-new, --max-new]")
-    ap.add_argument("--bench-spec", action="store_true",
-                    help="speculative-decoding acceptance A/B: spec-on vs "
-                         "spec-off greedy lanes on a repetitive-suffix trace "
-                         "(every request parity-checked) + a chaos kill lane "
-                         "with speculation on; emits BENCH_SPEC JSON gating "
-                         "passes-per-token and n-gram acceptance")
-    ap.add_argument("--bench-kv-economy", action="store_true",
-                    help="fleet KV-economy acceptance A/B: a many-tenant "
-                         "shared-prefix trace over a 4-replica fleet, "
-                         "affinity-only vs prefix-aware routing (both "
-                         "tiered), a host-rung promote TTFT lane, and a "
-                         "mid-promote chaos kill lane; emits BENCH_KVECON "
-                         "JSON with gates")
     ap.add_argument("--vocab-size", type=int, default=512)
     ap.add_argument("--max-seq-len", type=int, default=128)
     ap.add_argument("--n-embd", type=int, default=128)
@@ -1119,18 +714,6 @@ def main(argv=None) -> int:
                     help="adopt an already-running socket replica child "
                          "(--serve-socket --listen) at this address; "
                          "repeatable — each endpoint is one router member")
-    ap.add_argument("--bench-net", action="store_true",
-                    help="acceptance A/B for the socket replica transport: "
-                         "stdio-vs-socket throughput at equal replica count, "
-                         "a partition+delay+SIGKILL chaos soak over a "
-                         "3-replica socket fleet, and a delay-jitter "
-                         "no-false-kill lane; emits BENCH_NET JSON")
-    ap.add_argument("--bench-hosts", action="store_true",
-                    help="acceptance A/B for process-parallel replica hosts: "
-                         "concurrency overlap via the span tracer, a real-"
-                         "SIGKILL + supervised-respawn soak, and the "
-                         "autoscaled-vs-static latency A/B with real "
-                         "per-replica compute; emits BENCH_HOSTS JSON")
     ap.add_argument("--autoscale", action="store_true",
                     help="attach the metrics-driven Autoscaler: start at "
                          "--min-replicas, scale within "
@@ -1145,10 +728,6 @@ def main(argv=None) -> int:
                          "completion misses their deadline, at admission")
     ap.add_argument("--deadline-s", type=float, default=None,
                     help="per-request deadline (seconds from submission)")
-    ap.add_argument("--bench-autoscale", action="store_true",
-                    help="acceptance A/B: autoscaled vs static-min vs "
-                         "static-max under a load swing + an SLO-admission "
-                         "lane; emits BENCH_AUTOSCALE JSON with gates")
     ap.add_argument("--chaos", default=None,
                     help="chaos spec (see inference.serving.chaos), e.g. "
                          "'kill:replica=1,when=busy;"
@@ -1166,15 +745,8 @@ def main(argv=None) -> int:
                     help="enable the tail-latency flight recorder + anomaly "
                          "detector (implies tracing) and write the Perfetto-"
                          "loadable flight bundle here at the end of the run; "
-                         "the BENCH detail gains the per-request attribution "
+                         "the JSON's detail gains the per-request attribution "
                          "breakdown (phase shares at p50 vs p99)")
-    ap.add_argument("--obs-ab", action="store_true",
-                    help="observability-overhead A/B: interleaved "
-                         "off/tracing/flight reps over one engine; BENCH "
-                         "JSON gates TPOT overhead < 2%% for tracing AND for "
-                         "tracing+attribution+flight+anomaly")
-    ap.add_argument("--obs-reps", type=int, default=3,
-                    help="repetitions per arm of the --obs-ab run")
     ap.add_argument("--smoke", action="store_true",
                     help="seconds-long tiny-model run (used by the test suite)")
     args = ap.parse_args(argv)
@@ -1271,9 +843,6 @@ def main(argv=None) -> int:
         # adopted router member); an explicit larger --replicas tops up with
         # locally-spawned socket children
         args.replicas = max(args.replicas, len(args.replica_endpoint))
-    if (args.host_replicas or args.replica_endpoint) and args.obs_ab:
-        ap.error("--obs-ab measures the single-scheduler hot "
-                 "path; drop --host-replicas/--replica-endpoint")
     if args.autoscale and args.max_replicas < args.min_replicas:
         ap.error("--max-replicas must be >= --min-replicas")
     if args.autoscale and args.replicas > args.max_replicas:
@@ -1292,49 +861,6 @@ def main(argv=None) -> int:
         monitor = MonitorMaster(MonitorConfig(jsonl_monitor={
             "enabled": True, "output_path": args.jsonl_metrics,
             "job_name": "loadgen"}))
-    if (args.bench_autoscale or args.bench_hosts
-            or args.bench_net or args.bench_spec or args.bench_kv_economy) \
-            and (args.flight_out or args.trace_out):
-        # these lanes dispatch before the tracer/flight wiring: refusing
-        # beats silently writing no bundle the caller asked for
-        ap.error("--bench-autoscale/--bench-hosts/--bench-net/"
-                 "--bench-spec/--bench-kv-economy manage their own runs; "
-                 "--trace-out/--flight-out are single-run options")
-    if args.bench_net:
-        # the bench pins its own geometry + fleets (stdio AND socket)
-        if args.bench_autoscale or args.obs_ab or args.bench_hosts:
-            ap.error("--bench-net is its own acceptance run; drop the "
-                     "other bench flags")
-        return _run_net_bench(args, monitor)
-    if args.bench_hosts:
-        # the bench pins its own geometry + arrival shape (self-calibrated)
-        if args.bench_autoscale or args.obs_ab:
-            ap.error("--bench-hosts is its own acceptance run; drop the "
-                     "other bench flags")
-        return _run_hosts_bench(args, monitor)
-    if args.bench_spec:
-        # dispatched before serving_cfg: the bench pins its own geometry,
-        # prompt trace (repetitive-suffix), and per-lane serving configs
-        if args.bench_autoscale or args.obs_ab:
-            ap.error("--bench-spec is its own acceptance run; drop the "
-                     "other bench flags")
-        if args.replicas > 1 or args.chaos or args.autoscale:
-            ap.error("--bench-spec manages its own lanes (incl. the chaos "
-                     "one); drop --replicas/--chaos/--autoscale")
-        return _run_spec_bench(args, monitor)
-    if args.bench_kv_economy:
-        # dispatched before serving_cfg: the bench pins its own geometry,
-        # many-tenant trace, per-lane cache budgets and router configs
-        if args.bench_autoscale or args.obs_ab \
-                or args.bench_net or args.bench_hosts or args.bench_spec:
-            ap.error("--bench-kv-economy is its own acceptance run; drop "
-                     "the other bench flags")
-        if args.replicas > 1 or args.chaos or args.autoscale \
-                or args.host_replicas or args.replica_endpoint:
-            ap.error("--bench-kv-economy manages its own fleets (incl. the "
-                     "chaos one); drop --replicas/--chaos/--autoscale/"
-                     "--host-replicas/--replica-endpoint")
-        return _run_kvecon_bench(args, monitor)
     prefix_cfg = None
     if args.prefix_cache:
         from deepspeed_tpu.inference.serving import PrefixCacheConfig
@@ -1348,16 +874,6 @@ def main(argv=None) -> int:
         slots=args.slots, chunk_size=args.chunk_size, max_queue=args.max_queue,
         max_seq_len=args.max_seq_len, chunk_deadline_s=args.chunk_deadline,
         prefix_cache=prefix_cfg, kv_page_size=args.kv_page_size)
-    if args.obs_ab:
-        if args.replicas > 1 or args.chaos:
-            ap.error("--obs-ab measures the single-scheduler hot path; "
-                     "drop --replicas/--chaos")
-        if args.trace_out or args.flight_out:
-            ap.error("--obs-ab manages tracing/flight itself (per-arm); "
-                     "--trace-out/--flight-out are single-run options")
-        return _run_obs_ab(args, serving_cfg)
-    if args.bench_autoscale:
-        return _run_autoscale_bench(args, serving_cfg, monitor)
     from deepspeed_tpu.observability.trace import get_tracer
     tracer = None
     if args.trace_out or args.flight_out:
@@ -1407,8 +923,7 @@ def main(argv=None) -> int:
         and detail.get("parity_ok", True) \
         and detail.get("chaos_exhausted", True)
     if args.prefix_pool and args.prefix_cache:
-        # the prefix-cache acceptance gates ride the JSON so the bench
-        # artifact is self-certifying
+        # the prefix-cache acceptance gates ride the JSON
         trace = detail["prefix_trace"]
         hit_p50, miss_p50 = (trace["ttft_hit_ms_p50"],
                              trace["ttft_miss_ms_p50"])
@@ -1441,1233 +956,6 @@ def main(argv=None) -> int:
             json.dump(out, f, indent=1)
     print(json.dumps(out))
     return 0 if ok else 1
-
-
-def _merge_intervals(iv):
-    """Sorted union of (t0, t1) intervals."""
-    out = []
-    for t0, t1 in sorted(iv):
-        if out and t0 <= out[-1][1]:
-            out[-1] = (out[-1][0], max(out[-1][1], t1))
-        else:
-            out.append((t0, t1))
-    return out
-
-
-def _overlap_seconds(lanes):
-    """Wall-clock seconds during which >= 2 lanes (each a merged interval
-    list, µs timestamps) are simultaneously busy."""
-    edges = []
-    for iv in lanes:
-        for t0, t1 in iv:
-            edges.append((t0, 1))
-            edges.append((t1, -1))
-    edges.sort()
-    depth, last_t, overlap = 0, None, 0.0
-    for t, d in edges:
-        if depth >= 2 and last_t is not None:
-            overlap += t - last_t
-        depth += d
-        last_t = t
-    return overlap / 1e6
-
-
-def _run_net_bench(args, monitor) -> int:
-    """Socket-transport acceptance A/B (``BENCH_NET`` JSON).
-
-    Four lanes over REAL child processes, the socket lanes carrying protocol
-    v1 in CRC-framed TCP (``serving.net``) instead of the stdio pipe:
-
-    - **throughput A/B** — the same saturating closed-loop burst over a
-      2-host stdio fleet and a 2-host socket fleet (identical geometry,
-      equal replica count): the gate is socket throughput >= 0.9x stdio —
-      framing + CRC + the io thread must not tax the serving hot path —
-      with the coordinated-omission-honest TTFT-e2e p95 of both lanes
-      reported beside it;
-    - **soak** — 3 socket hosts under traffic with a real mid-decode
-      ``SIGKILL`` (respawn + fresh dial), a ``net:partition`` long enough
-      to trip LIVE→SUSPECT→DEAD (the router evicts and retries elsewhere;
-      the link itself recovers when the fault expires), and a ``net:delay``
-      jitter window: ``lost == 0``, every retried request bit-identical to
-      an unkilled reference ``generate``, every chaos event fires, the
-      supervisor respawns the killed child, and both disturbed replicas
-      return LIVE;
-    - **sever-resume probe** — after the storm, cut one LIVE replica's
-      connection outright: the reconnect machine must redial and RESUME the
-      same child session (token match, no respawn), and the fleet must
-      serve through it again;
-    - **delay no-false-kill** — a 2-host socket fleet under a ``net:delay``
-      jitter window below the SUSPECT threshold: nothing may die — zero
-      evictions, zero restarts, every replica LIVE at the end.
-
-    ``--smoke`` trims request counts only (every lane runs in both forms);
-    the committed artifact is a full run.
-    """
-    import copy
-    from deepspeed_tpu.inference.serving import (ChaosSchedule,
-                                                 QueueFullError, ReplicaState,
-                                                 parse_chaos)
-    args = copy.copy(args)
-    smoke = bool(args.smoke)
-    args.host_replicas = True
-    args.replica_endpoint = None
-    args.prefix_pool, args.prefix_cache = 0, False
-    args.verify_parity = False
-    args.autoscale = False
-    args.schedule_windows, args.deadline_s = None, None
-    args.arrival = "poisson"
-    args.vocab_size, args.max_seq_len = 96, 64
-    args.n_embd, args.n_layer, args.n_head = 32, 2, 4
-    args.slots, args.chunk_size = 1, 2
-    args.min_prompt, args.max_prompt = 3, 6
-    args.min_new, args.max_new = (8, 14) if smoke else (16, 24)
-    args.max_queue = 64
-    args.restart_backoff = 0.3
-    args.kv_page_size = None
-    args.chunk_deadline = None
-    args.smoke = True     # _build_router: hosted-loose health thresholds
-
-    def drive(host, handles, timeout=120.0):
-        t0 = time.monotonic()
-        while any(not h.done for h in handles) \
-                and time.monotonic() - t0 < timeout:
-            host.step()
-        return [h.done for h in handles]
-
-    def warm(hosts, n=2):
-        rng = np.random.default_rng(7)
-        for h in hosts:
-            hs = []
-            for _ in range(n):
-                hs.append(h.submit(
-                    rng.integers(0, args.vocab_size, size=args.max_prompt
-                                 ).astype(np.int32),
-                    max_new_tokens=args.min_new))
-                drive(h, hs)
-
-    # ------------------------------------------------- throughput A/B lanes
-    ab = {}
-    for lane, transport in (("stdio", "stdio"), ("socket", "socket")):
-        print(f"[bench-net] spawning 2 {lane} hosts (throughput lane)...",
-              file=sys.stderr)
-        hosts = spawn_hosts(args, 2, transport=transport)
-        warm(hosts)
-        a = copy.copy(args)
-        a.requests = 16 if smoke else 48
-        a.rate = 1000.0               # saturating: throughput, not arrival
-        front, _, supervisor = _build_router(a, None, monitor, n_static=2,
-                                             host_pool=hosts)
-        snap = run_load(front, a, supervisor=supervisor)
-        close_hosts(front)
-        ab[lane] = snap
-        print(f"[bench-net] {lane}: {snap['tokens_per_sec']:.1f} tok/s "
-              f"ttft_e2e_p95={snap.get('ttft_e2e_ms_p95')}", file=sys.stderr)
-    ratio = (ab["socket"]["tokens_per_sec"] / ab["stdio"]["tokens_per_sec"]
-             if ab["stdio"]["tokens_per_sec"] else None)
-
-    # ----------------------------------------------------------- soak lane
-    print("[bench-net] spawning 3 socket hosts (partition+delay+SIGKILL "
-          "soak)...", file=sys.stderr)
-    hosts = spawn_hosts(args, 3, transport="socket")
-    warm(hosts)
-    a = copy.copy(args)
-    a.requests = 18 if smoke else 48
-    a.rate = 50.0
-    a.min_new, a.max_new = 16, 24
-    spec = ("kill:replica=0,sig=KILL,when=busy;"
-            "net:replica=1,mode=partition,at=0.4,s=2.5;"
-            "net:replica=2,mode=delay=40,at=0.6,s=1.5")
-    chaos = ChaosSchedule(parse_chaos(spec))
-    front, _, supervisor = _build_router(a, None, monitor, n_static=3,
-                                         host_pool=hosts)
-    # the partition must outlive dead_after (DEAD fires mid-fault) and the
-    # bench proves the probe path, not the production recovery window
-    front.config.suspect_after_s, front.config.dead_after_s = 0.5, 1.5
-    front.config.recover_after_s, front.config.max_attempts = 2.0, 4
-    soak = run_load(front, a, chaos=chaos, supervisor=supervisor)
-    # post-storm: keep supervising until BOTH disturbed replicas are re-
-    # admitted (probe bursts — dispatch prefers LIVE replicas, so only
-    # overflow reaches a half-open one)
-    rng = np.random.default_rng(11)
-    t0 = time.monotonic()
-    probes = []
-    while time.monotonic() - t0 < 90.0:
-        supervisor.step()
-        front.step()
-        if all(front.replica_state(i) == ReplicaState.LIVE
-               for i in (0, 1)):
-            break
-        for i in (0, 1):
-            ri = front.replica_by_id(i)
-            if (front.replica_state(i) == ReplicaState.RECOVERING
-                    and ri is not None and ri.available > 0
-                    and front.queue_depth == 0 and len(probes) < 96):
-                try:
-                    for _ in range(args.slots * 3 + 2):
-                        probes.append(front.submit(
-                            rng.integers(0, args.vocab_size,
-                                         size=4).astype(np.int32),
-                            max_new_tokens=6))
-                except QueueFullError:
-                    pass
-    while front.busy and time.monotonic() - t0 < 120.0:
-        supervisor.step()
-        front.step()
-    soak["killed_back_live"] = \
-        front.replica_state(0) == ReplicaState.LIVE
-    soak["partitioned_back_live"] = \
-        front.replica_state(1) == ReplicaState.LIVE
-    soak["hosts"] = supervisor.report()
-    print(f"[bench-net] soak: lost={soak['lost']} "
-          f"parity={soak.get('parity_ok')} "
-          f"restarts={soak['hosts']['restarts_total']} "
-          f"killed_live={soak['killed_back_live']} "
-          f"partitioned_live={soak['partitioned_back_live']}",
-          file=sys.stderr)
-
-    # -------------------------------------------------- sever-resume probe
-    sever = {"resumed": False, "reconnects": 0, "served_after": False}
-    r2 = front.replica_by_id(2)
-    if r2 is not None and getattr(r2, "is_socket", False):
-        session0 = r2.session
-        r2.force_sever("bench-resume-probe")
-        t0 = time.monotonic()
-        # resumed_last resets to None at sever and only the NEXT hello's
-        # ready re-stamps it — wait for the verdict, not just the TCP connect
-        # (reconnects increments before the hello answer lands)
-        while time.monotonic() - t0 < 15.0 \
-                and (r2.severed or r2.reconnects < 1
-                     or r2.resumed_last is None):
-            supervisor.step()
-            front.step()
-        sever["reconnects"] = r2.reconnects
-        sever["resumed"] = bool(r2.resumed_last and r2.session == session0)
-        if not r2.severed:
-            try:
-                h = r2.submit(rng.integers(0, args.vocab_size,
-                                           size=4).astype(np.int32),
-                              max_new_tokens=6)
-                drive(r2, [h], timeout=30.0)
-                sever["served_after"] = bool(h.done)
-            except QueueFullError:
-                pass
-    close_hosts(front)
-    print(f"[bench-net] sever-resume: reconnects={sever['reconnects']} "
-          f"resumed={sever['resumed']} served={sever['served_after']}",
-          file=sys.stderr)
-
-    # ------------------------------------------------ delay no-false-kill
-    print("[bench-net] spawning 2 socket hosts (delay no-false-kill)...",
-          file=sys.stderr)
-    hosts = spawn_hosts(args, 2, transport="socket")
-    warm(hosts)
-    a = copy.copy(args)
-    a.requests = 12 if smoke else 32
-    a.rate = 20.0
-    chaos = ChaosSchedule(parse_chaos(
-        "net:replica=1,mode=delay=30,at=0.3,s=1.5"))
-    front, _, supervisor = _build_router(a, None, monitor, n_static=2,
-                                         host_pool=hosts)
-    front.config.suspect_after_s, front.config.dead_after_s = 0.5, 1.5
-    delay = run_load(front, a, chaos=chaos, supervisor=supervisor)
-    delay["hosts"] = supervisor.report()
-    delay["replica_health"] = {
-        i: front.replica_state(i).value for i in (0, 1)}
-    close_hosts(front)
-    print(f"[bench-net] delay: lost={delay['lost']} "
-          f"evicted={delay['evicted']} "
-          f"restarts={delay['hosts']['restarts_total']} "
-          f"health={delay['replica_health']}", file=sys.stderr)
-
-    gates = {
-        "harness_note": "socket lanes carry protocol v1 in CRC-framed TCP "
-                        "(serving.net); stdio lanes are the PR 15 pipe — "
-                        "same children, same geometry, equal replica count",
-        "stdio_tokens_per_sec": ab["stdio"]["tokens_per_sec"],
-        "socket_tokens_per_sec": ab["socket"]["tokens_per_sec"],
-        "socket_over_stdio": ratio,
-        "socket_holds_0p9x": bool(ratio is not None and ratio >= 0.9),
-        "stdio_ttft_e2e_ms_p95": ab["stdio"].get("ttft_e2e_ms_p95"),
-        "socket_ttft_e2e_ms_p95": ab["socket"].get("ttft_e2e_ms_p95"),
-        "soak_lost": soak["lost"],
-        "soak_chaos_exhausted": soak.get("chaos_exhausted", False),
-        "soak_chaos_unfired": soak.get("chaos_unfired", []),
-        "soak_parity_ok": soak.get("parity_ok", True),
-        "soak_restarts": soak["hosts"]["restarts_total"],
-        "respawn_with_redial": soak["hosts"]["restarts_total"] >= 1,
-        # the respawn-vs-redial split, negatively: the PARTITIONED child's
-        # process never died, so the supervisor must not have respawned it —
-        # its recovery was connection-level (sever-evict-redial)
-        "partition_no_respawn": (
-            soak["hosts"]["replicas"].get(1, {}).get("restarts", 0) == 0),
-        "killed_back_live": soak["killed_back_live"],
-        "partitioned_back_live": soak["partitioned_back_live"],
-        "soak_ok": bool(soak["lost"] == 0
-                        and soak.get("chaos_exhausted", False)
-                        and soak.get("parity_ok", True)
-                        and soak["hosts"]["restarts_total"] >= 1
-                        and soak["killed_back_live"]
-                        and soak["partitioned_back_live"]),
-        "sever_resumed_session": sever["resumed"],
-        "sever_served_after": sever["served_after"],
-        "delay_lost": delay["lost"],
-        "delay_evicted": delay["evicted"],
-        "delay_restarts": delay["hosts"]["restarts_total"],
-        "delay_no_false_kill": bool(
-            delay["lost"] == 0 and delay["evicted"] == 0
-            and delay["hosts"]["restarts_total"] == 0
-            and all(v == "live"
-                    for v in delay["replica_health"].values())),
-    }
-    checks = ["socket_holds_0p9x", "soak_ok", "partition_no_respawn",
-              "sever_resumed_session", "sever_served_after",
-              "delay_no_false_kill"]
-    ok = all(bool(gates[k]) for k in checks)
-    out = {"metric": "socket_over_stdio_throughput",
-           "value": ratio, "unit": "x", "smoke": smoke,
-           "net_gates": gates, "gates_ok": ok,
-           "detail": {"ab": ab, "soak": soak, "sever_resume": sever,
-                      "delay": delay}}
-    if args.out:
-        with open(args.out, "w") as f:
-            json.dump(out, f, indent=1)
-    print(json.dumps(out))
-    return 0 if ok else 1
-
-
-def _run_hosts_bench(args, monitor) -> int:
-    """Process-parallel replica hosts acceptance A/B (``BENCH_HOSTS`` JSON).
-
-    Four lanes, all over REAL child processes (``serving.host``), retiring the
-    ``BENCH_AUTOSCALE_r12`` harness caveat ("serial in-process pump: replica
-    count does not add host parallelism"):
-
-    - **concurrency** — 2 hosts behind the router under a saturating burst,
-      parent tracer ingesting the children's decode/prefill spans: the gate is
-      MEASURED wall-clock overlap (seconds during which both children have a
-      compute span open) > 0 — replica count now buys machine parallelism;
-    - **soak** — 3 supervised hosts under traffic with a real mid-decode
-      ``SIGKILL`` and a later ``SIGTERM`` kill: ``lost == 0``, every
-      evicted-and-retried request bit-identical to an unkilled reference
-      ``generate``, the supervisor respawns >= 1 child within the run, and
-      every chaos event fires (an unfired event fails the lane);
-    - **latency A/B** — over ``static_min`` (1 host), ``static_max`` (N
-      hosts), and ``autoscaled`` (1 -> N, scale-ups drawing pre-spawned warm
-      spares — the warm-fleet stand-in, since a cold child boot is a jax
-      import): the autoscaled lane must HOLD the coordinated-omission-honest
-      TTFT-p95 gate that the static-min lane BREACHES — the claim PR 12
-      filed as unmeasurable in-process — with ``lost == 0`` and bit-exact
-      parity across its scale churn. The A/B's children are PACED
-      device-bound replicas (fixed per-chunk delay via the
-      ``DS_TPU_FAULT_SPEC`` env contract): an unpaced toy child is
-      host-CPU-bound, and on a core-starved CI host N such processes share
-      one core's capacity — which measures the machine, not the serving
-      architecture. The offered swing self-calibrates against BOTH measured
-      capacities (one host's closed-loop rate and the N-host aggregate,
-      gated >= 1.8x apart) so the surge lands above the former and inside
-      the latter, with an r12-style re-offer when a machine-speed swing
-      dissolves the separation anyway.
-
-    ``--smoke`` runs concurrency + soak only (2 hosts, seconds-scale) — the
-    form the test suite executes; the committed artifact is a full run.
-    """
-    import copy
-    from deepspeed_tpu.inference.serving import (ChaosSchedule,
-                                                 QueueFullError, parse_chaos)
-    from deepspeed_tpu.observability.trace import get_tracer
-    args = copy.copy(args)
-    args.host_replicas = True
-    args.prefix_pool, args.prefix_cache = 0, False
-    args.verify_parity = False
-    args.autoscale = False
-    args.schedule_windows, args.deadline_s = None, None
-    if args.smoke:
-        args.vocab_size, args.max_seq_len = 96, 64
-        args.n_embd, args.n_layer, args.n_head = 32, 2, 4
-        args.slots, args.chunk_size = 1, 2
-        args.min_prompt, args.max_prompt = 3, 6
-        args.min_new, args.max_new = 8, 14
-        args.max_queue = 64
-        args.restart_backoff = 0.3
-    else:
-        args.vocab_size, args.max_seq_len = 96, 96
-        args.n_embd, args.n_layer, args.n_head = 32, 2, 4
-        args.slots, args.chunk_size = 1, 4
-        args.min_prompt, args.max_prompt = 3, 8
-        args.min_new, args.max_new = 24, 40
-        args.max_queue = 128
-    args.min_replicas, args.max_replicas = 1, 3
-
-    def drive(host, handles, timeout=120.0):
-        t0 = time.monotonic()
-        while any(not h.done for h in handles) \
-                and time.monotonic() - t0 < timeout:
-            host.step()
-        return [h.done for h in handles]
-
-    def warm(hosts, n=2):
-        # pay each child's prefill-bucket + chunk XLA compiles before any
-        # lane's clock starts (the warm-fleet premise)
-        rng = np.random.default_rng(7)
-        for h in hosts:
-            hs = []
-            for _ in range(n):
-                hs.append(h.submit(
-                    rng.integers(0, args.vocab_size, size=args.max_prompt
-                                 ).astype(np.int32),
-                    max_new_tokens=args.min_new))
-                drive(h, hs)
-
-    tracer = get_tracer()
-
-    # ---------------------------------------------------- concurrency lane
-    print("[bench-hosts] spawning 2 hosts (concurrency lane)...",
-          file=sys.stderr)
-    hosts = spawn_hosts(args, 2)
-    warm(hosts)
-    tracer.enable(pid_label="bench-hosts")
-    tracer.reset()
-    a = copy.copy(args)
-    a.requests = 16 if args.smoke else 48
-    a.rate = 1000.0                       # saturate both hosts
-    front, _, supervisor = _build_router(a, None, monitor, n_static=2,
-                                         host_pool=hosts)
-    conc = run_load(front, a, supervisor=supervisor)
-    # one more harvest round so the children's tail spans land in the parent
-    t_h = time.monotonic()
-    while time.monotonic() - t_h < 1.0:
-        front.step()
-    lanes_iv = {}
-    for s in tracer.spans:
-        if s["name"] in ("decode_chunk", "prefill", "suffix_prefill") \
-                and str(s["pid"]).startswith("host"):
-            lanes_iv.setdefault(s["pid"], []).append((s["ts"],
-                                                      s["ts"] + s["dur"]))
-    merged = {pid: _merge_intervals(iv) for pid, iv in lanes_iv.items()}
-    busy_s = {pid: sum(t1 - t0 for t0, t1 in iv) / 1e6
-              for pid, iv in merged.items()}
-    overlap_s = _overlap_seconds(list(merged.values()))
-    overlap_frac = (overlap_s / min(busy_s.values())
-                    if len(busy_s) >= 2 and min(busy_s.values()) > 0 else 0.0)
-    tracer.disable()
-    tracer.reset()
-    close_hosts(front)
-    conc["span_lanes"] = {pid: round(b, 4) for pid, b in busy_s.items()}
-    conc["overlap_s"] = overlap_s
-    conc["overlap_frac"] = overlap_frac
-    print(f"[bench-hosts] concurrency: busy={busy_s} overlap={overlap_s:.3f}s"
-          f" ({overlap_frac:.2%})", file=sys.stderr)
-
-    # ----------------------------------------------------------- soak lane
-    n_soak = 2 if args.smoke else 3
-    print(f"[bench-hosts] spawning {n_soak} hosts (SIGKILL+respawn soak)...",
-          file=sys.stderr)
-    hosts = spawn_hosts(args, n_soak)
-    warm(hosts)
-    a = copy.copy(args)
-    # saturating-ish: every replica stays mid-decode so the when=busy kill
-    # has a real window to land in
-    a.requests = 16 if args.smoke else 48
-    a.rate = 50.0 if args.smoke else 30.0
-    a.min_new, a.max_new = (16, 24) if args.smoke else (24, 40)
-    spec = "kill:replica=1,sig=KILL,when=busy"
-    if not args.smoke:
-        spec += ";kill:replica=2,sig=TERM,at=3.0"
-    chaos = ChaosSchedule(parse_chaos(spec))
-    front, _, supervisor = _build_router(a, None, monitor, n_static=n_soak,
-                                         host_pool=hosts)
-    front.config.recover_after_s = 2.0   # the bench proves the probe path;
-    #   it need not wait out the production recovery window
-    soak = run_load(front, a, chaos=chaos, supervisor=supervisor)
-    # post-storm supervision: keep the loop alive until the respawned child
-    # is re-admitted through the RECOVERING warm probe, then prove it serves
-    # again. The probe needs a BURST (not one request): dispatch prefers the
-    # least-loaded LIVE replica, so only overflow traffic reaches the
-    # half-open one.
-    from deepspeed_tpu.inference.serving import ReplicaState
-    rng = np.random.default_rng(11)
-    t0 = time.monotonic()
-    probes = []
-    while time.monotonic() - t0 < 90.0:
-        supervisor.step()
-        front.step()
-        if front.replica_state(1) == ReplicaState.LIVE:
-            break
-        r1 = front.replica_by_id(1)
-        if (front.replica_state(1) == ReplicaState.RECOVERING
-                and r1 is not None and r1.available > 0
-                and front.queue_depth == 0 and len(probes) < 64):
-            # probe traffic only once the respawned child can actually take
-            # one (hello landed, slots free): anything offered during its
-            # boot window just drains into the survivors and burns the
-            # probe budget before the half-open slot exists
-            try:
-                for _ in range(args.slots * n_soak + 2):
-                    probes.append(front.submit(
-                        rng.integers(0, args.vocab_size,
-                                     size=4).astype(np.int32),
-                        max_new_tokens=6))
-            except QueueFullError:
-                pass
-    while front.busy and time.monotonic() - t0 < 120.0:
-        supervisor.step()
-        front.step()
-    soak["respawned_back_live"] = \
-        front.replica_state(1) == ReplicaState.LIVE
-    soak["hosts"] = supervisor.report()
-    close_hosts(front)
-    print(f"[bench-hosts] soak: lost={soak['lost']} "
-          f"parity={soak.get('parity_ok')} "
-          f"restarts={soak['hosts']['restarts_total']} "
-          f"live_again={soak['respawned_back_live']}", file=sys.stderr)
-
-    # ----------------------------------------------------- latency A/B lanes
-    ab = None
-    if not args.smoke:
-        rng = np.random.default_rng(5)
-        mean_new = int(0.5 * (args.min_new + args.max_new))
-
-        def closed_loop_rate(front_or_host, K):
-            """Saturating closed-loop burst: true service rate of one host
-            (direct submit) or a whole router (aggregate)."""
-            t_cal = time.monotonic()
-            hs, remaining = [], K
-            while (remaining or any(not h.done for h in hs)) \
-                    and time.monotonic() - t_cal < 300.0:
-                while remaining:
-                    try:
-                        hs.append(front_or_host.submit(
-                            rng.integers(0, args.vocab_size,
-                                         size=args.max_prompt
-                                         ).astype(np.int32),
-                            max_new_tokens=mean_new))
-                        remaining -= 1
-                    except QueueFullError:
-                        break
-                front_or_host.step()
-            return K / (time.monotonic() - t_cal)
-
-        # the A/B's children are PACED device-bound replicas: every decode
-        # chunk carries a fixed delay via the DS_TPU_FAULT_SPEC env contract
-        # (the subprocess parity test's chunk-spacing idiom). Real replicas
-        # are device-bound — each owns its chip — but an unpaced toy child is
-        # host-CPU-bound, and on a core-starved CI host N such processes
-        # share ONE core's capacity (measured here: cap3 ~= cap1), so no
-        # offered surge can separate static_min from static_max. Pacing
-        # restores the regime the claim lives in: per-host capacity is bound
-        # by the (modeled) device step, host cores only run the light serving
-        # loop, and N hosts scale structurally.
-        from deepspeed_tpu.utils.fault_injection import FaultSpec, fault_env
-        pace_s = 0.025
-        pace_env = fault_env([("serving.decode_chunk",
-                               FaultSpec(kind="delay", delay_s=pace_s))],
-                             seed=1)
-
-        def ensure_pool(pool, n):
-            """Replace dead hosts (a prior lane's retire/kill closed them)
-            with fresh warmed spawns so every attempt starts whole."""
-            alive = [h for h in pool if h.alive]
-            if len(alive) < n:
-                fresh = spawn_hosts(args, n - len(alive), env=pace_env)
-                warm(fresh)
-                alive += fresh
-            return alive
-
-        # calibrate BOTH capacities: one host's service rate AND the full
-        # pool's measured aggregate — the surge must land above the former
-        # (static_min drowns) and inside the latter (static_max holds)
-        print("[bench-hosts] calibrating per-host + aggregate rates...",
-              file=sys.stderr)
-        pool1 = spawn_hosts(args, 1, env=pace_env)
-        warm(pool1)
-        cap1 = max(closed_loop_rate(pool1[0], 12)
-                   for _ in range(2))            # best-of-2: a transient
-        #   machine pause under-reads (the r12 calibration discipline)
-        pool_max = spawn_hosts(args, args.max_replicas, env=pace_env)
-        warm(pool_max)
-        cal_router, _, _cal_sup = _build_router(
-            copy.copy(args), None, monitor, n_static=args.max_replicas,
-            host_pool=pool_max)
-        cap_n = closed_loop_rate(cal_router, 12 * args.max_replicas)
-        auto_pool = spawn_hosts(args, args.max_replicas, env=pace_env)
-        warm(auto_pool)
-        req_floor = args.requests
-
-        def offer(surge, trough):
-            args.arrival = f"schedule:{trough}@2,{surge}@2,{trough}@10"
-            args.schedule_windows = parse_schedule(
-                args.arrival.split(":", 1)[1])
-            args.requests = min(400, max(req_floor, 72,
-                                         int(12 * trough + 2 * surge)))
-
-        def ab_lane(name, pool, n_static=None, autoscale=False):
-            a = copy.copy(args)
-            a.autoscale = autoscale
-            front, autoscaler, supervisor = _build_router(
-                a, None, monitor, n_static=n_static, host_pool=pool)
-            print(f"[bench-hosts] lane {name}: offering {a.arrival} over "
-                  f"{a.requests} requests...", file=sys.stderr)
-            snap = run_load(front, a, autoscaler=autoscaler,
-                            supervisor=supervisor)
-            snap["lane"] = name
-            return snap
-
-        def p95(s):
-            return s.get("ttft_e2e_ms_p95")
-
-        # the surge must straddle the two PROVISIONINGS: clearly above one
-        # host's rate (static_min must drown) yet inside the measured
-        # aggregate (static_max must hold) — with a re-offer pass because
-        # this machine's throughput swings between runs (the r12 bench's
-        # self-aware re-offer, pointed at separation instead of vacuousness)
-        surge = max(1.15 * cap1, min(2.5 * cap1, 0.8 * cap_n))
-        trough = 0.35 * cap1
-        print(f"[bench-hosts] cap1 ~{cap1:.1f} req/s, "
-              f"cap{args.max_replicas} ~{cap_n:.1f} req/s aggregate",
-              file=sys.stderr)
-        attempts = []
-        for attempt in range(3):
-            offer(round(surge, 2), round(trough, 2))
-            pool1 = ensure_pool(pool1, args.min_replicas)
-            static_min = ab_lane("static_min", pool1,
-                                 n_static=args.min_replicas)
-            pool_max = ensure_pool(pool_max, args.max_replicas)
-            static_max = ab_lane("static_max", pool_max,
-                                 n_static=args.max_replicas)
-            auto_pool = ensure_pool(auto_pool, args.max_replicas)
-            autoscaled = ab_lane("autoscaled", auto_pool, autoscale=True)
-            transient_ms = 1e3 * (autoscaled.get("autoscale") or {}).get(
-                "transient_s", 0.0)
-            gate_ms = (max(2.5 * p95(static_max), 1.2 * transient_ms)
-                       if p95(static_max) else None)
-            breaches = bool(gate_ms is not None
-                            and p95(static_min) is not None
-                            and p95(static_min) > gate_ms)
-            holds = bool(gate_ms is not None and p95(autoscaled) is not None
-                         and p95(autoscaled) <= gate_ms)
-            attempts.append({"attempt": attempt, "arrival": args.arrival,
-                             "requests": args.requests, "gate_ms": gate_ms,
-                             "static_min_p95": p95(static_min),
-                             "static_max_p95": p95(static_max),
-                             "autoscaled_p95": p95(autoscaled),
-                             "breaches": breaches, "holds": holds})
-            if breaches and holds:
-                break
-            if not breaches:
-                surge *= 1.35          # static_min survived: press harder
-            elif not holds:
-                surge *= 0.8           # even elastic capacity drowned: the
-                #   offered surge outran the machine, not the control loop
-            print(f"[bench-hosts] no separation (breaches={breaches}, "
-                  f"holds={holds}); re-offering", file=sys.stderr)
-        close_hosts(pool1)
-        close_hosts(pool_max)
-        close_hosts(auto_pool)
-        asr = autoscaled.get("autoscale") or {}
-        ab = {
-            "lanes": {"static_min": static_min, "static_max": static_max,
-                      "autoscaled": autoscaled},
-            "pace_chunk_delay_s": pace_s,
-            "pacing_note": "A/B children are paced device-bound replicas "
-                           "(fixed per-chunk delay via DS_TPU_FAULT_SPEC): "
-                           "an unpaced toy child is host-CPU-bound and N "
-                           "processes share one CI core's capacity, which "
-                           "measures the machine, not the serving "
-                           "architecture",
-            "capacity_req_s_per_host": cap1,
-            "capacity_req_s_aggregate": cap_n,
-            "parallel_speedup": (cap_n / cap1 if cap1 else None),
-            "offer_attempts": attempts,
-            "ttft_gate_ms": gate_ms,
-            "static_min_ttft_p95_ms": p95(static_min),
-            "static_max_ttft_p95_ms": p95(static_max),
-            "autoscaled_ttft_p95_ms": p95(autoscaled),
-            "static_min_breaches_gate": breaches,
-            "autoscaled_holds_gate": holds,
-            "scale_ups": asr.get("scale_ups", 0),
-            "scale_downs": asr.get("scale_downs", 0),
-            "autoscaled_lost": autoscaled.get("lost"),
-            "autoscaled_parity_ok": autoscaled.get("parity_ok", True),
-            "mean_replicas": {
-                "static_min": static_min.get("mean_replicas"),
-                "static_max": static_max.get("mean_replicas"),
-                "autoscaled": autoscaled.get("mean_replicas")},
-        }
-
-    gates = {
-        "harness_note": "replicas are real supervised child processes; the "
-                        "r12 'serial in-process pump' caveat is retired by "
-                        "this artifact",
-        "concurrent_pump_overlap_s": overlap_s,
-        "concurrent_pump_overlap_frac": overlap_frac,
-        "hosts_pump_concurrently": bool(overlap_s > 0
-                                        and len(busy_s) >= 2),
-        "soak_lost": soak["lost"],
-        "soak_chaos_exhausted": soak.get("chaos_exhausted", False),
-        "soak_parity_ok": soak.get("parity_ok", True),
-        "soak_restarts": soak["hosts"]["restarts_total"],
-        "supervised_respawn": soak["hosts"]["restarts_total"] >= 1,
-        "respawned_back_live": soak["respawned_back_live"],
-        "soak_ok": bool(soak["lost"] == 0
-                        and soak.get("chaos_exhausted", False)
-                        and soak.get("parity_ok", True)
-                        and soak["hosts"]["restarts_total"] >= 1),
-    }
-    checks = ["hosts_pump_concurrently", "soak_ok", "respawned_back_live"]
-    if ab is not None:
-        gates.update({
-            "parallel_speedup": ab["parallel_speedup"],
-            "aggregate_scales_with_hosts": bool(
-                ab["parallel_speedup"] is not None
-                and ab["parallel_speedup"] >= 1.8),
-            "ttft_gate_ms": ab["ttft_gate_ms"],
-            "static_min_breaches_gate": ab["static_min_breaches_gate"],
-            "autoscaled_holds_gate": ab["autoscaled_holds_gate"],
-            "autoscaled_ttft_p95_ms": ab["autoscaled_ttft_p95_ms"],
-            "static_min_ttft_p95_ms": ab["static_min_ttft_p95_ms"],
-            "scaled_up": ab["scale_ups"] >= 1,
-            "autoscaled_lost_zero": ab["autoscaled_lost"] == 0,
-            "autoscaled_parity_ok": ab["autoscaled_parity_ok"],
-            "r12_caveat_retired": bool(ab["static_min_breaches_gate"]
-                                       and ab["autoscaled_holds_gate"]),
-        })
-        checks += ["aggregate_scales_with_hosts",
-                   "static_min_breaches_gate", "autoscaled_holds_gate",
-                   "scaled_up", "autoscaled_lost_zero",
-                   "autoscaled_parity_ok"]
-    ok = all(bool(gates[k]) for k in checks)
-    out = {"metric": "hosts_concurrent_overlap_frac", "value": overlap_frac,
-           "unit": "frac", "smoke": bool(args.smoke),
-           "hosts_gates": gates, "gates_ok": ok,
-           "detail": {"concurrency": conc, "soak": soak,
-                      **({"latency_ab": ab} if ab is not None else {})}}
-    if args.out:
-        with open(args.out, "w") as f:
-            json.dump(out, f, indent=1)
-    print(json.dumps(out))
-    return 0 if ok else 1
-
-
-def _run_spec_bench(args, monitor) -> int:
-    """Speculative-decoding acceptance A/B (``BENCH_SPEC`` JSON).
-
-    Three lanes over ONE tiny engine (shared compile cache — the A/B
-    isolates speculation, not compilation), all greedy with EVERY request
-    parity-checked against per-request ``generate``:
-
-    - **spec-off** — the plain chunked paged decode path (the baseline);
-    - **spec-on** — the same trace with the self-speculative n-gram
-      proposer + one-pass k-token verify. The trace is repetitive-suffix
-      (``prompt_style="repetitive"``: tiled short units — templated/
-      structured prompts), the regime the n-gram draft exists for. Gates:
-      acceptance >= 0.6 and **target passes per committed token <= 0.55**
-      — the verify-round count divided by tokens emitted, i.e. the
-      weight-streaming bytes multiplier speculation exists to shrink
-      (PERF.md's bytes/step model; on a decode-bandwidth-bound chip
-      tok/s tracks its inverse);
-    - **chaos** — a 2-replica router with speculation on and a mid-flight
-      replica kill: the checkpointless-retry contract must hold under
-      speculation (lost == 0, every retried request bit-exact).
-
-    The on/off lanes are order-interleaved per rep and gated on medians so
-    machine drift cancels. Wall-clock tok/s for both lanes rides along in
-    the artifact but is NOT gated: on the CPU host the verify forward is
-    compute-bound (k+1 rows cost ~(k+1)x a single-row step), so the
-    passes-per-token win does not convert to wall-clock here — on a chip
-    the decode step is weight-bandwidth-bound and the conversion is the
-    point (ROADMAP carried item, same family as the paged-gather caveat).
-    """
-    import copy
-    from deepspeed_tpu.inference.serving import (ChaosSchedule,
-                                                 ContinuousBatchingScheduler,
-                                                 Router, RouterConfig,
-                                                 ServingConfig, parse_chaos)
-    geom = dict(vocab_size=96, max_seq_len=64, n_embd=32, n_layer=2, n_head=4,
-                cap=64, slots=2, chunk=3, page=8, k=4)
-    if args.smoke:
-        requests, reps, chaos_requests = 10, 2, 6
-    else:
-        requests, reps, chaos_requests = 40, 3, 12
-    a0 = copy.copy(args)
-    for key in ("vocab_size", "max_seq_len", "n_embd", "n_layer", "n_head"):
-        setattr(a0, key, geom[key])
-    a0.rate, a0.verify_parity = 1000.0, True    # saturate: sustained rate
-    a0.requests = requests
-    a0.max_queue = 256
-    a0.prefix_pool, a0.prefix_cache = 0, False
-    a0.prompt_style = "repetitive"
-    a0.min_prompt, a0.max_prompt = 12, 20
-    a0.min_new, a0.max_new = 8, 16
-    a0.prompt_dist = a0.output_dist = None
-    a0.chaos = None
-    a0.deadline_s = None
-    engine = build_engine(a0)
-
-    def cfg_for(speculate):
-        return ServingConfig(slots=geom["slots"], chunk_size=geom["chunk"],
-                             max_queue=256, max_seq_len=geom["cap"],
-                             kv_page_size=geom["page"],
-                             speculate=speculate, spec_k=geom["k"])
-
-    def lane(speculate, record):
-        a = copy.copy(a0)
-        front = ContinuousBatchingScheduler(engine, cfg_for(speculate))
-        snap = run_load(front, a)
-        snap["sustained_tok_s"] = (snap["tokens_total"] / snap["wall_s"]
-                                   if snap["wall_s"] > 0 else 0.0)
-        if record is not None:
-            record.append(snap)
-        return snap
-
-    print("[bench-spec] warming both lanes' compiles...", file=sys.stderr)
-    lane(False, None)
-    lane(True, None)
-    rec = {"off": [], "on": []}
-    for rep in range(reps):
-        order = (("off", "on") if rep % 2 == 0 else ("on", "off"))
-        for kind in order:
-            print(f"[bench-spec] lane {kind} rep {rep}...", file=sys.stderr)
-            lane(kind == "on", rec[kind])
-
-    # chaos lane: 2 replicas sharing params (bit-identical), speculation on
-    # both; kill one mid-flight — the router's checkpointless retry restarts
-    # the request on the survivor and run_load parity-checks every retried
-    # request against generate (plus full greedy parity on all of them)
-    print("[bench-spec] chaos lane (kill under speculation)...",
-          file=sys.stderr)
-    a = copy.copy(a0)
-    a.requests = chaos_requests
-    a.min_new, a.max_new = 10, 16       # enough in-flight decode to land on
-    engine2 = build_engine(a0, params=engine.params)
-    rcfg = RouterConfig(serving=cfg_for(True), suspect_after_s=0.04,
-                        dead_after_s=0.12, recover_after_s=30.0,
-                        breaker_threshold=2, max_attempts=4,
-                        retry_base_delay=0.001)
-    chaos = ChaosSchedule(parse_chaos("kill:replica=0,when=busy"))
-    chaos_snap = run_load(Router([engine, engine2], rcfg), a, chaos=chaos)
-
-    def med(snaps, key):
-        return _med_notnull(s.get(key) for s in snaps)
-
-    acceptance = med(rec["on"], "spec_acceptance_rate")
-    ppt = med(rec["on"], "spec_passes_per_token")
-    tok_off = med(rec["off"], "sustained_tok_s")
-    tok_on = med(rec["on"], "sustained_tok_s")
-    parity_all = all(
-        s.get("parity_ok", False) and s.get("full_parity_bad", 1) == 0
-        for s in rec["off"] + rec["on"] + [chaos_snap])
-    lost_all = all(
-        s.get("lost", 1) == 0 and s.get("all_finished", False)
-        for s in rec["off"] + rec["on"] + [chaos_snap])
-    gates = {
-        "acceptance_rate": acceptance,
-        "acceptance_gate": 0.6,
-        "acceptance_ok": bool(acceptance is not None and acceptance >= 0.6),
-        "passes_per_token": ppt,
-        "passes_per_token_gate": 0.55,
-        "passes_ok": bool(ppt is not None and ppt <= 0.55),
-        "sustained_tok_s_off": tok_off,
-        "sustained_tok_s_on": tok_on,
-        "parity_ok_every_request": parity_all,
-        "lost_zero_all_lanes": lost_all,
-        "chaos_exhausted": bool(chaos_snap.get("chaos_exhausted", False)),
-        "chaos_retried": chaos_snap.get("retried", 0),
-        "chaos_ok": bool(chaos_snap.get("chaos_exhausted", False)
-                         and chaos_snap.get("retried", 0) >= 1),
-    }
-    ok = all(bool(gates[k]) for k in
-             ("acceptance_ok", "passes_ok", "parity_ok_every_request",
-              "lost_zero_all_lanes", "chaos_ok"))
-    out = {"metric": "spec_target_passes_per_token", "value": ppt,
-           "unit": "passes/tok", "smoke": bool(args.smoke),
-           "spec_k": geom["k"], "proposer": "ngram",
-           "geometry": geom, "requests_per_lane": requests, "reps": reps,
-           "spec_gates": gates, "gates_ok": ok,
-           "harness_note": (
-               "CPU-host A/B: passes-per-token and acceptance are the gated "
-               "(machine-independent) quantities; the tiny-model verify "
-               "forward is compute-bound on CPU, so the tok/s pair is "
-               "reported ungated — on-chip, decode is weight-bandwidth-bound "
-               "and tok/s ~ 1/passes_per_token (ROADMAP carried item)"),
-           "detail": {"off": rec["off"], "on": rec["on"],
-                      "chaos": chaos_snap}}
-    if args.out:
-        with open(args.out, "w") as f:
-            json.dump(out, f, indent=1)
-    print(json.dumps(out))
-    return 0 if ok else 1
-
-
-def _run_kvecon_bench(args, monitor) -> int:
-    """Fleet KV-economy acceptance A/B (``BENCH_KVECON`` JSON).
-
-    A many-tenant shared-prefix trace (``session_style="tenant"``: every
-    request is its own session, so affinity carries NO locality signal —
-    the regime prefix-aware dispatch exists for), all lanes greedy with
-    EVERY request parity-checked against per-request ``generate``:
-
-    - **single** — one tiered scheduler: the per-process hit-rate ceiling
-      the fleet is judged against;
-    - **affinity vs aware** — the SAME trace over a 4-replica router,
-      once with legacy affinity-only dispatch and once with prefix-aware
-      scoring (both fleets tiered; fresh per-replica caches per lane).
-      Gate: aware fleet admission-level hit rate >= 0.9x the
-      single-replica ceiling AND strictly above the affinity-only lane —
-      a fleet must not pay ~Nx the cold misses just for being a fleet;
-    - **promote** — one scheduler whose device rung holds ~1 entry over a
-      1 MiB host rung, cycling 3 prefixes: nearly every hit is a
-      host-rung promote (slab restore), spilling what it evicts. Gates:
-      promote-path TTFT p50 strictly below miss TTFT p50 (a promote must
-      beat recomputing the prefill it skips), spills and promotions both
-      actually moved;
-    - **chaos** — a 2-replica prefix-aware fleet with the same churning
-      tier and ``kill:replica=0,when=restore``: the kill lands exactly
-      between the host->device promote restore and the suffix prefill.
-      The checkpointless-retry contract must hold mid-promote (lost == 0,
-      every retried request bit-exact).
-
-    Hit rates are counting gates (machine-independent); the promote lane's
-    TTFT comparison is within-lane self-controlled, so machine drift
-    cancels without interleaving."""
-    import copy
-    from deepspeed_tpu.inference.serving import (ChaosSchedule,
-                                                 ContinuousBatchingScheduler,
-                                                 PrefixCacheConfig, Router,
-                                                 RouterConfig, ServingConfig,
-                                                 parse_chaos)
-    # per-token KV bytes = n_layer * 2 * n_embd * 4B = 512; a prefix(24) +
-    # tail(<=6) prompt rounds to 4 pages = 16 KiB/entry under page=8 — the
-    # 24 KiB device budget below therefore holds exactly one entry
-    geom = dict(vocab_size=96, max_seq_len=64, n_embd=32, n_layer=2, n_head=4,
-                cap=64, slots=2, chunk=4, page=8, fleet=4, pool=4,
-                prefix_len=24, tier_mb=1.0, device_mb=4.0,
-                promote_prefix_len=40, promote_device_kb=28)
-    if args.smoke:
-        requests, reps, promote_requests, chaos_requests = 24, 1, 10, 8
-        min_moves = 2
-    else:
-        requests, reps, promote_requests, chaos_requests = 48, 2, 30, 12
-        min_moves = 5
-    a0 = copy.copy(args)
-    for key in ("vocab_size", "max_seq_len", "n_embd", "n_layer", "n_head"):
-        setattr(a0, key, geom[key])
-    a0.requests, a0.verify_parity = requests, True
-    # paced (NOT saturated) arrivals: routing can only exploit a cache entry
-    # inserted by an EARLIER request's prefill — an all-at-once burst would
-    # make every pick before any insert exists and flatten the A/B
-    a0.rate = 40.0
-    a0.max_queue = 256
-    a0.prefix_pool, a0.prefix_len = geom["pool"], geom["prefix_len"]
-    a0.prefix_cache, a0.prefix_min_hit = True, 8
-    a0.prefix_insert_on = "prefill"
-    a0.session_style = "tenant"
-    a0.prompt_style = None
-    a0.min_prompt, a0.max_prompt = 2, 6
-    a0.min_new, a0.max_new = 4, 8
-    a0.prompt_dist = a0.output_dist = None
-    a0.chaos, a0.deadline_s = None, None
-    a0.autoscale = a0.slo_admission = False
-
-    def pcfg(device_bytes):
-        return PrefixCacheConfig(
-            max_bytes=int(device_bytes),
-            host_tier_bytes=int(geom["tier_mb"] * 2**20),
-            min_hit_tokens=a0.prefix_min_hit,
-            min_insert_tokens=a0.prefix_min_hit, insert_on="prefill")
-
-    def scfg(device_bytes):
-        return ServingConfig(slots=geom["slots"], chunk_size=geom["chunk"],
-                             max_queue=256, max_seq_len=geom["cap"],
-                             kv_page_size=geom["page"],
-                             prefix_cache=pcfg(device_bytes))
-
-    roomy = int(geom["device_mb"] * 2**20)       # holds every pool prefix
-    tight = geom["promote_device_kb"] * 1024     # holds ~one entry
-    engine = build_engine(a0)
-    engines = [engine] + [build_engine(a0, params=engine.params)
-                          for _ in range(geom["fleet"] - 1)]
-
-    def single_lane(device_bytes, n_requests, rate, record=None,
-                    prefix_len=None):
-        a = copy.copy(a0)
-        a.requests, a.rate = n_requests, rate
-        if prefix_len is not None:
-            # promote lane: a LONGER shared prefix so the prefill a promote
-            # skips dwarfs the restore's own cost — with the base 24-token
-            # prefix the saved ~6 chunk-steps roughly equal one host->device
-            # restore on the tiny CPU model and the TTFT gate reads noise
-            a.prefix_len = prefix_len
-        front = ContinuousBatchingScheduler(engine, scfg(device_bytes),
-                                            monitor=monitor)
-        snap = run_load(front, a)
-        if record is not None:
-            record.append(snap)
-        return snap
-
-    def fleet_lane(aware, record=None):
-        a = copy.copy(a0)
-        rcfg = RouterConfig(serving=scfg(roomy), max_queue=256,
-                            prefix_aware_routing=aware)
-        snap = run_load(Router(list(engines), rcfg, monitor=monitor), a)
-        snap["fleet_hit_rate"] = (snap.get("kv_economy")
-                                  or {}).get("fleet_hit_rate")
-        if record is not None:
-            record.append(snap)
-        return snap
-
-    # warm with the tight budget so the spill (gather) and promote (restore)
-    # movers compile here, not inside a measured lane — both prefix lengths,
-    # because the movers' jit keys are row counts derived from matched/prompt
-    # pages and the promote lane's longer prefix uses different ones
-    print("[bench-kvecon] warming compiles (incl. spill/promote movers)...",
-          file=sys.stderr)
-    single_lane(tight, 8, 1000.0)
-    single_lane(tight, 8, 1000.0, prefix_len=geom["promote_prefix_len"])
-    rec = {"single": [], "affinity": [], "aware": [], "promote": []}
-    for rep in range(reps):
-        print(f"[bench-kvecon] rep {rep}: single / affinity / aware / "
-              "promote lanes...", file=sys.stderr)
-        single_lane(roomy, requests, a0.rate, rec["single"])
-        order = (("affinity", "aware") if rep % 2 == 0
-                 else ("aware", "affinity"))
-        for kind in order:
-            fleet_lane(kind == "aware", rec[kind])
-        # promote lane: unsaturated so TTFT reflects the promote itself
-        single_lane(tight, promote_requests, 12.0, rec["promote"],
-                    prefix_len=geom["promote_prefix_len"])
-
-    # chaos lane: 2 prefix-aware replicas sharing params, the same churning
-    # tight tier; when=restore kills replica 0 between its promote restore
-    # and the suffix prefill — the retry must land on the survivor bit-exact
-    print("[bench-kvecon] chaos lane (kill mid-promote)...", file=sys.stderr)
-    a = copy.copy(a0)
-    a.requests, a.rate = chaos_requests, 1000.0
-    a.prefix_pool = 2
-    a.min_new, a.max_new = 10, 16
-    rcfg = RouterConfig(serving=scfg(tight), max_queue=256,
-                        prefix_aware_routing=True, suspect_after_s=0.04,
-                        dead_after_s=0.12, recover_after_s=30.0,
-                        breaker_threshold=2, max_attempts=4,
-                        retry_base_delay=0.001)
-    chaos = ChaosSchedule(parse_chaos("kill:replica=0,when=restore"))
-    chaos_snap = run_load(Router(engines[:2], rcfg), a, chaos=chaos)
-
-    def med(snaps, key):
-        return _med_notnull(s.get(key) for s in snaps)
-
-    hr_single = med(rec["single"], "prefix_hit_rate")
-    hr_affinity = med(rec["affinity"], "fleet_hit_rate")
-    hr_aware = med(rec["aware"], "fleet_hit_rate")
-    hit_p50 = _med_notnull((s.get("prefix_trace") or {}).get("ttft_hit_ms_p50")
-                           for s in rec["promote"])
-    miss_p50 = _med_notnull(
-        (s.get("prefix_trace") or {}).get("ttft_miss_ms_p50")
-        for s in rec["promote"])
-    spills = sum((s.get("prefix_cache_report") or {}).get("spills", 0)
-                 for s in rec["promote"])
-    promotions = sum((s.get("prefix_cache_report") or {}).get("promotions", 0)
-                     for s in rec["promote"])
-    all_lanes = (rec["single"] + rec["affinity"] + rec["aware"]
-                 + rec["promote"] + [chaos_snap])
-    parity_all = all(
-        s.get("parity_ok", False) and s.get("full_parity_bad", 1) == 0
-        for s in all_lanes)
-    lost_all = all(
-        s.get("lost", 1) == 0 and s.get("all_finished", False)
-        for s in all_lanes)
-    gates = {
-        "single_hit_rate": hr_single,
-        "fleet_hit_rate_affinity": hr_affinity,
-        "fleet_hit_rate_aware": hr_aware,
-        "fleet_hit_floor": 0.9,
-        "fleet_hit_ok": bool(hr_aware is not None and hr_single is not None
-                             and hr_aware >= 0.9 * hr_single),
-        "aware_beats_affinity": bool(hr_aware is not None
-                                     and hr_affinity is not None
-                                     and hr_aware > hr_affinity),
-        "promote_ttft_hit_ms_p50": hit_p50,
-        "promote_ttft_miss_ms_p50": miss_p50,
-        "promote_ok": bool(hit_p50 is not None and miss_p50 is not None
-                           and hit_p50 < miss_p50),
-        "tier_spills": spills,
-        "tier_promotions": promotions,
-        "tier_exercised": bool(spills >= min_moves
-                               and promotions >= min_moves),
-        "parity_ok_every_request": parity_all,
-        "lost_zero_all_lanes": lost_all,
-        "chaos_exhausted": bool(chaos_snap.get("chaos_exhausted", False)),
-        "chaos_retried": chaos_snap.get("retried", 0),
-        "chaos_ok": bool(chaos_snap.get("chaos_exhausted", False)
-                         and chaos_snap.get("retried", 0) >= 1),
-    }
-    ok = all(bool(gates[k]) for k in
-             ("fleet_hit_ok", "aware_beats_affinity", "promote_ok",
-              "tier_exercised", "parity_ok_every_request",
-              "lost_zero_all_lanes", "chaos_ok"))
-    out = {"metric": "fleet_prefix_hit_rate", "value": hr_aware,
-           "unit": "hit_rate", "smoke": bool(args.smoke),
-           "geometry": geom, "requests_per_lane": requests, "reps": reps,
-           "kvecon_gates": gates, "gates_ok": ok,
-           "harness_note": (
-               "many-tenant trace: sessions are per-request, so the "
-               "affinity-only lane has no locality signal — its fleet hit "
-               "rate is the cost of cache-blind dispatch, reported as the "
-               "A/B foil; the gated quantities (hit rates, spill/promote "
-               "counts, parity, lost) are machine-independent, and the "
-               "promote TTFT gate is within-lane self-controlled"),
-           "detail": {"single": rec["single"], "affinity": rec["affinity"],
-                      "aware": rec["aware"], "promote": rec["promote"],
-                      "chaos": chaos_snap}}
-    if args.out:
-        with open(args.out, "w") as f:
-            json.dump(out, f, indent=1)
-    print(json.dumps(out))
-    return 0 if ok else 1
-
-
-def _med_notnull(xs):
-    """Median over the non-None entries; None when nothing survived (a rep
-    whose requests all failed must read as a failed gate, not a traceback)."""
-    vals = [x for x in xs if x is not None]
-    return float(np.median(vals)) if vals else None
-
-
-def _run_obs_ab(args, serving_cfg) -> int:
-    """Observability-overhead acceptance A/B: the same request set replayed
-    with (a) everything off, (b) the span tracer on, (c) the FULL diagnostic
-    stack on — tracer + flight recorder (attribution on every completion) +
-    anomaly detector — arms interleaved over ONE engine (shared compile cache
-    — the A/B isolates observability cost from compilation). Emits the
-    ``BENCH_OBS``/``BENCH_FLIGHT`` JSON with the <2% TPOT gates for BOTH the
-    tracing arm and the flight arm.
-
-    The gated quantity is **aggregate TPOT under saturation**: arrivals are
-    forced open-throttle so the scheduler is always busy and
-    ``wall_s / tokens_total`` measures the pure per-token serving cost —
-    per-request TPOT percentiles under open-loop arrivals carry queueing
-    variance an order of magnitude above the 2% gate (they ride along in
-    ``detail``). Deltas are paired per rep (each arm against the same rep's
-    off run) and position-rotated so machine drift cancels."""
-    from deepspeed_tpu.inference.serving import ContinuousBatchingScheduler
-    from deepspeed_tpu.observability import (AnomalyDetector, FlightRecorder,
-                                             get_registry)
-    from deepspeed_tpu.observability.anomaly import install_detector
-    from deepspeed_tpu.observability.trace import get_tracer
-    tracer = get_tracer()
-    args.rate = max(args.rate, 1000.0)      # saturate: measure serving, not
-    args.max_queue = max(args.max_queue, args.requests)   # arrival gaps
-    serving_cfg.max_queue = args.max_queue
-    engine = build_engine(args)
-    # warmup: pays every prefill-bucket + chunk compile, discarded
-    run_load(ContinuousBatchingScheduler(engine, serving_cfg), args)
-    arms = {"off": [], "on": [], "flight": []}
-    span_counts = []
-    row_counts = []
-    breakdown = None
-    for rep in range(max(1, args.obs_reps)):
-        # interleaved AND position-rotated: the later runs of a round see
-        # warmer allocator/cache state, which reads as a systematic arm bias
-        # unless every arm takes every position across reps
-        base = ["off", "on", "flight"]
-        order = base[rep % 3:] + base[:rep % 3]
-        for arm in order:
-            recorder = detector = None
-            if arm == "off":
-                tracer.disable()
-            else:
-                tracer.enable(pid_label="loadgen-ab")
-                tracer.reset()
-            if arm == "flight":
-                # dump_path=None: retention/attribution run, nothing written
-                # — the arm measures the recorder, not file IO
-                recorder = FlightRecorder(dump_path=None).attach(tracer)
-                detector = AnomalyDetector(recorder=recorder)
-                install_detector(detector)
-                get_registry().attach_monitor(detector)
-            snap = run_load(ContinuousBatchingScheduler(engine, serving_cfg),
-                            args)
-            if arm == "on":
-                span_counts.append(len(tracer.spans))
-            if arm == "flight":
-                row_counts.append(len(recorder.rows))
-                breakdown = recorder.breakdown()
-                get_registry().detach_monitor(detector)
-                install_detector(None)
-                recorder.detach()
-            arms[arm].append(snap)
-    tracer.disable()
-
-    def med(arm, key):
-        return _med_notnull(s.get(key) for s in arms[arm])
-
-    tpot_off, tpot_on = (med("off", "tpot_ms_p50_exact"),
-                         med("on", "tpot_ms_p50_exact"))
-
-    def agg_ms_per_tok(s):
-        return (s["wall_s"] / s["tokens_total"] * 1e3
-                if s.get("tokens_total") else None)
-
-    # paired per-rep deltas (each arm's rep against the SAME rep's off run
-    # over the identical request set), median across reps: slow machine drift
-    # hits every arm of a round equally and cancels, unlike a cross-rep median
-    def paired_overhead(arm):
-        deltas = [(agg_ms_per_tok(b) - agg_ms_per_tok(a)) / agg_ms_per_tok(a)
-                  for a, b in zip(arms["off"], arms[arm])
-                  if agg_ms_per_tok(a) and agg_ms_per_tok(b)]
-        return (float(np.median(deltas)) if deltas else None), deltas
-
-    overhead, deltas = paired_overhead("on")
-    flight_overhead, flight_deltas = paired_overhead("flight")
-    out = {
-        "metric": "obs_tracing_tpot_overhead_frac",
-        "value": overhead, "unit": "frac", "smoke": bool(args.smoke),
-        "obs_gates": {
-            "agg_tpot_ms_per_token_off": _med_notnull(
-                agg_ms_per_tok(s) for s in arms["off"]),
-            "agg_tpot_ms_per_token_on": _med_notnull(
-                agg_ms_per_tok(s) for s in arms["on"]),
-            "agg_tpot_ms_per_token_flight": _med_notnull(
-                agg_ms_per_tok(s) for s in arms["flight"]),
-            "tpot_ms_p50_off": tpot_off,
-            "tpot_ms_p50_on": tpot_on,
-            "tpot_overhead_frac": overhead,
-            "tpot_within_2pct": bool(overhead is not None
-                                     and overhead <= 0.02),
-            # the PR 14 gate: attribution + flight recorder + anomaly
-            # detector all enabled still land within 2% of everything-off
-            "flight_overhead_frac": flight_overhead,
-            "flight_within_2pct": bool(flight_overhead is not None
-                                       and flight_overhead <= 0.02),
-            "spans_per_on_rep": (float(np.median(span_counts))
-                                 if span_counts else 0.0),
-            "attribution_rows_per_flight_rep": (
-                float(np.median(row_counts)) if row_counts else 0.0),
-            "attribution_breakdown_emitted": bool(
-                breakdown is not None and breakdown.get("requests", 0) > 0),
-        },
-        "detail": {
-            "reps": args.obs_reps,
-            "paired_tpot_deltas": deltas,     # per-pair noise, artifact-honest
-            "paired_flight_deltas": flight_deltas,
-            "attribution": breakdown,         # p50-vs-p99 phase shares
-            "tokens_per_sec_off": med("off", "tokens_per_sec"),
-            "tokens_per_sec_on": med("on", "tokens_per_sec"),
-            "tokens_per_sec_flight": med("flight", "tokens_per_sec"),
-            "tpot_ms_mean_off": med("off", "tpot_ms_mean_exact"),
-            "tpot_ms_mean_on": med("on", "tpot_ms_mean_exact"),
-            "ttft_ms_p50_off": med("off", "ttft_ms_p50_exact"),
-            "ttft_ms_p50_on": med("on", "ttft_ms_p50_exact"),
-            "completed_off": sum(s["completed"] for s in arms["off"]),
-            "completed_on": sum(s["completed"] for s in arms["on"]),
-            "completed_flight": sum(s["completed"] for s in arms["flight"]),
-        },
-    }
-    if args.out:
-        with open(args.out, "w") as f:
-            json.dump(out, f, indent=1)
-    print(json.dumps(out))
-    g = out["obs_gates"]
-    return 0 if g["tpot_within_2pct"] and g["flight_within_2pct"] else 1
 
 
 if __name__ == "__main__":

@@ -28,7 +28,7 @@ GSPMD-*implicit* collectives (a ``with_sharding_constraint`` that lowers to
 an a2a, the monolithic-psum fallback's allreduce) never appear in the jaxpr
 — sites recorded with those ops are excluded from the exact cross-check and
 surfaced as ``info`` findings instead (documented limitation; their volume
-is checked by the bench A/B lanes, not statically).
+is not checked statically).
 
 Quantized wires need no special convention: the ppermute rule sums ALL
 operand avals, so a fused-quantized-ring hop (``parallel/qring.py``) —
@@ -155,7 +155,7 @@ def qring_wire_bytes(m: int, n: int, W: int, *, wire_bits: Optional[int] = 8,
 
     The qring span records this same number at trace time and the jaxpr
     side re-derives it from the ppermute operand avals — three independent
-    computations that the lint lane and ``bench.py --qring`` require to
+    computations that the lint lane and ``test_qring.py`` require to
     agree exactly, so bytes-on-wire claims are never hand-computed.
     """
     from ..comm.compressed import intn_wire_nbytes

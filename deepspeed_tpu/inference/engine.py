@@ -297,8 +297,8 @@ class InferenceEngine:
         billed at 2 bytes/elem, so the model describes a bf16 TPU deployment
         with one consistent denominator regardless of the dtype a CPU test
         engine happens to serve in. ``reduction_quantized_nodes`` is the
-        kernel-accounting reduction over the quantized set (the bench's
-        modeled bytes-per-step figure); ``reduction_total`` includes the
+        kernel-accounting reduction over the quantized set (the modeled
+        bytes-per-step figure); ``reduction_total`` includes the
         fp-kept matrices (embeddings/lm_head/excluded)."""
         from ..ops.quantizer import (dense_weight_bytes, is_quant_node,
                                      node_logical_shape, node_weight_bytes)

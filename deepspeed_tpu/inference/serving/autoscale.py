@@ -31,7 +31,7 @@ Three pieces:
   keeps the scaler from fighting itself — or the circuit breaker.
 - **replica-seconds accounting** — attached replicas integrated over wall
   time: the provisioned-capacity cost an autoscaled run is judged against a
-  static-N deployment on (``BENCH_AUTOSCALE`` gates static-N at >= 2x).
+  static-N deployment on (``Autoscaler.report()["replica_seconds"]``).
 
 Decisions are observable end to end: ``autoscale/scale_up_total`` /
 ``autoscale/scale_down_total`` / ``autoscale/replica_seconds`` counters and

@@ -754,7 +754,7 @@ class SocketHostedReplica(HostedReplica):
         """Cut the connection NOW (the live process keeps running): in-flight
         work evicts with prefixes on the next step and the reconnect machine
         redials with the session token — the sever-resume probe the net
-        bench and tests drive directly."""
+        tests drive directly."""
         if self._rep is not None:
             self._rep.force_sever(why)
 

@@ -249,7 +249,7 @@ class SpecStats:
     @property
     def passes_per_token(self) -> float:
         """Target forward passes per emitted decode token (non-speculative
-        decode is exactly 1.0 — the bench gate divides this)."""
+        decode is exactly 1.0)."""
         return self.rounds / self.tokens if self.tokens else 1.0
 
     def snapshot(self) -> dict:

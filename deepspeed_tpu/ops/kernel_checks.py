@@ -1,8 +1,8 @@
 """Compiled-kernel parity checks — ONE source of shapes and tolerances.
 
-Shared by the real-TPU test lane (``tests/unit/ops/test_kernels_tpu.py``), the
-bench's pre-run gate (``bench.py kernel_gate``) and ``chip_smoke.py``, so they
-cannot drift: a Mosaic regression that fails one fails all three identically. Each check
+Shared by the real-TPU test lane (``tests/unit/ops/test_kernels_tpu.py``)
+and ``chip_smoke.py``, which runs the whole table on the chip, so they
+cannot drift: a Mosaic regression that fails one fails both identically. Each check
 compiles the Pallas kernel (no interpret mode) and compares against its XLA
 reference; thresholds are per-check, matched to the check's dtype.
 """

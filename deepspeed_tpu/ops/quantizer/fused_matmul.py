@@ -182,8 +182,8 @@ def quantized_matmul_xla(x, q, scales, *, bits: int = 8, out_dtype=None):
 def node_weight_bytes(node) -> int:
     """HBM bytes the fused kernel streams for one full pass over a quant node
     (each weight/scale block is read exactly once): quantized payload + scales.
-    This is the kernel's own block accounting — ``bench.py --wq`` sums it into
-    the modeled bytes-per-step figure."""
+    This is the kernel's own block accounting — ``weight_stream_report`` sums
+    it into the modeled bytes-per-step figure."""
     q, s = node_qs(node)
     return int(np.prod(q.shape)) * q.dtype.itemsize + \
         int(np.prod(s.shape)) * s.dtype.itemsize

@@ -34,8 +34,8 @@ ring with a quantized wire payload and a dequant-GEMM per ring step (serving
 TP decode over quantized weights) — lives in ``parallel/qring.py``.
 
 Every decomposed/monolithic call site records a trace-time bytes-on-wire span
-(``utils.comms_logging.collective_spans``) so MonitorMaster and ``bench.py
---overlap`` can report collective volume and overlap ratio.
+(``utils.comms_logging.collective_spans``) so MonitorMaster and the tests
+can report collective volume and overlap ratio.
 """
 
 import contextlib

@@ -303,8 +303,8 @@ def quant_row_parallel_apply(x, q, scales, *, bits: int, dtype,
                              interpret=None, site: str = "tp.row_dense"):
     """Quantized row-parallel dense through the fused quantized ring — the
     quant-node analogue of ``overlap.row_parallel_dense_apply`` (same row
-    padding, same ``site``/``site + ".gather"`` span convention, so bench
-    A/Bs line up column-for-column).
+    padding, same ``site``/``site + ".gather"`` span convention, so the two
+    rings' spans line up column-for-column).
 
     The ring's wire width and scale block come from the engine's
     ``comm_overlap`` config (``chunk_bits``/``quant_block``); the EF residual

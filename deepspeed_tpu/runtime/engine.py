@@ -682,7 +682,7 @@ class DeepSpeedEngine:
         sharded over the data axis (one fp32 copy per device). Optimizer-state
         adjacent but deliberately NOT in ``TrainState`` (and not checkpointed):
         restores reset it to zero, which costs one step of feedback — benign
-        (documented in docs/PERF.md)."""
+        (documented in docs/FEATURES.md)."""
         mesh = self.mesh_spec
         W = mesh.size(AXIS_DATA)
 

@@ -113,7 +113,7 @@ class CollectiveSpans:
     reports per-trace estimates (one record per compiled call site, not per
     step); ``overlap_ratio`` is the fraction of recorded bytes moved by
     overlap-scheduled (chunked ring / pipelined a2a) collectives. Consumed by
-    MonitorMaster events and ``bench.py --overlap``.
+    MonitorMaster events and ``tests/unit/parallel/test_overlap.py``.
     """
 
     def __init__(self):

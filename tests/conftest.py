@@ -101,18 +101,16 @@ def pytest_configure(config):
         "fast lane")
     config.addinivalue_line(
         "markers", "comm_overlap: comm-compute overlap parity lane (chunked "
-        "collective matmuls, quantized allreduce, bench --overlap smoke) — "
-        "tier-1 fast lane")
+        "collective matmuls, quantized allreduce) — tier-1 fast lane")
     config.addinivalue_line(
         "markers", "qring: fused quantized collective-matmul ring lane "
         "(fp-wire last-ulp parity vs monolithic psum, intN wire error "
         "bounds, EF-across-ring-steps convergence, overflow gate, "
-        "chunk_bits sweep + byte crosscheck) — tier-1 fast lane; its "
-        "bench --qring smoke is marked slow")
+        "chunk_bits sweep + byte crosscheck) — tier-1 fast lane")
     config.addinivalue_line(
         "markers", "weight_quant: weight-streaming quantized decode lane "
-        "(int4 packing, fused dequant-matmul parity, audit, bench --wq "
-        "smoke) — tier-1 fast lane")
+        "(int4 packing, fused dequant-matmul parity, audit) — tier-1 fast "
+        "lane")
     config.addinivalue_line(
         "markers", "prefix_cache: radix prompt-prefix KV cache lane (trie "
         "semantics, LRU eviction, suffix prefill, hit-vs-miss greedy parity, "
@@ -120,8 +118,8 @@ def pytest_configure(config):
     config.addinivalue_line(
         "markers", "observability: tracing/metrics/profiler lane (span "
         "nesting + Perfetto schema, cross-process trace join, histogram "
-        "percentiles, /metrics exposition, tag-schema lint, overhead A/B "
-        "smoke) — tier-1 fast lane")
+        "percentiles, /metrics exposition, tag-schema lint, loadgen "
+        "--trace-out) — tier-1 fast lane")
     config.addinivalue_line(
         "markers", "analysis: program-contract analyzer lane (donation "
         "audit, retrace lint, host-sync detector, loop-invariance pin, "
@@ -131,7 +129,7 @@ def pytest_configure(config):
         "markers", "paged_kv: paged KV memory lane (page allocator, refcount "
         "+ copy-on-write lifecycle, paged-attention kernel-vs-XLA parity, "
         "hit/miss/retry/drain/migration bit-exactness, page-bind chaos "
-        "kill, bench --bench-paged smoke) — tier-1 fast lane")
+        "kill) — tier-1 fast lane")
     config.addinivalue_line(
         "markers", "serving_autoscale: elastic control plane lane "
         "(autoscaler scale-up/down, hysteresis, SLO admission shed-vs-"
@@ -142,18 +140,17 @@ def pytest_configure(config):
         "(subproc protocol hello/quarantine/stop-ladder, HostedReplica "
         "router membership, ReplicaSupervisor restart storm + budget, "
         "chaos sig= grammar, real SIGKILL+respawn parity) — tier-1 fast "
-        "lane; its bench smoke is marked slow")
+        "lane")
     config.addinivalue_line(
         "markers", "serving_net: socket replica transport lane (frame codec "
         "roundtrip + CRC quarantine/resync, versioned hello + session "
         "resume, sever-evict-redial parity, net:* chaos grammar, partition/"
-        "delay soak over real TCP children) — tier-1 fast lane; its bench "
-        "smoke is marked slow")
+        "delay soak over real TCP children) — tier-1 fast lane")
     config.addinivalue_line(
         "markers", "speculative: speculative decoding lane (n-gram/draft "
         "proposers, one-pass verify, greedy bit-identity across hit/miss/"
         "retry/drain/migration, rejection-sampling exactness, rollback edge "
-        "cases, bench --bench-spec smoke) — tier-1 fast lane")
+        "cases) — tier-1 fast lane")
 
 
 def pytest_collection_modifyitems(config, items):

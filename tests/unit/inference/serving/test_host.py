@@ -407,22 +407,3 @@ def test_hosted_stall_is_real_sigstop(live_hosts):
     assert saw_suspect, "SIGSTOP silence never aged the replica"
     assert router.replica_state(0) == ReplicaState.LIVE, \
         "SIGCONT did not bring the replica back"
-
-
-@pytest.mark.slow
-def test_bench_hosts_smoke(capsys):
-    """Full --bench-hosts --smoke acceptance (concurrency overlap + SIGKILL/
-    respawn soak): heavy (several child boots + respawn waits) — slow lane;
-    the committed BENCH_HOSTS artifact is the full-run evidence."""
-    sys.path.insert(0, os.path.join(REPO, "benchmarks", "serving"))
-    import importlib
-    loadgen = importlib.import_module("loadgen")
-    rc = loadgen.main(["--bench-hosts", "--smoke"])
-    out = capsys.readouterr().out.strip().splitlines()[-1]
-    doc = json.loads(out)
-    assert rc == 0
-    g = doc["hosts_gates"]
-    assert doc["gates_ok"] is True
-    assert g["hosts_pump_concurrently"] and g["concurrent_pump_overlap_s"] > 0
-    assert g["soak_ok"] and g["supervised_respawn"]
-    assert g["respawned_back_live"]

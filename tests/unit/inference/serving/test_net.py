@@ -17,7 +17,6 @@ import json
 import os
 import socket
 import struct
-import sys
 import threading
 import time
 import zlib
@@ -578,28 +577,6 @@ def test_socket_fleet_partition_sigkill_soak(socket_fleet):
     # the healed partition kept its connection-level counters sane
     assert hosts[2].resumed_last is False
     assert not hosts[1].severed and not hosts[2].severed
-
-
-# ------------------------------------------------------------------ bench smoke
-@pytest.mark.slow
-def test_bench_net_smoke(capsys):
-    """Full --bench-net --smoke acceptance (stdio-vs-socket A/B + partition/
-    delay/SIGKILL soak + sever-resume probe + delay no-false-kill): heavy
-    (many child boots) — slow lane; the committed BENCH_NET artifact is the
-    full-run evidence."""
-    sys.path.insert(0, os.path.join(REPO, "benchmarks", "serving"))
-    import importlib
-    loadgen = importlib.import_module("loadgen")
-    rc = loadgen.main(["--bench-net", "--smoke"])
-    out = capsys.readouterr().out.strip().splitlines()[-1]
-    doc = json.loads(out)
-    assert rc == 0
-    g = doc["net_gates"]
-    assert doc["gates_ok"] is True
-    assert g["socket_holds_0p9x"]
-    assert g["soak_ok"] and g["respawn_with_redial"]
-    assert g["sever_resumed_session"] and g["sever_served_after"]
-    assert g["delay_no_false_kill"]
 
 
 def test_a_link_reads_a_socket_numbered_past_selects_limit():

@@ -12,10 +12,6 @@ threading through executor/scheduler/router preserves it under every recovery
 path the serving column has.
 """
 
-import importlib.util
-import json
-import os
-
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -39,8 +35,6 @@ pytestmark = pytest.mark.speculative
 TINY = dict(vocab_size=96, max_seq_len=64, n_embd=32, n_layer=2, n_head=4,
             dtype=jnp.float32)
 CAP = 48
-REPO = os.path.dirname(os.path.dirname(os.path.dirname(os.path.dirname(
-    os.path.dirname(os.path.abspath(__file__))))))
 
 
 @pytest.fixture(scope="module")
@@ -456,28 +450,3 @@ def test_mid_verify_chaos_kill_bit_exact_retry(engines):
     np.testing.assert_array_equal(h.result(), _ref(engines[0], p, 12))
     assert router.snapshot()["lost"] == 0
     fi.reset_faults()
-
-
-# --------------------------------------------------------------- bench smoke
-@pytest.mark.slow
-def test_bench_spec_smoke(tmp_path, capsys):
-    """--bench-spec --smoke: schema + parity/lost gates must hold in-process.
-    Slow lane (tier-1 window reclaim): the in-window speculative unit lanes
-    above cover the semantics; the committed BENCH_SPEC artifact gates the
-    acceptance/passes-per-token thresholds."""
-    spec = importlib.util.spec_from_file_location(
-        "loadgen_specbench", os.path.join(REPO, "benchmarks", "serving",
-                                          "loadgen.py"))
-    lg = importlib.util.module_from_spec(spec)
-    spec.loader.exec_module(lg)
-    out_file = str(tmp_path / "BENCH_SPEC_smoke.json")
-    lg.main(["--smoke", "--bench-spec", "--out", out_file])
-    capsys.readouterr()
-    with open(out_file) as f:
-        out = json.load(f)
-    assert out["metric"] == "spec_target_passes_per_token"
-    g = out["spec_gates"]
-    assert g["parity_ok_every_request"] is True
-    assert g["lost_zero_all_lanes"] is True
-    assert g["acceptance_rate"] is not None
-    assert g["passes_per_token"] is not None

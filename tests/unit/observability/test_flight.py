@@ -7,8 +7,8 @@ flight dump + profiler arming), the chaos-soak acceptance criterion (every
 retried/evicted/shed/deadline-missed request keeps its full span tree; the
 injected stall trips the detector and the dump carries the evidence), the
 cross-process kill→retry tail capture over a real subprocess, the
-``/statusz``/``/healthz`` status plane + ``ds-tpu-top``, loadgen
-``--flight-out``, and ``bench.py --trajectory``.
+``/statusz``/``/healthz`` status plane + ``ds-tpu-top``, and loadgen
+``--flight-out``.
 """
 
 import importlib.util
@@ -683,7 +683,7 @@ class TestStatusPlane:
         assert "unreachable" in capsys.readouterr().out
 
 
-# --------------------------------------------------------- loadgen + bench
+# ---------------------------------------------------------------- loadgen
 class TestLoadgenFlight:
     def _loadgen(self):
         spec = importlib.util.spec_from_file_location(
@@ -695,7 +695,7 @@ class TestLoadgenFlight:
 
     def test_flight_out_bundle_attribution_and_jsonl(self, tmp_path, capsys):
         """One smoke run covers the --flight-out surface: the bundle, the
-        BENCH attribution detail, AND the --jsonl-metrics mirror (per-request
+        JSON's attribution detail, AND the --jsonl-metrics mirror (per-request
         latency/e2e_ms + latency/phase/* rows, no telemetry double-write)."""
         loadgen = self._loadgen()
         path = str(tmp_path / "bundle.json")
@@ -704,7 +704,7 @@ class TestLoadgenFlight:
         assert rc == 0
         out = capsys.readouterr().out.strip().splitlines()[-1]
         bench = json.loads(out)
-        # the BENCH detail carries the schema-checked p50-vs-p99 breakdown
+        # the detail carries the schema-checked p50-vs-p99 breakdown
         bd = bench["detail"]["attribution"]
         assert bd["requests"] > 0
         for group in ("p50_shares", "p99_shares"):
@@ -723,57 +723,3 @@ class TestLoadgenFlight:
         assert tags.get("latency/e2e_ms", 0) > 0
         assert tags.get("latency/phase/decode_ms", 0) > 0
         assert tags.get("serving/ttft_ms", 0) == tags["latency/e2e_ms"]
-
-    def test_flight_out_rejected_by_dedicated_bench_lanes(self, tmp_path):
-        """--bench-spec/--bench-autoscale dispatch before the flight wiring:
-        the combination must error, not silently write no bundle."""
-        loadgen = self._loadgen()
-        for lane in ("--bench-spec", "--bench-autoscale"):
-            with pytest.raises(SystemExit) as ei:
-                loadgen.main(["--smoke", lane,
-                              "--flight-out", str(tmp_path / "f.json")])
-            assert ei.value.code == 2
-
-    def test_bench_trajectory(self, tmp_path):
-        spec = importlib.util.spec_from_file_location(
-            "bench_traj", os.path.join(REPO, "bench.py"))
-        bench = importlib.util.module_from_spec(spec)
-        spec.loader.exec_module(bench)
-        # self-made fixtures in the two shapes the scraper reads: a headline
-        # with an in-file ``*_gates`` dict, and a gate-less headline
-        fixtures = {
-            "BENCH_OBS_r10.json": {
-                "metric": "obs_tracing_tpot_overhead_frac", "value": 0.01,
-                "unit": "fraction", "smoke": False,
-                "obs_gates": {"overhead_le_5pct": True, "spans_complete": True}},
-            "BENCH_PAGED_r13.json": {
-                "metric": "paged_vs_slots_tokens_ratio", "value": 1.7,
-                "unit": "x", "paged_gates": {"parity": True}},
-            "BENCH_PLAIN_r01.json": {
-                "metric": "train_tokens_per_sec_per_chip", "value": 123.0,
-                "unit": "tokens/s/chip"},
-        }
-        for name, doc in fixtures.items():
-            (tmp_path / name).write_text(json.dumps(doc))
-        out = bench.bench_trajectory(root=str(tmp_path))
-        assert out["artifacts"] == 3
-        rows = {r["file"]: r for r in out["rows"]}
-        assert rows["BENCH_OBS_r10.json"]["gates_ok"] is True
-        assert rows["BENCH_OBS_r10.json"]["gates_total"] == 2
-        assert rows["BENCH_OBS_r10.json"]["metric"] \
-            == "obs_tracing_tpot_overhead_frac"
-        assert rows["BENCH_PLAIN_r01.json"]["round"] == 1
-        assert rows["BENCH_PLAIN_r01.json"]["value"] == 123.0
-        assert rows["BENCH_PLAIN_r01.json"]["gates_ok"] is None
-        # round ordering: r01 first
-        assert out["rows"][0]["file"] == "BENCH_PLAIN_r01.json"
-        traj = json.load(open(tmp_path / "BENCH_TRAJECTORY.json"))
-        assert traj["artifacts"] == 3
-        assert traj["all_gates_ok"] is True
-        md = open(tmp_path / "BENCH_TRAJECTORY.md").read()
-        assert "| BENCH_PAGED_r13.json |" in md
-        # an unreadable artifact breaks the record: all_gates_ok must flip
-        (tmp_path / "BENCH_BROKEN_r99.json").write_text("{truncated")
-        out2 = bench.bench_trajectory(root=str(tmp_path))
-        assert out2["all_gates_ok"] is False
-        assert any("error" in r for r in out2["rows"])

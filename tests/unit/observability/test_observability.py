@@ -556,36 +556,8 @@ class TestTrainSpans:
         assert snap["Train/step_time_ms"]["count"] >= 1
 
 
-# ------------------------------------------------------------ overhead A/B smoke
-class TestOverheadSmoke:
-    def test_obs_ab_smoke_json(self, capsys):
-        spec = importlib.util.spec_from_file_location(
-            "serving_loadgen_obs", os.path.join(REPO, "benchmarks", "serving",
-                                                "loadgen.py"))
-        loadgen = importlib.util.module_from_spec(spec)
-        spec.loader.exec_module(loadgen)
-        rc = loadgen.main(["--smoke", "--obs-ab", "--obs-reps", "1"])
-        out = capsys.readouterr().out.strip().splitlines()[-1]
-        doc = json.loads(out)
-        assert doc["metric"] == "obs_tracing_tpot_overhead_frac"
-        g = doc["obs_gates"]
-        for key in ("agg_tpot_ms_per_token_off", "agg_tpot_ms_per_token_on",
-                    "agg_tpot_ms_per_token_flight",
-                    "tpot_overhead_frac", "tpot_within_2pct",
-                    "flight_overhead_frac", "flight_within_2pct",
-                    "spans_per_on_rep", "attribution_rows_per_flight_rep"):
-            assert key in g
-        assert g["spans_per_on_rep"] > 0           # tracing arm really traced
-        # the flight arm really attributed every completion
-        assert g["attribution_rows_per_flight_rep"] > 0
-        assert g["attribution_breakdown_emitted"] is True
-        bd = doc["detail"]["attribution"]
-        assert set(bd["p50_shares"]) == set(bd["p99_shares"])
-        # rc reflects the gate; on a noisy CI host the smoke-size model can
-        # exceed 2% — the committed BENCH_OBS artifact is the acceptance run
-        assert rc in (0, 1)
-        assert get_tracer().enabled is False       # A/B leaves tracing off
-
+# ------------------------------------------------------------ loadgen --trace-out
+class TestLoadgenTrace:
     def test_loadgen_trace_out(self, tmp_path, capsys):
         spec = importlib.util.spec_from_file_location(
             "serving_loadgen_trace", os.path.join(
@@ -599,15 +571,8 @@ class TestOverheadSmoke:
         _chrome_check(doc["traceEvents"])
         out = capsys.readouterr().out.strip().splitlines()[-1]
         bench = json.loads(out)
-        assert bench["trace"]["spans"] > 0
-
-    def test_bench_obs_artifact_gates(self):
-        path = os.path.join(REPO, "BENCH_OBS_r10.json")
-        doc = json.load(open(path))
-        g = doc["obs_gates"]
-        assert g["tpot_within_2pct"] is True
-        assert g["tpot_overhead_frac"] <= 0.02
-        assert g["spans_per_on_rep"] > 0
+        assert bench["trace"]["spans"] > 0         # the run really traced
+        assert get_tracer().enabled is False       # and left tracing off
 
 
 # --------------------------------------------------- chaos soak + acceptance

@@ -2,8 +2,8 @@
 interpreter mode and a Mosaic regression would go unseen).
 
 The check bodies and tolerances live in ``deepspeed_tpu.ops.kernel_checks`` — the
-SAME source bench.py's pre-run kernel gate executes every round, so the test lane
-and the driver-visible gate cannot drift.
+SAME table ``chip_smoke.py``'s kernel gate runs whole on the chip, so the test lane
+and that gate cannot drift.
 
 Run on a TPU host with ``python -m pytest tests/unit/ops/test_kernels_tpu.py -p
 no:cacheprovider`` OUTSIDE the CPU-pinning conftest, or drive via

@@ -1,7 +1,7 @@
 """Gather-fused MoE decode FFN kernel vs the XLA gather reference (interpret mode).
 
 Real-TPU compiled parity rides the shared kernel gate
-(``ops/kernel_checks.py::check_moe_decode_ffn``, run by ``bench.py`` and the TPU lane).
+(``ops/kernel_checks.py::check_moe_decode_ffn``, run by ``chip_smoke.py`` and the TPU lane).
 """
 
 import jax

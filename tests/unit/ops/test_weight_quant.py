@@ -3,16 +3,11 @@
 Covers the ``weight_quant`` lane (ISSUE 5): int4 pack/unpack roundtrip,
 fused-kernel vs XLA-dequant parity (interpret mode), TP=2 sharded quantized
 projections on the virtual CPU mesh, greedy-token parity of quantized engines
-vs fp, the quantize-time outlier audit, the loop-invariance HLO pin (no
-dequant inside compiled decode bodies on the fallback path), and the
-``bench.py --wq --smoke`` JSON-schema lane.
+vs fp, the quantize-time outlier audit and the loop-invariance HLO pin (no
+dequant inside compiled decode bodies on the fallback path).
 """
 
-import json
 import logging
-import os
-import subprocess
-import sys
 
 import jax
 import jax.numpy as jnp
@@ -198,7 +193,8 @@ def test_engine_audit_outlier_exclusion_and_config_exclude():
                if a["decision"] == "quantized")
 
 
-def test_engine_audit_monitor_events():
+@pytest.mark.parametrize("bits, reduction", [(8, 1.8), (4, 3.2)])
+def test_engine_audit_monitor_events(bits, reduction):
     class FakeMonitor:
         enabled = True
 
@@ -208,7 +204,7 @@ def test_engine_audit_monitor_events():
         def write_events(self, evs):
             self.events += list(evs)
 
-    _, _, e = _tiny_engines(bits=8)
+    _, _, e = _tiny_engines(bits=bits)
     mon = FakeMonitor()
     e.set_monitor(mon)
     tags = {t for t, _, _ in mon.events}
@@ -216,7 +212,9 @@ def test_engine_audit_monitor_events():
             "inference/weight_quant/matrices_quantized",
             "inference/weight_quant/reduction_vs_bf16"} <= tags
     rep = e.weight_stream_report()
-    assert rep["reduction_quantized_nodes"] > 1.8        # int8 + scale overhead
+    # modeled bytes a decode step streams, bf16 over quantized + scales
+    assert rep["reduction_quantized_nodes"] > reduction
+    assert rep["modeled_step_bytes"] > 0
 
 
 def test_legacy_int8_resolves_to_weight_quant():
@@ -349,42 +347,3 @@ def test_no_dequant_inside_decode_loop_body():
     with pytest.raises(LoopInvarianceError, match="dequant-hoist"):
         assert_loop_invariant(bad_loop, args, invar_predicate=_INT8_INVAR,
                               what="dequant-hoist")
-
-
-# ------------------------------------------------------------ bench lane
-def test_bench_wq_smoke_emits_valid_json(tmp_path):
-    """``bench.py --wq --smoke``: the interleaved A/B harness runs end-to-end
-    on CPU and emits schema-complete JSON (CI lane so the bench can't rot —
-    same contract as the ``--overlap`` smoke lane)."""
-    out = tmp_path / "wq.json"
-    root = os.path.dirname(os.path.dirname(os.path.dirname(
-        os.path.dirname(os.path.abspath(__file__)))))
-    env = dict(os.environ)
-    env["PYTHONPATH"] = root + os.pathsep + env.get("PYTHONPATH", "")
-    proc = subprocess.run(
-        [sys.executable, os.path.join(root, "bench.py"), "--wq", "--smoke",
-         "--out", str(out)],
-        capture_output=True, text=True, timeout=560, env=env,
-        cwd=str(tmp_path))
-    assert proc.returncode == 0, proc.stderr[-2000:]
-    data = json.loads(out.read_text())
-    assert data["metric"] == "weight_quant_decode_interleaved_ab"
-    assert data["smoke"] is True
-    for lane in ("bf16", "int8", "int4"):
-        assert lane in data["lanes"]
-    for lane in ("int8", "int4"):
-        d = data["lanes"][lane]
-        assert 0.0 <= d["greedy_parity_vs_bf16"] <= 1.0
-        assert d["modeled_bytes_reduction_quantized_nodes"] > 1.0
-        assert d["modeled_step_bytes"] > 0
-    assert set(data["acceptance"]) >= {
-        "int8_greedy_parity_ge_0.98", "modeled_reduction_int8_ge_1.9x",
-        "modeled_reduction_int4_ge_3.5x"}
-    # looser than the real ≥1.9x/≥3.5x criteria (held by the non-smoke lane,
-    # see BENCH_WQ_r07.json): the smoke model's k=64 matrices degrade to
-    # effective group 64, which lands int8 at ~1.901 — a knife-edge a tiny
-    # model tweak shouldn't turn into a CI failure
-    assert data["lanes"]["int8"]["modeled_bytes_reduction_quantized_nodes"] \
-        >= 1.8
-    assert data["lanes"]["int4"]["modeled_bytes_reduction_quantized_nodes"] \
-        >= 3.2

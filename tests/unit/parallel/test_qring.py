@@ -14,11 +14,6 @@ must agree to the byte. Runs inside the tier-1 window (``qring`` marker,
 rank 5 in ``TIER1_BUDGETS_S``).
 """
 
-import json
-import os
-import subprocess
-import sys
-
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -40,9 +35,6 @@ from deepspeed_tpu.utils.comms_logging import collective_spans
 from deepspeed_tpu.utils.jax_compat import shard_map
 
 pytestmark = pytest.mark.qring
-
-REPO = os.path.dirname(os.path.dirname(os.path.dirname(
-    os.path.dirname(os.path.abspath(__file__)))))
 
 
 def _build_slab(rng, k, n, bits, group=8):
@@ -302,27 +294,3 @@ def test_crosscheck_pass_agrees_with_span_and_closed_form(eight_devices):
             [f.message for f in res.findings]
         rec = collective_spans.summary()[site]["bytes_total"]
         assert rec == qring_wire_bytes(m, n, tp, wire_bits=wb, block=qb)
-
-
-# ----------------------------------------------------------------- bench lane
-@pytest.mark.slow
-def test_bench_qring_smoke_emits_json(tmp_path):
-    """``bench.py --qring --smoke`` runs the three-lane A/B/C harness end to
-    end on the virtual CPU mesh (forced-fused engines, so the quant nodes
-    actually reach the ring) and every in-file gate holds: teacher-forced
-    parity, bytes ratio <= 0.3, three-way crosscheck exact."""
-    out = tmp_path / "BENCH_QRING_smoke.json"
-    env = dict(os.environ)
-    env["PYTHONPATH"] = REPO + os.pathsep + env.get("PYTHONPATH", "")
-    proc = subprocess.run(
-        [sys.executable, os.path.join(REPO, "bench.py"), "--qring", "--smoke",
-         "--out", str(out)],
-        capture_output=True, text=True, timeout=420, env=env, cwd=str(tmp_path))
-    assert proc.returncode == 0, proc.stderr[-2000:]
-    data = json.loads(out.read_text())
-    assert data["metric"] == "qring_interleaved_ab"
-    assert data["smoke"] is True
-    assert data["crosscheck"]["exact"] is True
-    assert all(data["qring_gates"].values()), data["qring_gates"]
-    assert set(data["ring_bytes_recorded"]) == {"mono_quant", "fp_ring",
-                                                "qring"}

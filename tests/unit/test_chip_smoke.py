@@ -41,24 +41,6 @@ def test_alone_in_a_directory_it_fails(tmp_path):
     assert not _result_lines(out.stdout)
 
 
-@pytest.mark.parametrize("mode", ["train", "inference"])
-def test_the_bench_gate_asks_only_for_checks_that_exist(mode, monkeypatch):
-    """``bench.py``'s pre-run gate names its checks by hand: each is a key of
-    ``KERNEL_CHECKS`` (a check deleted from the table and left in the gate's
-    list is a ``KeyError`` on the chip, where no CPU test looked)."""
-    import importlib.util
-    from deepspeed_tpu.ops import kernel_checks
-    spec = importlib.util.spec_from_file_location(
-        "_bench_under_test", os.path.join(REPO, "bench.py"))
-    bench = importlib.util.module_from_spec(spec)
-    spec.loader.exec_module(bench)
-    asked = []
-    monkeypatch.setattr(kernel_checks, "run_kernel_checks",
-                        lambda names: asked.extend(names) or {})
-    assert bench.kernel_gate(mode) == {}
-    assert asked and set(asked) <= set(kernel_checks.KERNEL_CHECKS)
-
-
 @pytest.mark.slow
 def test_rehearsal_passes_and_a_forced_dispatch_failure_fails():
     out = _run(["--rehearse-cpu"])
